@@ -11,11 +11,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))      # repo root (run from anywhere)
 
 import jax
-
-# honor JAX_PLATFORMS=cpu even when a TPU plugin is installed (the
-# env var alone does not always override a preinstalled plugin)
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 import numpy as np
 import optax

@@ -20,7 +20,6 @@ os.environ.setdefault("XLA_FLAGS",
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 import jax.numpy as jnp
 import numpy as np

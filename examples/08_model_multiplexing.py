@@ -13,7 +13,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
-jax.config.update("jax_platforms", "cpu")   # env alone may not win
 
 import ray_tpu
 from ray_tpu import serve

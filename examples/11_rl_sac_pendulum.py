@@ -13,11 +13,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))      # repo root (run from anywhere)
 
-import jax
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import ray_tpu
 from ray_tpu.rllib import SACConfig
 

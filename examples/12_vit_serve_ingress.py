@@ -12,9 +12,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import jax
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-
 import json
 import urllib.request
 

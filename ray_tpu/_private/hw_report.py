@@ -3,9 +3,9 @@
 Role parity with the reference's reporter agent
 (dashboard/modules/reporter/reporter_agent.py — psutil snapshots per
 node shipped with heartbeats and surfaced by the dashboard). TPU
-metrics come from already-initialized jax backends only: probing
-`jax.devices()` here could block on a wedged device tunnel, so a node
-that never touched the TPU simply reports none.
+metrics come from already-initialized jax backends only: the reporter
+must never be the one to open a chip (the process that opens it owns
+it), so a node that never touched the TPU simply reports none.
 """
 from __future__ import annotations
 
@@ -55,22 +55,15 @@ def _tpu_stats() -> Optional[list]:
     jax = sys.modules.get("jax")
     if jax is None:
         return None
-    try:
-        from jax._src import xla_bridge
-        if not xla_bridge._backends:        # nothing initialized yet
-            return None
-        out = []
-        for dev in jax.local_devices():
-            if dev.platform != "tpu":
-                continue
-            entry = {"id": dev.id, "kind": dev.device_kind}
-            try:
-                ms = dev.memory_stats() or {}
-                entry["hbm_bytes_in_use"] = ms.get("bytes_in_use")
-                entry["hbm_bytes_limit"] = ms.get("bytes_limit")
-            except Exception:
-                pass
-            out.append(entry)
-        return out or None
-    except Exception:
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
         return None
+    out = []
+    for dev in jax.local_devices():
+        if dev.platform != "tpu":
+            continue
+        ms = dev.memory_stats() or {}
+        out.append({"id": dev.id, "kind": dev.device_kind,
+                    "hbm_bytes_in_use": ms.get("bytes_in_use"),
+                    "hbm_bytes_limit": ms.get("bytes_limit")})
+    return out or None

@@ -12,9 +12,10 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from typing import Dict, List, Optional
+
+from ray_tpu._private.native_build import ensure_built
 
 _REPO_ROOT = os.path.abspath(
     os.path.join(os.path.dirname(__file__), "..", ".."))
@@ -30,23 +31,12 @@ TYPE_GAUGE = 2
 TYPE_HISTOGRAM = 3
 
 
-def _ensure_built() -> str:
-    if not os.path.exists(_LIB) or \
-            os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
-        os.makedirs(_BUILD_DIR, exist_ok=True)
-        subprocess.run(
-            ["g++", "-O2", "-Wall", "-fPIC", "-std=c++17", "-shared",
-             "-o", _LIB, _SRC, "-lpthread", "-lrt"],
-            check=True, capture_output=True)
-    return _LIB
-
-
 def _load() -> ctypes.CDLL:
     global _lib
     with _lib_lock:
         if _lib is not None:
             return _lib
-        lib = ctypes.CDLL(_ensure_built())
+        lib = ctypes.CDLL(ensure_built(_SRC, _LIB))
         lib.metrics_create.restype = ctypes.c_void_p
         lib.metrics_create.argtypes = [ctypes.c_char_p]
         lib.metrics_attach.restype = ctypes.c_void_p

@@ -10,10 +10,11 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 import time
 from typing import Optional, Tuple
+
+from ray_tpu._private.native_build import ensure_built
 
 from ray_tpu._private.ids import ObjectID
 
@@ -61,23 +62,12 @@ class ShmTimeout(ShmStoreError):
     pass
 
 
-def _ensure_built() -> str:
-    if not os.path.exists(_LIB) or \
-            os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
-        os.makedirs(_BUILD_DIR, exist_ok=True)
-        subprocess.run(
-            ["g++", "-O2", "-Wall", "-fPIC", "-std=c++17", "-shared",
-             "-o", _LIB, _SRC, "-lpthread", "-lrt"],
-            check=True, capture_output=True)
-    return _LIB
-
-
 def _load() -> ctypes.CDLL:
     global _lib
     with _lib_lock:
         if _lib is not None:
             return _lib
-        lib = ctypes.CDLL(_ensure_built())
+        lib = ctypes.CDLL(ensure_built(_SRC, _LIB))
         lib.store_create.restype = ctypes.c_void_p
         lib.store_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64]
         lib.store_attach.restype = ctypes.c_void_p

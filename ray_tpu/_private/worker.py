@@ -44,16 +44,14 @@ def global_worker() -> Worker:
 
 
 def _detect_tpu_chips() -> int:
-    """Count local TPU chips without forcing a jax import unless one is
-    plausibly present."""
+    """Count local TPU chips. ``JAX_PLATFORMS=cpu`` answers 0 without
+    touching JAX; otherwise whatever JAX raises while opening its
+    backend is raised here too — a node whose chip failed to open must
+    not come up quietly with no ``TPU`` resource."""
     if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
         return 0
-    try:
-        import jax
-        return sum(1 for d in jax.devices()
-                   if d.platform not in ("cpu",))
-    except Exception:
-        return 0
+    import jax
+    return sum(1 for d in jax.devices() if d.platform != "cpu")
 
 
 def init(address: Optional[str] = None,

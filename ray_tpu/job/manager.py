@@ -68,7 +68,6 @@ class JobManager:
             info = JobInfo(job_id, entrypoint, metadata)
             self._jobs[job_id] = info
         env = dict(os.environ)
-        env.pop("PYTHONPATH", None)   # breaks the TPU plugin discovery
         env["RAY_TPU_ADDRESS"] = self._head_address
         env["RAY_TPU_JOB_ID"] = job_id
         cwd = None

@@ -471,9 +471,8 @@ def generate_stream(model: Llama, params, prompt_ids: jnp.ndarray,
     bursts of up to ``chunk_size``.
 
     Why chunked: a host readback pays the runtime's completion-
-    notification latency (tens of ms on tunneled devices) REGARDLESS
-    of compute size, so syncing per token caps streaming at ~1/latency
-    tokens/s. One scan dispatch + one [K, B] readback amortizes that
+    notification latency REGARDLESS of compute size, so syncing per
+    token caps streaming at ~1/latency tokens/s. One scan dispatch + one [K, B] readback amortizes that
     latency over K tokens while keeping time-to-first-token at one
     prefill + one sync. The whole-sequence `generate` (on-device
     while_loop) remains the fastest path for full completions.

@@ -2,25 +2,49 @@
 
 impl:
 - "xla":   einsum attention; XLA fuses mask+softmax well on TPU.
-- "flash": pallas blockwise flash-attention kernel (TPU only, falls back
-           to xla off-TPU) — ray_tpu.ops.flash_attention.
+- "flash": pallas blockwise flash-attention kernel (compiled on TPU,
+           interpreted elsewhere) — ray_tpu.ops.flash_attention.
 - "ring":  sequence-parallel ring attention over the mesh `sequence` axis —
            ray_tpu.parallel.sequence (callers use it via shard_map).
 - "auto":  flash on TPU when shapes allow, else xla.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
+
+
+def _flash_on_mesh(q, k, v, causal: bool) -> jax.Array:
+    """The flash kernel under whatever mesh is ambient (jax.set_mesh).
+
+    GSPMD cannot partition a Mosaic kernel, so on a multi-device mesh
+    the kernel runs per shard inside a shard_map: batch over the data
+    axes (the ones put_batch shards it on), heads over ``tensor`` when
+    they divide. Attention is independent per (batch row, head), so no
+    collective is needed. With no mesh, or one device, it is a plain
+    call."""
+    from ray_tpu.ops.flash_attention import flash_attention
+    attn = functools.partial(flash_attention, causal=causal)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return attn(q, k, v)
+    sizes = dict(mesh.shape)
+    batch = tuple(a for a in ("dcn", "data", "fsdp")
+                  if sizes.get(a, 1) > 1)
+    tensor = sizes.get("tensor", 1)
+    heads = "tensor" if tensor > 1 and q.shape[2] % tensor == 0 \
+        else None
+    spec = P(batch or None, None, heads, None)
+    return jax.shard_map(attn, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def xla_attention(q, k, v, causal: bool = True,
@@ -57,7 +81,6 @@ def xla_attention(q, k, v, causal: bool = True,
 def multi_head_attention(q, k, v, causal: bool = True,
                          impl: str = "auto",
                          bias: Optional[jax.Array] = None) -> jax.Array:
-    was_auto = impl == "auto"
     if impl == "auto":
         # Measured on v5e (fwd+bwd, H=12 D=64): at T=1024 the pallas
         # kernel wins for B>=8 (B=24: 43.2% vs 34.3% MFU — XLA's
@@ -70,18 +93,9 @@ def multi_head_attention(q, k, v, causal: bool = True,
                            (T >= 2048 or (T >= 1024 and B >= 8))) \
             else "xla"
     if impl == "flash":
-        from ray_tpu.ops.flash_attention import flash_attention
-        if was_auto:
-            # auto picked flash opportunistically: a pallas/libtpu
-            # hiccup falls back to XLA rather than failing the model.
-            try:
-                return flash_attention(q, k, v, causal=causal)
-            except Exception:
-                return xla_attention(q, k, v, causal=causal,
-                                     bias=bias)
-        # Explicitly requested flash must not silently become XLA
-        # (benchmarks and kernel tests would record the wrong path).
-        return flash_attention(q, k, v, causal=causal)
+        # No fallback: a kernel that fails to lower must fail the
+        # model, not quietly become its XLA reference.
+        return _flash_on_mesh(q, k, v, causal)
     if impl == "ring":
         raise ValueError(
             "impl='ring' must be invoked through "

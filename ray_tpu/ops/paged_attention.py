@@ -298,21 +298,34 @@ def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                  m_sc, l_sc, acc_sc, page_size=page_size, scale=scale)
 
 
-def _kernel_q(pt_ref, pos_ref, q_ref, k_ref, v_ref, sk_ref, sv_ref,
+def _kernel_q(pt_ref, pos_ref, sk_ref, sv_ref, q_ref, k_ref, v_ref,
               o_ref, m_sc, l_sc, acc_sc, *, page_size: int,
               scale: float):
-    """Int8 variant: the page's fp32 absmax scale rides its own tiny
-    block (chosen by the same scalar-prefetched page-table entry) and
-    the dequantize happens IN REGISTER right after the page DMA — the
-    fp window never exists in HBM or VMEM, so the kernel's memory
-    footprint is the halved int8 one."""
+    """Int8 variant: the fp32 absmax scales of the pages this call
+    attends ride SCALAR PREFETCH next to the page table (flat
+    ``[KH * B * max_pages]``, gathered by the wrapper), and the
+    dequantize happens IN REGISTER right after the page DMA — the fp
+    window never exists in HBM or VMEM, so the kernel's memory
+    footprint is the halved int8 one. (A ``(KH, 1, 1)`` VMEM block
+    over the ``[KH, n_pages, 1]`` scale tensor is not tileable: Mosaic
+    wants the last two block dims (8, 128)-aligned or whole.)"""
     b = pl.program_id(0)
     p = pl.program_id(1)
+    n_b = pl.num_programs(0)
+    n_p = pl.num_programs(1)
+    KH = k_ref.shape[0]
     inv = 1.0 / _QMAX
-    sk = sk_ref[:, 0].astype(jnp.float32) * inv   # [KH, 1]
-    sv = sv_ref[:, 0].astype(jnp.float32) * inv
-    k = k_ref[:, 0].astype(jnp.float32) * sk[:, :, None]  # [KH, Pg, D]
-    v = v_ref[:, 0].astype(jnp.float32) * sv[:, :, None]
+    # [KH, 1, 1] scale columns assembled from KH SMEM scalars: KH is
+    # the untiled leading dim, so the selects touch one vreg each.
+    h_iota = jax.lax.broadcasted_iota(jnp.int32, (KH, 1, 1), 0)
+    sk = jnp.zeros((KH, 1, 1), jnp.float32)
+    sv = jnp.zeros((KH, 1, 1), jnp.float32)
+    for h in range(KH):
+        i = (h * n_b + b) * n_p + p
+        sk = jnp.where(h_iota == h, sk_ref[i] * inv, sk)
+        sv = jnp.where(h_iota == h, sv_ref[i] * inv, sv)
+    k = k_ref[:, 0].astype(jnp.float32) * sk      # [KH, Pg, D]
+    v = v_ref[:, 0].astype(jnp.float32) * sv
     _attend_page(b, p, pos_ref, q_ref, k, v, o_ref,
                  m_sc, l_sc, acc_sc, page_size=page_size, scale=scale)
 
@@ -340,41 +353,38 @@ def paged_decode_attention(q, pages_k, pages_v, page_table, positions,
         _check_scale_shapes(pages_k, scales_k, scales_v)
 
     grid = (B, max_pages)
-    page_spec = [
-        # ONE physical page of K/V across ALL kv heads, chosen by
-        # the scalar-prefetched page table: [KH, 1, Pg, D]
-        pl.BlockSpec((KH, 1, Pg, D),
-                     lambda b, p, pt, pos: (0, pt[b, p], 0, 0)),
-        pl.BlockSpec((KH, 1, Pg, D),
-                     lambda b, p, pt, pos: (0, pt[b, p], 0, 0)),
-    ]
-    in_specs = [
-        # q block for this slot, every head: [1, KH, rep, D]
-        pl.BlockSpec((1, KH, rep, D),
-                     lambda b, p, pt, pos: (b, 0, 0, 0)),
-    ] + page_spec
-    operands = [qg, pages_k, pages_v]
+    prefetch = [page_table, positions]
     kern = _kernel
     if quantized:
-        # the page's scale column follows the same page-table index
-        in_specs += [
-            pl.BlockSpec((KH, 1, 1),
-                         lambda b, p, pt, pos: (0, pt[b, p], 0)),
-            pl.BlockSpec((KH, 1, 1),
-                         lambda b, p, pt, pos: (0, pt[b, p], 0)),
-        ]
-        operands += [scales_k, scales_v]
+        # the scales of exactly the pages the table names, flat
+        # [KH * B * max_pages] fp32: KH x the page table's own SMEM
+        # footprint, whatever the pool size
+        prefetch += [scales_k[:, page_table, 0].reshape(-1),
+                     scales_v[:, page_table, 0].reshape(-1)]
         kern = _kernel_q
+
+    def _page(b, p, pt, *_):
+        # ONE physical page of K/V across ALL kv heads, chosen by
+        # the scalar-prefetched page table: [KH, 1, Pg, D]
+        return (0, pt[b, p], 0, 0)
+
+    def _slot(b, p, *_):
+        # q/out block for this slot, every head: [1, KH, rep, D]
+        return (b, 0, 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, KH, rep, D), _slot),
+        pl.BlockSpec((KH, 1, Pg, D), _page),
+        pl.BlockSpec((KH, 1, Pg, D), _page),
+    ]
     kernel = functools.partial(kern, page_size=Pg, scale=scale)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, KH, rep, D),
-                lambda b, p, pt, pos: (b, 0, 0, 0)),
+            out_specs=pl.BlockSpec((1, KH, rep, D), _slot),
             scratch_shapes=[
                 pltpu.VMEM((KH, rep, 1), jnp.float32),    # m
                 pltpu.VMEM((KH, rep, 1), jnp.float32),    # l
@@ -383,7 +393,7 @@ def paged_decode_attention(q, pages_k, pages_v, page_table, positions,
         ),
         out_shape=jax.ShapeDtypeStruct((B, KH, rep, D), q.dtype),
         interpret=_interpret() if interpret is None else interpret,
-    )(page_table, positions, *operands)
+    )(*prefetch, qg, pages_k, pages_v)
     return out.reshape(B, H, D)
 
 

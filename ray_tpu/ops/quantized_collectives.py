@@ -35,11 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                   # jax >= 0.6 top-level export
-    _shard_map = jax.shard_map
-except AttributeError:                 # 0.4/0.5 experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 _QMAX = 127.0
 
 
@@ -102,13 +97,13 @@ def quantized_psum_sharded(x, mesh: Mesh, axis: str = "tensor"):
     spec = P(axis, *([None] * (x.ndim - 1)))
     x = jax.device_put(x, NamedSharding(mesh, spec))
 
-    # check_rep=False: the output IS replicated (every rank computes
-    # the identical gathered sum) but the static rep-checker cannot
+    # check_vma=False: the output IS replicated (every rank computes
+    # the identical gathered sum) but the static checker cannot
     # infer that through all_gather-then-sum
     @jax.jit
     @functools.partial(
-        _shard_map, mesh=mesh, in_specs=spec, out_specs=P(),
-        check_rep=False)
+        jax.shard_map, mesh=mesh, in_specs=spec, out_specs=P(),
+        check_vma=False)
     def run(xs):
         # sum over the local shard first so each rank contributes ONE
         # quantized partial (the EQuARX shape), then exchange

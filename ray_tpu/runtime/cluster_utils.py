@@ -69,7 +69,6 @@ class Cluster:
         import sys
         import time
         env = dict(os.environ)
-        env.pop("PYTHONPATH", None)
         from ray_tpu._private.config import GlobalConfig
         env.update(GlobalConfig.to_env())
         env["JAX_PLATFORMS"] = "cpu"

@@ -82,7 +82,6 @@ def start(head, address, num_workers, resources, store_capacity, block):
                "--resources", resources,
                "--store-capacity", str(store_capacity)]
         env = dict(os.environ)
-        env.pop("PYTHONPATH", None)
         if block:
             os.execve(sys.executable, [sys.executable] + cmd[1:], env)
         proc = subprocess.Popen(cmd, env=env,

@@ -698,9 +698,20 @@ class EnginePool:
                 raise RuntimeError(
                     f"replica {idx} is {rep.state}; only live "
                     f"replicas can swap weights")
-        gen = rep.engine.swap_weights(params, generation=generation,
-                                      weights_id=weights_id,
-                                      mode=mode)
+        try:
+            gen = rep.engine.swap_weights(params,
+                                          generation=generation,
+                                          weights_id=weights_id,
+                                          mode=mode)
+        except BaseException:
+            # A swap that dies WITH its replica is how the rollout
+            # controller meets a corpse. Deaths are otherwise noted
+            # lazily, by routed traffic: without this the replica
+            # still reads HEALTHY and the controller's bounded retry
+            # burns every attempt on the corpse within a millisecond
+            # instead of waiting for the rebuild.
+            self._note_replica_death(rep)
+            raise
         with self._lock:
             self.route_stats["weight_swaps"] += 1
         self.events.append("weight_swap", sid=idx,

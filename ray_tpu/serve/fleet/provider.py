@@ -41,6 +41,26 @@ def _addr_pair(ep: Any) -> Tuple[str, int]:
     return (str(ep[0]), int(ep[1]))
 
 
+class FleetNeedsCpuBackend(RuntimeError):
+    """Agent processes were asked for on a host whose JAX is not held
+    to the CPU. Raised instead of quietly defaulting the agents to the
+    CPU backend while the caller believes they serve from chips."""
+
+
+def require_cpu_backend(env) -> None:
+    """Gate every launcher of fleet agent processes (this provider,
+    tools/chaos_serve.py --fleet, serve_bench.py --fleet)."""
+    if env.get("JAX_PLATFORMS", "").strip() != "cpu":
+        raise FleetNeedsCpuBackend(
+            "the multi-process fleet is a CPU-tested control plane, "
+            "not yet brought up on chips: a chip belongs to one "
+            "process and agent processes get no chip assignment, so "
+            "an agent spawned here would fight its parent for the "
+            "device. Set JAX_PLATFORMS=cpu to run the control plane, "
+            "or serve from one process "
+            "(LlamaDeployment(num_engine_replicas=N)).")
+
+
 class FleetCapacityProvider(ReplicaCapacityProvider):
     """Capacity == a warm agent process registered in the directory.
 
@@ -154,7 +174,7 @@ class FleetCapacityProvider(ReplicaCapacityProvider):
         cmd += self._extra_args
         env = dict(self._env if self._env is not None
                    else os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        require_cpu_backend(env)
         proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
                                 stderr=subprocess.DEVNULL,
                                 env=env, text=True)

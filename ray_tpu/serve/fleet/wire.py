@@ -16,7 +16,7 @@ boundaries without pickling exceptions.
                "error": {"type": str, "msg": str,
                          "retry_after_s": float | null}}
 
-Fleet-specific typed errors subclass the serving taxonomy so the
+Fleet-specific typed errors subclass the serving error hierarchy so the
 HTTP proxy's status mapping keeps working unchanged:
 
 - ``StaleFencingToken`` (-> EngineShutdown/503): a write carried a

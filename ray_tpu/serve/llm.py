@@ -72,7 +72,8 @@ class LlamaDeployment:
         self.model = model_cls(self.cfg)
         if params is None:
             import jax.numpy as jnp
-            params = self.model.init(
+            # jitted: un-jitted, flax runs every initialiser op by op
+            params = jax.jit(self.model.init)(
                 jax.random.PRNGKey(0),
                 jnp.zeros((1, 8), jnp.int32))
         self.params = params
@@ -279,6 +280,9 @@ class LlamaDeployment:
                                 // opts["page_size"])
                     opts["n_pages"] = opts["max_slots"] * per_seq + 1
                 per = self.tensor_parallel * self.expert_parallel
+                single = not (self.fleet or self.autoscale
+                              or self.disaggregate
+                              or self.num_engine_replicas > 1)
 
                 def _replica_sharding(idx):
                     # One EngineSharding per replica over its own
@@ -286,8 +290,12 @@ class LlamaDeployment:
                     # restart/scale-up for whatever idx the pool
                     # hands us — the group assignment is pure
                     # arithmetic, so a rebuilt replica idx lands on
-                    # the same devices its predecessor used.
-                    if per == 1:
+                    # the same devices its predecessor used. A
+                    # one-device replica still gets its (degenerate)
+                    # mesh: without one, every replica's params and
+                    # KV pool would pile onto the default device.
+                    # Only the lone unsharded engine stays there.
+                    if per == 1 and single:
                         return None
                     from ray_tpu.serve.sharding import (
                         EngineSharding, replica_device_groups)

@@ -57,6 +57,22 @@ KV_POOL_SPEC = P("tensor", None, None, None)
 KV_SCALE_SPEC = P("tensor", None, None)
 
 
+def constrain_kv_pool(mesh: Mesh, pages):
+    """Inside-jit sharding constraint pinning a KV pool pytree to
+    the head-sharded layout on ``mesh``. Uses concrete
+    NamedShardings, so it binds without a mesh context manager;
+    applied to every jitted step's output pool it guarantees GSPMD
+    can never reshard the pool (which would both break donation
+    aliasing and introduce the KV collectives this layer exists to
+    avoid). Rank-dispatches so int8 scale tensors (rank 3) pin to
+    their own spec alongside the rank-4 pages."""
+    return jax.tree_util.tree_map(
+        lambda t: jax.lax.with_sharding_constraint(
+            t, NamedSharding(
+                mesh, KV_SCALE_SPEC if t.ndim == 3 else KV_POOL_SPEC)),
+        pages)
+
+
 class ShardingConfigError(ValueError):
     """Engine sharding that cannot work: a model dimension that does
     not divide over the requested mesh, a device count that does not
@@ -181,17 +197,7 @@ class EngineSharding:
         return jax.device_put(x, self.replicated)
 
     def constrain_kv(self, pages):
-        """Inside-jit sharding constraint pinning a KV pool pytree to
-        the head-sharded layout. Uses the concrete NamedSharding, so
-        it binds without a mesh context manager; applied to every
-        jitted step's output pool it guarantees GSPMD can never
-        reshard the pool (which would both break donation aliasing
-        and introduce the KV collectives this layer exists to
-        avoid). Rank-dispatches so int8 scale tensors pin to their
-        own spec alongside the pages."""
-        return jax.tree_util.tree_map(
-            lambda t: jax.lax.with_sharding_constraint(
-                t, self._kv_sharding_for(t)), pages)
+        return constrain_kv_pool(self.mesh, pages)
 
     def describe(self) -> dict:
         return {"tp": self.tp, "ep": self.ep,
@@ -204,10 +210,11 @@ def replica_device_groups(n_replicas: int, devices_per_replica: int,
     """Partition the host's devices into per-replica groups for 2-D
     scale-out (replicate across slices x shard within a slice).
 
-    Groups are disjoint while devices last; once exhausted they wrap
-    around (replica i reuses the group at ``i % n_full_groups``) —
-    time-sharing devices is meaningless on real chips but exactly
-    what a forced-multi-device CPU host mesh wants for pool tests.
+    Groups are disjoint while devices last. On the forced-multi-device
+    CPU host the pool tests run on, further replicas wrap around
+    (replica i reuses the group at ``i % n_full_groups``); on real
+    chips time-sharing a device is never what was asked for, so a
+    replica beyond the last full group raises ShardingConfigError.
     """
     if n_replicas <= 0 or devices_per_replica <= 0:
         raise ShardingConfigError(
@@ -221,6 +228,11 @@ def replica_device_groups(n_replicas: int, devices_per_replica: int,
             f"devices_per_replica={devices_per_replica} exceeds the "
             f"{len(devices)} visible devices")
     n_full = len(devices) // devices_per_replica
+    if n_replicas > n_full and devices[0].platform != "cpu":
+        raise ShardingConfigError(
+            f"{n_replicas} replicas x {devices_per_replica} device(s) "
+            f"do not fit the {len(devices)} {devices[0].platform} "
+            f"devices of this host")
     groups = []
     for i in range(n_replicas):
         j = i if i < n_full else i % n_full

@@ -18,6 +18,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.mesh.sharding import ShardingRules, infer_sharding
+from ray_tpu.util.compile_cache import enable_compile_cache
 
 
 @jax.tree_util.register_dataclass
@@ -65,6 +66,7 @@ def make_train_step(loss_fn: Callable[[Any, Any], jax.Array],
                     donate: bool = True):
     """loss_fn(params, batch) -> scalar loss. Returns jitted
     (state, batch) -> (state, metrics)."""
+    enable_compile_cache()
 
     def step_fn(state: TrainState, batch):
         loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)

@@ -7,25 +7,28 @@ device count) and provides a fresh runtime per test.
 """
 import os
 
-# Must be set before jax is imported anywhere.
-_flag = "--xla_force_host_platform_device_count=8"
-if _flag not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " " +
-                               _flag).strip()
+# Must be set before jax is imported anywhere. Worker processes the
+# tests spawn inherit all of it.
+#  - eight virtual CPU devices stand in for a multi-chip host;
+#  - the tests check results, not speed, and on one core the suite's
+#    time is XLA-CPU compile time: skipping LLVM's optimisation passes
+#    takes about a third off it;
+#  - one OpenMP thread per process: xgboost/torch/lightgbm workers
+#    otherwise each spin up a thread per core and fight over them.
+for _flag in ("--xla_force_host_platform_device_count=8",
+              "--xla_backend_optimization_level=0",
+              "--xla_llvm_disable_expensive_passes=true"):
+    if _flag.split("=")[0] not in os.environ.get("XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                                   " " + _flag).strip()
 os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("OMP_NUM_THREADS", "1")
 
 import pytest  # noqa: E402
 
+from ray_tpu.util.compile_cache import enable_compile_cache  # noqa: E402
 
-def _force_cpu():
-    import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
-
-
-_force_cpu()
+enable_compile_cache()
 
 
 @pytest.fixture

@@ -30,8 +30,8 @@ def tiny_model():
     cfg = llama_tiny(dtype=jnp.float32)
     model = Llama(cfg)
     import jax
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
     return model, params
 
 

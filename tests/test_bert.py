@@ -12,18 +12,20 @@ def test_forward_shapes_and_padding_mask():
     model = Bert(cfg)
     rng = np.random.RandomState(0)
     ids = jnp.asarray(rng.randint(1, cfg.vocab_size, (2, 16)))
-    params = model.init(jax.random.PRNGKey(0), ids,
-                    return_mlm_logits=True)
-    h = model.apply(params, ids)
+    # jitted: op-by-op dispatch compiles every primitive on its own
+    params = jax.jit(model.init, static_argnames="return_mlm_logits")(
+        jax.random.PRNGKey(0), ids, return_mlm_logits=True)
+    apply = jax.jit(model.apply, static_argnames="return_mlm_logits")
+    h = apply(params, ids)
     assert h.shape == (2, 16, cfg.dim)
-    logits = model.apply(params, ids, return_mlm_logits=True)
+    logits = apply(params, ids, return_mlm_logits=True)
     assert logits.shape == (2, 16, cfg.vocab_size)
     # padding positions must not influence unpadded outputs
     mask = jnp.asarray([[1] * 16, [1] * 8 + [0] * 8])
-    h_masked = model.apply(params, ids, attention_mask=mask)
+    h_masked = apply(params, ids, attention_mask=mask)
     ids_trunc = ids[1:, :8]
-    h_trunc = model.apply(params, ids_trunc,
-                          attention_mask=jnp.ones((1, 8), jnp.int32))
+    h_trunc = apply(params, ids_trunc,
+                    attention_mask=jnp.ones((1, 8), jnp.int32))
     np.testing.assert_allclose(np.asarray(h_masked[1, :8]),
                                np.asarray(h_trunc[0]), atol=2e-4)
 
@@ -63,8 +65,8 @@ def test_mlm_training_learns_and_shards():
     model = Bert(cfg)
     rng = np.random.RandomState(0)
     init_ids = jnp.asarray(rng.randint(4, cfg.vocab_size, (2, 16)))
-    params = model.init(jax.random.PRNGKey(0), init_ids,
-                    return_mlm_logits=True)
+    params = jax.jit(model.init, static_argnames="return_mlm_logits")(
+        jax.random.PRNGKey(0), init_ids, return_mlm_logits=True)
     # structured data: token at t+1 == token at t (copy pattern), so
     # masked positions are predictable from neighbors
     def batch_ids(n=16):

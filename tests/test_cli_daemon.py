@@ -21,7 +21,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _cli(*args, timeout=120):
     env = dict(os.environ)
-    env.pop("PYTHONPATH", None)
     env.setdefault("JAX_PLATFORMS", "cpu")
     return subprocess.run(
         [sys.executable, "-m", "ray_tpu", *args],

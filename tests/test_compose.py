@@ -123,6 +123,7 @@ def test_composed_training_loss_decreases():
     assert losses[-1] < losses[0] * 0.5, losses[::8]
 
 
+@pytest.mark.slow      # 13 s: a two-process gang composing dcn x pipeline x sequence
 def test_composed_gang_dcn_pipeline_sequence():
     """VERDICT r5 #5 done bar: JaxTrainer with a mixed
     {dcn, pipeline, data, sequence} mesh spanning a 2-process gang;

@@ -87,3 +87,35 @@ def test_flash_packed_groups_and_padding(H, D):
                       precision="highest") ** 2))(q)
     np.testing.assert_allclose(np.asarray(gf), np.asarray(gx),
                                rtol=5e-3, atol=5e-3)
+
+
+def test_flash_impl_runs_per_shard_under_a_mesh(cpu_mesh_devices):
+    """GSPMD cannot partition a Mosaic kernel (the chip's compiler
+    says so; interpret mode does not), so under an ambient
+    multi-device mesh impl="flash" must go through shard_map: batch
+    over the data axes, heads over tensor."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ray_tpu.mesh import create_mesh
+    from ray_tpu.ops.attention import (multi_head_attention,
+                                       xla_attention)
+    mesh = create_mesh({"data": 2, "tensor": 2},
+                       devices=cpu_mesh_devices[:4])
+    rng = np.random.default_rng(0)
+    q, k, v = (jnp.asarray(rng.standard_normal((4, 128, 4, 64)),
+                           jnp.float32) for _ in range(3))
+    sh = NamedSharding(mesh, P("data", None, "tensor", None))
+
+    def attn(q, k, v):
+        return multi_head_attention(q, k, v, causal=True, impl="flash")
+
+    with jax.set_mesh(mesh):
+        args = [jax.device_put(x, sh) for x in (q, k, v)]
+        assert "shard_map" in str(jax.make_jaxpr(attn)(*args))
+        out = jax.jit(attn)(*args)
+    assert out.sharding.is_equivalent_to(sh, out.ndim)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(xla_attention(q, k, v, precision="highest")),
+        rtol=2e-3, atol=2e-3)
+    # no mesh: a plain kernel call
+    assert "shard_map" not in str(jax.make_jaxpr(attn)(q, k, v))

@@ -145,7 +145,7 @@ def test_wire_envelope_and_typed_errors():
     with pytest.raises(wire.WireError):
         wire.raise_error({"type": "SomethingElse", "msg": "x"})
 
-    # fleet errors subclass the serving taxonomy (proxy status map)
+    # fleet errors subclass the serving error hierarchy (proxy status map)
     assert issubclass(StaleFencingToken, EngineShutdown)
     assert issubclass(UnknownMember, EngineShutdown)
     assert issubclass(AgentFenced, EngineDraining)
@@ -613,6 +613,7 @@ def test_llm_deployment_fleet_knob():
 # ------------------------------------------------------- cross-process
 
 
+@pytest.mark.slow      # 15 s: spawns directory and agent OS processes and kills them
 def test_fleet_mini_campaign_cross_process(tmp_path):
     """2 real OS-process agents + a directory process under the
     seeded fault schedule (fake engines): the run's own gates assert

@@ -279,8 +279,8 @@ def tiny_model():
     from ray_tpu.models.llama import Llama, llama_tiny
     cfg = llama_tiny(dtype=jnp.float32)
     model = Llama(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
     return model, params
 
 

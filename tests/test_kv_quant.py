@@ -340,8 +340,8 @@ def test_kv_dtype_resolution_precedence(monkeypatch):
 def tiny():
     cfg = llama_tiny(dtype=jnp.float32)
     model = Llama(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
     return cfg, model, params
 
 
@@ -477,8 +477,8 @@ def test_tp4_int8_agreement(tiny, cpu_mesh_devices):
     from ray_tpu.serve.sharding import EngineSharding
     cfg = llama_tiny(n_kv_heads=4, dtype=jnp.float32)
     model = Llama(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
     rng = np.random.RandomState(3)
     prompts = [rng.randint(1, cfg.vocab_size - 1, size=12).tolist()
                for _ in range(4)]

@@ -26,8 +26,8 @@ def tiny_model():
     cfg = llama_tiny(dtype=jnp.float32)
     model = Llama(cfg)
     import jax
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
     return model, params
 
 
@@ -259,8 +259,8 @@ def test_mixtral_through_engine():
     from ray_tpu.models.mixtral import Mixtral, mixtral_tiny
     cfg = mixtral_tiny(dtype=jnp.float32)
     model = Mixtral(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
     prompt = [3, 1, 4, 1, 5]
     want = _reference_completion(model, params, prompt, 8)
     eng = LLMEngine(model, params, max_slots=2, page_size=8,

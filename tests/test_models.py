@@ -52,10 +52,10 @@ def test_gpt2_loss_decreases_one_step(tiny_gpt):
     def loss_fn(p):
         return cross_entropy_loss(model.apply(p, x), y)
 
-    l0, grads = jax.value_and_grad(loss_fn)(params)
+    l0, grads = jax.jit(jax.value_and_grad(loss_fn))(params)
     params2 = jax.tree_util.tree_map(lambda p, g: p - 0.5 * g, params,
                                      grads)
-    l1 = loss_fn(params2)
+    l1 = jax.jit(loss_fn)(params2)
     assert float(l1) < float(l0)
 
 
@@ -96,13 +96,15 @@ def test_resnet18_forward():
                    small_inputs=True)
     model = ResNet(cfg)
     x = jnp.ones((2, 32, 32, 3))
-    variables = model.init(jax.random.PRNGKey(0), x)
-    logits = model.apply(variables, x)
+    # jitted: op-by-op dispatch compiles every conv/bn on its own
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0), x)
+    logits = jax.jit(model.apply)(variables, x)
     assert logits.shape == (2, 10)
 
     # Train mode updates batch stats.
-    logits, updates = model.apply(
-        variables, x, train=True, mutable=["batch_stats"])
+    logits, updates = jax.jit(
+        lambda v, x: model.apply(v, x, train=True,
+                                 mutable=["batch_stats"]))(variables, x)
     assert logits.shape == (2, 10)
     assert "batch_stats" in updates
 
@@ -117,7 +119,7 @@ def test_llama_forward_shapes(cpu_mesh_devices):
     cfg = llama_tiny()
     model = Llama(cfg)
     ids = jnp.zeros((2, 16), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), ids)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)
     logits, caches = model.apply(params, ids)
     assert logits.shape == (2, 16, cfg.vocab_size)
     assert caches is None
@@ -131,8 +133,8 @@ def test_llama_gqa_param_shapes():
 
     cfg = llama_tiny()
     model = Llama(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 4), jnp.int32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 4), jnp.int32))
     wk = params["params"]["layers_0"]["attention"]["wk"]["kernel"]
     wq = params["params"]["layers_0"]["attention"]["wq"]["kernel"]
     # GQA: kv projection is n_kv_heads/n_heads the size of q.
@@ -152,17 +154,20 @@ def test_llama_kv_cache_decode_matches_full_forward():
     model = Llama(cfg)
     ids = jax.random.randint(jax.random.PRNGKey(1), (1, 12), 0,
                              cfg.vocab_size)
-    params = model.init(jax.random.PRNGKey(0), ids)
-    full_logits, _ = model.apply(params, ids)
+    # jitted: op-by-op dispatch compiles every primitive on its own,
+    # and a traced cache_len makes the six decode steps one program
+    apply = jax.jit(model.apply)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)
+    full_logits, _ = apply(params, ids)
 
     caches = init_kv_caches(cfg, 1, 12)
     # Prefill 6 tokens, then decode 6 single tokens.
-    logits, caches = model.apply(params, ids[:, :6], kv_caches=caches,
-                                 cache_len=0)
+    logits, caches = apply(params, ids[:, :6], kv_caches=caches,
+                           cache_len=0)
     step_logits = [logits]
     for t in range(6, 12):
-        lg, caches = model.apply(params, ids[:, t:t + 1],
-                                 kv_caches=caches, cache_len=t)
+        lg, caches = apply(params, ids[:, t:t + 1],
+                           kv_caches=caches, cache_len=t)
         step_logits.append(lg)
     stitched = jnp.concatenate(step_logits, axis=1)
     np.testing.assert_allclose(np.asarray(stitched),
@@ -178,7 +183,7 @@ def test_llama_generate_greedy_deterministic():
     cfg = llama_tiny()
     model = Llama(cfg)
     prompt = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), prompt)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), prompt)
     out1 = generate(model, params, prompt, max_new_tokens=8)
     out2 = generate(model, params, prompt, max_new_tokens=8)
     assert out1.shape == (1, 12)
@@ -196,7 +201,7 @@ def test_llama_sharded_on_mesh(cpu_mesh_devices):
     cfg = llama_tiny()
     model = Llama(cfg)
     ids = jnp.zeros((4, 16), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), ids)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)
     mesh = Mesh(np.array(cpu_mesh_devices).reshape(2, 2, 2),
                 ("data", "fsdp", "tensor"))
     from ray_tpu.mesh import shard_params
@@ -229,18 +234,20 @@ def test_fused_linear_cross_entropy_matches_naive():
     ids = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0,
                              cfg.vocab_size)
     x, y = ids[:, :-1], ids[:, 1:]
-    params = model.init(jax.random.PRNGKey(0), x)
-    naive = float(cross_entropy_loss(model.apply(params, x), y))
-    feats = model.apply(params, x, return_features=True)
-    fused = float(fused_linear_cross_entropy(
-        feats, params["params"]["wte"], y, chunk=8))
-    np.testing.assert_allclose(naive, fused, rtol=1e-2)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x)
 
-    g1 = jax.grad(lambda p: cross_entropy_loss(
-        model.apply(p, x), y))(params)
-    g2 = jax.grad(lambda p: fused_linear_cross_entropy(
-        model.apply(p, x, return_features=True),
-        p["params"]["wte"], y, chunk=8))(params)
+    def naive_loss(p):
+        return cross_entropy_loss(model.apply(p, x), y)
+
+    def fused_loss(p):
+        return fused_linear_cross_entropy(
+            model.apply(p, x, return_features=True),
+            p["params"]["wte"], y, chunk=8)
+
+    # jitted: op-by-op dispatch compiles every primitive on its own
+    naive, g1 = jax.jit(jax.value_and_grad(naive_loss))(params)
+    fused, g2 = jax.jit(jax.value_and_grad(fused_loss))(params)
+    np.testing.assert_allclose(float(naive), float(fused), rtol=1e-2)
     n1 = float(jnp.sqrt(sum(jnp.sum(a * a)
                             for a in jax.tree_util.tree_leaves(g1))))
     n2 = float(jnp.sqrt(sum(jnp.sum(a * a)
@@ -259,7 +266,7 @@ def test_llama_generate_eos_zero_not_instant_stop():
     cfg = llama_tiny()
     model = Llama(cfg)
     prompt = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), prompt)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), prompt)
     ref = generate(model, params, prompt, max_new_tokens=8)
     out = generate(model, params, prompt, max_new_tokens=8, eos_id=0)
     # Greedy decode with eos_id=0 matches the no-eos decode until a real
@@ -282,7 +289,8 @@ def test_llama_generate_stream_matches_generate():
                                       llama_tiny)
     cfg = llama_tiny()
     m = Llama(cfg)
-    p = m.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    p = jax.jit(m.init)(jax.random.PRNGKey(0),
+                        jnp.zeros((2, 8), jnp.int32))
     prompt = jnp.asarray(
         np.random.RandomState(3).randint(1, 200, (2, 16)), jnp.int32)
     full = np.asarray(generate(m, p, prompt, max_new_tokens=21))
@@ -301,7 +309,8 @@ def test_llama_generate_stream_eos_stops():
                                       llama_tiny)
     cfg = llama_tiny()
     m = Llama(cfg)
-    p = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    p = jax.jit(m.init)(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))
     prompt = jnp.asarray(
         np.random.RandomState(5).randint(1, 200, (1, 16)), jnp.int32)
     full = np.asarray(generate(m, p, prompt, max_new_tokens=24))
@@ -324,7 +333,7 @@ def test_mixtral_forward_and_shared_decode_paths():
     m = Mixtral(cfg)
     ids = jnp.asarray(
         np.random.RandomState(0).randint(1, 200, (2, 16)), jnp.int32)
-    vs = m.init(jax.random.PRNGKey(0), ids)
+    vs = jax.jit(m.init)(jax.random.PRNGKey(0), ids)
     logits, _ = m.apply(vs, ids)
     assert logits.shape == (2, 16, cfg.vocab_size)
     _, aux = m.apply(vs, ids, mutable=["losses"])
@@ -388,14 +397,14 @@ def test_vit_forward_and_learning():
     rng = np.random.RandomState(0)
     imgs = jnp.asarray(rng.rand(8, 32, 32, 3), jnp.float32)
     labels = jnp.asarray(rng.randint(0, cfg.num_classes, 8))
-    params = model.init(jax.random.PRNGKey(0), imgs)
-    logits = model.apply(params, imgs)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), imgs)
+    logits = jax.jit(model.apply)(params, imgs)
     assert logits.shape == (8, cfg.num_classes)
     assert logits.dtype == jnp.float32
     # mean pooling variant runs too
-    cfg_m = vit_tiny(pool="mean")
-    lm = ViT(cfg_m).apply(ViT(cfg_m).init(jax.random.PRNGKey(0), imgs),
-                          imgs)
+    vit_m = ViT(vit_tiny(pool="mean"))
+    lm = jax.jit(vit_m.apply)(
+        jax.jit(vit_m.init)(jax.random.PRNGKey(0), imgs), imgs)
     assert lm.shape == (8, cfg.num_classes)
 
     opt = optax.adam(1e-2)

@@ -117,19 +117,27 @@ def test_llama_decode_paths_agree(monkeypatch):
     page_table = jnp.array([[1, 2, 3, 4], [5, 6, 7, 8]],
                            dtype=jnp.int32)
     tok = jax.random.randint(rng, (B, 1), 0, cfg.vocab_size)
-    params = model.init(rng, tok)
+    params = jax.jit(model.init)(rng, tok)
     pos = jnp.array([0, 13], dtype=jnp.int32)
 
     def step(force):
         monkeypatch.setenv("RAY_TPU_PAGED_KERNEL", force)
-        kv = [PagedKVLayer(pk, pv, page_table) for pk, pv in pages]
-        out, _ = model.apply(params, tok, kv_caches=kv,
-                             cache_len=pos)
-        return np.asarray(out, dtype=np.float32)
 
-    with jax.disable_jit():
-        a = step("1")
-        b = step("0")
+        def fwd(params, pages):
+            # a fresh function per call: the knob is read at trace
+            # time, so each call traces its own branch
+            kv = [PagedKVLayer(pk, pv, page_table) for pk, pv in pages]
+            out, _ = model.apply(params, tok, kv_caches=kv,
+                                 cache_len=pos)
+            return out
+
+        jaxpr = str(jax.make_jaxpr(fwd)(params, pages))
+        assert ("pallas_call" in jaxpr) == (force == "1")
+        return np.asarray(jax.jit(fwd)(params, pages),
+                          dtype=np.float32)
+
+    a = step("1")
+    b = step("0")
     np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
 

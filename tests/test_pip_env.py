@@ -60,6 +60,7 @@ def test_stage_and_cache(tmp_path):
     assert out.stdout.strip() == "np"
 
 
+@pytest.mark.slow      # 16 s: builds a virtualenv and boots a worker in it
 def test_pip_task_runs_in_dedicated_venv_worker(tmp_path):
     """A task with a pip env runs on an env-keyed worker that
     re-exec'd into the venv interpreter and can import the package

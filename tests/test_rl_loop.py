@@ -32,8 +32,8 @@ DELAY_S = 0.2
 def tiny_model():
     cfg = llama_tiny(dtype=jnp.float32)
     model = Llama(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, PROMPT_LEN), jnp.int32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, PROMPT_LEN), jnp.int32))
     return model, params
 
 

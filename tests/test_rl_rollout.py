@@ -26,8 +26,8 @@ from ray_tpu.serve.engine import LLMEngine
 def tiny_model():
     cfg = llama_tiny(dtype=jnp.float32)
     model = Llama(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
     return model, params
 
 

@@ -14,27 +14,30 @@ def test_forward_shapes_and_causality():
     rng = np.random.RandomState(0)
     enc = jnp.asarray(rng.randint(2, cfg.vocab_size, (2, 10)))
     dec = jnp.asarray(rng.randint(2, cfg.vocab_size, (2, 7)))
-    params = model.init(jax.random.PRNGKey(0), enc, dec)
-    logits = model.apply(params, enc, dec)
+    # jitted: op-by-op dispatch compiles every primitive on its own
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), enc, dec)
+    apply = jax.jit(model.apply)
+    logits = apply(params, enc, dec)
     assert logits.shape == (2, 7, cfg.vocab_size)
     # decoder causality: changing a LATER target token must not
     # change earlier positions' logits
     dec2 = dec.at[:, 5].set((dec[:, 5] + 1) % cfg.vocab_size)
-    l2 = model.apply(params, enc, dec2)
+    l2 = apply(params, enc, dec2)
     np.testing.assert_allclose(np.asarray(logits[:, :5]),
                                np.asarray(l2[:, :5]), atol=1e-5)
     assert not np.allclose(np.asarray(logits[:, 5:]),
                            np.asarray(l2[:, 5:]))
     # encoder padding mask: padded source positions don't leak
     mask = jnp.asarray([[1] * 10, [1] * 6 + [0] * 4])
-    lm = model.apply(params, enc, dec, enc_mask=mask)
+    lm = apply(params, enc, dec, enc_mask=mask)
     enc_trunc = enc[1:, :6]
-    lt = model.apply(params, enc_trunc, dec[1:],
-                     enc_mask=jnp.ones((1, 6), jnp.int32))
+    lt = apply(params, enc_trunc, dec[1:],
+               enc_mask=jnp.ones((1, 6), jnp.int32))
     np.testing.assert_allclose(np.asarray(lm[1]), np.asarray(lt[0]),
                                atol=2e-4)
 
 
+@pytest.mark.slow      # 17 s: trains to convergence on the 8-device mesh
 def test_copy_task_trains_and_decodes():
     """Seq2seq training under the SHARDED spmd step on the 8-device
     mesh: the model fits a fixed batch of copy examples (pure T5 has
@@ -65,8 +68,9 @@ def test_copy_task_trains_and_decodes():
                 "tgt": src.astype(np.int32)}
 
     b0 = make_batch(2)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.asarray(b0["enc"]), jnp.asarray(b0["dec"]))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.asarray(b0["enc"]),
+        jnp.asarray(b0["dec"]))
     optimizer = optax.adam(1e-2)
     state = shard_state(TrainState.create(params, optimizer),
                         t5_sharding_rules(), mesh)
@@ -80,7 +84,8 @@ def test_copy_task_trains_and_decodes():
     losses = []
     with jax.set_mesh(mesh):
         batch = put_batch(fixed, mesh)
-        for _ in range(250):
+        # loss crosses 0.3 near step 60 and sits at ~0.02 by 120
+        for _ in range(120):
             state, m = step(state, batch)
             losses.append(float(m["loss"]))
     assert losses[-1] < 0.3, (losses[0], losses[-1])

@@ -34,8 +34,8 @@ def tiny():
     # n_kv_heads=4 so heads divide tp=4 (llama_tiny defaults to 2)
     cfg = llama_tiny(n_kv_heads=4, dtype=jnp.float32)
     model = Llama(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
     return cfg, model, params
 
 
@@ -178,8 +178,8 @@ def test_mixtral_expert_parallel_parity(cpu_mesh_devices):
     from ray_tpu.models.mixtral import Mixtral, mixtral_tiny
     cfg = mixtral_tiny(dtype=jnp.float32)
     model = Mixtral(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
     sh = EngineSharding.build(cfg, tp=2, ep=2,
                               devices=cpu_mesh_devices[:4])
     prompts = [np.random.RandomState(3).randint(
@@ -293,8 +293,8 @@ def test_match_partition_rules_covers_mixtral():
     from ray_tpu.models.mixtral import (Mixtral, mixtral_tiny,
                                         mixtral_sharding_rules)
     cfg = mixtral_tiny()
-    params = Mixtral(cfg).init(jax.random.PRNGKey(0),
-                               jnp.zeros((1, 8), jnp.int32))
+    params = jax.jit(Mixtral(cfg).init)(jax.random.PRNGKey(0),
+                                        jnp.zeros((1, 8), jnp.int32))
     match_partition_rules(mixtral_sharding_rules(fsdp=False), params)
 
 

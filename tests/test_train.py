@@ -127,6 +127,7 @@ def test_jax_trainer_spmd_gang(rt, cpu_mesh_devices):
 
 # ---- widened surface: torch backend, predictors, estimator trainers -------
 
+@pytest.mark.slow      # 12 s: a two-process torch DDP gloo gang
 def test_torch_trainer_ddp_gloo():
     """TorchTrainer on a multiprocess cluster: gloo process group spans
     gang members in distinct worker processes; gradients allreduce."""
@@ -448,6 +449,7 @@ def test_jax_trainer_multihost_dcn_mesh():
         assert m["last_loss"] < m["first_loss"] * 0.1
 
 
+@pytest.mark.slow      # 14 s: kills and regrows a two-process training gang
 def test_jax_trainer_gang_elastic_restart():
     """Gang elastic restart re-bootstraps jax.distributed cleanly: each
     attempt gets FRESH dedicated worker processes (a process can join
@@ -514,6 +516,7 @@ def test_torch_helpers_and_checkpoint_roundtrip():
     assert ckpt.to_dict()["epoch"] == 3
 
 
+@pytest.mark.slow      # 33 s: a two-process torch/transformers training gang
 def test_huggingface_trainer_distributed():
     """HuggingFaceTrainer: each gang member builds a transformers
     Trainer; accelerate adopts the gloo group, gradients sync, rank 0

@@ -9,6 +9,7 @@ probe rollback, resume-after-controller-death, rebuild re-stamping).
 """
 import os
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -29,8 +30,8 @@ def tiny_model():
     # across a same-tensor weight swap (the parity proofs below)
     cfg = llama_tiny(dtype=jnp.float32)
     model = Llama(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
     return model, params
 
 
@@ -39,6 +40,16 @@ def _reference_completion(model, params, prompt, n):
     out = generate(model, params, jnp.asarray([prompt], jnp.int32),
                    max_new_tokens=n, temperature=0.0)
     return np.asarray(out)[0, len(prompt):].tolist()
+
+
+def _slow_rounds(sleep_s=0.05):
+    """Hold every scheduling round open (the chaos harness's delay
+    hook): a tiny model otherwise finishes a whole request before a
+    swap or shutdown issued "mid-request" can land."""
+    from ray_tpu.serve.faults import FaultInjector
+    inj = FaultInjector()
+    inj.slow("step", sleep_s, times=10 ** 9)
+    return inj
 
 
 def _engine(model, params, **kw):
@@ -64,15 +75,15 @@ def test_preempt_swap_is_token_identical_and_fenced(tiny_model):
     new id, so token identity is provable); the fence advances; the
     prefix cache is invalidated."""
     model, params = tiny_model
-    eng = _engine(model, params)
+    eng = _engine(model, params, fault_injector=_slow_rounds())
     try:
         prompt = [3, 1, 4, 1, 5, 9, 2, 6]
-        want = _reference_completion(model, params, prompt, 12)
+        want = _reference_completion(model, params, prompt, 24)
         # warm the prefix cache so invalidation is observable
         assert eng.submit(list(prompt), max_new_tokens=4).result() \
             == want[:4]
         assert eng.prefix_cache.cached_pages > 0
-        h = eng.submit(list(prompt), max_new_tokens=12)
+        h = eng.submit(list(prompt), max_new_tokens=24)
         # consume two tokens so the request provably OCCUPIES a slot
         # when the flip lands — the swap preempts it mid-decode
         it = h.stream()
@@ -164,7 +175,7 @@ def test_shutdown_releases_pending_drain_swap(tiny_model):
     waiter typed, not hang it."""
     from ray_tpu.serve.errors import EngineShutdown
     model, params = tiny_model
-    eng = _engine(model, params)
+    eng = _engine(model, params, fault_injector=_slow_rounds())
     prompt = [7, 7, 7, 7]
     eng.submit(list(prompt), max_new_tokens=64, deadline_s=30)
     err = {}
@@ -178,6 +189,12 @@ def test_shutdown_releases_pending_drain_swap(tiny_model):
 
     t = threading.Thread(target=swapper, daemon=True)
     t.start()
+    # the swap must be PENDING behind the still-decoding request when
+    # the engine stops (16 rounds of >= 50 ms keep it decoding)
+    deadline = time.monotonic() + 10
+    while eng._pending_swap is None and time.monotonic() < deadline:
+        time.sleep(0.002)
+    assert eng._pending_swap is not None
     eng.shutdown()
     t.join(30)
     assert isinstance(err.get("e"), EngineShutdown)

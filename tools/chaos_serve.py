@@ -827,8 +827,11 @@ def _run_rollout_phases(model, params, flight_dir, seed, kv_dtype):
         tick(4)
         eng0 = pool.replica(0).engine
         # pace the canary's rounds and pin a slot so the drain-mode
-        # flip PENDS instead of applying at the next idle boundary
-        eng0._injector.slow("step", 0.03, times=2000)
+        # flip PENDS instead of applying at the next idle boundary.
+        # The busy request must still be decoding when the kill
+        # lands: 48 tokens in 2-token rounds at 0.15 s a round hold
+        # it open ~3.6 s (0.03 s lost the race on a loaded host).
+        eng0._injector.slow("step", 0.15, times=2000)
         busy_box = {}
 
         def consume_busy():
@@ -1616,7 +1619,8 @@ def run_fleet_chaos(seed=47, agents=3, duration_s=4.0, clients=3,
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    from ray_tpu.serve.fleet.provider import require_cpu_backend
+    require_cpu_backend(env)
 
     # ground truth: one correct completion per prompt
     shared = [3, 1, 4, 1, 5, 9, 2, 6]

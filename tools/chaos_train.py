@@ -322,9 +322,6 @@ def main():
     ap.add_argument("--deadline", type=float, default=0.6)
     ap.add_argument("--out", default="")
     args = ap.parse_args()
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import ray_tpu
     ray_tpu.init()
     artifact = run_chaos(

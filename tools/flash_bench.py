@@ -57,8 +57,6 @@ def bench_one(impl: str, B: int, H: int, T: int, D: int,
 
 def main():
     import jax
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     dev = jax.devices()[0]
     on_tpu = dev.platform == "tpu"
     B, H, D = 4, 8, 64

@@ -154,9 +154,6 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="")
     args = ap.parse_args()
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import ray_tpu
     ray_tpu.init()
     legacy = run_mode(use_engine=False)
