@@ -47,6 +47,7 @@ def test_mask_tokens_contract():
     assert (masked[~picked] == ids[~picked]).all()
 
 
+@pytest.mark.slow      # 23 s: trains 100 sharded steps to a loss threshold
 def test_mlm_training_learns_and_shards():
     """MLM loss decreases on a learnable toy stream, with params
     sharded by bert_sharding_rules on the 8-device mesh (the spmd
@@ -108,9 +109,11 @@ def test_pooled_output():
     cfg = bert_tiny()
     model = Bert(cfg)
     ids = jnp.ones((2, 8), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), ids,
-                        return_pooled=True)
-    hidden, pooled = model.apply(params, ids, return_pooled=True)
+    params = jax.jit(model.init, static_argnames="return_pooled")(
+        jax.random.PRNGKey(0), ids, return_pooled=True)
+    hidden, pooled = jax.jit(
+        model.apply, static_argnames="return_pooled")(
+            params, ids, return_pooled=True)
     assert hidden.shape == (2, 8, cfg.dim)
     assert pooled.shape == (2, cfg.dim)
     assert float(abs(pooled).max()) <= 1.0      # tanh-bounded

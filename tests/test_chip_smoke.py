@@ -44,6 +44,9 @@ def test_training_phase_fails_when_kernel_expected():
                                   steps=1, expect_flash=True)
 
 
+# 17 s: six engines and two train steps. The placement it depends on
+# stays in tier 1 (test_pool_replicas_land_on_distinct_devices).
+@pytest.mark.slow
 def test_multichip_phase_rehearsal():
     out = chip_smoke.multichip_phase(
         llama_tiny(dtype=jnp.float32, n_kv_heads=4), gpt2_tiny(),

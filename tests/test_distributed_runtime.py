@@ -464,7 +464,7 @@ def test_cancel_queued_and_force_running():
             return "done"
 
         # occupy BOTH CPUs, then queue a third task and cancel it
-        running = [sleeper.remote(30), sleeper.remote(6)]
+        running = [sleeper.remote(30), sleeper.remote(4)]
         time.sleep(0.5)
         queued = sleeper.remote(0)
         time.sleep(0.3)

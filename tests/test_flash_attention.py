@@ -45,8 +45,9 @@ def test_flash_gradients_match(causal):
             xla_attention(q, k, v, causal=causal,
                           precision="highest") ** 2)
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gx = jax.grad(loss_xla, argnums=(0, 1, 2))(q, k, v)
+    # jitted: eager grad dispatches the interpreted kernel op by op
+    gf = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    gx = jax.jit(jax.grad(loss_xla, argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(gf, gx, "qkv"):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=5e-3, atol=5e-3,
@@ -80,11 +81,11 @@ def test_flash_packed_groups_and_padding(H, D):
     np.testing.assert_allclose(np.asarray(expected), np.asarray(out),
                                rtol=2e-3, atol=2e-3)
     # Gradients flow through the pad/slice wrapper correctly.
-    gf = jax.grad(lambda q: jnp.sum(
-        flash_attention(q, k, v, causal=True) ** 2))(q)
-    gx = jax.grad(lambda q: jnp.sum(
+    gf = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, causal=True) ** 2)))(q, k, v)
+    gx = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
         xla_attention(q, k, v, causal=True,
-                      precision="highest") ** 2))(q)
+                      precision="highest") ** 2)))(q, k, v)
     np.testing.assert_allclose(np.asarray(gf), np.asarray(gx),
                                rtol=5e-3, atol=5e-3)
 

@@ -41,6 +41,10 @@ from ray_tpu.util.envknobs import (EnvKnobError, parse_kv_dtype_env,
 
 KH, PG, D = 2, 8, 16
 
+# jitted: eager, every scatter/gather of the int8 append compiles and
+# dispatches on its own (shape errors still raise, at trace time)
+paged_append = jax.jit(paged_append)
+
 
 def _fresh(n_pages=8, B=1, max_pages=4):
     pk = jnp.zeros((KH, n_pages, PG, D), jnp.int8)

@@ -334,9 +334,10 @@ def test_mixtral_forward_and_shared_decode_paths():
     ids = jnp.asarray(
         np.random.RandomState(0).randint(1, 200, (2, 16)), jnp.int32)
     vs = jax.jit(m.init)(jax.random.PRNGKey(0), ids)
-    logits, _ = m.apply(vs, ids)
+    logits, _ = jax.jit(m.apply)(vs, ids)
     assert logits.shape == (2, 16, cfg.vocab_size)
-    _, aux = m.apply(vs, ids, mutable=["losses"])
+    _, aux = jax.jit(
+        lambda vs, ids: m.apply(vs, ids, mutable=["losses"]))(vs, ids)
     lb = float(moe_aux_loss(aux))
     assert 0.5 < lb < 4.0      # ~1.0 at balance, E at collapse
     full = np.asarray(generate(m, vs, ids, max_new_tokens=9))

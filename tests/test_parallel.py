@@ -108,8 +108,9 @@ def test_moe_routes_and_matches_manual(cpu_mesh_devices):
                     use_sharding_constraint=False)
     rng = jax.random.PRNGKey(0)
     x = jax.random.normal(jax.random.PRNGKey(1), (B, T, D))
-    variables = moe.init(rng, x)
-    out, aux = moe.apply(variables, x, mutable=["losses"])
+    variables = jax.jit(moe.init)(rng, x)
+    out, aux = jax.jit(
+        lambda v, x: moe.apply(v, x, mutable=["losses"]))(variables, x)
     assert out.shape == (B, T, D)
 
     # Manual reference: route each token to its argmax expert.
@@ -132,11 +133,11 @@ def test_moe_sharded_execution(cpu_mesh_devices):
     mesh = create_mesh({"expert": 4, "data": 2})
     moe = SwitchMoE(num_experts=4, d_model=8, d_ff=16, dtype=jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(2), (4, 8, 8))
-    variables = moe.init(jax.random.PRNGKey(0), x)
+    variables = jax.jit(moe.init)(jax.random.PRNGKey(0), x)
     with jax.set_mesh(mesh):
         out = jax.jit(lambda v, x: moe.apply(v, x))(variables, x)
     assert out.shape == x.shape
     # Same numbers as unsharded execution.
-    expected = moe.apply(variables, x)
+    expected = jax.jit(moe.apply)(variables, x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=1e-4, atol=1e-5)

@@ -62,10 +62,11 @@ def test_captured_logprobs_match_teacher_forced_dense(tiny_model):
         lps = [list(h.logprobs) for h in handles]
     finally:
         eng.shutdown()
+    apply = jax.jit(model.apply)
     for p, c, lp in zip(prompts, outs, lps):
         assert len(lp) == len(c), \
             "logprobs must be index-aligned with the completion"
-        logits, _ = model.apply(params, jnp.asarray([p + c], jnp.int32))
+        logits, _ = apply(params, jnp.asarray([p + c], jnp.int32))
         ref = jax.nn.log_softmax(
             np.asarray(logits, np.float32)[0] / temp, axis=-1)
         for j, tok in enumerate(c):

@@ -30,6 +30,7 @@ def test_ppo_single_iteration_metrics(rt):
         algo.stop()
 
 
+@pytest.mark.slow      # 10 s: trains to a reward threshold
 def test_ppo_learns_sign_env(rt):
     algo = PPOConfig(env="Sign", num_rollout_workers=2,
                      rollout_fragment_length=256,
