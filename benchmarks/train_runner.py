@@ -1,0 +1,144 @@
+"""The one runner for every configuration of ``kind: train``: the SPMD
+train step exactly as bench.py and examples/02_train_gpt2.py build it
+(shard_state / put_batch / make_train_step on a {"data": -1} mesh), fed
+a fresh seeded batch through put_batch every step.
+"""
+from __future__ import annotations
+
+import time
+import types
+from typing import Any, Dict
+
+from benchmarks import parity, trafficgen, weights
+from benchmarks.common import Timer, Tracer, cache_report, log
+
+TRACE_SECONDS = 3.0
+
+
+def gpt2_config(cfg: Dict[str, Any]):
+    from ray_tpu.models import gpt2_124m
+    if cfg["n_positions"] != cfg["n_ctx"]:
+        raise SystemExit("benchmarks: n_positions != n_ctx")
+    if cfg["activation_function"] != "gelu_new":
+        raise SystemExit("benchmarks: the program's MLP is gelu_new")
+    return gpt2_124m(vocab_size=cfg["vocab_size"], n_ctx=cfg["n_ctx"],
+                     n_embd=cfg["n_embd"], n_layer=cfg["n_layer"],
+                     n_head=cfg["n_head"])
+
+
+def reference_weights(params, n_layer: int) -> Dict[str, Any]:
+    """The program's flax tree under the plain reference's names."""
+    p = params["params"]
+    layers = []
+    for i in range(n_layer):
+        h = p[f"h_{i}"]
+        layers.append({"ln_1": h["ln_1"], "ln_2": h["ln_2"],
+                       "c_attn": h["attn"]["c_attn"],
+                       "attn_proj": h["attn"]["c_proj"],
+                       "c_fc": h["mlp"]["c_fc"],
+                       "mlp_proj": h["mlp"]["c_proj"]})
+    return {"wte": p["wte"], "wpe": p["wpe"], "ln_f": p["ln_f"],
+            "layers": layers}
+
+
+def run(ctx) -> types.SimpleNamespace:
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from ray_tpu.mesh import create_mesh
+    from ray_tpu.models import GPT2, gpt2_sharding_rules
+    from ray_tpu.models.gpt2 import linear_cross_entropy
+    from ray_tpu.train.spmd import (TrainState, make_train_step,
+                                    put_batch, shard_state)
+    from ray_tpu.util.compile_cache import enable_compile_cache
+
+    cfg, tr, args, meter = ctx.cfg, ctx.traffic, ctx.args, ctx.meter
+    cache_dir = enable_compile_cache()
+    log(f"[cache] {cache_dir} before: {cache_report(cache_dir)}")
+    gcfg = gpt2_config(cfg)
+    model = GPT2(gcfg)
+    batch, seq = int(tr["batch"]), int(tr["seq"])
+    devices = jax.devices()[:ctx.chips]
+    mesh = create_mesh({"data": -1}, devices=devices)
+
+    def loss_fn(params, b):
+        x, y = b["ids"][:, :-1], b["ids"][:, 1:]
+        feats = model.apply(params, x, return_features=True)
+        return linear_cross_entropy(feats, params["params"]["wte"], y)
+
+    with Timer("weights from the seed, one call", meter):
+        with jax.default_device(devices[0]):
+            params = weights.gpt2_params(model, args.seed)
+    batches = trafficgen.ZipfBatches(tr, args.seed, cfg["vocab_size"])
+
+    # step 0 of the reference, on the weights and the batch of step 0,
+    # before the train state (which donates them) exists
+    with Timer("reference: loss and gradient norm of step 0", meter):
+        from benchmarks.reference import gpt2 as ref
+        ref_loss, ref_gnorm = ref.loss_and_grad_norm(
+            reference_weights(params, gcfg.n_layer),
+            jnp.asarray(batches(0)), n_head=gcfg.n_head,
+            eps=float(cfg["layer_norm_epsilon"]))
+
+    tcfg = cfg["train"]
+    optimizer = optax.adamw(tcfg["lr"], weight_decay=tcfg["weight_decay"])
+    state = shard_state(TrainState.create(params, optimizer),
+                        gpt2_sharding_rules(fsdp=False), mesh)
+    train_step = make_train_step(loss_fn, optimizer)
+    in_flight = int(tr.get("in_flight", 2))
+    losses, gnorms = [], []
+
+    with jax.set_mesh(mesh):
+        with Timer("warm-up: the train step (step 0)", meter):
+            b = put_batch({"ids": batches(0)}, mesh)
+            state, m = train_step(state, b)
+            loss0, gnorm0 = float(m["loss"]), float(m["grad_norm"])
+
+        tracer = None
+        t_open = time.monotonic()
+        t_close = t_open + float(args.seconds)
+        if args.trace:
+            tracer = Tracer(ctx.trace_dir, t_open, args.seconds,
+                            TRACE_SECONDS)
+            tracer.start()
+        window_meter = meter.snapshot()
+        step = 1
+        pending = []
+        while time.monotonic() < t_close:
+            b = put_batch({"ids": batches(step)}, mesh)
+            state, m = train_step(state, b)
+            pending.append(m)
+            losses.append(m["loss"])
+            gnorms.append(m["grad_norm"])
+            step += 1
+            if len(pending) > in_flight:
+                jax.block_until_ready(pending.pop(0)["loss"])
+        jax.block_until_ready(state)
+        t_end = time.monotonic()
+        in_window = meter.since(window_meter)
+    if tracer is not None:
+        tracer.join(timeout=120)
+    losses = [float(x) for x in losses]
+    steps = len(losses)
+    tokens = steps * batch * seq
+    elapsed = t_end - t_open
+    check = parity.train_rule(loss0, gnorm0, ref_loss, ref_gnorm,
+                              [loss0] + losses)
+    log(f"[correct] {check}")
+    log(f"[window] {steps} steps of {batch} x {seq} in {elapsed:.3f} s "
+        f"({1e3 * elapsed / max(steps, 1):.2f} ms a step); programs "
+        f"built or loaded inside the window: "
+        f"{in_window['programs']:.0f} (must be 0)")
+    e2e = {"train_tokens_per_s": tokens / elapsed / ctx.chips,
+           "setup_s": t_open - ctx.t_process}
+    log(f"[cache] after: {cache_report(cache_dir, top=6)}")
+    return types.SimpleNamespace(
+        kind="train", cfg=cfg, traffic=tr, chips=ctx.chips,
+        peaks=ctx.peaks, seconds=float(args.seconds),
+        window=(t_open, t_end), e2e=e2e, attempted=steps, failed=0,
+        correct=bool(check["ok"]),
+        compiles_in_window=int(in_window["programs"]),
+        steps=steps, batch=batch, seq=seq, losses=losses,
+        trace_span=tracer.span if tracer else None,
+        trace=None, shutdown=lambda: None)
