@@ -282,6 +282,22 @@ class Tracer(threading.Thread):
         return (self.t0, self.t1)
 
 
+def prepare_trace(trace_dir: str, start, stop) -> None:
+    """--trace 2, once the window's numbers are taken: start and stop
+    the profiler once through the program's control (``start(dir)``,
+    ``stop()``) and throw that trace away, so that the cost of the
+    first start falls into no number; then leave ``trace_dir`` empty
+    for the trace that counts."""
+    import shutil
+    scrap = trace_dir + ".first"
+    shutil.rmtree(scrap, ignore_errors=True)
+    start(scrap)
+    stop()
+    shutil.rmtree(scrap, ignore_errors=True)
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    os.makedirs(trace_dir, exist_ok=True)
+
+
 def emit_result(result: Dict[str, Any]) -> None:
     """The contract's one JSON object, last on standard output."""
     sys.stdout.flush()

@@ -1,12 +1,16 @@
 """python3 -m benchmarks.run --workload <name> --seed <n> --seconds <s>
---trace <0|1>
+--trace <0|1|2>
 
 One process: loads the cell's configuration and traffic files, makes the
 weights from the seed, warms only that cell's shapes, measures for
 ``--seconds``, and prints the contract's JSON object as the last line of
 standard output. ``--trace 0`` reports the cell's end-to-end metrics,
 ``--trace 1`` its per-layer metrics (a profiler trace is taken over a
-few seconds of the window). Without a TPU, with fewer chips than the
+few seconds of the window). ``--trace 2`` is a ``--trace 0`` run
+followed by a short traced phase: it does what ``--trace 0`` does until
+the window has closed and its numbers are taken, then traces a few
+seconds of the same traffic through the program's own control and
+reports both kinds of metric in one line. Without a TPU, with fewer chips than the
 cell asks, or on a device kind missing from benchmarks/peaks.json, it
 exits non-zero and prints no result. ``--rehearse`` is the only CPU
 path: it runs a toy cell of benchmarks/rehearsal/cells.json, never a
@@ -35,7 +39,7 @@ def parse(argv=None):
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=None)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU, toy cell of rehearsal/cells.json")
     ap.add_argument("--rate", type=float, default=None,
@@ -43,6 +47,13 @@ def parse(argv=None):
     ap.add_argument("--dump-trace", default=None,
                     help="also write the trace's plain form (.json.gz)")
     return ap.parse_args(argv)
+
+
+def end_to_end(bench, cell_name, run, units):
+    wanted = [m["name"] for m in common.metrics_of_cell(
+        bench, "end_to_end", cell_name)]
+    return {n: {"value": run.e2e[n], "unit": units[n]}
+            for n in wanted if n in run.e2e}
 
 
 def main(argv=None) -> int:
@@ -100,7 +111,9 @@ def main(argv=None) -> int:
     meter = common.CompileMeter().start()
     trace_dir = os.path.join(common.ROOT, ".bench_trace",
                              cell["name"].replace("/", "_"))
-    if args.trace:
+    if args.trace == 1:
+        # (--trace 2 touches nothing of the trace before its window has
+        # closed: the runner makes the directory then)
         shutil.rmtree(trace_dir, ignore_errors=True)
         os.makedirs(trace_dir, exist_ok=True)
     ctx = types.SimpleNamespace(
@@ -138,7 +151,6 @@ def main(argv=None) -> int:
                         exist_ok=True)
             trace_reduce.save_json(ir, args.dump_trace)
         run.trace = trace_reduce.reduce(ir, chips)
-        shutil.rmtree(trace_dir, ignore_errors=True)
         if not run.trace or run.trace["busy_s"] <= 0:
             if not args.rehearse:
                 raise SystemExit("benchmarks: the trace shows no "
@@ -148,17 +160,18 @@ def main(argv=None) -> int:
             device["busy_s"] = run.trace["busy_s"]
             device["window_s"] = run.trace["window_s"]
             log(f"[trace] modules {run.trace['modules']}")
-        metrics = {}
+        # --trace 2 reports both kinds side by side; its readers may
+        # open the trace itself (run.trace_dir), so it goes only now
+        metrics = (end_to_end(bench, metrics_as, run, units)
+                   if args.trace == 2 else {})
         for m in common.metrics_of_cell(bench, "per_layer", metrics_as):
             value = common.load_metric_reader(m["name"])(run)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         result["breakdown"] = run.trace["breakdown"]
+        shutil.rmtree(trace_dir, ignore_errors=True)
     else:
-        wanted = [m["name"] for m in common.metrics_of_cell(
-            bench, "end_to_end", metrics_as)]
-        metrics = {n: {"value": run.e2e[n], "unit": units[n]}
-                   for n in wanted if n in run.e2e}
+        metrics = end_to_end(bench, metrics_as, run, units)
     result["metrics"] = metrics
     result["device"] = device
     result["compiles_in_window"] = run.compiles_in_window

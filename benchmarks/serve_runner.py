@@ -8,6 +8,11 @@ Everything that differs between cells comes from the configuration file
 rate or clients, lengths). An open loop's end-to-end metrics are its
 requests' times at the clients; a closed loop's is the tokens a second
 its clients received over a window of whole engine rounds.
+
+``--trace 2`` measures first and traces afterwards: up to the moment
+the window's numbers are taken it does what ``--trace 0`` does, then
+``traced_phase`` traces a few seconds of the same traffic through the
+program's own control (``LlamaDeployment.start_trace``/``stop_trace``).
 """
 from __future__ import annotations
 
@@ -22,7 +27,8 @@ import numpy as np
 
 from benchmarks import parity, trafficgen, weights
 from benchmarks.common import (Timer, Tracer, cache_report, log,
-                               percentile, whole_rounds_rate)
+                               percentile, prepare_trace,
+                               whole_rounds_rate)
 
 TRACE_SECONDS = 4.0            # a few seconds of the steady window
 # a closed loop's clients start this far apart, so that the first
@@ -102,9 +108,11 @@ class _Client:
 
 
 def _stream_one(handle, c: _Client, prompt: List[int],
-                stop_at: Optional[float]) -> None:
+                stop_at: Optional[List[float]]) -> None:
     """Send one request through the serve handle and read its stream.
-    ``stop_at``: a closed-loop client abandons the stream there."""
+    ``stop_at``: [instant] at which the client abandons the stream (a
+    closed loop's end; a list so that --trace 2 can move it out past
+    its traced phase)."""
     payload = {"prompt_ids": prompt,
                "max_new_tokens": c.req.output_len,
                "trace_id": c.trace_id}
@@ -113,7 +121,7 @@ def _stream_one(handle, c: _Client, prompt: List[int],
         for _tok in handle.stream.options(stream=True).remote(payload):
             now = time.monotonic()
             c.token_times.append(now)
-            if stop_at is not None and now >= stop_at:
+            if stop_at is not None and now >= stop_at[0]:
                 c.abandoned = True
                 break
         c.done = time.monotonic()
@@ -126,10 +134,11 @@ class _Sampler(threading.Thread):
     """Once a second: load_report() and the engine's new events (its
     log is a ring of 8192; a window outlasts it)."""
 
-    def __init__(self, eng):
+    def __init__(self, eng, after: Optional["_Sampler"] = None):
         super().__init__(name="bench-sampler", daemon=True)
         self.eng, self.samples, self.events = eng, [], []
-        self._cursor = 0
+        # ``after``: carry on where a stopped sampler left off
+        self._cursor = after._cursor if after is not None else 0
         self._halt = threading.Event()
         self.dropped = 0
 
@@ -156,6 +165,26 @@ class _Sampler(threading.Thread):
         self._halt.set()
         self.join(timeout=5)
         self.drain()
+
+
+def traced_phase(dep, trace_dir: str, seconds: float, ramp_s: float,
+                 arrivals) -> tuple:
+    """--trace 2, after the window: one start and stop of the profiler
+    whose trace is thrown away (the first start's cost falls into no
+    number), then ``seconds`` of the traffic traced into ``trace_dir``
+    through the deployment's own control. An open loop hands
+    ``arrivals(t_zero)``, which replays its schedule around ``t_zero``
+    (``ramp_s`` of it before); a closed loop's clients are simply still
+    running. Returns the traced span on time.monotonic()."""
+    prepare_trace(trace_dir, dep.start_trace, dep.stop_trace)
+    t_zero = time.monotonic() + ramp_s
+    if arrivals is not None:
+        threading.Thread(target=arrivals, args=(t_zero,), daemon=True,
+                         name="bench-replay").start()
+    time.sleep(max(0.0, t_zero - time.monotonic()))
+    dep.start_trace(trace_dir)
+    time.sleep(seconds)
+    return dep.stop_trace()
 
 
 def run(ctx) -> types.SimpleNamespace:
@@ -286,23 +315,29 @@ def run(ctx) -> types.SimpleNamespace:
     t_open = t_stream + ramp
     t_close = t_open + float(args.seconds)
     tracer = None
-    if args.trace:
+    if args.trace == 1:
         tracer = Tracer(ctx.trace_dir, t_open, args.seconds,
                         TRACE_SECONDS)
         tracer.start()
     window_meter = None
+    # a closed loop's clients take requests until loop_until and leave
+    # their streams at stream_until; --trace 2 keeps them going through
+    # its traced phase and cuts them when the trace stops
+    after = args.trace == 2
+    loop_until = [float("inf") if after else t_close]
+    stream_until = [float("inf") if after else t_close + EDGE_TAIL_S]
 
     if closed:
         counter = itertools.count()
 
         def client_loop():
-            while time.monotonic() < t_close:
+            while time.monotonic() < loop_until[0]:
                 with lock:
                     r = population[next(counter) % len(population)]
                     c = _Client(r, time.monotonic())
                     clients.append(c)
                 _stream_one(handle, c, prompts_by_index[r.index],
-                            t_close + EDGE_TAIL_S)
+                            stream_until)
 
         for i in range(n_clients):
             threading.Thread(target=client_loop, daemon=True,
@@ -422,6 +457,60 @@ def run(ctx) -> types.SimpleNamespace:
         tokens_in_window=tokens_in_window, ttft_s=ttft, itl_s=itl,
         trace_span=tracer.span if tracer else None,
         trace=None, closed=closed)
+
+    if after:
+        # the window is closed and its numbers are taken (everything
+        # above): now trace a few seconds of the same traffic
+        with lock:
+            seen = len(clients)
+        if not closed:
+            # the drain left the engine empty: replay the head of the
+            # same draw, its ramp unmeasured, and trace what follows
+            replay = trafficgen.open_schedule(tr, TRACE_SECONDS, ctx.rate)
+            for r in replay:       # (a window shorter than the trace)
+                if r.index not in prompts_by_index:
+                    prompts_by_index[r.index] = trafficgen.prompt_tokens(
+                        args.seed, r.index, r.prompt_len, vocab, shared)
+
+            def arrivals(t_zero):
+                for r in replay:
+                    time.sleep(max(0.0, t_zero + r.due_s
+                                   - time.monotonic()))
+                    if time.monotonic() >= stream_until[0]:
+                        return
+                    c = _Client(r, t_zero + r.due_s)
+                    with lock:
+                        clients.append(c)
+                    threading.Thread(
+                        target=_stream_one, daemon=True,
+                        args=(handle, c, prompts_by_index[r.index],
+                              stream_until)).start()
+        else:
+            arrivals = None
+        sampler2 = _Sampler(eng, after=sampler)
+        sampler2.start()
+        run_.trace_span = traced_phase(
+            holder["dep"], ctx.trace_dir, TRACE_SECONDS,
+            ramp if not closed else 0.0, arrivals)
+        # cut the clients: each leaves its stream at its next token
+        loop_until[0] = stream_until[0] = 0.0
+        sampler2.stop()
+        run_.events = sampler.events + sampler2.events
+        run_.samples = sampler.samples + sampler2.samples
+        run_.trace_dir = ctx.trace_dir
+        t0_, t1_ = run_.trace_span
+        times = [t for c in list(clients) for t in list(c.token_times)]
+        n_tok = sum(1 for t in times if t0_ <= t < t1_)
+        # whole rounds too (the trace is still being written while the
+        # clients read on, so the closing burst is there)
+        whole = whole_rounds_rate(times, t0_, t1_ - 1.0, BURST_GUARD_S)
+        log(f"[traced phase] {t1_ - t0_:.2f} s after the window: "
+            f"{n_tok} tokens reached clients = "
+            f"{n_tok / (t1_ - t0_):.1f} tokens/s with the profiler on"
+            + (f", {whole[0]:.3f} over {whole[1]:.3f} s of whole rounds"
+               if whole else "")
+            + f" ({len(clients) - seen} requests sent after the window);"
+            f" events dropped by the ring {sampler2.dropped}")
 
     def shutdown():
         serve.shutdown()

@@ -55,7 +55,9 @@ def test_files_hold_the_published_sizes_but_for_reduced(bench):
 
 def test_benchmark_json_shape(bench):
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
+                          "workloads", "end_to_end", "per_layer",
+                          "trace_in_run"}
+    assert bench["trace_in_run"] is True     # the harness takes --trace 2
     assert 1 <= bench["run_seconds"] <= 51
     cells = [w["name"] for w in bench["workloads"]]
     confs = {c["name"]: c for c in bench["configs"]}

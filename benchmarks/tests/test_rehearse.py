@@ -42,6 +42,57 @@ def test_rehearsal_prints_the_contract_line(cell, trace, metric):
     assert line["compiles_in_window"] == 0
 
 
+def _last_line(out):
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = [ln for ln in out.stdout.strip().splitlines()
+             if ln.startswith("{")]
+    assert len(lines) == 1          # one result, and it is the last line
+    assert out.stdout.strip().splitlines()[-1] == lines[0]
+    return json.loads(lines[0])
+
+
+@pytest.mark.parametrize("cell,e2e,per_layer", [
+    ("toy-llama.chat-r80", "ttft_p50_ms itl_p50_ms setup_s",
+     "ttft_p95_ms queue_wait_p95_ms prefill_in_slot_p50_ms "
+     "prefill_budget_share"),
+    ("toy-llama.chat-sat", "serve_tokens_per_s setup_s",
+     "kv_peak_share host_gap_share decode_riders_mean round_host_ms"),
+    ("toy-gpt2.train", "train_tokens_per_s setup_s", "train_mfu"),
+])
+def test_trace_2_measures_then_traces(cell, e2e, per_layer):
+    out = _run("--rehearse", "--workload", cell, "--seed",
+               str(2**31 + 11), "--seconds", "3", "--trace", "2")
+    line = _last_line(out)
+    assert {"correct", "attempted", "failed", "metrics", "device",
+            "compiles_in_window", "breakdown"} <= set(line)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0 and line["compiles_in_window"] == 0
+    # both kinds of metric side by side, every end-to-end value there
+    for name in e2e.split():
+        assert line["metrics"][name]["value"] > 0, name
+    for name in per_layer.split():
+        if name == "train_mfu":      # needs the chip's peak: not on a CPU
+            continue
+        assert set(line["metrics"][name]) == {"value", "unit"}, name
+    assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
+    assert "[traced phase]" in out.stdout
+    # the trace is deleted once it is reduced
+    assert not os.path.exists(os.path.join(
+        common.ROOT, ".bench_trace", cell))
+
+
+def test_trace_2_reports_the_end_to_end_keys_of_trace_0():
+    args = ("--rehearse", "--workload", "toy-gpt2.train", "--seed", "5",
+            "--seconds", "2")
+    plain = _last_line(_run(*args, "--trace", "0"))
+    both = _last_line(_run(*args, "--trace", "2"))
+    assert set(plain) | {"breakdown"} == set(both)
+    assert set(plain["metrics"]) <= set(both["metrics"])
+    for name, m in plain["metrics"].items():
+        assert both["metrics"][name]["unit"] == m["unit"]
+    assert set(plain["device"]) <= set(both["device"])
+
+
 def test_a_cell_refuses_the_cpu():
     out = _run("--workload", "gpt2-124m.train-b24", "--seed", "1",
                "--seconds", "1", "--trace", "0")

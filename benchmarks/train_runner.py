@@ -1,7 +1,9 @@
 """The one runner for every configuration of ``kind: train``: the SPMD
 train step exactly as bench.py and examples/02_train_gpt2.py build it
 (shard_state / put_batch / make_train_step on a {"data": -1} mesh), fed
-a fresh seeded batch through put_batch every step.
+a fresh seeded batch through put_batch every step. ``--trace 2`` runs
+the same loop on, after the window's numbers are taken, under a trace
+started through the program's control (ray_tpu._private.profiling).
 """
 from __future__ import annotations
 
@@ -10,7 +12,8 @@ import types
 from typing import Any, Dict
 
 from benchmarks import parity, trafficgen, weights
-from benchmarks.common import Timer, Tracer, cache_report, log
+from benchmarks.common import (Timer, Tracer, cache_report, log,
+                               prepare_trace)
 
 TRACE_SECONDS = 3.0
 
@@ -98,7 +101,7 @@ def run(ctx) -> types.SimpleNamespace:
         tracer = None
         t_open = time.monotonic()
         t_close = t_open + float(args.seconds)
-        if args.trace:
+        if args.trace == 1:
             tracer = Tracer(ctx.trace_dir, t_open, args.seconds,
                             TRACE_SECONDS)
             tracer.start()
@@ -133,7 +136,7 @@ def run(ctx) -> types.SimpleNamespace:
     e2e = {"train_tokens_per_s": tokens / elapsed / ctx.chips,
            "setup_s": t_open - ctx.t_process}
     log(f"[cache] after: {cache_report(cache_dir, top=6)}")
-    return types.SimpleNamespace(
+    run_ = types.SimpleNamespace(
         kind="train", cfg=cfg, traffic=tr, chips=ctx.chips,
         peaks=ctx.peaks, seconds=float(args.seconds),
         window=(t_open, t_end), e2e=e2e, attempted=steps, failed=0,
@@ -142,3 +145,31 @@ def run(ctx) -> types.SimpleNamespace:
         steps=steps, batch=batch, seq=seq, losses=losses,
         trace_span=tracer.span if tracer else None,
         trace=None, shutdown=lambda: None)
+    if args.trace == 2:
+        # the window is closed and its numbers are taken: the same loop
+        # runs on with the next batches under a trace. One start and
+        # stop first, thrown away, so that the profiler's first start
+        # falls into no number.
+        from ray_tpu._private import profiling
+        prepare_trace(ctx.trace_dir, profiling.start_device_trace,
+                      profiling.stop_device_trace)
+        with jax.set_mesh(mesh):
+            profiling.start_device_trace(ctx.trace_dir)
+            t_stop = time.monotonic() + TRACE_SECONDS
+            first, pending = step, []
+            while time.monotonic() < t_stop:
+                b = put_batch({"ids": batches(step)}, mesh)
+                state, m = train_step(state, b)
+                pending.append(m)
+                step += 1
+                if len(pending) > in_flight:
+                    jax.block_until_ready(pending.pop(0)["loss"])
+            jax.block_until_ready(state)
+            run_.trace_span = profiling.stop_device_trace()
+        t0_, t1_ = run_.trace_span
+        log(f"[traced phase] {step - first} steps in {t1_ - t0_:.3f} s "
+            f"after the window = "
+            f"{(step - first) * batch * seq / (t1_ - t0_) / ctx.chips:.0f}"
+            f" tokens/s per chip with the profiler on")
+        run_.trace_dir = ctx.trace_dir
+    return run_

@@ -4,8 +4,13 @@ Capability parity with the reference's profile-event pipeline
 (src/ray/core_worker/profiling.h, python/ray/_private/profiling.py,
 GlobalState.chrome_tracing_dump in python/ray/_private/state.py:413): every
 runtime records named events/spans; ``timeline()`` dumps a Chrome
-``chrome://tracing`` JSON. The TPU flavor can merge XLA profiler traces via
-``merge_xla_trace``.
+``chrome://tracing`` JSON.
+
+The device's own timeline is ``jax.profiler``'s, and this module holds the
+one control over it: ``start_device_trace`` / ``stop_device_trace`` start
+and stop the profiler in the process that holds the chip. Host
+``TraceAnnotation``s (the serving engine's ``engine.*`` phases) land on the
+trace's host plane, which shares the trace's clock with the device planes.
 """
 from __future__ import annotations
 
@@ -13,7 +18,7 @@ import json
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 _lock = threading.Lock()
 _events: List[Dict[str, Any]] = []
@@ -86,11 +91,45 @@ def chrome_trace(filename: Optional[str] = None) -> List[Dict[str, Any]]:
     return events
 
 
-def merge_xla_trace(xla_trace_events: List[Dict[str, Any]]):
-    """Merge device-side events from the XLA profiler into the host
-    timeline (pid=1 lane)."""
-    with _lock:
-        for e in xla_trace_events:
-            e = dict(e)
-            e["pid"] = 1
-            _events.append(e)
+# ------------------------------------------------------ device trace
+
+_trace_lock = threading.Lock()
+_trace_t0: Optional[float] = None      # time.monotonic() at the start
+_trace_dir: Optional[str] = None
+
+
+def start_device_trace(log_dir: str) -> float:
+    """Start ``jax.profiler`` in this process (the one that holds the
+    chip), writing under ``log_dir``: device planes and host TraceMes,
+    no Python tracer. One trace at a time: a second start raises
+    ``RuntimeError``. Returns ``time.monotonic()`` at the start."""
+    global _trace_t0, _trace_dir
+    import jax
+    with _trace_lock:
+        if _trace_t0 is not None:
+            raise RuntimeError(
+                f"a device trace is already running (into {_trace_dir})")
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0       # device and host TraceMes only
+        opts.host_tracer_level = 2
+        t0 = time.monotonic()
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
+        _trace_t0, _trace_dir = t0, log_dir
+    return t0
+
+
+def stop_device_trace() -> Tuple[float, float]:
+    """Stop the running trace and write it out. Returns the traced span
+    ``(t0, t1)`` on ``time.monotonic()`` (``t1`` is taken before the
+    trace is written). ``RuntimeError`` when none is running."""
+    global _trace_t0, _trace_dir
+    import jax
+    with _trace_lock:
+        if _trace_t0 is None:
+            raise RuntimeError("no device trace is running")
+        t0, t1 = _trace_t0, time.monotonic()
+        try:
+            jax.profiler.stop_trace()
+        finally:
+            _trace_t0 = _trace_dir = None
+    return t0, t1

@@ -175,14 +175,19 @@ def linear_cross_entropy(features, wte, targets,
     scan-chunked variant (fused_linear_cross_entropy) by 7+ points —
     XLA overlaps the one big projection better than a serialized scan.
     """
-    mask = (targets != ignore_index)
-    tgt = jnp.where(mask, targets, 0)
-    logits = jax.lax.dot_general(
-        features, wte.astype(features.dtype), (((2,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, tgt[..., None], axis=-1)[..., 0]
-    return ((lse - gold) * mask).sum() / jnp.maximum(mask.sum(), 1)
+    # "loss_head" names this work in a device trace, forward and
+    # backward (the transpose keeps the scope): metadata only
+    with jax.named_scope("loss_head"):
+        mask = (targets != ignore_index)
+        tgt = jnp.where(mask, targets, 0)
+        logits = jax.lax.dot_general(
+            features, wte.astype(features.dtype),
+            (((2,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, tgt[..., None],
+                                   axis=-1)[..., 0]
+        return ((lse - gold) * mask).sum() / jnp.maximum(mask.sum(), 1)
 
 
 def fused_linear_cross_entropy(features, wte, targets,

@@ -163,19 +163,24 @@ class LlamaAttention(nn.Module):
             pos = cache_len                       # [B] int32
             Pg = pc.page_size
             from ray_tpu.ops.paged_attention import paged_append
+            # The named scopes below (kv_append, then attn_kernel or
+            # kv_gather, attn_scores, attn_pv) are metadata only: a device trace
+            # splits a step's time by them (PERF.md section 3); the
+            # compiled program is the same with or without them.
+            with jax.named_scope("kv_append"):
+                appended = paged_append(
+                    pc.pages_k, pc.pages_v, pc.page_table, pos, k, v,
+                    pc.scales_k, pc.scales_v)
             if pc.quantized:
                 # int8 pool: append quantizes in place and returns
                 # updated per-page scales, which travel WITH the
                 # pages through the cache pytree (COW, donation,
                 # placement all move them together).
-                pk, pv, sk, sv = paged_append(
-                    pc.pages_k, pc.pages_v, pc.page_table, pos, k, v,
-                    pc.scales_k, pc.scales_v)
+                pk, pv, sk, sv = appended
                 new_cache = pc._replace(pages_k=pk, pages_v=pv,
                                         scales_k=sk, scales_v=sv)
             else:
-                pk, pv = paged_append(pc.pages_k, pc.pages_v,
-                                      pc.page_table, pos, k, v)
+                pk, pv = appended
                 sk = sv = None
                 new_cache = pc._replace(pages_k=pk, pages_v=pv)
             if T == 1 and _use_paged_kernel():
@@ -183,8 +188,9 @@ class LlamaAttention(nn.Module):
                 # table rides scalar prefetch; the page window is
                 # never materialized (ops/paged_attention.py). Int8
                 # pages dequantize in-register inside the kernel.
-                y = paged_decode_attention(
-                    q[:, 0], pk, pv, pc.page_table, pos, sk, sv)
+                with jax.named_scope("attn_kernel"):
+                    y = paged_decode_attention(
+                        q[:, 0], pk, pv, pc.page_table, pos, sk, sv)
                 y = y.reshape(B, 1, cfg.n_heads, hd)
             else:
                 # CPU/XLA fallback and chunk prefill: gather the page
@@ -192,21 +198,22 @@ class LlamaAttention(nn.Module):
                 # [KH, B, L, D]; gathered index == logical sequence
                 # position by construction.
                 L = pc.page_table.shape[1] * Pg
-                kg = pk[:, pc.page_table]
-                vg = pv[:, pc.page_table]
-                if sk is not None:
-                    # dequantize the gathered window in fp32 using the
-                    # gathered per-page scales (value = q * s / 127) —
-                    # only the per-step [B, L] window ever exists in
-                    # fp, never the pool itself
-                    skg = sk[:, pc.page_table]  # [KH, B, MP, 1]
-                    svg = sv[:, pc.page_table]
-                    kg = kg.astype(jnp.float32) * \
-                        (skg * (1.0 / 127.0))[..., None]
-                    vg = vg.astype(jnp.float32) * \
-                        (svg * (1.0 / 127.0))[..., None]
-                kg = kg.reshape(cfg.n_kv_heads, B, L, hd)
-                vg = vg.reshape(cfg.n_kv_heads, B, L, hd)
+                with jax.named_scope("kv_gather"):
+                    kg = pk[:, pc.page_table]
+                    vg = pv[:, pc.page_table]
+                    if sk is not None:
+                        # dequantize the gathered window in fp32 using
+                        # the gathered per-page scales (value = q * s /
+                        # 127) — only the per-step [B, L] window ever
+                        # exists in fp, never the pool itself
+                        skg = sk[:, pc.page_table]  # [KH, B, MP, 1]
+                        svg = sv[:, pc.page_table]
+                        kg = kg.astype(jnp.float32) * \
+                            (skg * (1.0 / 127.0))[..., None]
+                        vg = vg.astype(jnp.float32) * \
+                            (svg * (1.0 / 127.0))[..., None]
+                    kg = kg.reshape(cfg.n_kv_heads, B, L, hd)
+                    vg = vg.reshape(cfg.n_kv_heads, B, L, hd)
                 # Grouped-query attention WITHOUT materializing
                 # repeated K/V: q reshapes to [B, T, KH, rep, D] and
                 # contracts against the grouped cache directly — at
@@ -214,27 +221,30 @@ class LlamaAttention(nn.Module):
                 # per step, the decode hot loop's dominant traffic.
                 rep = cfg.n_heads // cfg.n_kv_heads
                 qg = q.reshape(B, -1, cfg.n_kv_heads, rep, hd)
-                scores = jnp.einsum(
-                    "btkrd,kbsd->bkrts", qg.astype(jnp.float32),
-                    kg.astype(jnp.float32)) / np.sqrt(hd)
-                # causal over absolute positions: query t of slot b
-                # sits at pos[b] + t and sees keys 0..pos[b]+t
-                q_pos = pos[:, None] + jnp.arange(T)[None]   # [B, T]
-                valid = jnp.arange(L)[None, None] <= \
-                    q_pos[:, :, None]                        # [B, T, L]
-                scores = jnp.where(valid[:, None, None],
-                                   scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1)
-                y = jnp.einsum("bkrts,kbsd->btkrd",
-                               probs.astype(vg.dtype), vg)
+                with jax.named_scope("attn_scores"):
+                    scores = jnp.einsum(
+                        "btkrd,kbsd->bkrts", qg.astype(jnp.float32),
+                        kg.astype(jnp.float32)) / np.sqrt(hd)
+                    # causal over absolute positions: query t of slot
+                    # b sits at pos[b] + t and sees keys 0..pos[b]+t
+                    q_pos = pos[:, None] + jnp.arange(T)[None]  # [B, T]
+                    valid = jnp.arange(L)[None, None] <= \
+                        q_pos[:, :, None]                    # [B, T, L]
+                    scores = jnp.where(valid[:, None, None],
+                                       scores, -1e30)
+                    probs = jax.nn.softmax(scores, axis=-1)
+                with jax.named_scope("attn_pv"):
+                    y = jnp.einsum("bkrts,kbsd->btkrd",
+                                   probs.astype(vg.dtype), vg)
                 y = y.reshape(B, -1, cfg.n_heads, hd)
         elif kv_cache is not None:
             # Decode path: append this step's K/V into the static cache.
             ck, cv = kv_cache
-            ck = jax.lax.dynamic_update_slice(
-                ck, k.astype(ck.dtype), (0, cache_len, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cv, v.astype(cv.dtype), (0, cache_len, 0, 0))
+            with jax.named_scope("kv_append"):
+                ck = jax.lax.dynamic_update_slice(
+                    ck, k.astype(ck.dtype), (0, cache_len, 0, 0))
+                cv = jax.lax.dynamic_update_slice(
+                    cv, v.astype(cv.dtype), (0, cache_len, 0, 0))
             new_cache = (ck, cv)
             k, v = ck, cv
             S = k.shape[1]
@@ -245,16 +255,18 @@ class LlamaAttention(nn.Module):
             # the paged branch above)
             rep = cfg.n_heads // cfg.n_kv_heads
             qg = q.reshape(B, T, cfg.n_kv_heads, rep, hd)
-            scores = jnp.einsum(
-                "btkrd,bskd->bkrts", qg.astype(jnp.float32),
-                k.astype(jnp.float32)) / np.sqrt(hd)
-            q_pos = cache_len + jnp.arange(T)
-            causal = kv_pos[None, :] <= q_pos[:, None]
-            mask = (causal & valid[None, :])[None, None, None]
-            scores = jnp.where(mask, scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            y = jnp.einsum("bkrts,bskd->btkrd",
-                           probs.astype(v.dtype), v)
+            with jax.named_scope("attn_scores"):
+                scores = jnp.einsum(
+                    "btkrd,bskd->bkrts", qg.astype(jnp.float32),
+                    k.astype(jnp.float32)) / np.sqrt(hd)
+                q_pos = cache_len + jnp.arange(T)
+                causal = kv_pos[None, :] <= q_pos[:, None]
+                mask = (causal & valid[None, :])[None, None, None]
+                scores = jnp.where(mask, scores, -1e30)
+                probs = jax.nn.softmax(scores, axis=-1)
+            with jax.named_scope("attn_pv"):
+                y = jnp.einsum("bkrts,bskd->btkrd",
+                               probs.astype(v.dtype), v)
             y = y.reshape(B, T, cfg.n_heads, hd)
         else:
             rep = cfg.n_heads // cfg.n_kv_heads
@@ -328,10 +340,11 @@ def transformer_forward(mod: nn.Module, cfg, block_cls, input_ids,
             x, freqs, positions, cache_i, cache_len)
         new_caches.append(nc)
     x = RMSNorm(cfg.norm_eps, name="norm")(x)
-    logits = jax.lax.dot_general(
-        x.astype(cfg.dtype), tok.astype(cfg.dtype),
-        (((2,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    with jax.named_scope("head"):
+        logits = jax.lax.dot_general(
+            x.astype(cfg.dtype), tok.astype(cfg.dtype),
+            (((2,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
     if kv_caches is None:
         return logits, None
     return logits, new_caches

@@ -193,6 +193,7 @@ def _flash_fwd(q, k, v, causal: bool, H: int, D: int,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -350,6 +351,7 @@ def _flash_bwd_packed(causal, H, D, scale, res, g):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q, k, v, o, do, lse)
 
     # dkv grid: kv blocks in the third slot, q blocks innermost.
@@ -379,6 +381,7 @@ def _flash_bwd_packed(causal, H, D, scale, res, g):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q, k, v, o, do, lse)
     return dq, dk, dv
 

@@ -393,6 +393,7 @@ def paged_decode_attention(q, pages_k, pages_v, page_table, positions,
         ),
         out_shape=jax.ShapeDtypeStruct((B, KH, rep, D), q.dtype),
         interpret=_interpret() if interpret is None else interpret,
+        name="paged_decode",
     )(*prefetch, qg, pages_k, pages_v)
     return out.reshape(B, H, D)
 

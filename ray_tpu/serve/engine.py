@@ -63,6 +63,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ray_tpu.models.kv_cache import (BlockAllocator, PagedKVLayer,
                                      export_page_bytes, init_kv_pool,
@@ -82,6 +83,7 @@ from ray_tpu.serve.scheduler import (LANE_BATCH, LANE_ONLINE,
                                      REPLICA_ROLES, ROLE_UNIFIED,
                                      StepPlan, SlotView, plan_step,
                                      role_plan_caps)
+from ray_tpu.util.compile_cache import metadata_keyed
 
 _DONE = object()
 
@@ -399,6 +401,12 @@ class _Slot:
         return len(self.prompt) - self.prefilled
 
 
+def _new_round_info() -> Dict[str, int]:
+    """What a round dispatched, as its ``round`` event reports it."""
+    return {"decode_riders": 0, "decode_steps": 0,
+            "prefill_tokens": 0, "prefill_budget": 0}
+
+
 class LLMEngine:
     """Continuous-batching decode engine for one model replica.
 
@@ -609,8 +617,15 @@ class LLMEngine:
         # prompts actually share page-aligned prefixes.
         self.prefix_cache = (PrefixCache(self.alloc, page_size)
                              if prefix_cache else None)
-        self._copy_page_fn = (_jit_copy_page(self._mesh)
-                              if prefix_cache else None)
+        # The engine's jitted programs by their names in a device
+        # trace (jit_<function>), with the executables each held when
+        # this engine took it: step() counts what a round adds
+        # (stats["programs_built"], the ``compile`` event).
+        self._programs: Dict[str, Any] = {}
+        self._program_sizes: Dict[str, int] = {}
+        self._copy_page_fn = (
+            self._track_program(_jit_copy_page(self._mesh))
+            if prefix_cache else None)
         # Fleet prefix-cache digest advertisement cap: load reports
         # ship at most this many path hashes, truncated prefix-closed
         # longest/hottest-first (PrefixCache.digest) so fleet routing
@@ -716,9 +731,9 @@ class LLMEngine:
         # per pow2 chunk bucket (floor page_size, cap prefill_chunk) —
         # a handful of shapes total, vs the old one-per-prompt-length
         # cache whose misses were measured as multi-second p99 stalls.
-        self._prefill_fn = _jit_prefill(
+        self._prefill_fn = self._track_program(_jit_prefill(
             self.model, self.temperature, self._max_prefill_batch,
-            self.capture_logprobs, self._mesh)
+            self.capture_logprobs, self._mesh))
         # Typed lifecycle event log (serve/obs.py): lock-free bounded
         # ring recording every request phase and scheduler action.
         # ``events=False`` is the A/B arm proving the log costs
@@ -744,10 +759,61 @@ class LLMEngine:
         # the TTFT EWMA above
         self._itl_ewma: Optional[float] = None
         self._itl_ewma_alpha = 0.2
-        self._decode_fn = _jit_decode(
+        self._decode_fn = self._track_program(_jit_decode(
             self.model, self.temperature, self.KMAX, self.S,
-            self.capture_logprobs, self._mesh)
-        self._seed_fn = _jit_seed()
+            self.capture_logprobs, self._mesh))
+        self._seed_fn = self._track_program(_jit_seed())
+        # what this round dispatched, for its ``round`` event
+        self._round_info = _new_round_info()
+
+    def _track_program(self, fn):
+        """Register a jitted step program under its trace name. The
+        builders are shared per process (lru_cache), so the baseline
+        is what the program already holds, not zero."""
+        name = "jit_" + fn.__name__
+        self._programs[name] = fn
+        self._program_sizes[name] = fn._cache_size()
+        return fn
+
+    def _count_programs_locked(self, wall_s: float) -> None:
+        """After a round: which of the engine's programs gained an
+        executable (built, or loaded from the persistent cache) — the
+        in-program answer to "which round recompiled"."""
+        for name, fn in self._programs.items():
+            n = fn._cache_size()
+            grew = n - self._program_sizes[name]
+            if grew > 0:
+                self._program_sizes[name] = n
+                self.stats["programs_built"] += grew
+                self.events.append("compile", data={
+                    "program": name, "round": self._round,
+                    "built": grew, "wall_s": round(wall_s, 6)})
+
+    # ------------------------------------------------- device trace
+
+    def start_trace(self, log_dir: str) -> float:
+        """Start a device trace (``jax.profiler``) in this process and
+        mark it in the event log. The ``engine.*`` annotations of
+        step() carry the round number, and so do ``trace_start`` /
+        ``trace_stop``: the event log and the trace join by round,
+        without mapping clocks. One trace at a time (a second start
+        raises ``RuntimeError``). Returns time.monotonic() at the
+        start."""
+        from ray_tpu._private import profiling
+        t0 = profiling.start_device_trace(log_dir)
+        self.events.append("trace_start", t=t0, data={
+            "round": self._round, "log_dir": log_dir})
+        return t0
+
+    def stop_trace(self):
+        """Stop the trace ``start_trace`` began and write it out;
+        returns the traced span (t0, t1) on time.monotonic().
+        ``RuntimeError`` when none is running."""
+        from ray_tpu._private import profiling
+        t0, t1 = profiling.stop_device_trace()
+        self.events.append("trace_stop", t=t1, data={
+            "round": self._round, "span_s": round(t1 - t0, 6)})
+        return t0, t1
 
     def _h2d(self, x):
         """Host->device for dispatch operands (page tables, token
@@ -1494,13 +1560,22 @@ class LLMEngine:
         dispatch requeue-or-fail under the bounded retry policy —
         and the engine keeps serving. Only non-attributable errors
         still escape to ``_fail_all`` via ``_loop``."""
-        with self._lock:
+        # metadata_keyed: the step programs' named scopes are read
+        # from device traces, so the persistent compile cache must not
+        # hand back an executable that names its operations otherwise
+        with self._lock, metadata_keyed():
             self._round += 1
             self._hb = time.monotonic()   # progress heartbeat: a new
                                           # round means the previous
                                           # one completed
             _pm = obs.phase_metrics() if self._obs_enabled else None
             _t0 = self._hb
+            # The engine.* TraceAnnotations below put this round's host
+            # phases on a device trace's host plane (same clock as the
+            # device planes), each with the round number; closed, with
+            # no trace running, one costs under a microsecond.
+            _rnd = self._round
+            self._round_info = _ri = _new_round_info()
             self._fire("step")     # global-fault site: escapes to
                                    # _fail_all, like real device loss
             if self._stopped:
@@ -1509,42 +1584,45 @@ class LLMEngine:
                 return False
             self._reap_deadlines_locked()
             _tg = time.monotonic()
-            if self.overlap:
-                # Overlapped hot loop: plan round N+1 from the STALE
-                # token frontier while round N still runs on device.
-                # This sweep only reads buffers the device already
-                # finished — it NEVER blocks, in eos mode either.
-                # Completion detection moves to the trailing drain:
-                # emission truncates at a late-revealed eos, the
-                # planner caps stale riders at one decode chunk
-                # (serve/scheduler.py SlotView.stale), and the
-                # overshot KV frontier is reclaimed by the same
-                # clamp-and-reseed machinery spec rollback uses. Spec
-                # mode still syncs, but at its own dispatch
-                # (_dispatch_spec_locked) — acceptance gates the NEXT
-                # verify, not this round's prefill/decode lanes.
-                self._drain_fetches_locked(ready_only=True)
-            elif not self._deferred or self.spec_len:
-                # Lockstep eos mode: emissions gate planning. Spec
-                # mode: the proposer's context and the verify's input
-                # token are HOST state (req.generated), so every
-                # round syncs to the device before planning —
-                # speculation trades the deferred pipeline's async
-                # pacing for multi-token dispatches.
-                self._drain_fetches_locked()
-            else:
-                # Opportunistic: read back anything already finished
-                # BEFORE admitting — free on a fast local device, and
-                # it gets completions to clients (whose resubmissions
-                # can then land during the upcoming dispatch) a full
-                # dispatch earlier. Never blocks.
-                self._drain_fetches_locked(ready_only=True)
-            _gap = time.monotonic() - _tg
+            with TraceAnnotation("engine.drain_ready", round=_rnd):
+                if self.overlap:
+                    # Overlapped hot loop: plan round N+1 from the STALE
+                    # token frontier while round N still runs on device.
+                    # This sweep only reads buffers the device already
+                    # finished — it NEVER blocks, in eos mode either.
+                    # Completion detection moves to the trailing drain:
+                    # emission truncates at a late-revealed eos, the
+                    # planner caps stale riders at one decode chunk
+                    # (serve/scheduler.py SlotView.stale), and the
+                    # overshot KV frontier is reclaimed by the same
+                    # clamp-and-reseed machinery spec rollback uses. Spec
+                    # mode still syncs, but at its own dispatch
+                    # (_dispatch_spec_locked) — acceptance gates the NEXT
+                    # verify, not this round's prefill/decode lanes.
+                    self._drain_fetches_locked(ready_only=True)
+                elif not self._deferred or self.spec_len:
+                    # Lockstep eos mode: emissions gate planning. Spec
+                    # mode: the proposer's context and the verify's input
+                    # token are HOST state (req.generated), so every
+                    # round syncs to the device before planning —
+                    # speculation trades the deferred pipeline's async
+                    # pacing for multi-token dispatches.
+                    self._drain_fetches_locked()
+                else:
+                    # Opportunistic: read back anything already finished
+                    # BEFORE admitting — free on a fast local device, and
+                    # it gets completions to clients (whose resubmissions
+                    # can then land during the upcoming dispatch) a full
+                    # dispatch earlier. Never blocks.
+                    self._drain_fetches_locked(ready_only=True)
+            _ta = time.monotonic()
+            _gap = _ta - _tg
             if self._pending_swap is not None:
                 # drain-mode weight swap: admission is paused; flip
                 # here — between rounds — once everything settled
                 self._maybe_apply_pending_swap_locked()
-            self._admit_locked()
+            with TraceAnnotation("engine.admit", round=_rnd):
+                self._admit_locked()
             if not any(self.slots):
                 if self._fetchq or self._pending_prefill:
                     self._drain_fetches_locked(limit=1)
@@ -1564,15 +1642,17 @@ class LLMEngine:
                     self._work.wait(timeout=0.01)
                 return True
             _tp = time.monotonic()
-            plan = self._plan_steps_locked()
+            with TraceAnnotation("engine.plan", round=_rnd):
+                plan = self._plan_steps_locked()
             _tpe = time.monotonic()
             _gap += _tpe - _tp
             if _pm is not None:
                 _pm["plan"].observe(_tpe - _tp)
-            _td = time.monotonic() if _pm is not None else 0.0
             try:
                 if plan.prefill:
-                    self._dispatch_prefill_locked(plan.prefill)
+                    with TraceAnnotation("engine.dispatch_prefill",
+                                         round=_rnd):
+                        self._dispatch_prefill_locked(plan.prefill)
             except EngineFault as e:
                 e.sids = sorted({g.sid for g in plan.prefill}
                                 | set(e.sids))
@@ -1580,12 +1660,16 @@ class LLMEngine:
                 return True
             try:
                 if plan.spec:
-                    self._dispatch_spec_locked(plan.spec)
+                    with TraceAnnotation("engine.dispatch_spec",
+                                         round=_rnd):
+                        self._dispatch_spec_locked(plan.spec)
                 elif plan.decode_steps:
                     riders = [i for i, s in enumerate(self.slots)
                               if s is not None and s.cur is not None]
-                    self._grow_or_preempt_locked(plan.decode_steps)
-                    self._dispatch_chunk_locked(plan.decode_steps)
+                    with TraceAnnotation("engine.dispatch_decode",
+                                         round=_rnd):
+                        self._grow_or_preempt_locked(plan.decode_steps)
+                        self._dispatch_chunk_locked(plan.decode_steps)
                     if self._deferred:
                         self._retire_planned_locked()
             except EngineFault as e:
@@ -1594,12 +1678,14 @@ class LLMEngine:
                 e.sids = sorted(part | set(e.sids))
                 self._contain_fault_locked(e)
                 return True
+            _tde = time.monotonic()
             if _pm is not None:
-                _pm["dispatch"].observe(time.monotonic() - _td)
+                _pm["dispatch"].observe(_tde - _tpe)
             # trailing readback: block only on a dispatch OLDER than
             # the one just queued (keep=1), so the fetch round trip
             # overlaps the newest dispatch's compute — never its own
-            self._drain_fetches_locked(limit=1, keep=1)
+            with TraceAnnotation("engine.readback", round=_rnd):
+                self._drain_fetches_locked(limit=1, keep=1)
             _now = time.monotonic()
             # Per-round pipeline accounting: host_gap is the time the
             # host spent GATING this round's dispatches (pre-plan
@@ -1610,13 +1696,25 @@ class LLMEngine:
             # derives overlap efficiency from these events; the
             # serve_phase_host_gap_s histogram is the aggregate
             # cross-check.
+            # The rest says what the round was: its number (the join
+            # key with a device trace's engine.* annotations), where
+            # the host's time went (admit/plan/dispatch wait for no
+            # device; readback_s is the trailing drain, which does),
+            # and what was dispatched against the planner's budget.
             self.events.append("round", data={
                 "host_gap_s": round(_gap, 6),
                 "wall_s": round(_now - _t0, 6),
-                "overlap": self.overlap})
+                "overlap": self.overlap,
+                "round": _rnd,
+                "admit_s": round(_tp - _ta, 6),
+                "plan_s": round(_tpe - _tp, 6),
+                "dispatch_s": round(_tde - _tpe, 6),
+                "readback_s": round(_now - _tde, 6),
+                **_ri})
             if _pm is not None:
                 _pm["round_wall"].observe(_now - _t0)
                 _pm["host_gap"].observe(_gap)
+            self._count_programs_locked(_now - _t0)
             return True
 
     def _contain_fault_locked(self, e: EngineFault) -> None:
@@ -1726,6 +1824,7 @@ class LLMEngine:
                               decode_chunk=self.K,
                               prefill_budget=self.PC,
                               max_run_ahead=self.KMAX)
+        self._round_info["prefill_budget"] = caps["prefill_budget"]
         return plan_step(views, total_slots=self.S,
                          prefill_budget=caps["prefill_budget"],
                          decode_chunk=self.K,
@@ -2188,7 +2287,8 @@ class LLMEngine:
         if page_ids is None:
             return 0
         if self._write_page_fn is None:
-            self._write_page_fn = _jit_write_page(self._mesh)
+            self._write_page_fn = self._track_program(
+                _jit_write_page(self._mesh))
         for dst, page_cols in zip(page_ids, cols):
             self.pages = self._write_page_fn(
                 self.pages, self._h2d(jnp.int32(dst)),
@@ -2444,6 +2544,8 @@ class LLMEngine:
             slot.pos += steps
             slot.decoded += steps
         self._fetchq.append((toks, riders, steps))
+        self._round_info["decode_riders"] = len(riders)
+        self._round_info["decode_steps"] = steps
         self.events.append("decode", data=steps)
         self.stats["chunks"] += 1
         self.stats["decode_steps"] += steps
@@ -2496,7 +2598,8 @@ class LLMEngine:
             self._drain_fetches_locked()
         T = self.spec_len + 1
         if self._verify_fn is None:
-            self._verify_fn = _jit_verify(self.model, self._mesh)
+            self._verify_fn = self._track_program(
+                _jit_verify(self.model, self._mesh))
         rows = []
         for g in grants:
             slot = self.slots[g.sid]
@@ -2555,6 +2658,8 @@ class LLMEngine:
             self._h2d(start), self._h2d(pt))
         out = np.asarray(out_dev)    # host sync: acceptance gates
         self._hb = time.monotonic()  # verify completed: progress
+        self._round_info["decode_riders"] = len(rows)
+        self._round_info["decode_steps"] = 1   # one verify forward
         m = spec_decode.metrics()
         self.stats["spec_rounds"] += 1
         # surviving slots' device decode state is reseeded with the
@@ -2895,8 +3000,9 @@ class LLMEngine:
             rid=tuple(slot.req.rid for _ix, slot, _t in rows),
             data=tuple((ix, take) for ix, _s, take in rows))
         self.stats["prefills"] += 1
-        self.stats["prefill_tokens"] += sum(
-            take for _ix, _s, take in rows)
+        _granted = sum(take for _ix, _s, take in rows)
+        self.stats["prefill_tokens"] += _granted
+        self._round_info["prefill_tokens"] += _granted
         self.stats["prefilled_seqs"] += len(placements)
         self._hb = time.monotonic()   # dispatch completed: a long
                                       # prompt prefilling chunk by
@@ -2965,7 +3071,8 @@ def _jit_prefill(model, temp, B, capture, mesh):
                                      cache_len=start)
         new_pages = constrain([kv_layer_store(c) for c in new_kv])
         last = logits[jnp.arange(B), last_idx]        # [B, V]
-        firsts = _pick_token(last, sub, temp)
+        with jax.named_scope("sample"):
+            firsts = _pick_token(last, sub, temp)
         if capture:
             # Score under the SAMPLING distribution (temperature-
             # scaled at temp > 0) — the behavior policy an RL
@@ -3031,7 +3138,8 @@ def _jit_decode(model, temp, KMAX, S, capture, mesh):
                   for layer in pages]
             logits, new_kv = model.apply(
                 params, cur[:, None], kv_caches=kv, cache_len=pos)
-            nxt = _pick_token(logits[:, -1], sub, temp)
+            with jax.named_scope("sample"):
+                nxt = _pick_token(logits[:, -1], sub, temp)
             if capture:
                 # Behavior-policy logprob: temperature-scaled to
                 # match what _pick_token actually sampled from.

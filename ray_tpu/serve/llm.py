@@ -463,6 +463,31 @@ class LlamaDeployment:
                         **opts).start()
             return self._engine
 
+    def start_trace(self, log_dir: str) -> float:
+        """Trace this replica's chip from outside: start
+        ``jax.profiler`` in the process that holds it (the engine's
+        ``start_trace``: device planes plus the ``engine.*`` host
+        annotations, ``trace_start`` in the event log with the round
+        number). Reachable through the serve handle
+        (``handle.start_trace.remote(dir)``). One engine only: a pool
+        of replicas shares the process and the profiler, so trace it
+        with ``ray_tpu._private.profiling.start_device_trace``."""
+        return self._trace_engine().start_trace(log_dir)
+
+    def stop_trace(self):
+        """Stop the trace and write it; returns its span (t0, t1) on
+        time.monotonic(). RuntimeError when none is running."""
+        return self._trace_engine().stop_trace()
+
+    def _trace_engine(self):
+        from ray_tpu.serve.engine import LLMEngine
+        eng = self.engine()
+        if not isinstance(eng, LLMEngine):
+            raise RuntimeError(
+                "start_trace/stop_trace drive one engine; this "
+                f"deployment runs a {type(eng).__name__}")
+        return eng
+
     def autoscaler(self):
         """The attached PoolAutoscaler (None until the lazy engine is
         built or when autoscale=False). Disaggregated deployments

@@ -1,0 +1,321 @@
+"""What the engine says about its own work: the ``round`` event's keys,
+the compile counter, the device-trace control and its ``engine.*``
+annotations, and the program names the benchmark's readers key on.
+
+Each engine here gets a model configuration no other test file uses:
+the jitted step programs are shared per process by (model, knobs), and
+a program another file already compiled would not count as built.
+"""
+import glob
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu._private import profiling
+from ray_tpu.models.llama import Llama, llama_tiny
+from ray_tpu.serve.engine import LLMEngine
+
+
+def _model(vocab):
+    cfg = llama_tiny(dtype=jnp.float32, vocab_size=vocab)
+    model = Llama(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
+    return model, params
+
+
+def _engine(vocab, **kw):
+    model, params = _model(vocab)
+    opts = dict(max_slots=4, page_size=8, n_pages=65, chunk=4,
+                prefill_chunk=16)
+    opts.update(kw)
+    return LLMEngine(model, params, **opts).start()
+
+
+def _events(eng, *kinds):
+    return [e for e in eng.events.snapshot() if e[2] in kinds]
+
+
+# ----------------------------------------------------- the round event
+
+@pytest.fixture(scope="module")
+def rounds():
+    """One engine's whole event log over a few mixed requests, grouped
+    by round: [(round event's data, the prefill and decode events
+    since the round before)]."""
+    eng = _engine(251)
+    try:
+        hs = [eng.submit(list(range(1, 4 + 5 * i)), max_new_tokens=6 + i)
+              for i in range(5)]
+        for h in hs:
+            h.result()
+    finally:
+        eng.shutdown()
+    out, since = [], []
+    for e in eng.events.snapshot():
+        if e[2] in ("prefill", "decode"):
+            since.append(e)
+        elif e[2] == "round":
+            out.append((e[5], since))
+            since = []
+    assert len(out) >= 4
+    return out
+
+
+NEW_KEYS = ("round", "admit_s", "plan_s", "dispatch_s", "readback_s",
+            "decode_riders", "decode_steps", "prefill_tokens",
+            "prefill_budget")
+
+
+@pytest.mark.parametrize("case", ["keys", "phases_within_wall",
+                                  "numbered", "prefill_agrees",
+                                  "decode_agrees", "budget"])
+def test_round_event_says_what_the_round_was(rounds, case):
+    if case == "keys":
+        for data, _ in rounds:
+            assert {"host_gap_s", "wall_s", "overlap", *NEW_KEYS} \
+                <= set(data)
+    elif case == "phases_within_wall":
+        for data, _ in rounds:
+            parts = (data["admit_s"] + data["plan_s"]
+                     + data["dispatch_s"] + data["readback_s"])
+            assert min(data[k] for k in NEW_KEYS[1:5]) >= 0
+            assert parts <= data["wall_s"] + 4e-6     # 6-digit rounding
+    elif case == "numbered":
+        numbers = [data["round"] for data, _ in rounds]
+        assert numbers == sorted(set(numbers)) and numbers[0] >= 1
+    elif case == "prefill_agrees":
+        for data, since in rounds:
+            granted = sum(take for e in since if e[2] == "prefill"
+                          for _sid, take in e[5])
+            assert data["prefill_tokens"] == granted
+        assert any(data["prefill_tokens"] for data, _ in rounds)
+    elif case == "decode_agrees":
+        for data, since in rounds:
+            steps = [e[5] for e in since if e[2] == "decode"]
+            assert data["decode_steps"] == sum(steps)
+            assert (data["decode_riders"] > 0) == bool(steps)
+            assert data["decode_riders"] <= 4
+        assert any(data["decode_riders"] > 1 for data, _ in rounds)
+    else:
+        for data, _ in rounds:
+            assert data["prefill_budget"] == 16
+            assert data["prefill_tokens"] <= data["prefill_budget"]
+
+
+# -------------------------------------------------- the compile counter
+
+@pytest.mark.parametrize("case", ["warm_up_counts", "one_more",
+                                  "one_event", "none_when_warm"])
+def test_a_new_prefill_width_is_one_compile_event(case):
+    eng = _engine(241 + ["warm_up_counts", "one_more", "one_event",
+                         "none_when_warm"].index(case),
+                  prefill_chunk=32)
+    try:
+        eng.submit([1, 2, 3, 4, 5], max_new_tokens=4).result()  # T=8
+        assert eng.wait_idle(10)
+        warm = eng.stats["programs_built"]
+        seen = len(_events(eng, "compile"))
+        if case == "warm_up_counts":
+            names = {e[5]["program"] for e in _events(eng, "compile")}
+            # (jit_seed is one program per process, built by whichever
+            # engine came first)
+            assert {"jit_prefill", "jit_decode"} <= names
+            assert warm >= 2
+            return
+        if case == "none_when_warm":
+            eng.submit([5, 4, 3, 2, 1, 7], max_new_tokens=4).result()
+            assert eng.wait_idle(10)
+            assert eng.stats["programs_built"] == warm
+            assert len(_events(eng, "compile")) == seen
+            return
+        eng.submit(list(range(1, 21)), max_new_tokens=4).result()  # T=32
+        assert eng.wait_idle(10)
+        new = _events(eng, "compile")[seen:]
+        if case == "one_more":
+            assert eng.stats["programs_built"] == warm + 1
+        else:
+            assert len(new) == 1
+            data = new[0][5]
+            assert data["program"] == "jit_prefill"
+            assert data["built"] == 1 and data["wall_s"] > 0
+            at = [e[5] for e in _events(eng, "round")
+                  if e[5]["round"] == data["round"]]
+            assert len(at) == 1 and at[0]["prefill_tokens"] == 20
+    finally:
+        eng.shutdown()
+
+
+# ---------------------------------------------------- the trace control
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """A traced burst on one engine, and what the trace and the event
+    log hold of it."""
+    from jax.profiler import ProfileData
+    log_dir = str(tmp_path_factory.mktemp("trace"))
+    eng = _engine(239)
+    facts = {}
+    try:
+        eng.submit([1, 2, 3], max_new_tokens=4).result()      # warm
+        try:
+            eng.stop_trace()
+        except RuntimeError as e:
+            facts["stop_without_start"] = str(e)
+        t0 = eng.start_trace(log_dir)
+        try:
+            eng.start_trace(log_dir)
+        except RuntimeError as e:
+            facts["second_start"] = str(e)
+        for h in [eng.submit(list(range(1, 5 + i)), max_new_tokens=6)
+                  for i in range(4)]:
+            h.result()
+        facts["span"] = eng.stop_trace()
+        facts["t0"] = t0
+        try:
+            eng.stop_trace()
+        except RuntimeError as e:
+            facts["second_stop"] = str(e)
+    finally:
+        eng.shutdown()
+    facts["marks"] = _events(eng, "trace_start", "trace_stop")
+    facts["rounds"] = [e[5]["round"] for e in _events(eng, "round")]
+    files = glob.glob(log_dir + "/plugins/profile/*/*.xplane.pb")
+    facts["files"] = files
+    host = {}
+    for plane in ProfileData.from_file(files[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(("engine.", "PjitFunction(")):
+                    host.setdefault(ev.name, []).append(dict(ev.stats))
+    facts["host"] = host
+    return facts
+
+
+@pytest.mark.parametrize("case", [
+    "writes_xplane", "engine.plan", "engine.readback", "engine.admit",
+    "engine.drain_ready", "engine.dispatch_prefill",
+    "engine.dispatch_decode", "second_start_refused",
+    "stop_without_start_is_an_error", "marks_carry_the_round",
+    "program_names_in_the_trace"])
+def test_start_and_stop_trace(traced, case):
+    if case == "writes_xplane":
+        assert len(traced["files"]) == 1
+        t0, t1 = traced["span"]
+        assert t0 == traced["t0"] and t1 > t0
+        assert "no device trace" in traced["second_stop"]
+    elif case.startswith("engine."):
+        stats = traced["host"].get(case)
+        assert stats, sorted(traced["host"])
+        # every annotation names its round, and the rounds are ones
+        # the event log has too: the two join without mapping clocks
+        assert all("round" in s for s in stats)
+        assert {int(s["round"]) for s in stats} & set(traced["rounds"])
+    elif case == "second_start_refused":
+        assert "already running" in traced["second_start"]
+    elif case == "stop_without_start_is_an_error":
+        assert "no device trace" in traced["stop_without_start"]
+    elif case == "marks_carry_the_round":
+        start, stop = traced["marks"]
+        assert start[2] == "trace_start" and stop[2] == "trace_stop"
+        assert start[1] == traced["span"][0]
+        assert stop[1] == traced["span"][1]
+        assert 1 <= start[5]["round"] < stop[5]["round"]
+        assert stop[5]["span_s"] == pytest.approx(
+            traced["span"][1] - traced["span"][0], abs=1e-5)
+    else:
+        assert "PjitFunction(decode)" in traced["host"]
+        assert "PjitFunction(prefill)" in traced["host"]
+
+
+def test_module_control_refuses_and_recovers(tmp_path):
+    with pytest.raises(RuntimeError):
+        profiling.stop_device_trace()
+    profiling.start_device_trace(str(tmp_path))
+    with pytest.raises(RuntimeError):
+        profiling.start_device_trace(str(tmp_path))
+    t0, t1 = profiling.stop_device_trace()
+    assert t1 >= t0
+    with pytest.raises(RuntimeError):
+        profiling.stop_device_trace()
+
+
+# ---------------------------------- the programs' names are an interface
+
+@pytest.mark.parametrize("program", ["jit_decode", "jit_prefill",
+                                     "jit_step_fn"])
+def test_program_names_the_benchmark_keys_on(program):
+    """benchmarks/metrics/decode_step_ms.py, decode_roofline.py,
+    flash_roofline.py and the ledger's idle-gap labels find the
+    programs by these names: renaming the functions empties a metric
+    without failing anything else."""
+    if program == "jit_step_fn":
+        import optax
+        from ray_tpu.train.spmd import TrainState, make_train_step
+        opt = optax.sgd(0.1)
+        step = make_train_step(lambda p, b: (p["w"] * b["x"]).sum(), opt)
+        state = TrainState.create({"w": jnp.ones((4,))}, opt)
+        text = step.lower(state, {"x": jnp.ones((4,))}).as_text()
+        assert "module @jit_step_fn" in text
+        return
+    from ray_tpu.serve import engine as engine_mod
+    model, _ = _model(251)
+    fn = {"jit_decode": lambda: engine_mod._jit_decode(
+              model, 0.0, 128, 4, False, None),
+          "jit_prefill": lambda: engine_mod._jit_prefill(
+              model, 0.0, 4, False, None)}[program]()
+    assert "jit_" + fn.__name__ == program
+
+
+# ------------------------------------------- the cost with tracing off
+
+def test_closed_annotations_cost_nothing_measurable():
+    """Six a round, each under a microsecond with no trace running
+    (0.7 us here, PERF.md section 6): 10,000 stay under 50 ms even on
+    a loaded test host."""
+    from jax.profiler import TraceAnnotation
+    best = float("inf")
+    for _ in range(3):
+        t = time.perf_counter()
+        for i in range(10_000):
+            with TraceAnnotation("engine.plan", round=i):
+                pass
+        best = min(best, time.perf_counter() - t)
+    assert best < 0.05
+
+
+# ------------------------- scopes are part of a step program's identity
+
+@pytest.mark.parametrize("keyed", [False, True],
+                         ids=["default_key_ignores_scopes",
+                              "metadata_keyed_sees_scopes"])
+def test_persistent_cache_key_and_named_scopes(keyed):
+    """The persistent cache strips debug information from its key, so
+    an executable loaded from it names its operations as whoever
+    compiled it did; inside ``metadata_keyed`` (the engine's step())
+    a changed scope is another program."""
+    import contextlib
+    import hashlib
+    from jax._src import cache_key
+    from ray_tpu.util.compile_cache import metadata_keyed
+
+    def build(scope):
+        def step(x):
+            with jax.named_scope(scope):
+                return x * 2
+        return step
+
+    def key_of(scope):
+        with (metadata_keyed() if keyed else contextlib.nullcontext()):
+            module = jax.jit(build(scope)).lower(
+                jnp.ones((4,))).compiler_ir()
+            h = hashlib.sha256()
+            cache_key._hash_computation(
+                h, module, cache_key.IgnoreCallbacks.NO)
+        return h.hexdigest()
+
+    assert (key_of("attn_scores") != key_of("attn_pv")) == keyed
