@@ -27,7 +27,10 @@ from ray_tpu.ops.paged_attention import paged_decode_attention
 
 
 def _use_paged_kernel() -> bool:
-    """Paged decode attention backend: default is the XLA gather.
+    """Paged decode attention backend: default is the XLA gather
+    (_paged_window_attention: blocks of pages up to the longest live
+    context; the measurements below predate it and gathered the whole
+    page table's width, L).
     Measured on a v5e chip, 1.1B bf16, 16 slots, L=256, full decode
     step (dense floor 3.5ms): standalone the pallas kernel wins at
     page_size 64 (3.6ms vs gather 8.2ms), but INSIDE the engine's
@@ -124,6 +127,133 @@ class RMSNorm(nn.Module):
         return (norm * scale).astype(x.dtype)
 
 
+# Tokens of context one iteration of the paged window loop gathers and
+# attends (rounded to whole pages): the unit in which the attended
+# window follows the live contexts. Smaller blocks waste less on the
+# last, partly filled block and pay the loop's fixed cost more often
+# (PERF.md section 6, PR 26 has the chip's readings).
+_WINDOW_BLOCK_TOKENS = 512
+
+
+def paged_window_block_pages(page_size: int, max_pages: int) -> int:
+    """Logical pages one iteration of the paged window loop covers: a
+    constant of the shapes, not a knob."""
+    return min(max_pages, max(1, _WINDOW_BLOCK_TOKENS // page_size))
+
+
+def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
+    """Causal grouped-query attention of ``q`` [B, T, H, D] (row b's
+    queries at absolute positions ``pos[b] + t``) over its page-table
+    row's K/V in the head-major pool ``pk``/``pv`` [KH, n_pages, Pg, D]
+    (``sk``/``sv``: an int8 pool's per-page scales, else None).
+
+    Work follows the live contexts, not the table's width: a loop with
+    a RUNTIME trip count walks blocks of ``block_pages`` logical pages
+    up to the block holding the last position any live row can see,
+    folding each block's float32 scores into a running max / sum /
+    accumulator (the online softmax: the same mathematics as one
+    softmax over the whole window, nothing approximated, no visible
+    position left out). A live row is one whose page-table row is not
+    the null row: non-riders and dummy prefill rows carry rows of 0,
+    and their ``pos`` may be stale and large, so they must not widen
+    the window. The count is a value, not a shape: one executable
+    serves every context length, and inside the decode loop it is
+    recomputed every step, so a context that crosses a block's edge in
+    the middle of a dispatch is still attended whole.
+
+    The named scopes (kv_gather, attn_scores, attn_pv) are metadata
+    only: a device trace splits a step's time by them (PERF.md
+    section 3).
+    """
+    B, T, H, D = q.shape
+    KH, _, Pg, _ = pk.shape
+    max_pages = page_table.shape[1]
+    block_pages = paged_window_block_pages(Pg, max_pages)
+    Lb = block_pages * Pg
+    max_blocks = -(-max_pages // block_pages)
+    # Grouped-query attention WITHOUT materializing repeated K/V: q
+    # reshapes to [B, T, KH, rep, D] and contracts against the grouped
+    # cache directly (a repeat would move rep x the KV bytes a step).
+    qg = q.reshape(B, T, KH, H // KH, D).astype(jnp.float32)
+    # causal over absolute positions: query t of row b sits at
+    # pos[b] + t and sees keys 0..pos[b]+t
+    q_pos = pos[:, None] + jnp.arange(T)[None]              # [B, T]
+    with jax.named_scope("kv_gather"):
+        # a whole number of blocks: columns past the table are null
+        # pages, which the mask never lets a live query see
+        table = jnp.pad(
+            page_table,
+            ((0, 0), (0, max_blocks * block_pages - max_pages)))
+        live = page_table[:, 0] != 0
+        last = jnp.max(jnp.where(live, pos + (T - 1), 0))
+        n_blocks = jnp.minimum(last // Lb + 1, max_blocks)
+
+    # The pool is head-major for the Pallas kernel; here each page is
+    # gathered whole, so the loop reads a page-major VIEW of it,
+    # [n_pages, Pg, KH, D]. On the TPU that is the layout XLA keeps the
+    # loop-carried pool in (it suits paged_append's scatter), so the
+    # view is free and the pool enters the loop as it lies; gathered
+    # through its head-major shape the compiler re-laid the whole pool
+    # out for the loop, every layer of every step (PERF.md, PR 26).
+    pk_t = pk.transpose(1, 2, 0, 3)
+    pv_t = pv.transpose(1, 2, 0, 3)
+    if sk is not None:
+        sk_t = sk[..., 0].T                                  # [n_pages, KH]
+        sv_t = sv[..., 0].T
+
+    def block(j, carry):
+        m, l, acc = carry
+        with jax.named_scope("kv_gather"):
+            cols = jax.lax.dynamic_slice_in_dim(
+                table, j * block_pages, block_pages, axis=1)
+            # [B, block_pages, Pg, KH, D] -> [B, Lb, KH, D]; gathered
+            # index + j * Lb == logical position by construction
+            kg = pk_t[cols]
+            vg = pv_t[cols]
+            if sk is not None:
+                # dequantize the gathered block in fp32 with the
+                # gathered per-page scales (value = q * s / 127): only
+                # one block ever exists in fp, never the pool itself
+                kg = kg.astype(jnp.float32) * \
+                    (sk_t[cols] * (1.0 / 127.0))[:, :, None, :, None]
+                vg = vg.astype(jnp.float32) * \
+                    (sv_t[cols] * (1.0 / 127.0))[:, :, None, :, None]
+            kg = kg.reshape(B, Lb, KH, D)
+            vg = vg.reshape(B, Lb, KH, D)
+        with jax.named_scope("attn_scores"):
+            s = jnp.einsum("btkrd,bskd->bkrts", qg,
+                           kg.astype(jnp.float32)) / np.sqrt(D)
+            valid = (j * Lb + jnp.arange(Lb))[None, None] <= \
+                q_pos[:, :, None]                            # [B, T, Lb]
+            s = jnp.where(valid[:, None, None], s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            scale = jnp.exp(m - m_new)
+            l = l * scale + jnp.sum(p, axis=-1)
+        with jax.named_scope("attn_pv"):
+            acc = acc * scale[..., None] + jnp.einsum(
+                "bkrts,bskd->bkrtd", p.astype(vg.dtype), vg,
+                preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    stat = (B, KH, H // KH, T)
+    carry = (jnp.full(stat, -1e30, jnp.float32),
+             jnp.zeros(stat, jnp.float32),
+             jnp.zeros(stat + (D,), jnp.float32))
+    if max_blocks == 1:
+        # the table is one block wide: no loop, the one-shot softmax
+        # over the whole window as straight-line code
+        carry = block(0, carry)
+    else:
+        carry = jax.lax.fori_loop(0, n_blocks, block, carry)
+    _, l, acc = carry
+    with jax.named_scope("attn_pv"):
+        # key 0 is visible to every query, so l > 0
+        y = (acc / l[..., None]).astype(q.dtype)
+    # [B, KH, rep, T, D] -> [B, T, H, D]
+    return y.transpose(0, 3, 1, 2, 4).reshape(B, T, H, D)
+
+
 class LlamaAttention(nn.Module):
     config: LlamaConfig
 
@@ -161,7 +291,6 @@ class LlamaAttention(nn.Module):
             # host-side, so no lax.cond is needed.
             pc = kv_cache
             pos = cache_len                       # [B] int32
-            Pg = pc.page_size
             from ray_tpu.ops.paged_attention import paged_append
             # The named scopes below (kv_append, then attn_kernel or
             # kv_gather, attn_scores, attn_pv) are metadata only: a device trace
@@ -193,50 +322,11 @@ class LlamaAttention(nn.Module):
                         q[:, 0], pk, pv, pc.page_table, pos, sk, sv)
                 y = y.reshape(B, 1, cfg.n_heads, hd)
             else:
-                # CPU/XLA fallback and chunk prefill: gather the page
-                # window dense. [KH, B, max_pages, Pg, D] ->
-                # [KH, B, L, D]; gathered index == logical sequence
-                # position by construction.
-                L = pc.page_table.shape[1] * Pg
-                with jax.named_scope("kv_gather"):
-                    kg = pk[:, pc.page_table]
-                    vg = pv[:, pc.page_table]
-                    if sk is not None:
-                        # dequantize the gathered window in fp32 using
-                        # the gathered per-page scales (value = q * s /
-                        # 127) — only the per-step [B, L] window ever
-                        # exists in fp, never the pool itself
-                        skg = sk[:, pc.page_table]  # [KH, B, MP, 1]
-                        svg = sv[:, pc.page_table]
-                        kg = kg.astype(jnp.float32) * \
-                            (skg * (1.0 / 127.0))[..., None]
-                        vg = vg.astype(jnp.float32) * \
-                            (svg * (1.0 / 127.0))[..., None]
-                    kg = kg.reshape(cfg.n_kv_heads, B, L, hd)
-                    vg = vg.reshape(cfg.n_kv_heads, B, L, hd)
-                # Grouped-query attention WITHOUT materializing
-                # repeated K/V: q reshapes to [B, T, KH, rep, D] and
-                # contracts against the grouped cache directly — at
-                # rep=8 (1.1B) a repeat would move 8x the KV bytes
-                # per step, the decode hot loop's dominant traffic.
-                rep = cfg.n_heads // cfg.n_kv_heads
-                qg = q.reshape(B, -1, cfg.n_kv_heads, rep, hd)
-                with jax.named_scope("attn_scores"):
-                    scores = jnp.einsum(
-                        "btkrd,kbsd->bkrts", qg.astype(jnp.float32),
-                        kg.astype(jnp.float32)) / np.sqrt(hd)
-                    # causal over absolute positions: query t of slot
-                    # b sits at pos[b] + t and sees keys 0..pos[b]+t
-                    q_pos = pos[:, None] + jnp.arange(T)[None]  # [B, T]
-                    valid = jnp.arange(L)[None, None] <= \
-                        q_pos[:, :, None]                    # [B, T, L]
-                    scores = jnp.where(valid[:, None, None],
-                                       scores, -1e30)
-                    probs = jax.nn.softmax(scores, axis=-1)
-                with jax.named_scope("attn_pv"):
-                    y = jnp.einsum("bkrts,kbsd->btkrd",
-                                   probs.astype(vg.dtype), vg)
-                y = y.reshape(B, -1, cfg.n_heads, hd)
+                # CPU/XLA fallback and chunk prefill: gather and
+                # attend over the batch's longest live context, a
+                # block of pages at a time (_paged_window_attention).
+                y = _paged_window_attention(
+                    q, pk, pv, sk, sv, pc.page_table, pos)
         elif kv_cache is not None:
             # Decode path: append this step's K/V into the static cache.
             ck, cv = kv_cache

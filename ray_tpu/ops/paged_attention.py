@@ -2,9 +2,10 @@
 
 The continuous-batching engine's decode step attends each slot's
 single query against its KV pages. The XLA fallback (models/llama.py
-paged branch) GATHERS the whole page window into a dense
-[B, L, KH, D] tensor every step — at L=2048 that is the dominant HBM
-traffic of the decode loop. This kernel never materializes the
+_paged_window_attention) GATHERS the pages into dense [B, L, KH, D]
+blocks every step, up to the batch's longest live context (it once
+gathered the whole page table's width, the dominant HBM traffic of
+the decode loop). This kernel never materializes the
 window: the page table rides scalar prefetch
 (pltpu.PrefetchScalarGridSpec) and each grid step DMAs exactly one
 physical page per (slot, kv-head), accumulating flash-style online

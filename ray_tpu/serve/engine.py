@@ -404,7 +404,9 @@ class _Slot:
 def _new_round_info() -> Dict[str, int]:
     """What a round dispatched, as its ``round`` event reports it."""
     return {"decode_riders": 0, "decode_steps": 0,
-            "prefill_tokens": 0, "prefill_budget": 0}
+            "decode_window_tokens": 0,
+            "prefill_tokens": 0, "prefill_budget": 0,
+            "prefill_window_tokens": 0}
 
 
 class LLMEngine:
@@ -572,11 +574,17 @@ class LLMEngine:
         # it explicitly — batch decode tolerates longer syncs.
         self.KMAX = (max(chunk, 128) if max_run_ahead is None
                      else max(chunk, int(max_run_ahead)))
-        # Page-table width == the attention gather window (L =
-        # max_pages * page_size per slot), so cap it at what the model
-        # can legally address rather than the whole pool.
+        # Page-table width: the most a slot can address, so cap it at
+        # what the model can legally address rather than the whole
+        # pool. The attention programs gather and attend only up to
+        # the batch's longest live context, in blocks of
+        # ``_window_block`` tokens (models/llama.py
+        # _paged_window_attention); the width bounds that window.
         self.max_pages = min(n_pages - 1,
                              -(-self.cfg.max_seq_len // page_size))
+        from ray_tpu.models.llama import paged_window_block_pages
+        self._window_block = page_size * paged_window_block_pages(
+            page_size, self.max_pages)
         # KV storage dtype: "fp" (cfg.dtype pages, PR 1-14 behavior)
         # or "int8" (quantized pages + per-page scales, half the page
         # bytes -> double the pages at a fixed byte budget). The env
@@ -2509,6 +2517,18 @@ class LLMEngine:
                                           else LANE_ONLINE)})
         self._wait.appendleft(slot.req)   # front: re-admit first
 
+    def _note_window(self, key: str, end: int) -> None:
+        """Record under ``key`` the positions a dispatch's paged
+        attention gathers and attends when its longest live row's last
+        query sits at ``end - 1``: ``end`` rounded up to whole blocks,
+        inside the table's width. The round event keeps the round's
+        widest, ``stats`` the sum over dispatches. The host knows every
+        row's position, so this costs no readback."""
+        blk = self._window_block
+        window = min(-(-end // blk) * blk, self.max_pages * self.Pg)
+        self._round_info[key] = max(self._round_info[key], window)
+        self.stats[key] += window
+
     def _dispatch_chunk_locked(self, steps: int):
         """Launch one decode dispatch of ``steps`` steps
         asynchronously. The full carry — pages, per-slot write
@@ -2546,6 +2566,10 @@ class LLMEngine:
         self._fetchq.append((toks, riders, steps))
         self._round_info["decode_riders"] = len(riders)
         self._round_info["decode_steps"] = steps
+        # slot.pos already counts this dispatch: the window its LAST
+        # step attends (the program widens it step by step)
+        self._note_window("decode_window_tokens",
+                          max(slot.pos for _i, slot, _t in riders))
         self.events.append("decode", data=steps)
         self.stats["chunks"] += 1
         self.stats["decode_steps"] += steps
@@ -2660,6 +2684,8 @@ class LLMEngine:
         self._hb = time.monotonic()  # verify completed: progress
         self._round_info["decode_riders"] = len(rows)
         self._round_info["decode_steps"] = 1   # one verify forward
+        self._note_window("decode_window_tokens",
+                          max(slot.pos for _i, slot, _d in rows) + T)
         m = spec_decode.metrics()
         self.stats["spec_rounds"] += 1
         # surviving slots' device decode state is reseeded with the
@@ -3003,6 +3029,9 @@ class LLMEngine:
         _granted = sum(take for _ix, _s, take in rows)
         self.stats["prefill_tokens"] += _granted
         self._round_info["prefill_tokens"] += _granted
+        # every row's queries run to start + T, padding and all
+        self._note_window("prefill_window_tokens",
+                          int(start[:len(rows)].max()) + T)
         self.stats["prefilled_seqs"] += len(placements)
         self._hb = time.monotonic()   # dispatch completed: a long
                                       # prompt prefilling chunk by
