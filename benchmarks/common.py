@@ -40,6 +40,22 @@ def find_named(entries: List[Dict[str, Any]], name: str, what: str):
                      f"{[e['name'] for e in entries]}")
 
 
+def load_rehearsal_cell(name: str) -> Dict[str, Any]:
+    """benchmarks/rehearsal/cells/<name>.json: a toy cell for
+    ``--rehearse``, one file each, with a ``workloads`` entry's keys,
+    ``metrics_as`` (the real cell whose metric lists it borrows) and
+    ``reports`` (the per-layer metrics a CPU run of it must show: the
+    tests' expectation, read by nothing else)."""
+    path = os.path.join(HERE, "rehearsal", "cells", name + ".json")
+    if not os.path.exists(path):
+        have = sorted(f[:-5] for f in os.listdir(os.path.dirname(path))
+                      if f.endswith(".json"))
+        raise SystemExit(f"benchmarks: no rehearsal cell named {name!r};"
+                         f" have {have}")
+    with open(path) as f:
+        return json.load(f)
+
+
 def metrics_of_cell(bench: Dict[str, Any], section: str,
                     cell: str) -> List[Dict[str, Any]]:
     """The metrics of ``section`` that this cell reports: those with no
@@ -60,6 +76,42 @@ def load_metric_reader(name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+# what a family file must hold, by the ``kind`` of the configurations
+# that name it (benchmarks/README.md, "A family", says what each is)
+FAMILY_ATTRS = {
+    "serve": ("program_config", "model", "init_params",
+              "reference_weights", "reference_logits",
+              "kv_bytes_per_token", "decode_step_bytes"),
+    "train": ("program_config", "model", "init_params", "loss_fn",
+              "sharding_rules", "reference_loss_and_grad_norm",
+              "train_flops_per_token", "attention_shape"),
+}
+
+
+def load_family(name: str, kind: str):
+    """benchmarks/families/<name>.py, found by a configuration file's
+    ``family`` key as a reader is found by its metric's name: the one
+    place that knows the model's classes, its seeded weights, its plain
+    reference and its byte and FLOP counts. A file that lacks what its
+    ``kind`` of runner asks for is refused here, not in the window."""
+    path = os.path.join(HERE, "families", name + ".py")
+    if not os.path.exists(path):
+        raise SystemExit(f"benchmarks: family {name!r} has no file at "
+                         f"{path}")
+    if kind not in FAMILY_ATTRS:
+        raise SystemExit(f"benchmarks: unknown kind {kind!r}")
+    spec = importlib.util.spec_from_file_location(
+        "benchmarks.families." + name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [a for a in FAMILY_ATTRS[kind] if not hasattr(mod, a)]
+    if missing:
+        raise SystemExit(f"benchmarks: family {name!r} serves no "
+                         f"{kind!r} configuration: {path} lacks "
+                         f"{missing}")
+    return mod
 
 
 def peaks_for(device_kind: str) -> Dict[str, float]:

@@ -21,12 +21,10 @@ def read(run):
                    if rec[2] == "custom-call")
     if not mod or not mod["runs"] or kernel_s <= 0:
         return None
-    cfg = run.cfg
-    per_chip_batch = run.batch / run.chips
-    shape = (per_chip_batch, run.seq, cfg["n_head"],
-             cfg["n_embd"] // cfg["n_head"])
-    flops = cfg["n_layer"] * costs.flash_causal_flops(*shape)
-    nbytes = cfg["n_layer"] * costs.flash_bytes(*shape)
+    layers, heads, head_dim = run.family.attention_shape(run.cfg)
+    shape = (run.batch / run.chips, run.seq, heads, head_dim)
+    flops = layers * costs.flash_causal_flops(*shape)
+    nbytes = layers * costs.flash_bytes(*shape)
     least_s = mod["runs"] * max(flops / run.peaks["bf16_flops"],
                                 nbytes / run.peaks["hbm_bytes_per_s"])
     return 100.0 * least_s / kernel_s

@@ -47,28 +47,37 @@ def rotary(x, theta):
                             x2 * cos + x1 * sin], axis=-1)
 
 
+def attention(x, w, *, n_heads, n_kv_heads, eps, theta):
+    """x [B, T, D] float32 plus the block's causal grouped-query
+    attention of its pre-norm; ``w`` float32. Not jitted, and no
+    precision set: it is a part of ``layer`` (and of another family's
+    block that shares this attention)."""
+    B, T, D = x.shape
+    hd = w["wq"].shape[1] // n_heads
+    h = rms_norm(x, w["attn_norm"], eps)
+    q = (h @ w["wq"]).reshape(B, T, n_heads, hd)
+    k = (h @ w["wk"]).reshape(B, T, n_kv_heads, hd)
+    v = (h @ w["wv"]).reshape(B, T, n_kv_heads, hd)
+    q, k = rotary(q, theta), rotary(k, theta)
+    rep = n_heads // n_kv_heads
+    k = jnp.repeat(k, rep, axis=2)          # query head j reads
+    v = jnp.repeat(v, rep, axis=2)          # kv head j // rep
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(F32(hd))
+    causal = jnp.tril(jnp.ones((T, T), bool))
+    s = jnp.where(causal[None, None], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    a = jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(B, T, n_heads * hd)
+    return x + a @ w["wo"]
+
+
 @functools.partial(jax.jit, static_argnames=("n_heads", "n_kv_heads",
                                              "eps", "theta"))
 def layer(x, w, *, n_heads, n_kv_heads, eps, theta):
     """One decoder block on x [B, T, D] float32."""
     with jax.default_matmul_precision("highest"):
         w = jax.tree_util.tree_map(lambda a: a.astype(F32), w)
-        B, T, D = x.shape
-        hd = w["wq"].shape[1] // n_heads
-        h = rms_norm(x, w["attn_norm"], eps)
-        q = (h @ w["wq"]).reshape(B, T, n_heads, hd)
-        k = (h @ w["wk"]).reshape(B, T, n_kv_heads, hd)
-        v = (h @ w["wv"]).reshape(B, T, n_kv_heads, hd)
-        q, k = rotary(q, theta), rotary(k, theta)
-        rep = n_heads // n_kv_heads
-        k = jnp.repeat(k, rep, axis=2)          # query head j reads
-        v = jnp.repeat(v, rep, axis=2)          # kv head j // rep
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(F32(hd))
-        causal = jnp.tril(jnp.ones((T, T), bool))
-        s = jnp.where(causal[None, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        a = jnp.einsum("bhqk,bkhd->bqhd", p, v).reshape(B, T, n_heads * hd)
-        x = x + a @ w["wo"]
+        x = attention(x, w, n_heads=n_heads, n_kv_heads=n_kv_heads,
+                      eps=eps, theta=theta)
         h = rms_norm(x, w["ffn_norm"], eps)
         gate = h @ w["w_gate"]
         x = x + (jax.nn.silu(gate) * (h @ w["w_up"])) @ w["w_down"]
@@ -86,10 +95,12 @@ def _head(x, norm, head, *, eps):
         return rms_norm(x, norm, eps) @ head.astype(F32).T
 
 
-def forward(weights, ids, *, n_heads, n_kv_heads, eps, theta):
-    """ids [B, T] int32 -> logits [B, T, V] float32."""
+def forward(weights, ids, *, eps, block=layer, **sizes):
+    """ids [B, T] int32 -> logits [B, T, V] float32. ``sizes`` are the
+    block's own (n_heads, n_kv_heads, theta); ``block`` is this file's
+    ``layer``, or another family's that shares embedding, final norm
+    and head."""
     x = _embed(weights["embed"], ids)
     for w in weights["layers"]:
-        x = layer(x, w, n_heads=n_heads, n_kv_heads=n_kv_heads,
-                  eps=eps, theta=theta)
+        x = block(x, w, eps=eps, **sizes)
     return _head(x, weights["norm"], weights["head"], eps=eps)

@@ -13,8 +13,10 @@ seconds of the same traffic through the program's own control and
 reports both kinds of metric in one line. Without a TPU, with fewer chips than the
 cell asks, or on a device kind missing from benchmarks/peaks.json, it
 exits non-zero and prints no result. ``--rehearse`` is the only CPU
-path: it runs a toy cell of benchmarks/rehearsal/cells.json, never a
-cell of BENCHMARK.json, and says ``platform: cpu``.
+path: it runs a toy cell of benchmarks/rehearsal/cells/, never a cell
+of BENCHMARK.json, and says ``platform: cpu``. What belongs to a model
+family comes from benchmarks/families/<family>.py, found by the
+configuration file's ``family`` key.
 """
 from __future__ import annotations
 
@@ -41,7 +43,7 @@ def parse(argv=None):
     ap.add_argument("--seconds", type=float, default=None)
     ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--rehearse", action="store_true",
-                    help="CPU, toy cell of rehearsal/cells.json")
+                    help="CPU, toy cell of rehearsal/cells/")
     ap.add_argument("--rate", type=float, default=None,
                     help="knee sweep only: override an open loop's rate")
     ap.add_argument("--dump-trace", default=None,
@@ -61,9 +63,7 @@ def main(argv=None) -> int:
     common.refuse_selectors()
     bench = common.load_benchmark()
     if args.rehearse:
-        cells = common.load_json("rehearsal", "cells.json")
-        cell = common.find_named(cells["workloads"], args.workload,
-                                 "rehearsal cell")
+        cell = common.load_rehearsal_cell(args.workload)
         cfg = common.load_json("rehearsal", cell["config"] + ".json")
         metrics_as = cell["metrics_as"]
     else:
@@ -119,7 +119,8 @@ def main(argv=None) -> int:
     ctx = types.SimpleNamespace(
         args=args, cell=cell, cfg=cfg, traffic=traffic, chips=chips,
         meter=meter, t_process=T_PROCESS, peaks=peaks,
-        trace_dir=trace_dir, rate=args.rate, rehearse=args.rehearse)
+        trace_dir=trace_dir, rate=args.rate, rehearse=args.rehearse,
+        family=common.load_family(cfg["family"], cfg["kind"]))
     if cfg["kind"] == "serve":
         from benchmarks import serve_runner as runner
     elif cfg["kind"] == "train":

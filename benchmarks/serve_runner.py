@@ -4,8 +4,10 @@ ray_tpu.init() -> serve.run() of a deployment wrapping LlamaDeployment
 (the continuous-batching engine), in this process, as a user deploys it;
 clients are threads that call the serve handle and read the stream.
 Everything that differs between cells comes from the configuration file
-(model sizes, deployment arguments, chips) and the traffic file (loop,
-rate or clients, lengths). An open loop's end-to-end metrics are its
+(model sizes, deployment arguments, chips), its family's file
+(benchmarks/families/: the model's classes, seeded weights and plain
+reference, as ``ctx.family``) and the traffic file (loop, rate or
+clients, lengths). An open loop's end-to-end metrics are its
 requests' times at the clients; a closed loop's is the tokens a second
 its clients received over a window of whole engine rounds.
 
@@ -38,58 +40,10 @@ TRACE_SECONDS = 4.0            # a few seconds of the steady window
 CLIENT_STAGGER_S = 0.015
 # a closed loop's window is one of whole rounds (common.whole_rounds_rate):
 # its clients read this long past the nominal end so that the closing
-# burst is whole (a round is 0.5-0.7 s today), and a burst may take the
-# guard to reach all of them
+# burst is whole (a round is 0.16 s since PR 26, 0.5-0.7 s before), and
+# a burst may take the guard to reach all of them
 EDGE_TAIL_S = 3.0
 BURST_GUARD_S = 0.1
-
-
-def llama_config(cfg: Dict[str, Any]):
-    """LlamaConfig from the configuration file's published keys."""
-    import jax.numpy as jnp
-    from ray_tpu.models.llama import LlamaConfig
-    if cfg["hidden_size"] // cfg["num_attention_heads"] != cfg["head_dim"]:
-        raise SystemExit("benchmarks: LlamaConfig derives head_dim as "
-                         "hidden_size / heads; this file disagrees")
-    if cfg.get("sliding_window") is not None:
-        raise SystemExit("benchmarks: the program has no sliding window")
-    if (not cfg["tie_word_embeddings"] and "tie_word_embeddings"
-            not in cfg.get("unsupported_by_program", {})):
-        raise SystemExit("benchmarks: the program ties its output head")
-    dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
-        cfg["torch_dtype"]]
-    return LlamaConfig(
-        vocab_size=cfg["vocab_size"],
-        max_seq_len=cfg["max_position_embeddings"],
-        dim=cfg["hidden_size"], n_layers=cfg["num_hidden_layers"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        hidden_dim=cfg["intermediate_size"],
-        rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        dtype=dtype, param_dtype=dtype)
-
-
-def reference_weights(params, n_layers: int) -> Dict[str, Any]:
-    """The program's flax tree under the plain reference's names. The
-    program has no head of its own, so the reference's (untied) head is
-    handed the embedding: ``correct`` cannot see that the published
-    model has a separate one (the configuration file's
-    ``unsupported_by_program``; PERF.md, Open questions)."""
-    p = params["params"]
-    layers = []
-    for i in range(n_layers):
-        lp = p[f"layers_{i}"]
-        a, f = lp["attention"], lp["feed_forward"]
-        layers.append({
-            "attn_norm": lp["attention_norm"]["scale"],
-            "wq": a["wq"]["kernel"], "wk": a["wk"]["kernel"],
-            "wv": a["wv"]["kernel"], "wo": a["wo"]["kernel"],
-            "ffn_norm": lp["ffn_norm"]["scale"],
-            "w_gate": f["w1"]["kernel"], "w_up": f["w3"]["kernel"],
-            "w_down": f["w2"]["kernel"]})
-    return {"embed": p["tok_embeddings"], "head": p["tok_embeddings"],
-            "norm": p["norm"]["scale"], "layers": layers}
 
 
 class _Client:
@@ -188,38 +142,41 @@ def traced_phase(dep, trace_dir: str, seconds: float, ramp_s: float,
 
 
 def run(ctx) -> types.SimpleNamespace:
-    """ctx: args, cell, cfg, traffic, chips, meter, t_process, peaks,
-    trace_dir, rate (sweep override or None)."""
+    """ctx: args, cell, cfg, family, traffic, chips, meter, t_process,
+    peaks, trace_dir, rate (sweep override or None)."""
     import jax
     import jax.numpy as jnp
 
     import ray_tpu
     from ray_tpu import serve
     from ray_tpu.mesh.sharding import infer_sharding
-    from ray_tpu.models.llama import Llama
     from ray_tpu.serve.llm import LlamaDeployment
     from ray_tpu.serve.sharding import EngineSharding
     from ray_tpu.util.compile_cache import enable_compile_cache
 
     cfg, tr, args, meter = ctx.cfg, ctx.traffic, ctx.args, ctx.meter
+    fam = ctx.family
     dep_args = dict(cfg["deployment"])
     tp = int(dep_args.pop("tensor_parallel", 1))
-    if tp != ctx.chips and not ctx.rehearse:
+    # stays in dep_args: it reaches LlamaDeployment only where the file
+    # sets it
+    ep = int(dep_args.get("expert_parallel", 1))
+    if tp * ep != ctx.chips and not ctx.rehearse:
         raise SystemExit(f"benchmarks: cell asks {ctx.chips} chips, its "
-                         f"configuration shards over {tp}")
+                         f"configuration shards over tensor_parallel "
+                         f"{tp} x expert_parallel {ep}")
     cache_dir = enable_compile_cache()
     log(f"[cache] {cache_dir} before: {cache_report(cache_dir)}")
 
-    lcfg = llama_config(cfg)
-    model = Llama(lcfg)
-    shapes = weights.param_shapes(model)
+    pcfg = fam.program_config(cfg)
+    shapes = weights.param_shapes(fam.model(pcfg))
     shardings = None
-    if tp > 1:
-        es = EngineSharding.build(lcfg, tp=tp,
-                                  devices=jax.devices()[:tp])
+    if tp * ep > 1:
+        es = EngineSharding.build(pcfg, tp=tp, ep=ep,
+                                  devices=jax.devices()[:tp * ep])
         shardings = infer_sharding(shapes, es.rules, es.mesh)
     with Timer("weights from the seed, one call", meter):
-        params = weights.llama_params(shapes, args.seed, shardings)
+        params = fam.init_params(shapes, args.seed, shardings)
 
     # ---- the served path -------------------------------------------
     holder: Dict[str, Any] = {}
@@ -233,7 +190,7 @@ def run(ctx) -> types.SimpleNamespace:
     class Llm:
         def __init__(self):
             self.inner = LlamaDeployment(
-                config=lcfg, params=params, tensor_parallel=tp,
+                config=pcfg, params=params, tensor_parallel=tp,
                 prefix_cache=prefix_cache, **dep_args)
             holder["dep"] = self.inner
 
@@ -280,15 +237,11 @@ def run(ctx) -> types.SimpleNamespace:
     if ids.shape != (len(prompts), P + G):
         raise SystemExit(f"benchmarks: parity output shape {ids.shape}")
     with Timer("parity: plain float32 reference", meter):
-        from benchmarks.reference import llama as ref
-        rw = reference_weights(params, lcfg.n_layers)
+        rw = fam.reference_weights(params, pcfg)
         dev_ids = jnp.asarray(ids)
         if shardings is not None:
             dev_ids = jax.device_put(dev_ids, es.replicated)
-        logits = np.asarray(ref.forward(
-            rw, dev_ids, n_heads=lcfg.n_heads,
-            n_kv_heads=lcfg.n_kv_heads, eps=lcfg.norm_eps,
-            theta=lcfg.rope_theta))
+        logits = np.asarray(fam.reference_logits(rw, dev_ids, pcfg))
     check = parity.margin_rule(logits, ids, P)
     log(f"[correct] margin rule: {check}")
     del logits, rw
@@ -447,7 +400,8 @@ def run(ctx) -> types.SimpleNamespace:
     log(f"[cache] after: {cache_report(cache_dir, top=6)}")
 
     run_ = types.SimpleNamespace(
-        kind="serve", cfg=cfg, traffic=tr, deployment=cfg["deployment"],
+        kind="serve", cfg=cfg, family=fam, traffic=tr,
+        deployment=cfg["deployment"],
         chips=ctx.chips, peaks=ctx.peaks, seconds=float(args.seconds),
         window=(t_open, t_close), clients=clients, measured=measured,
         events=sampler.events, samples=sampler.samples,

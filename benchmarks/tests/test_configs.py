@@ -1,5 +1,7 @@
-"""Each configuration file against its source's published sizes, and
-BENCHMARK.json against the files and the contract's shape."""
+"""Each configuration file against its source's published sizes
+(published/<config>.json: the source's ``config.json`` keys as the
+source or the catalog gives them; a configuration without one fails),
+and BENCHMARK.json against the files and the contract's shape."""
 import json
 import os
 import re
@@ -8,19 +10,6 @@ import pytest
 
 from benchmarks import common
 
-# mistralai/Mistral-7B-v0.3 config.json
-MISTRAL = {"vocab_size": 32768, "hidden_size": 4096,
-           "intermediate_size": 14336, "num_hidden_layers": 32,
-           "num_attention_heads": 32, "num_key_value_heads": 8,
-           "head_dim": 128, "hidden_act": "silu", "rms_norm_eps": 1e-05,
-           "rope_theta": 1000000.0, "sliding_window": None,
-           "max_position_embeddings": 32768,
-           "tie_word_embeddings": False, "torch_dtype": "bfloat16"}
-# openai-community/gpt2 config.json
-GPT2 = {"vocab_size": 50257, "n_positions": 1024, "n_ctx": 1024,
-        "n_embd": 768, "n_layer": 12, "n_head": 12,
-        "activation_function": "gelu_new", "layer_norm_epsilon": 1e-05}
-PUBLISHED = {"mistral-7b-v0.3-d16": MISTRAL, "gpt2-124m": GPT2}
 # what may be cut: depth, and the context a chip's share holds
 REDUCIBLE = {"num_hidden_layers", "max_position_embeddings", "n_layer"}
 WIDTH = re.compile(r"_size$|_dim$|_rank$|^n_embd$|expan|experts_per")
@@ -32,12 +21,22 @@ def bench():
     return common.load_benchmark()
 
 
+def published(config: str):
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "published", config + ".json")
+    assert os.path.exists(path), (
+        f"configuration {config} has no published sizes at {path}")
+    with open(path) as f:
+        return json.load(f)
+
+
 def test_files_hold_the_published_sizes_but_for_reduced(bench):
     for conf in bench["configs"]:
         with open(os.path.join(common.ROOT, conf["file"])) as f:
             cfg = json.load(f)
         assert cfg["reduced"] == conf["reduced"]
-        for key, want in PUBLISHED[conf["name"]].items():
+        source = published(conf["name"])
+        for key, want in source.items():
             if key in conf["reduced"]:
                 assert cfg[key] != want, key
                 assert cfg["reduced_from"][key] == want, key
@@ -49,7 +48,7 @@ def test_files_hold_the_published_sizes_but_for_reduced(bench):
         # and is named, never listed as reduced
         for key in cfg.get("unsupported_by_program", {}):
             assert key not in conf["reduced"]
-            assert cfg[key] == PUBLISHED[conf["name"]][key], key
+            assert cfg[key] == source[key], key
         assert cfg["chips"] in (1, 4)
 
 

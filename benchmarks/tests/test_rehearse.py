@@ -19,13 +19,28 @@ def _run(*args):
         env=env, capture_output=True, text=True, timeout=600)
 
 
-@pytest.mark.parametrize("cell,trace,metric", [
-    ("toy-llama.chat-r80", "0", "ttft_p50_ms itl_p50_ms"),
-    ("toy-llama.chat-r80", "1", "ttft_p95_ms"),
-    ("toy-llama.chat-sat", "1", "kv_peak_share"),
-    ("toy-gpt2.train", "0", "train_tokens_per_s"),
-])
-def test_rehearsal_prints_the_contract_line(cell, trace, metric):
+def _cells():
+    """Every toy cell under rehearsal/cells/: a new file is a new case.
+    ``reports`` lists the per-layer metrics a CPU rehearsal of the cell
+    must show (those that need neither the chip's peaks nor a device
+    in the trace)."""
+    cell_dir = os.path.join(common.HERE, "rehearsal", "cells")
+    return [common.load_rehearsal_cell(f[:-5])
+            for f in sorted(os.listdir(cell_dir)) if f.endswith(".json")]
+
+
+CELLS = {c["name"]: c for c in _cells()}
+BENCH = common.load_benchmark()
+
+
+def _e2e_names(cell):
+    return [m["name"] for m in common.metrics_of_cell(
+        BENCH, "end_to_end", CELLS[cell]["metrics_as"])]
+
+
+@pytest.mark.parametrize("cell,trace", [(c, "0") for c in CELLS] + [
+    ("toy-llama.chat-r80", "1"), ("toy-llama.chat-sat", "1")])
+def test_rehearsal_prints_the_contract_line(cell, trace):
     out = _run("--rehearse", "--workload", cell, "--seed",
                str(2**31 + 11), "--seconds", "3", "--trace", trace)
     assert out.returncode == 0, out.stderr[-2000:]
@@ -35,7 +50,9 @@ def test_rehearsal_prints_the_contract_line(cell, trace, metric):
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
     assert line["device"]["platform"] == "cpu"
-    for name in metric.split():
+    wanted = _e2e_names(cell) if trace == "0" else CELLS[cell]["reports"]
+    assert wanted
+    for name in wanted:
         assert set(line["metrics"][name]) == {"value", "unit"}
     if trace == "0":
         assert line["metrics"]["setup_s"]["value"] > 0
@@ -51,15 +68,8 @@ def _last_line(out):
     return json.loads(lines[0])
 
 
-@pytest.mark.parametrize("cell,e2e,per_layer", [
-    ("toy-llama.chat-r80", "ttft_p50_ms itl_p50_ms setup_s",
-     "ttft_p95_ms queue_wait_p95_ms prefill_in_slot_p50_ms "
-     "prefill_budget_share"),
-    ("toy-llama.chat-sat", "serve_tokens_per_s setup_s",
-     "kv_peak_share host_gap_share decode_riders_mean round_host_ms"),
-    ("toy-gpt2.train", "train_tokens_per_s setup_s", "train_mfu"),
-])
-def test_trace_2_measures_then_traces(cell, e2e, per_layer):
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_trace_2_measures_then_traces(cell):
     out = _run("--rehearse", "--workload", cell, "--seed",
                str(2**31 + 11), "--seconds", "3", "--trace", "2")
     line = _last_line(out)
@@ -68,11 +78,9 @@ def test_trace_2_measures_then_traces(cell, e2e, per_layer):
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0 and line["compiles_in_window"] == 0
     # both kinds of metric side by side, every end-to-end value there
-    for name in e2e.split():
+    for name in _e2e_names(cell):
         assert line["metrics"][name]["value"] > 0, name
-    for name in per_layer.split():
-        if name == "train_mfu":      # needs the chip's peak: not on a CPU
-            continue
+    for name in CELLS[cell]["reports"]:
         assert set(line["metrics"][name]) == {"value", "unit"}, name
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
     assert "[traced phase]" in out.stdout

@@ -27,18 +27,29 @@ from typing import Any, Dict, Iterator, List, Optional, Tuple
 from benchmarks import trace_reduce
 from benchmarks.common import log
 
-# scopes ray_tpu.models.llama.LlamaAttention names around the KV window
-ATTENTION = ("kv_append", "kv_gather", "attn_scores", "attn_pv",
-             "attn_kernel")
-# the rest of a decode step, by the flax module or scope that names it:
-# (part, path components that mean it)
-DENSE = (("projections", ("wq", "wk", "wv", "wo")),
-         ("mlp", ("feed_forward",)),
-         ("norms", ("attention_norm", "ffn_norm", "norm")),
-         ("head", ("head",)),
-         ("sample", ("sample",)),
-         # rope and the reshapes around it: directly under the module
-         ("rope", ("attention",)))
+# The table a program's operations are sorted by. A family file gives
+# its own as ``parts`` (benchmarks/families/); this one serves a family
+# that gives none, and is the Llama block's and GPT-2's loss head's:
+# ``wrapped`` scopes are looked for inside transformations
+# (``transpose(jvp(loss_head))``) and are each their own part;
+# ``attention`` scopes (a paged-attention module's names around the KV
+# window) are each their own part and add up to decode_attn_ms;
+# ``dense`` is (part, path components that mean it), by the flax module
+# or scope that names the rest of a decode step, and adds up to
+# decode_dense_ms. The first match in that order decides.
+DEFAULT_PARTS = {
+    "wrapped": ("loss_head",),
+    "attention": ("kv_append", "kv_gather", "attn_scores", "attn_pv",
+                  "attn_kernel"),
+    "dense": (("projections", ("wq", "wk", "wv", "wo")),
+              ("mlp", ("feed_forward",)),
+              ("norms", ("attention_norm", "ffn_norm", "norm")),
+              ("head", ("head",)),
+              ("sample", ("sample",)),
+              # rope and the reshapes around it: directly under the
+              # module
+              ("rope", ("attention",))),
+}
 
 
 # ------------------------------------------------- protobuf wire format
@@ -189,55 +200,68 @@ def load_json(path: str) -> Dict[str, Any]:
 
 # ---------------------------------------------------------------- split
 
-def part_of(tf_op: str) -> str:
-    """The part a scope path belongs to: one of ATTENTION, one of
-    DENSE's names, ``loss_head``, ``other`` (a path that names none of
-    them) or ``unnamed`` (no metadata). Transformations wrap a scope
-    (``transpose(jvp(loss_head))``), so components match by the name
-    inside."""
+def part_of(tf_op: str, parts: Optional[Dict[str, Any]] = None) -> str:
+    """The part a scope path belongs to by the table ``parts``
+    (DEFAULT_PARTS where none is given): a ``wrapped`` scope, an
+    ``attention`` scope, one of ``dense``'s names, ``other`` (a path
+    that names none of them) or ``unnamed`` (no metadata).
+    Transformations wrap a scope (``transpose(jvp(loss_head))``), so a
+    ``wrapped`` scope matches by the name inside."""
     if not tf_op:
         return "unnamed"
+    parts = parts or DEFAULT_PARTS
     comps = [c.rstrip(":") for c in tf_op.split("/")]
     inner = [c.rsplit("(", 1)[-1].rstrip(")") for c in comps]
-    if "loss_head" in inner:
-        return "loss_head"
-    for name in ATTENTION:
+    for name in parts.get("wrapped", ()):
+        if name in inner:
+            return name
+    for name in parts.get("attention", ()):
         if name in comps:
             return name
-    for part, names in DENSE:
+    for part, names in parts.get("dense", ()):
         if any(n in comps for n in names):
             return part
     return "other"
 
 
-def split(ir: Dict[str, Any], module: str) -> Optional[Dict[str, Any]]:
-    """Self time of ``module``'s operations by part, in seconds:
-    {"runs", "module_s" (its runs' device time), "parts": {part: s},
-    "gaps_s" (module time in which no operation ran)}. None where the
-    trace has no run of the module."""
+def split(ir: Dict[str, Any], module: str,
+          parts: Optional[Dict[str, Any]] = None
+          ) -> Optional[Dict[str, Any]]:
+    """Self time of ``module``'s operations by part (``part_of`` by the
+    table ``parts``), in seconds: {"runs", "module_s" (its runs' device
+    time), "parts": {part: s}, "gaps_s" (module time in which no
+    operation ran)}. None where the trace has no run of the module."""
     spans = sorted((s, s + d) for n, s, d in ir["modules"]
                    if trace_reduce.module_name(n) == module)
     if not spans:
         return None
     ops = sorted(ir["ops"], key=lambda e: (e[1], -e[2]))
     selfs = trace_reduce.self_times([e[:3] for e in ops])
-    parts: Dict[str, float] = {}
+    by_part: Dict[str, float] = {}
     i = 0
     for (_n, start, _d, self_ns), op in zip(selfs, ops):
         while i < len(spans) and spans[i][1] <= start:
             i += 1
         if i < len(spans) and spans[i][0] <= start:
-            p = part_of(op[3])
-            parts[p] = parts.get(p, 0.0) + self_ns / 1e9
+            p = part_of(op[3], parts)
+            by_part[p] = by_part.get(p, 0.0) + self_ns / 1e9
     module_s = sum(e - s for s, e in spans) / 1e9
-    return {"runs": len(spans), "module_s": module_s, "parts": parts,
-            "gaps_s": module_s - sum(parts.values())}
+    return {"runs": len(spans), "module_s": module_s, "parts": by_part,
+            "gaps_s": module_s - sum(by_part.values())}
+
+
+def parts_of_run(run) -> Dict[str, Any]:
+    """The table of the run's family (``run.family.parts``), or
+    DEFAULT_PARTS where the run or its family has none."""
+    return getattr(getattr(run, "family", None), "parts",
+                   None) or DEFAULT_PARTS
 
 
 def for_run(run, module: str) -> Optional[Dict[str, Any]]:
-    """``split`` of the trace under ``run.trace_dir``, read once per
-    run and module; None without one (``--trace 1`` keeps no trace for
-    readers). Logs the ``[parts]`` line."""
+    """``split`` of the trace under ``run.trace_dir`` by the table of
+    the run's family, read once per run and module; None without a
+    trace (``--trace 1`` keeps none for readers). Logs the ``[parts]``
+    line."""
     trace_dir = getattr(run, "trace_dir", None)
     if not trace_dir:
         return None
@@ -248,7 +272,8 @@ def for_run(run, module: str) -> Optional[Dict[str, Any]]:
                 memo["ir"] = load(trace_dir)
             except FileNotFoundError:
                 memo["ir"] = {"ops": [], "modules": []}
-        got = memo[module] = split(memo["ir"], module)
+        got = memo[module] = split(memo["ir"], module,
+                                   parts_of_run(run))
         if got:
             log(f"[parts] {module}: {got['runs']} runs, "
                 f"{got['module_s']:.6f} s; "
@@ -274,9 +299,11 @@ def decode_step_parts(run) -> Optional[Dict[str, float]]:
         run.trace["module_ops"].get("jit_decode", {}), mod["runs"])
     if not steps:
         return None
-    dense_names = [p for p, _ in DENSE]
-    attn = sum(got["parts"].get(p, 0.0) for p in ATTENTION)
-    dense = sum(got["parts"].get(p, 0.0) for p in dense_names)
+    table = parts_of_run(run)
+    attn = sum(got["parts"].get(p, 0.0)
+               for p in table.get("attention", ()))
+    dense = sum(got["parts"].get(p, 0.0)
+                for p, _ in table.get("dense", ()))
     out = memo["decode_step"] = {
         "attn_ms": 1e3 * attn / steps, "dense_ms": 1e3 * dense / steps,
         "rest_ms": 1e3 * (got["module_s"] - attn - dense) / steps,

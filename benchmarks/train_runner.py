@@ -1,7 +1,9 @@
 """The one runner for every configuration of ``kind: train``: the SPMD
 train step exactly as bench.py and examples/02_train_gpt2.py build it
 (shard_state / put_batch / make_train_step on a {"data": -1} mesh), fed
-a fresh seeded batch through put_batch every step. ``--trace 2`` runs
+a fresh seeded batch through put_batch every step. The model, its loss,
+its sharding rules and its plain reference are the configuration's
+family's (benchmarks/families/, as ``ctx.family``). ``--trace 2`` runs
 the same loop on, after the window's numbers are taken, under a trace
 started through the program's control (ray_tpu._private.profiling).
 """
@@ -9,39 +11,12 @@ from __future__ import annotations
 
 import time
 import types
-from typing import Any, Dict
 
-from benchmarks import parity, trafficgen, weights
+from benchmarks import parity, trafficgen
 from benchmarks.common import (Timer, Tracer, cache_report, log,
                                prepare_trace)
 
 TRACE_SECONDS = 3.0
-
-
-def gpt2_config(cfg: Dict[str, Any]):
-    from ray_tpu.models import gpt2_124m
-    if cfg["n_positions"] != cfg["n_ctx"]:
-        raise SystemExit("benchmarks: n_positions != n_ctx")
-    if cfg["activation_function"] != "gelu_new":
-        raise SystemExit("benchmarks: the program's MLP is gelu_new")
-    return gpt2_124m(vocab_size=cfg["vocab_size"], n_ctx=cfg["n_ctx"],
-                     n_embd=cfg["n_embd"], n_layer=cfg["n_layer"],
-                     n_head=cfg["n_head"])
-
-
-def reference_weights(params, n_layer: int) -> Dict[str, Any]:
-    """The program's flax tree under the plain reference's names."""
-    p = params["params"]
-    layers = []
-    for i in range(n_layer):
-        h = p[f"h_{i}"]
-        layers.append({"ln_1": h["ln_1"], "ln_2": h["ln_2"],
-                       "c_attn": h["attn"]["c_attn"],
-                       "attn_proj": h["attn"]["c_proj"],
-                       "c_fc": h["mlp"]["c_fc"],
-                       "mlp_proj": h["mlp"]["c_proj"]})
-    return {"wte": p["wte"], "wpe": p["wpe"], "ln_f": p["ln_f"],
-            "layers": layers}
 
 
 def run(ctx) -> types.SimpleNamespace:
@@ -50,45 +25,35 @@ def run(ctx) -> types.SimpleNamespace:
     import optax
 
     from ray_tpu.mesh import create_mesh
-    from ray_tpu.models import GPT2, gpt2_sharding_rules
-    from ray_tpu.models.gpt2 import linear_cross_entropy
     from ray_tpu.train.spmd import (TrainState, make_train_step,
                                     put_batch, shard_state)
     from ray_tpu.util.compile_cache import enable_compile_cache
 
     cfg, tr, args, meter = ctx.cfg, ctx.traffic, ctx.args, ctx.meter
+    fam = ctx.family
     cache_dir = enable_compile_cache()
     log(f"[cache] {cache_dir} before: {cache_report(cache_dir)}")
-    gcfg = gpt2_config(cfg)
-    model = GPT2(gcfg)
+    model = fam.model(fam.program_config(cfg))
     batch, seq = int(tr["batch"]), int(tr["seq"])
     devices = jax.devices()[:ctx.chips]
     mesh = create_mesh({"data": -1}, devices=devices)
 
-    def loss_fn(params, b):
-        x, y = b["ids"][:, :-1], b["ids"][:, 1:]
-        feats = model.apply(params, x, return_features=True)
-        return linear_cross_entropy(feats, params["params"]["wte"], y)
-
     with Timer("weights from the seed, one call", meter):
         with jax.default_device(devices[0]):
-            params = weights.gpt2_params(model, args.seed)
+            params = fam.init_params(model, args.seed)
     batches = trafficgen.ZipfBatches(tr, args.seed, cfg["vocab_size"])
 
     # step 0 of the reference, on the weights and the batch of step 0,
     # before the train state (which donates them) exists
     with Timer("reference: loss and gradient norm of step 0", meter):
-        from benchmarks.reference import gpt2 as ref
-        ref_loss, ref_gnorm = ref.loss_and_grad_norm(
-            reference_weights(params, gcfg.n_layer),
-            jnp.asarray(batches(0)), n_head=gcfg.n_head,
-            eps=float(cfg["layer_norm_epsilon"]))
+        ref_loss, ref_gnorm = fam.reference_loss_and_grad_norm(
+            params, jnp.asarray(batches(0)), cfg)
 
     tcfg = cfg["train"]
     optimizer = optax.adamw(tcfg["lr"], weight_decay=tcfg["weight_decay"])
     state = shard_state(TrainState.create(params, optimizer),
-                        gpt2_sharding_rules(fsdp=False), mesh)
-    train_step = make_train_step(loss_fn, optimizer)
+                        fam.sharding_rules(), mesh)
+    train_step = make_train_step(fam.loss_fn(model), optimizer)
     in_flight = int(tr.get("in_flight", 2))
     losses, gnorms = [], []
 
@@ -137,7 +102,7 @@ def run(ctx) -> types.SimpleNamespace:
            "setup_s": t_open - ctx.t_process}
     log(f"[cache] after: {cache_report(cache_dir, top=6)}")
     run_ = types.SimpleNamespace(
-        kind="train", cfg=cfg, traffic=tr, chips=ctx.chips,
+        kind="train", cfg=cfg, family=fam, traffic=tr, chips=ctx.chips,
         peaks=ctx.peaks, seconds=float(args.seconds),
         window=(t_open, t_end), e2e=e2e, attempted=steps, failed=0,
         correct=bool(check["ok"]),

@@ -2,9 +2,10 @@
 in the type they are served in and already laid out as the engine will
 shard them. The key is an ARGUMENT: a closed-over key lets XLA fold the
 weights into the executable (a 133.6 MiB cache entry; PERF.md, PR 23).
-``jit(model.init)`` is not used for the served model: it traces and
+``jit(model.init)`` is not used for a served model: it traces and
 compiles the whole forward pass only to throw it away (63 s at 1.1B;
-PERF.md, PR 23).
+PERF.md, PR 23). Which leaf gets which scale is the family's to say
+(benchmarks/families/<family>.py ``init_params``).
 """
 from __future__ import annotations
 
@@ -20,22 +21,20 @@ def param_shapes(model):
                           jnp.zeros((1, 8), jnp.int32))
 
 
-def llama_params(shapes, seed: int, shardings=None):
-    """Llama-family weights for ``param_shapes(model)`` with the model's
-    own scales: normal with std 1/sqrt(fan_in) for matrices, 0.02 for
-    the embedding, ones for the norms (chip_smoke.init_llama's rule,
-    copied)."""
+def seeded_normal(shapes, seed: int, std_of, shardings=None):
+    """Weights for ``param_shapes(model)``: leaf ``i`` of the flattened
+    tree is ``std_of(path name, leaf)`` times a standard normal drawn
+    from ``fold_in(key, i)``, or all ones where ``std_of`` gives None
+    (a norm's scale)."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
 
     def make(key):
         out = []
         for i, (path, leaf) in enumerate(leaves):
-            if leaf.ndim == 1:
+            std = std_of(jax.tree_util.keystr(path), leaf)
+            if std is None:
                 out.append(jnp.ones(leaf.shape, leaf.dtype))
                 continue
-            name = jax.tree_util.keystr(path)
-            std = (0.02 if "tok_embeddings" in name
-                   else leaf.shape[0] ** -0.5)
             x = jax.random.normal(jax.random.fold_in(key, i),
                                   leaf.shape, jnp.float32)
             out.append((std * x).astype(leaf.dtype))
@@ -43,13 +42,3 @@ def llama_params(shapes, seed: int, shardings=None):
 
     fn = jax.jit(make, out_shardings=shardings)
     return jax.block_until_ready(fn(jax_key(seed, 0)))
-
-
-def gpt2_params(model, seed: int):
-    """GPT-2 master weights from the model's own initialisers, as
-    bench.py and the examples make them; key and ids are arguments.
-    The ids are 1 x 8: no parameter's shape depends on them, and a
-    24 x 1024 batch makes the init trace the flash kernel for 8 s."""
-    ids = jnp.zeros((1, 8), jnp.int32)
-    return jax.block_until_ready(
-        jax.jit(model.init)(jax_key(seed, 0), ids))
