@@ -64,18 +64,24 @@ def metrics_of_cell(bench: Dict[str, Any], section: str,
             if "workloads" not in m or cell in m["workloads"]]
 
 
-def load_metric_reader(name: str):
-    """benchmarks/metrics/<name>.py, found by the metric's name. The
-    name may hold dots, so the file is loaded by path."""
-    path = os.path.join(HERE, "metrics", name + ".py")
+def _load_by_path(subdir: str, name: str, what: str):
+    """The module benchmarks/<subdir>/<name>.py. The name may hold
+    dots, so the file is loaded by path."""
+    path = os.path.join(HERE, subdir, name + ".py")
     if not os.path.exists(path):
-        raise SystemExit(f"benchmarks: per-layer metric {name!r} has no "
-                         f"reader at {path}")
+        raise SystemExit(f"benchmarks: {what} {name!r} has no file at "
+                         f"{path}")
     spec = importlib.util.spec_from_file_location(
-        "benchmarks.metrics." + name.replace(".", "_"), path)
+        f"benchmarks.{subdir}." + name.replace(".", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_metric_reader(name: str):
+    """benchmarks/metrics/<name>.py's ``read``, found by the metric's
+    name."""
+    return _load_by_path("metrics", name, "per-layer metric").read
 
 
 # what a family file must hold, by the ``kind`` of the configurations
@@ -96,20 +102,13 @@ def load_family(name: str, kind: str):
     place that knows the model's classes, its seeded weights, its plain
     reference and its byte and FLOP counts. A file that lacks what its
     ``kind`` of runner asks for is refused here, not in the window."""
-    path = os.path.join(HERE, "families", name + ".py")
-    if not os.path.exists(path):
-        raise SystemExit(f"benchmarks: family {name!r} has no file at "
-                         f"{path}")
     if kind not in FAMILY_ATTRS:
         raise SystemExit(f"benchmarks: unknown kind {kind!r}")
-    spec = importlib.util.spec_from_file_location(
-        "benchmarks.families." + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    mod = _load_by_path("families", name, "family")
     missing = [a for a in FAMILY_ATTRS[kind] if not hasattr(mod, a)]
     if missing:
         raise SystemExit(f"benchmarks: family {name!r} serves no "
-                         f"{kind!r} configuration: {path} lacks "
+                         f"{kind!r} configuration: its file lacks "
                          f"{missing}")
     return mod
 

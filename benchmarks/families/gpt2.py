@@ -81,8 +81,8 @@ def reference_loss_and_grad_norm(params, ids, cfg: Dict[str, Any],
         micro_batch=micro_batch)
 
 
-def train_flops_per_token(cfg: Dict[str, Any], seq: int) -> float:
-    return costs.gpt2_train_flops_per_token(cfg, seq)
+# the count stays the benchmark's, in benchmarks/costs.py
+train_flops_per_token = costs.gpt2_train_flops_per_token
 
 
 def attention_shape(cfg: Dict[str, Any]):

@@ -90,14 +90,9 @@ def reference_logits(rw, ids, pcfg):
                        theta=pcfg.rope_theta)
 
 
-def kv_bytes_per_token(cfg: Dict[str, Any]) -> int:
-    return costs.llama_kv_bytes_per_token(cfg)
-
-
-def decode_step_bytes(cfg: Dict[str, Any], context_tokens: float,
-                      slots: int) -> float:
-    return costs.llama_decode_step_bytes(cfg, context_tokens, slots)
-
+# the counts stay the benchmark's, in benchmarks/costs.py
+kv_bytes_per_token = costs.llama_kv_bytes_per_token
+decode_step_bytes = costs.llama_decode_step_bytes
 
 # the scopes ray_tpu.models.llama.LlamaAttention names around the KV
 # window, and flax's names of the modules of a block

@@ -2,10 +2,11 @@
 over the time it took. The least time is the bytes the step must move
 (the family's ``decode_step_bytes``, counted in benchmarks/costs.py:
 weights once, the head once, the K/V of the tokens really in context;
-one chip's share on a sharded mesh) over the chip's published HBM bandwidth: at 32-64
-rows a decode step is bound by bytes, not FLOPs. Tokens in context are
-the mean of load_report()'s kv_bytes_in_use over the traced seconds
-(whole pages, so at most half a page a slot too many)."""
+one chip's share on a sharded mesh) over the chip's published HBM
+bandwidth: at 32-64 rows a decode step is bound by bytes, not FLOPs.
+Tokens in context are the mean of load_report()'s kv_bytes_in_use over
+the traced seconds (whole pages, so at most half a page a slot too
+many)."""
 from benchmarks import trace_reduce
 
 

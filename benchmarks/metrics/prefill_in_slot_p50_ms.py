@@ -3,7 +3,7 @@ the window of first ``admit`` -> ``first_token``, from the engine's event
 log: the time a request spends INSIDE a slot waiting for the 256-token
 prefill budget a round and being prefilled. queue_wait_p95_ms cannot see
 it (admission is immediate while slots are free); with the median queue
-wait and entry_overhead_ms it is what ttft_p50_ms is made of (medians do
+wait and entry_overhead_ms it is what ttft_median_ms is made of (medians do
 not add exactly; PERF.md section 6)."""
 import statistics
 

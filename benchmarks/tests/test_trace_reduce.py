@@ -89,10 +89,11 @@ def test_recorded_training_trace_and_the_flash_reader():
                if r[2] == "custom-call" and r[1] > 1e-6]
     assert len(kernels) == 36          # 12 layers x (fwd, dq, dk/dv)
     assert sum(r[1] for r in kernels) == pytest.approx(0.0524770, abs=1e-6)
+    cfg = common.load_json("configs", "gpt2-124m.json")
+    fam = common.load_family(cfg["family"], cfg["kind"])
     run = types.SimpleNamespace(
         kind="train", trace=red, peaks=common.peaks_for("TPU v5 lite"),
-        cfg=common.load_json("configs", "gpt2-124m.json"), batch=24,
-        seq=1024, chips=1)
+        cfg=cfg, family=fam, batch=24, seq=1024, chips=1)
     # 12 layers x 3.5 x 38.65 GFLOP = 1.623 TFLOP a step: 8.24 ms at the
     # peak, against 52.5 ms of kernel time
     assert common.load_metric_reader("flash_roofline")(run) == \
@@ -100,7 +101,7 @@ def test_recorded_training_trace_and_the_flash_reader():
     assert common.load_metric_reader("device_idle_share.train")(run) == \
         pytest.approx(100 * (1 - 0.387899947 / 0.388060344))
     assert common.load_metric_reader("train_mfu")(types.SimpleNamespace(
-        kind="train", peaks=run.peaks, cfg=run.cfg, seq=1024,
+        kind="train", peaks=run.peaks, cfg=cfg, family=fam, seq=1024,
         e2e={"train_tokens_per_s": 134223.85})) == \
         pytest.approx(58.538, abs=0.01)
 

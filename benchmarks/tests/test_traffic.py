@@ -16,7 +16,8 @@ def chat():
 def test_one_schedule_whatever_the_seed(chat):
     a = trafficgen.open_schedule(chat, 40)
     assert a == trafficgen.open_schedule(chat, 40)
-    assert a != trafficgen.open_schedule(dict(chat, traffic_seed=3), 40)
+    assert a != trafficgen.open_schedule(
+        dict(chat, traffic_seed=chat["traffic_seed"] + 1), 40)
     assert trafficgen.prompt_tokens(7, 3, 50, 32768) == \
         trafficgen.prompt_tokens(7, 3, 50, 32768)
     assert trafficgen.prompt_tokens(7, 3, 50, 32768) != \
@@ -35,7 +36,7 @@ def test_the_committed_draw_offers_what_the_file_declares(chat):
     ramp + window within 10 % of rate x the distribution's mean."""
     reqs = trafficgen.open_schedule(chat, 40)
     span = chat["ramp_s"] + 40
-    assert len(reqs) == 48 and sum(r.measured for r in reqs) == 35
+    assert len(reqs) == 158 and sum(r.measured for r in reqs) == 122
     big = np.random.default_rng(0)
     for key in ("prompt_len", "output_len"):
         mean = trafficgen.draw_lengths(chat[key], big, 1_000_000).mean()
