@@ -11,7 +11,8 @@ from ray_tpu.models.llama import (Llama, LlamaConfig, generate,
                                   llama_tiny)
 from ray_tpu.models.mixtral import (Mixtral, MixtralConfig,
                                     mixtral_8x7b, mixtral_sharding_rules,
-                                    mixtral_tiny, moe_aux_loss)
+                                    mixtral_tiny, moe_aux_loss,
+                                    olmoe_1b_7b, olmoe_tiny)
 from ray_tpu.models.resnet import ResNet, ResNetConfig, resnet50, resnet18
 from ray_tpu.models.vit import (ViT, ViTConfig, classification_loss,
                                 vit_base_16, vit_sharding_rules,
@@ -29,5 +30,6 @@ __all__ = [
     "Llama", "LlamaConfig", "llama2_7b", "llama_tiny",
     "llama_sharding_rules", "generate",
     "Mixtral", "MixtralConfig", "mixtral_8x7b", "mixtral_tiny",
-    "mixtral_sharding_rules", "moe_aux_loss",
+    "mixtral_sharding_rules", "moe_aux_loss", "olmoe_1b_7b",
+    "olmoe_tiny",
 ]

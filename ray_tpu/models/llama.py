@@ -70,6 +70,13 @@ class LlamaConfig:
     param_dtype: Any = jnp.float32
     remat: bool = False
     attention_impl: str = "auto"
+    # False: logits go through a separate ``lm_head`` matrix [V, dim]
+    # (Mistral, OLMoE publish ``tie_word_embeddings: false``)
+    tie_word_embeddings: bool = True
+    # True: an RMSNorm with a learned scale over the WHOLE projected
+    # query and key vectors, before the split into heads and before
+    # rope (OLMoE's ``q_norm``/``k_norm``)
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -271,6 +278,9 @@ class LlamaAttention(nn.Module):
         v = nn.Dense(cfg.n_kv_heads * hd, use_bias=False,
                      dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                      name="wv")(x)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.norm_eps, name="q_norm")(q)
+            k = RMSNorm(cfg.norm_eps, name="k_norm")(k)
         q = q.reshape(B, T, cfg.n_heads, hd)
         k = k.reshape(B, T, cfg.n_kv_heads, hd)
         v = v.reshape(B, T, cfg.n_kv_heads, hd)
@@ -401,7 +411,9 @@ class LlamaBlock(nn.Module):
 def transformer_forward(mod: nn.Module, cfg, block_cls, input_ids,
                         kv_caches=None, cache_len=None):
     """Shared decoder-transformer body (embedding, RoPE table,
-    position/cache plumbing, layer loop, final norm, tied logits).
+    position/cache plumbing, layer loop, final norm, logits through
+    the embedding or, with ``tie_word_embeddings`` false, through a
+    separate ``lm_head``).
     Every Llama-shaped family (Llama, Mixtral) calls this with its own
     block class, so the decode contract `generate`/`generate_stream`
     rely on cannot drift per family. Called from a compact __call__:
@@ -430,9 +442,13 @@ def transformer_forward(mod: nn.Module, cfg, block_cls, input_ids,
             x, freqs, positions, cache_i, cache_len)
         new_caches.append(nc)
     x = RMSNorm(cfg.norm_eps, name="norm")(x)
+    head = tok
+    if not cfg.tie_word_embeddings:
+        head = mod.param("lm_head", nn.initializers.normal(0.02),
+                         (cfg.vocab_size, cfg.dim), cfg.param_dtype)
     with jax.named_scope("head"):
         logits = jax.lax.dot_general(
-            x.astype(cfg.dtype), tok.astype(cfg.dtype),
+            x.astype(cfg.dtype), head.astype(cfg.dtype),
             (((2,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
     if kv_caches is None:
@@ -671,7 +687,10 @@ def llama_sharding_rules(fsdp: bool = True) -> ShardingRules:
         (r"attention/wo/kernel",     P("tensor", f)),
         (r"feed_forward/w[13]/kernel", P(f, "tensor")),
         (r"feed_forward/w2/kernel",  P("tensor", f)),
-        (r"tok_embeddings$",
+        # the norm over a whole projected q / k vector: its scale
+        # shards as the projection's columns do
+        (r"attention/[qk]_norm/scale", P("tensor")),
+        (r"(tok_embeddings|lm_head)$",
          P(("tensor", "fsdp") if fsdp else "tensor", None)),
     ])
 
@@ -695,13 +714,27 @@ def llama_tp_validate(cfg: LlamaConfig, tp: int) -> None:
                 f"{what}={n} for this Llama config")
 
 
+def attention_param_count(cfg) -> int:
+    """One block's attention: the four projections and, where the
+    config has them, the query/key norms' scales."""
+    n = (cfg.dim * cfg.n_heads * cfg.head_dim +
+         2 * cfg.dim * cfg.n_kv_heads * cfg.head_dim +
+         cfg.n_heads * cfg.head_dim * cfg.dim)
+    if cfg.qk_norm:
+        n += (cfg.n_heads + cfg.n_kv_heads) * cfg.head_dim
+    return n
+
+
+def embedding_param_count(cfg) -> int:
+    """The embedding, the final norm and, where untied, the head."""
+    heads = 1 if cfg.tie_word_embeddings else 2
+    return heads * cfg.vocab_size * cfg.dim + cfg.dim
+
+
 def llama_param_count(cfg: LlamaConfig) -> int:
-    per_layer = (cfg.dim * cfg.n_heads * cfg.head_dim +
-                 2 * cfg.dim * cfg.n_kv_heads * cfg.head_dim +
-                 cfg.n_heads * cfg.head_dim * cfg.dim +
+    per_layer = (attention_param_count(cfg) +
                  3 * cfg.dim * cfg.hidden_dim + 2 * cfg.dim)
-    return (cfg.vocab_size * cfg.dim + cfg.n_layers * per_layer +
-            cfg.dim)
+    return embedding_param_count(cfg) + cfg.n_layers * per_layer
 
 
 def llama_flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:

@@ -1,29 +1,34 @@
 """Mixtral-family sparse-MoE transformer: Llama blocks with the FFN
-replaced by a top-k routed mixture of SwiGLU experts.
+replaced by a top-k routed mixture of SwiGLU experts. OLMoE is the same
+block with three declared differences, each a field of the config:
+the router's rule (``norm_topk_prob``), an RMSNorm over the projected
+query and key (``qk_norm``) and an untied output head
+(``tie_word_embeddings``).
 
-The reference has no model zoo; this family is the expert-parallel
-exemplar of the model stack (SURVEY.md §2.4 EP): experts live on an
-`expert` mesh axis, tokens dispatch with capacity buffers via dense
-einsums (compiler-friendly: no dynamic shapes, XLA lowers the
-dispatch/combine einsums onto the MXU and inserts the all-to-alls the
-expert sharding implies). Attention/norm/RoPE and the KV-cache decode
-path are shared with models/llama.py, so `generate` /
-`generate_stream` work unchanged."""
+The mixture is DROPLESS and its work follows the routing: the (token,
+expert) pairs are sorted by expert, each expert's rows go through its
+three matrices in one grouped matmul (``jax.lax.ragged_dot``: static
+shapes, the group sizes are values), and every pair comes back to its
+token weighted by its gate. No capacity, no overflow, at any call size.
+Expert weights carry the `expert` axis for EP sharding.
+Attention/norm/RoPE and the KV-cache decode path are shared with
+models/llama.py, so `generate` / `generate_stream` work unchanged."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.mesh.sharding import ShardingRules
-from ray_tpu.models.llama import (LlamaConfig, block_forward,
+from ray_tpu.models.kv_cache import PagedKVLayer
+from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.models.llama import (LlamaConfig, attention_param_count,
+                                  block_forward, embedding_param_count,
                                   transformer_forward)
-from ray_tpu.parallel.expert import _maybe_constrain
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,13 +42,18 @@ class MixtralConfig:
     hidden_dim: int = 14336        # per-expert SwiGLU inner dim
     num_experts: int = 8
     num_experts_per_tok: int = 2   # top-k routing (Mixtral: 2)
-    capacity_factor: float = 1.25
     rope_theta: float = 1e6
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = False
     attention_impl: str = "auto"
+    # True (Mixtral): the k chosen gates are renormalised to sum to 1
+    # (a softmax over the chosen logits). False (OLMoE): the softmax
+    # over ALL experts' logits, the k largest as they are.
+    norm_topk_prob: bool = True
+    tie_word_embeddings: bool = True   # as LlamaConfig's
+    qk_norm: bool = False              # as LlamaConfig's
 
     @property
     def head_dim(self) -> int:
@@ -59,7 +69,9 @@ class MixtralConfig:
             hidden_dim=self.hidden_dim, rope_theta=self.rope_theta,
             norm_eps=self.norm_eps, dtype=self.dtype,
             param_dtype=self.param_dtype, remat=self.remat,
-            attention_impl=self.attention_impl)
+            attention_impl=self.attention_impl,
+            tie_word_embeddings=self.tie_word_embeddings,
+            qk_norm=self.qk_norm)
 
 
 def mixtral_8x7b(**overrides) -> MixtralConfig:
@@ -75,56 +87,66 @@ def mixtral_tiny(**overrides) -> MixtralConfig:
     return MixtralConfig(**d)
 
 
-class MoEFeedForward(nn.Module):
-    """Top-k routed SwiGLU experts with capacity buffers.
+def olmoe_1b_7b(**overrides) -> MixtralConfig:
+    """OLMoE-1B-7B-0125 as published (allenai, config.json): 16
+    layers, 64 experts of 1,024, 8 a token, gates not renormalised,
+    query/key norm, untied head."""
+    d = dict(vocab_size=50304, max_seq_len=4096, dim=2048, n_layers=16,
+             n_heads=16, n_kv_heads=16, hidden_dim=1024, num_experts=64,
+             num_experts_per_tok=8, rope_theta=10000.0, norm_eps=1e-5,
+             norm_topk_prob=False, tie_word_embeddings=False,
+             qk_norm=True)
+    d.update(overrides)
+    return MixtralConfig(**d)
 
-    Dense-dispatch formulation (same shape discipline as
-    parallel/expert.py SwitchMoE, generalized to top-k): static [E, C]
-    capacity buffers, dispatch/combine as einsums, overflow dropped.
-    Expert weight tensors carry the `expert` axis for EP sharding."""
+
+def olmoe_tiny(**overrides) -> MixtralConfig:
+    """Test-size OLMoE (8 experts, 3 a token) for the CPU tests."""
+    d = dict(vocab_size=256, max_seq_len=128, dim=64, n_layers=2,
+             n_heads=4, n_kv_heads=4, hidden_dim=32, num_experts=8,
+             num_experts_per_tok=3, rope_theta=10000.0,
+             norm_topk_prob=False, tie_word_embeddings=False,
+             qk_norm=True)
+    d.update(overrides)
+    return MixtralConfig(**d)
+
+
+# The collection a mixture sows what its router chose into, for a
+# caller that asks for it (``mutable=[MOE_STATS]``): each layer's
+# ``topk`` [B, T, K] expert indices. The serving engine's programs
+# reduce it to counters over their live rows (moe_stats_vector).
+MOE_STATS = "moe_stats"
+
+
+class MoEFeedForward(nn.Module):
+    """Top-k routed SwiGLU experts, dropless (the module docstring says
+    how). ``live`` [B] bool, where the caller knows it (a paged call:
+    rows of free slots ride every decode call), marks the rows that
+    carry a request: the others are given no expert, so they stream no
+    expert's weights, and their output is zero."""
     config: MixtralConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, live=None):
         cfg = self.config
         B, T, D = x.shape
         E, K = cfg.num_experts, cfg.num_experts_per_tok
         N = B * T
-        # Small token counts (decode steps) run DROP-FREE: worst-case
-        # capacity N*K is tiny there, and dropping at T=1 would
-        # silently zero expert contributions on routing collisions and
-        # change generated tokens. Large N (prefill/training) uses the
-        # standard capacity factor.
-        if N * K <= 4096:
-            C = N * K
-        else:
-            C = max(K, int(cfg.capacity_factor * K * N / E))
-
         tokens = x.reshape(N, D)
-        router_w = self.param("router", nn.initializers.normal(0.02),
-                              (D, E), jnp.float32)
-        logits = tokens.astype(jnp.float32) @ router_w        # [N, E]
-        # Mixtral normalizes softmax over the selected top-k only.
-        topk_logits, topk_idx = jax.lax.top_k(logits, K)      # [N, K]
-        topk_gates = jax.nn.softmax(topk_logits, axis=-1)     # [N, K]
 
-        # Capacity slots per (token, choice): position of this
-        # assignment within its expert's buffer, counted over the
-        # flattened [N*K] assignment stream.
-        assign_onehot = jax.nn.one_hot(
-            topk_idx.reshape(-1), E, dtype=jnp.int32)         # [N*K, E]
-        pos = (jnp.cumsum(assign_onehot, axis=0) - 1) * assign_onehot
-        slot = jnp.sum(pos, axis=-1).reshape(N, K)            # [N, K]
-        keep = slot < C                                       # overflow
-
-        # dispatch[n, e, c] = sum over kept choices of token n
-        disp = (jax.nn.one_hot(topk_idx, E, dtype=cfg.dtype) *
-                keep[..., None].astype(cfg.dtype))            # [N,K,E]
-        slots = jax.nn.one_hot(slot, C, dtype=cfg.dtype)      # [N,K,C]
-        dispatch = jnp.einsum("nke,nkc->nec", disp, slots)    # [N,E,C]
-        combine = jnp.einsum(
-            "nke,nkc,nk->nec", disp, slots,
-            topk_gates.astype(cfg.dtype))                     # [N,E,C]
+        with jax.named_scope("moe_router"):
+            router_w = self.param("router", nn.initializers.normal(0.02),
+                                  (D, E), jnp.float32)
+            logits = tokens.astype(jnp.float32) @ router_w    # [N, E]
+            probs = jax.nn.softmax(logits, axis=-1)
+            # the k largest probabilities are the k largest logits
+            gates, topk_idx = jax.lax.top_k(probs, K)         # [N, K]
+            if cfg.norm_topk_prob:
+                gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        if not self.is_initializing():      # no weights: not in init's tree
+            self.sow(MOE_STATS, "topk", topk_idx.reshape(B, T, K),
+                     reduce_fn=lambda _prev, new: new,
+                     init_fn=lambda: None)
 
         pd = cfg.param_dtype
         w1 = self.param("w1", nn.initializers.lecun_normal(),
@@ -134,25 +156,67 @@ class MoEFeedForward(nn.Module):
         w2 = self.param("w2", nn.initializers.lecun_normal(),
                         (E, cfg.hidden_dim, D), pd).astype(cfg.dtype)
 
-        expert_in = jnp.einsum("nd,nec->ecd",
-                               tokens.astype(cfg.dtype), dispatch)
-        expert_in = _maybe_constrain(expert_in,
-                                     P("expert", None, None))
-        h = nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, w1)) * \
-            jnp.einsum("ecd,edf->ecf", expert_in, w3)
-        expert_out = jnp.einsum("ecf,efd->ecd", h, w2)
-        expert_out = _maybe_constrain(expert_out,
-                                      P("expert", None, None))
-
-        out = jnp.einsum("ecd,nec->nd", expert_out, combine)
+        with jax.named_scope("moe_dispatch"):
+            # Sort the N*K pairs by expert: each expert's rows become
+            # one contiguous group. A row that carries no request gets
+            # the expert "E": it sorts behind every group and belongs
+            # to none.
+            pair_expert = topk_idx.reshape(N * K)
+            if live is not None:
+                token_live = jnp.repeat(live, T)              # [N]
+                pair_expert = jnp.where(jnp.repeat(token_live, K),
+                                        pair_expert, E)
+            order = jnp.argsort(pair_expert, stable=True)     # [N*K]
+            group_sizes = jnp.sum(
+                pair_expert[:, None] == jnp.arange(E)[None, :],
+                axis=0, dtype=jnp.int32)                      # [E]
+            rows = tokens.astype(cfg.dtype)[order // K]       # [N*K, D]
+        with jax.named_scope("moe_experts"):
+            h = nn.silu(grouped_matmul(rows, w1, group_sizes)) * \
+                grouped_matmul(rows, w3, group_sizes)
+            out_rows = grouped_matmul(h, w2, group_sizes)     # [N*K, D]
+        with jax.named_scope("moe_combine"):
+            # back to pair order (the inverse permutation), then each
+            # token's K rows weighted by their gates
+            back = jnp.zeros_like(order).at[order].set(
+                jnp.arange(N * K, dtype=order.dtype))
+            pairs = out_rows[back].reshape(N, K, D)
+            if live is not None:
+                # rows past the last group are whatever the grouped
+                # matmul left there
+                pairs = jnp.where(token_live[:, None, None], pairs, 0)
+            out = jnp.einsum("nkd,nk->nd", pairs.astype(jnp.float32),
+                             gates).astype(cfg.dtype)
 
         # Load-balance auxiliary (Switch eq. 4 over top-1 choice).
         top1 = jax.nn.one_hot(topk_idx[:, 0], E, dtype=jnp.float32)
         frac_tokens = jnp.mean(top1, axis=0)
-        frac_probs = jnp.mean(jax.nn.softmax(logits, axis=-1), axis=0)
+        frac_probs = jnp.mean(probs, axis=0)
         self.sow("losses", "load_balance",
                  E * jnp.sum(frac_tokens * frac_probs))
         return out.reshape(B, T, D)
+
+
+def moe_stats_vector(stats, live, num_experts: int):
+    """What the router chose in one forward pass, over live tokens
+    only, as one int32 vector [E + 3]: each expert's pairs summed over
+    the layers, then the distinct experts touched summed over the
+    layers, the fullest expert's pairs summed over the layers, and the
+    number of layers (what to divide the two sums by). ``stats`` is the
+    ``MOE_STATS`` collection of an apply, ``live`` [B, T] bool."""
+    E = num_experts
+    counts = jnp.zeros((E,), jnp.int32)
+    touched = fullest = layers = jnp.int32(0)
+    for topk in jax.tree_util.tree_leaves(stats):             # [B, T, K]
+        hit = (topk[..., None] == jnp.arange(E)) & \
+            live[:, :, None, None]
+        c = jnp.sum(hit, axis=(0, 1, 2), dtype=jnp.int32)     # [E]
+        counts = counts + c
+        touched = touched + jnp.sum(c > 0, dtype=jnp.int32)
+        fullest = fullest + jnp.max(c)
+        layers = layers + 1
+    return jnp.concatenate(
+        [counts, jnp.stack([touched, fullest, layers])])
 
 
 class MixtralBlock(nn.Module):
@@ -162,9 +226,14 @@ class MixtralBlock(nn.Module):
     def __call__(self, x, freqs, positions, kv_cache=None,
                  cache_len=None):
         cfg = self.config
+        moe = MoEFeedForward(cfg, name="moe")
+        live = None
+        if isinstance(kv_cache, PagedKVLayer):
+            # a row whose page-table row is the null row carries no
+            # request (models/llama.py _paged_window_attention's rule)
+            live = kv_cache.page_table[:, 0] != 0
         return block_forward(
-            cfg, cfg.attention_config(),
-            MoEFeedForward(cfg, name="moe"),
+            cfg, cfg.attention_config(), lambda h: moe(h, live),
             x, freqs, positions, kv_cache, cache_len)
 
 
@@ -189,10 +258,11 @@ def mixtral_sharding_rules(fsdp: bool = True) -> ShardingRules:
     return ShardingRules([
         (r"attention/w[qkv]/kernel", P(f, "tensor")),
         (r"attention/wo/kernel",     P("tensor", f)),
+        (r"attention/[qk]_norm/scale", P("tensor")),
         (r"moe/w[13]$",              P("expert", f, "tensor")),
         (r"moe/w2$",                 P("expert", "tensor", f)),
         (r"moe/router$",             P(None, None)),
-        (r"tok_embeddings$",
+        (r"(tok_embeddings|lm_head)$",
          P(("tensor", "fsdp") if fsdp else "tensor", None)),
     ])
 
@@ -227,22 +297,17 @@ def moe_aux_loss(variables) -> jnp.ndarray:
     return sum(jnp.asarray(v).mean() for v in vals) / len(vals)
 
 
-def mixtral_param_count(cfg: MixtralConfig) -> int:
-    attn = (cfg.dim * cfg.n_heads * cfg.head_dim +
-            2 * cfg.dim * cfg.n_kv_heads * cfg.head_dim +
-            cfg.n_heads * cfg.head_dim * cfg.dim)
-    moe = cfg.num_experts * 3 * cfg.dim * cfg.hidden_dim + \
+def _param_count(cfg: MixtralConfig, experts: int) -> int:
+    moe = experts * 3 * cfg.dim * cfg.hidden_dim + \
         cfg.dim * cfg.num_experts
-    per_layer = attn + moe + 2 * cfg.dim
-    return cfg.vocab_size * cfg.dim + cfg.n_layers * per_layer + cfg.dim
+    per_layer = attention_param_count(cfg) + moe + 2 * cfg.dim
+    return embedding_param_count(cfg) + cfg.n_layers * per_layer
+
+
+def mixtral_param_count(cfg: MixtralConfig) -> int:
+    return _param_count(cfg, cfg.num_experts)
 
 
 def active_params_per_token(cfg: MixtralConfig) -> int:
     """Sparse models are priced by ACTIVE params: K experts of E."""
-    attn = (cfg.dim * cfg.n_heads * cfg.head_dim +
-            2 * cfg.dim * cfg.n_kv_heads * cfg.head_dim +
-            cfg.n_heads * cfg.head_dim * cfg.dim)
-    moe = cfg.num_experts_per_tok * 3 * cfg.dim * cfg.hidden_dim + \
-        cfg.dim * cfg.num_experts
-    per_layer = attn + moe + 2 * cfg.dim
-    return cfg.vocab_size * cfg.dim + cfg.n_layers * per_layer + cfg.dim
+    return _param_count(cfg, cfg.num_experts_per_tok)

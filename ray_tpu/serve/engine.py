@@ -51,6 +51,7 @@ LlamaAttention via block_forward.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import itertools
@@ -401,6 +402,20 @@ class _Slot:
         return len(self.prompt) - self.prefilled
 
 
+def _new_moe_info() -> Dict[str, int]:
+    """The router's counters of a mixture-of-experts model, as the
+    ``round`` event reports them (docs/serving.md)."""
+    return {prefix + key: 0 for prefix in ("moe_", "moe_decode_")
+            for key in ("pairs", "experts_touched", "load_max",
+                        "layer_steps")}
+
+
+def _moe_experts_of(model) -> int:
+    """Experts whose routing the model's step programs report: the
+    config's ``num_experts``, 0 for a dense model."""
+    return int(getattr(model.config, "num_experts", 0))
+
+
 def _new_round_info() -> Dict[str, int]:
     """What a round dispatched, as its ``round`` event reports it."""
     return {"decode_riders": 0, "decode_steps": 0,
@@ -681,6 +696,15 @@ class LLMEngine:
         self._fetchq: "collections.deque" = collections.deque()
         # in-flight prefills: [(firsts_dev, [(ix, slot, row), ...])]
         self._pending_prefill: List = []
+        # A mixture-of-experts model's step programs also return what
+        # the router chose (models/mixtral.py moe_stats_vector), over
+        # live rows only: [(vector_dev, is_decode)], read back behind
+        # the tokens of the same dispatch, never waited for. 0 experts
+        # = a dense model: nothing is returned, queued or reported.
+        self._moe_experts = _moe_experts_of(model)
+        self._moe_pending: "collections.deque" = collections.deque()
+        self._moe_expert_pairs = np.zeros((self._moe_experts,), np.int64)
+        self._moe_unreported = _new_moe_info()
         # Device-authoritative decode state: the next-token input and
         # write position per slot LIVE ON DEVICE and chain dispatch to
         # dispatch — no host readback sits on the decode critical
@@ -1178,6 +1202,17 @@ class LLMEngine:
             "prefix_pages_evicted": evicted})
         self._hb = time.monotonic()
 
+    def _moe_load_report(self) -> Dict[str, Any]:
+        """A mixture-of-experts model's routing so far: each expert's
+        share of the (token, expert) pairs of live rows, and their
+        number. Nothing for a dense model."""
+        if not self._moe_experts:
+            return {}
+        pairs = self._moe_expert_pairs
+        total = int(pairs.sum())
+        return {"moe_pairs_total": total,
+                "moe_expert_share": (pairs / max(1, total)).tolist()}
+
     def load_report(self) -> Dict[str, Any]:
         """Compact load snapshot for pool routing: free capacity,
         queue pressure, outstanding token work, and the prefix-cache
@@ -1255,6 +1290,7 @@ class LLMEngine:
                     self.prefix_digest_max)
                     if self.prefix_cache is not None
                     else frozenset()),
+                **self._moe_load_report(),
             }
         if self._lock.acquire(timeout=0.02):
             try:
@@ -1718,7 +1754,7 @@ class LLMEngine:
                 "plan_s": round(_tpe - _tp, 6),
                 "dispatch_s": round(_tde - _tpe, 6),
                 "readback_s": round(_now - _tde, 6),
-                **_ri})
+                **_ri, **self._take_moe_info_locked()})
             if _pm is not None:
                 _pm["round_wall"].observe(_now - _t0)
                 _pm["host_gap"].observe(_gap)
@@ -2555,10 +2591,11 @@ class LLMEngine:
             # or the engine was force-killed mid-loop (zombie fence)
             return
         (toks, self.pages, self._rng, self._dev_pos,
-         self._dev_cur) = self._decode_fn(
+         self._dev_cur, *moe) = self._decode_fn(
             self.params, self.pages, self._h2d(pt),
             self._dev_pos, self._dev_cur, self._rng,
             self._h2d(jnp.int32(steps)))
+        self._moe_pending.extend((v, True) for v in moe)
         # host mirrors advance NOW; emission trails
         for _i, slot, _t in riders:
             slot.pos += steps
@@ -2677,9 +2714,10 @@ class LLMEngine:
                 ids[i, 1:1 + len(drafts)] = drafts
             start[i] = slot.pos
             pt[i, :len(slot.pages)] = slot.pages
-        out_dev, self.pages = self._verify_fn(
+        out_dev, self.pages, *moe = self._verify_fn(
             self.params, self.pages, self._h2d(ids),
             self._h2d(start), self._h2d(pt))
+        self._moe_pending.extend((v, True) for v in moe)
         out = np.asarray(out_dev)    # host sync: acceptance gates
         self._hb = time.monotonic()  # verify completed: progress
         self._round_info["decode_riders"] = len(rows)
@@ -2814,6 +2852,7 @@ class LLMEngine:
             vals = jax.device_get(
                 [b[0] for b in batch] + [f for f, _ in pend_pre])
             self._hb = time.monotonic()   # readback completed
+            self._collect_moe_locked()
             self.events.append(
                 "readback",
                 data={"bufs": len(batch) + len(pend_pre)})
@@ -2856,6 +2895,40 @@ class LLMEngine:
                     self._emit_to(slot.req, toks[:take, i].tolist(), i,
                                   lps=(None if lp_buf is None
                                        else lp_buf[:take, i].tolist()))
+
+    def _collect_moe_locked(self) -> None:
+        """Read the routing counters of every dispatch that has
+        finished (each vector leaves its program with that dispatch's
+        tokens, so after a token readback the ones before it are
+        there: this never waits) and add them to the running totals
+        and to what the next ``round`` event reports."""
+        ready = []
+        while self._moe_pending and _dev_ready(self._moe_pending[0][0]):
+            ready.append(self._moe_pending.popleft())
+        if not ready:
+            return
+        E, acc = self._moe_experts, self._moe_unreported
+        for vec, (_v, decode) in zip(
+                jax.device_get([v for v, _d in ready]), ready):
+            self._moe_expert_pairs += vec[:E]
+            sums = dict(zip(
+                ("pairs", "experts_touched", "load_max", "layer_steps"),
+                (int(vec[:E].sum()), *(int(x) for x in vec[E:]))))
+            for prefix in ("moe_", "moe_decode_") if decode else ("moe_",):
+                for key, value in sums.items():
+                    acc[prefix + key] += value
+
+    def _take_moe_info_locked(self) -> Dict[str, int]:
+        """The counters gathered since the last ``round`` event, for
+        this one: those of the dispatches whose results were read back
+        meanwhile (under the overlapped loop, the round before's).
+        Nothing for a dense model."""
+        if not self._moe_experts:
+            return {}
+        out, self._moe_unreported = self._moe_unreported, _new_moe_info()
+        for k, v in out.items():
+            self.stats[k] += v
+        return out
 
     def _fail_rider_locked(self, ix: int, slot: _Slot,
                            err: BaseException) -> None:
@@ -2988,10 +3061,11 @@ class LLMEngine:
             start[r] = slot.prefilled
             last_idx[r] = take - 1
             pt[r, :len(slot.pages)] = slot.pages
-        out, self.pages, self._rng = self._prefill_fn(
+        out, self.pages, self._rng, *moe = self._prefill_fn(
             self.params, self.pages, self._h2d(ids),
             self._h2d(start), self._h2d(last_idx),
             self._h2d(pt), self._rng)
+        self._moe_pending.extend((v, False) for v in moe)
         # logprob capture packs (firsts, first_logprobs); the seed
         # scatter takes the raw firsts, emission gets the pair
         firsts = out[0] if self.capture_logprobs else out
@@ -3049,6 +3123,39 @@ class LLMEngine:
 # decides the KV-pool sharding constraint, and a replica rebuilt over
 # the same devices hashes to the same entry.
 
+def _moe_apply(model, mesh):
+    """``model.apply`` for a step program. For a mixture-of-experts
+    model the third result is (the int32 vector,) of what the router
+    chose over the program's live tokens (models/mixtral.py
+    moe_stats_vector; ``live()`` gives the [B, T] mask); for a dense
+    model it is () and the program is what it was. A sharded
+    replica's mesh is made ambient while the mixture is traced: its
+    grouped matmul asks for it (ops/grouped_matmul.py: no Mosaic
+    kernel under a mesh)."""
+    E = _moe_experts_of(model)
+    if not E:
+        def apply(params, ids, kv, start, live):
+            logits, new_kv = model.apply(params, ids, kv_caches=kv,
+                                         cache_len=start)
+            return logits, new_kv, ()
+        return apply
+    from ray_tpu.models.mixtral import MOE_STATS, moe_stats_vector
+
+    ambient = (contextlib.nullcontext if mesh is None else
+               functools.partial(jax.sharding.use_abstract_mesh,
+                                 mesh.abstract_mesh))
+
+    def apply(params, ids, kv, start, live):
+        with ambient():
+            (logits, new_kv), sown = model.apply(
+                params, ids, kv_caches=kv, cache_len=start,
+                mutable=[MOE_STATS])
+        with jax.named_scope("moe_stats"):
+            vec = moe_stats_vector(sown[MOE_STATS], live(), E)
+        return logits, new_kv, (vec,)
+    return apply
+
+
 def _constrain_for(mesh):
     """Pin a jitted step's output KV pool to the head-sharded layout
     (identity unsharded). Keeps GSPMD from ever resharding the pool
@@ -3087,6 +3194,7 @@ def _jit_prefill(model, temp, B, capture, mesh):
     mid-prompt, consumed only for rows that just finished their
     prompt."""
     constrain = _constrain_for(mesh)
+    apply = _moe_apply(model, mesh)
     from ray_tpu.models.llama import _pick_token
 
     def prefill(params, pages, ids, start, last_idx, page_table,
@@ -3096,8 +3204,12 @@ def _jit_prefill(model, temp, B, capture, mesh):
         # fp layers are (pk, pv), int8 layers (pk, pv, sk, sv) —
         # the scales ride the same donated tuple through the step
         kv = [kv_layer_view(layer, page_table) for layer in pages]
-        logits, new_kv = model.apply(params, ids, kv_caches=kv,
-                                     cache_len=start)
+        # live tokens: a real row's positions up to its last real one
+        # (dummy rows point at the null page; the rest is padding)
+        logits, new_kv, moe = apply(
+            params, ids, kv, start,
+            lambda: (page_table[:, :1] != 0)
+            & (jnp.arange(ids.shape[1])[None] <= last_idx[:, None]))
         new_pages = constrain([kv_layer_store(c) for c in new_kv])
         last = logits[jnp.arange(B), last_idx]        # [B, V]
         with jax.named_scope("sample"):
@@ -3112,8 +3224,8 @@ def _jit_prefill(model, temp, B, capture, mesh):
             lp = jnp.take_along_axis(
                 jax.nn.log_softmax(slog),
                 firsts[:, None], axis=-1)[:, 0]
-            return (firsts, lp), new_pages, rng
-        return firsts, new_pages, rng
+            return ((firsts, lp), new_pages, rng) + moe
+        return (firsts, new_pages, rng) + moe
 
     return jax.jit(prefill, donate_argnums=(1,))
 
@@ -3129,14 +3241,18 @@ def _jit_verify(model, mesh):
     acceptance is a pure prefix compare on the host. No rng
     threading — speculation is disabled at temperature > 0."""
     constrain = _constrain_for(mesh)
+    apply = _moe_apply(model, mesh)
 
     def verify(params, pages, ids, start, page_table):
         kv = [kv_layer_view(layer, page_table) for layer in pages]
-        logits, new_kv = model.apply(params, ids, kv_caches=kv,
-                                     cache_len=start)
+        # every position of a verified slot's row is a forward pass,
+        # unused draft places included
+        logits, new_kv, moe = apply(
+            params, ids, kv, start,
+            lambda: jnp.broadcast_to(page_table[:, :1] != 0, ids.shape))
         new_pages = constrain([kv_layer_store(c) for c in new_kv])
         return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                new_pages)
+                new_pages) + moe
 
     return jax.jit(verify, donate_argnums=(1,))
 
@@ -3144,6 +3260,8 @@ def _jit_verify(model, mesh):
 @functools.lru_cache(maxsize=64)
 def _jit_decode(model, temp, KMAX, S, capture, mesh):
     constrain = _constrain_for(mesh)
+    apply = _moe_apply(model, mesh)
+    E = _moe_experts_of(model)
     from ray_tpu.models.llama import _pick_token
 
     def decode(params, pages, page_table, pos, cur, rng, steps):
@@ -3160,13 +3278,20 @@ def _jit_decode(model, temp, KMAX, S, capture, mesh):
         buf0 = jnp.zeros((KMAX, S), jnp.int32)
         lp0 = jnp.zeros((KMAX, S), jnp.float32)
 
+        # a mixture-of-experts model's routing counters ride the
+        # carry too, summed over the steps (riders only: the other
+        # slots' page-table rows are null)
+        moe0 = (jnp.zeros((E + 3,), jnp.int32),) if E else ()
+
         def body(i, carry):
-            pages, pos, cur, key, buf, lps = carry
+            pages, pos, cur, key, buf, lps, *moe = carry
             key, sub = jax.random.split(key)
             kv = [kv_layer_view(layer, page_table)
                   for layer in pages]
-            logits, new_kv = model.apply(
-                params, cur[:, None], kv_caches=kv, cache_len=pos)
+            logits, new_kv, vec = apply(
+                params, cur[:, None], kv, pos,
+                lambda: page_table[:, :1] != 0)
+            moe = tuple(m + v for m, v in zip(moe, vec))
             with jax.named_scope("sample"):
                 nxt = _pick_token(logits[:, -1], sub, temp)
             if capture:
@@ -3185,13 +3310,13 @@ def _jit_decode(model, temp, KMAX, S, capture, mesh):
             new_pages = constrain(
                 [kv_layer_store(c) for c in new_kv])
             return (new_pages, pos + 1, nxt, key,
-                    buf.at[i].set(nxt), lps)
-        pages, pos, cur, key, buf, lps = jax.lax.fori_loop(
-            0, steps, body, (pages, pos, cur, rng, buf0, lp0))
+                    buf.at[i].set(nxt), lps) + moe
+        pages, pos, cur, key, buf, lps, *moe = jax.lax.fori_loop(
+            0, steps, body, (pages, pos, cur, rng, buf0, lp0) + moe0)
         # key/pos/cur return as device state: the host never syncs
         # on them between dispatches
         out = (buf, lps) if capture else buf
-        return out, pages, key, pos, cur   # buf: [KMAX, S]
+        return (out, pages, key, pos, cur) + tuple(moe)  # buf: [KMAX, S]
 
     return jax.jit(decode, donate_argnums=(1, 3, 4))
 

@@ -86,3 +86,25 @@ def test_paged_decode_compiles(one_chip, H, KH, D, quantized):
         return paged_decode_attention(*a, interpret=False)
 
     _compile(step, one_chip, *shapes)
+
+
+# (sorted pairs, what calls with them): OLMoE-1B-7B's experts (64 of
+# 2048 x 1024, 8 a token) in a 32-slot decode call and in a 4 x 256
+# prefill call, the three matmuls of the mixture
+@pytest.mark.parametrize("M", [256, 8192], ids=["decode", "prefill"])
+def test_grouped_matmul_compiles(one_chip, monkeypatch, M):
+    from ray_tpu.ops import grouped_matmul as gm
+    # the op asks the attached backend, which is the CPU here
+    monkeypatch.setattr(gm, "_use_kernel", lambda: True)
+    E, D, F = 64, 2048, 1024
+
+    def experts(rows, w1, w3, w2, group_sizes):
+        h = jax.nn.silu(gm.grouped_matmul(rows, w1, group_sizes)) * \
+            gm.grouped_matmul(rows, w3, group_sizes)
+        return gm.grouped_matmul(h, w2, group_sizes)
+
+    compiled = _compile(
+        experts, one_chip, ((M, D), jnp.bfloat16),
+        ((E, D, F), jnp.bfloat16), ((E, D, F), jnp.bfloat16),
+        ((E, F, D), jnp.bfloat16), ((E,), jnp.int32))
+    assert compiled.as_text().count("tpu_custom_call") >= 3
