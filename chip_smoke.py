@@ -399,6 +399,8 @@ def kernel_phase(*, paged_shapes=((32, 4, 64), (16, 16, 128)),
                          .reshape(B, MP) + 1, jnp.int32)
         pos = jnp.asarray(rng.integers(0, MP * Pg, B), jnp.int32)
         q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.bfloat16)
+        # the kernel's own head-major contract (the engine's pool is
+        # page-major and reaches the kernel as a transposed view)
         shape = (KH, n_pages, Pg, D)
         pools = {
             "bf16": (jnp.asarray(rng.standard_normal(shape),

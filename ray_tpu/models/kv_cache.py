@@ -6,31 +6,39 @@ coalesces calls but decodes each batch to completion; this pool is the
 structure that lets requests join/leave the decode batch per token):
 
 - The KV pool is ONE static-shape array per layer,
-  ``[n_kv_heads, n_pages, page_size, head_dim]`` — XLA never sees a
+  ``[n_pages, page_size, n_kv_heads, head_dim]`` — XLA never sees a
   dynamic allocation; the host-side ``BlockAllocator`` hands page ids
   to sequences as they grow and reclaims them on completion or
-  preemption. The layout is HEAD-MAJOR so one physical page for one
-  kv head is a contiguous ``[page_size, head_dim]`` tile — exactly
-  what the pallas decode kernel (ops/paged_attention.py) DMAs per
-  grid step, and a shape Mosaic can tile (last two dims divisible by
-  (8, 128) or full). Page-major ``[n_pages, Pg, KH, D]`` would force
-  a (1, Pg, 1, D) block whose sublane dim (1 of KH) Mosaic rejects.
+  preemption. The layout is PAGE-MAJOR: one physical page is one
+  contiguous ``[page_size, n_kv_heads, head_dim]`` slab, which is
+  what every step program moves — ``paged_append`` scatters whole
+  ``[n_kv_heads, head_dim]`` rows at ``(page, offset)`` and the
+  window loop gathers whole pages by id. Up to PR 28 the pool was
+  declared head-major ``[KH, n_pages, Pg, D]`` for the Pallas decode
+  kernel's sake, and the chip showed what that cost: the TPU
+  compiler kept the pool page-major inside every step program
+  anyway, so each program copied every layer's K and V pool into
+  that layout on entry and back on exit — 64 whole-pool copies a
+  dispatch at 16 layers, 14-15 % of three serving cells' chip time
+  and a 2 GiB temporary (PERF.md section 6, PR 29). Declared as it
+  is kept, no program copies it. The kernel (off by default) still
+  wants head-major and is handed a transposed view
+  (models/llama.py ``LlamaAttention``).
 - Page 0 is the NULL page: inactive decode slots point their page
   table at it and harmlessly scatter their dead writes there, so the
   jitted decode step needs no ``lax.cond`` masking — every slot does
   identical work every step (SPMD-friendly, no divergence).
 - Gather/scatter use plain advanced indexing: XLA lowers them to
-  dynamic-gather/scatter HLO that tiles fine on TPU. A dedicated
-  pallas paged-attention kernel can replace the gather later without
-  changing this layout.
+  dynamic-gather/scatter HLO that tiles fine on TPU.
 - ``kv_dtype="int8"`` halves page bytes: pages store int8 with one
-  fp32 absmax scale per (kv_head, physical page) — shape
-  ``[n_kv_heads, n_pages, 1]`` so the scale shards with its
-  head-sharded page column under tensor parallelism. Scales travel
+  fp32 absmax scale per (physical page, kv_head) — shape
+  ``[n_pages, n_kv_heads]``, page-major like the pages, so one page
+  id indexes a page and its scales alike and the head axis shards
+  with its head-sharded pages under tensor parallelism. Scales travel
   with page ids: the allocator, prefix cache, and COW path all deal
-  in page ids only, and every consumer that moves a page column
+  in page ids only, and every consumer that moves a page
   (copy-on-write, placement, donation) moves the matching scale
-  column in the same jitted op. Quantize/dequantize live in
+  row in the same jitted op. Quantize/dequantize live in
   ops/paged_attention.py; nothing outside it interprets the int8
   payload.
 """
@@ -48,10 +56,11 @@ class PagedKVLayer(NamedTuple):
     """Per-layer view of the paged KV pool handed to the attention
     module (a pytree: safe to carry through jit/scan).
 
-    pages_k/pages_v: [n_kv_heads, n_pages, page_size, head_dim]
+    pages_k/pages_v: [n_pages, page_size, n_kv_heads, head_dim]
+                     (page-major: a page is one contiguous slab)
     page_table:      [n_slots, max_pages] int32 — logical page p of
                      slot s lives in physical page ``page_table[s, p]``
-    scales_k/scales_v: [n_kv_heads, n_pages, 1] fp32 per-page absmax
+    scales_k/scales_v: [n_pages, n_kv_heads] fp32 per-page absmax
                      scales when the pool is int8, else None. Optional
                      LAST so fp pytrees keep their PR 1–14 structure.
     """
@@ -63,7 +72,7 @@ class PagedKVLayer(NamedTuple):
 
     @property
     def page_size(self) -> int:
-        return self.pages_k.shape[2]
+        return self.pages_k.shape[1]
 
     @property
     def quantized(self) -> bool:
@@ -96,21 +105,22 @@ def init_kv_pool(cfg, n_pages: int, page_size: int,
                  kv_dtype: str = "fp"):
     """One page pool per layer. Page 0 is reserved (null).
 
-    fp:   [(pages_k, pages_v), ...] in cfg.dtype (unchanged layout).
+    fp:   [(pages_k, pages_v), ...] in cfg.dtype, each
+          [n_pages, page_size, n_kv_heads, head_dim].
     int8: [(pages_k, pages_v, scales_k, scales_v), ...] — int8 pages
-          plus fp32 per-(head, page) absmax scales initialised to 0
+          plus fp32 per-(page, head) absmax scales initialised to 0
           (a 0 scale means "page holds nothing"; paged_append's
           reset-on-offset-0 rule keeps that true across realloc
           without any host-side scale bookkeeping).
     """
-    shape = (cfg.n_kv_heads, n_pages, page_size, cfg.head_dim)
+    shape = (n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
     if kv_dtype == "fp":
         return [(jnp.zeros(shape, cfg.dtype),
                  jnp.zeros(shape, cfg.dtype))
                 for _ in range(cfg.n_layers)]
     if kv_dtype != "int8":
         raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
-    sshape = (cfg.n_kv_heads, n_pages, 1)
+    sshape = (n_pages, cfg.n_kv_heads)
     return [(jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
              jnp.zeros(sshape, KV_SCALE_DTYPE),
              jnp.zeros(sshape, KV_SCALE_DTYPE))
@@ -135,27 +145,27 @@ def kv_pool_page_bytes(cfg, page_size: int,
 
 def export_page_bytes(layers, page: int) -> List[List[bytes]]:
     """Raw bytes of ONE physical page across every layer — the unit a
-    cross-replica KV pull ships. Each entry is the layer's column
+    cross-replica KV pull ships. Each entry is the layer's tensor
     tuple serialized in storage order: ``[k, v]`` for fp pools,
     ``[k, v, sk, sv]`` for int8 (the per-page scales TRAVEL WITH the
-    payload — a page without its scale is garbage). ``t[:, page]`` is
-    the head-major column, so k/v blobs are ``[KH, Pg, D]`` and scale
-    blobs ``[KH, 1]``; blocks until any in-flight device computation
-    producing ``layers`` has settled."""
-    return [[np.asarray(t[:, page]).tobytes() for t in layer]
+    payload — a page without its scale is garbage). ``t[page]`` is
+    the page as it lies in the pool, so k/v blobs are ``[Pg, KH, D]``
+    and scale blobs ``[KH]``; blocks until any in-flight device
+    computation producing ``layers`` has settled."""
+    return [[np.asarray(t[page]).tobytes() for t in layer]
             for layer in layers]
 
 
 def page_cols_from_bytes(cfg, page_size: int, kv_dtype: str,
                          blobs: Sequence[Sequence[bytes]]):
     """Inverse of ``export_page_bytes``: rebuild one page's per-layer
-    column arrays from raw bytes, shaped for a
-    ``pages.at[:, dst].set(col)`` landing — k/v ``[KH, Pg, D]``,
-    scales ``[KH, 1]``. Validates arity and byte counts so a
+    arrays from raw bytes, shaped for a
+    ``pages.at[dst].set(col)`` landing — k/v ``[Pg, KH, D]``,
+    scales ``[KH]``. Validates arity and byte counts so a
     truncated or cross-dtype blob fails typed instead of landing
     garbage KV."""
-    shape = (cfg.n_kv_heads, page_size, cfg.head_dim)
-    sshape = (cfg.n_kv_heads, 1)
+    shape = (page_size, cfg.n_kv_heads, cfg.head_dim)
+    sshape = (cfg.n_kv_heads,)
     if kv_dtype == "int8":
         dts = (np.int8, np.int8,
                np.dtype(KV_SCALE_DTYPE), np.dtype(KV_SCALE_DTYPE))

@@ -23,7 +23,8 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.mesh.sharding import ShardingRules
 from ray_tpu.models.kv_cache import PagedKVLayer
-from ray_tpu.ops.paged_attention import paged_decode_attention
+from ray_tpu.ops.paged_attention import (kernel_pool_view,
+                                         paged_decode_attention)
 
 
 def _use_paged_kernel() -> bool:
@@ -44,7 +45,11 @@ def _use_paged_kernel() -> bool:
     latency behind device compute, but the aliasing defeat is a
     compile-time property of the dispatched computation itself, so
     the per-step pool copy is still paid on-device where no amount
-    of host overlap can cover it. Until that aliasing is proven
+    of host overlap can cover it. Since PR 29 the pool is stored
+    page-major (models/kv_cache.py), the layout the gather and
+    paged_append want; the kernel keeps its head-major contract and
+    reads a transposed view, so this branch starts a further pool
+    copy a step behind (ROADMAP S1 (b)). Until that aliasing is proven
     through the custom call, the gather is the right default on
     every backend; RAY_TPU_PAGED_KERNEL=1 forces the kernel (and
     =0 forces the gather) for experiments and tests. Junk values
@@ -151,8 +156,9 @@ def paged_window_block_pages(page_size: int, max_pages: int) -> int:
 def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
     """Causal grouped-query attention of ``q`` [B, T, H, D] (row b's
     queries at absolute positions ``pos[b] + t``) over its page-table
-    row's K/V in the head-major pool ``pk``/``pv`` [KH, n_pages, Pg, D]
-    (``sk``/``sv``: an int8 pool's per-page scales, else None).
+    row's K/V in the page-major pool ``pk``/``pv`` [n_pages, Pg, KH, D]
+    (``sk``/``sv``: an int8 pool's per-page scales [n_pages, KH], else
+    None). Each page is gathered whole, by its id, as it lies.
 
     Work follows the live contexts, not the table's width: a loop with
     a RUNTIME trip count walks blocks of ``block_pages`` logical pages
@@ -173,7 +179,7 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
     section 3).
     """
     B, T, H, D = q.shape
-    KH, _, Pg, _ = pk.shape
+    _, Pg, KH, _ = pk.shape
     max_pages = page_table.shape[1]
     block_pages = paged_window_block_pages(Pg, max_pages)
     Lb = block_pages * Pg
@@ -195,19 +201,6 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
         last = jnp.max(jnp.where(live, pos + (T - 1), 0))
         n_blocks = jnp.minimum(last // Lb + 1, max_blocks)
 
-    # The pool is head-major for the Pallas kernel; here each page is
-    # gathered whole, so the loop reads a page-major VIEW of it,
-    # [n_pages, Pg, KH, D]. On the TPU that is the layout XLA keeps the
-    # loop-carried pool in (it suits paged_append's scatter), so the
-    # view is free and the pool enters the loop as it lies; gathered
-    # through its head-major shape the compiler re-laid the whole pool
-    # out for the loop, every layer of every step (PERF.md, PR 26).
-    pk_t = pk.transpose(1, 2, 0, 3)
-    pv_t = pv.transpose(1, 2, 0, 3)
-    if sk is not None:
-        sk_t = sk[..., 0].T                                  # [n_pages, KH]
-        sv_t = sv[..., 0].T
-
     def block(j, carry):
         m, l, acc = carry
         with jax.named_scope("kv_gather"):
@@ -215,16 +208,16 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
                 table, j * block_pages, block_pages, axis=1)
             # [B, block_pages, Pg, KH, D] -> [B, Lb, KH, D]; gathered
             # index + j * Lb == logical position by construction
-            kg = pk_t[cols]
-            vg = pv_t[cols]
+            kg = pk[cols]
+            vg = pv[cols]
             if sk is not None:
                 # dequantize the gathered block in fp32 with the
                 # gathered per-page scales (value = q * s / 127): only
                 # one block ever exists in fp, never the pool itself
                 kg = kg.astype(jnp.float32) * \
-                    (sk_t[cols] * (1.0 / 127.0))[:, :, None, :, None]
+                    (sk[cols] * (1.0 / 127.0))[:, :, None, :, None]
                 vg = vg.astype(jnp.float32) * \
-                    (sv_t[cols] * (1.0 / 127.0))[:, :, None, :, None]
+                    (sv[cols] * (1.0 / 127.0))[:, :, None, :, None]
             kg = kg.reshape(B, Lb, KH, D)
             vg = vg.reshape(B, Lb, KH, D)
         with jax.named_scope("attn_scores"):
@@ -327,9 +320,14 @@ class LlamaAttention(nn.Module):
                 # table rides scalar prefetch; the page window is
                 # never materialized (ops/paged_attention.py). Int8
                 # pages dequantize in-register inside the kernel.
+                # The kernel keeps a head-major contract (Mosaic
+                # cannot tile a (1, Pg, 1, D) block), so it reads a
+                # transposed view of the page-major pool.
                 with jax.named_scope("attn_kernel"):
                     y = paged_decode_attention(
-                        q[:, 0], pk, pv, pc.page_table, pos, sk, sv)
+                        q[:, 0], kernel_pool_view(pk),
+                        kernel_pool_view(pv), pc.page_table, pos,
+                        kernel_pool_view(sk), kernel_pool_view(sv))
                 y = y.reshape(B, 1, cfg.n_heads, hd)
             else:
                 # CPU/XLA fallback and chunk prefill: gather and

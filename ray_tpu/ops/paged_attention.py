@@ -13,12 +13,19 @@ softmax in VMEM. Per-step traffic drops from O(B * L) gathered copies
 to O(B * L) page READS only — no gathered intermediate, no scatter of
 it back.
 
-Layout contract (matches models/kv_cache.py):
+Two layout contracts live in this file. ``paged_append`` writes the
+engine's pool, which is PAGE-MAJOR (models/kv_cache.py):
+``[n_pages, page_size, n_kv_heads, head_dim]``, scales
+``[n_pages, n_kv_heads]``. The KERNEL keeps its own head-major
+contract, and its one caller (models/llama.py, under
+RAY_TPU_PAGED_KERNEL=1) hands it a transposed view of the pool
+(``kernel_pool_view``):
   pages_k/pages_v: [n_kv_heads, n_pages, page_size, head_dim] —
                    HEAD-MAJOR so each grid step's block is one
                    contiguous [page_size, head_dim] tile, which
                    Mosaic can tile (page-major would put a size-1
                    slice of n_kv_heads in the sublane dim)
+  scales_k/scales_v: [n_kv_heads, n_pages, 1] fp32 (int8 pools)
   page_table:      [n_slots, max_pages] int32 (0 = null page)
   positions:       [n_slots]            int32 — current decode
                    position; the step attends keys 0..pos inclusive
@@ -77,7 +84,7 @@ class PagedShapeError(ValueError):
 def _check_append_shapes(pages_k, pages_v, page_table, pos, k, v):
     if pages_k.ndim != 4 or pages_v.ndim != 4:
         raise PagedShapeError(
-            f"pages_k/pages_v must be rank-4 [KH, n_pages, Pg, D]; "
+            f"pages_k/pages_v must be rank-4 [n_pages, Pg, KH, D]; "
             f"got pages_k {pages_k.shape}, pages_v {pages_v.shape}")
     if pages_k.shape != pages_v.shape:
         raise PagedShapeError(
@@ -90,7 +97,7 @@ def _check_append_shapes(pages_k, pages_v, page_table, pos, k, v):
     if k.shape != v.shape:
         raise PagedShapeError(
             f"k and v chunks disagree: {k.shape} vs {v.shape}")
-    KH, _, _, D = pages_k.shape
+    _, _, KH, D = pages_k.shape
     if k.shape[2] != KH:
         raise PagedShapeError(
             f"chunk has {k.shape[2]} kv heads but the page pool holds "
@@ -116,14 +123,12 @@ def _check_append_shapes(pages_k, pages_v, page_table, pos, k, v):
             f"pos must be [B]={k.shape[0]}; got shape {pos.shape}")
 
 
-def _check_scale_shapes(pages_k, scales_k, scales_v):
-    KH, n_pages = pages_k.shape[:2]
-    want = (KH, n_pages, 1)
+def _check_scale_shapes(pages_k, scales_k, scales_v, want):
     for name, s in (("scales_k", scales_k), ("scales_v", scales_v)):
         if s.shape != want:
             raise PagedShapeError(
-                f"{name} must be [KH, n_pages, 1]={want} to pair with "
-                f"pool {pages_k.shape}; got {s.shape}")
+                f"{name} must be {want} to pair with pool "
+                f"{pages_k.shape}; got {s.shape}")
     if pages_k.dtype != jnp.int8:
         raise PagedShapeError(
             f"per-page scales supplied but the pool is {pages_k.dtype}"
@@ -132,12 +137,12 @@ def _check_scale_shapes(pages_k, scales_k, scales_v):
 
 def paged_append(pages_k, pages_v, page_table, pos, k, v,
                  scales_k=None, scales_v=None):
-    """Scatter a [B, T] chunk of new K/V into the head-major page pool
+    """Scatter a [B, T] chunk of new K/V into the page-major page pool
     at each slot's current write offset (append-at-offset: the chunk
     may START mid-page and SPAN page boundaries — the partial-prompt
     case chunked prefill creates).
 
-    pages_k/pages_v: [KH, n_pages, Pg, D] (head-major pool)
+    pages_k/pages_v: [n_pages, Pg, KH, D] (page-major pool)
     page_table:      [B, max_pages] int32 (0 = null page)
     pos:             [B] int32 — first token of the chunk lands at
                      logical position ``pos[b]``
@@ -152,7 +157,7 @@ def paged_append(pages_k, pages_v, page_table, pos, k, v,
     addressable window so a padded tail can never alias another
     slot's pages through index clamping.
 
-    Int8 pools pass ``scales_k``/``scales_v`` ([KH, n_pages, 1] fp32
+    Int8 pools pass ``scales_k``/``scales_v`` ([n_pages, KH] fp32
     per-page absmax) and get a 4-tuple back (pages + updated scales).
     The append then does three scatters per tensor:
 
@@ -192,10 +197,11 @@ def paged_append(pages_k, pages_v, page_table, pos, k, v,
         raise PagedShapeError(
             "int8 pool appended without its per-page scales — pass "
             "scales_k/scales_v (kv_dtype='int8' wiring bug)")
+    n_pages, Pg, KH, D = pages_k.shape
     if quantized:
-        _check_scale_shapes(pages_k, scales_k, scales_v)
+        _check_scale_shapes(pages_k, scales_k, scales_v,
+                            (n_pages, KH))
     B, T = k.shape[:2]
-    Pg = pages_k.shape[2]
     max_pages = page_table.shape[1]
     tpos = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None]  # [B, T]
     tpos = jnp.minimum(tpos, max_pages * Pg - 1)
@@ -203,44 +209,43 @@ def paged_append(pages_k, pages_v, page_table, pos, k, v,
     off = tpos % Pg
     flat_p = pidx.reshape(-1)
     flat_o = off.reshape(-1)
-    # [B, T, KH, D] -> [KH, B*T, D] to match the head-major pool.
-    kT = k.reshape(B * T, -1, k.shape[-1]).transpose(1, 0, 2)
-    vT = v.reshape(B * T, -1, v.shape[-1]).transpose(1, 0, 2)
+    # [B, T, KH, D] -> [B*T, KH, D]: a token's row as it lies in a page
+    kT = k.reshape(B * T, KH, D)
+    vT = v.reshape(B * T, KH, D)
     if not quantized:
-        return (pages_k.at[:, flat_p, flat_o].set(
+        return (pages_k.at[flat_p, flat_o].set(
                     kT.astype(pages_k.dtype)),
-                pages_v.at[:, flat_p, flat_o].set(
+                pages_v.at[flat_p, flat_o].set(
                     vT.astype(pages_v.dtype)))
 
-    n_pages = pages_k.shape[1]
     # (1) pages whose offset-0 slot this chunk writes start over.
     reset = jnp.zeros((n_pages,), jnp.bool_).at[flat_p].max(
         flat_o == 0)                                   # [n_pages]
 
     def _one(pages, scales, xT):
-        xT32 = xT.astype(jnp.float32)                  # [KH, B*T, D]
-        s_base = jnp.where(reset[None, :, None], 0.0,
+        xT32 = xT.astype(jnp.float32)                  # [B*T, KH, D]
+        s_base = jnp.where(reset[:, None], 0.0,
                            scales.astype(jnp.float32))
         # (2) running absmax, monotone while the page is live.
-        amax = jnp.max(jnp.abs(xT32), axis=2)          # [KH, B*T]
-        s_new = s_base.at[:, flat_p, 0].max(amax)      # [KH, n_pages, 1]
+        amax = jnp.max(jnp.abs(xT32), axis=2)          # [B*T, KH]
+        s_new = s_base.at[flat_p].max(amax)            # [n_pages, KH]
         # (3a) re-code touched pages old-scale -> new-scale. Gathering
         # per token (not per unique page) keeps this jit-static;
         # duplicates recompute identical bytes.
-        old_q = pages[:, flat_p].astype(jnp.float32)   # [KH, BT, Pg, D]
-        sb = s_base[:, flat_p]                         # [KH, BT, 1]
-        sn = s_new[:, flat_p]
+        old_q = pages[flat_p].astype(jnp.float32)      # [BT, Pg, KH, D]
+        sb = s_base[flat_p]                            # [BT, KH]
+        sn = s_new[flat_p]
         ratio = jnp.where(sn > 0.0, sb / jnp.maximum(sn, 1e-30), 0.0)
-        req = jnp.clip(jnp.round(old_q * ratio[..., None]),
+        req = jnp.clip(jnp.round(old_q * ratio[:, None, :, None]),
                        -_QMAX, _QMAX).astype(jnp.int8)
-        pages = pages.at[:, flat_p].set(req)
+        pages = pages.at[flat_p].set(req)
         # (3b) quantize the chunk tokens at the new scale. A zero page
         # scale implies the token itself is all-zero (absmax was maxed
         # in above), so the guarded divide is exact, not a fudge.
         inv = jnp.where(sn > 0.0, _QMAX / jnp.maximum(sn, 1e-30), 0.0)
-        q_tok = jnp.clip(jnp.round(xT32 * inv), -_QMAX, _QMAX
-                         ).astype(jnp.int8)
-        pages = pages.at[:, flat_p, flat_o].set(q_tok)
+        q_tok = jnp.clip(jnp.round(xT32 * inv[..., None]),
+                         -_QMAX, _QMAX).astype(jnp.int8)
+        pages = pages.at[flat_p, flat_o].set(q_tok)
         return pages, s_new.astype(scales.dtype)
 
     new_pk, new_sk = _one(pages_k, scales_k, kT)
@@ -331,6 +336,16 @@ def _kernel_q(pt_ref, pos_ref, sk_ref, sv_ref, q_ref, k_ref, v_ref,
                  m_sc, l_sc, acc_sc, page_size=page_size, scale=scale)
 
 
+def kernel_pool_view(t):
+    """The kernel's head-major view of one tensor of the engine's
+    page-major pool: pages [n_pages, Pg, KH, D] -> [KH, n_pages, Pg, D],
+    an int8 pool's scales [n_pages, KH] -> [KH, n_pages, 1], None as
+    it is (an fp pool has no scales)."""
+    if t is None:
+        return None
+    return t.transpose(2, 0, 1, 3) if t.ndim == 4 else t.T[..., None]
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q, pages_k, pages_v, page_table, positions,
                            scales_k=None, scales_v=None,
@@ -338,8 +353,9 @@ def paged_decode_attention(q, pages_k, pages_v, page_table, positions,
     """One decode step of paged attention.
 
     q: [B, H, D]; returns [B, H, D] in q.dtype. See module docstring
-    for the pool layout. Falls back transparently to interpreter mode
-    off-TPU (tests). Int8 pools pass scales_k/scales_v
+    for the kernel's HEAD-MAJOR layout ([KH, n_pages, Pg, D]: a
+    transposed view of the engine's page-major pool). Falls back
+    transparently to interpreter mode off-TPU (tests). Int8 pools pass scales_k/scales_v
     ([KH, n_pages, 1] fp32) and get in-register dequantization.
     """
     B, H, D = q.shape
@@ -351,7 +367,8 @@ def paged_decode_attention(q, pages_k, pages_v, page_table, positions,
     scale = 1.0 / (D ** 0.5)
     quantized = scales_k is not None
     if quantized:
-        _check_scale_shapes(pages_k, scales_k, scales_v)
+        _check_scale_shapes(pages_k, scales_k, scales_v,
+                            (KH, n_pages, 1))
 
     grid = (B, max_pages)
     prefetch = [page_table, positions]
@@ -400,8 +417,9 @@ def paged_decode_attention(q, pages_k, pages_v, page_table, positions,
 
 
 def dequantize_pages(pages, scales):
-    """Debug/test helper: materialize the fp view of an int8 pool
-    (``q * s / 127``). NEVER used on the serving path — the whole
-    point of the int8 mode is that this tensor never exists there."""
+    """Debug/test helper: materialize the fp view of a page-major int8
+    pool ([n_pages, Pg, KH, D], scales [n_pages, KH]: ``q * s / 127``).
+    NEVER used on the serving path — the whole point of the int8 mode
+    is that this tensor never exists there."""
     return pages.astype(jnp.float32) * (
-        scales.astype(jnp.float32) / _QMAX)[..., None]
+        scales.astype(jnp.float32) / _QMAX)[:, None, :, None]

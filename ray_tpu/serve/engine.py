@@ -3179,7 +3179,7 @@ def _jit_write_page(mesh):
 
     def write(pages, dst, cols):
         return constrain(
-            [tuple(t.at[:, dst].set(c)
+            [tuple(t.at[dst].set(c)
                    for t, c in zip(layer, layer_cols))
              for layer, layer_cols in zip(pages, cols)])
     return jax.jit(write, donate_argnums=(0,))
@@ -3329,17 +3329,17 @@ def _jit_copy_page(mesh):
     into a private page so the one-token re-prefill (the model
     needs the last position's logits) never scatters into a
     shared page. src/dst are traced scalars: one executable.
-    Under tensor parallelism the copy stays device-local: axis 0
-    (the sharded kv-head axis) is untouched, each device
-    duplicates its own head shard of the page."""
+    Under tensor parallelism the copy stays device-local: the
+    sharded kv-head axis is untouched, each device duplicates its
+    own head shard of the page."""
     constrain = _constrain_for(mesh)
 
     def copy(pages, src, dst):
         # int8 layers are 4-tuples whose trailing scale tensors
-        # copy their (rank-3) page column the same way — COW gets
+        # copy their (rank-2) page row the same way — COW gets
         # the page's quantization scale for free, so a COW'd page
         # dequantizes identically to its source
-        return constrain([tuple(t.at[:, dst].set(t[:, src])
+        return constrain([tuple(t.at[dst].set(t[src])
                                 for t in layer)
                           for layer in pages])
     return jax.jit(copy, donate_argnums=(0,))

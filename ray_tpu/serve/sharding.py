@@ -13,9 +13,9 @@ KV pool:
   row-parallel wo/w2, vocab-parallel embeddings; Mixtral adds
   expert-parallel w1/w3/w2 over the ``expert`` axis with a replicated
   router (``mixtral_sharding_rules``).
-- The KV pool is HEAD-sharded: the head-major layout
-  ``[n_kv_heads, n_pages, page_size, head_dim]`` shards axis 0 over
-  ``tensor``, so every KV operation the engine performs —
+- The KV pool is HEAD-sharded: the page-major layout
+  ``[n_pages, page_size, n_kv_heads, head_dim]`` shards axis 2 (the
+  kv heads) over ``tensor``, so every KV operation the engine performs —
   ``paged_append`` scatter, decode gather, spec-verify, prefix-cache
   page copy — indexes only the page/offset axes and stays
   device-local. No KV collectives exist; the only cross-device
@@ -47,14 +47,15 @@ from ray_tpu.mesh.device_mesh import create_mesh
 from ray_tpu.mesh.sharding import (ShardingRules, match_partition_rules,
                                    infer_sharding)
 
-# KV pool layout contract (models/kv_cache.py): axis 0 is n_kv_heads,
-# the ONLY sharded axis — pages/offsets stay whole on every device.
-KV_POOL_SPEC = P("tensor", None, None, None)
-# Int8 pools carry per-(kv_head, page) fp32 scales [KH, n_pages, 1]:
+# KV pool layout contract (models/kv_cache.py): the pool is page-major
+# [n_pages, Pg, KH, D]; axis 2 is n_kv_heads, the ONLY sharded axis —
+# pages/offsets stay whole on every device.
+KV_POOL_SPEC = P(None, None, "tensor", None)
+# Int8 pools carry per-(page, kv_head) fp32 scales [n_pages, KH]:
 # same head axis sharded, so each device holds exactly the scales for
 # its own page shards and quantize/dequantize stays device-local — no
 # new collectives enter the KV path.
-KV_SCALE_SPEC = P("tensor", None, None)
+KV_SCALE_SPEC = P(None, "tensor")
 
 
 def constrain_kv_pool(mesh: Mesh, pages):
@@ -64,12 +65,12 @@ def constrain_kv_pool(mesh: Mesh, pages):
     applied to every jitted step's output pool it guarantees GSPMD
     can never reshard the pool (which would both break donation
     aliasing and introduce the KV collectives this layer exists to
-    avoid). Rank-dispatches so int8 scale tensors (rank 3) pin to
+    avoid). Rank-dispatches so int8 scale tensors (rank 2) pin to
     their own spec alongside the rank-4 pages."""
     return jax.tree_util.tree_map(
         lambda t: jax.lax.with_sharding_constraint(
             t, NamedSharding(
-                mesh, KV_SCALE_SPEC if t.ndim == 3 else KV_POOL_SPEC)),
+                mesh, KV_SCALE_SPEC if t.ndim == 2 else KV_POOL_SPEC)),
         pages)
 
 
@@ -133,9 +134,9 @@ class EngineSharding:
         self.replicated = NamedSharding(mesh, P())
 
     def _kv_sharding_for(self, t):
-        # rank dispatch: rank-4 page pools vs rank-3 scale tensors
-        # (int8 mode) — both head-sharded on axis 0
-        return (self.kv_scale_sharding if getattr(t, "ndim", 4) == 3
+        # rank dispatch: rank-4 page pools vs rank-2 scale tensors
+        # (int8 mode) — both sharded on their kv-head axis
+        return (self.kv_scale_sharding if getattr(t, "ndim", 4) == 2
                 else self.kv_sharding)
 
     @classmethod
@@ -181,11 +182,11 @@ class EngineSharding:
 
     def place_kv_pool(self, pages: List[Any]):
         """Head-shard the paged KV pool: each layer's (pages_k,
-        pages_v) splits axis 0 (kv heads) over ``tensor``. Page
+        pages_v) splits axis 2 (kv heads) over ``tensor``. Page
         indices and in-page offsets are global coordinates valid on
         every device, so the host-side allocator / prefix cache /
         page tables need no changes. Int8 layers are 4-tuples (pages
-        + per-page scales); rank-3 scale tensors pin to KV_SCALE_SPEC
+        + per-page scales); rank-2 scale tensors pin to KV_SCALE_SPEC
         next to their head-sharded pages."""
         return [tuple(jax.device_put(t, self._kv_sharding_for(t))
                       for t in layer) for layer in pages]

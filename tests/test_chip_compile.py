@@ -1,5 +1,8 @@
 """The main path's Pallas kernels compiled for a described (not
-attached) TPU v5e, at the widths chip_smoke.py runs them.
+attached) TPU v5e, at the widths chip_smoke.py runs them, and the
+engine's step programs at the serving cells' KV pool shapes (that no
+program copies the pool can be read off the program, not counted at
+run time).
 
 Interpret mode accepts block shapes the chip's compiler refuses, so
 these compiles are the only CPU-side guard against a kernel that
@@ -10,7 +13,9 @@ pytest worker imports every file), and the persistent compile cache
 is off around the compiles (an entry written for a described chip
 cannot be read back without one).
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,11 +23,12 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.ops import flash_attention as flash_mod
-from ray_tpu.ops.paged_attention import paged_decode_attention
+from ray_tpu.ops.paged_attention import (kernel_pool_view,
+                                         paged_decode_attention)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -34,9 +40,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, one_chip, *shapes):
@@ -75,15 +86,19 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, monkeypatch,
 def test_paged_decode_compiles(one_chip, H, KH, D, quantized):
     B, Pg, per_seq = 16, 64, 16
     n_pages = B * per_seq + 1
-    pool = ((KH, n_pages, Pg, D),
+    # the engine's page-major pool; the kernel reads the head-major
+    # view LlamaAttention hands it
+    pool = ((n_pages, Pg, KH, D),
             jnp.int8 if quantized else jnp.bfloat16)
     shapes = [((B, H, D), jnp.bfloat16), pool, pool,
               ((B, per_seq), jnp.int32), ((B,), jnp.int32)]
     if quantized:
-        shapes += [((KH, n_pages, 1), jnp.float32)] * 2
+        shapes += [((n_pages, KH), jnp.float32)] * 2
 
-    def step(*a):
-        return paged_decode_attention(*a, interpret=False)
+    def step(q, pk, pv, pt, pos, *scales):
+        return paged_decode_attention(
+            q, kernel_pool_view(pk), kernel_pool_view(pv), pt, pos,
+            *map(kernel_pool_view, scales), interpret=False)
 
     _compile(step, one_chip, *shapes)
 
@@ -108,3 +123,125 @@ def test_grouped_matmul_compiles(one_chip, monkeypatch, M):
         ((E, D, F), jnp.bfloat16), ((E, D, F), jnp.bfloat16),
         ((E, F, D), jnp.bfloat16), ((E,), jnp.int32))
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+# ---------------------------------------------------------------
+# The engine's step programs keep the KV pool as it is stored
+# (models/kv_cache.py says what a pool declared otherwise cost).
+
+N_PAGES, PAGE, SLOTS, KMAX = 513, 64, 32, 8
+
+
+def _cell_cfg(kv_heads):
+    """Two layers at a serving cell's attention and pool widths:
+    Mistral-7B (32 heads over 8 KV heads, 4096 wide) or OLMoE-1B-7B's
+    attention (16 over 16, 2048 wide; a dense MLP stands in for the
+    mixture, which never touches the pool)."""
+    from ray_tpu.models.llama import LlamaConfig
+    heads, dim, hidden = {8: (32, 4096, 14336),
+                          16: (16, 2048, 1024)}[kv_heads]
+    return LlamaConfig(vocab_size=32768, max_seq_len=4096, dim=dim,
+                       n_layers=2, n_heads=heads, n_kv_heads=kv_heads,
+                       hidden_dim=hidden, rope_theta=1e6,
+                       dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+
+
+def _program(name, model, mesh):
+    """(jitted program, its arguments after params and pages) as the
+    engine builds and calls them."""
+    from ray_tpu.serve import engine as engine_mod
+    i32 = jnp.int32
+    table = ((SLOTS, model.config.max_seq_len // PAGE), i32)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    key = (key.shape, key.dtype)
+    if name == "decode":
+        return (engine_mod._jit_decode(model, 0.0, KMAX, SLOTS, False,
+                                       mesh),
+                [table, ((SLOTS,), i32), ((SLOTS,), i32), key,
+                 ((), i32)])
+    if name == "prefill":
+        B, T = 4, 256
+        return (engine_mod._jit_prefill(model, 0.0, B, False, mesh),
+                [((B, T), i32), ((B,), i32), ((B,), i32),
+                 ((B, table[0][1]), i32), key])
+    T = 5                                   # spec_len 4
+    return (engine_mod._jit_verify(model, mesh),
+            [((SLOTS, T), i32), ((SLOTS,), i32), table])
+
+
+def _pool_copies(text, shard_shape):
+    """Lines of the compiled program that COPY a tensor of one
+    layer's pool shape (a plain copy or a fusion XLA named for one),
+    whatever layout they write."""
+    dims = ",".join(str(d) for d in shard_shape)
+    pat = re.compile(
+        r"^\s*(?:ROOT )?%?(\S+) = bf16\[" + dims
+        + r"\](?:\{[^}]*\})? (copy|fusion)\(", re.M)
+    return [m.group(0).strip() for m in pat.finditer(text)
+            if m.group(2) == "copy" or m.group(1).startswith("copy")]
+
+
+def _compile_step(name, cfg, pool_sharding, param_sharding, small,
+                  mesh=None):
+    from ray_tpu.models.kv_cache import init_kv_pool
+    from ray_tpu.models.llama import Llama
+    model = Llama(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    params = jax.tree_util.tree_map(
+        lambda t, sh: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                           sharding=sh),
+        params, param_sharding(params))
+    pages = jax.eval_shape(lambda: init_kv_pool(cfg, N_PAGES, PAGE))
+    pages = jax.tree_util.tree_map(
+        lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                       sharding=pool_sharding), pages)
+    fn, rest = _program(name, model, mesh)
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=small)
+            for s, d in rest]
+    compiled = fn.lower(params, pages, *rest).compile()
+    pool = pages[0][0]
+    return compiled, pool
+
+
+def _assert_pool_stays(compiled, pool, shard_shape):
+    assert pool.shape == (N_PAGES, PAGE) + pool.shape[2:], (
+        "the pool is stored page-major", pool.shape)
+    copies = _pool_copies(compiled.as_text(), shard_shape)
+    assert not copies, (
+        f"{len(copies)} whole-pool copies in the program", copies[:4])
+    # 2 layers x (K, V) x bf16
+    one_pool = 2 * 2 * math.prod(shard_shape) * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < one_pool, (
+        "the program's temporaries hold a second pool", temp, one_pool)
+
+
+@pytest.mark.parametrize("kv_heads", [8, 16], ids=["mistral", "olmoe"])
+@pytest.mark.parametrize("name", ["decode", "prefill", "verify"])
+def test_step_programs_copy_no_pool(one_chip, name, kv_heads):
+    cfg = _cell_cfg(kv_heads)
+    compiled, pool = _compile_step(
+        name, cfg, one_chip,
+        lambda params: jax.tree_util.tree_map(lambda _: one_chip,
+                                              params),
+        one_chip)
+    _assert_pool_stays(compiled, pool, pool.shape)
+
+
+def test_decode_copies_no_pool_shard_under_tp4(topo):
+    """Tensor-parallel over the four described chips: each chip holds
+    2 of Mistral's 8 KV heads of every page, and copies none of it."""
+    from ray_tpu.mesh.sharding import infer_sharding
+    from ray_tpu.serve.sharding import EngineSharding
+    cfg = _cell_cfg(8)
+    try:
+        sh = EngineSharding.build(cfg, tp=4, devices=topo.devices)
+    except Exception as e:
+        pytest.skip(f"no tensor mesh over the described chips: {e}")
+    compiled, pool = _compile_step(
+        "decode", cfg, sh.kv_sharding,
+        lambda params: infer_sharding(params, sh.rules, sh.mesh),
+        sh.replicated, mesh=sh.mesh)
+    _assert_pool_stays(compiled, pool,
+                       sh.kv_sharding.shard_shape(pool.shape))

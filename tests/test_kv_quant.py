@@ -15,7 +15,7 @@ hits replay the SAME quantized bytes -> identical tokens), tolerance-
 equal vs fp (token agreement gated at the same floor the kvq A/B
 artifact records — quantized bytes are write-history dependent, see
 docs/serving.md), spec accept-rate preserved, tp-sharded pools with
-scale columns pinned alongside their heads, and the bytes view
+scale rows pinned alongside their heads, and the bytes view
 (kv_pool_page_bytes -> BlockAllocator -> load_report -> gauge).
 """
 import os
@@ -30,6 +30,7 @@ from ray_tpu.models.kv_cache import (BlockAllocator, init_kv_pool,
                                      kv_pool_page_bytes, PagedKVLayer)
 from ray_tpu.models.llama import Llama, llama_tiny
 from ray_tpu.ops.paged_attention import (dequantize_pages,
+                                         kernel_pool_view,
                                          paged_append,
                                          paged_decode_attention,
                                          PagedShapeError)
@@ -47,10 +48,10 @@ paged_append = jax.jit(paged_append)
 
 
 def _fresh(n_pages=8, B=1, max_pages=4):
-    pk = jnp.zeros((KH, n_pages, PG, D), jnp.int8)
-    pv = jnp.zeros((KH, n_pages, PG, D), jnp.int8)
-    sk = jnp.zeros((KH, n_pages, 1), jnp.float32)
-    sv = jnp.zeros((KH, n_pages, 1), jnp.float32)
+    pk = jnp.zeros((n_pages, PG, KH, D), jnp.int8)
+    pv = jnp.zeros((n_pages, PG, KH, D), jnp.int8)
+    sk = jnp.zeros((n_pages, KH), jnp.float32)
+    sv = jnp.zeros((n_pages, KH), jnp.float32)
     pt = jnp.asarray(
         np.arange(1, 1 + B * max_pages).reshape(B, max_pages),
         jnp.int32)
@@ -72,13 +73,13 @@ def test_bulk_roundtrip_within_half_step():
     pk, pv, sk, sv = paged_append(pk, pv, pt, jnp.zeros(1, jnp.int32),
                                   k, v, sk, sv)
     deq = np.asarray(dequantize_pages(pk, sk))
-    ref = np.asarray(k)[0].transpose(1, 0, 2)      # [KH, T, D]
+    ref = np.asarray(k)[0]                         # [T, KH, D]
     for page, lo in ((1, 0), (2, PG)):
         # int8 rounding error is at most half a quantization step:
         # scale (= page absmax) / 254 per element
-        tol = np.asarray(sk)[:, page] / 254.0 + 1e-6
-        err = np.abs(deq[:, page] - ref[:, lo:lo + PG])
-        assert (err <= tol[..., None]).all()
+        tol = np.asarray(sk)[page] / 254.0 + 1e-6  # [KH]
+        err = np.abs(deq[page] - ref[lo:lo + PG])
+        assert (err <= tol[None, :, None]).all()
 
 
 def test_per_page_scales_isolate_magnitude():
@@ -93,14 +94,14 @@ def test_per_page_scales_isolate_magnitude():
     pk, pv, sk, sv = paged_append(pk, pv, pt, jnp.zeros(1, jnp.int32),
                                   k, v, sk, sv)
     sk_np = np.asarray(sk)
-    assert (sk_np[:, 1] > 1.0).all()       # big page's absmax
-    assert (sk_np[:, 2] < 0.1).all()       # small page kept its own
+    assert (sk_np[1] > 1.0).all()          # big page's absmax
+    assert (sk_np[2] < 0.1).all()          # small page kept its own
     deq = np.asarray(dequantize_pages(pk, sk))
-    small_ref = np.asarray(k_small)[0].transpose(1, 0, 2)
-    err = np.abs(deq[:, 2] - small_ref)
+    small_ref = np.asarray(k_small)[0]
+    err = np.abs(deq[2] - small_ref)
     # resolution follows the SMALL page's scale; under one shared
     # scale the error would be ~100/254, four orders worse
-    assert err.max() <= sk_np[:, 2].max() / 254.0 + 1e-7
+    assert err.max() <= sk_np[2].max() / 254.0 + 1e-7
 
 
 def test_incremental_scale_matches_bulk_and_is_monotone():
@@ -111,12 +112,12 @@ def test_incremental_scale_matches_bulk_and_is_monotone():
                                     jnp.zeros(1, jnp.int32), k, v,
                                     sk, sv)
     ik, iv, isk, isv = pk, pv, sk, sv
-    last = np.zeros((KH, 1))
+    last = np.zeros((KH,))
     for t in range(PG):
         ik, iv, isk, isv = paged_append(
             ik, iv, pt, jnp.full((1,), t, jnp.int32),
             k[:, t:t + 1], v[:, t:t + 1], isk, isv)
-        cur = np.asarray(isk)[:, 1]
+        cur = np.asarray(isk)[1]
         assert (cur >= last - 1e-7).all()  # monotone while page live
         last = cur
     # same tokens -> same final absmax, both build orders
@@ -125,9 +126,9 @@ def test_incremental_scale_matches_bulk_and_is_monotone():
     # BYTES may differ (write-history dependent re-rounding: the
     # incremental build re-codes earlier tokens at each scale growth,
     # double-rounding them) but values stay within one extra step
-    deq_b = np.asarray(dequantize_pages(bk, bsk))[:, 1]
-    deq_i = np.asarray(dequantize_pages(ik, isk))[:, 1]
-    step = np.asarray(bsk)[:, 1][..., None] / 127.0
+    deq_b = np.asarray(dequantize_pages(bk, bsk))[1]
+    deq_i = np.asarray(dequantize_pages(ik, isk))[1]
+    step = np.asarray(bsk)[1][None, :, None] / 127.0
     assert (np.abs(deq_b - deq_i) <= 1.5 * step + 1e-7).all()
 
 
@@ -140,15 +141,15 @@ def test_scale_resets_on_offset_zero_rewrite():
     k_big, v_big = _kv(rng, 1, PG, scale=50.0)
     pk, pv, sk, sv = paged_append(pk, pv, pt, jnp.zeros(1, jnp.int32),
                                   k_big, v_big, sk, sv)
-    assert np.asarray(sk)[:, 1].max() > 10.0
+    assert np.asarray(sk)[1].max() > 10.0
     k_small, v_small = _kv(rng, 1, PG, scale=0.02)
     pk, pv, sk, sv = paged_append(pk, pv, pt, jnp.zeros(1, jnp.int32),
                                   k_small, v_small, sk, sv)
     sk_np = np.asarray(sk)
-    assert sk_np[:, 1].max() < 0.1         # old owner's scale is gone
-    deq = np.asarray(dequantize_pages(pk, sk))[:, 1]
-    ref = np.asarray(k_small)[0].transpose(1, 0, 2)
-    assert np.abs(deq - ref).max() <= sk_np[:, 1].max() / 254.0 + 1e-7
+    assert sk_np[1].max() < 0.1            # old owner's scale is gone
+    deq = np.asarray(dequantize_pages(pk, sk))[1]
+    ref = np.asarray(k_small)[0]
+    assert np.abs(deq - ref).max() <= sk_np[1].max() / 254.0 + 1e-7
 
 
 def test_mid_page_append_grows_scale_without_reset():
@@ -159,15 +160,15 @@ def test_mid_page_append_grows_scale_without_reset():
     k1, v1 = _kv(rng, 1, 4, scale=0.5)
     pk, pv, sk, sv = paged_append(pk, pv, pt, jnp.zeros(1, jnp.int32),
                                   k1, v1, sk, sv)
-    s1 = np.asarray(sk)[:, 1].copy()
+    s1 = np.asarray(sk)[1].copy()
     k2, v2 = _kv(rng, 1, 4, scale=20.0)    # same page, offsets 4..7
     pk, pv, sk, sv = paged_append(pk, pv, pt,
                                   jnp.full((1,), 4, jnp.int32),
                                   k2, v2, sk, sv)
-    s2 = np.asarray(sk)[:, 1]
+    s2 = np.asarray(sk)[1]
     assert (s2 >= s1 - 1e-7).all() and s2.max() > 5.0
-    deq = np.asarray(dequantize_pages(pk, sk))[:, 1, :4]
-    ref = np.asarray(k1)[0].transpose(1, 0, 2)
+    deq = np.asarray(dequantize_pages(pk, sk))[1, :4]
+    ref = np.asarray(k1)[0]
     # earlier tokens survived the re-code at the grown scale: error
     # is one step of the NEW scale (coarser, but never garbage)
     assert np.abs(deq - ref).max() <= s2.max() / 127.0 + 1e-6
@@ -179,18 +180,26 @@ def _dense_ref_deq(q, pk, sk, pv, sv, pt, pos):
     kg = np.asarray(dequantize_pages(pk, sk))
     vg = np.asarray(dequantize_pages(pv, sv))
     B, H, Dh = q.shape
-    kh = kg.shape[0]
-    L = pt.shape[1] * pk.shape[2]
-    kq = kg[:, np.asarray(pt)].reshape(kh, B, L, Dh)
-    vq = vg[:, np.asarray(pt)].reshape(kh, B, L, Dh)
+    kh = kg.shape[2]
+    L = pt.shape[1] * pk.shape[1]
+    kq = kg[np.asarray(pt)].reshape(B, L, kh, Dh)
+    vq = vg[np.asarray(pt)].reshape(B, L, kh, Dh)
     qg = np.asarray(q).reshape(B, kh, H // kh, Dh).astype(np.float32)
-    s = np.einsum("bkrd,kbsd->bkrs", qg, kq) / np.sqrt(Dh)
+    s = np.einsum("bkrd,bskd->bkrs", qg, kq) / np.sqrt(Dh)
     valid = np.arange(L)[None] <= np.asarray(pos)[:, None]
     s = np.where(valid[:, None, None, :], s, -1e30)
     s = s - s.max(axis=-1, keepdims=True)
     p = np.exp(s)
     p /= p.sum(axis=-1, keepdims=True)
-    return np.einsum("bkrs,kbsd->bkrd", p, vq).reshape(B, H, Dh)
+    return np.einsum("bkrs,bskd->bkrd", p, vq).reshape(B, H, Dh)
+
+
+def _kernel(q, pk, pv, pt, pos, sk, sv):
+    """The Pallas kernel on the head-major VIEW of a page-major pool,
+    as ``LlamaAttention`` hands it over."""
+    pk, pv, sk, sv = map(kernel_pool_view, (pk, pv, sk, sv))
+    return np.asarray(paged_decode_attention(q, pk, pv, pt, pos, sk, sv,
+                                             interpret=True))
 
 
 def test_rollback_garbage_is_masked_and_precision_only():
@@ -207,13 +216,11 @@ def test_rollback_garbage_is_masked_and_precision_only():
     pk2, pv2, sk2, sv2 = paged_append(
         pk, pv, pt, jnp.full((1,), n_real, jnp.int32), kg, vg,
         sk, sv)
-    assert np.asarray(sk2)[:, 1].max() > np.asarray(sk)[:, 1].max()
+    assert np.asarray(sk2)[1].max() > np.asarray(sk)[1].max()
     q = jnp.asarray(rng.standard_normal((1, 2 * KH, D)),
                     jnp.float32)
     pos = jnp.full((1,), n_real - 1, jnp.int32)
-    out = np.asarray(paged_decode_attention(q, pk2, pv2, pt, pos,
-                                            sk2, sv2,
-                                            interpret=True))
+    out = _kernel(q, pk2, pv2, pt, pos, sk2, sv2)
     # reference over the dequantized REAL window of the garbage pool:
     # the garbage positions are masked, so only the re-rounding of
     # the real tokens (scale growth) can move the output
@@ -227,20 +234,19 @@ def test_rollback_garbage_is_masked_and_precision_only():
 def test_kernel_matches_gather_dequant_int8():
     rng = np.random.default_rng(6)
     B, max_pages, n_pages = 3, 4, 32
-    pk = jnp.asarray(rng.integers(-127, 128, (KH, n_pages, PG, D)),
+    pk = jnp.asarray(rng.integers(-127, 128, (n_pages, PG, KH, D)),
                      jnp.int8)
-    pv = jnp.asarray(rng.integers(-127, 128, (KH, n_pages, PG, D)),
+    pv = jnp.asarray(rng.integers(-127, 128, (n_pages, PG, KH, D)),
                      jnp.int8)
-    sk = jnp.asarray(rng.uniform(0.1, 2.0, (KH, n_pages, 1)),
+    sk = jnp.asarray(rng.uniform(0.1, 2.0, (n_pages, KH)),
                      jnp.float32)
-    sv = jnp.asarray(rng.uniform(0.1, 2.0, (KH, n_pages, 1)),
+    sv = jnp.asarray(rng.uniform(0.1, 2.0, (n_pages, KH)),
                      jnp.float32)
     pt = jnp.asarray(rng.permutation(n_pages - 1)[:B * max_pages]
                      .reshape(B, max_pages) + 1, jnp.int32)
     pos = jnp.asarray(rng.integers(0, max_pages * PG, B), jnp.int32)
     q = jnp.asarray(rng.standard_normal((B, 2 * KH, D)), jnp.float32)
-    out = np.asarray(paged_decode_attention(q, pk, pv, pt, pos,
-                                            sk, sv, interpret=True))
+    out = _kernel(q, pk, pv, pt, pos, sk, sv)
     ref = _dense_ref_deq(q, pk, sk, pv, sv, pt, pos)
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
 
@@ -256,7 +262,7 @@ def test_shape_errors():
         paged_append(pk, pv, pt, pos, k, v, sk, None)
     with pytest.raises(PagedShapeError):
         paged_append(pk, pv, pt, pos, k, v,     # bad scale shape
-                     sk[:, :, 0], sv[:, :, 0])
+                     sk[..., None], sv[..., None])
     fpk = jnp.zeros(pk.shape, jnp.float32)
     with pytest.raises(PagedShapeError, match="int8"):
         paged_append(fpk, fpk, pt, pos, k, v, sk, sv)
@@ -271,7 +277,8 @@ def test_init_pool_shapes_and_layer_views():
     assert len(pool) == cfg.n_layers
     pk, pv, sk, sv = pool[0]
     assert pk.dtype == jnp.int8 and pv.dtype == jnp.int8
-    assert sk.shape == (cfg.n_kv_heads, 16, 1)
+    assert pk.shape == (16, 8, cfg.n_kv_heads, cfg.head_dim)
+    assert sk.shape == (16, cfg.n_kv_heads)
     assert sk.dtype == jnp.float32
     pt = jnp.zeros((2, 4), jnp.int32)
     cache = kv_layer_view(pool[0], pt)
@@ -475,7 +482,7 @@ def test_int8_load_report_bytes_and_gauge(tiny):
 
 def test_tp4_int8_agreement(tiny, cpu_mesh_devices):
     # int8 under tensor parallelism: pools shard on the head axis,
-    # scale columns ride P("tensor", None, None) beside their heads.
+    # scale rows ride P(None, "tensor") beside their heads.
     # tp=4 reduction order perturbs pre-quantization activations, so
     # the gate is agreement, not identity (unlike fp tp A/B).
     from ray_tpu.serve.sharding import EngineSharding

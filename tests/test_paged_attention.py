@@ -1,40 +1,48 @@
 """Pallas paged-attention decode kernel vs dense reference.
 
 Kernel runs in interpreter mode on the CPU test mesh; the dense
-reference is the same math the llama gather fallback uses.
+reference is the same math the llama gather fallback uses. Pools are
+built PAGE-MAJOR [n_pages, Pg, KH, D], as the engine stores them; the
+kernel keeps a head-major contract and gets the transposed view
+``LlamaAttention`` hands it (``kernel_pool_view``).
 """
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.ops.paged_attention import paged_decode_attention
+from ray_tpu.ops.paged_attention import (kernel_pool_view,
+                                         paged_decode_attention)
+
+
+def _kernel_view(pages):
+    return kernel_pool_view(jnp.asarray(pages))
 
 
 def _dense_ref(q, pages_k, pages_v, page_table, positions):
     B, H, D = q.shape
-    KH, _, Pg, _ = pages_k.shape
+    _, Pg, KH, _ = pages_k.shape
     L = page_table.shape[1] * Pg
     rep = H // KH
-    kg = pages_k[:, page_table].reshape(KH, B, L, D)
-    vg = pages_v[:, page_table].reshape(KH, B, L, D)
+    kg = pages_k[page_table].reshape(B, L, KH, D)
+    vg = pages_v[page_table].reshape(B, L, KH, D)
     qg = q.reshape(B, KH, rep, D).astype(np.float32)
-    s = np.einsum("bkrd,kbsd->bkrs", qg,
+    s = np.einsum("bkrd,bskd->bkrs", qg,
                   kg.astype(np.float32)) / np.sqrt(D)
     valid = np.arange(L)[None] <= np.asarray(positions)[:, None]
     s = np.where(valid[:, None, None, :], s, -1e30)
     s = s - s.max(axis=-1, keepdims=True)
     p = np.exp(s)
     p /= p.sum(axis=-1, keepdims=True)
-    o = np.einsum("bkrs,kbsd->bkrd", p, vg.astype(np.float32))
+    o = np.einsum("bkrs,bskd->bkrd", p, vg.astype(np.float32))
     return o.reshape(B, H, D)
 
 
 def _random_layout(rng, B, n_pages, max_pages, Pg, KH, D, H,
                    dtype=np.float32):
     # Page 0 is the null page; each slot gets a distinct page chain.
-    pages_k = rng.standard_normal((KH, n_pages, Pg, D)).astype(dtype)
-    pages_v = rng.standard_normal((KH, n_pages, Pg, D)).astype(dtype)
+    pages_k = rng.standard_normal((n_pages, Pg, KH, D)).astype(dtype)
+    pages_v = rng.standard_normal((n_pages, Pg, KH, D)).astype(dtype)
     perm = rng.permutation(n_pages - 1)[: B * max_pages] + 1
     page_table = perm.reshape(B, max_pages).astype(np.int32)
     positions = rng.integers(0, max_pages * Pg, size=B).astype(np.int32)
@@ -51,7 +59,7 @@ def test_kernel_matches_dense(rep):
     q, pk, pv, pt, pos = _random_layout(
         rng, B, n_pages, max_pages, Pg, KH, D, H)
     out = paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
+        jnp.asarray(q), _kernel_view(pk), _kernel_view(pv),
         jnp.asarray(pt), jnp.asarray(pos), interpret=True)
     ref = _dense_ref(q, pk, pv, pt, pos)
     np.testing.assert_allclose(np.asarray(out), ref,
@@ -67,14 +75,14 @@ def test_position_zero_and_full():
         rng, B, 32, max_pages, Pg, KH, D, H)
     pos = np.array([0, max_pages * Pg - 1], dtype=np.int32)
     out = paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
+        jnp.asarray(q), _kernel_view(pk), _kernel_view(pv),
         jnp.asarray(pt), jnp.asarray(pos), interpret=True)
     ref = _dense_ref(q, pk, pv, pt, pos)
     np.testing.assert_allclose(np.asarray(out), ref,
                                rtol=2e-4, atol=2e-4)
     # Slot 0's output must equal V at position 0 exactly (softmax
     # over a single key).
-    v0 = pv[0, pt[0, 0], 0]
+    v0 = pv[pt[0, 0], 0, 0]
     np.testing.assert_allclose(np.asarray(out)[0, 0], v0,
                                rtol=1e-5, atol=1e-5)
 
@@ -87,8 +95,8 @@ def test_bf16_inputs():
         rng, B, 16, max_pages, Pg, KH, D, H)
     to = lambda a: jnp.asarray(a, dtype=jnp.bfloat16)
     out = paged_decode_attention(
-        to(q), to(pk), to(pv), jnp.asarray(pt), jnp.asarray(pos),
-        interpret=True)
+        to(q), _kernel_view(to(pk)), _kernel_view(to(pv)),
+        jnp.asarray(pt), jnp.asarray(pos), interpret=True)
     assert out.dtype == jnp.bfloat16
     ref = _dense_ref(q.astype(np.float32), pk.astype(np.float32),
                      pv.astype(np.float32), pt, pos)
@@ -96,11 +104,13 @@ def test_bf16_inputs():
         np.asarray(out, dtype=np.float32), ref, rtol=0.05, atol=0.05)
 
 
-def test_llama_decode_paths_agree(monkeypatch):
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_llama_decode_paths_agree(monkeypatch, kv_dtype):
     """The llama paged branch must produce the same step output via
-    the pallas kernel (forced) and the XLA gather fallback."""
+    the pallas kernel (forced: it reads a head-major VIEW of the
+    page-major pool, scales too) and the XLA gather fallback."""
     from ray_tpu.models.llama import LlamaConfig, Llama
-    from ray_tpu.models.kv_cache import PagedKVLayer, init_kv_pool
+    from ray_tpu.models.kv_cache import init_kv_pool, kv_layer_view
 
     cfg = LlamaConfig(vocab_size=64, max_seq_len=64, dim=32,
                       n_layers=2, n_heads=4, n_kv_heads=2,
@@ -109,11 +119,20 @@ def test_llama_decode_paths_agree(monkeypatch):
     model = Llama(cfg)
     rng = jax.random.PRNGKey(0)
     B = 2
-    pages = init_kv_pool(cfg, n_pages=16, page_size=4)
+    pages = init_kv_pool(cfg, n_pages=16, page_size=4,
+                         kv_dtype=kv_dtype)
     # Seed the pool with nonzero history so past positions matter.
-    pages = [(pk + 0.1 * jax.random.normal(rng, pk.shape),
-              pv + 0.1 * jax.random.normal(rng, pv.shape))
-             for pk, pv in pages]
+    if kv_dtype == "fp":
+        pages = [(pk + 0.1 * jax.random.normal(rng, pk.shape),
+                  pv + 0.1 * jax.random.normal(rng, pv.shape))
+                 for pk, pv in pages]
+    else:
+        r = np.random.default_rng(0)
+        pages = [tuple(
+            jnp.asarray(r.integers(-127, 128, t.shape), jnp.int8)
+            if t.ndim == 4 else
+            jnp.asarray(r.uniform(0.1, 0.5, t.shape), jnp.float32)
+            for t in layer) for layer in pages]
     page_table = jnp.array([[1, 2, 3, 4], [5, 6, 7, 8]],
                            dtype=jnp.int32)
     tok = jax.random.randint(rng, (B, 1), 0, cfg.vocab_size)
@@ -126,7 +145,7 @@ def test_llama_decode_paths_agree(monkeypatch):
         def fwd(params, pages):
             # a fresh function per call: the knob is read at trace
             # time, so each call traces its own branch
-            kv = [PagedKVLayer(pk, pv, page_table) for pk, pv in pages]
+            kv = [kv_layer_view(layer, page_table) for layer in pages]
             out, _ = model.apply(params, tok, kv_caches=kv,
                                  cache_len=pos)
             return out
@@ -148,8 +167,8 @@ def test_paged_append_mid_page_span():
     from ray_tpu.ops.paged_attention import paged_append
     rng = np.random.default_rng(3)
     B, T, KH, D, Pg, n_pages, max_pages = 2, 6, 2, 8, 4, 16, 4
-    pk = rng.standard_normal((KH, n_pages, Pg, D)).astype(np.float32)
-    pv = rng.standard_normal((KH, n_pages, Pg, D)).astype(np.float32)
+    pk = rng.standard_normal((n_pages, Pg, KH, D)).astype(np.float32)
+    pv = rng.standard_normal((n_pages, Pg, KH, D)).astype(np.float32)
     pt = np.array([[1, 2, 3, 4], [5, 6, 7, 8]], np.int32)
     pos = np.array([3, 5], np.int32)      # both start mid-page
     k = rng.standard_normal((B, T, KH, D)).astype(np.float32)
@@ -161,10 +180,126 @@ def test_paged_append_mid_page_span():
     for b in range(B):
         for t in range(T):
             p = pos[b] + t
-            ref_k[:, pt[b, p // Pg], p % Pg] = k[b, t]
-            ref_v[:, pt[b, p // Pg], p % Pg] = v[b, t]
+            ref_k[pt[b, p // Pg], p % Pg] = k[b, t]
+            ref_v[pt[b, p // Pg], p % Pg] = v[b, t]
     np.testing.assert_array_equal(np.asarray(nk), ref_k)
     np.testing.assert_array_equal(np.asarray(nv), ref_v)
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_append_then_block_gather_matches_plain_cache(kv_dtype):
+    """paged_append then the window loop's block gather, against a
+    plain numpy cache [B, L, KH, D]: a first chunk, then a chunk that
+    starts mid-page and spans pages, land where a contiguous cache
+    puts them, in the page-major pool and (int8) its page-major
+    scales."""
+    from ray_tpu.models.kv_cache import init_kv_pool, kv_layer_view
+    from ray_tpu.models.llama import LlamaConfig, _paged_window_attention
+    from ray_tpu.ops.paged_attention import dequantize_pages, paged_append
+    rng = np.random.default_rng(11)
+    B, KH, H, D, Pg, n_pages, max_pages = 2, 2, 4, 8, 4, 16, 4
+    cfg = LlamaConfig(dim=H * D, n_heads=H, n_kv_heads=KH, n_layers=1,
+                      dtype=jnp.float32)
+    layer = init_kv_pool(cfg, n_pages, Pg, kv_dtype)[0]
+    assert layer[0].shape == (n_pages, Pg, KH, D)
+    pt = jnp.asarray([[3, 9, 1, 12], [5, 2, 14, 7]], jnp.int32)
+    plain_k = np.zeros((B, max_pages * Pg, KH, D), np.float32)
+    plain_v = np.zeros_like(plain_k)
+    append = jax.jit(paged_append)
+    # 3 tokens from 0, then 7 from position 3: mid-page, over two edges
+    for start, T in ((0, 3), (3, 7)):
+        k = rng.standard_normal((B, T, KH, D)).astype(np.float32)
+        v = rng.standard_normal((B, T, KH, D)).astype(np.float32)
+        plain_k[:, start:start + T], plain_v[:, start:start + T] = k, v
+        layer = append(layer[0], layer[1], pt,
+                       jnp.full((B,), start, jnp.int32),
+                       jnp.asarray(k), jnp.asarray(v), *layer[2:])
+    n = 10
+    if kv_dtype == "fp":
+        got_k, got_v = (np.asarray(t)[np.asarray(pt)].reshape(
+            B, -1, KH, D) for t in layer)
+        np.testing.assert_array_equal(got_k[:, :n], plain_k[:, :n])
+        np.testing.assert_array_equal(got_v[:, :n], plain_v[:, :n])
+        tol = 1e-5
+    else:
+        pk, pv, sk, sv = layer
+        assert sk.shape == (n_pages, KH)
+        got_k = np.asarray(dequantize_pages(pk, sk))[
+            np.asarray(pt)].reshape(B, -1, KH, D)
+        # a page's scale is its absmax per head; a step is scale / 127
+        # and the second chunk re-coded the first once
+        step = np.asarray(sk)[np.asarray(pt)].repeat(Pg, axis=1) / 127.0
+        assert (np.abs(got_k - plain_k)[:, :n]
+                <= 1.5 * step[:, :n, :, None] + 1e-7).all()
+        plain_k = got_k
+        plain_v = np.asarray(dequantize_pages(pv, sv))[
+            np.asarray(pt)].reshape(B, -1, KH, D)
+        tol = 1e-4
+    # the block gather reads the same cache: attention of one query at
+    # position n - 1 over the pool equals attention over the plain cache
+    q = rng.standard_normal((B, 1, H, D)).astype(np.float32)
+    pos = jnp.full((B,), n - 1, jnp.int32)
+    cache = kv_layer_view(layer, pt)
+    y = jax.jit(_paged_window_attention)(
+        jnp.asarray(q), cache.pages_k, cache.pages_v, cache.scales_k,
+        cache.scales_v, pt, pos)
+    qg = q[:, 0].reshape(B, KH, H // KH, D)
+    s = np.einsum("bkrd,bskd->bkrs", qg, plain_k[:, :n]) / np.sqrt(D)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    ref = np.einsum("bkrs,bskd->bkrd", p, plain_v[:, :n]).reshape(B, H, D)
+    np.testing.assert_allclose(np.asarray(y)[:, 0], ref, rtol=tol,
+                               atol=tol)
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_page_bytes_round_trip_lands_identical_pages(kv_dtype):
+    """export_page_bytes -> page_cols_from_bytes -> the engine's
+    _jit_write_page: a page leaves one pool as it lies ([Pg, KH, D],
+    scales [KH]) and lands byte-identical in another page of a second
+    pool, every layer, touching no other page; the frame's arity and
+    byte counts are still checked."""
+    from ray_tpu.models.kv_cache import (export_page_bytes, init_kv_pool,
+                                         page_cols_from_bytes)
+    from ray_tpu.models.llama import llama_tiny
+    from ray_tpu.serve.engine import _jit_write_page
+    cfg = llama_tiny(dtype=jnp.float32)
+    Pg, n_pages, src, dst = 4, 8, 5, 2
+    rng = np.random.default_rng(12)
+
+    def filled():
+        return [tuple(
+            jnp.asarray(rng.integers(-127, 128, t.shape), t.dtype)
+            if t.dtype == jnp.int8 else
+            jnp.asarray(rng.standard_normal(t.shape), t.dtype)
+            for t in layer)
+            for layer in init_kv_pool(cfg, n_pages, Pg, kv_dtype)]
+
+    donor, taker = filled(), filled()
+    before = jax.tree_util.tree_map(np.asarray, taker)
+    blobs = export_page_bytes(donor, src)
+    k_bytes = Pg * cfg.n_kv_heads * cfg.head_dim * (
+        1 if kv_dtype == "int8" else 4)
+    assert [len(b) for b in blobs[0][:2]] == [k_bytes, k_bytes]
+    cols = page_cols_from_bytes(cfg, Pg, kv_dtype, blobs)
+    assert cols[0][0].shape == (Pg, cfg.n_kv_heads, cfg.head_dim)
+    taker = _jit_write_page(None)(
+        taker, jnp.int32(dst),
+        [tuple(jnp.asarray(c) for c in layer) for layer in cols])
+    for d_layer, t_layer, b_layer in zip(donor, taker, before):
+        for d, t, b in zip(d_layer, t_layer, b_layer):
+            t = np.asarray(t)
+            assert t[dst].tobytes() == np.asarray(d)[src].tobytes()
+            keep = np.arange(n_pages) != dst
+            np.testing.assert_array_equal(t[keep], b[keep])
+    with pytest.raises(ValueError, match="layers"):
+        page_cols_from_bytes(cfg, Pg, kv_dtype, blobs[:-1])
+    with pytest.raises(ValueError, match="byte"):
+        page_cols_from_bytes(cfg, Pg, kv_dtype,
+                             [[b[:-1] for b in layer] for layer in blobs])
+    other = "fp" if kv_dtype == "int8" else "int8"
+    with pytest.raises(ValueError, match="tensors"):
+        page_cols_from_bytes(cfg, Pg, other, blobs)
 
 
 def test_paged_append_tail_hits_null_page_only():
@@ -174,8 +309,8 @@ def test_paged_append_tail_hits_null_page_only():
     from ray_tpu.ops.paged_attention import paged_append
     rng = np.random.default_rng(4)
     B, T, KH, D, Pg, n_pages, max_pages = 1, 8, 1, 4, 4, 8, 2
-    pk = rng.standard_normal((KH, n_pages, Pg, D)).astype(np.float32)
-    pv = rng.standard_normal((KH, n_pages, Pg, D)).astype(np.float32)
+    pk = rng.standard_normal((n_pages, Pg, KH, D)).astype(np.float32)
+    pv = rng.standard_normal((n_pages, Pg, KH, D)).astype(np.float32)
     pt = np.zeros((B, max_pages), np.int32)
     pt[0, 0] = 3                          # ONE allocated page
     pos = np.array([2], np.int32)         # 8-token chunk overruns it
@@ -186,11 +321,11 @@ def test_paged_append_tail_hits_null_page_only():
                           jnp.asarray(k), jnp.asarray(v))
     nk, nv = np.asarray(nk), np.asarray(nv)
     # page 3 got its two in-window tokens
-    np.testing.assert_array_equal(nk[:, 3, 2], k[0, 0])
-    np.testing.assert_array_equal(nk[:, 3, 3], k[0, 1])
+    np.testing.assert_array_equal(nk[3, 2], k[0, 0])
+    np.testing.assert_array_equal(nk[3, 3], k[0, 1])
     # every page except the null page and page 3 is untouched
     for pg in range(1, n_pages):
         if pg == 3:
             continue
-        np.testing.assert_array_equal(nk[:, pg], pk[:, pg])
-        np.testing.assert_array_equal(nv[:, pg], pv[:, pg])
+        np.testing.assert_array_equal(nk[pg], pk[pg])
+        np.testing.assert_array_equal(nv[pg], pv[pg])

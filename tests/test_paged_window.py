@@ -111,7 +111,7 @@ def _run(case, model, params, pages, pt):
         toks = np.asarray(toks)[:steps, live]
         lps = np.asarray(lps)[:steps, live]
     # page 0 is the null page: dead rows scatter junk there
-    pool = [tuple(np.asarray(t)[:, 1:] for t in layer)
+    pool = [tuple(np.asarray(t)[1:] for t in layer)
             for layer in pages]
     return toks, lps, pool
 
@@ -168,7 +168,7 @@ def test_trip_count_follows_live_rows_only(block_tokens):
     up to the longest LIVE row's last query, whatever a null row's
     position says, and never more than the table holds."""
     block_tokens(BLOCK)
-    pk = jnp.zeros((2, N_PAGES, PAGE, 16), jnp.float32)
+    pk = jnp.zeros((N_PAGES, PAGE, 2, 16), jnp.float32)
     q = jnp.zeros((3, 1, 4, 16), jnp.float32)
     seen = []
     real = jax.lax.fori_loop

@@ -201,20 +201,24 @@ def test_mixtral_expert_parallel_parity(cpu_mesh_devices):
 # ------------------------------------------------- placement + units
 
 def test_kv_pool_is_head_sharded(tiny, tp4):
-    """The engine's page pool must shard axis 0 (kv heads) over
-    ``tensor`` and nothing else — the invariant that keeps
-    paged_append / decode / page copies collective-free."""
+    """The engine's page-major pool [n_pages, Pg, KH, D] must shard
+    axis 2 (kv heads) over ``tensor`` and nothing else — the
+    invariant that keeps paged_append / decode / page copies
+    collective-free."""
     eng = _engine(tiny, tp4)
     try:
         for pk, pv in eng.pages:
             for t in (pk, pv):
-                spec = t.sharding.spec
-                assert spec[0] == "tensor"
-                assert all(s is None for s in spec[1:])
+                assert t.shape[2] == tiny[0].n_kv_heads
+                spec = tuple(t.sharding.spec) + (None,) * 4
+                assert spec[2] == "tensor"
+                assert all(s is None for i, s in enumerate(spec)
+                           if i != 2)
                 # per-device shard holds KH/tp heads, ALL pages
                 shard_shape = t.sharding.shard_shape(t.shape)
-                assert shard_shape[0] == t.shape[0] // 4
-                assert shard_shape[1:] == t.shape[1:]
+                assert shard_shape[2] == t.shape[2] // 4
+                assert shard_shape[:2] == t.shape[:2]
+                assert shard_shape[3] == t.shape[3]
     finally:
         eng.shutdown()
 
@@ -302,8 +306,8 @@ def test_paged_append_typed_shape_errors():
     from ray_tpu.ops.paged_attention import (PagedShapeError,
                                              paged_append)
     KH, n_pages, Pg, D = 2, 8, 4, 8
-    pk = jnp.zeros((KH, n_pages, Pg, D))
-    pv = jnp.zeros((KH, n_pages, Pg, D))
+    pk = jnp.zeros((n_pages, Pg, KH, D))
+    pv = jnp.zeros((n_pages, Pg, KH, D))
     pt = jnp.zeros((2, 4), jnp.int32)
     pos = jnp.zeros((2,), jnp.int32)
     ok_k = jnp.zeros((2, 3, KH, D))
