@@ -9,8 +9,8 @@ through the entry points a user calls:
             from a seed; concurrent, streamed and HTTP requests; greedy
             output checked against the model's cache-free full forward;
             one int8-KV request checked the same way
-  kernels   the Pallas paged-decode (bf16, int8) and flash-attention
-            (fwd+bwd) kernels, compiled, against their XLA references
+  kernels   the Pallas flash-attention kernel (fwd+bwd), compiled,
+            against its XLA reference
   training  GPT-2-124M at batch 24 x 1024 through shard_state /
             put_batch / make_train_step; flash kernel present in the
             compiled step; loss finite and falling
@@ -348,85 +348,22 @@ def _rel_err(a, b) -> float:
     return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
 
 
-def _paged_gather_ref(q, pk, pv, pt, pos, sk=None, sv=None):
-    """The llama gather branch's math (models/llama.py): gather each
-    slot's page window dense, dequantize int8, grouped-query softmax
-    in fp32."""
-    import jax
-    import jax.numpy as jnp
-    B, H, D = q.shape
-    KH, _, Pg, _ = pk.shape
-    L = pt.shape[1] * Pg
-    kg = pk[:, pt].astype(jnp.float32)
-    vg = pv[:, pt].astype(jnp.float32)
-    if sk is not None:
-        kg = kg * (sk[:, pt] * (1.0 / 127.0))[..., None]
-        vg = vg * (sv[:, pt] * (1.0 / 127.0))[..., None]
-    kg, vg = kg.reshape(KH, B, L, D), vg.reshape(KH, B, L, D)
-    qg = q.reshape(B, KH, H // KH, D).astype(jnp.float32)
-    s = jnp.einsum("bkrd,kbsd->bkrs", qg, kg) / (D ** 0.5)
-    valid = jnp.arange(L)[None] <= pos[:, None]
-    s = jnp.where(valid[:, None, None], s, -1e30)
-    o = jnp.einsum("bkrs,kbsd->bkrd", jax.nn.softmax(s, axis=-1), vg)
-    return o.reshape(B, H, D)
-
-
-def kernel_phase(*, paged_shapes=((32, 4, 64), (16, 16, 128)),
-                 flash_shapes=((24, 1024, 12, 64), (8, 1024, 32, 64)),
-                 slots: int = 16, page_size: int = 64,
-                 pages_per_slot: int = 16, interpret: bool = False,
-                 seed: int = SEED) -> dict:
-    """Each Pallas kernel the main path can select, compiled (not
-    interpreted, unless the CPU rehearsal asks) and compared with its
-    XLA reference. The only place the paged kernel runs: the engine
-    defaults to the gather."""
+def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
+                                  (8, 1024, 32, 64)),
+                 interpret: bool = False, seed: int = SEED) -> dict:
+    """The flash-attention kernel, compiled (not interpreted, unless
+    the CPU rehearsal asks) and compared with its XLA reference,
+    forward and backward."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from ray_tpu.ops import flash_attention as flash_mod
     from ray_tpu.ops.attention import xla_attention
-    from ray_tpu.ops.paged_attention import paged_decode_attention
     assert flash_mod._interpret() == interpret, (
         "flash_attention would run "
         + ("interpreted" if flash_mod._interpret() else "compiled"))
     rng = np.random.default_rng(seed)
     errs = {}
-
-    B, Pg, MP = slots, page_size, pages_per_slot
-    n_pages = B * MP + 1
-    for H, KH, D in paged_shapes:
-        pt = jnp.asarray(rng.permutation(n_pages - 1)[:B * MP]
-                         .reshape(B, MP) + 1, jnp.int32)
-        pos = jnp.asarray(rng.integers(0, MP * Pg, B), jnp.int32)
-        q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.bfloat16)
-        # the kernel's own head-major contract (the engine's pool is
-        # page-major and reaches the kernel as a transposed view)
-        shape = (KH, n_pages, Pg, D)
-        pools = {
-            "bf16": (jnp.asarray(rng.standard_normal(shape),
-                                 jnp.bfloat16),
-                     jnp.asarray(rng.standard_normal(shape),
-                                 jnp.bfloat16)),
-            "int8": (jnp.asarray(rng.integers(-127, 128, shape),
-                                 jnp.int8),
-                     jnp.asarray(rng.integers(-127, 128, shape),
-                                 jnp.int8),
-                     jnp.asarray(rng.uniform(0.1, 2.0,
-                                             (KH, n_pages, 1)),
-                                 jnp.float32),
-                     jnp.asarray(rng.uniform(0.1, 2.0,
-                                             (KH, n_pages, 1)),
-                                 jnp.float32)),
-        }
-        for kind, (pk, pv, *scales) in pools.items():
-            name = f"paged_{kind}_H{H}_KH{KH}_D{D}"
-            with timed(f"kernels: {name}"):
-                out = paged_decode_attention(q, pk, pv, pt, pos,
-                                             *scales,
-                                             interpret=interpret)
-                ref = jax.jit(_paged_gather_ref)(q, pk, pv, pt, pos,
-                                                 *scales)
-                errs[name] = _rel_err(out, ref)
 
     for Bq, T, H, D in flash_shapes:
         name = f"flash_B{Bq}_T{T}_H{H}_D{D}"
@@ -603,7 +540,8 @@ def multichip_phase(cfg, gpt2_cfg=None, *, n_chips: int = 4,
             assert len(p_dev) == n_chips and len(kv_dev) == n_chips, (
                 f"tp engine: params on {p_dev}, pool on {kv_dev}")
             shard = eng.pages[0][0].addressable_shards[0].data.shape
-            assert shard[0] * n_chips == cfg.n_kv_heads, (
+            # page-major pool [n_pages, Pg, KH, D]: the head axis is 2
+            assert shard[2] * n_chips == cfg.n_kv_heads, (
                 f"KV pool not head-sharded: shard {shard}")
         finally:
             eng.shutdown()

@@ -5,8 +5,7 @@ Run (CPU demo):
 
 What this shows
 ---------------
-- `LlamaDeployment(use_engine=True)` (the default) serves every
-  Llama-shaped family through the device-paced continuous-batching
+- `LlamaDeployment` serves every Llama-shaped family through the device-paced continuous-batching
   engine (ray_tpu/serve/engine.py): requests join/leave the decode
   batch at token granularity — a short completion never waits for a
   long one to finish the way whole-call batching makes it

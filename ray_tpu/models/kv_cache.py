@@ -21,9 +21,8 @@ structure that lets requests join/leave the decode batch per token):
   that layout on entry and back on exit — 64 whole-pool copies a
   dispatch at 16 layers, 14-15 % of three serving cells' chip time
   and a 2 GiB temporary (PERF.md section 6, PR 29). Declared as it
-  is kept, no program copies it. The kernel (off by default) still
-  wants head-major and is handed a transposed view
-  (models/llama.py ``LlamaAttention``).
+  is kept, no program copies it. (That kernel lost to the gather on
+  the chip and went in PR 30.)
 - Page 0 is the NULL page: inactive decode slots point their page
   table at it and harmlessly scatter their dead writes there, so the
   jitted decode step needs no ``lax.cond`` masking — every slot does
@@ -50,6 +49,21 @@ import jax.numpy as jnp
 import numpy as np
 
 KV_SCALE_DTYPE = jnp.float32
+KV_DTYPES = ("fp", "int8")
+
+
+def check_kv_dtype(kv_dtype: Optional[str]) -> str:
+    """The pool's storage dtype as a constructor argument names it:
+    ``"fp"`` (or None) stores cfg.dtype pages, ``"int8"`` quantized
+    pages with per-page scales. Anything else would silently serve
+    from a pool the caller did not ask for, so it is an error."""
+    if kv_dtype is None:
+        return "fp"
+    if kv_dtype not in KV_DTYPES:
+        raise ValueError(
+            "kv_dtype=%r is not supported (choose one of %s)" %
+            (kv_dtype, ", ".join(repr(d) for d in KV_DTYPES)))
+    return kv_dtype
 
 
 class PagedKVLayer(NamedTuple):
@@ -114,12 +128,10 @@ def init_kv_pool(cfg, n_pages: int, page_size: int,
           without any host-side scale bookkeeping).
     """
     shape = (n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-    if kv_dtype == "fp":
+    if check_kv_dtype(kv_dtype) == "fp":
         return [(jnp.zeros(shape, cfg.dtype),
                  jnp.zeros(shape, cfg.dtype))
                 for _ in range(cfg.n_layers)]
-    if kv_dtype != "int8":
-        raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
     sshape = (n_pages, cfg.n_kv_heads)
     return [(jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
              jnp.zeros(sshape, KV_SCALE_DTYPE),

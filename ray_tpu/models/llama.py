@@ -23,41 +23,8 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.mesh.sharding import ShardingRules
 from ray_tpu.models.kv_cache import PagedKVLayer
-from ray_tpu.ops.paged_attention import (kernel_pool_view,
-                                         paged_decode_attention)
-
-
-def _use_paged_kernel() -> bool:
-    """Paged decode attention backend: default is the XLA gather
-    (_paged_window_attention: blocks of pages up to the longest live
-    context; the measurements below predate it and gathered the whole
-    page table's width, L).
-    Measured on a v5e chip, 1.1B bf16, 16 slots, L=256, full decode
-    step (dense floor 3.5ms): standalone the pallas kernel wins at
-    page_size 64 (3.6ms vs gather 8.2ms), but INSIDE the engine's
-    donated decode loop the ranking flips — gather steps run at
-    4.2ms (XLA aliases the pool update in place across iterations)
-    while the kernel steps run at 7.5ms: the pallas custom call
-    defeats the loop-carry aliasing of the 67MB/layer pools and
-    buys a full pool copy per step. Re-examined under the engine's
-    OVERLAPPED hot loop (serve_bench.py --overlap-ab --paged-kernel):
-    the ranking does NOT flip back — overlap hides host readback
-    latency behind device compute, but the aliasing defeat is a
-    compile-time property of the dispatched computation itself, so
-    the per-step pool copy is still paid on-device where no amount
-    of host overlap can cover it. Since PR 29 the pool is stored
-    page-major (models/kv_cache.py), the layout the gather and
-    paged_append want; the kernel keeps its head-major contract and
-    reads a transposed view, so this branch starts a further pool
-    copy a step behind (ROADMAP S1 (b)). Until that aliasing is proven
-    through the custom call, the gather is the right default on
-    every backend; RAY_TPU_PAGED_KERNEL=1 forces the kernel (and
-    =0 forces the gather) for experiments and tests. Junk values
-    raise EnvKnobError (util/envknobs.py) instead of silently
-    picking the default — a typo here would invalidate a whole
-    perf-triage session."""
-    from ray_tpu.util.envknobs import parse_paged_kernel_env
-    return parse_paged_kernel_env(default=False)
+from ray_tpu.ops.paged_attention import (_paged_window_attention,
+                                         paged_append)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,121 +106,6 @@ class RMSNorm(nn.Module):
         return (norm * scale).astype(x.dtype)
 
 
-# Tokens of context one iteration of the paged window loop gathers and
-# attends (rounded to whole pages): the unit in which the attended
-# window follows the live contexts. Smaller blocks waste less on the
-# last, partly filled block and pay the loop's fixed cost more often
-# (PERF.md section 6, PR 26 has the chip's readings).
-_WINDOW_BLOCK_TOKENS = 512
-
-
-def paged_window_block_pages(page_size: int, max_pages: int) -> int:
-    """Logical pages one iteration of the paged window loop covers: a
-    constant of the shapes, not a knob."""
-    return min(max_pages, max(1, _WINDOW_BLOCK_TOKENS // page_size))
-
-
-def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
-    """Causal grouped-query attention of ``q`` [B, T, H, D] (row b's
-    queries at absolute positions ``pos[b] + t``) over its page-table
-    row's K/V in the page-major pool ``pk``/``pv`` [n_pages, Pg, KH, D]
-    (``sk``/``sv``: an int8 pool's per-page scales [n_pages, KH], else
-    None). Each page is gathered whole, by its id, as it lies.
-
-    Work follows the live contexts, not the table's width: a loop with
-    a RUNTIME trip count walks blocks of ``block_pages`` logical pages
-    up to the block holding the last position any live row can see,
-    folding each block's float32 scores into a running max / sum /
-    accumulator (the online softmax: the same mathematics as one
-    softmax over the whole window, nothing approximated, no visible
-    position left out). A live row is one whose page-table row is not
-    the null row: non-riders and dummy prefill rows carry rows of 0,
-    and their ``pos`` may be stale and large, so they must not widen
-    the window. The count is a value, not a shape: one executable
-    serves every context length, and inside the decode loop it is
-    recomputed every step, so a context that crosses a block's edge in
-    the middle of a dispatch is still attended whole.
-
-    The named scopes (kv_gather, attn_scores, attn_pv) are metadata
-    only: a device trace splits a step's time by them (PERF.md
-    section 3).
-    """
-    B, T, H, D = q.shape
-    _, Pg, KH, _ = pk.shape
-    max_pages = page_table.shape[1]
-    block_pages = paged_window_block_pages(Pg, max_pages)
-    Lb = block_pages * Pg
-    max_blocks = -(-max_pages // block_pages)
-    # Grouped-query attention WITHOUT materializing repeated K/V: q
-    # reshapes to [B, T, KH, rep, D] and contracts against the grouped
-    # cache directly (a repeat would move rep x the KV bytes a step).
-    qg = q.reshape(B, T, KH, H // KH, D).astype(jnp.float32)
-    # causal over absolute positions: query t of row b sits at
-    # pos[b] + t and sees keys 0..pos[b]+t
-    q_pos = pos[:, None] + jnp.arange(T)[None]              # [B, T]
-    with jax.named_scope("kv_gather"):
-        # a whole number of blocks: columns past the table are null
-        # pages, which the mask never lets a live query see
-        table = jnp.pad(
-            page_table,
-            ((0, 0), (0, max_blocks * block_pages - max_pages)))
-        live = page_table[:, 0] != 0
-        last = jnp.max(jnp.where(live, pos + (T - 1), 0))
-        n_blocks = jnp.minimum(last // Lb + 1, max_blocks)
-
-    def block(j, carry):
-        m, l, acc = carry
-        with jax.named_scope("kv_gather"):
-            cols = jax.lax.dynamic_slice_in_dim(
-                table, j * block_pages, block_pages, axis=1)
-            # [B, block_pages, Pg, KH, D] -> [B, Lb, KH, D]; gathered
-            # index + j * Lb == logical position by construction
-            kg = pk[cols]
-            vg = pv[cols]
-            if sk is not None:
-                # dequantize the gathered block in fp32 with the
-                # gathered per-page scales (value = q * s / 127): only
-                # one block ever exists in fp, never the pool itself
-                kg = kg.astype(jnp.float32) * \
-                    (sk[cols] * (1.0 / 127.0))[:, :, None, :, None]
-                vg = vg.astype(jnp.float32) * \
-                    (sv[cols] * (1.0 / 127.0))[:, :, None, :, None]
-            kg = kg.reshape(B, Lb, KH, D)
-            vg = vg.reshape(B, Lb, KH, D)
-        with jax.named_scope("attn_scores"):
-            s = jnp.einsum("btkrd,bskd->bkrts", qg,
-                           kg.astype(jnp.float32)) / np.sqrt(D)
-            valid = (j * Lb + jnp.arange(Lb))[None, None] <= \
-                q_pos[:, :, None]                            # [B, T, Lb]
-            s = jnp.where(valid[:, None, None], s, -1e30)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            p = jnp.exp(s - m_new[..., None])
-            scale = jnp.exp(m - m_new)
-            l = l * scale + jnp.sum(p, axis=-1)
-        with jax.named_scope("attn_pv"):
-            acc = acc * scale[..., None] + jnp.einsum(
-                "bkrts,bskd->bkrtd", p.astype(vg.dtype), vg,
-                preferred_element_type=jnp.float32)
-        return m_new, l, acc
-
-    stat = (B, KH, H // KH, T)
-    carry = (jnp.full(stat, -1e30, jnp.float32),
-             jnp.zeros(stat, jnp.float32),
-             jnp.zeros(stat + (D,), jnp.float32))
-    if max_blocks == 1:
-        # the table is one block wide: no loop, the one-shot softmax
-        # over the whole window as straight-line code
-        carry = block(0, carry)
-    else:
-        carry = jax.lax.fori_loop(0, n_blocks, block, carry)
-    _, l, acc = carry
-    with jax.named_scope("attn_pv"):
-        # key 0 is visible to every query, so l > 0
-        y = (acc / l[..., None]).astype(q.dtype)
-    # [B, KH, rep, T, D] -> [B, T, H, D]
-    return y.transpose(0, 3, 1, 2, 4).reshape(B, T, H, D)
-
-
 class LlamaAttention(nn.Module):
     config: LlamaConfig
 
@@ -294,11 +146,11 @@ class LlamaAttention(nn.Module):
             # host-side, so no lax.cond is needed.
             pc = kv_cache
             pos = cache_len                       # [B] int32
-            from ray_tpu.ops.paged_attention import paged_append
-            # The named scopes below (kv_append, then attn_kernel or
-            # kv_gather, attn_scores, attn_pv) are metadata only: a device trace
-            # splits a step's time by them (PERF.md section 3); the
-            # compiled program is the same with or without them.
+            # The named scopes (kv_append here; kv_gather, attn_scores,
+            # attn_pv inside _paged_window_attention) are metadata
+            # only: a device trace splits a step's time by them
+            # (PERF.md section 3); the compiled program is the same
+            # with or without them.
             with jax.named_scope("kv_append"):
                 appended = paged_append(
                     pc.pages_k, pc.pages_v, pc.page_table, pos, k, v,
@@ -315,26 +167,11 @@ class LlamaAttention(nn.Module):
                 pk, pv = appended
                 sk = sv = None
                 new_cache = pc._replace(pages_k=pk, pages_v=pv)
-            if T == 1 and _use_paged_kernel():
-                # TPU decode: pallas paged-attention kernel — page
-                # table rides scalar prefetch; the page window is
-                # never materialized (ops/paged_attention.py). Int8
-                # pages dequantize in-register inside the kernel.
-                # The kernel keeps a head-major contract (Mosaic
-                # cannot tile a (1, Pg, 1, D) block), so it reads a
-                # transposed view of the page-major pool.
-                with jax.named_scope("attn_kernel"):
-                    y = paged_decode_attention(
-                        q[:, 0], kernel_pool_view(pk),
-                        kernel_pool_view(pv), pc.page_table, pos,
-                        kernel_pool_view(sk), kernel_pool_view(sv))
-                y = y.reshape(B, 1, cfg.n_heads, hd)
-            else:
-                # CPU/XLA fallback and chunk prefill: gather and
-                # attend over the batch's longest live context, a
-                # block of pages at a time (_paged_window_attention).
-                y = _paged_window_attention(
-                    q, pk, pv, sk, sv, pc.page_table, pos)
+            # decode step and prefill chunk alike: gather and attend
+            # over the batch's longest live context, a block of pages
+            # at a time
+            y = _paged_window_attention(
+                q, pk, pv, sk, sv, pc.page_table, pos)
         elif kv_cache is not None:
             # Decode path: append this step's K/V into the static cache.
             ck, cv = kv_cache

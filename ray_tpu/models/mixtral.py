@@ -230,7 +230,7 @@ class MixtralBlock(nn.Module):
         live = None
         if isinstance(kv_cache, PagedKVLayer):
             # a row whose page-table row is the null row carries no
-            # request (models/llama.py _paged_window_attention's rule)
+            # request (ops/paged_attention.py _paged_window_attention's rule)
             live = kv_cache.page_table[:, 0] != 0
         return block_forward(
             cfg, cfg.attention_config(), lambda h: moe(h, live),

@@ -30,7 +30,6 @@ attention materializes the [B,H,T,T] score tensor in HBM. Design notes:
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
@@ -42,15 +41,17 @@ _NEG_INF = -1e30
 _LANES = 128
 
 
-def _pick_block(t: int, target: int = 0) -> int:
-    """Measured on v5e (GPT-2-124M fwd+bwd, B=24 T=1024): target 1024
-    gives the best step time — bigger blocks amortize grid overhead and
-    keep the MXU busy; the 1024x1024 fp32 score block (4 MiB) still
-    fits VMEM comfortably. Override with RAY_TPU_FLASH_BLOCK for
-    sweeps."""
-    if not target:
-        target = int(os.environ.get("RAY_TPU_FLASH_BLOCK", "1024"))
-    blk = min(t, target)
+# Measured on v5e (GPT-2-124M fwd+bwd, B=24 T=1024): 1024 gives the
+# best step time — bigger blocks amortize grid overhead and keep the
+# MXU busy; the 1024x1024 fp32 score block (4 MiB) still fits VMEM
+# comfortably.
+_BLOCK_TARGET = 1024
+
+
+def _pick_block(t: int) -> int:
+    """The largest block of at most ``_BLOCK_TARGET`` positions that
+    divides a sequence of ``t`` (never under a lane's width)."""
+    blk = min(t, _BLOCK_TARGET)
     while t % blk:
         blk //= 2
     return max(blk, min(t, _LANES))

@@ -1,60 +1,36 @@
-"""Pallas paged-attention decode kernel (the vLLM kernel, TPU-style).
+"""The paged KV pool's two step-time operations, and the one layout
+they read and write.
 
-The continuous-batching engine's decode step attends each slot's
-single query against its KV pages. The XLA fallback (models/llama.py
-_paged_window_attention) GATHERS the pages into dense [B, L, KH, D]
-blocks every step, up to the batch's longest live context (it once
-gathered the whole page table's width, the dominant HBM traffic of
-the decode loop). This kernel never materializes the
-window: the page table rides scalar prefetch
-(pltpu.PrefetchScalarGridSpec) and each grid step DMAs exactly one
-physical page per (slot, kv-head), accumulating flash-style online
-softmax in VMEM. Per-step traffic drops from O(B * L) gathered copies
-to O(B * L) page READS only — no gathered intermediate, no scatter of
-it back.
+The continuous-batching engine keeps every layer's K and V in a pool
+of fixed-size pages (models/kv_cache.py makes it), PAGE-MAJOR:
 
-Two layout contracts live in this file. ``paged_append`` writes the
-engine's pool, which is PAGE-MAJOR (models/kv_cache.py):
-``[n_pages, page_size, n_kv_heads, head_dim]``, scales
-``[n_pages, n_kv_heads]``. The KERNEL keeps its own head-major
-contract, and its one caller (models/llama.py, under
-RAY_TPU_PAGED_KERNEL=1) hands it a transposed view of the pool
-(``kernel_pool_view``):
-  pages_k/pages_v: [n_kv_heads, n_pages, page_size, head_dim] —
-                   HEAD-MAJOR so each grid step's block is one
-                   contiguous [page_size, head_dim] tile, which
-                   Mosaic can tile (page-major would put a size-1
-                   slice of n_kv_heads in the sublane dim)
-  scales_k/scales_v: [n_kv_heads, n_pages, 1] fp32 (int8 pools)
-  page_table:      [n_slots, max_pages] int32 (0 = null page)
-  positions:       [n_slots]            int32 — current decode
-                   position; the step attends keys 0..pos inclusive
-  q:               [n_slots, n_heads, head_dim] (grouped-query: head
-                   h uses kv head h // (n_heads // n_kv_heads))
+  pages_k/pages_v:   [n_pages, page_size, n_kv_heads, head_dim]
+  scales_k/scales_v: [n_pages, n_kv_heads] fp32 (int8 pools only)
+  page_table:        [n_slots, max_pages] int32 (0 = the null page)
 
-Grid (B, n_pages_per_slot): the page dimension is innermost, so TPU
-executes it sequentially per slot and the online-softmax scratch
-carries across pages. Each grid step processes ONE physical page for
-ALL kv heads at once — the block ``[KH, 1, Pg, D]`` is a strided but
-Mosaic-expressible slice of the head-major pool, so one step moves
-KH*(Pg*D) bytes per tensor (64KB at 1.1B shapes) instead of a 4KB
-single-head page, and the [KH, rep, Pg] score tile fills the VPU
-sublanes. (A first cut used grid (B, KH, pages) with one head-page
-per step: 4096 serialized 4KB DMAs measured 31ms/step at 1.1B-16-slot
-shapes vs 8.2ms for XLA's dense gather — DMA-issue latency-bound.)
-Inactive slots point at the null page and mask everything — their
-outputs are ignored host-side.
+A page is one contiguous slab holding every KV head of its tokens, so
+a step program scatters and gathers whole pages by their id and never
+re-lays the pool out (PERF.md section 6, PRs 26 and 29). Every step
+program (decode, chunked prefill, speculative verify; fp and int8;
+one chip or tensor-parallel over the KV-head axis) does exactly two
+things with the pool, both here, and nothing else at step time knows
+the layout:
+
+- ``paged_append`` scatters a chunk of new K/V at each slot's write
+  offset;
+- ``_paged_window_attention`` attends a chunk of queries over each
+  slot's pages, gathered a block of pages at a time up to the longest
+  live context.
+
+Inactive slots point at the null page: their writes land there, the
+causal mask hides it from every live query, and their outputs are
+ignored host-side.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-_NEG_INF = -1e30
+import numpy as np
 
 # Int8 pages use a symmetric absmax code: value = q * scale / 127 with
 # q in [-127, 127] (-128 unused so the code is symmetric). One fp32
@@ -62,10 +38,6 @@ _NEG_INF = -1e30
 # per page per head, fine enough that one outlier page cannot poison
 # the whole pool's precision.
 _QMAX = 127.0
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 class PagedShapeError(ValueError):
@@ -253,167 +225,119 @@ def paged_append(pages_k, pages_v, page_table, pos, k, v,
     return new_pk, new_pv, new_sk, new_sv
 
 
-def _attend_page(b, p, pos_ref, q_ref, k, v, o_ref,
-                 m_sc, l_sc, acc_sc, *, page_size: int, scale: float):
-    """Shared flash-style online-softmax body: one physical page of
-    already-dequantized fp32 K/V for all kv heads."""
-    n_p = pl.num_programs(1)
-
-    @pl.when(p == 0)
-    def _init():
-        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-        acc_sc[...] = jnp.zeros_like(acc_sc)
-
-    q = q_ref[0].astype(jnp.float32)             # [KH, rep, D]
-    s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale   # [KH, rep, Pg]
-    pos = pos_ref[b]
-    kpos = p * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 2)
-    s = jnp.where(kpos <= pos, s, _NEG_INF)
-
-    m_prev = m_sc[...]                            # [KH, rep, 1]
-    m_cur = jnp.max(s, axis=2, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    # Fully-masked pages keep exp() finite.
-    m_safe = jnp.maximum(m_new, -1e29)
-    alpha = jnp.exp(m_prev - m_safe)
-    pexp = jnp.exp(s - m_safe)                    # [KH, rep, Pg]
-    l_sc[...] = l_sc[...] * alpha + \
-        jnp.sum(pexp, axis=2, keepdims=True)
-    acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
-        pexp, v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)       # [KH, rep, D]
-    m_sc[...] = m_new
-
-    @pl.when(p == n_p - 1)
-    def _fin():
-        l = jnp.maximum(l_sc[...], 1e-30)
-        o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
+# Tokens of context one iteration of the paged window loop gathers and
+# attends (rounded to whole pages): the unit in which the attended
+# window follows the live contexts. Smaller blocks waste less on the
+# last, partly filled block and pay the loop's fixed cost more often
+# (PERF.md section 6, PR 26 has the chip's readings).
+_WINDOW_BLOCK_TOKENS = 512
 
 
-def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-            m_sc, l_sc, acc_sc, *, page_size: int, scale: float):
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    k = k_ref[:, 0].astype(jnp.float32)          # [KH, Pg, D]
-    v = v_ref[:, 0].astype(jnp.float32)          # [KH, Pg, D]
-    _attend_page(b, p, pos_ref, q_ref, k, v, o_ref,
-                 m_sc, l_sc, acc_sc, page_size=page_size, scale=scale)
+def paged_window_block_pages(page_size: int, max_pages: int) -> int:
+    """Logical pages one iteration of the paged window loop covers: a
+    constant of the shapes, not a knob."""
+    return min(max_pages, max(1, _WINDOW_BLOCK_TOKENS // page_size))
 
 
-def _kernel_q(pt_ref, pos_ref, sk_ref, sv_ref, q_ref, k_ref, v_ref,
-              o_ref, m_sc, l_sc, acc_sc, *, page_size: int,
-              scale: float):
-    """Int8 variant: the fp32 absmax scales of the pages this call
-    attends ride SCALAR PREFETCH next to the page table (flat
-    ``[KH * B * max_pages]``, gathered by the wrapper), and the
-    dequantize happens IN REGISTER right after the page DMA — the fp
-    window never exists in HBM or VMEM, so the kernel's memory
-    footprint is the halved int8 one. (A ``(KH, 1, 1)`` VMEM block
-    over the ``[KH, n_pages, 1]`` scale tensor is not tileable: Mosaic
-    wants the last two block dims (8, 128)-aligned or whole.)"""
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    n_b = pl.num_programs(0)
-    n_p = pl.num_programs(1)
-    KH = k_ref.shape[0]
-    inv = 1.0 / _QMAX
-    # [KH, 1, 1] scale columns assembled from KH SMEM scalars: KH is
-    # the untiled leading dim, so the selects touch one vreg each.
-    h_iota = jax.lax.broadcasted_iota(jnp.int32, (KH, 1, 1), 0)
-    sk = jnp.zeros((KH, 1, 1), jnp.float32)
-    sv = jnp.zeros((KH, 1, 1), jnp.float32)
-    for h in range(KH):
-        i = (h * n_b + b) * n_p + p
-        sk = jnp.where(h_iota == h, sk_ref[i] * inv, sk)
-        sv = jnp.where(h_iota == h, sv_ref[i] * inv, sv)
-    k = k_ref[:, 0].astype(jnp.float32) * sk      # [KH, Pg, D]
-    v = v_ref[:, 0].astype(jnp.float32) * sv
-    _attend_page(b, p, pos_ref, q_ref, k, v, o_ref,
-                 m_sc, l_sc, acc_sc, page_size=page_size, scale=scale)
+def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
+    """Causal grouped-query attention of ``q`` [B, T, H, D] (row b's
+    queries at absolute positions ``pos[b] + t``) over its page-table
+    row's K/V in the page-major pool ``pk``/``pv`` [n_pages, Pg, KH, D]
+    (``sk``/``sv``: an int8 pool's per-page scales [n_pages, KH], else
+    None). Each page is gathered whole, by its id, as it lies.
 
+    Work follows the live contexts, not the table's width: a loop with
+    a RUNTIME trip count walks blocks of ``block_pages`` logical pages
+    up to the block holding the last position any live row can see,
+    folding each block's float32 scores into a running max / sum /
+    accumulator (the online softmax: the same mathematics as one
+    softmax over the whole window, nothing approximated, no visible
+    position left out). A live row is one whose page-table row is not
+    the null row: non-riders and dummy prefill rows carry rows of 0,
+    and their ``pos`` may be stale and large, so they must not widen
+    the window. The count is a value, not a shape: one executable
+    serves every context length, and inside the decode loop it is
+    recomputed every step, so a context that crosses a block's edge in
+    the middle of a dispatch is still attended whole.
 
-def kernel_pool_view(t):
-    """The kernel's head-major view of one tensor of the engine's
-    page-major pool: pages [n_pages, Pg, KH, D] -> [KH, n_pages, Pg, D],
-    an int8 pool's scales [n_pages, KH] -> [KH, n_pages, 1], None as
-    it is (an fp pool has no scales)."""
-    if t is None:
-        return None
-    return t.transpose(2, 0, 1, 3) if t.ndim == 4 else t.T[..., None]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention(q, pages_k, pages_v, page_table, positions,
-                           scales_k=None, scales_v=None,
-                           interpret: bool | None = None):
-    """One decode step of paged attention.
-
-    q: [B, H, D]; returns [B, H, D] in q.dtype. See module docstring
-    for the kernel's HEAD-MAJOR layout ([KH, n_pages, Pg, D]: a
-    transposed view of the engine's page-major pool). Falls back
-    transparently to interpreter mode off-TPU (tests). Int8 pools pass scales_k/scales_v
-    ([KH, n_pages, 1] fp32) and get in-register dequantization.
+    The named scopes (kv_gather, attn_scores, attn_pv) are metadata
+    only: a device trace splits a step's time by them (PERF.md
+    section 3).
     """
-    B, H, D = q.shape
-    KH, n_pages, Pg, Dk = pages_k.shape
-    assert D == Dk, (D, Dk)
-    rep = H // KH
+    B, T, H, D = q.shape
+    _, Pg, KH, _ = pk.shape
     max_pages = page_table.shape[1]
-    qg = q.reshape(B, KH, rep, D)
-    scale = 1.0 / (D ** 0.5)
-    quantized = scales_k is not None
-    if quantized:
-        _check_scale_shapes(pages_k, scales_k, scales_v,
-                            (KH, n_pages, 1))
+    block_pages = paged_window_block_pages(Pg, max_pages)
+    Lb = block_pages * Pg
+    max_blocks = -(-max_pages // block_pages)
+    # Grouped-query attention WITHOUT materializing repeated K/V: q
+    # reshapes to [B, T, KH, rep, D] and contracts against the grouped
+    # cache directly (a repeat would move rep x the KV bytes a step).
+    qg = q.reshape(B, T, KH, H // KH, D).astype(jnp.float32)
+    # causal over absolute positions: query t of row b sits at
+    # pos[b] + t and sees keys 0..pos[b]+t
+    q_pos = pos[:, None] + jnp.arange(T)[None]              # [B, T]
+    with jax.named_scope("kv_gather"):
+        # a whole number of blocks: columns past the table are null
+        # pages, which the mask never lets a live query see
+        table = jnp.pad(
+            page_table,
+            ((0, 0), (0, max_blocks * block_pages - max_pages)))
+        live = page_table[:, 0] != 0
+        last = jnp.max(jnp.where(live, pos + (T - 1), 0))
+        n_blocks = jnp.minimum(last // Lb + 1, max_blocks)
 
-    grid = (B, max_pages)
-    prefetch = [page_table, positions]
-    kern = _kernel
-    if quantized:
-        # the scales of exactly the pages the table names, flat
-        # [KH * B * max_pages] fp32: KH x the page table's own SMEM
-        # footprint, whatever the pool size
-        prefetch += [scales_k[:, page_table, 0].reshape(-1),
-                     scales_v[:, page_table, 0].reshape(-1)]
-        kern = _kernel_q
+    def block(j, carry):
+        m, l, acc = carry
+        with jax.named_scope("kv_gather"):
+            cols = jax.lax.dynamic_slice_in_dim(
+                table, j * block_pages, block_pages, axis=1)
+            # [B, block_pages, Pg, KH, D] -> [B, Lb, KH, D]; gathered
+            # index + j * Lb == logical position by construction
+            kg = pk[cols]
+            vg = pv[cols]
+            if sk is not None:
+                # dequantize the gathered block in fp32 with the
+                # gathered per-page scales (value = q * s / 127): only
+                # one block ever exists in fp, never the pool itself
+                kg = kg.astype(jnp.float32) * \
+                    (sk[cols] * (1.0 / 127.0))[:, :, None, :, None]
+                vg = vg.astype(jnp.float32) * \
+                    (sv[cols] * (1.0 / 127.0))[:, :, None, :, None]
+            kg = kg.reshape(B, Lb, KH, D)
+            vg = vg.reshape(B, Lb, KH, D)
+        with jax.named_scope("attn_scores"):
+            s = jnp.einsum("btkrd,bskd->bkrts", qg,
+                           kg.astype(jnp.float32)) / np.sqrt(D)
+            valid = (j * Lb + jnp.arange(Lb))[None, None] <= \
+                q_pos[:, :, None]                            # [B, T, Lb]
+            s = jnp.where(valid[:, None, None], s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            scale = jnp.exp(m - m_new)
+            l = l * scale + jnp.sum(p, axis=-1)
+        with jax.named_scope("attn_pv"):
+            acc = acc * scale[..., None] + jnp.einsum(
+                "bkrts,bskd->bkrtd", p.astype(vg.dtype), vg,
+                preferred_element_type=jnp.float32)
+        return m_new, l, acc
 
-    def _page(b, p, pt, *_):
-        # ONE physical page of K/V across ALL kv heads, chosen by
-        # the scalar-prefetched page table: [KH, 1, Pg, D]
-        return (0, pt[b, p], 0, 0)
-
-    def _slot(b, p, *_):
-        # q/out block for this slot, every head: [1, KH, rep, D]
-        return (b, 0, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, KH, rep, D), _slot),
-        pl.BlockSpec((KH, 1, Pg, D), _page),
-        pl.BlockSpec((KH, 1, Pg, D), _page),
-    ]
-    kernel = functools.partial(kern, page_size=Pg, scale=scale)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, KH, rep, D), _slot),
-            scratch_shapes=[
-                pltpu.VMEM((KH, rep, 1), jnp.float32),    # m
-                pltpu.VMEM((KH, rep, 1), jnp.float32),    # l
-                pltpu.VMEM((KH, rep, D), jnp.float32),    # acc
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, KH, rep, D), q.dtype),
-        interpret=_interpret() if interpret is None else interpret,
-        name="paged_decode",
-    )(*prefetch, qg, pages_k, pages_v)
-    return out.reshape(B, H, D)
+    stat = (B, KH, H // KH, T)
+    carry = (jnp.full(stat, -1e30, jnp.float32),
+             jnp.zeros(stat, jnp.float32),
+             jnp.zeros(stat + (D,), jnp.float32))
+    if max_blocks == 1:
+        # the table is one block wide: no loop, the one-shot softmax
+        # over the whole window as straight-line code
+        carry = block(0, carry)
+    else:
+        carry = jax.lax.fori_loop(0, n_blocks, block, carry)
+    _, l, acc = carry
+    with jax.named_scope("attn_pv"):
+        # key 0 is visible to every query, so l > 0
+        y = (acc / l[..., None]).astype(q.dtype)
+    # [B, KH, rep, T, D] -> [B, T, H, D]
+    return y.transpose(0, 3, 1, 2, 4).reshape(B, T, H, D)
 
 
 def dequantize_pages(pages, scales):

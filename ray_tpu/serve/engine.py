@@ -55,7 +55,6 @@ import contextlib
 import dataclasses
 import functools
 import itertools
-import os
 import queue
 import threading
 import time
@@ -67,10 +66,12 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from ray_tpu.models.kv_cache import (BlockAllocator, PagedKVLayer,
+                                     check_kv_dtype,
                                      export_page_bytes, init_kv_pool,
                                      kv_layer_store, kv_layer_view,
                                      kv_pool_page_bytes,
                                      page_cols_from_bytes)
+from ray_tpu.ops.paged_attention import paged_window_block_pages
 from ray_tpu.serve import kv_migration, obs, spec_decode
 # Typed lifecycle errors live in a jax-free module (serve/errors.py)
 # so the HTTP proxy and clients can import them without the device
@@ -501,8 +502,7 @@ class LLMEngine:
         clamp-and-reseed machinery spec-decode rollback uses.
         ``overlap=False`` restores the lockstep loop (full blocking
         drain before planning in eos/spec mode — the PR-10 latency
-        profile). Env ``RAY_TPU_OVERLAP=0``/``1`` force-overrides
-        the knob for A/B runs without touching call sites.
+        profile).
     capture_logprobs: record the sampling logprob of every emitted
         token (RL rollout capture, ray_tpu/rl). The jitted decode and
         prefill steps compute ``log_softmax`` of the sampling logits
@@ -522,8 +522,8 @@ class LLMEngine:
         /prefix residency. Outputs are tolerance-equal to fp (greedy
         token agreement gated in tests; spec accept-rate unchanged
         within noise), NOT bit-equal: quantized bytes depend on
-        write history (docs/serving.md). Env ``RAY_TPU_KV_DTYPE``
-        overrides; junk values raise EnvKnobError.
+        write history (docs/serving.md). Anything else is a
+        ``ValueError`` (models/kv_cache.py ``check_kv_dtype``).
     """
 
     def __init__(self, model, params, *, max_slots: int = 8,
@@ -545,7 +545,7 @@ class LLMEngine:
                  fault_injector=None,
                  events: bool = True,
                  flight_dir: Optional[str] = None,
-                 overlap: Optional[bool] = None,
+                 overlap: bool = True,
                  kv_dtype: Optional[str] = None,
                  prefix_digest_max: int = 512,
                  role: str = ROLE_UNIFIED,
@@ -593,21 +593,16 @@ class LLMEngine:
         # what the model can legally address rather than the whole
         # pool. The attention programs gather and attend only up to
         # the batch's longest live context, in blocks of
-        # ``_window_block`` tokens (models/llama.py
+        # ``_window_block`` tokens (ops/paged_attention.py
         # _paged_window_attention); the width bounds that window.
         self.max_pages = min(n_pages - 1,
                              -(-self.cfg.max_seq_len // page_size))
-        from ray_tpu.models.llama import paged_window_block_pages
         self._window_block = page_size * paged_window_block_pages(
             page_size, self.max_pages)
         # KV storage dtype: "fp" (cfg.dtype pages, PR 1-14 behavior)
         # or "int8" (quantized pages + per-page scales, half the page
-        # bytes -> double the pages at a fixed byte budget). The env
-        # override RAY_TPU_KV_DTYPE wins over the constructor arg so
-        # bench/chaos harnesses can flip whole fleets; junk values in
-        # either raise typed errors (util/envknobs.py).
-        from ray_tpu.util.envknobs import resolve_kv_dtype
-        self.kv_dtype = resolve_kv_dtype(kv_dtype)
+        # bytes -> double the pages at a fixed byte budget).
+        self.kv_dtype = check_kv_dtype(kv_dtype)
         # Disaggregation role (serve/scheduler.py REPLICA_ROLES):
         # selects the planner knob clamps via role_plan_caps and is
         # stamped into every load_report so routing, autoscaling, and
@@ -719,11 +714,7 @@ class LLMEngine:
         # every round; the OVERLAPPED loop (default) plans from the
         # stale frontier instead and detects eos at readback time.
         self._deferred = eos_id is None
-        _env = os.environ.get("RAY_TPU_OVERLAP", "")
-        if _env in ("0", "1"):
-            self.overlap = _env == "1"
-        else:
-            self.overlap = True if overlap is None else bool(overlap)
+        self.overlap = bool(overlap)
         self._stopped = False
         self._draining = False
         # Progress heartbeat (watchdog signal, serve/watchdog.py):

@@ -49,7 +49,7 @@ class FleetNeedsCpuBackend(RuntimeError):
 
 def require_cpu_backend(env) -> None:
     """Gate every launcher of fleet agent processes (this provider,
-    tools/chaos_serve.py --fleet, serve_bench.py --fleet)."""
+    tools/chaos_serve.py --fleet)."""
     if env.get("JAX_PLATFORMS", "").strip() != "cpu":
         raise FleetNeedsCpuBackend(
             "the multi-process fleet is a CPU-tested control plane, "

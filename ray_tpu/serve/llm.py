@@ -1,17 +1,15 @@
 """LLM serving: Llama replicas behind serve deployments.
 
 The build's serving north star (BASELINE.md: "Serve Llama-2-7B JAX
-replicas autoscaled on v5e"): a deployment class wrapping a jitted
-Llama decode (models/llama.py generate — prefill + while_loop KV-cache
-steps), with request batching via the serve batching queue and an
-optional device mesh per replica (tensor-parallel serving = a replica
-whose mesh has a nontrivial `tensor` axis; cf. serve/_private/replica.py
-in the reference for the replica wrapper shape)."""
+replicas autoscaled on v5e"): a deployment class whose every request
+goes through the continuous-batching engine (serve/engine.py: paged
+KV, chunked prefill beside decode), with an optional device mesh per
+replica (tensor-parallel serving = a replica whose mesh has a
+nontrivial `tensor` axis; cf. serve/_private/replica.py in the
+reference for the replica wrapper shape)."""
 from __future__ import annotations
 
 from typing import Any, Dict, List, Optional
-
-import numpy as np
 
 
 def _family_for(cfg):
@@ -32,10 +30,9 @@ class LlamaDeployment:
     num_replicas/autoscaling stay caller-controlled."""
 
     def __init__(self, config=None, params=None, max_new_tokens: int = 64,
-                 temperature: float = 0.0, stream_chunk: int = 8,
-                 use_engine: bool = True, max_slots: int = 16,
+                 temperature: float = 0.0, max_slots: int = 16,
                  page_size: int = 64, n_pages: Optional[int] = None,
-                 decode_chunk: Optional[int] = None,
+                 decode_chunk: int = 8,
                  prefill_chunk: Optional[int] = None,
                  eos_id: Optional[int] = None,
                  prefix_cache: bool = False,
@@ -55,7 +52,7 @@ class LlamaDeployment:
                  autoscale_provider=None,
                  engine_stall_deadline_s: Optional[float] = None,
                  watchdog_interval_s: Optional[float] = None,
-                 overlap: Optional[bool] = None,
+                 overlap: bool = True,
                  fleet: int = 0,
                  fleet_lease_ttl_s: float = 2.0,
                  kv_dtype: Optional[str] = None,
@@ -79,15 +76,10 @@ class LlamaDeployment:
         self.params = params
         self.max_new_tokens = max_new_tokens
         self.temperature = temperature
-        # tokens per device round trip when streaming: each chunk pays
-        # one host-sync latency, so bigger chunks raise steady-state
-        # tok/s at the cost of burstier delivery (TTFT is unaffected)
-        self.stream_chunk = stream_chunk
         self.mesh = None
         # Continuous batching (serve/engine.py): requests join/leave
         # the decode batch at token granularity instead of riding
         # whole-call batches (supersedes @serve.batch for LLMs).
-        self.use_engine = use_engine
         self._engine = None
         import threading
         self._engine_lock = threading.Lock()
@@ -232,7 +224,10 @@ class LlamaDeployment:
             self.decode_replicas = None
         self._engine_opts = dict(
             max_slots=max_slots, page_size=page_size,
-            n_pages=n_pages, chunk=decode_chunk or stream_chunk,
+            # decode steps per device round trip: each chunk pays
+            # one host-sync latency, so bigger chunks raise
+            # steady-state tok/s at the cost of burstier delivery
+            n_pages=n_pages, chunk=decode_chunk,
             prefill_chunk=prefill_chunk, eos_id=eos_id,
             prefix_cache=prefix_cache,
             spec_len=spec_len, spec_ngram=spec_ngram,
@@ -242,8 +237,7 @@ class LlamaDeployment:
             # wedged scheduler sheds-and-reroutes instead of parking
             # on the wedged engine's lock
             admit_timeout_s=engine_stall_deadline_s,
-            # overlapped hot loop (engine.py): None defers to the
-            # engine default (on) and the RAY_TPU_OVERLAP override
+            # overlapped hot loop (engine.py); False is the lockstep one
             overlap=overlap,
             # KV storage dtype ("fp"/"int8"): int8 halves page bytes
             # at tolerance-gated parity; every replica/fleet engine
@@ -504,7 +498,7 @@ class LlamaDeployment:
         """Replica metrics hook (merged into Replica.stats() under
         "user"): engine counters plus live slot occupancy, without
         forcing a lazy engine into existence."""
-        if not self.use_engine or self._engine is None:
+        if self._engine is None:
             return {"engine": None}
         eng = self._engine
         if self.fleet:
@@ -577,7 +571,7 @@ class LlamaDeployment:
         """Compact load snapshot for the controller's replica table
         (engine or pool-aggregate; None before the lazy engine
         exists — an idle replica carries no load)."""
-        if not self.use_engine or self._engine is None:
+        if self._engine is None:
             return None
         rpt = dict(self._engine.load_report())
         # the digest is an intra-pool affinity signal, not something
@@ -637,6 +631,20 @@ class LlamaDeployment:
             return f"{gen}:{getattr(eng, 'weights_id', None)}"
         return "0:g0"
 
+    def _echo(self, payload, h) -> Dict[str, Any]:
+        """What a dict payload asked to have echoed about the handle
+        that serves it: ``replica`` (``echo_replica``) and
+        ``generation`` (``echo_generation``); empty for a plain
+        request."""
+        echo: Dict[str, Any] = {}
+        if isinstance(payload, dict):
+            if payload.get("echo_replica"):
+                echo["replica"] = getattr(
+                    h, "replica_tag", None) or "0:0"
+            if payload.get("echo_generation"):
+                echo["generation"] = self._weights_tag(h)
+        return echo
+
     def __call__(self, prompt_ids: List[int]) -> List[int]:
         """One request: token ids in, prompt+generated ids out.
 
@@ -648,31 +656,11 @@ class LlamaDeployment:
         fleet ``replica_id:generation``, single engine ``0:0``), so
         a client can see a failover land on a different
         incarnation."""
-        if self.use_engine:
-            ids, mnt, dl, sid, tid = self._request_args(prompt_ids)
-            h = self._submit(ids, mnt, dl, sid, tid)
-            gen = h.result()
-            out = list(ids) + gen
-            echo_rep = isinstance(prompt_ids, dict) \
-                and prompt_ids.get("echo_replica")
-            echo_gen = isinstance(prompt_ids, dict) \
-                and prompt_ids.get("echo_generation")
-            if echo_rep or echo_gen:
-                resp: Dict[str, Any] = {"ids": out}
-                if echo_rep:
-                    resp["replica"] = getattr(
-                        h, "replica_tag", None) or "0:0"
-                if echo_gen:
-                    resp["generation"] = self._weights_tag(h)
-                return resp
-            return out
-        import jax.numpy as jnp
-        from ray_tpu.models.llama import generate
-        prompt = jnp.asarray([prompt_ids], jnp.int32)
-        out = generate(self.model, self.params, prompt,
-                       max_new_tokens=self.max_new_tokens,
-                       temperature=self.temperature)
-        return np.asarray(out[0]).tolist()
+        ids, mnt, dl, sid, tid = self._request_args(prompt_ids)
+        h = self._submit(ids, mnt, dl, sid, tid)
+        out = list(ids) + h.result()
+        echo = self._echo(prompt_ids, h)
+        return {"ids": out, **echo} if echo else out
 
     def stream(self, prompt_ids: List[int]):
         """Streaming request: yields each generated token id as soon
@@ -686,70 +674,29 @@ class LlamaDeployment:
         committing the chunked response, so streaming clients get
         the same which-incarnation-served-me signal unary clients
         do."""
-        if self.use_engine:
-            ids, mnt, dl, sid, tid = self._request_args(prompt_ids)
-            h = self._submit(ids, mnt, dl, sid, tid)
-            echo_rep = isinstance(prompt_ids, dict) \
-                and prompt_ids.get("echo_replica")
-            echo_gen = isinstance(prompt_ids, dict) \
-                and prompt_ids.get("echo_generation")
-            if echo_rep or echo_gen:
-                marker: Dict[str, Any] = {}
-                if echo_rep:
-                    marker["replica"] = getattr(
-                        h, "replica_tag", None) or "0:0"
-                if echo_gen:
-                    marker["generation"] = self._weights_tag(h)
-                yield marker
-            try:
-                yield from h.stream()
-            except GeneratorExit:
-                # The client disconnected: the replica abandons the
-                # stream and garbage-collects this generator
-                # (controller.py _drain_sync), which closes it here.
-                # Cancel so the slot and its KV pages free NOW — an
-                # abandoned stream must not decode to completion.
-                h.cancel()
-                raise
-            return
-        import jax.numpy as jnp
-        from ray_tpu.models.llama import generate_stream
-        prompt = jnp.asarray([prompt_ids], jnp.int32)
-        for tok in generate_stream(self.model, self.params, prompt,
-                                   max_new_tokens=self.max_new_tokens,
-                                   temperature=self.temperature,
-                                   chunk_size=self.stream_chunk):
-            yield int(tok[0])
+        ids, mnt, dl, sid, tid = self._request_args(prompt_ids)
+        h = self._submit(ids, mnt, dl, sid, tid)
+        echo = self._echo(prompt_ids, h)
+        if echo:
+            yield echo
+        try:
+            yield from h.stream()
+        except GeneratorExit:
+            # The client disconnected: the replica abandons the
+            # stream and garbage-collects this generator
+            # (controller.py _drain_sync), which closes it here.
+            # Cancel so the slot and its KV pages free NOW — an
+            # abandoned stream must not decode to completion.
+            h.cancel()
+            raise
 
     def generate_batch(self, prompts: List[List[int]]) -> List[List[int]]:
-        """Batched generation for throughput serving: prompts are
-        bucketed by length and each bucket decodes as one batch on the
-        chip (one MXU-efficient kernel instead of B tiny ones).
-
-        Bucketing instead of padding: the model applies only a causal
-        mask, so padding a shorter prompt would let it attend to the
-        pad tokens and change its completion versus an unbatched
-        call — same-length batching is the correctness-preserving way
-        to batch (serving clients typically use fixed prompt shapes,
-        giving one bucket)."""
-        if self.use_engine:
-            eng = self.engine()
-            hs = [eng.submit(p, max_new_tokens=self.max_new_tokens)
-                  for p in prompts]
-            return [h.result() for h in hs]
-        import jax.numpy as jnp
-        from ray_tpu.models.llama import generate
-        buckets: Dict[int, List[int]] = {}
-        for i, p in enumerate(prompts):
-            buckets.setdefault(len(p), []).append(i)
-        results: List[Optional[List[int]]] = [None] * len(prompts)
-        for plen, idxs in buckets.items():
-            batch = np.asarray([prompts[i] for i in idxs], np.int32)
-            out = generate(self.model, self.params,
-                           jnp.asarray(batch),
-                           max_new_tokens=self.max_new_tokens,
-                           temperature=self.temperature)
-            gen = np.asarray(out)[:, plen:]
-            for row, i in zip(gen, idxs):
-                results[i] = row.tolist()
-        return results
+        """Batched generation for throughput serving: every prompt is
+        submitted to the engine at once and joins its decode batch as
+        slots free up (continuous batching: prompts of any lengths
+        share a step, and each completion equals its unbatched
+        call's). Returns the generated ids only, in prompt order."""
+        eng = self.engine()
+        hs = [eng.submit(p, max_new_tokens=self.max_new_tokens)
+              for p in prompts]
+        return [h.result() for h in hs]

@@ -466,6 +466,15 @@ class _GatedLoopback(Transport):
                                 trace_id=trace_id)
 
 
+def _until(cond, timeout_s=30.0, poll_s=0.02):
+    """Poll ``cond`` up to a deadline; its last reading either way (the
+    asserts after it say what was missing)."""
+    deadline = time.monotonic() + timeout_s
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(poll_s)
+    return cond()
+
+
 def test_fleet_three_way_race():
     """Lease expiry (partition) vs graceful drain vs hard kill, all
     racing in one 3-agent fleet under client load: every admitted
@@ -491,6 +500,8 @@ def test_fleet_three_way_race():
     results = {"ok": 0, "typed": 0, "lost": 0, "mismatched": 0}
     rlock = threading.Lock()
     stop = threading.Event()
+    # set by a client that a0 served after it had re-joined
+    rebuilt = threading.Event()
 
     def client(cseed):
         i = 0
@@ -498,12 +509,15 @@ def test_fleet_three_way_race():
             i += 1
             p = [cseed, i % 50]
             try:
-                got = r.submit(p, max_new_tokens=4).result()
+                h = r.submit(p, max_new_tokens=4)
+                got = h.result()
                 with rlock:
                     if got == scripted_completion(p, 4):
                         results["ok"] += 1
                     else:
                         results["mismatched"] += 1
+                if h.replica_idx == "a0" and agents["a0"].generation:
+                    rebuilt.set()
             except (EngineShutdown, EngineDraining,
                     EngineOverloaded):
                 with rlock:
@@ -516,22 +530,46 @@ def test_fleet_three_way_race():
                for c in range(3)]
     for t in threads:
         t.start()
+    def members():
+        return {m["replica_id"] for m in d.rpc_snapshot()["members"]}
+
+    def settled():
+        # what the asserts below read, and nothing else: on a loaded
+        # host the collapse and the rebuild take as long as they take
+        a0 = agents["a0"]
+        return (a0.counters["self_fences"] >= 1 and a0.generation >= 1
+                and a0.state == "active"
+                and "a1" in d.rpc_stats()["tombstones"]
+                and r.counters["deaths_confirmed"] >= 1
+                and members() == {"a0"} and rebuilt.is_set())
+
     try:
-        time.sleep(0.15)
-        # the race: partition a0 (lease expiry path), drain a1
-        # (scale-down path), kill a2 (crash path) — all inside one
-        # lease period
+        # load is flowing through a whole fleet before the race
+        # starts, and the kill catches a request in flight on a2:
+        # that request's poll loop is what carries the router to the
+        # directory's verdict once a2's lease has run out (its
+        # patience, 0.4 s, outlasts the 0.3 s lease). With nothing in
+        # flight there, nobody asks: the router routes around an
+        # expired member, and a2 stays a death candidate in the
+        # snapshot for good.
+        _until(lambda: results["ok"] >= 3
+               and members() == {"a0", "a1", "a2"})
+        _until(lambda: agents["a2"].engine._active >= 1, poll_s=0.0005)
+        # the race: kill a2 (crash path: the host is gone before
+        # anyone hears of its engine), partition a0 (lease expiry
+        # path), drain a1 (scale-down path) — all inside one lease
+        # period
+        agents["a2"]._partition_until = float("inf")
+        agents["a2"]._stop.set()          # renewals die with the host
+        agents["a2"].engine.force_kill(
+            EngineShutdown("simulated SIGKILL"))
         agents["a0"].rpc_inject_partition(duration_s=0.8)
         threading.Thread(
             target=lambda: agents["a1"].rpc_drain(timeout_s=2.0),
             daemon=True).start()
-        agents["a2"].engine.force_kill(
-            EngineShutdown("simulated SIGKILL"))
-        agents["a2"]._stop.set()          # renewals die with the host
-        agents["a2"]._partition_until = float("inf")
 
         # let the fleet collapse to zero and rebuild from a0
-        time.sleep(1.6)
+        _until(settled)
     finally:
         stop.set()
         for t in threads:
@@ -552,8 +590,7 @@ def test_fleet_three_way_race():
         assert "a1" in st["tombstones"]
         # a2's death was adjudicated by the directory, not guessed
         assert r.counters["deaths_confirmed"] >= 1
-        snap = {m["replica_id"]
-                for m in d.rpc_snapshot()["members"]}
+        snap = members()
         assert "a2" not in snap and "a1" not in snap
         assert "a0" in snap
         # and the recovered fleet still serves token-identically

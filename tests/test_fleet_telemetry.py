@@ -275,8 +275,8 @@ def test_collector_loopback_scrape_trace_and_metrics(tmp_path):
         assert served in ph["members"]
         assert "router" in ph["members"]
         # loopback fleet = one OS process: spans exist per member but
-        # the pid set collapses (the >=3-process stitch is proven by
-        # serve_bench --fleet --trace over real processes)
+        # the pid set collapses (the >=3-process stitch is on record
+        # in the committed SERVE_FLEET_TRACE artifact)
         assert ph["n_processes"] == 1 and ph["stitched"] is False
         for span in ph["spans"]:
             assert span["end_s"] >= span["start_s"]

@@ -5,9 +5,8 @@ Op level (ops/paged_attention.py): symmetric absmax int8 round-trips
 within scale/254 per element, per-PAGE scales isolate magnitude across
 page boundaries, the reset-on-offset-0 rule retires a freed page's
 stale scale with no host bookkeeping, spec-rollback garbage past
-``pos`` is precision-only (masked at read, never attended), and the
-pallas kernel (interpret mode) dequantizes in-register to the same
-numbers as the gather fallback.
+``pos`` is precision-only (masked at read by the block gather, never
+attended).
 
 Engine level (serve/engine.py kv_dtype="int8"): deterministic given a
 write history (same engine + load twice -> identical tokens; prefix
@@ -18,27 +17,22 @@ docs/serving.md), spec accept-rate preserved, tp-sharded pools with
 scale rows pinned alongside their heads, and the bytes view
 (kv_pool_page_bytes -> BlockAllocator -> load_report -> gauge).
 """
-import os
-
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models.kv_cache import (BlockAllocator, init_kv_pool,
-                                     kv_layer_store, kv_layer_view,
-                                     kv_pool_page_bytes, PagedKVLayer)
+from ray_tpu.models.kv_cache import (BlockAllocator, check_kv_dtype,
+                                     init_kv_pool, kv_layer_store,
+                                     kv_layer_view, kv_pool_page_bytes,
+                                     PagedKVLayer)
 from ray_tpu.models.llama import Llama, llama_tiny
-from ray_tpu.ops.paged_attention import (dequantize_pages,
-                                         kernel_pool_view,
+from ray_tpu.ops.paged_attention import (_paged_window_attention,
+                                         dequantize_pages,
                                          paged_append,
-                                         paged_decode_attention,
                                          PagedShapeError)
 from ray_tpu.serve.engine import LLMEngine
 from ray_tpu.serve.faults import check_quiesced
-from ray_tpu.util.envknobs import (EnvKnobError, parse_kv_dtype_env,
-                                   parse_paged_kernel_env,
-                                   resolve_kv_dtype)
 
 KH, PG, D = 2, 8, 16
 
@@ -174,11 +168,13 @@ def test_mid_page_append_grows_scale_without_reset():
     assert np.abs(deq - ref).max() <= s2.max() / 127.0 + 1e-6
 
 
-# ------------------------------------- masking, kernel, shape errors
+# --------------------------------------------- masking, shape errors
 
 def _dense_ref_deq(q, pk, sk, pv, sv, pt, pos):
-    kg = np.asarray(dequantize_pages(pk, sk))
-    vg = np.asarray(dequantize_pages(pv, sv))
+    """Dense softmax over the pool's fp view (an fp pool has no scales
+    and is its own view)."""
+    kg = np.asarray(pk if sk is None else dequantize_pages(pk, sk))
+    vg = np.asarray(pv if sv is None else dequantize_pages(pv, sv))
     B, H, Dh = q.shape
     kh = kg.shape[2]
     L = pt.shape[1] * pk.shape[1]
@@ -194,61 +190,51 @@ def _dense_ref_deq(q, pk, sk, pv, sv, pt, pos):
     return np.einsum("bkrs,bskd->bkrd", p, vq).reshape(B, H, Dh)
 
 
-def _kernel(q, pk, pv, pt, pos, sk, sv):
-    """The Pallas kernel on the head-major VIEW of a page-major pool,
-    as ``LlamaAttention`` hands it over."""
-    pk, pv, sk, sv = map(kernel_pool_view, (pk, pv, sk, sv))
-    return np.asarray(paged_decode_attention(q, pk, pv, pt, pos, sk, sv,
-                                             interpret=True))
+def _window(q, pk, pv, pt, pos, sk, sv):
+    """One decode step's attention through the block gather
+    (q [B, H, D] -> [B, H, D])."""
+    return np.asarray(jax.jit(_paged_window_attention)(
+        q[:, None], pk, pv, sk, sv, pt, pos))[:, 0]
 
 
-def test_rollback_garbage_is_masked_and_precision_only():
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_rollback_garbage_is_masked_and_precision_only(kv_dtype):
     # Spec rollback is a position clamp: rejected drafts stay in the
-    # pool past ``pos``. They may inflate the page scale (precision)
-    # but must never be ATTENDED (correctness).
+    # pool past ``pos``. In an int8 pool they may inflate the page
+    # scale (precision) but in either pool they must never be
+    # ATTENDED (correctness).
     rng = np.random.default_rng(5)
     pk, pv, sk, sv, pt = _fresh()
+    scales = (sk, sv)
+    if kv_dtype == "fp":
+        pk, pv, scales = (pk.astype(jnp.float32),
+                          pv.astype(jnp.float32), ())
     n_real = 6
     k, v = _kv(rng, 1, n_real)
-    pk, pv, sk, sv = paged_append(pk, pv, pt, jnp.zeros(1, jnp.int32),
-                                  k, v, sk, sv)
+    pk, pv, *scales = paged_append(pk, pv, pt, jnp.zeros(1, jnp.int32),
+                                   k, v, *scales)
     kg, vg = _kv(rng, 1, 2, scale=30.0)    # rejected drafts, big
-    pk2, pv2, sk2, sv2 = paged_append(
+    pk2, pv2, *scales2 = paged_append(
         pk, pv, pt, jnp.full((1,), n_real, jnp.int32), kg, vg,
-        sk, sv)
-    assert np.asarray(sk2)[1].max() > np.asarray(sk)[1].max()
+        *scales)
+    sk, sv = scales or (None, None)
+    sk2, sv2 = scales2 or (None, None)
+    if kv_dtype == "int8":
+        assert np.asarray(sk2)[1].max() > np.asarray(sk)[1].max()
     q = jnp.asarray(rng.standard_normal((1, 2 * KH, D)),
                     jnp.float32)
     pos = jnp.full((1,), n_real - 1, jnp.int32)
-    out = _kernel(q, pk2, pv2, pt, pos, sk2, sv2)
-    # reference over the dequantized REAL window of the garbage pool:
-    # the garbage positions are masked, so only the re-rounding of
-    # the real tokens (scale growth) can move the output
+    out = _window(q, pk2, pv2, pt, pos, sk2, sv2)
+    # reference over the (dequantized) REAL window of the garbage
+    # pool: the garbage positions are masked, so only the re-rounding
+    # of the real tokens (scale growth) can move the output
     ref = _dense_ref_deq(q, pk2, sk2, pv2, sv2, pt, pos)
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
     # and vs the garbage-free pool: bounded by one re-rounding step
+    # (int8), nothing at all (fp: no scale for the garbage to grow)
     clean = _dense_ref_deq(q, pk, sk, pv, sv, pt, pos)
-    assert np.abs(out - clean).max() < 0.5
-
-
-def test_kernel_matches_gather_dequant_int8():
-    rng = np.random.default_rng(6)
-    B, max_pages, n_pages = 3, 4, 32
-    pk = jnp.asarray(rng.integers(-127, 128, (n_pages, PG, KH, D)),
-                     jnp.int8)
-    pv = jnp.asarray(rng.integers(-127, 128, (n_pages, PG, KH, D)),
-                     jnp.int8)
-    sk = jnp.asarray(rng.uniform(0.1, 2.0, (n_pages, KH)),
-                     jnp.float32)
-    sv = jnp.asarray(rng.uniform(0.1, 2.0, (n_pages, KH)),
-                     jnp.float32)
-    pt = jnp.asarray(rng.permutation(n_pages - 1)[:B * max_pages]
-                     .reshape(B, max_pages) + 1, jnp.int32)
-    pos = jnp.asarray(rng.integers(0, max_pages * PG, B), jnp.int32)
-    q = jnp.asarray(rng.standard_normal((B, 2 * KH, D)), jnp.float32)
-    out = _kernel(q, pk, pv, pt, pos, sk, sv)
-    ref = _dense_ref_deq(q, pk, sk, pv, sv, pt, pos)
-    np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
+    assert np.abs(out - clean).max() < (
+        0.5 if kv_dtype == "int8" else 1e-5)
 
 
 def test_shape_errors():
@@ -314,35 +300,18 @@ def test_allocator_bytes_view():
     assert a.bytes_in_use() == 0
 
 
-# ------------------------------------------------------ env knobs
+# ------------------------------------------------- the argument
 
-def test_env_knobs_reject_junk(monkeypatch):
-    monkeypatch.setenv("RAY_TPU_KV_DTYPE", "bogus")
-    with pytest.raises(EnvKnobError) as ei:
-        parse_kv_dtype_env()
-    assert ei.value.name == "RAY_TPU_KV_DTYPE"
-    monkeypatch.setenv("RAY_TPU_PAGED_KERNEL", "yes")
-    with pytest.raises(EnvKnobError):
-        parse_paged_kernel_env()
-    monkeypatch.setenv("RAY_TPU_PAGED_KERNEL", "1")
-    assert parse_paged_kernel_env() is True
-    monkeypatch.setenv("RAY_TPU_PAGED_KERNEL", "")
-    assert parse_paged_kernel_env() is False
-
-
-def test_kv_dtype_resolution_precedence(monkeypatch):
-    monkeypatch.delenv("RAY_TPU_KV_DTYPE", raising=False)
-    assert resolve_kv_dtype(None) == "fp"
-    assert resolve_kv_dtype("int8") == "int8"
-    monkeypatch.setenv("RAY_TPU_KV_DTYPE", "int8")
-    assert resolve_kv_dtype("fp") == "int8"     # env wins over arg
-    monkeypatch.setenv("RAY_TPU_KV_DTYPE", "")
-    assert resolve_kv_dtype("int8") == "int8"   # empty = unset
-    with pytest.raises(ValueError):             # bad ARG: plain error
-        resolve_kv_dtype("fp16")
-    monkeypatch.setenv("RAY_TPU_KV_DTYPE", "int4")
-    with pytest.raises(EnvKnobError):           # bad ENV: typed error
-        resolve_kv_dtype(None)
+def test_kv_dtype_argument_validation(tiny):
+    assert check_kv_dtype(None) == "fp"
+    assert check_kv_dtype("fp") == "fp"
+    assert check_kv_dtype("int8") == "int8"
+    for junk in ("fp16", "int4", "", "INT8"):
+        with pytest.raises(ValueError, match="kv_dtype"):
+            check_kv_dtype(junk)
+    # the engine refuses it before it makes a pool
+    with pytest.raises(ValueError, match="kv_dtype"):
+        _engine(tiny, kv_dtype="int4")
 
 
 # ----------------------------------------------------- engine level
@@ -510,13 +479,3 @@ def test_tp4_int8_agreement(tiny, cpu_mesh_devices):
     agree = sum(x == y for a, b in zip(tp1, tp4)
                 for x, y in zip(a, b))
     assert agree / total >= 0.9, (agree, total)
-
-
-def test_engine_env_kv_dtype_override(tiny, monkeypatch):
-    monkeypatch.setenv("RAY_TPU_KV_DTYPE", "int8")
-    eng = _engine(tiny, kv_dtype="fp")
-    assert eng.kv_dtype == "int8"          # env wins over kwarg
-    eng.shutdown()
-    monkeypatch.setenv("RAY_TPU_KV_DTYPE", "int4")
-    with pytest.raises(EnvKnobError):
-        _engine(tiny)

@@ -5,7 +5,7 @@ and the serve_phase_* metric singletons.
 Pure unit tests over fakes — the engine/pool integration surface
 (typed events on the real scheduler hot path, trace-id plumbing) is
 covered by test_llm_engine.py / test_engine_pool.py and the
-serve_bench --trace artifact gate.
+committed SERVE_TRACE artifact's schema gate.
 """
 import json
 import os

@@ -92,26 +92,10 @@ REP_PROMPT = ([7, 8, 9, 10] * 6)[:20]
 # ------------------------------------------------------- knob resolution
 
 
-def test_overlap_default_on_and_kwarg(tiny_model, monkeypatch):
+def test_overlap_default_on_and_kwarg(tiny_model):
     model, params = tiny_model
-    monkeypatch.delenv("RAY_TPU_OVERLAP", raising=False)
     assert LLMEngine(model, params, max_slots=1, page_size=8,
                      n_pages=16).overlap is True
-    assert LLMEngine(model, params, max_slots=1, page_size=8,
-                     n_pages=16, overlap=False).overlap is False
-
-
-def test_overlap_env_override_beats_kwarg(tiny_model, monkeypatch):
-    """RAY_TPU_OVERLAP pins the mode for a live deployment bisect:
-    it must win over whatever the code passed."""
-    model, params = tiny_model
-    monkeypatch.setenv("RAY_TPU_OVERLAP", "0")
-    assert LLMEngine(model, params, max_slots=1, page_size=8,
-                     n_pages=16, overlap=True).overlap is False
-    monkeypatch.setenv("RAY_TPU_OVERLAP", "1")
-    assert LLMEngine(model, params, max_slots=1, page_size=8,
-                     n_pages=16, overlap=False).overlap is True
-    monkeypatch.setenv("RAY_TPU_OVERLAP", "bogus")
     assert LLMEngine(model, params, max_slots=1, page_size=8,
                      n_pages=16, overlap=False).overlap is False
 

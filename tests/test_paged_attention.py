@@ -1,22 +1,47 @@
-"""Pallas paged-attention decode kernel vs dense reference.
+"""The paged pool's two step-time operations (ops/paged_attention.py)
+against plain references.
 
-Kernel runs in interpreter mode on the CPU test mesh; the dense
-reference is the same math the llama gather fallback uses. Pools are
-built PAGE-MAJOR [n_pages, Pg, KH, D], as the engine stores them; the
-kernel keeps a head-major contract and gets the transposed view
-``LlamaAttention`` hands it (``kernel_pool_view``).
+``_paged_window_attention`` over a PAGE-MAJOR pool [n_pages, Pg, KH, D],
+as the engine stores it, fp and int8 (per-page scales [n_pages, KH]),
+against a dense numpy softmax over the same (dequantized) pages;
+``paged_append`` against plain numpy caches.
 """
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.ops.paged_attention import (kernel_pool_view,
-                                         paged_decode_attention)
+from ray_tpu.ops.paged_attention import (_paged_window_attention,
+                                         dequantize_pages)
 
 
-def _kernel_view(pages):
-    return kernel_pool_view(jnp.asarray(pages))
+def _quantize_pages(pages):
+    """A page-major fp pool coded as the int8 pool stores it: one
+    absmax scale per (page, kv head), value = q * scale / 127."""
+    scales = np.abs(pages).max(axis=(1, 3)).astype(np.float32)
+    q = np.round(pages / np.maximum(scales, 1e-30)[:, None, :, None]
+                 * 127.0).astype(np.int8)
+    return q, scales
+
+
+def _window(q, pk, pv, pt, pos, kv_dtype, dtype=None):
+    """One decode step's attention through the block gather, and the
+    fp pages the dense reference must read (the int8 pool's
+    dequantized view: the coding is paged_append's business, not the
+    gather's)."""
+    to = (lambda a: jnp.asarray(a, dtype)) if dtype else jnp.asarray
+    if kv_dtype == "int8":
+        (pk, sk), (pv, sv) = _quantize_pages(pk), _quantize_pages(pv)
+        ref_k = np.asarray(dequantize_pages(pk, sk))
+        ref_v = np.asarray(dequantize_pages(pv, sv))
+        pools = (jnp.asarray(pk), jnp.asarray(pv), jnp.asarray(sk),
+                 jnp.asarray(sv))
+    else:
+        ref_k, ref_v = (np.asarray(to(t), np.float32) for t in (pk, pv))
+        pools = (to(pk), to(pv), None, None)
+    out = jax.jit(_paged_window_attention)(
+        to(q)[:, None], *pools, jnp.asarray(pt), jnp.asarray(pos))
+    return out[:, 0], ref_k, ref_v
 
 
 def _dense_ref(q, pages_k, pages_v, page_table, positions):
@@ -50,114 +75,58 @@ def _random_layout(rng, B, n_pages, max_pages, Pg, KH, D, H,
     return q, pages_k, pages_v, page_table, positions
 
 
+KV_DTYPES = pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+
+
+@KV_DTYPES
 @pytest.mark.parametrize("rep", [1, 4])
-def test_kernel_matches_dense(rep):
+def test_window_matches_dense(rep, kv_dtype):
     rng = np.random.default_rng(0)
     B, Pg, KH, D = 3, 8, 2, 16
     max_pages, n_pages = 4, 64
     H = KH * rep
     q, pk, pv, pt, pos = _random_layout(
         rng, B, n_pages, max_pages, Pg, KH, D, H)
-    out = paged_decode_attention(
-        jnp.asarray(q), _kernel_view(pk), _kernel_view(pv),
-        jnp.asarray(pt), jnp.asarray(pos), interpret=True)
-    ref = _dense_ref(q, pk, pv, pt, pos)
+    out, ref_k, ref_v = _window(q, pk, pv, pt, pos, kv_dtype)
+    ref = _dense_ref(q, ref_k, ref_v, pt, pos)
     np.testing.assert_allclose(np.asarray(out), ref,
                                rtol=2e-4, atol=2e-4)
 
 
-def test_position_zero_and_full():
-    # pos=0 attends exactly one key; pos=L-1 attends the full window.
+@KV_DTYPES
+def test_position_zero_and_full(kv_dtype):
+    # pos=0 attends exactly one key; pos=L-1 attends the full window
+    # (a table three pages wide is one block: the straight-line path).
     rng = np.random.default_rng(1)
     B, Pg, KH, D, max_pages = 2, 4, 1, 8, 3
     H = 2
     q, pk, pv, pt, _ = _random_layout(
         rng, B, 32, max_pages, Pg, KH, D, H)
     pos = np.array([0, max_pages * Pg - 1], dtype=np.int32)
-    out = paged_decode_attention(
-        jnp.asarray(q), _kernel_view(pk), _kernel_view(pv),
-        jnp.asarray(pt), jnp.asarray(pos), interpret=True)
-    ref = _dense_ref(q, pk, pv, pt, pos)
+    out, ref_k, ref_v = _window(q, pk, pv, pt, pos, kv_dtype)
+    ref = _dense_ref(q, ref_k, ref_v, pt, pos)
     np.testing.assert_allclose(np.asarray(out), ref,
                                rtol=2e-4, atol=2e-4)
     # Slot 0's output must equal V at position 0 exactly (softmax
     # over a single key).
-    v0 = pv[pt[0, 0], 0, 0]
+    v0 = ref_v[pt[0, 0], 0, 0]
     np.testing.assert_allclose(np.asarray(out)[0, 0], v0,
                                rtol=1e-5, atol=1e-5)
 
 
-def test_bf16_inputs():
+@KV_DTYPES
+def test_bf16_inputs(kv_dtype):
     rng = np.random.default_rng(2)
     B, Pg, KH, D, max_pages = 2, 8, 2, 16, 2
     H = 4
     q, pk, pv, pt, pos = _random_layout(
         rng, B, 16, max_pages, Pg, KH, D, H)
-    to = lambda a: jnp.asarray(a, dtype=jnp.bfloat16)
-    out = paged_decode_attention(
-        to(q), _kernel_view(to(pk)), _kernel_view(to(pv)),
-        jnp.asarray(pt), jnp.asarray(pos), interpret=True)
+    out, ref_k, ref_v = _window(q, pk, pv, pt, pos, kv_dtype,
+                                dtype=jnp.bfloat16)
     assert out.dtype == jnp.bfloat16
-    ref = _dense_ref(q.astype(np.float32), pk.astype(np.float32),
-                     pv.astype(np.float32), pt, pos)
+    ref = _dense_ref(q.astype(np.float32), ref_k, ref_v, pt, pos)
     np.testing.assert_allclose(
         np.asarray(out, dtype=np.float32), ref, rtol=0.05, atol=0.05)
-
-
-@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
-def test_llama_decode_paths_agree(monkeypatch, kv_dtype):
-    """The llama paged branch must produce the same step output via
-    the pallas kernel (forced: it reads a head-major VIEW of the
-    page-major pool, scales too) and the XLA gather fallback."""
-    from ray_tpu.models.llama import LlamaConfig, Llama
-    from ray_tpu.models.kv_cache import init_kv_pool, kv_layer_view
-
-    cfg = LlamaConfig(vocab_size=64, max_seq_len=64, dim=32,
-                      n_layers=2, n_heads=4, n_kv_heads=2,
-                      hidden_dim=64, dtype=jnp.float32,
-                      param_dtype=jnp.float32)
-    model = Llama(cfg)
-    rng = jax.random.PRNGKey(0)
-    B = 2
-    pages = init_kv_pool(cfg, n_pages=16, page_size=4,
-                         kv_dtype=kv_dtype)
-    # Seed the pool with nonzero history so past positions matter.
-    if kv_dtype == "fp":
-        pages = [(pk + 0.1 * jax.random.normal(rng, pk.shape),
-                  pv + 0.1 * jax.random.normal(rng, pv.shape))
-                 for pk, pv in pages]
-    else:
-        r = np.random.default_rng(0)
-        pages = [tuple(
-            jnp.asarray(r.integers(-127, 128, t.shape), jnp.int8)
-            if t.ndim == 4 else
-            jnp.asarray(r.uniform(0.1, 0.5, t.shape), jnp.float32)
-            for t in layer) for layer in pages]
-    page_table = jnp.array([[1, 2, 3, 4], [5, 6, 7, 8]],
-                           dtype=jnp.int32)
-    tok = jax.random.randint(rng, (B, 1), 0, cfg.vocab_size)
-    params = jax.jit(model.init)(rng, tok)
-    pos = jnp.array([0, 13], dtype=jnp.int32)
-
-    def step(force):
-        monkeypatch.setenv("RAY_TPU_PAGED_KERNEL", force)
-
-        def fwd(params, pages):
-            # a fresh function per call: the knob is read at trace
-            # time, so each call traces its own branch
-            kv = [kv_layer_view(layer, page_table) for layer in pages]
-            out, _ = model.apply(params, tok, kv_caches=kv,
-                                 cache_len=pos)
-            return out
-
-        jaxpr = str(jax.make_jaxpr(fwd)(params, pages))
-        assert ("pallas_call" in jaxpr) == (force == "1")
-        return np.asarray(jax.jit(fwd)(params, pages),
-                          dtype=np.float32)
-
-    a = step("1")
-    b = step("0")
-    np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
 
 def test_paged_append_mid_page_span():
@@ -194,8 +163,8 @@ def test_append_then_block_gather_matches_plain_cache(kv_dtype):
     puts them, in the page-major pool and (int8) its page-major
     scales."""
     from ray_tpu.models.kv_cache import init_kv_pool, kv_layer_view
-    from ray_tpu.models.llama import LlamaConfig, _paged_window_attention
-    from ray_tpu.ops.paged_attention import dequantize_pages, paged_append
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.ops.paged_attention import paged_append
     rng = np.random.default_rng(11)
     B, KH, H, D, Pg, n_pages, max_pages = 2, 2, 4, 8, 4, 16, 4
     cfg = LlamaConfig(dim=H * D, n_heads=H, n_kv_heads=KH, n_layers=1,

@@ -18,6 +18,7 @@ import pytest
 from ray_tpu.models import llama as llama_mod
 from ray_tpu.models.kv_cache import init_kv_pool
 from ray_tpu.models.llama import Llama, llama_tiny
+from ray_tpu.ops import paged_attention as paged_mod
 from ray_tpu.serve import engine as engine_mod
 from ray_tpu.serve.engine import LLMEngine
 
@@ -44,7 +45,7 @@ def block_tokens(monkeypatch):
         engine_mod._jit_prefill.cache_clear()
 
     def set_(n):
-        monkeypatch.setattr(llama_mod, "_WINDOW_BLOCK_TOKENS", n)
+        monkeypatch.setattr(paged_mod, "_WINDOW_BLOCK_TOKENS", n)
         clear()
     yield set_
     clear()
@@ -179,7 +180,7 @@ def test_trip_count_follows_live_rows_only(block_tokens):
 
     def trips(pt, pos):
         def hi(pt, pos):
-            llama_mod._paged_window_attention(q, pk, pk, None, None,
+            paged_mod._paged_window_attention(q, pk, pk, None, None,
                                               pt, pos)
             return seen[-1]
 

@@ -552,7 +552,7 @@ def test_stream_disconnect_cancels_engine_request():
     to completion."""
     from ray_tpu.serve.llm import LlamaDeployment
     dep = LlamaDeployment(max_new_tokens=64, max_slots=4,
-                          page_size=8, use_engine=True)
+                          page_size=8)
     gen = dep.stream([3, 1, 4])
     next(gen)                        # stream established
     gen.close()                      # client disconnect

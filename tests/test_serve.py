@@ -747,7 +747,7 @@ def test_llm_deployment_serves_mixtral(serve_rt):
     class MoELLM(LlamaDeployment):
         def __init__(self):
             super().__init__(config=mixtral_tiny(), max_new_tokens=6,
-                             stream_chunk=3)
+                             decode_chunk=3)
 
     h = serve.run(MoELLM.bind(), timeout_s=300)
     prompt = list(range(1, 9))

@@ -1109,8 +1109,8 @@ def run_chaos(seed=47, replicas=3, duration_s=3.0, clients=3,
     # derives its references from a same-knobs reference ENGINE
     # instead — the quantized write history is what replicas
     # reproduce bit-for-bit, not the dense fp math.
-    from ray_tpu.util.envknobs import resolve_kv_dtype
-    kv_dtype = resolve_kv_dtype(kv_dtype)
+    from ray_tpu.models.kv_cache import check_kv_dtype
+    kv_dtype = check_kv_dtype(kv_dtype)
     shared = [3, 1, 4, 1, 5, 9, 2, 6]
     prompts = [shared + [10 + i, 20 + i] for i in range(8)]
     if kv_dtype == "int8":

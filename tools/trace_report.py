@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Turn a SERVE_TRACE artifact (serve_bench.py --trace) into a
+"""Turn a SERVE_TRACE artifact (the committed SERVE_TRACE_*.json) into a
 per-request phase breakdown and a p50/p99 critical-path table.
 
 The artifact carries three views of the same run (serve/obs.py):
@@ -59,8 +59,8 @@ def _events_by_rid(events: List[Dict[str, Any]]
 
 def report(artifact: Dict[str, Any]) -> Dict[str, Any]:
     """Phase breakdown + percentiles + the TTFT cross-check.
-    Pure function over the artifact dict (serve_bench calls it
-    in-process; ``main`` feeds it a loaded file)."""
+    Pure function over the artifact dict (``main`` feeds it a loaded
+    file)."""
     requests: Dict[str, Any] = artifact.get("requests", {})
     events: List[Dict[str, Any]] = artifact.get("events", [])
     by_rid = _events_by_rid(events)
