@@ -24,20 +24,22 @@ TPU/XLA design:
   syncs exactly when a scheduling decision is possible — host round
   trips never gate the token rate.
   Join/leave granularity under load is ``chunk`` tokens.
-- Prefill is CHUNKED and interleaved with decode: prompts advance by
-  at most ``prefill_chunk`` tokens per scheduling round (a shared
-  per-round token budget packed across up to ``_max_prefill_batch``
-  mid-prefill slots), and every round dispatches the prefill chunk
-  immediately followed by a short decode chunk, so in-flight decode
-  never stalls for a whole prompt the way monolithic padded-batch
-  prefill stalls it. Admission only needs pages for the FIRST chunk
-  (chunk-budget admission), later chunks grow pages like decode
-  does. A request's first token is sampled by the chunk that
-  consumes the END of its prompt and is emitted to the stream right
-  then — TTFT is one prompt-prefill, not prompt-prefill plus a
-  decode-chunk drain. The round planner itself is pure and
-  device-free (serve/scheduler.py) so CPU tests drive it
-  deterministically.
+- Prefill is CHUNKED and interleaved with decode: each prompt
+  advances by at most ``prefill_chunk`` tokens per scheduling round,
+  in a row of its own of ONE prefill call that is
+  ``_max_prefill_batch`` rows wide whatever rides in it — so a round
+  grants up to ``_max_prefill_batch`` mid-prefill slots a chunk each
+  (the round's budget is rows x chunk: what the call costs), and
+  every round dispatches the prefill chunk immediately followed by a
+  short decode chunk, so in-flight decode never stalls for a whole
+  prompt the way monolithic padded-batch prefill stalls it.
+  Admission only needs pages for the FIRST chunk (chunk-budget
+  admission), later chunks grow pages like decode does. A request's
+  first token is sampled by the chunk that consumes the END of its
+  prompt and is emitted to the stream right then — TTFT is one
+  prompt-prefill, not prompt-prefill plus a decode-chunk drain. The
+  round planner itself is pure and device-free (serve/scheduler.py)
+  so CPU tests drive it deterministically.
 - Preemption is recompute-based: when the pool runs dry the youngest
   slot is evicted, its pages freed, and the request requeued with
   prompt = original prompt + tokens generated so far, so clients see
@@ -422,7 +424,7 @@ def _new_round_info() -> Dict[str, int]:
     return {"decode_riders": 0, "decode_steps": 0,
             "decode_window_tokens": 0,
             "prefill_tokens": 0, "prefill_budget": 0,
-            "prefill_window_tokens": 0}
+            "prefill_rows": 0, "prefill_window_tokens": 0}
 
 
 class LLMEngine:
@@ -435,13 +437,17 @@ class LLMEngine:
     page_size: tokens per KV page.
     n_pages: physical pages in the pool (page 0 reserved as null).
     chunk: decode steps per device dispatch (host-sync amortization).
-    prefill_chunk: prompt-token budget per scheduling round, shared
-        across the mid-prefill slots scheduled that round. Prompts
-        longer than this prefill over several rounds with decode
-        chunks interleaved between them, so a long arrival cannot
-        stall in-flight streams; smaller values tighten decode
-        latency under prefill load, larger values finish prompts
-        (and thus first tokens) in fewer rounds.
+    prefill_chunk: prompt tokens ONE slot may prefill per scheduling
+        round: the width of a row of the round's prefill call. The
+        call is four rows wide whatever rides in it, so a round
+        grants up to four mid-prefill slots a chunk each (a budget
+        of 4 x prefill_chunk tokens, at the one call's price).
+        Prompts longer than this prefill over several rounds, in
+        one row, with decode chunks interleaved between them, so a
+        long arrival stalls neither in-flight streams nor the
+        prompts behind it; smaller values tighten decode latency
+        under prefill load, larger values finish prompts (and thus
+        first tokens) in fewer rounds.
     prefix_cache: share KV pages of identical page-aligned prompt
         prefixes across requests (radix tree + refcounts + LRU
         eviction, serve/prefix_cache.py). Repeated system-prompt /
@@ -747,8 +753,8 @@ class LLMEngine:
         self._injector = fault_injector
         self._round = 0              # scheduling-round counter (the
                                      # fault seam's deterministic clock)
-        # mid-prefill slots share each round's token budget up to
-        # this batch width (one jitted call, fixed row count)
+        # rows of the round's prefill call (one jitted call, fixed
+        # row count): each carries one mid-prefill slot's chunk
         self._max_prefill_batch = 4
         # Chunked prefill: ONE jitted function, which jit specializes
         # per pow2 chunk bucket (floor page_size, cap prefill_chunk) —
@@ -1817,7 +1823,7 @@ class LLMEngine:
     def _plan_steps_locked(self) -> StepPlan:
         """Plan this round with the pure, device-free planner
         (serve/scheduler.py plan_step): which mid-prefill slots
-        advance under the shared ``prefill_chunk`` token budget, and
+        advance, a row of up to ``prefill_chunk`` tokens each, and
         how many decode steps ride behind them. Run-ahead-to-next-
         completion, quick cadence while admission work is pending,
         and the eos bound all live in the planner — this wrapper only
@@ -1857,14 +1863,18 @@ class LLMEngine:
         # so the pool can re-role a replica between requests.
         caps = role_plan_caps(self.role, page_size=self.Pg,
                               decode_chunk=self.K,
-                              prefill_budget=self.PC,
+                              prefill_chunk=self.PC,
+                              prefill_batch=self._max_prefill_batch,
                               max_run_ahead=self.KMAX)
-        self._round_info["prefill_budget"] = caps["prefill_budget"]
+        # what the round's prefill call could carry: its rows times
+        # a row's chunk
+        self._round_info["prefill_budget"] = (
+            caps["prefill_batch"] * caps["prefill_chunk"])
         return plan_step(views, total_slots=self.S,
-                         prefill_budget=caps["prefill_budget"],
+                         prefill_chunk=caps["prefill_chunk"],
                          decode_chunk=self.K,
                          max_run_ahead=caps["max_run_ahead"],
-                         prefill_batch=self._max_prefill_batch,
+                         prefill_batch=caps["prefill_batch"],
                          eos_bounded=self.eos_id is not None,
                          spec_enabled=bool(self.spec_len))
 
@@ -2066,8 +2076,8 @@ class LLMEngine:
         cached page-aligned prefix: the slot's page table points at
         those shared pages read-only, prefill RESUMES at the matched
         offset (the existing mid-offset chunked-prefill path), and
-        the round's prefill budget only ever pays for the tokens
-        actually computed — skipped tokens never enter
+        the slot's row of the prefill call only ever pays for the
+        tokens actually computed — skipped tokens never enter
         ``prompt_remaining``. A fully-cached prompt copies its final
         matched page into a private page (COW: the model still needs
         the last position's logits to sample the first token, and
@@ -3091,6 +3101,8 @@ class LLMEngine:
             rid=tuple(slot.req.rid for _ix, slot, _t in rows),
             data=tuple((ix, take) for ix, _s, take in rows))
         self.stats["prefills"] += 1
+        self.stats["prefill_rows"] += len(rows)
+        self._round_info["prefill_rows"] += len(rows)
         _granted = sum(take for _ix, _s, take in rows)
         self.stats["prefill_tokens"] += _granted
         self._round_info["prefill_tokens"] += _granted

@@ -3,11 +3,11 @@
 One scheduling round of the continuous-batching engine
 (serve/engine.py) is planned here, device-free: given a host-side
 snapshot of the slots, decide (a) which mid-prefill slots advance and
-by how many prompt tokens, under a shared per-round token budget
-(``prefill_budget``, the ``prefill_chunk`` knob), and (b) how many
-decode steps to dispatch in the SAME round. The engine dispatches the
-prefill chunk first and the decode chunk immediately behind it, both
-asynchronously, so the device pipeline interleaves
+by how many prompt tokens — one row of the prefill program each, up
+to ``prefill_chunk`` tokens a row, up to ``prefill_batch`` rows — and
+(b) how many decode steps to dispatch in the SAME round. The engine
+dispatches the prefill chunk first and the decode chunk immediately
+behind it, both asynchronously, so the device pipeline interleaves
 ``P D P D P D ...`` — decode never stalls for a whole prompt the way
 monolithic padded-batch prefill stalls it (the r05 161ms-TTFT /
 1.63x-throughput shape this module exists to fix).
@@ -23,14 +23,19 @@ Policy, in order:
 - Prefill grants: mid-prefill slots in lane-then-admission order
   (online lane first, FIFO within each lane — admission never
   reorders within a lane, so neither does prefill) each receive
-  ``min(prompt_remaining, budget_left)`` tokens until the round's
-  token budget or the prefill batch width runs out. A long prompt
-  takes the whole budget for several rounds; several short prompts
-  pack into one round. ``prompt_remaining`` is net of any tokens the
-  prefix cache (serve/prefix_cache.py) satisfied at admission — a
-  cache-hit slot enters mid-prompt, so the round's budget only ever
-  pays for tokens actually computed; skipped prefix tokens never
-  consume it.
+  ``min(prompt_remaining, prefill_chunk)`` tokens until the prefill
+  program's rows (``prefill_batch``) run out. The unit of the budget
+  is what the device is charged for: the prefill call is
+  ``[prefill_batch, T]`` wide whatever rides in it, so a round's
+  budget is ``prefill_batch x prefill_chunk`` tokens and every row
+  the program computes carries a prompt when one waits. A long
+  prompt holds ONE row for ``ceil(len / prefill_chunk)`` rounds and
+  the prompts behind it proceed in the other rows; with one slot
+  mid-prefill the round grants it one chunk, as a single shared
+  chunk would. ``prompt_remaining`` is net of any tokens the prefix
+  cache (serve/prefix_cache.py) satisfied at admission — a
+  cache-hit slot enters mid-prompt, so its row only ever pays for
+  tokens actually computed.
 - Decode steps: if any seeded slot exists, decode rides every round.
   While admission work is pending (a free slot, an unseeded slot, a
   prefill grant this round) the cadence stays at ``decode_chunk`` so
@@ -46,7 +51,7 @@ Policy, in order:
 - Priority lanes (``SlotView.batch``, serve/batch_tier.py): offline
   batch slots share the round with online traffic but never crowd it.
   Prefill grants order ONLINE slots first (FIFO within the lane),
-  batch slots take whatever budget is left — a deep batch backlog can
+  batch slots take whatever rows are left — a deep batch backlog can
   never delay an online prompt's next chunk by more than the chunk
   already in flight. Decode is lane-blind by design: a seeded batch
   slot rides the same dispatch as everyone else (evicting it saves
@@ -100,8 +105,8 @@ ROLE_UNIFIED = "unified"
 REPLICA_ROLES = frozenset({ROLE_PREFILL, ROLE_DECODE, ROLE_UNIFIED})
 
 
-def role_plan_caps(role, *, page_size, decode_chunk, prefill_budget,
-                   max_run_ahead):
+def role_plan_caps(role, *, page_size, decode_chunk, prefill_chunk,
+                   prefill_batch, max_run_ahead):
     """Role-adjusted planner knobs, pure data in -> data out.
 
     - ``prefill``: refuses decode-phase growth. Run-ahead is clamped
@@ -110,13 +115,13 @@ def role_plan_caps(role, *, page_size, decode_chunk, prefill_budget,
       single bridging token each handoff needs, and anything longer
       only delays the next waiting prompt (exactly the interference
       disaggregation exists to remove).
-    - ``decode``: skips the prefill lane. The per-round prefill
-      budget collapses to one page plus one token — enough to absorb
-      a handoff's residual tail (``len(prompt) mod page_size`` plus
-      the bridging token always fits one round) and to crawl through
-      a full plain prefill when a fallback or chaos resubmit lands
-      here (correct, just slow — a hard refusal would strand exactly
-      the recovery paths that must keep working).
+    - ``decode``: skips the prefill lane. The round's prefill lane
+      collapses to ONE row of one page plus one token — enough to
+      absorb a handoff's residual tail (``len(prompt) mod page_size``
+      plus the bridging token always fits one round) and to crawl
+      through a full plain prefill when a fallback or chaos resubmit
+      lands here (correct, just slow — a hard refusal would strand
+      exactly the recovery paths that must keep working).
     - ``unified``: knobs pass through untouched.
 
     Unknown roles raise: a typo'd role silently planning as unified
@@ -126,19 +131,25 @@ def role_plan_caps(role, *, page_size, decode_chunk, prefill_budget,
         raise ValueError(
             f"unknown replica role {role!r}; expected one of "
             f"{sorted(REPLICA_ROLES)}")
-    caps = {"prefill_budget": prefill_budget,
+    caps = {"prefill_chunk": prefill_chunk,
+            "prefill_batch": prefill_batch,
             "max_run_ahead": max_run_ahead}
     if role == ROLE_PREFILL:
         caps["max_run_ahead"] = max(1, min(max_run_ahead,
                                            decode_chunk))
     elif role == ROLE_DECODE:
-        caps["prefill_budget"] = max(1, min(prefill_budget,
-                                            page_size + 1))
+        caps["prefill_chunk"] = max(1, min(prefill_chunk,
+                                           page_size + 1))
+        caps["prefill_batch"] = 1
     return caps
 
 # Named knob presets for the two serving regimes. Pure data (the
 # import guard above applies): the engine/deployment layer maps these
 # onto its constructor knobs; the planner itself reads nothing here.
+# ``prefill_chunk`` is prompt tokens a ROW a round: a round's prefill
+# call carries up to ``prefill_batch`` rows of it (the engine's program
+# is four rows wide) and costs the same whether one row or all of them
+# hold a prompt, so a round may grant 4 x ``prefill_chunk`` tokens.
 #
 # - ``latency``: the defaults the online path has always run —
 #   short decode cadence, bounded admission queue, moderate prefill
@@ -245,7 +256,7 @@ class StepPlan:
 
 
 def plan_step(slots: Sequence[SlotView], *, total_slots: int,
-              prefill_budget: int, decode_chunk: int,
+              prefill_chunk: int, decode_chunk: int,
               max_run_ahead: int, prefill_batch: int,
               eos_bounded: bool,
               spec_enabled: bool = False) -> StepPlan:
@@ -253,30 +264,31 @@ def plan_step(slots: Sequence[SlotView], *, total_slots: int,
     engine state — everything it needs is in the arguments.
 
     slots: occupied slots only (free slots are ``total_slots`` minus
-    ``len(slots)``). Returns the prefill grants (FIFO, budget-packed)
-    and either the decode step count (0 = no decode dispatch) or, when
+    ``len(slots)``). Returns the prefill grants (lane order then
+    FIFO, one row of up to ``prefill_chunk`` tokens each, at most
+    ``prefill_batch`` rows) and either the decode step count (0 = no
+    decode dispatch) or, when
     ``spec_enabled`` and any seeded slot proposed drafts, the spec
     grants for one batched verify dispatch (decode_steps is then 0 —
     the lanes are exclusive per round).
     """
-    if prefill_budget < 1:
-        raise ValueError("prefill_budget must be >= 1")
+    if prefill_chunk < 1:
+        raise ValueError("prefill_chunk must be >= 1")
     if decode_chunk < 1:
         raise ValueError("decode_chunk must be >= 1")
 
-    grants = []
-    budget = prefill_budget
     # Lane-ordered prefill: every online slot (FIFO) ahead of every
     # batch slot (FIFO) — a deep batch backlog mid-prefill must never
-    # consume the budget an online prompt's next chunk needs. bool
-    # sorts False < True, so (batch, admit_seq) is exactly that order.
-    for v in sorted((v for v in slots if v.prefilling),
-                    key=lambda v: (v.batch, v.admit_seq)):
-        if budget <= 0 or len(grants) >= prefill_batch:
-            break
-        take = min(v.prompt_remaining, budget)
-        grants.append(PrefillGrant(v.sid, take))
-        budget -= take
+    # take the row an online prompt's next chunk needs. bool sorts
+    # False < True, so (batch, admit_seq) is exactly that order. Each
+    # grant is one row of the prefill call, which computes all
+    # ``prefill_batch`` rows whatever they hold: there is no token cap
+    # shared between rows.
+    waiting = sorted((v for v in slots if v.prefilling),
+                     key=lambda v: (v.batch, v.admit_seq))
+    grants = [PrefillGrant(v.sid,
+                           min(v.prompt_remaining, prefill_chunk))
+              for v in waiting[:prefill_batch]]
 
     seeded = sorted((v for v in slots if v.seeded),
                     key=lambda v: v.admit_seq)

@@ -45,38 +45,49 @@ from ray_tpu.serve.errors import EngineShutdown
 
 def test_role_plan_caps_prefill_clamps_run_ahead():
     caps = role_plan_caps(ROLE_PREFILL, page_size=16, decode_chunk=4,
-                          prefill_budget=512, max_run_ahead=256)
-    assert caps == {"prefill_budget": 512, "max_run_ahead": 4}
+                          prefill_chunk=512, prefill_batch=4,
+                          max_run_ahead=256)
+    assert caps == {"prefill_chunk": 512, "prefill_batch": 4,
+                    "max_run_ahead": 4}
 
 
 def test_role_plan_caps_decode_collapses_prefill_budget():
     # page_size + 1: one residual page plus the bridging token — the
     # largest tail a handoff can leave unpulled
+    # ... and ONE row of it: the lane is no larger than the 17 tokens
+    # a round it was when the rows shared one budget
     caps = role_plan_caps(ROLE_DECODE, page_size=16, decode_chunk=4,
-                          prefill_budget=512, max_run_ahead=256)
-    assert caps == {"prefill_budget": 17, "max_run_ahead": 256}
+                          prefill_chunk=512, prefill_batch=4,
+                          max_run_ahead=256)
+    assert caps == {"prefill_chunk": 17, "prefill_batch": 1,
+                    "max_run_ahead": 256}
 
 
 def test_role_plan_caps_unified_passthrough():
     caps = role_plan_caps(ROLE_UNIFIED, page_size=16, decode_chunk=4,
-                          prefill_budget=512, max_run_ahead=256)
-    assert caps == {"prefill_budget": 512, "max_run_ahead": 256}
+                          prefill_chunk=512, prefill_batch=4,
+                          max_run_ahead=256)
+    assert caps == {"prefill_chunk": 512, "prefill_batch": 4,
+                    "max_run_ahead": 256}
 
 
 def test_role_plan_caps_floors_never_zero():
     # degenerate knobs still leave one unit of budget on each side
     caps = role_plan_caps(ROLE_PREFILL, page_size=1, decode_chunk=0,
-                          prefill_budget=1, max_run_ahead=8)
+                          prefill_chunk=1, prefill_batch=4,
+                          max_run_ahead=8)
     assert caps["max_run_ahead"] == 1
     caps = role_plan_caps(ROLE_DECODE, page_size=0, decode_chunk=4,
-                          prefill_budget=0, max_run_ahead=8)
-    assert caps["prefill_budget"] == 1
+                          prefill_chunk=0, prefill_batch=4,
+                          max_run_ahead=8)
+    assert caps["prefill_chunk"] == 1 and caps["prefill_batch"] == 1
 
 
 def test_role_plan_caps_unknown_role_raises():
     with pytest.raises(ValueError, match="unknown replica role"):
         role_plan_caps("prefil", page_size=16, decode_chunk=4,
-                       prefill_budget=512, max_run_ahead=256)
+                       prefill_chunk=512, prefill_batch=4,
+                       max_run_ahead=256)
 
 
 # ------------------------------------------------- pull-knob typing

@@ -8,6 +8,7 @@ a program another file already compiled would not count as built.
 """
 import glob
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -66,12 +67,13 @@ def rounds():
 
 NEW_KEYS = ("round", "admit_s", "plan_s", "dispatch_s", "readback_s",
             "decode_riders", "decode_steps", "prefill_tokens",
-            "prefill_budget")
+            "prefill_budget", "prefill_rows")
 
 
 @pytest.mark.parametrize("case", ["keys", "phases_within_wall",
                                   "numbered", "prefill_agrees",
-                                  "decode_agrees", "budget"])
+                                  "rows_agree", "decode_agrees",
+                                  "budget"])
 def test_round_event_says_what_the_round_was(rounds, case):
     if case == "keys":
         for data, _ in rounds:
@@ -92,6 +94,14 @@ def test_round_event_says_what_the_round_was(rounds, case):
                           for _sid, take in e[5])
             assert data["prefill_tokens"] == granted
         assert any(data["prefill_tokens"] for data, _ in rounds)
+    elif case == "rows_agree":
+        for data, since in rounds:
+            calls = [e[5] for e in since if e[2] == "prefill"]
+            assert data["prefill_rows"] == sum(len(c) for c in calls)
+            assert data["prefill_rows"] <= 4
+        # five requests submitted at once on four slots: some call
+        # carried more than one row
+        assert any(data["prefill_rows"] > 1 for data, _ in rounds)
     elif case == "decode_agrees":
         for data, since in rounds:
             steps = [e[5] for e in since if e[2] == "decode"]
@@ -100,9 +110,46 @@ def test_round_event_says_what_the_round_was(rounds, case):
             assert data["decode_riders"] <= 4
         assert any(data["decode_riders"] > 1 for data, _ in rounds)
     else:
+        # the call's four rows x prefill_chunk: what it could carry
         for data, _ in rounds:
-            assert data["prefill_budget"] == 16
+            assert data["prefill_budget"] == 4 * 16
             assert data["prefill_tokens"] <= data["prefill_budget"]
+
+
+@pytest.mark.parametrize("case", ["hand_made", "engine_log",
+                                  "parent_has_no_key"])
+def test_prefill_rows_mean_reads_the_round_event(rounds, case):
+    """benchmarks/metrics/prefill_rows_mean.py: rows a prefill call
+    carried, over the window's rounds that dispatched one; None (and
+    no error) on a program whose events lack the key."""
+    from benchmarks import common
+    read = common.load_metric_reader("prefill_rows_mean")
+
+    def run(datas, window=(0.5, 8.0)):
+        events = [(0, float(t), "round", None, None, d)
+                  for t, d in enumerate(datas, 1)]
+        return types.SimpleNamespace(kind="serve", window=window,
+                                     events=events)
+    if case == "hand_made":
+        got = read(run([{"prefill_rows": 4}, {"prefill_rows": 1},
+                        {"prefill_rows": 0},           # no prefill
+                        {"prefill_rows": 4}, {}, {}, {}, {},
+                        {"prefill_rows": 2}]))         # outside
+        assert got == pytest.approx(3.0)
+        assert read(run([{"prefill_rows": 0}])) is None
+    elif case == "engine_log":
+        datas = [data for data, _ in rounds]
+        calls = [d["prefill_rows"] for d in datas if d["prefill_rows"]]
+        got = read(run(datas, window=(0.0, len(datas) + 1.0)))
+        assert got == pytest.approx(sum(calls) / len(calls))
+        assert 1.0 < got <= 4.0
+    else:
+        old = [{k: v for k, v in data.items() if k != "prefill_rows"}
+               for data, _ in rounds]
+        assert read(run(old, window=(0.0, len(old) + 1.0))) is None
+        train = run([{"prefill_rows": 4}])
+        train.kind = "train"
+        assert read(train) is None
 
 
 # -------------------------------------------------- the compile counter

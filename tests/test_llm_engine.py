@@ -455,30 +455,170 @@ def test_decode_interleaved_between_prefill_chunks(tiny_model):
             trace[a:b + 1]
 
 
+def _round_events(eng):
+    return [e[5] for e in eng.events.snapshot() if e[2] == "round"]
+
+
+def test_four_waiting_prompts_share_one_prefill_call(tiny_model):
+    """Four one-chunk prompts waiting in slots: ONE prefill dispatch
+    advances all four rows (the call computes four rows whatever they
+    hold), the round reports rows, tokens and the real budget, and
+    every request's tokens are ``generate``'s."""
+    model, params = tiny_model
+    eng = LLMEngine(model, params, max_slots=4, page_size=8,
+                    n_pages=64, chunk=4, prefill_chunk=16)
+    prompts = [list(range(1 + i, 17 + i)) for i in range(4)]  # 16 each
+    wants = [_reference_completion(model, params, p, 6)
+             for p in prompts]
+    hs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+    eng.step()
+    assert eng.stats["prefills"] == 1
+    assert eng.stats["prefill_rows"] == 4
+    assert eng.stats["prefill_tokens"] == 4 * 16
+    assert eng.stats["prefilled_seqs"] == 4
+    (first,) = _round_events(eng)
+    assert first["prefill_rows"] == 4
+    assert first["prefill_tokens"] == first["prefill_budget"] == 4 * 16
+    while eng.step():
+        pass
+    assert [h.result() for h in hs] == wants
+    assert eng.stats["prefills"] == 1      # nothing left to prefill
+    later = _round_events(eng)[1:]
+    assert later and all(r["prefill_rows"] == 0 for r in later)
+
+
+def test_long_prompt_prefills_beside_the_short_ones(tiny_model):
+    """A 3-chunk prompt admitted FIRST holds one row for three rounds;
+    the two short prompts behind it are seeded by the first call, not
+    after the long one finishes. Tokens stay exact for all three."""
+    model, params = tiny_model
+    eng = LLMEngine(model, params, max_slots=4, page_size=8,
+                    n_pages=64, chunk=2, prefill_chunk=8)
+    prompts = [list(range(1, 25)), [7, 3, 9], [4, 4, 2, 11, 6]]
+    wants = [_reference_completion(model, params, p, 8)
+             for p in prompts]
+    hs = [eng.submit(p, max_new_tokens=8) for p in prompts]
+    eng.step()
+    assert eng.stats["prefills"] == 1
+    assert eng.stats["prefill_rows"] == 3
+    assert eng.stats["prefill_tokens"] == 8 + 3 + 5
+    assert eng.stats["prefilled_seqs"] == 2     # both short ones
+    while eng.step():
+        pass
+    assert [h.result() for h in hs] == wants
+    assert eng.stats["prefills"] == 3           # the long one's chunks
+    assert eng.stats["prefill_rows"] == 3 + 1 + 1
+
+
+def test_decode_role_prefill_lane_is_one_small_row(tiny_model):
+    """A ``decode``-role replica keeps the lane it had: one row of
+    page_size + 1 tokens a round, whatever waits in its slots (a plain
+    prefill that lands here crawls, and stays exact)."""
+    model, params = tiny_model
+    eng = LLMEngine(model, params, max_slots=4, page_size=8,
+                    n_pages=64, chunk=2, prefill_chunk=16,
+                    role="decode")
+    prompts = [list(range(1, 21)), [5, 9, 2]]
+    wants = [_reference_completion(model, params, p, 4)
+             for p in prompts]
+    hs = [eng.submit(p, max_new_tokens=4) for p in prompts]
+    while eng.step():
+        pass
+    assert [h.result() for h in hs] == wants
+    rounds = _round_events(eng)
+    assert all(r["prefill_budget"] == 9 for r in rounds)
+    assert all(r["prefill_rows"] <= 1 for r in rounds)
+    assert all(r["prefill_tokens"] <= 9 for r in rounds)
+    # 20 tokens at 9 a round = 3 calls, then the short prompt's one
+    assert eng.stats["prefills"] == 4 == eng.stats["prefill_rows"]
+
+
 # ------------------------------------------------------- pure planner
 
 
-_PLAN = dict(total_slots=4, prefill_budget=16, decode_chunk=4,
+_PLAN = dict(total_slots=4, prefill_chunk=16, decode_chunk=4,
              max_run_ahead=128, prefill_batch=4, eos_bounded=False)
 
 
-def test_planner_long_prompt_takes_whole_budget():
-    views = [SlotView(sid=0, admit_seq=0, prompt_remaining=100,
-                      owed=0, seeded=False),
-             SlotView(sid=1, admit_seq=1, prompt_remaining=3,
-                      owed=0, seeded=False)]
-    plan = plan_step(views, **_PLAN)
-    assert plan.prefill == (PrefillGrant(0, 16),)   # FIFO, all budget
+def _waiting(remaining, **kw):
+    """Unseeded mid-prefill views, admitted in list order."""
+    return [SlotView(sid=i, admit_seq=i, prompt_remaining=n, owed=0,
+                     seeded=False, **kw)
+            for i, n in enumerate(remaining)]
+
+
+def test_planner_long_prompt_takes_one_row():
+    """A long prompt at the head of the lane holds ONE row of one
+    chunk; the short prompt behind it rides the same call."""
+    plan = plan_step(_waiting([100, 3]), **_PLAN)
+    assert plan.prefill == (PrefillGrant(0, 16), PrefillGrant(1, 3))
     assert plan.decode_steps == 0                   # nothing seeded
 
 
-def test_planner_packs_short_prompts_into_one_round():
-    views = [SlotView(sid=i, admit_seq=i, prompt_remaining=n,
-                      owed=0, seeded=False)
-             for i, n in enumerate([5, 6, 9])]
-    plan = plan_step(views, **_PLAN)
+def test_planner_short_prompts_each_take_a_row():
+    """No token cap is shared between rows: the third prompt gets all
+    9 of its tokens (5 + 6 + 9 = 20 > one chunk of 16)."""
+    plan = plan_step(_waiting([5, 6, 9]), **_PLAN)
     assert plan.prefill == (PrefillGrant(0, 5), PrefillGrant(1, 6),
-                            PrefillGrant(2, 5))     # 16-token budget
+                            PrefillGrant(2, 9))
+
+
+def test_planner_four_one_chunk_prompts_in_one_round():
+    """The saturated cell's round: four prompts of exactly one chunk
+    are all granted whole, at once — the budget is rows x chunk."""
+    plan = plan_step(_waiting([16, 16, 16, 16, 16]),
+                     **dict(_PLAN, total_slots=8))
+    assert plan.prefill == tuple(PrefillGrant(i, 16) for i in range(4))
+
+
+def test_planner_long_head_does_not_delay_the_rows_behind_it():
+    """Round by round: three short prompts behind a 5-chunk prompt all
+    finish in the FIRST round, and the long one advances a chunk a
+    round, as it would alone."""
+    remaining = [80, 7, 16, 2]
+    rounds = []
+    while any(remaining):
+        plan = plan_step(_waiting(remaining), **_PLAN)
+        rounds.append({g.sid: g.tokens for g in plan.prefill})
+        for g in plan.prefill:
+            remaining[g.sid] -= g.tokens
+    assert rounds[0] == {0: 16, 1: 7, 2: 16, 3: 2}
+    assert rounds[1:] == [{0: 16}] * 4
+
+
+@pytest.mark.parametrize("remaining", [1, 15, 16, 17, 100])
+def test_planner_one_mid_prefill_slot_plans_as_one_shared_chunk(
+        remaining):
+    """With ONE slot mid-prefill the plan is what a single shared
+    chunk granted: min(remaining, chunk), beside the same decode."""
+    views = [SlotView(sid=0, admit_seq=0, prompt_remaining=0,
+                      owed=50, seeded=True),
+             SlotView(sid=1, admit_seq=1, prompt_remaining=remaining,
+                      owed=0, seeded=False)]
+    plan = plan_step(views, **_PLAN)
+    assert plan.prefill == (PrefillGrant(1, min(remaining, 16)),)
+    assert plan.decode_steps == 4
+
+
+def test_planner_online_rows_before_batch_rows():
+    """More than four slots wait: every online slot takes a row before
+    any batch slot, FIFO within each lane, whatever the admission
+    order between the lanes."""
+    views = [SlotView(sid=i, admit_seq=i, prompt_remaining=40, owed=0,
+                      seeded=False, batch=b)
+             for i, b in enumerate([True, True, False, True, False,
+                                    False])]
+    plan = plan_step(views, **dict(_PLAN, total_slots=8))
+    assert [g.sid for g in plan.prefill] == [2, 4, 5, 0]
+    assert all(g.tokens == 16 for g in plan.prefill)
+
+
+def test_planner_pulling_slot_takes_no_row():
+    views = _waiting([16, 16]) + [
+        SlotView(sid=2, admit_seq=2, prompt_remaining=16, owed=0,
+                 seeded=False, pulling=True)]
+    plan = plan_step(views, **_PLAN)
+    assert [g.sid for g in plan.prefill] == [0, 1]
 
 
 def test_planner_decode_rides_behind_prefill():
@@ -514,7 +654,7 @@ def test_planner_prefill_batch_width_cap():
 
 def test_planner_validates_budgets():
     with pytest.raises(ValueError):
-        plan_step([], **dict(_PLAN, prefill_budget=0))
+        plan_step([], **dict(_PLAN, prefill_chunk=0))
     with pytest.raises(ValueError):
         plan_step([], **dict(_PLAN, decode_chunk=0))
     assert plan_step([], **_PLAN).idle

@@ -103,7 +103,7 @@ def test_overlap_default_on_and_kwarg(tiny_model):
 # ----------------------------------------------------- planner stale cap
 
 
-_PLAN = dict(total_slots=2, prefill_budget=16, decode_chunk=4,
+_PLAN = dict(total_slots=2, prefill_chunk=16, decode_chunk=4,
              max_run_ahead=128, prefill_batch=4, eos_bounded=True)
 
 

@@ -61,7 +61,7 @@ slots = [mod.SlotView(sid=0, admit_seq=0, prompt_remaining=8,
                       owed=4, seeded=False),
          mod.SlotView(sid=1, admit_seq=1, prompt_remaining=0,
                       owed=4, seeded=True)]
-plan = mod.plan_step(slots, total_slots=4, prefill_budget=16,
+plan = mod.plan_step(slots, total_slots=4, prefill_chunk=16,
                      decode_chunk=4, max_run_ahead=64,
                      prefill_batch=4, eos_bounded=False)
 print(json.dumps({{"bad": bad,

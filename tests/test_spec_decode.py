@@ -87,7 +87,7 @@ def test_ngram_validates_order():
 # ------------------------------------------------------ planner spec lane
 
 
-_PLAN = dict(total_slots=4, prefill_budget=16, decode_chunk=4,
+_PLAN = dict(total_slots=4, prefill_chunk=16, decode_chunk=4,
              max_run_ahead=128, prefill_batch=4, eos_bounded=False,
              spec_enabled=True)
 
