@@ -40,10 +40,24 @@ structure that lets requests join/leave the decode batch per token):
   row in the same jitted op. Quantize/dequantize live in
   ops/paged_attention.py; nothing outside it interprets the int8
   payload.
+
+TWO KINDS OF PER-REQUEST STATE. The pool is a list of per-layer
+entries BY KIND (``layer_kinds``): a layer of softmax attention has
+pages, as above; a layer of linear attention (models/solar_open2.py's
+KDA) has none, but a fixed-size ``RecurrentState`` a decode SLOT: the
+delta rule's matrix a head in float32 and the last inputs of its
+short convolution, whatever the context's length. Pages are handed
+out by the allocator as a sequence grows; a slot's state simply
+belongs to the slot. Nothing on the host ever clears it: the layer
+itself starts a row whose write offset is 0 from zeros (as
+``paged_append`` resets an int8 page's scale at offset 0), and rows
+or positions that carry no request leave it as it was. A model
+whose layers are all of one kind declares nothing and gets the pool
+it always had.
 """
 from __future__ import annotations
 
-from typing import List, NamedTuple, Optional, Sequence
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -64,6 +78,61 @@ def check_kv_dtype(kv_dtype: Optional[str]) -> str:
             "kv_dtype=%r is not supported (choose one of %s)" %
             (kv_dtype, ", ".join(repr(d) for d in KV_DTYPES)))
     return kv_dtype
+
+
+KIND_KV = "kv"                  # a layer with K/V pages
+KIND_RECURRENT = "recurrent"    # a layer with a fixed-size state a slot
+
+
+def layer_kinds(cfg) -> Tuple[str, ...]:
+    """Each layer's kind of per-request state: the config's own
+    ``layer_kinds`` where it declares them, K/V pages everywhere
+    otherwise."""
+    return tuple(getattr(cfg, "layer_kinds", None)
+                 or (KIND_KV,) * cfg.n_layers)
+
+
+def has_recurrent_state(cfg) -> bool:
+    return KIND_RECURRENT in layer_kinds(cfg)
+
+
+class RecurrentState(NamedTuple):
+    """One recurrent layer's storage, carried between jitted steps as
+    a paged layer's ``(pages_k, pages_v)`` is: row s is decode slot
+    s's.
+
+    state: [n_slots, *cfg.recurrent_state_shape] float32
+    conv:  [n_slots, *cfg.recurrent_conv_shape] cfg.dtype
+    """
+    state: jnp.ndarray
+    conv: jnp.ndarray
+
+
+class RecurrentStateView(NamedTuple):
+    """``RecurrentState`` as a layer sees it in one call of B rows.
+
+    slots: [B] int32, the slot each row carries (a row that carries
+           none names slot ``n_slots``: it reads zeros and its write
+           is dropped), or None where row i IS slot i (a decode call).
+    valid: [B, T] bool, the real positions: a row's first ones.
+    """
+    state: jnp.ndarray
+    conv: jnp.ndarray
+    slots: Optional[jnp.ndarray]
+    valid: jnp.ndarray
+
+    def take(self, pool):
+        """The rows' entries of ``pool`` (``state`` or ``conv``)."""
+        if self.slots is None:
+            return pool
+        return pool.at[self.slots].get(mode="fill", fill_value=0)
+
+    def put(self, pool, rows):
+        """``pool`` with the rows' entries replaced."""
+        rows = rows.astype(pool.dtype)
+        if self.slots is None:
+            return rows
+        return pool.at[self.slots].set(rows, mode="drop")
 
 
 class PagedKVLayer(NamedTuple):
@@ -93,12 +162,19 @@ class PagedKVLayer(NamedTuple):
         return self.scales_k is not None
 
 
-def kv_layer_view(layer, page_table: jnp.ndarray) -> PagedKVLayer:
-    """Wrap one engine layer tuple — ``(pk, pv)`` fp or
-    ``(pk, pv, sk, sv)`` int8 — as the PagedKVLayer the attention
-    module consumes. Keeps the jitted engine builders dtype-agnostic:
-    they thread opaque tuples and only this view/store pair knows the
-    arity."""
+def kv_layer_view(layer, page_table: jnp.ndarray, slots=None,
+                  valid=None):
+    """Wrap one engine layer entry — ``(pk, pv)`` fp,
+    ``(pk, pv, sk, sv)`` int8, or a ``RecurrentState`` — as what its
+    layer consumes: a PagedKVLayer over ``page_table``, or a
+    RecurrentStateView of the rows' ``slots`` and real positions
+    (``valid``: a function giving the [B, T] mask, which only a
+    recurrent layer calls).
+    Keeps the jitted engine builders kind- and dtype-agnostic: they
+    thread opaque entries and only this view/store pair knows what
+    they are."""
+    if isinstance(layer, RecurrentState):
+        return RecurrentStateView(layer.state, layer.conv, slots, valid())
     if len(layer) == 2:
         pk, pv = layer
         return PagedKVLayer(pk, pv, page_table)
@@ -107,8 +183,11 @@ def kv_layer_view(layer, page_table: jnp.ndarray) -> PagedKVLayer:
 
 
 def kv_layer_store(cache: PagedKVLayer):
-    """Inverse of kv_layer_view: the storage tuple (without the shared
-    page table) the engine carries between jitted steps."""
+    """Inverse of kv_layer_view: the storage entry (without the
+    call's page table, slots and valid positions) the engine carries
+    between jitted steps."""
+    if isinstance(cache, RecurrentStateView):
+        return RecurrentState(cache.state, cache.conv)
     if cache.scales_k is None:
         return (cache.pages_k, cache.pages_v)
     return (cache.pages_k, cache.pages_v,
@@ -116,35 +195,45 @@ def kv_layer_store(cache: PagedKVLayer):
 
 
 def init_kv_pool(cfg, n_pages: int, page_size: int,
-                 kv_dtype: str = "fp"):
-    """One page pool per layer. Page 0 is reserved (null).
+                 kv_dtype: str = "fp", n_slots: int = 0):
+    """One entry per layer, by ``layer_kinds(cfg)``. Page 0 of a paged
+    layer is reserved (null).
 
-    fp:   [(pages_k, pages_v), ...] in cfg.dtype, each
+    fp:   (pages_k, pages_v) in cfg.dtype, each
           [n_pages, page_size, n_kv_heads, head_dim].
-    int8: [(pages_k, pages_v, scales_k, scales_v), ...] — int8 pages
+    int8: (pages_k, pages_v, scales_k, scales_v) — int8 pages
           plus fp32 per-(page, head) absmax scales initialised to 0
           (a 0 scale means "page holds nothing"; paged_append's
           reset-on-offset-0 rule keeps that true across realloc
           without any host-side scale bookkeeping).
+    recurrent: RecurrentState of ``n_slots`` rows, zeros.
     """
     shape = (n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-    if check_kv_dtype(kv_dtype) == "fp":
-        return [(jnp.zeros(shape, cfg.dtype),
-                 jnp.zeros(shape, cfg.dtype))
-                for _ in range(cfg.n_layers)]
     sshape = (n_pages, cfg.n_kv_heads)
-    return [(jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-             jnp.zeros(sshape, KV_SCALE_DTYPE),
-             jnp.zeros(sshape, KV_SCALE_DTYPE))
-            for _ in range(cfg.n_layers)]
+    quantized = check_kv_dtype(kv_dtype) == "int8"
+
+    def entry(kind):
+        if kind == KIND_RECURRENT:
+            return RecurrentState(
+                jnp.zeros((n_slots,) + tuple(cfg.recurrent_state_shape),
+                          jnp.float32),
+                jnp.zeros((n_slots,) + tuple(cfg.recurrent_conv_shape),
+                          cfg.dtype))
+        if not quantized:
+            return (jnp.zeros(shape, cfg.dtype),
+                    jnp.zeros(shape, cfg.dtype))
+        return (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
+                jnp.zeros(sshape, KV_SCALE_DTYPE),
+                jnp.zeros(sshape, KV_SCALE_DTYPE))
+    return [entry(kind) for kind in layer_kinds(cfg)]
 
 
 def kv_pool_page_bytes(cfg, page_size: int,
                        kv_dtype: str = "fp") -> int:
-    """Bytes ONE physical page costs across all layers (k+v payload
-    plus, for int8, its two fp32 scales). The allocator multiplies
-    this by occupancy for the bytes view in load/leak reports — the
-    number the capacity A/B halves."""
+    """Bytes ONE physical page costs across the layers that HAVE pages
+    (k+v payload plus, for int8, its two fp32 scales). The allocator
+    multiplies this by occupancy for the bytes view in load/leak
+    reports — the number the capacity A/B halves."""
     if kv_dtype == "int8":
         payload = 1
         scale = 2 * cfg.n_kv_heads * 4
@@ -152,12 +241,25 @@ def kv_pool_page_bytes(cfg, page_size: int,
         payload = jnp.dtype(cfg.dtype).itemsize
         scale = 0
     per_layer = 2 * cfg.n_kv_heads * page_size * cfg.head_dim * payload
-    return cfg.n_layers * (per_layer + scale)
+    return layer_kinds(cfg).count(KIND_KV) * (per_layer + scale)
+
+
+def state_bytes_per_slot(cfg) -> int:
+    """Bytes ONE decode slot's recurrent state costs across the layers
+    that keep one (0 for a model with none): the float32 state and
+    the convolution tail in cfg.dtype."""
+    n = layer_kinds(cfg).count(KIND_RECURRENT)
+    if not n:
+        return 0
+    return n * (4 * int(np.prod(cfg.recurrent_state_shape))
+                + jnp.dtype(cfg.dtype).itemsize
+                * int(np.prod(cfg.recurrent_conv_shape)))
 
 
 def export_page_bytes(layers, page: int) -> List[List[bytes]]:
-    """Raw bytes of ONE physical page across every layer — the unit a
-    cross-replica KV pull ships. Each entry is the layer's tensor
+    """Raw bytes of ONE physical page across every layer (all of them
+    paged: the engine exports nothing for a model with recurrent
+    state) — the unit a cross-replica KV pull ships. Each entry is the layer's tensor
     tuple serialized in storage order: ``[k, v]`` for fp pools,
     ``[k, v, sk, sv]`` for int8 (the per-page scales TRAVEL WITH the
     payload — a page without its scale is garbage). ``t[page]`` is
@@ -187,10 +289,11 @@ def page_cols_from_bytes(cfg, page_size: int, kv_dtype: str,
         shapes = (shape, shape)
     else:
         raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
-    if len(blobs) != cfg.n_layers:
+    n_paged = layer_kinds(cfg).count(KIND_KV)
+    if len(blobs) != n_paged:
         raise ValueError(
             f"page payload has {len(blobs)} layers, pool has "
-            f"{cfg.n_layers}")
+            f"{n_paged}")
     out = []
     for li, layer_blobs in enumerate(blobs):
         if len(layer_blobs) != len(dts):

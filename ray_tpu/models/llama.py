@@ -107,7 +107,13 @@ class RMSNorm(nn.Module):
 
 
 class LlamaAttention(nn.Module):
+    """``rope`` false: no position encoding (``freqs`` is not read).
+    ``out_gate`` true: the heads' output times sigmoid(x W_gate), one
+    gate a channel, before ``wo``. The defaults are Llama's, and leave
+    its parameter tree and programs as they were."""
     config: LlamaConfig
+    rope: bool = True
+    out_gate: bool = False
 
     @nn.compact
     def __call__(self, x, freqs, positions, kv_cache=None,
@@ -129,8 +135,9 @@ class LlamaAttention(nn.Module):
         q = q.reshape(B, T, cfg.n_heads, hd)
         k = k.reshape(B, T, cfg.n_kv_heads, hd)
         v = v.reshape(B, T, cfg.n_kv_heads, hd)
-        q = apply_rope(q, freqs, positions)
-        k = apply_rope(k, freqs, positions)
+        if self.rope:
+            q = apply_rope(q, freqs, positions)
+            k = apply_rope(k, freqs, positions)
 
         new_cache = None
         if isinstance(kv_cache, PagedKVLayer):
@@ -211,6 +218,13 @@ class LlamaAttention(nn.Module):
             y = multi_head_attention(q, k, v, causal=True,
                                      impl=cfg.attention_impl)
         y = y.reshape(B, T, cfg.n_heads * hd)
+        if self.out_gate:
+            with jax.named_scope("attn_gate"):
+                gate = nn.Dense(cfg.n_heads * hd, use_bias=False,
+                                dtype=cfg.dtype,
+                                param_dtype=cfg.param_dtype,
+                                name="w_gate")(x)
+                y = y.astype(cfg.dtype) * jax.nn.sigmoid(gate)
         out = nn.Dense(cfg.dim, use_bias=False, dtype=cfg.dtype,
                        param_dtype=cfg.param_dtype, name="wo")(y)
         return out, new_cache
@@ -239,26 +253,31 @@ class LlamaBlock(nn.Module):
                  cache_len=None):
         cfg = self.config
         return block_forward(
-            cfg, cfg, LlamaMLP(cfg, name="feed_forward"),
+            cfg, LlamaAttention(cfg, name="attention"),
+            LlamaMLP(cfg, name="feed_forward"),
             x, freqs, positions, kv_cache, cache_len)
 
 
-def transformer_forward(mod: nn.Module, cfg, block_cls, input_ids,
-                        kv_caches=None, cache_len=None):
+def transformer_forward(mod: nn.Module, cfg, block_of, input_ids,
+                        kv_caches=None, cache_len=None, rope=True):
     """Shared decoder-transformer body (embedding, RoPE table,
     position/cache plumbing, layer loop, final norm, logits through
     the embedding or, with ``tie_word_embeddings`` false, through a
     separate ``lm_head``).
     Every Llama-shaped family (Llama, Mixtral) calls this with its own
     block class, so the decode contract `generate`/`generate_stream`
-    rely on cannot drift per family. Called from a compact __call__:
-    submodules bind into the caller's scope."""
+    rely on cannot drift per family. ``block_of`` gives layer i's
+    block class: one class for every layer, or a class by the layer's
+    kind (models/solar_open2.py). ``rope`` false: no position
+    encoding, so no table (the blocks get None). Called from a compact
+    __call__: submodules bind into the caller's scope."""
     B, T = input_ids.shape
     tok = mod.param("tok_embeddings",
                     nn.initializers.normal(0.02),
                     (cfg.vocab_size, cfg.dim), cfg.param_dtype)
     x = tok[input_ids].astype(cfg.dtype)
-    freqs = rope_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
+    freqs = (rope_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
+             if rope else None)
     if cache_len is None:
         positions = jnp.arange(T)
     elif jnp.ndim(cache_len) == 1:
@@ -267,11 +286,11 @@ def transformer_forward(mod: nn.Module, cfg, block_cls, input_ids,
         positions = cache_len[:, None] + jnp.arange(T)[None]
     else:
         positions = cache_len + jnp.arange(T)
-    block = block_cls
-    if cfg.remat:
-        block = nn.remat(block_cls, static_argnums=())
     new_caches = []
     for i in range(cfg.n_layers):
+        block = block_of(i)
+        if cfg.remat:
+            block = nn.remat(block, static_argnums=())
         cache_i = None if kv_caches is None else kv_caches[i]
         x, nc = block(cfg, name=f"layers_{i}")(
             x, freqs, positions, cache_i, cache_len)
@@ -291,11 +310,13 @@ def transformer_forward(mod: nn.Module, cfg, block_cls, input_ids,
     return logits, new_caches
 
 
-def block_forward(cfg, attn_cfg, ffn_module, x, freqs, positions,
+def block_forward(cfg, attention, ffn_module, x, freqs, positions,
                   kv_cache=None, cache_len=None):
     """Shared pre-norm block body: attention residual + FFN residual.
-    The FFN module is the only thing that varies across families."""
-    h, new_cache = LlamaAttention(attn_cfg, name="attention")(
+    ``attention`` (a module named "attention": LlamaAttention, or a
+    layer that keeps a recurrent state instead of K/V) and the FFN
+    module are what varies across families and kinds of layer."""
+    h, new_cache = attention(
         RMSNorm(cfg.norm_eps, name="attention_norm")(x),
         freqs, positions, kv_cache, cache_len)
     x = x + h
@@ -310,7 +331,8 @@ class Llama(nn.Module):
     def __call__(self, input_ids, kv_caches=None, cache_len=None):
         """Returns (logits, new_kv_caches). kv_caches: list per layer of
         (k, v) arrays [B, max_seq, n_kv_heads, head_dim]."""
-        return transformer_forward(self, self.config, LlamaBlock,
+        return transformer_forward(self, self.config,
+                                   lambda i: LlamaBlock,
                                    input_ids, kv_caches, cache_len)
 
 

@@ -16,7 +16,7 @@ models/llama.py, so `generate` / `generate_stream` work unchanged."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -26,8 +26,9 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.mesh.sharding import ShardingRules
 from ray_tpu.models.kv_cache import PagedKVLayer
 from ray_tpu.ops.grouped_matmul import grouped_matmul
-from ray_tpu.models.llama import (LlamaConfig, attention_param_count,
-                                  block_forward, embedding_param_count,
+from ray_tpu.models.llama import (LlamaAttention, LlamaConfig,
+                                  attention_param_count, block_forward,
+                                  embedding_param_count,
                                   transformer_forward)
 
 
@@ -54,6 +55,12 @@ class MixtralConfig:
     norm_topk_prob: bool = True
     tie_word_embeddings: bool = True   # as LlamaConfig's
     qk_norm: bool = False              # as LlamaConfig's
+    # What MoEFeedForward reads beyond the above (its docstring says
+    # what each means); the defaults are Mixtral's and OLMoE's.
+    router: str = "softmax"            # or "sigmoid_bias"
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    experts_held: Optional[Tuple[int, int]] = None   # (lo, n) or all
 
     @property
     def head_dim(self) -> int:
@@ -118,12 +125,43 @@ def olmoe_tiny(**overrides) -> MixtralConfig:
 MOE_STATS = "moe_stats"
 
 
+ROUTERS = ("softmax", "sigmoid_bias")
+
+
+def experts_held(cfg) -> Tuple[int, int]:
+    """(lo, n): the experts whose weights this mixture holds, lo ..
+    lo + n of the router's ``num_experts``; all of them where the
+    config names no share."""
+    return cfg.experts_held or (0, cfg.num_experts)
+
+
 class MoEFeedForward(nn.Module):
     """Top-k routed SwiGLU experts, dropless (the module docstring says
     how). ``live`` [B] bool, where the caller knows it (a paged call:
     rows of free slots ride every decode call), marks the rows that
     carry a request: the others are given no expert, so they stream no
-    expert's weights, and their output is zero."""
+    expert's weights, and their output is zero.
+
+    What the config declares, each as a field:
+
+    - ``router``: ``"softmax"`` (Mixtral, OLMoE: softmax over all
+      experts' logits, the k largest, renormalised or not by
+      ``norm_topk_prob``) or ``"sigmoid_bias"`` (the DeepSeek-V3 /
+      GLM-4.5 rule: s = sigmoid(logits); the k experts with the
+      largest s + b, b a stored bias a expert that takes part in the
+      CHOICE only; gates s_chosen, divided by their sum where
+      ``norm_topk_prob``), times ``routed_scaling_factor``;
+    - ``n_shared_experts``: a SwiGLU of that many experts' width which
+      every token passes, added to the routed result;
+    - ``experts_held`` (lo, n): THE CHIP'S SHARE under expert
+      parallelism. The router keeps its published width and k; the
+      weights are [n, D, F], experts lo .. lo + n; a pair whose expert
+      is not held gets the group "none", exactly as a free slot's row
+      does (it sorts behind every group and streams nothing), and adds
+      nothing. The gates are normalised over all k chosen, held or
+      not: the shares' results add up to the whole layer's
+      (tests/test_solar_open2.py). The exchange that would bring other
+      chips' tokens here is not this module's."""
     config: MixtralConfig
 
     @nn.compact
@@ -131,6 +169,10 @@ class MoEFeedForward(nn.Module):
         cfg = self.config
         B, T, D = x.shape
         E, K = cfg.num_experts, cfg.num_experts_per_tok
+        lo, held = experts_held(cfg)
+        router = cfg.router
+        if router not in ROUTERS:
+            raise ValueError(f"router={router!r} is not one of {ROUTERS}")
         N = B * T
         tokens = x.reshape(N, D)
 
@@ -138,11 +180,20 @@ class MoEFeedForward(nn.Module):
             router_w = self.param("router", nn.initializers.normal(0.02),
                                   (D, E), jnp.float32)
             logits = tokens.astype(jnp.float32) @ router_w    # [N, E]
-            probs = jax.nn.softmax(logits, axis=-1)
-            # the k largest probabilities are the k largest logits
-            gates, topk_idx = jax.lax.top_k(probs, K)         # [N, K]
+            if router == "softmax":
+                probs = jax.nn.softmax(logits, axis=-1)
+                # the k largest probabilities are the k largest logits
+                gates, topk_idx = jax.lax.top_k(probs, K)     # [N, K]
+            else:
+                bias = self.param("router_bias", nn.initializers.zeros,
+                                  (E,), jnp.float32)
+                probs = jax.nn.sigmoid(logits)
+                _, topk_idx = jax.lax.top_k(probs + bias, K)
+                gates = jnp.take_along_axis(probs, topk_idx, axis=1)
             if cfg.norm_topk_prob:
                 gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+            if cfg.routed_scaling_factor != 1.0:
+                gates = gates * cfg.routed_scaling_factor
         if not self.is_initializing():      # no weights: not in init's tree
             self.sow(MOE_STATS, "topk", topk_idx.reshape(B, T, K),
                      reduce_fn=lambda _prev, new: new,
@@ -150,26 +201,31 @@ class MoEFeedForward(nn.Module):
 
         pd = cfg.param_dtype
         w1 = self.param("w1", nn.initializers.lecun_normal(),
-                        (E, D, cfg.hidden_dim), pd).astype(cfg.dtype)
+                        (held, D, cfg.hidden_dim), pd).astype(cfg.dtype)
         w3 = self.param("w3", nn.initializers.lecun_normal(),
-                        (E, D, cfg.hidden_dim), pd).astype(cfg.dtype)
+                        (held, D, cfg.hidden_dim), pd).astype(cfg.dtype)
         w2 = self.param("w2", nn.initializers.lecun_normal(),
-                        (E, cfg.hidden_dim, D), pd).astype(cfg.dtype)
+                        (held, cfg.hidden_dim, D), pd).astype(cfg.dtype)
 
         with jax.named_scope("moe_dispatch"):
-            # Sort the N*K pairs by expert: each expert's rows become
-            # one contiguous group. A row that carries no request gets
-            # the expert "E": it sorts behind every group and belongs
-            # to none.
+            # Sort the N*K pairs by expert: each held expert's rows
+            # become one contiguous group. A pair that carries no
+            # request, or whose expert is not held here, gets the group
+            # "held": it sorts behind every group and belongs to none.
             pair_expert = topk_idx.reshape(N * K)
+            away = None
+            if (lo, held) != (0, E):
+                pair_expert = pair_expert - lo
+                away = (pair_expert < 0) | (pair_expert >= held)
+                pair_expert = jnp.where(away, held, pair_expert)
             if live is not None:
                 token_live = jnp.repeat(live, T)              # [N]
                 pair_expert = jnp.where(jnp.repeat(token_live, K),
-                                        pair_expert, E)
+                                        pair_expert, held)
             order = jnp.argsort(pair_expert, stable=True)     # [N*K]
             group_sizes = jnp.sum(
-                pair_expert[:, None] == jnp.arange(E)[None, :],
-                axis=0, dtype=jnp.int32)                      # [E]
+                pair_expert[:, None] == jnp.arange(held)[None, :],
+                axis=0, dtype=jnp.int32)                      # [held]
             rows = tokens.astype(cfg.dtype)[order // K]       # [N*K, D]
         with jax.named_scope("moe_experts"):
             h = nn.silu(grouped_matmul(rows, w1, group_sizes)) * \
@@ -181,42 +237,69 @@ class MoEFeedForward(nn.Module):
             back = jnp.zeros_like(order).at[order].set(
                 jnp.arange(N * K, dtype=order.dtype))
             pairs = out_rows[back].reshape(N, K, D)
+            # rows past the last group are whatever the grouped
+            # matmul left there
             if live is not None:
-                # rows past the last group are whatever the grouped
-                # matmul left there
                 pairs = jnp.where(token_live[:, None, None], pairs, 0)
+            if away is not None:
+                pairs = jnp.where(away.reshape(N, K, 1), 0, pairs)
             out = jnp.einsum("nkd,nk->nd", pairs.astype(jnp.float32),
                              gates).astype(cfg.dtype)
 
-        # Load-balance auxiliary (Switch eq. 4 over top-1 choice).
-        top1 = jax.nn.one_hot(topk_idx[:, 0], E, dtype=jnp.float32)
-        frac_tokens = jnp.mean(top1, axis=0)
-        frac_probs = jnp.mean(probs, axis=0)
-        self.sow("losses", "load_balance",
-                 E * jnp.sum(frac_tokens * frac_probs))
+        n_shared = cfg.n_shared_experts
+        if n_shared:
+            with jax.named_scope("moe_shared"):
+                Fs = n_shared * cfg.hidden_dim
+                s1, s3 = (self.param(name, nn.initializers.lecun_normal(),
+                                     (D, Fs), pd).astype(cfg.dtype)
+                          for name in ("shared_w1", "shared_w3"))
+                s2 = self.param("shared_w2", nn.initializers.lecun_normal(),
+                                (Fs, D), pd).astype(cfg.dtype)
+                t = tokens.astype(cfg.dtype)
+                out = out + (nn.silu(t @ s1) * (t @ s3)) @ s2
+
+        if router == "softmax":
+            # Load-balance auxiliary (Switch eq. 4 over top-1 choice);
+            # a biased router is balanced through its bias instead.
+            top1 = jax.nn.one_hot(topk_idx[:, 0], E, dtype=jnp.float32)
+            frac_tokens = jnp.mean(top1, axis=0)
+            frac_probs = jnp.mean(probs, axis=0)
+            self.sow("losses", "load_balance",
+                     E * jnp.sum(frac_tokens * frac_probs))
         return out.reshape(B, T, D)
 
 
-def moe_stats_vector(stats, live, num_experts: int):
+def moe_stats_vector(stats, live, num_experts: int, held=None):
     """What the router chose in one forward pass, over live tokens
     only, as one int32 vector [E + 3]: each expert's pairs summed over
     the layers, then the distinct experts touched summed over the
     layers, the fullest expert's pairs summed over the layers, and the
     number of layers (what to divide the two sums by). ``stats`` is the
-    ``MOE_STATS`` collection of an apply, ``live`` [B, T] bool."""
-    E = num_experts
-    counts = jnp.zeros((E,), jnp.int32)
-    touched = fullest = layers = jnp.int32(0)
+    ``MOE_STATS`` collection of an apply, ``live`` [B, T] bool.
+
+    A mixture that holds a share ``held`` = (lo, n) of its router's
+    experts (``experts_held``) counts THOSE, and its vector [n + 4]
+    ends with one number more: the pairs the router made of the live
+    tokens, held or not. A mixture that holds every expert routes
+    exactly the pairs it counts, and its vector stays as it was."""
+    lo, n = held or (0, num_experts)
+    experts = jnp.arange(n) if held is None else lo + jnp.arange(n)
+    counts = jnp.zeros((n,), jnp.int32)
+    touched = fullest = layers = routed = jnp.int32(0)
     for topk in jax.tree_util.tree_leaves(stats):             # [B, T, K]
-        hit = (topk[..., None] == jnp.arange(E)) & \
+        hit = (topk[..., None] == experts) & \
             live[:, :, None, None]
-        c = jnp.sum(hit, axis=(0, 1, 2), dtype=jnp.int32)     # [E]
+        c = jnp.sum(hit, axis=(0, 1, 2), dtype=jnp.int32)     # [n]
         counts = counts + c
         touched = touched + jnp.sum(c > 0, dtype=jnp.int32)
         fullest = fullest + jnp.max(c)
         layers = layers + 1
-    return jnp.concatenate(
-        [counts, jnp.stack([touched, fullest, layers])])
+        if held is not None:
+            routed = routed + topk.shape[-1] * jnp.sum(
+                live, dtype=jnp.int32)
+    tail = [touched, fullest, layers] + (
+        [] if held is None else [routed])
+    return jnp.concatenate([counts, jnp.stack(tail)])
 
 
 class MixtralBlock(nn.Module):
@@ -233,7 +316,8 @@ class MixtralBlock(nn.Module):
             # request (ops/paged_attention.py _paged_window_attention's rule)
             live = kv_cache.page_table[:, 0] != 0
         return block_forward(
-            cfg, cfg.attention_config(), lambda h: moe(h, live),
+            cfg, LlamaAttention(cfg.attention_config(), name="attention"),
+            lambda h: moe(h, live),
             x, freqs, positions, kv_cache, cache_len)
 
 
@@ -246,7 +330,8 @@ class Mixtral(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, kv_caches=None, cache_len=None):
-        return transformer_forward(self, self.config, MixtralBlock,
+        return transformer_forward(self, self.config,
+                                   lambda i: MixtralBlock,
                                    input_ids, kv_caches, cache_len)
 
 

@@ -48,7 +48,20 @@ TPU/XLA design:
   place — decode does not copy the cache every step.
 
 Works for every Llama-shaped family (Llama, Mixtral) since they share
-LlamaAttention via block_forward.
+LlamaAttention via block_forward, and for a hybrid whose layers keep
+TWO KINDS of per-request state (models/solar_open2.py): K/V pages in
+some layers, a fixed-size recurrent state a SLOT in the others
+(models/kv_cache.py ``RecurrentState``). The step programs thread
+both through the same donated pool; the host hands a prefill call its
+rows' slot ids and keeps no other book on the state: a row whose
+start offset is 0 begins from zeros inside the program, so
+admission, preemption-recompute and fault-requeue need nothing new,
+and slots that carry no request ride a decode call without moving
+theirs. What a recurrent state cannot do yet is be snapshotted or
+rewound, so the engine refuses ``prefix_cache``, ``spec_len > 0``,
+KV export/pull and tensor/expert sharding for such a model
+(``refuse_for_recurrent_state``; docs/serving.md says what would
+lift each).
 """
 from __future__ import annotations
 
@@ -69,10 +82,12 @@ from jax.profiler import TraceAnnotation
 
 from ray_tpu.models.kv_cache import (BlockAllocator, PagedKVLayer,
                                      check_kv_dtype,
-                                     export_page_bytes, init_kv_pool,
+                                     export_page_bytes,
+                                     has_recurrent_state, init_kv_pool,
                                      kv_layer_store, kv_layer_view,
                                      kv_pool_page_bytes,
-                                     page_cols_from_bytes)
+                                     page_cols_from_bytes,
+                                     state_bytes_per_slot)
 from ray_tpu.ops.paged_attention import paged_window_block_pages
 from ray_tpu.serve import kv_migration, obs, spec_decode
 # Typed lifecycle errors live in a jax-free module (serve/errors.py)
@@ -405,18 +420,70 @@ class _Slot:
         return len(self.prompt) - self.prefilled
 
 
+# What the ``round`` event reports of a step program's routing vector
+# (models/mixtral.py moe_stats_vector: the counted experts' pairs, then
+# one entry for each name here but the first): ``pairs`` is the sum of
+# the counts. A mixture that holds a share of its router's experts
+# counts the HELD ones, and its vector ends with ``pairs_routed``, all
+# the pairs its router made; one that holds them all routes what it
+# counts, and the host reports ``pairs`` under both names.
+_MOE_SUMS = ("pairs", "experts_touched", "load_max", "layer_steps")
+_MOE_ROUTED = "pairs_routed"
+
+
 def _new_moe_info() -> Dict[str, int]:
     """The router's counters of a mixture-of-experts model, as the
     ``round`` event reports them (docs/serving.md)."""
     return {prefix + key: 0 for prefix in ("moe_", "moe_decode_")
-            for key in ("pairs", "experts_touched", "load_max",
-                        "layer_steps")}
+            for key in _MOE_SUMS + (_MOE_ROUTED,)}
+
+
+def _moe_vector_of(model):
+    """(experts counted, held, length) of the routing vector the
+    model's step programs return: ``held`` is the config's
+    ``experts_held`` share (lo, n) or None where the mixture holds
+    every expert; (0, None, 0) for a dense model."""
+    cfg = model.config
+    if not getattr(cfg, "num_experts", 0):
+        return 0, None, 0
+    if cfg.experts_held is None:
+        return cfg.num_experts, None, cfg.num_experts + len(_MOE_SUMS) - 1
+    n = cfg.experts_held[1]
+    return n, cfg.experts_held, n + len(_MOE_SUMS)
 
 
 def _moe_experts_of(model) -> int:
-    """Experts whose routing the model's step programs report: the
-    config's ``num_experts``, 0 for a dense model."""
-    return int(getattr(model.config, "num_experts", 0))
+    """Experts whose routing the model's step programs report: those
+    its mixture holds, 0 for a dense model."""
+    return _moe_vector_of(model)[0]
+
+
+def refuse_for_recurrent_state(cfg, **asked) -> None:
+    """A model with recurrent layers keeps, beside its pages, a state
+    a slot that can be neither snapshotted at a page boundary nor
+    rewound: every option that shares, rewinds, ships or shards
+    per-request state is refused by name until it learns to."""
+    if not has_recurrent_state(cfg):
+        return
+    why = {
+        "prefix_cache": "a cached prefix's pages are shared, but the "
+                        "recurrent state after that prefix was never "
+                        "snapshotted",
+        "spec_len": "rejected drafts are rolled back by clamping a "
+                    "page offset, and a recurrent state cannot be "
+                    "rewound",
+        "kv_migration": "a KV pull ships pages only, and the recurrent "
+                        "state is not in its frames",
+        "sharding": "no partition rules exist for the recurrent state "
+                    "or the layer that keeps it",
+    }
+    for option, value in asked.items():
+        if value:
+            raise ValueError(
+                f"{option}={value!r} is not supported for "
+                f"{type(cfg).__name__}: it has layers that keep a "
+                f"recurrent state a slot instead of K/V pages; "
+                f"{why[option]}")
 
 
 def _new_round_info() -> Dict[str, int]:
@@ -566,6 +633,9 @@ class LLMEngine:
         # every host->device operand commits replicated via _h2d.
         # Everything below the placement layer is sharding-oblivious —
         # same planner, same jitted step structure, same page tables.
+        refuse_for_recurrent_state(
+            self.cfg, prefix_cache=prefix_cache, spec_len=spec_len,
+            sharding=sharding is not None)
         self._sharding = sharding
         self._mesh = sharding.mesh if sharding is not None else None
         if sharding is not None:
@@ -624,8 +694,11 @@ class LLMEngine:
                                              self.kv_dtype)
         self.alloc = BlockAllocator(n_pages,
                                     page_bytes=self.page_bytes)
+        # the state a SLOT holds in the layers that keep no pages
+        # (0 bytes, and nothing below differs, for a model without)
+        self.state_bytes_per_slot = state_bytes_per_slot(self.cfg)
         self.pages = init_kv_pool(self.cfg, n_pages, page_size,
-                                  self.kv_dtype)
+                                  self.kv_dtype, n_slots=max_slots)
         if sharding is not None:
             self.pages = sharding.place_kv_pool(self.pages)
         # capacity gauge: the whole-pool byte budget this engine holds
@@ -1248,6 +1321,13 @@ class LLMEngine:
                 "kv_page_bytes": self.page_bytes,
                 "kv_bytes_in_use": self.alloc.bytes_in_use(),
                 "kv_bytes_total": self.alloc.bytes_total(),
+                # the other kind of request state: what the slots
+                # hold in layers that keep a recurrent state (0 for
+                # a model with pages only)
+                "state_bytes_in_use": self.state_bytes_per_slot
+                * (len(self.slots) - free_slots),
+                "state_bytes_total": self.state_bytes_per_slot
+                * len(self.slots),
                 # Per-lane queue depth. ``queue_depth`` is the ONLINE
                 # lane only — the number routing saturation
                 # (Candidate.saturated vs max_queued) and the
@@ -1305,6 +1385,9 @@ class LLMEngine:
                 "kv_page_bytes": self.page_bytes,
                 "kv_bytes_in_use": self.alloc.bytes_in_use(),
                 "kv_bytes_total": self.alloc.bytes_total(),
+                "state_bytes_in_use": 0,
+                "state_bytes_total": self.state_bytes_per_slot
+                * len(self.slots),
                 "queue_depth": len(self._wait),
                 "queue_depth_online": len(self._wait),
                 "queue_depth_batch": 0,
@@ -2367,7 +2450,10 @@ class LLMEngine:
         unlocked read could touch an invalidated buffer mid-round.
         A stopped donor refuses with the typed abort — in-process
         pools must mirror what a dead peer process looks like over
-        the socket, or chaos kills would "succeed" off a corpse."""
+        the socket, or chaos kills would "succeed" off a corpse.
+        A model with recurrent layers exports nothing: its pages are
+        not the whole of a request's state."""
+        refuse_for_recurrent_state(self.cfg, kv_migration="export")
         with self._lock:
             if self._stopped:
                 raise kv_migration.KVPullAborted(
@@ -2566,6 +2652,16 @@ class LLMEngine:
         self._round_info[key] = max(self._round_info[key], window)
         self.stats[key] += window
 
+    def _note_state_slots(self, n: int) -> None:
+        """``n`` slots' recurrent state was advanced by a dispatch (a
+        prefill call's rows, a decode call's riders): the ``round``
+        event's and the stats' ``state_slots``. Nothing for a model
+        that keeps none."""
+        if self.state_bytes_per_slot:
+            self._round_info["state_slots"] = (
+                self._round_info.get("state_slots", 0) + n)
+            self.stats["state_slots"] += n
+
     def _dispatch_chunk_locked(self, steps: int):
         """Launch one decode dispatch of ``steps`` steps
         asynchronously. The full carry — pages, per-slot write
@@ -2602,6 +2698,7 @@ class LLMEngine:
             slot.pos += steps
             slot.decoded += steps
         self._fetchq.append((toks, riders, steps))
+        self._note_state_slots(len(riders))
         self._round_info["decode_riders"] = len(riders)
         self._round_info["decode_steps"] = steps
         # slot.pos already counts this dispatch: the window its LAST
@@ -2913,8 +3010,10 @@ class LLMEngine:
                 jax.device_get([v for v, _d in ready]), ready):
             self._moe_expert_pairs += vec[:E]
             sums = dict(zip(
-                ("pairs", "experts_touched", "load_max", "layer_steps"),
+                _MOE_SUMS + (_MOE_ROUTED,),
                 (int(vec[:E].sum()), *(int(x) for x in vec[E:]))))
+            # a mixture that holds every expert routes what it counts
+            sums.setdefault(_MOE_ROUTED, sums["pairs"])
             for prefix in ("moe_", "moe_decode_") if decode else ("moe_",):
                 for key, value in sums.items():
                     acc[prefix + key] += value
@@ -3056,16 +3155,20 @@ class LLMEngine:
         start = np.zeros((B,), np.int32)
         last_idx = np.zeros((B,), np.int32)
         pt = np.zeros((B, self.max_pages), np.int32)  # dummies -> null
-        for r, (_ix, slot, take) in enumerate(rows):
+        slot_ids = np.full((B,), self.S, np.int32)    # dummies -> none
+        for r, (ix, slot, take) in enumerate(rows):
             ids[r, :take] = slot.prompt[
                 slot.prefilled:slot.prefilled + take]
             start[r] = slot.prefilled
             last_idx[r] = take - 1
             pt[r, :len(slot.pages)] = slot.pages
+            slot_ids[r] = ix
         out, self.pages, self._rng, *moe = self._prefill_fn(
             self.params, self.pages, self._h2d(ids),
             self._h2d(start), self._h2d(last_idx),
-            self._h2d(pt), self._rng)
+            self._h2d(pt), self._rng,
+            # only a model with recurrent layers has a state a slot
+            self._h2d(slot_ids) if self.state_bytes_per_slot else None)
         self._moe_pending.extend((v, False) for v in moe)
         # logprob capture packs (firsts, first_logprobs); the seed
         # scatter takes the raw firsts, emission gets the pair
@@ -3103,6 +3206,7 @@ class LLMEngine:
         self.stats["prefills"] += 1
         self.stats["prefill_rows"] += len(rows)
         self._round_info["prefill_rows"] += len(rows)
+        self._note_state_slots(len(rows))
         _granted = sum(take for _ix, _s, take in rows)
         self.stats["prefill_tokens"] += _granted
         self._round_info["prefill_tokens"] += _granted
@@ -3135,7 +3239,7 @@ def _moe_apply(model, mesh):
     replica's mesh is made ambient while the mixture is traced: its
     grouped matmul asks for it (ops/grouped_matmul.py: no Mosaic
     kernel under a mesh)."""
-    E = _moe_experts_of(model)
+    E, held, _ = _moe_vector_of(model)
     if not E:
         def apply(params, ids, kv, start, live):
             logits, new_kv = model.apply(params, ids, kv_caches=kv,
@@ -3154,9 +3258,23 @@ def _moe_apply(model, mesh):
                 params, ids, kv_caches=kv, cache_len=start,
                 mutable=[MOE_STATS])
         with jax.named_scope("moe_stats"):
-            vec = moe_stats_vector(sown[MOE_STATS], live(), E)
+            vec = moe_stats_vector(sown[MOE_STATS], live(),
+                                   model.config.num_experts, held)
         return logits, new_kv, (vec,)
     return apply
+
+
+def _views(pages, page_table, live, slots=None):
+    """Every layer's entry of the pool as its layer consumes it
+    (models/kv_cache.py kv_layer_view): a paged layer over the call's
+    page table, a recurrent layer over the rows' ``slots`` (None: row
+    i is slot i) and the [B, T] positions ``live()`` gives (nothing
+    calls it for a model with pages only). kv_layer_view/store
+    keep the builders kind- and dtype-agnostic: fp layers are
+    (pk, pv), int8 layers (pk, pv, sk, sv) — the scales ride the same
+    donated tuple through the step."""
+    return [kv_layer_view(layer, page_table, slots, live)
+            for layer in pages]
 
 
 def _constrain_for(mesh):
@@ -3201,18 +3319,19 @@ def _jit_prefill(model, temp, B, capture, mesh):
     from ray_tpu.models.llama import _pick_token
 
     def prefill(params, pages, ids, start, last_idx, page_table,
-                rng):
+                rng, slots=None):
         rng, sub = jax.random.split(rng)
-        # kv_layer_view/store keep this builder dtype-agnostic:
-        # fp layers are (pk, pv), int8 layers (pk, pv, sk, sv) —
-        # the scales ride the same donated tuple through the step
-        kv = [kv_layer_view(layer, page_table) for layer in pages]
         # live tokens: a real row's positions up to its last real one
-        # (dummy rows point at the null page; the rest is padding)
+        # (dummy rows point at the null page; the rest is padding).
+        # ``slots`` [B]: the decode slot each row belongs to, which
+        # only a model with recurrent layers reads (a dummy row's is
+        # out of range: it reads zeros and writes nothing)
+        def live():
+            return (page_table[:, :1] != 0) & (
+                jnp.arange(ids.shape[1])[None] <= last_idx[:, None])
         logits, new_kv, moe = apply(
-            params, ids, kv, start,
-            lambda: (page_table[:, :1] != 0)
-            & (jnp.arange(ids.shape[1])[None] <= last_idx[:, None]))
+            params, ids, _views(pages, page_table, live, slots), start,
+            live)
         new_pages = constrain([kv_layer_store(c) for c in new_kv])
         last = logits[jnp.arange(B), last_idx]        # [B, V]
         with jax.named_scope("sample"):
@@ -3247,12 +3366,12 @@ def _jit_verify(model, mesh):
     apply = _moe_apply(model, mesh)
 
     def verify(params, pages, ids, start, page_table):
-        kv = [kv_layer_view(layer, page_table) for layer in pages]
         # every position of a verified slot's row is a forward pass,
-        # unused draft places included
+        # unused draft places included; row i is slot i
+        def live():
+            return jnp.broadcast_to(page_table[:, :1] != 0, ids.shape)
         logits, new_kv, moe = apply(
-            params, ids, kv, start,
-            lambda: jnp.broadcast_to(page_table[:, :1] != 0, ids.shape))
+            params, ids, _views(pages, page_table, live), start, live)
         new_pages = constrain([kv_layer_store(c) for c in new_kv])
         return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
                 new_pages) + moe
@@ -3264,7 +3383,7 @@ def _jit_verify(model, mesh):
 def _jit_decode(model, temp, KMAX, S, capture, mesh):
     constrain = _constrain_for(mesh)
     apply = _moe_apply(model, mesh)
-    E = _moe_experts_of(model)
+    moe_len = _moe_vector_of(model)[2]
     from ray_tpu.models.llama import _pick_token
 
     def decode(params, pages, page_table, pos, cur, rng, steps):
@@ -3284,16 +3403,18 @@ def _jit_decode(model, temp, KMAX, S, capture, mesh):
         # a mixture-of-experts model's routing counters ride the
         # carry too, summed over the steps (riders only: the other
         # slots' page-table rows are null)
-        moe0 = (jnp.zeros((E + 3,), jnp.int32),) if E else ()
+        moe0 = (jnp.zeros((moe_len,), jnp.int32),) if moe_len else ()
+        # row i is slot i; a slot that rides without a request (its
+        # page-table row is null) moves no recurrent state either
+        def live():
+            return page_table[:, :1] != 0
 
         def body(i, carry):
             pages, pos, cur, key, buf, lps, *moe = carry
             key, sub = jax.random.split(key)
-            kv = [kv_layer_view(layer, page_table)
-                  for layer in pages]
             logits, new_kv, vec = apply(
-                params, cur[:, None], kv, pos,
-                lambda: page_table[:, :1] != 0)
+                params, cur[:, None], _views(pages, page_table, live),
+                pos, live)
             moe = tuple(m + v for m, v in zip(moe, vec))
             with jax.named_scope("sample"):
                 nxt = _pick_token(logits[:, -1], sub, temp)

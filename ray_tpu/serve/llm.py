@@ -19,8 +19,12 @@ def _family_for(cfg):
     from ray_tpu.models.llama import Llama, llama_sharding_rules
     from ray_tpu.models.mixtral import (Mixtral, MixtralConfig,
                                         mixtral_sharding_rules)
+    from ray_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
     if isinstance(cfg, MixtralConfig):
         return Mixtral, mixtral_sharding_rules(fsdp=False)
+    if isinstance(cfg, SolarOpen2Config):
+        # no partition rules yet: the deployment refuses to shard it
+        return SolarOpen2, None
     return Llama, llama_sharding_rules(fsdp=False)
 
 
@@ -109,6 +113,14 @@ class LlamaDeployment:
                              "be >= 1")
         self.tensor_parallel = int(tensor_parallel)
         self.expert_parallel = int(expert_parallel)
+        # a model whose layers keep a recurrent state beside K/V pages
+        # (serve/engine.py says what each refusal waits for)
+        from ray_tpu.serve.engine import refuse_for_recurrent_state
+        refuse_for_recurrent_state(
+            self.cfg, kv_migration=disaggregate and "disaggregate",
+            prefix_cache=prefix_cache, spec_len=spec_len,
+            sharding=(self.tensor_parallel, self.expert_parallel)
+            != (1, 1))
         if self.tensor_parallel > 1 or self.expert_parallel > 1:
             from ray_tpu.serve.sharding import validate_tp
             validate_tp(self.cfg, self.tensor_parallel,

@@ -1,20 +1,25 @@
 """The benchmark's family seam and its families, in tier-1: the cases
 of benchmarks/tests/test_families.py (the seam), test_mixtral_family.py
-(the rehearsal family) and test_olmoe_family.py (the OLMoE family: the
+(the rehearsal family), test_olmoe_family.py (the OLMoE family: the
 program against the plain reference at the toy size, the byte counts
 and the four readers against hand counts, the rehearsal cell end to
-end), collected here so that the suite the driver runs guards them.
+end) and test_solar_open2_family.py (the Solar-Open2 family: the
+configuration against its published copy, a chip's share against the
+reference, byte counts, three readers, doc-sat, the rehearsal cell),
+collected here so that the suite the driver runs guards them.
 `python -m pytest benchmarks/tests` still runs them where they live."""
 import pytest
 
 _FILES = ("benchmarks.tests.test_families",
           "benchmarks.tests.test_mixtral_family",
-          "benchmarks.tests.test_olmoe_family")
+          "benchmarks.tests.test_olmoe_family",
+          "benchmarks.tests.test_solar_open2_family")
 pytest.register_assert_rewrite(*_FILES)
 
 from benchmarks.tests.test_families import *          # noqa: E402,F401,F403
 from benchmarks.tests.test_mixtral_family import *    # noqa: E402,F401,F403
 from benchmarks.tests.test_olmoe_family import *      # noqa: E402,F401,F403
+from benchmarks.tests.test_solar_open2_family import *  # noqa: E402,F401,F403
 
 # Recorded without tier-1's low-optimisation XLA flags (tests/conftest.py),
 # under which the CPU draws a normal's last bits differently: the digests

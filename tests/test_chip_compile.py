@@ -221,3 +221,64 @@ def test_decode_copies_no_pool_shard_under_tp4(topo):
         sh.replicated, mesh=sh.mesh)
     _assert_pool_stays(compiled, pool,
                        sh.kv_sharding.shard_shape(pool.shape))
+
+
+# ---------------------------------------------------------------
+# A hybrid's step programs at the cell's widths (Solar-Open2: one GQA
+# layer and three delta-rule layers of 64 heads of 128, 32 slots, 1,025
+# pages; 8 of 320 experts held keeps the compile short): they fit, keep
+# the recurrent state where it lies as they keep the pool, and hold the
+# chunked delta rule's [C, C, d] products in no temporary.
+
+def _hybrid_step(name, one_chip):
+    from ray_tpu.models.kv_cache import init_kv_pool
+    from ray_tpu.models.solar_open2 import SolarOpen2, solar_open2_250b
+    from ray_tpu.serve import engine as engine_mod
+    cfg = solar_open2_250b(n_layers=4, vocab_size=24576, max_seq_len=4096,
+                           experts_held=(0, 8), param_dtype=jnp.bfloat16)
+    model = SolarOpen2(cfg)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                           sharding=one_chip), tree)
+    params = placed({"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]})
+    pages = placed(jax.eval_shape(
+        lambda: init_kv_pool(cfg, 1025, PAGE, n_slots=SLOTS)))
+    i32 = jnp.int32
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    table = ((SLOTS, cfg.max_seq_len // PAGE), i32)
+    if name == "decode":
+        fn = engine_mod._jit_decode(model, 0.0, 128, SLOTS, False, None)
+        rest = [table, ((SLOTS,), i32), ((SLOTS,), i32),
+                (key.shape, key.dtype), ((), i32)]
+    else:
+        fn = engine_mod._jit_prefill(model, 0.0, 4, False, None)
+        rest = [((4, 256), i32), ((4,), i32), ((4,), i32),
+                ((4, table[0][1]), i32), (key.shape, key.dtype),
+                ((4,), i32)]
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in rest]
+    return fn.lower(params, pages, *rest).compile()
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_hybrid_step_programs_keep_the_state_in_place(one_chip,
+                                                      monkeypatch, name):
+    from ray_tpu.ops import grouped_matmul as gm
+    monkeypatch.setattr(gm, "_use_kernel", lambda: True)
+    compiled = _hybrid_step(name, one_chip)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text          # the experts' grouped matmul
+    state = r"f32\[32,64,128,128\]"
+    copies = re.findall(r"= " + state + r"(?:\{[^}]*\})? copy\(", text)
+    assert not copies, f"{len(copies)} whole-state copies in {name}"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    one_state = 32 * 64 * 128 * 128 * 4       # a layer's, 128 MiB
+    # decode: the step's own temporaries and at most one state's worth;
+    # prefill: the chunk's [4, 64, 64, 64, 128] float32 products alone
+    # would be 512 MiB each
+    assert temp < (2 * one_state if name == "decode" else 4 * one_state), (
+        name, temp)

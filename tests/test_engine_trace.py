@@ -318,6 +318,44 @@ def test_program_names_the_benchmark_keys_on(program):
     assert "jit_" + fn.__name__ == program
 
 
+@pytest.mark.parametrize("program", ["jit_decode", "jit_prefill"])
+def test_scope_names_the_benchmark_keys_on(program):
+    """The family tables of benchmarks/families/ sort a program's
+    device time by these names (decode_linear_attn_ms,
+    linear_state_roofline, decode_moe_ms, moe_experts_roofline,
+    decode_attn_ms ...): a hybrid model's step programs carry every
+    one of them in their operations' metadata, the delta-rule layer's
+    four, the GQA layer's gate and the shared expert's among them."""
+    from ray_tpu.models.kv_cache import init_kv_pool
+    from ray_tpu.models.solar_open2 import SolarOpen2, solar_open2_tiny
+    from ray_tpu.serve import engine as engine_mod
+    cfg = solar_open2_tiny(dtype=jnp.float32, n_layers=4)
+    model = SolarOpen2(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    params = {"params": params["params"]}
+    S, i32 = 4, jnp.int32
+    pages = jax.eval_shape(lambda: init_kv_pool(cfg, 17, 8, n_slots=S))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    arr = jax.ShapeDtypeStruct
+    if program == "jit_decode":
+        fn = engine_mod._jit_decode(model, 0.0, 128, S, False, None)
+        text = fn.lower(params, pages, arr((S, 8), i32), arr((S,), i32),
+                        arr((S,), i32), key, arr((), i32)
+                        ).as_text(debug_info=True)
+    else:
+        fn = engine_mod._jit_prefill(model, 0.0, 4, False, None)
+        text = fn.lower(params, pages, arr((4, 16), i32), arr((4,), i32),
+                        arr((4,), i32), arr((4, 8), i32), key,
+                        arr((4,), i32)).as_text(debug_info=True)
+    assert f"module @{program}" in text
+    for scope in ("kda_conv", "kda_gates", "kda_recurrence", "kda_out",
+                  "attn_gate", "moe_shared", "moe_router", "moe_dispatch",
+                  "moe_experts", "moe_combine", "moe_stats", "kv_append",
+                  "kv_gather", "attn_scores", "attn_pv", "head", "sample"):
+        assert f"/{scope}" in text, scope
+
+
 # ------------------------------------------- the cost with tracing off
 
 def test_closed_annotations_cost_nothing_measurable():
