@@ -233,6 +233,19 @@ def paged_append(pages_k, pages_v, page_table, pos, k, v,
 _WINDOW_BLOCK_TOKENS = 512
 
 
+# Rows a KV head's query group presents to the block loop's two
+# contractions at the least. A group of ONE row (one query head a KV
+# head, one token a row: a decode step over a cache with as many KV
+# heads as heads) is a matrix-vector product; XLA reduces it to a
+# multiply-and-reduce on the vector unit, which on a TPU v5e has no
+# bfloat16, behind a float32 copy of the whole gathered K block and V
+# block: two thirds of the bytes attention moves, 1.07 ms a layer-step
+# where the padded form takes 0.35 (PERF.md section 6, PR 33). Two is
+# the smallest count the chip's compiler keeps on the matrix unit, and
+# four and eight measured no faster (0.35 and 0.37 ms).
+_MIN_GROUP_ROWS = 2
+
+
 def paged_window_block_pages(page_size: int, max_pages: int) -> int:
     """Logical pages one iteration of the paged window loop covers: a
     constant of the shapes, not a knob."""
@@ -260,6 +273,14 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
     recomputed every step, so a context that crosses a block's edge in
     the middle of a dispatch is still attended whole.
 
+    A KV head's query group (``H // KH`` heads x T tokens) never
+    presents fewer than ``_MIN_GROUP_ROWS`` rows to the two
+    contractions of a block, so that they stay matrix products that
+    read the gathered blocks as the bfloat16 they are stored in: a
+    one-row group cost a float32 copy of every gathered K and V block
+    (1.07 -> 0.35 ms a layer-step of OLMoE's 32-row decode on a TPU
+    v5e).
+
     The named scopes (kv_gather, attn_scores, attn_pv) are metadata
     only: a device trace splits a step's time by them (PERF.md
     section 3).
@@ -273,7 +294,20 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
     # Grouped-query attention WITHOUT materializing repeated K/V: q
     # reshapes to [B, T, KH, rep, D] and contracts against the grouped
     # cache directly (a repeat would move rep x the KV bytes a step).
-    qg = q.reshape(B, T, KH, H // KH, D).astype(jnp.float32)
+    rep = H // KH
+    qg = q.reshape(B, T, KH, rep, D)
+    if rep * T < _MIN_GROUP_ROWS and sk is None:
+        # a zero row beside the real one: it scores 0 against every
+        # key, touches none of the real row's sums and is dropped at
+        # the end. The operands stay as they are stored (bfloat16 on a
+        # deployment: exact products, float32 accumulation).
+        rows = _MIN_GROUP_ROWS
+        qg = jnp.pad(qg, ((0, 0),) * 3 + ((0, rows - rep), (0, 0)))
+    else:
+        # an int8 pool's dequantised blocks are float32 values that no
+        # bfloat16 holds: they keep the float32 contraction
+        rows = rep
+        qg = qg.astype(jnp.float32)
     # causal over absolute positions: query t of row b sits at
     # pos[b] + t and sees keys 0..pos[b]+t
     q_pos = pos[:, None] + jnp.arange(T)[None]              # [B, T]
@@ -308,7 +342,9 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
             vg = vg.reshape(B, Lb, KH, D)
         with jax.named_scope("attn_scores"):
             s = jnp.einsum("btkrd,bskd->bkrts", qg,
-                           kg.astype(jnp.float32)) / np.sqrt(D)
+                           kg.astype(qg.dtype),
+                           preferred_element_type=jnp.float32
+                           ) / np.sqrt(D)
             valid = (j * Lb + jnp.arange(Lb))[None, None] <= \
                 q_pos[:, :, None]                            # [B, T, Lb]
             s = jnp.where(valid[:, None, None], s, -1e30)
@@ -322,7 +358,7 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
                 preferred_element_type=jnp.float32)
         return m_new, l, acc
 
-    stat = (B, KH, H // KH, T)
+    stat = (B, KH, rows, T)
     carry = (jnp.full(stat, -1e30, jnp.float32),
              jnp.zeros(stat, jnp.float32),
              jnp.zeros(stat + (D,), jnp.float32))
@@ -336,6 +372,8 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
     with jax.named_scope("attn_pv"):
         # key 0 is visible to every query, so l > 0
         y = (acc / l[..., None]).astype(q.dtype)
+    if rows > rep:
+        y = y[:, :, :rep]
     # [B, KH, rep, T, D] -> [B, T, H, D]
     return y.transpose(0, 3, 1, 2, 4).reshape(B, T, H, D)
 

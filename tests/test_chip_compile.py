@@ -13,6 +13,7 @@ pytest worker imports every file), and the persistent compile cache
 is off around the compiles (an entry written for a described chip
 cannot be read back without one).
 """
+import functools
 import math
 import os
 import re
@@ -193,16 +194,64 @@ def _assert_pool_stays(compiled, pool, shard_shape):
     assert "tpu_custom_call" not in compiled.as_text()
 
 
+@pytest.fixture(scope="module")
+def step_program(one_chip):
+    """get(name, kv_heads): that cell's step program compiled for one
+    described chip, and one layer's pool; compiled once a module."""
+    @functools.lru_cache(maxsize=None)
+    def get(name, kv_heads):
+        return _compile_step(
+            name, _cell_cfg(kv_heads), one_chip,
+            lambda params: jax.tree_util.tree_map(lambda _: one_chip,
+                                                  params),
+            one_chip)
+    return get
+
+
 @pytest.mark.parametrize("kv_heads", [8, 16], ids=["mistral", "olmoe"])
 @pytest.mark.parametrize("name", ["decode", "prefill", "verify"])
-def test_step_programs_copy_no_pool(one_chip, name, kv_heads):
-    cfg = _cell_cfg(kv_heads)
-    compiled, pool = _compile_step(
-        name, cfg, one_chip,
-        lambda params: jax.tree_util.tree_map(lambda _: one_chip,
-                                              params),
-        one_chip)
+def test_step_programs_copy_no_pool(step_program, name, kv_heads):
+    compiled, pool = step_program(name, kv_heads)
     _assert_pool_stays(compiled, pool, pool.shape)
+
+
+def _f32_blocks(text, kv_heads):
+    """Float32 tensors of the compiled program as large as one gathered
+    block of the window loop (32 rows x 512 tokens x KH x 128) that
+    carry its KV-head and head dimensions, in whatever order (the
+    prefill call's logits, f32[4,256,32768], are as large and are no
+    block)."""
+    block = SLOTS * 512 * kv_heads * 128
+    found = []
+    for m in re.finditer(r"= f32\[([0-9,]+)\]", text):
+        dims = [int(d) for d in m.group(1).split(",")]
+        if math.prod(dims) >= block and {kv_heads, 128} <= set(dims):
+            found.append(m.group(0))
+    return found
+
+
+# decode over 8 KV heads, and every prefill and verify call, presents a
+# KV head's query group as rep x T >= 2 rows: a matrix product already.
+# Decode over 16 KV heads (one query head each, T = 1) is the case
+# ops/paged_attention.py pads to two rows: without that, both
+# contractions fall to the vector unit behind a float32 copy of each
+# gathered block (20 such tensors in this program; PERF.md section 6,
+# PR 33)
+@pytest.mark.parametrize("name,kv_heads", [
+    ("decode", 8), ("decode", 16), ("prefill", 16), ("verify", 16)])
+def test_block_loop_contracts_on_the_matrix_unit(step_program, name,
+                                                 kv_heads):
+    text = step_program(name, kv_heads)[0].as_text()
+    blocks = _f32_blocks(text, kv_heads)
+    assert not blocks, (
+        f"{len(blocks)} float32 tensors of a gathered block's size",
+        sorted(set(blocks)))
+    for scope, spec in (("attn_scores", "btkrd,bskd->bkrts"),
+                        ("attn_pv", "bkrts,bskd->bkrtd")):
+        convs = re.findall(
+            r" convolution\([^\n]*" + scope + "/" + spec, text)
+        # one a layer
+        assert len(convs) >= 2, (scope, len(convs))
 
 
 def test_decode_copies_no_pool_shard_under_tp4(topo):

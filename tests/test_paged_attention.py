@@ -129,6 +129,101 @@ def test_bf16_inputs(kv_dtype):
         np.asarray(out, dtype=np.float32), ref, rtol=0.05, atol=0.05)
 
 
+# One query head a KV head at T = 1 (OLMoE's decode step): the group
+# is padded to two rows so that both contractions stay matrix products
+# (ops/paged_attention.py). The window loop's block is patched to 16
+# tokens: an 8-page table of 8-token pages is four blocks, block 1
+# holding positions 16..31.
+ONE_HEAD_CASES = {
+    # each row's query position (None: a null row, its stale position
+    # in the last block)
+    "ends_inside_a_block": [20, 17, 9],
+    "ends_on_a_block_edge": [31, 15, 30],
+    "one_past_a_block_edge": [32, 16, 33],
+    # the null row must not widen the window; its output is ignored
+    "null_row_beside_live_rows": [20, None, 5],
+    # a table of two pages is one block: the straight-line branch
+    "pool_of_one_block": [15, 0, 9],
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name", list(ONE_HEAD_CASES))
+def test_one_query_head_a_kv_head_decode(name, dtype, monkeypatch):
+    from ray_tpu.ops import paged_attention as paged_mod
+    monkeypatch.setattr(paged_mod, "_WINDOW_BLOCK_TOKENS", 16)
+    case = ONE_HEAD_CASES[name]
+    rng = np.random.default_rng(7)
+    B, Pg, KH, D, n_pages = len(case), 8, 4, 16, 32
+    max_pages = 2 if name == "pool_of_one_block" else 8
+    q, pk, pv, pt, _ = _random_layout(rng, B, n_pages, max_pages, Pg,
+                                      KH, D, KH)
+    live = [b for b, at in enumerate(case) if at is not None]
+    pt[[b for b in range(B) if b not in live]] = 0
+    pos = np.asarray([max_pages * Pg - 1 if at is None else at
+                      for at in case], np.int32)
+    dt = jnp.dtype(dtype)
+    out, ref_k, ref_v = _window(q, pk, pv, pt, pos, "fp", dtype=dt)
+    assert out.dtype == dt
+    ref = _dense_ref(np.asarray(jnp.asarray(q, dt), np.float32),
+                     ref_k, ref_v, pt, pos)
+    tol = 2e-4 if dtype == "float32" else 0.05
+    np.testing.assert_allclose(
+        np.asarray(out, dtype=np.float32)[live], ref[live],
+        rtol=tol, atol=tol)
+    if len(live) < B:
+        # the same rows with the dead row's position at 0: equal
+        calm, _, _ = _window(q, pk, pv, pt,
+                             np.where(pt[:, 0] == 0, 0, pos), "fp",
+                             dtype=dt)
+        np.testing.assert_array_equal(np.asarray(out)[live],
+                                      np.asarray(calm)[live])
+
+
+def _dot_operands(jaxpr):
+    """(the group's operand, the block's) of every dot_general, loop
+    bodies too; einsum hands them over in either order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(sorted((v.aval for v in eqn.invars),
+                                key=lambda a: -a.ndim))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_dot_operands(sub))
+    return found
+
+
+# (heads, T, pool) -> the query operand of the scores' contraction
+# [B, T, KH, rows, D]: a group of rep x T >= 2 rows, and every group
+# over an int8 pool (whose dequantised blocks are float32 values, not
+# bfloat16-valued), contracts as it always has, in float32 with no
+# padded row; the one-row group over a bfloat16 pool is padded to two
+# rows and reads the block as it is stored
+@pytest.mark.parametrize("heads,T,kv_dtype,rows,operand", [
+    (4, 1, "fp", 2, "float32"),        # rep 2
+    (2, 2, "fp", 1, "float32"),        # rep 1, a two-token chunk
+    (2, 1, "int8", 1, "float32"),      # rep 1, T 1, quantised pool
+    (2, 1, "fp", 2, "bfloat16"),       # rep 1, T 1: the padded group
+])
+def test_scores_contraction_operands(heads, T, kv_dtype, rows, operand):
+    B, Pg, KH, D, n_pages, max_pages = 3, 8, 2, 16, 16, 4
+    q = jnp.zeros((B, T, heads, D), jnp.bfloat16)
+    pool = jnp.zeros((n_pages, Pg, KH, D),
+                     jnp.int8 if kv_dtype == "int8" else jnp.bfloat16)
+    scales = (jnp.ones((n_pages, KH), jnp.float32)
+              if kv_dtype == "int8" else None)
+    jaxpr = jax.make_jaxpr(_paged_window_attention)(
+        q, pool, pool, scales, scales,
+        jnp.zeros((B, max_pages), jnp.int32), jnp.zeros((B,), jnp.int32))
+    (q_op, k_op), (p_op, v_op) = _dot_operands(jaxpr.jaxpr)
+    assert q_op.shape == (B, T, KH, rows, D)
+    assert k_op.shape == v_op.shape == (B, max_pages * Pg, KH, D)
+    assert q_op.dtype == k_op.dtype == jnp.dtype(operand)
+    assert p_op.shape == (B, KH, rows, T, max_pages * Pg)
+    assert v_op.dtype == (jnp.float32 if kv_dtype == "int8"
+                          else jnp.bfloat16)
+
+
 def test_paged_append_mid_page_span():
     """Append-at-offset: a chunk starting mid-page and spanning a page
     boundary lands token-exact in the right (page, offset) cells and

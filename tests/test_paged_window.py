@@ -164,13 +164,16 @@ def test_blocked_window_equals_one_shot(name, model_params,
         np.testing.assert_allclose(got[1], calm[1], rtol=1e-6, atol=1e-6)
 
 
-def test_trip_count_follows_live_rows_only(block_tokens):
+# 4 heads over the pool's 2 KV heads, and one head each (the group
+# padded to two rows): the count is the table's and the positions'
+@pytest.mark.parametrize("heads", [4, 2])
+def test_trip_count_follows_live_rows_only(block_tokens, heads):
     """The loop's trip count, read off the traced program: the blocks
     up to the longest LIVE row's last query, whatever a null row's
     position says, and never more than the table holds."""
     block_tokens(BLOCK)
     pk = jnp.zeros((N_PAGES, PAGE, 2, 16), jnp.float32)
-    q = jnp.zeros((3, 1, 4, 16), jnp.float32)
+    q = jnp.zeros((3, 1, heads, 16), jnp.float32)
     seen = []
     real = jax.lax.fori_loop
 
