@@ -41,14 +41,23 @@ structure that lets requests join/leave the decode batch per token):
   ops/paged_attention.py; nothing outside it interprets the int8
   payload.
 
-TWO KINDS OF PER-REQUEST STATE. The pool is a list of per-layer
+THREE KINDS OF PER-REQUEST STATE. The pool is a list of per-layer
 entries BY KIND (``layer_kinds``): a layer of softmax attention has
-pages, as above; a layer of linear attention (models/solar_open2.py's
-KDA) has none, but a fixed-size ``RecurrentState`` a decode SLOT: the
-delta rule's matrix a head in float32 and the last inputs of its
-short convolution, whatever the context's length. Pages are handed
-out by the allocator as a sequence grows; a slot's state simply
-belongs to the slot. Nothing on the host ever clears it: the layer
+K and V pages, as above; a layer of LATENT attention (models/axk1.py's
+MLA) has pages too, handed out by the same allocator through the same
+page table, but ONE pool of them, ``[n_pages, page_size,
+latent_page_width(cfg)]``: a token's compressed key-value vector and
+its shared rope key side by side, which every head reads (no V pool:
+the values are a column prefix of the same entry; no head axis; the
+entry stored in a whole number of the chip's 128-lane tiles, or the
+chip's compiler lays the pool out with ``n_pages`` minor-most and
+every step program copies it: PERF.md section 6, PR 34); a layer of linear
+attention (models/solar_open2.py's KDA) has none, but a fixed-size
+``RecurrentState`` a decode SLOT: the delta rule's matrix a head in
+float32 and the last inputs of its short convolution, whatever the
+context's length. ``page_layout`` is the one place that says what a
+page of a kind is stored as. Pages are handed out by the allocator as
+a sequence grows; a slot's state simply belongs to the slot. Nothing on the host ever clears it: the layer
 itself starts a row whose write offset is 0 from zeros (as
 ``paged_append`` resets an int8 page's scale at offset 0), and rows
 or positions that carry no request leave it as it was. A model
@@ -82,6 +91,7 @@ def check_kv_dtype(kv_dtype: Optional[str]) -> str:
 
 KIND_KV = "kv"                  # a layer with K/V pages
 KIND_RECURRENT = "recurrent"    # a layer with a fixed-size state a slot
+KIND_LATENT = "latent"          # a layer with one pool of latent pages
 
 
 def layer_kinds(cfg) -> Tuple[str, ...]:
@@ -94,6 +104,58 @@ def layer_kinds(cfg) -> Tuple[str, ...]:
 
 def has_recurrent_state(cfg) -> bool:
     return KIND_RECURRENT in layer_kinds(cfg)
+
+
+def has_latent_pages(cfg) -> bool:
+    return KIND_LATENT in layer_kinds(cfg)
+
+
+# The minor axis of a TPU array is stored in tiles of 128 lanes.
+_LANES = 128
+
+
+def latent_page_width(cfg) -> int:
+    """Columns a latent page stores a token's entry in:
+    ``cfg.latent_dim`` (576 for A.X-K1) rounded up to whole 128-lane
+    tiles (640), the rest zeros. The chip pads a minor axis of 576 to
+    640 in memory whatever is declared; declared as 576 the compiler's
+    compact layout avoids that padding by making ``n_pages`` the minor
+    axis, a pool no page can be gathered from, and every step program
+    copies each layer's pool into the page-major layout and back
+    (10 whole-pool copies a call at five layers: PERF.md section 6,
+    PR 34). Declared as it is kept, no program copies it."""
+    return -(-cfg.latent_dim // _LANES) * _LANES
+
+
+def page_layout(cfg, kind: str, page_size: int, kv_dtype: str = "fp"):
+    """What ONE physical page of a layer of ``kind`` is stored as: a
+    (shape, dtype) a tensor, in storage order. The pool
+    (``init_kv_pool``), its bytes (``kv_pool_page_bytes``) and a
+    shipped page's frames (``page_cols_from_bytes``) all read it here.
+
+    kv fp:   k, v           [Pg, KH, D] cfg.dtype
+    kv int8: k, v, sk, sv   [Pg, KH, D] int8 and [KH] fp32 absmax
+    latent:  one tensor     [Pg, latent_page_width(cfg)] cfg.dtype
+    recurrent: none (its state belongs to a slot, not to a page)
+    """
+    if kind == KIND_RECURRENT:
+        return ()
+    quantized = check_kv_dtype(kv_dtype) == "int8"
+    if kind == KIND_LATENT:
+        if quantized:
+            raise ValueError(
+                f"kv_dtype='int8' is not supported for "
+                f"{type(cfg).__name__}: it has layers that keep latent "
+                f"pages, and the per-(page, KV head) absmax scale has "
+                f"no meaning for a latent entry (one scale would span "
+                f"the compressed vector and the rope key alike)")
+        return (((page_size, latent_page_width(cfg)),
+                 jnp.dtype(cfg.dtype)),)
+    shape = (page_size, cfg.n_kv_heads, cfg.head_dim)
+    if quantized:
+        scale = ((cfg.n_kv_heads,), jnp.dtype(KV_SCALE_DTYPE))
+        return ((shape, jnp.dtype(jnp.int8)),) * 2 + (scale,) * 2
+    return ((shape, jnp.dtype(cfg.dtype)),) * 2
 
 
 class RecurrentState(NamedTuple):
@@ -140,7 +202,10 @@ class PagedKVLayer(NamedTuple):
     module (a pytree: safe to carry through jit/scan).
 
     pages_k/pages_v: [n_pages, page_size, n_kv_heads, head_dim]
-                     (page-major: a page is one contiguous slab)
+                     (page-major: a page is one contiguous slab). A
+                     LATENT layer has ``pages_v`` None and ``pages_k``
+                     [n_pages, page_size, latent_page_width]: its
+                     values are a column prefix of the same entries.
     page_table:      [n_slots, max_pages] int32 — logical page p of
                      slot s lives in physical page ``page_table[s, p]``
     scales_k/scales_v: [n_pages, n_kv_heads] fp32 per-page absmax
@@ -165,7 +230,8 @@ class PagedKVLayer(NamedTuple):
 def kv_layer_view(layer, page_table: jnp.ndarray, slots=None,
                   valid=None):
     """Wrap one engine layer entry — ``(pk, pv)`` fp,
-    ``(pk, pv, sk, sv)`` int8, or a ``RecurrentState`` — as what its
+    ``(pk, pv, sk, sv)`` int8, ``(pages,)`` latent, or a
+    ``RecurrentState`` — as what its
     layer consumes: a PagedKVLayer over ``page_table``, or a
     RecurrentStateView of the rows' ``slots`` and real positions
     (``valid``: a function giving the [B, T] mask, which only a
@@ -175,6 +241,8 @@ def kv_layer_view(layer, page_table: jnp.ndarray, slots=None,
     they are."""
     if isinstance(layer, RecurrentState):
         return RecurrentStateView(layer.state, layer.conv, slots, valid())
+    if len(layer) == 1:
+        return PagedKVLayer(layer[0], None, page_table)
     if len(layer) == 2:
         pk, pv = layer
         return PagedKVLayer(pk, pv, page_table)
@@ -188,6 +256,8 @@ def kv_layer_store(cache: PagedKVLayer):
     between jitted steps."""
     if isinstance(cache, RecurrentStateView):
         return RecurrentState(cache.state, cache.conv)
+    if cache.pages_v is None:
+        return (cache.pages_k,)
     if cache.scales_k is None:
         return (cache.pages_k, cache.pages_v)
     return (cache.pages_k, cache.pages_v,
@@ -206,12 +276,10 @@ def init_kv_pool(cfg, n_pages: int, page_size: int,
           (a 0 scale means "page holds nothing"; paged_append's
           reset-on-offset-0 rule keeps that true across realloc
           without any host-side scale bookkeeping).
+    latent: (pages,) in cfg.dtype,
+          [n_pages, page_size, latent_page_width(cfg)].
     recurrent: RecurrentState of ``n_slots`` rows, zeros.
     """
-    shape = (n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-    sshape = (n_pages, cfg.n_kv_heads)
-    quantized = check_kv_dtype(kv_dtype) == "int8"
-
     def entry(kind):
         if kind == KIND_RECURRENT:
             return RecurrentState(
@@ -219,29 +287,22 @@ def init_kv_pool(cfg, n_pages: int, page_size: int,
                           jnp.float32),
                 jnp.zeros((n_slots,) + tuple(cfg.recurrent_conv_shape),
                           cfg.dtype))
-        if not quantized:
-            return (jnp.zeros(shape, cfg.dtype),
-                    jnp.zeros(shape, cfg.dtype))
-        return (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-                jnp.zeros(sshape, KV_SCALE_DTYPE),
-                jnp.zeros(sshape, KV_SCALE_DTYPE))
+        return tuple(jnp.zeros((n_pages,) + shape, dtype) for shape, dtype
+                     in page_layout(cfg, kind, page_size, kv_dtype))
     return [entry(kind) for kind in layer_kinds(cfg)]
 
 
 def kv_pool_page_bytes(cfg, page_size: int,
                        kv_dtype: str = "fp") -> int:
     """Bytes ONE physical page costs across the layers that HAVE pages
-    (k+v payload plus, for int8, its two fp32 scales). The allocator
-    multiplies this by occupancy for the bytes view in load/leak
-    reports — the number the capacity A/B halves."""
-    if kv_dtype == "int8":
-        payload = 1
-        scale = 2 * cfg.n_kv_heads * 4
-    else:
-        payload = jnp.dtype(cfg.dtype).itemsize
-        scale = 0
-    per_layer = 2 * cfg.n_kv_heads * page_size * cfg.head_dim * payload
-    return layer_kinds(cfg).count(KIND_KV) * (per_layer + scale)
+    (a K/V layer's k+v payload plus, for int8, its two fp32 scales; a
+    latent layer's one entry a token). The allocator multiplies this
+    by occupancy for the bytes view in load/leak reports — the number
+    the capacity A/B halves."""
+    return sum(int(np.prod(shape)) * dtype.itemsize
+               for kind in layer_kinds(cfg)
+               for shape, dtype in page_layout(cfg, kind, page_size,
+                                               kv_dtype))
 
 
 def state_bytes_per_slot(cfg) -> int:
@@ -275,38 +336,28 @@ def page_cols_from_bytes(cfg, page_size: int, kv_dtype: str,
     """Inverse of ``export_page_bytes``: rebuild one page's per-layer
     arrays from raw bytes, shaped for a
     ``pages.at[dst].set(col)`` landing — k/v ``[Pg, KH, D]``,
-    scales ``[KH]``. Validates arity and byte counts so a
-    truncated or cross-dtype blob fails typed instead of landing
-    garbage KV."""
-    shape = (page_size, cfg.n_kv_heads, cfg.head_dim)
-    sshape = (cfg.n_kv_heads,)
-    if kv_dtype == "int8":
-        dts = (np.int8, np.int8,
-               np.dtype(KV_SCALE_DTYPE), np.dtype(KV_SCALE_DTYPE))
-        shapes = (shape, shape, sshape, sshape)
-    elif kv_dtype == "fp":
-        dts = (np.dtype(cfg.dtype), np.dtype(cfg.dtype))
-        shapes = (shape, shape)
-    else:
-        raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
-    n_paged = layer_kinds(cfg).count(KIND_KV)
-    if len(blobs) != n_paged:
+    scales ``[KH]`` (``page_layout`` a layer). Validates arity and
+    byte counts so a truncated or cross-dtype blob fails typed instead
+    of landing garbage KV."""
+    layouts = [page_layout(cfg, kind, page_size, kv_dtype)
+               for kind in layer_kinds(cfg) if kind != KIND_RECURRENT]
+    if len(blobs) != len(layouts):
         raise ValueError(
             f"page payload has {len(blobs)} layers, pool has "
-            f"{n_paged}")
+            f"{len(layouts)}")
     out = []
-    for li, layer_blobs in enumerate(blobs):
-        if len(layer_blobs) != len(dts):
+    for li, (layer_blobs, layout) in enumerate(zip(blobs, layouts)):
+        if len(layer_blobs) != len(layout):
             raise ValueError(
                 f"layer {li}: {len(layer_blobs)} tensors, "
-                f"{kv_dtype} pool stores {len(dts)}")
+                f"{kv_dtype} pool stores {len(layout)}")
         cols = []
-        for b, dt, sh in zip(layer_blobs, dts, shapes):
-            want = int(np.prod(sh)) * np.dtype(dt).itemsize
+        for b, (sh, dt) in zip(layer_blobs, layout):
+            want = int(np.prod(sh)) * dt.itemsize
             if len(b) != want:
                 raise ValueError(
                     f"layer {li}: {len(b)}-byte tensor, expected "
-                    f"{want} for shape {sh} {np.dtype(dt).name}")
+                    f"{want} for shape {sh} {dt.name}")
             cols.append(np.frombuffer(b, dtype=dt).reshape(sh))
         out.append(tuple(cols))
     return out

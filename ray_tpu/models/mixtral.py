@@ -57,7 +57,7 @@ class MixtralConfig:
     qk_norm: bool = False              # as LlamaConfig's
     # What MoEFeedForward reads beyond the above (its docstring says
     # what each means); the defaults are Mixtral's and OLMoE's.
-    router: str = "softmax"            # or "sigmoid_bias"
+    router: str = "softmax"            # or "sigmoid_bias", "sigmoid"
     n_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
     experts_held: Optional[Tuple[int, int]] = None   # (lo, n) or all
@@ -125,7 +125,7 @@ def olmoe_tiny(**overrides) -> MixtralConfig:
 MOE_STATS = "moe_stats"
 
 
-ROUTERS = ("softmax", "sigmoid_bias")
+ROUTERS = ("softmax", "sigmoid_bias", "sigmoid")
 
 
 def experts_held(cfg) -> Tuple[int, int]:
@@ -146,11 +146,12 @@ class MoEFeedForward(nn.Module):
 
     - ``router``: ``"softmax"`` (Mixtral, OLMoE: softmax over all
       experts' logits, the k largest, renormalised or not by
-      ``norm_topk_prob``) or ``"sigmoid_bias"`` (the DeepSeek-V3 /
+      ``norm_topk_prob``), ``"sigmoid_bias"`` (the DeepSeek-V3 /
       GLM-4.5 rule: s = sigmoid(logits); the k experts with the
       largest s + b, b a stored bias a expert that takes part in the
       CHOICE only; gates s_chosen, divided by their sum where
-      ``norm_topk_prob``), times ``routed_scaling_factor``;
+      ``norm_topk_prob``) or ``"sigmoid"`` (the same with no stored
+      bias: the k largest s), times ``routed_scaling_factor``;
     - ``n_shared_experts``: a SwiGLU of that many experts' width which
       every token passes, added to the routed result;
     - ``experts_held`` (lo, n): THE CHIP'S SHARE under expert
@@ -184,12 +185,15 @@ class MoEFeedForward(nn.Module):
                 probs = jax.nn.softmax(logits, axis=-1)
                 # the k largest probabilities are the k largest logits
                 gates, topk_idx = jax.lax.top_k(probs, K)     # [N, K]
-            else:
+            elif router == "sigmoid_bias":
                 bias = self.param("router_bias", nn.initializers.zeros,
                                   (E,), jnp.float32)
                 probs = jax.nn.sigmoid(logits)
                 _, topk_idx = jax.lax.top_k(probs + bias, K)
                 gates = jnp.take_along_axis(probs, topk_idx, axis=1)
+            else:
+                probs = jax.nn.sigmoid(logits)
+                gates, topk_idx = jax.lax.top_k(probs, K)
             if cfg.norm_topk_prob:
                 gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
             if cfg.routed_scaling_factor != 1.0:

@@ -8,6 +8,15 @@ of fixed-size pages (models/kv_cache.py makes it), PAGE-MAJOR:
   scales_k/scales_v: [n_pages, n_kv_heads] fp32 (int8 pools only)
   page_table:        [n_slots, max_pages] int32 (0 = the null page)
 
+A layer of LATENT attention (models/axk1.py) keeps ONE such pool and no
+V pool: ``pages_k`` [n_pages, page_size, width] (no head axis; the
+width a whole number of 128-lane tiles, models/kv_cache.py
+``latent_page_width``), ``pages_v`` None.
+Its entries are one "KV head" that every query head reads, its values the
+first ``value_dim`` columns of the same gathered entries, and its score
+scale is handed in: the same two operations below, told so by their
+arguments.
+
 A page is one contiguous slab holding every KV head of its tokens, so
 a step program scatters and gathers whole pages by their id and never
 re-lays the pool out (PERF.md section 6, PRs 26 and 29). Every step
@@ -54,6 +63,20 @@ class PagedShapeError(ValueError):
 
 
 def _check_append_shapes(pages_k, pages_v, page_table, pos, k, v):
+    if (pages_v is None) != (v is None):
+        raise PagedShapeError(
+            "a pool without V pages (latent pages) takes a chunk "
+            "without v, and a K/V pool a chunk with both; got pages_v "
+            f"{'None' if pages_v is None else pages_v.shape}, v "
+            f"{'None' if v is None else v.shape}")
+    if pages_v is None:
+        if pages_k.ndim != 3:
+            raise PagedShapeError(
+                f"latent pages must be rank-3 [n_pages, Pg, D]; got "
+                f"{pages_k.shape}")
+        # one KV head, as the chunk [B, T, 1, D] has it
+        pages_k = pages_k[:, :, None]
+        pages_v, v = pages_k, k
     if pages_k.ndim != 4 or pages_v.ndim != 4:
         raise PagedShapeError(
             f"pages_k/pages_v must be rank-4 [n_pages, Pg, KH, D]; "
@@ -120,6 +143,10 @@ def paged_append(pages_k, pages_v, page_table, pos, k, v,
                      logical position ``pos[b]``
     k/v:             [B, T, KH, D] new keys/values
 
+    A pool of latent pages [n_pages, Pg, D] has no V and no head axis:
+    ``pages_v`` and ``v`` are None, ``k`` [B, T, 1, D] is the chunk of
+    latent entries, and a 1-tuple comes back.
+
     Token t of row b goes to physical page
     ``page_table[b, (pos[b]+t) // Pg]`` at offset ``(pos[b]+t) % Pg``.
     Positions past the row's allocated pages resolve to page-table
@@ -165,11 +192,16 @@ def paged_append(pages_k, pages_v, page_table, pos, k, v,
     if quantized and (scales_k is None or scales_v is None):
         raise PagedShapeError(
             "scales_k and scales_v must be supplied together")
+    if quantized and pages_v is None:
+        raise PagedShapeError(
+            "per-page scales supplied for a pool without V pages: "
+            "latent pages are not quantized")
     if not quantized and pages_k.dtype == jnp.int8:
         raise PagedShapeError(
             "int8 pool appended without its per-page scales — pass "
             "scales_k/scales_v (kv_dtype='int8' wiring bug)")
-    n_pages, Pg, KH, D = pages_k.shape
+    n_pages, Pg = pages_k.shape[:2]
+    KH, D = k.shape[2:]
     if quantized:
         _check_scale_shapes(pages_k, scales_k, scales_v,
                             (n_pages, KH))
@@ -183,6 +215,9 @@ def paged_append(pages_k, pages_v, page_table, pos, k, v,
     flat_o = off.reshape(-1)
     # [B, T, KH, D] -> [B*T, KH, D]: a token's row as it lies in a page
     kT = k.reshape(B * T, KH, D)
+    if pages_v is None:
+        return (pages_k.at[flat_p, flat_o].set(
+                    kT.reshape(B * T, D).astype(pages_k.dtype)),)
     vT = v.reshape(B * T, KH, D)
     if not quantized:
         return (pages_k.at[flat_p, flat_o].set(
@@ -252,12 +287,19 @@ def paged_window_block_pages(page_size: int, max_pages: int) -> int:
     return min(max_pages, max(1, _WINDOW_BLOCK_TOKENS // page_size))
 
 
-def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
+def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
+                            softmax_scale=None, value_dim=None):
     """Causal grouped-query attention of ``q`` [B, T, H, D] (row b's
     queries at absolute positions ``pos[b] + t``) over its page-table
     row's K/V in the page-major pool ``pk``/``pv`` [n_pages, Pg, KH, D]
     (``sk``/``sv``: an int8 pool's per-page scales [n_pages, KH], else
     None). Each page is gathered whole, by its id, as it lies.
+
+    ``pv`` None (a pool of latent pages ``pk`` [n_pages, Pg, D], one
+    KV head with no axis of its own): a key's value is the first
+    ``value_dim`` columns of the key itself, so a block is gathered
+    ONCE and the result is [B, T, H, value_dim]. ``softmax_scale``:
+    what the scores are multiplied by, where it is not ``D ** -0.5``.
 
     Work follows the live contexts, not the table's width: a loop with
     a RUNTIME trip count walks blocks of ``block_pages`` logical pages
@@ -286,7 +328,14 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
     section 3).
     """
     B, T, H, D = q.shape
-    _, Pg, KH, _ = pk.shape
+    Pg, KH = pk.shape[1], (1 if pv is None else pk.shape[2])
+    if (pv is None) != (value_dim is not None):
+        raise PagedShapeError(
+            "value_dim names the columns of a key that are its value "
+            "where there is no V pool, and only there; got pv "
+            f"{'None' if pv is None else pv.shape}, value_dim "
+            f"{value_dim}")
+    Dv = D if value_dim is None else value_dim
     max_pages = page_table.shape[1]
     block_pages = paged_window_block_pages(Pg, max_pages)
     Lb = block_pages * Pg
@@ -329,7 +378,8 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
             # [B, block_pages, Pg, KH, D] -> [B, Lb, KH, D]; gathered
             # index + j * Lb == logical position by construction
             kg = pk[cols]
-            vg = pv[cols]
+            if pv is not None:
+                vg = pv[cols]
             if sk is not None:
                 # dequantize the gathered block in fp32 with the
                 # gathered per-page scales (value = q * s / 127): only
@@ -339,12 +389,16 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
                 vg = vg.astype(jnp.float32) * \
                     (sv[cols] * (1.0 / 127.0))[:, :, None, :, None]
             kg = kg.reshape(B, Lb, KH, D)
-            vg = vg.reshape(B, Lb, KH, D)
+            if pv is not None:
+                vg = vg.reshape(B, Lb, KH, D)
+            else:
+                vg = kg[..., :Dv]
         with jax.named_scope("attn_scores"):
             s = jnp.einsum("btkrd,bskd->bkrts", qg,
                            kg.astype(qg.dtype),
-                           preferred_element_type=jnp.float32
-                           ) / np.sqrt(D)
+                           preferred_element_type=jnp.float32)
+            s = (s / np.sqrt(D) if softmax_scale is None
+                 else s * softmax_scale)
             valid = (j * Lb + jnp.arange(Lb))[None, None] <= \
                 q_pos[:, :, None]                            # [B, T, Lb]
             s = jnp.where(valid[:, None, None], s, -1e30)
@@ -361,7 +415,7 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
     stat = (B, KH, rows, T)
     carry = (jnp.full(stat, -1e30, jnp.float32),
              jnp.zeros(stat, jnp.float32),
-             jnp.zeros(stat + (D,), jnp.float32))
+             jnp.zeros(stat + (Dv,), jnp.float32))
     if max_blocks == 1:
         # the table is one block wide: no loop, the one-shot softmax
         # over the whole window as straight-line code
@@ -374,8 +428,8 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos):
         y = (acc / l[..., None]).astype(q.dtype)
     if rows > rep:
         y = y[:, :, :rep]
-    # [B, KH, rep, T, D] -> [B, T, H, D]
-    return y.transpose(0, 3, 1, 2, 4).reshape(B, T, H, D)
+    # [B, KH, rep, T, Dv] -> [B, T, H, Dv]
+    return y.transpose(0, 3, 1, 2, 4).reshape(B, T, H, Dv)
 
 
 def dequantize_pages(pages, scales):

@@ -61,7 +61,12 @@ theirs. What a recurrent state cannot do yet is be snapshotted or
 rewound, so the engine refuses ``prefix_cache``, ``spec_len > 0``,
 KV export/pull and tensor/expert sharding for such a model
 (``refuse_for_recurrent_state``; docs/serving.md says what would
-lift each).
+lift each). A model of LATENT attention (models/axk1.py) has pages of
+a third kind: one pool a layer of one latent entry a token, handed
+out, shared and rewound by page id and offset exactly as K/V pages
+are; what interprets, ships or shards a page's payload (int8 pages,
+KV export/pull, tensor sharding) is refused for it
+(``refuse_for_latent_pages``).
 """
 from __future__ import annotations
 
@@ -83,6 +88,7 @@ from jax.profiler import TraceAnnotation
 from ray_tpu.models.kv_cache import (BlockAllocator, PagedKVLayer,
                                      check_kv_dtype,
                                      export_page_bytes,
+                                     has_latent_pages,
                                      has_recurrent_state, init_kv_pool,
                                      kv_layer_store, kv_layer_view,
                                      kv_pool_page_bytes,
@@ -458,6 +464,18 @@ def _moe_experts_of(model) -> int:
     return _moe_vector_of(model)[0]
 
 
+def _refuse(cfg, keeps: str, why: Dict[str, str], asked) -> None:
+    """ValueError for the first option of ``asked`` that is set: the
+    model ``keeps`` a kind of request state the option cannot handle,
+    for the reason ``why`` gives."""
+    for option, value in asked.items():
+        if value:
+            raise ValueError(
+                f"{option}={value!r} is not supported for "
+                f"{type(cfg).__name__}: it has layers that keep "
+                f"{keeps}; {why[option]}")
+
+
 def refuse_for_recurrent_state(cfg, **asked) -> None:
     """A model with recurrent layers keeps, beside its pages, a state
     a slot that can be neither snapshotted at a page boundary nor
@@ -465,7 +483,7 @@ def refuse_for_recurrent_state(cfg, **asked) -> None:
     per-request state is refused by name until it learns to."""
     if not has_recurrent_state(cfg):
         return
-    why = {
+    _refuse(cfg, "a recurrent state a slot instead of K/V pages", {
         "prefix_cache": "a cached prefix's pages are shared, but the "
                         "recurrent state after that prefix was never "
                         "snapshotted",
@@ -476,14 +494,29 @@ def refuse_for_recurrent_state(cfg, **asked) -> None:
                         "state is not in its frames",
         "sharding": "no partition rules exist for the recurrent state "
                     "or the layer that keeps it",
-    }
-    for option, value in asked.items():
-        if value:
-            raise ValueError(
-                f"{option}={value!r} is not supported for "
-                f"{type(cfg).__name__}: it has layers that keep a "
-                f"recurrent state a slot instead of K/V pages; "
-                f"{why[option]}")
+    }, asked)
+
+
+def refuse_for_latent_pages(cfg, **asked) -> None:
+    """A model with latent-attention layers keeps pages, handed out
+    and shared by page id as any other (so the prefix cache and
+    speculative decoding, which deal in page ids and a page offset
+    only, serve it), but a page's payload is one latent entry a token
+    and not K and V a head: every option that interprets, ships or
+    shards that payload is refused by name until it learns to."""
+    if not has_latent_pages(cfg):
+        return
+    _refuse(cfg, "latent pages instead of K/V pages", {
+        "kv_dtype": "the int8 code keeps one absmax scale a (page, KV "
+                    "head), and a latent entry has no heads: one scale "
+                    "would span the compressed vector and the rope key "
+                    "alike",
+        "kv_migration": "a KV pull's frames carry K and V a head, and "
+                        "no frame exists for a latent page",
+        "sharding": "the pool shards over the KV-head axis, and the "
+                    "one latent entry every head reads cannot be split "
+                    "over it; no partition rules exist for the layer",
+    }, asked)
 
 
 def _new_round_info() -> Dict[str, int]:
@@ -560,6 +593,18 @@ class LLMEngine:
         guards the engine so callers racing a WEDGED scheduler
         (serve/watchdog.py) shed-and-reroute instead of parking on
         a lock only teardown would release.
+    batch_wait_timeout_s: how long an engine with NO live slot holds
+        its first admission for the prefill call's rows to fill, as
+        ``serve.batch`` flushes at ``max_batch_size`` or
+        ``batch_wait_timeout_s`` (serve/batching.py): it admits when
+        as many requests wait as the call has rows, or when the
+        oldest has waited this long. The call computes all its rows
+        whatever they hold, and rows that start a prompt together
+        stay together (the call's window loop runs to its LONGEST
+        row), so a pipeline whose clients start milliseconds apart
+        gets one schedule and not whichever a race over the first
+        round picks. 0 (default) admits at once; a request never
+        waits behind a live slot.
     fault_injector: test-only seam (serve/faults.py FaultInjector);
         None in production — every site is then a no-op.
     overlap: overlapped hot loop (default on). Each round plans and
@@ -614,6 +659,7 @@ class LLMEngine:
                  retry_backoff_s: float = 0.02,
                  shed_retry_after_s: float = 1.0,
                  admit_timeout_s: Optional[float] = None,
+                 batch_wait_timeout_s: float = 0.0,
                  sharding=None,
                  fault_injector=None,
                  events: bool = True,
@@ -635,6 +681,9 @@ class LLMEngine:
         # same planner, same jitted step structure, same page tables.
         refuse_for_recurrent_state(
             self.cfg, prefix_cache=prefix_cache, spec_len=spec_len,
+            sharding=sharding is not None)
+        refuse_for_latent_pages(
+            self.cfg, kv_dtype=kv_dtype == "int8" and kv_dtype,
             sharding=sharding is not None)
         self._sharding = sharding
         self._mesh = sharding.mesh if sharding is not None else None
@@ -823,6 +872,9 @@ class LLMEngine:
         if admit_timeout_s is not None and admit_timeout_s <= 0:
             raise ValueError("admit_timeout_s must be > 0 or None")
         self.admit_timeout_s = admit_timeout_s
+        if batch_wait_timeout_s < 0:
+            raise ValueError("batch_wait_timeout_s must be >= 0")
+        self.batch_wait_timeout_s = float(batch_wait_timeout_s)
         self._injector = fault_injector
         self._round = 0              # scheduling-round counter (the
                                      # fault seam's deterministic clock)
@@ -1319,6 +1371,10 @@ class LLMEngine:
                 # pool_stats, flight bundles)
                 "kv_dtype": self.kv_dtype,
                 "kv_page_bytes": self.page_bytes,
+                # what one token of context costs over the layers
+                # that have pages, K and V a head or one latent entry
+                # (an int8 page's scales shared among its tokens)
+                "kv_bytes_per_token": self.page_bytes / self.Pg,
                 "kv_bytes_in_use": self.alloc.bytes_in_use(),
                 "kv_bytes_total": self.alloc.bytes_total(),
                 # the other kind of request state: what the slots
@@ -1383,6 +1439,7 @@ class LLMEngine:
                 "free_pages": self.alloc.n_free,
                 "kv_dtype": self.kv_dtype,
                 "kv_page_bytes": self.page_bytes,
+                "kv_bytes_per_token": self.page_bytes / self.Pg,
                 "kv_bytes_in_use": self.alloc.bytes_in_use(),
                 "kv_bytes_total": self.alloc.bytes_total(),
                 "state_bytes_in_use": 0,
@@ -1745,6 +1802,8 @@ class LLMEngine:
                 # drain-mode weight swap: admission is paused; flip
                 # here — between rounds — once everything settled
                 self._maybe_apply_pending_swap_locked()
+            if self._hold_idle_admission_locked():
+                return True
             with TraceAnnotation("engine.admit", round=_rnd):
                 self._admit_locked()
             if not any(self.slots):
@@ -1840,6 +1899,23 @@ class LLMEngine:
                 _pm["host_gap"].observe(_gap)
             self._count_programs_locked(_now - _t0)
             return True
+
+    def _hold_idle_admission_locked(self) -> bool:
+        """``batch_wait_timeout_s``: an engine with nothing live or in
+        flight waits (the lock released) until the prefill call's
+        rows can be filled from the queue or its oldest request has
+        waited long enough. True while it holds."""
+        if (self.batch_wait_timeout_s <= 0 or not self._wait
+                or len(self._wait) >= self._max_prefill_batch
+                or any(self.slots) or self._fetchq
+                or self._pending_prefill):
+            return False
+        left = (self._wait[0].t_submit + self.batch_wait_timeout_s
+                - time.monotonic())
+        if left <= 0:
+            return False
+        self._work.wait(timeout=left)     # submit() notifies
+        return True
 
     def _contain_fault_locked(self, e: EngineFault) -> None:
         """Per-slot failure containment: fail ONLY the culprit (the
@@ -2454,6 +2530,7 @@ class LLMEngine:
         A model with recurrent layers exports nothing: its pages are
         not the whole of a request's state."""
         refuse_for_recurrent_state(self.cfg, kv_migration="export")
+        refuse_for_latent_pages(self.cfg, kv_migration="export")
         with self._lock:
             if self._stopped:
                 raise kv_migration.KVPullAborted(

@@ -19,7 +19,11 @@ def _family_for(cfg):
     from ray_tpu.models.llama import Llama, llama_sharding_rules
     from ray_tpu.models.mixtral import (Mixtral, MixtralConfig,
                                         mixtral_sharding_rules)
+    from ray_tpu.models.axk1 import AXK1, AXK1Config
     from ray_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
+    if isinstance(cfg, AXK1Config):
+        # no partition rules yet: the deployment refuses to shard it
+        return AXK1, None
     if isinstance(cfg, MixtralConfig):
         return Mixtral, mixtral_sharding_rules(fsdp=False)
     if isinstance(cfg, SolarOpen2Config):
@@ -45,6 +49,7 @@ class LlamaDeployment:
                  max_queued: Optional[int] = None,
                  max_retries: int = 2,
                  retry_backoff_s: float = 0.02,
+                 batch_wait_timeout_s: float = 0.0,
                  num_engine_replicas: int = 1,
                  pool_auto_restart: bool = True,
                  tensor_parallel: int = 1,
@@ -115,12 +120,18 @@ class LlamaDeployment:
         self.expert_parallel = int(expert_parallel)
         # a model whose layers keep a recurrent state beside K/V pages
         # (serve/engine.py says what each refusal waits for)
-        from ray_tpu.serve.engine import refuse_for_recurrent_state
+        from ray_tpu.serve.engine import (refuse_for_latent_pages,
+                                          refuse_for_recurrent_state)
+        sharded = (self.tensor_parallel, self.expert_parallel) != (1, 1)
         refuse_for_recurrent_state(
             self.cfg, kv_migration=disaggregate and "disaggregate",
             prefix_cache=prefix_cache, spec_len=spec_len,
-            sharding=(self.tensor_parallel, self.expert_parallel)
-            != (1, 1))
+            sharding=sharded)
+        # a model whose pages hold latent entries, not K and V a head
+        refuse_for_latent_pages(
+            self.cfg, kv_dtype=kv_dtype == "int8" and kv_dtype,
+            kv_migration=disaggregate and "disaggregate",
+            sharding=sharded)
         if self.tensor_parallel > 1 or self.expert_parallel > 1:
             from ray_tpu.serve.sharding import validate_tp
             validate_tp(self.cfg, self.tensor_parallel,
@@ -245,6 +256,9 @@ class LlamaDeployment:
             spec_len=spec_len, spec_ngram=spec_ngram,
             max_queued=max_queued, max_retries=max_retries,
             retry_backoff_s=retry_backoff_s,
+            # an idle engine's first admission waits this long for
+            # the prefill call's rows to fill (engine.py); 0: at once
+            batch_wait_timeout_s=batch_wait_timeout_s,
             # with a watchdog guarding the pool, a submit racing a
             # wedged scheduler sheds-and-reroutes instead of parking
             # on the wedged engine's lock

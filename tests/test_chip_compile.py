@@ -331,3 +331,76 @@ def test_hybrid_step_programs_keep_the_state_in_place(one_chip,
     # would be 512 MiB each
     assert temp < (2 * one_state if name == "decode" else 4 * one_state), (
         name, temp)
+
+
+# ---------------------------------------------------------------
+# A latent-attention model's step programs at the cell's widths
+# (A.X-K1: 64 heads over a 576-wide latent entry, 32 slots, 4,353 pages
+# of 64, a page table 256 wide; the dense layer and one mixture layer
+# with 2 of 192 experts held keep the compile short): the pool stays
+# page-major where it lies, and the absorbed contractions are matrix
+# products over the gathered block as it is stored.
+
+def _latent_step(name, one_chip):
+    from ray_tpu.models.axk1 import AXK1, axk1
+    from ray_tpu.models.kv_cache import init_kv_pool
+    from ray_tpu.serve import engine as engine_mod
+    cfg = axk1(n_layers=2, vocab_size=20480, max_seq_len=16384,
+               experts_held=(0, 2), param_dtype=jnp.bfloat16)
+    model = AXK1(cfg)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                           sharding=one_chip), tree)
+    params = placed({"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]})
+    pages = placed(jax.eval_shape(lambda: init_kv_pool(cfg, 4353, PAGE)))
+    i32 = jnp.int32
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    table = ((SLOTS, cfg.max_seq_len // PAGE), i32)
+    if name == "decode":
+        fn = engine_mod._jit_decode(model, 0.0, 128, SLOTS, False, None)
+        rest = [table, ((SLOTS,), i32), ((SLOTS,), i32),
+                (key.shape, key.dtype), ((), i32)]
+    else:
+        fn = engine_mod._jit_prefill(model, 0.0, 4, False, None)
+        rest = [((4, 256), i32), ((4,), i32), ((4,), i32),
+                ((4, table[0][1]), i32), (key.shape, key.dtype)]
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in rest]
+    return fn.lower(params, pages, *rest).compile()
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_latent_step_programs_keep_the_pool_page_major(one_chip,
+                                                       monkeypatch, name):
+    from ray_tpu.ops import grouped_matmul as gm
+    monkeypatch.setattr(gm, "_use_kernel", lambda: True)
+    compiled = _latent_step(name, one_chip)
+    text = compiled.as_text()
+    # the entry is stored in whole 128-lane tiles (640 for 576): as
+    # [n_pages, 64, 1, 576] or [n_pages, 64, 576] the compiler's
+    # compact layout makes n_pages the minor axis and every program
+    # copies each layer's pool in and out (PERF.md section 6, PR 34)
+    pool = r"bf16\[4353,64,640\]"
+    entry = re.search(pool + r"(\{[^}]*\}) parameter", text)
+    assert entry and entry.group(1).startswith("{2,1,0"), entry
+    copies = re.findall(r"= " + pool + r"(?:\{[^}]*\})? copy\(", text)
+    assert not copies, f"{len(copies)} whole-pool copies in {name}"
+    one_pool = 4353 * 64 * 640 * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (one_pool if name == "decode" else 2 * one_pool), (
+        name, temp)
+    if name == "decode":
+        # no float32 copy of a gathered block [32, 512, 640]
+        big = [m.group(0) for m in re.finditer(r"= f32\[([0-9,]+)\]", text)
+               if math.prod(int(d) for d in m.group(1).split(","))
+               >= SLOTS * 512 * 640]
+        assert not big, sorted(set(big))
+    for scope, spec in (("attn_scores", "btkrd,bskd->bkrts"),
+                        ("attn_pv", "bkrts,bskd->bkrtd")):
+        convs = re.findall(
+            r" convolution\([^\n]*" + scope + "/" + spec, text)
+        assert len(convs) >= 2, (name, scope, len(convs))    # one a layer

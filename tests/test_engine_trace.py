@@ -356,6 +356,48 @@ def test_scope_names_the_benchmark_keys_on(program):
         assert f"/{scope}" in text, scope
 
 
+@pytest.mark.parametrize("program", ["jit_decode", "jit_prefill"])
+def test_latent_scope_names_the_benchmark_keys_on(program):
+    """benchmarks/families/axk1.py's table sorts a program's device
+    time by these names (decode_latent_attn_ms, latent_attn_roofline,
+    prefill_attn_share): a latent-attention model's step programs carry
+    the three ``mla_*`` scopes beside the page window's shared ones,
+    the mixture's and the leading dense layer's module name."""
+    from ray_tpu.models.axk1 import AXK1, axk1_tiny
+    from ray_tpu.models.kv_cache import init_kv_pool
+    from ray_tpu.serve import engine as engine_mod
+    cfg = axk1_tiny(dtype=jnp.float32, n_layers=2)
+    model = AXK1(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    params = {"params": params["params"]}
+    S, i32 = 4, jnp.int32
+    pages = jax.eval_shape(lambda: init_kv_pool(cfg, 17, 8))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    arr = jax.ShapeDtypeStruct
+    if program == "jit_decode":
+        fn = engine_mod._jit_decode(model, 0.0, 128, S, False, None)
+        text = fn.lower(params, pages, arr((S, 8), i32), arr((S,), i32),
+                        arr((S,), i32), key, arr((), i32)
+                        ).as_text(debug_info=True)
+    else:
+        fn = engine_mod._jit_prefill(model, 0.0, 4, False, None)
+        text = fn.lower(params, pages, arr((4, 16), i32), arr((4,), i32),
+                        arr((4,), i32), arr((4, 8), i32), key
+                        ).as_text(debug_info=True)
+    assert f"module @{program}" in text
+    for scope in ("mla_q", "mla_kv", "mla_absorb", "kv_append",
+                  "kv_gather", "attn_scores", "attn_pv", "feed_forward",
+                  "moe_shared", "moe_router", "moe_dispatch",
+                  "moe_experts", "moe_combine", "moe_stats", "head",
+                  "sample"):
+        assert f"/{scope}" in text, scope
+    # the low-rank projections lie inside their scopes, the output
+    # projection outside them
+    assert "mla_q/wq_b" in text and "mla_kv/wkv_a" in text
+    assert "mla_absorb/wo" not in text and "attention/wo" in text
+
+
 # ------------------------------------------- the cost with tracing off
 
 def test_closed_annotations_cost_nothing_measurable():

@@ -198,32 +198,52 @@ def test_the_routers_rule_matches_the_reference(tiny):
     np.testing.assert_allclose(weights.sum(-1), 1.0, rtol=1e-6)
 
 
-def test_the_shares_add_up_to_the_whole_layer(tiny):
-    """THE SHARE TEST (model-configs section 4): four chips hold 4 of
-    the 16 experts each. What each computes for the same tokens (its
-    own experts' part, the router at its full width, the gates
-    normalised over all chosen) plus the shared expert, which every
-    chip computes alike, counted ONCE, is what the uncut reference
-    gives for the whole layer."""
-    from benchmarks.reference import solar_open2 as ref
-    cfg, _model, params = tiny
-    x = jax.random.normal(jax.random.PRNGKey(4), (2, 11, cfg.dim))
-    whole = params["params"]["layers_2"]["moe"]
-    w = {k: jnp.asarray(v, jnp.float32)
-         for k, v in _moe_weights(cfg, params, 2).items()}
+def _share_case(family, tiny):
+    """(reference module, config, params, mixture layer, experts a
+    share, the reference's routing arguments) of a family's tiny
+    model."""
+    if family == "solar_open2":
+        from benchmarks.reference import solar_open2 as ref
+        cfg, _model, params = tiny
+        return ref, cfg, params, 2, 4, dict(top_k=4, scaling=1.0)
+    # A.X-K1: a sigmoid router without a choice bias, gates times 2.5;
+    # SIXTEEN shares of one expert each, as its benchmark cut has them
+    from benchmarks import common, weights
+    from benchmarks.reference import axk1 as ref
+    from ray_tpu.models.axk1 import AXK1, axk1_tiny
+    cfg = axk1_tiny(dtype=jnp.float32)
+    params = common.load_family("axk1", "serve").seeded(
+        weights.param_shapes(AXK1(cfg)), 0)
+    return ref, cfg, params, 1, 1, dict(top_k=4, scaling=2.5)
+
+
+@pytest.mark.parametrize("family", ["solar_open2", "axk1"])
+def test_the_shares_add_up_to_the_whole_layer(tiny, family):
+    """THE SHARE TEST (model-configs section 4): the chips of a group
+    hold a share of the 16 experts each (four of 4; sixteen of 1).
+    What each computes for the same tokens (its own experts' part, the
+    router at its full width, the gates normalised over all chosen)
+    plus the shared expert, which every chip computes alike, counted
+    ONCE, is what the uncut reference gives for the whole layer."""
+    from benchmarks import common
+    ref, cfg, params, layer, held, routing = _share_case(family, tiny)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 31, cfg.dim))
+    whole = params["params"][f"layers_{layer}"]["moe"]
+    w = {k: jnp.asarray(v, jnp.float32) for k, v in common.load_family(
+        family, "serve").reference_weights(params, cfg)["layers"][
+            layer].items()}
     with jax.default_matmul_precision("highest"):
         shared = ref.shared(x, w)
-        want = ref.routed(x, w, top_k=4, lo=0, norm_topk=True,
-                          scaling=1.0) + shared
+        want = ref.routed(x, w, lo=0, norm_topk=True, **routing) + shared
     total, landed = jnp.zeros_like(x), 0
-    for lo in range(0, 16, 4):
-        share_cfg = dataclasses.replace(cfg, experts_held=(lo, 4))
-        share = {k: (v[lo:lo + 4] if k in ("w1", "w2", "w3") else v)
+    for lo in range(0, 16, held):
+        share_cfg = dataclasses.replace(cfg, experts_held=(lo, held))
+        share = {k: (v[lo:lo + held] if k in ("w1", "w2", "w3") else v)
                  for k, v in whole.items()}
         part = MoEFeedForward(share_cfg).apply({"params": share}, x)
         total = total + (part - shared)
         landed += float(jnp.abs(part - shared).max() > 1e-3)
-    assert landed == 4                      # every share does some work
+    assert landed == 16 // held             # every share does some work
     np.testing.assert_allclose(np.asarray(total + shared),
                                np.asarray(want), rtol=RTOL, atol=ATOL)
 
