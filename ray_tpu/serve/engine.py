@@ -520,8 +520,14 @@ def refuse_for_latent_pages(cfg, **asked) -> None:
 
 
 def _new_round_info() -> Dict[str, int]:
-    """What a round dispatched, as its ``round`` event reports it."""
-    return {"decode_riders": 0, "decode_steps": 0,
+    """What a round dispatched, as its ``round`` event reports it.
+    ``backlog`` is the planner's (serve/scheduler.py
+    ``StepPlan.backlog``): mid-prefill slots the prefill call had no
+    row for, counted only where that queue outlasts the riders; beside
+    a non-zero ``decode_steps`` it says the round's decode was cut to
+    ``BACKLOG_DECODE_STEPS`` (``stats["backlog_rounds"]`` counts those
+    rounds)."""
+    return {"decode_riders": 0, "decode_steps": 0, "backlog": 0,
             "decode_window_tokens": 0,
             "prefill_tokens": 0, "prefill_budget": 0,
             "prefill_rows": 0, "prefill_window_tokens": 0}
@@ -1985,7 +1991,9 @@ class LLMEngine:
         advance, a row of up to ``prefill_chunk`` tokens each, and
         how many decode steps ride behind them. Run-ahead-to-next-
         completion, quick cadence while admission work is pending,
-        and the eos bound all live in the planner — this wrapper only
+        a step or two while prompts queue behind full prefill rows
+        for longer than the riders last, and the eos bound all live in
+        the planner — this wrapper only
         snapshots slot state (plus, with speculation on, one
         prompt-lookup proposal per seeded slot)."""
         if self.spec_len:
@@ -2029,13 +2037,20 @@ class LLMEngine:
         # a row's chunk
         self._round_info["prefill_budget"] = (
             caps["prefill_batch"] * caps["prefill_chunk"])
-        return plan_step(views, total_slots=self.S,
+        plan = plan_step(views, total_slots=self.S,
                          prefill_chunk=caps["prefill_chunk"],
                          decode_chunk=self.K,
                          max_run_ahead=caps["max_run_ahead"],
                          prefill_batch=caps["prefill_batch"],
                          eos_bounded=self.eos_id is not None,
                          spec_enabled=bool(self.spec_len))
+        # prompts queue behind full rows and outlast the riders: the
+        # planner cut this round's decode (the spec lane's one verify
+        # a round is not a cut)
+        self._round_info["backlog"] = plan.backlog
+        if plan.backlog and plan.decode_steps:
+            self.stats["backlog_rounds"] += 1
+        return plan
 
     def _propose_spec_locked(self):
         """Refresh each seeded slot's prompt-lookup proposal. In the

@@ -48,6 +48,35 @@ Policy, in order:
   stale eos-bounded rider tightens the cap to one ``decode_chunk``,
   which bounds the worst-case discard on a late-revealed eos to one
   chunk per slot.
+- Decode cadence follows the prefill backlog: when, after the grants,
+  a mid-prefill slot was left WITHOUT a row (the rows are full and
+  prompts queue behind them) AND the prompts in slots need more rounds
+  of prefill than the riders have rounds of decode left, the plain
+  decode lane dispatches ``BACKLOG_DECODE_STEPS`` steps, not
+  ``decode_chunk``. A request holds a row for ``len / prefill_chunk``
+  rounds and then rides ``tokens / steps`` rounds: with long prompts
+  and ``decode_chunk`` steps a round today's riders are gone before
+  the queued prompts reach the batch, so the batch stays a fraction
+  full for as long as prompts queue, and every decode step of such a
+  round delays the rows the queue waits for. Both sides are counted in
+  rounds from what the views hold: the prefill chunks of every
+  mid-prompt slot over the rows, against the riders' mean ``owed``
+  over ``decode_chunk``. Where the queue clears before the riders
+  leave (a ramp filling empty slots with short prompts; rows that keep
+  up with admission) the queued prompts join today's riders anyway and
+  the cadence stays what it was. It is the policy chunked-prefill
+  servers ship (a token or two an iteration beside the prefill
+  budget); ``decode_chunk`` remains the cadence otherwise. What it
+  trades: under backlog a rider's tokens come two a round instead of
+  ``decode_chunk`` a (longer) round, and a queued prompt reaches its
+  first token sooner. Decode still rides every round, so a rider's gap
+  stays bounded by one round. Two exceptions, both read from the
+  arguments: a queued BATCH-lane prompt counts only when no seeded
+  slot is online (batch work never slows online riders), and a lane of
+  ONE row counts no backlog at all — that is the residue lane
+  ``role_plan_caps`` leaves a decode-role replica, whose cadence must
+  not drop because fallback prompts crawl through it.
+  ``StepPlan.backlog`` carries the count.
 - Priority lanes (``SlotView.batch``, serve/batch_tier.py): offline
   batch slots share the round with online traffic but never crowd it.
   Prefill grants order ONLINE slots first (FIFO within the lane),
@@ -121,7 +150,10 @@ def role_plan_caps(role, *, page_size, decode_chunk, prefill_chunk,
       plus the bridging token always fits one round) and to crawl
       through a full plain prefill when a fallback or chaos resubmit
       lands here (correct, just slow — a hard refusal would strand
-      exactly the recovery paths that must keep working).
+      exactly the recovery paths that must keep working). The one-row
+      lane is also what keeps this role's decode cadence: the planner
+      counts no prefill backlog behind a single row, so prompts
+      crawling through the residue lane never cut a round's decode.
     - ``unified``: knobs pass through untouched.
 
     Unknown roles raise: a typo'd role silently planning as unified
@@ -248,11 +280,53 @@ class StepPlan:
     prefill: Tuple[PrefillGrant, ...]
     decode_steps: int
     spec: Tuple[SpecGrant, ...] = ()
+    backlog: int = 0         # mid-prefill slots left without a row
+                             # this round that the decode cadence
+                             # answers to (``_prefill_backlog``):
+                             # non-zero cuts the plain decode lane to
+                             # BACKLOG_DECODE_STEPS
 
     @property
     def idle(self) -> bool:
         return (not self.prefill and self.decode_steps == 0
                 and not self.spec)
+
+
+# Decode steps of a round that leaves prompts queuing behind full
+# prefill rows for longer than the riders last (``plan_step``, "Decode
+# cadence follows the prefill backlog"). A constant, not a knob: on the
+# chip, over 160 s of 8,192-token prompts, 1 and 2 read level (604 and
+# 608 tokens/s) and inside a 40 s window 2 read ahead (546 and 581): a
+# closed loop's slots swing between prefill-heavy and decode-heavy
+# phases, less deeply when a rider keeps two steps a round (PERF.md
+# section 6, PR 35).
+BACKLOG_DECODE_STEPS = 2
+
+
+def _prefill_backlog(waiting, seeded, *, prefill_chunk, prefill_batch,
+                     decode_chunk) -> int:
+    """Mid-prefill slots left without a row this round that the decode
+    cadence answers to. ``waiting``: the mid-prefill views in grant
+    order (the first ``prefill_batch`` hold the rows); ``seeded``: the
+    riders. 0 unless prompts queue behind full rows AND the queue
+    outlasts the riders: the prefill rounds every mid-prompt slot
+    still needs (chunks over rows) exceed the decode rounds the riders
+    have left (mean ``owed`` over ``decode_chunk``). A batch-lane
+    prompt counts only when no rider is online; behind a lane of one
+    row (a decode-role replica's residue lane) nothing counts."""
+    if prefill_batch < 2:
+        return 0
+    if any(not v.batch for v in seeded):
+        waiting = [v for v in waiting if not v.batch]
+    unserved = len(waiting) - prefill_batch
+    if unserved <= 0 or not seeded:
+        return max(0, unserved)
+    chunks = sum(-(-v.prompt_remaining // prefill_chunk) for v in waiting)
+    owed = sum(max(0, v.owed) for v in seeded)
+    # chunks / prefill_batch > owed / len(seeded) / decode_chunk
+    outlasts = (chunks * decode_chunk * len(seeded)
+                > owed * prefill_batch)
+    return unserved if outlasts else 0
 
 
 def plan_step(slots: Sequence[SlotView], *, total_slots: int,
@@ -292,8 +366,11 @@ def plan_step(slots: Sequence[SlotView], *, total_slots: int,
 
     seeded = sorted((v for v in slots if v.seeded),
                     key=lambda v: v.admit_seq)
+    backlog = _prefill_backlog(
+        waiting, seeded, prefill_chunk=prefill_chunk,
+        prefill_batch=prefill_batch, decode_chunk=decode_chunk)
     if not seeded:
-        return StepPlan(tuple(grants), 0)
+        return StepPlan(tuple(grants), 0, backlog=backlog)
 
     if spec_enabled and any(v.spec_drafts > 0 for v in seeded):
         # Spec lane: ONE batched verify covering every seeded slot
@@ -306,7 +383,7 @@ def plan_step(slots: Sequence[SlotView], *, total_slots: int,
             SpecGrant(v.sid, max(0, min(v.spec_drafts, v.owed - 1,
                                         max_run_ahead - 1)))
             for v in seeded)
-        return StepPlan(tuple(grants), 0, spec)
+        return StepPlan(tuple(grants), 0, spec, backlog)
 
     # Defensive clamp: cancelled/expired slots are torn down before
     # the engine snapshots views, so they never appear here at all —
@@ -323,6 +400,12 @@ def plan_step(slots: Sequence[SlotView], *, total_slots: int,
     # trades run-ahead pipelining for multi-token dispatches).
     steps = (decode_chunk if quick or spec_enabled
              else max(decode_chunk, min(rem)))
+    if backlog:
+        # The rows are full and the queue behind them outlasts the
+        # riders: every decode step of this round delays the row a
+        # queued prompt waits for, and the decode batch is short of
+        # exactly those prompts. A step or two keeps the riders moving.
+        steps = min(steps, BACKLOG_DECODE_STEPS)
     if eos_bounded:
         steps = min(steps, 2 * decode_chunk)
         if any(v.stale > 0 for v in seeded):
@@ -334,4 +417,5 @@ def plan_step(slots: Sequence[SlotView], *, total_slots: int,
             # deep — bounds the tokens ever discarded on a
             # late-revealed eos to at most one decode chunk per slot.
             steps = min(steps, decode_chunk)
-    return StepPlan(tuple(grants), max(1, min(steps, max_run_ahead)))
+    return StepPlan(tuple(grants), max(1, min(steps, max_run_ahead)),
+                    backlog=backlog)

@@ -16,7 +16,8 @@ import pytest
 from ray_tpu.models.kv_cache import BlockAllocator
 from ray_tpu.models.llama import Llama, generate, llama_tiny
 from ray_tpu.serve.engine import LLMEngine, RequestError
-from ray_tpu.serve.scheduler import PrefillGrant, SlotView, plan_step
+from ray_tpu.serve.scheduler import (BACKLOG_DECODE_STEPS, PrefillGrant,
+                                     SlotView, plan_step, role_plan_caps)
 
 
 @pytest.fixture(scope="module")
@@ -533,6 +534,49 @@ def test_decode_role_prefill_lane_is_one_small_row(tiny_model):
     assert eng.stats["prefills"] == 4 == eng.stats["prefill_rows"]
 
 
+@pytest.mark.parametrize("role,overlap", [("unified", False),
+                                          ("unified", True),
+                                          ("decode", False)])
+def test_prompt_burst_cuts_the_decode_cadence(tiny_model, role, overlap):
+    """Six five-chunk prompts land beside a stream that has a few
+    tokens left: four take the call's rows, two queue behind them, and
+    the queue outlasts the rider, so while they queue a round decodes
+    ``BACKLOG_DECODE_STEPS`` steps (``backlog`` on the ``round`` event,
+    ``stats["backlog_rounds"]``). Once every prompt has a row the
+    cadence is ``chunk`` again. Tokens are ``generate``'s whatever the
+    cadence. A decode-role replica's one-row lane counts no backlog:
+    its cadence never drops."""
+    model, params = tiny_model
+    eng = LLMEngine(model, params, max_slots=8, page_size=8,
+                    n_pages=128, chunk=4, prefill_chunk=8, role=role,
+                    overlap=overlap)
+    p0 = [3, 1, 4]
+    prompts = [list(range(1 + i, 41 + i)) for i in range(6)]  # 5 chunks
+    wants = [_reference_completion(model, params, p, n)
+             for p, n in [(p0, 24)] + [(p, 6) for p in prompts]]
+    hs = [eng.submit(p0, max_new_tokens=24)]
+    for _ in range(2):                       # p0 decoding solo
+        eng.step()
+    hs += [eng.submit(p, max_new_tokens=6) for p in prompts]
+    while eng.step():
+        pass
+    assert [h.result() for h in hs] == wants
+    rounds = [r for r in _round_events(eng) if r["decode_steps"]]
+    cut = [r for r in rounds if r["backlog"]]
+    if role == "decode":
+        assert eng.stats["backlog_rounds"] == 0 and not cut
+        return
+    assert eng.stats["backlog_rounds"] == len(cut) > 0
+    assert all(r["decode_steps"] == BACKLOG_DECODE_STEPS
+               and r["prefill_rows"] == 4 and r["backlog"] <= 2
+               for r in cut)
+    # the first four prompts hold their rows five rounds: the two
+    # behind them queue no longer than that
+    assert len(cut) <= 5
+    assert any(r["decode_steps"] == 4 and r["prefill_rows"]
+               for r in rounds if not r["backlog"])
+
+
 # ------------------------------------------------------- pure planner
 
 
@@ -540,11 +584,11 @@ _PLAN = dict(total_slots=4, prefill_chunk=16, decode_chunk=4,
              max_run_ahead=128, prefill_batch=4, eos_bounded=False)
 
 
-def _waiting(remaining, **kw):
+def _waiting(remaining, first_sid=0, **kw):
     """Unseeded mid-prefill views, admitted in list order."""
     return [SlotView(sid=i, admit_seq=i, prompt_remaining=n, owed=0,
                      seeded=False, **kw)
-            for i, n in enumerate(remaining)]
+            for i, n in enumerate(remaining, first_sid)]
 
 
 def test_planner_long_prompt_takes_one_row():
@@ -686,6 +730,102 @@ def test_planner_unbounded_tail_never_below_one():
                       owed=9, seeded=True)]
     plan = plan_step(views, **dict(_PLAN, total_slots=2))
     assert plan.decode_steps >= 1
+
+
+def _riders(n, *, owed=50, batch=False, first_sid=0, **kw):
+    """Seeded views riding decode, admitted before anything else."""
+    return [SlotView(sid=first_sid + i, admit_seq=first_sid + i,
+                     prompt_remaining=0, owed=owed, seeded=True,
+                     batch=batch, **kw) for i in range(n)]
+
+
+def _queued(n, *, first_sid, tokens=400, **kw):
+    """Unseeded mid-prompt views: 400 tokens is 25 chunks of 16, far
+    more rounds of prefill than a rider owed 50 has rounds of decode
+    (12.5 at 4 steps a round)."""
+    return _waiting([tokens] * n, first_sid, **kw)
+
+
+_BACKLOG_PLAN = dict(_PLAN, total_slots=8)
+_CUT = BACKLOG_DECODE_STEPS
+_DECODE_ROLE = role_plan_caps("decode", page_size=8, decode_chunk=4,
+                              prefill_chunk=16, prefill_batch=4,
+                              max_run_ahead=128)
+
+# (views, plan_step overrides, rows granted, decode steps, spec rows,
+#  StepPlan.backlog)
+_BACKLOG_CASES = {
+    "five prompts for four rows, riders: cut":
+        (_riders(2) + _queued(5, first_sid=2), {}, 4, _CUT, 0, 1),
+    "four prompts for four rows: decode_chunk":
+        (_riders(2) + _queued(4, first_sid=2), {}, 4, 4, 0, 0),
+    "full and seeded: run-ahead as before":
+        (_riders(8, owed=20), {}, 0, 20, 0, 0),
+    # 5 prompts x 2 chunks over 4 rows = 2.5 rounds of prefill; the
+    # riders have 50 / 4 = 12.5 rounds left: the queue joins them
+    "a queue that clears before the riders leave: decode_chunk":
+        (_riders(2) + _queued(5, first_sid=2, tokens=32),
+         {}, 4, 4, 0, 0),
+    # the same queue behind riders about to leave (2 / 4 of a round)
+    "the same queue outlasts riders about to leave: cut":
+        (_riders(2, owed=2) + _queued(5, first_sid=2, tokens=32),
+         {}, 4, _CUT, 0, 1),
+    # 10 chunks x 4 steps x 2 riders = 80 against 2 x 10 owed x 4 rows
+    "prefill rounds equal to decode rounds: decode_chunk":
+        (_riders(2, owed=10) + _queued(5, first_sid=2, tokens=32),
+         {}, 4, 4, 0, 0),
+    "batch backlog behind online riders: decode_chunk":
+        (_riders(2) + _queued(4, first_sid=2)
+         + _queued(2, first_sid=6, batch=True), {}, 4, 4, 0, 0),
+    "batch backlog, batch riders only: cut":
+        (_riders(2, batch=True) + _queued(4, first_sid=2)
+         + _queued(2, first_sid=6, batch=True), {}, 4, _CUT, 0, 2),
+    "online backlog behind batch riders: cut":
+        (_riders(2, batch=True) + _queued(5, first_sid=2),
+         {}, 4, _CUT, 0, 1),
+    "a pulling slot never counts":
+        (_riders(2) + _queued(4, first_sid=2)
+         + _queued(2, first_sid=6, pulling=True), {}, 4, 4, 0, 0),
+    "spec lane untouched: one verify, drafts kept":
+        (_riders(2, spec_drafts=3) + _queued(5, first_sid=2),
+         {"spec_enabled": True}, 4, 0, 2, 1),
+    "spec on, no proposal: the plain lane is cut":
+        (_riders(2) + _queued(5, first_sid=2),
+         {"spec_enabled": True}, 4, _CUT, 0, 1),
+    "eos and stale caps still apply under backlog":
+        (_riders(2, stale=4) + _queued(5, first_sid=2),
+         {"eos_bounded": True}, 4, _CUT, 0, 1),
+    "eos cap without backlog is what it was":
+        (_riders(8, owed=20), {"eos_bounded": True}, 0, 8, 0, 0),
+    "max_run_ahead of one caps the cut too":
+        (_riders(2) + _queued(5, first_sid=2), {"max_run_ahead": 1},
+         4, 1, 0, 1),
+    "decode_chunk of one is the cadence either way":
+        (_riders(2, owed=5) + _queued(5, first_sid=2),
+         {"decode_chunk": 1}, 4, 1, 0, 1),
+    "the decode role keeps its cadence":
+        (_riders(2) + _queued(5, first_sid=2), _DECODE_ROLE,
+         1, 4, 0, 0),
+    "no rider: nothing to cut, the count still reported":
+        (_queued(6, first_sid=0), {}, 4, 0, 0, 2),
+}
+
+
+@pytest.mark.parametrize("case", list(_BACKLOG_CASES))
+def test_planner_decode_cadence_follows_the_prefill_backlog(case):
+    """While a mid-prefill slot is left WITHOUT a row (the rows are
+    full, prompts queue behind them) and the prompts in slots need
+    more rounds of prefill than the riders have rounds of decode left,
+    the plain decode lane dispatches ``BACKLOG_DECODE_STEPS``; in
+    every other state the plan is what it was."""
+    views, over, rows, steps, spec_rows, backlog = _BACKLOG_CASES[case]
+    plan = plan_step(views, **dict(_BACKLOG_PLAN, **over))
+    assert len(plan.prefill) == rows
+    assert plan.decode_steps == steps
+    assert len(plan.spec) == spec_rows
+    assert plan.backlog == backlog
+    if spec_rows:
+        assert [g.drafts for g in plan.spec] == [3, 3]
 
 
 def test_planner_all_slots_mid_prefill_decode_lane_empty():
