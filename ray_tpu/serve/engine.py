@@ -526,11 +526,14 @@ def _new_round_info() -> Dict[str, int]:
     row for, counted only where that queue outlasts the riders; beside
     a non-zero ``decode_steps`` it says the round's decode was cut to
     ``BACKLOG_DECODE_STEPS`` (``stats["backlog_rounds"]`` counts those
-    rounds)."""
+    rounds). ``prefill_width`` is the ``T`` of the round's ``[rows, T]``
+    prefill call (a power of two up to ``prefill_chunk``; 0 = no
+    call): the shape a call's device time is grouped by."""
     return {"decode_riders": 0, "decode_steps": 0, "backlog": 0,
             "decode_window_tokens": 0,
             "prefill_tokens": 0, "prefill_budget": 0,
-            "prefill_rows": 0, "prefill_window_tokens": 0}
+            "prefill_rows": 0, "prefill_window_tokens": 0,
+            "prefill_width": 0}
 
 
 class LLMEngine:
@@ -668,7 +671,6 @@ class LLMEngine:
                  batch_wait_timeout_s: float = 0.0,
                  sharding=None,
                  fault_injector=None,
-                 events: bool = True,
                  flight_dir: Optional[str] = None,
                  overlap: bool = True,
                  kv_dtype: Optional[str] = None,
@@ -896,13 +898,12 @@ class LLMEngine:
             self.capture_logprobs, self._mesh))
         # Typed lifecycle event log (serve/obs.py): lock-free bounded
         # ring recording every request phase and scheduler action.
-        # ``events=False`` is the A/B arm proving the log costs
-        # nothing measurable. ``sched_trace`` stays as a compat view
-        # rendering the four legacy dispatch-order tuple kinds.
-        self.events = obs.EventLog(8192, name="engine",
-                                   enabled=events)
-        self._obs_enabled = bool(events)
+        # ``sched_trace`` stays as a compat view rendering the four
+        # legacy dispatch-order tuple kinds.
+        self.events = obs.EventLog(8192, name="engine")
         self.sched_trace = obs.SchedTraceView(self.events)
+        # the collector's long passes become ``gc`` events here
+        obs.watch_gc(self)
         # Flight recorder sink: when set, EngineFault containment and
         # whole-engine failure dump a postmortem bundle here.
         self.flight_dir = flight_dir
@@ -951,28 +952,62 @@ class LLMEngine:
 
     # ------------------------------------------------- device trace
 
+    def _dispatch_counts_locked(self) -> Dict[str, int]:
+        """The round and how many times each step program has been
+        dispatched so far: what ``trace_start`` and ``trace_stop``
+        carry, so that the log says how many executions a trace holds."""
+        return {"round": self._round,
+                "prefills": self.stats["prefills"],
+                "chunks": self.stats["chunks"],
+                "verifies": self.stats["spec_rounds"]}
+
     def start_trace(self, log_dir: str) -> float:
-        """Start a device trace (``jax.profiler``) in this process and
-        mark it in the event log. The ``engine.*`` annotations of
-        step() carry the round number, and so do ``trace_start`` /
-        ``trace_stop``: the event log and the trace join by round,
-        without mapping clocks. One trace at a time (a second start
-        raises ``RuntimeError``). Returns time.monotonic() at the
-        start."""
+        """Start a device trace (``jax.profiler``) in this process, on
+        a round's edge with nothing in flight, and mark it in the event
+        log. It takes the engine's lock between two rounds, reads back
+        every dispatch still in flight and waits for the device, starts
+        the profiler and appends ``trace_start``; only then the next
+        round runs. So the trace's n-th execution of a step program IS
+        the n-th dispatch of that program in a round after
+        ``trace_start``'s ``round``: device executions join to rounds
+        by order, checked by the counts ``trace_start`` and
+        ``trace_stop`` carry (``round``, ``prefills``, ``chunks``,
+        ``verifies``: cumulative dispatches of ``jit_prefill``,
+        ``jit_decode``, ``jit_verify``) and by the trace's clock, which
+        the device planes share with the ``engine.*`` annotations on
+        the host plane (benchmarks/trace_dispatch.py). The chip idles
+        while the profiler starts: that gap lies before the trace's
+        first device operation; ``trace_start`` says what it cost
+        (``wait_s`` for the dispatches in flight, ``start_s`` for the
+        profiler). One trace at a time (a second start raises
+        ``RuntimeError``). Returns time.monotonic() at the start."""
         from ray_tpu._private import profiling
-        t0 = profiling.start_device_trace(log_dir)
-        self.events.append("trace_start", t=t0, data={
-            "round": self._round, "log_dir": log_dir})
+        with self._lock:
+            t_wait = time.monotonic()
+            self._drain_fetches_locked()
+            # the seed scatter and the pool trail the last readback
+            jax.block_until_ready(
+                (self.pages, self._dev_cur, self._dev_pos))
+            t0 = profiling.start_device_trace(log_dir)
+            self.events.append("trace_start", t=t0, data={
+                **self._dispatch_counts_locked(), "log_dir": log_dir,
+                "wait_s": round(t0 - t_wait, 6),
+                "start_s": round(time.monotonic() - t0, 6)})
         return t0
 
     def stop_trace(self):
         """Stop the trace ``start_trace`` began and write it out;
         returns the traced span (t0, t1) on time.monotonic().
-        ``RuntimeError`` when none is running."""
+        Nothing waits on the device's side: an execution the stop cuts
+        off is absent from the trace's tail. ``trace_stop`` carries the
+        counts of ``trace_start``, read between two rounds just before
+        the profiler stops. ``RuntimeError`` when none is running."""
         from ray_tpu._private import profiling
+        with self._lock:
+            counts = self._dispatch_counts_locked()
         t0, t1 = profiling.stop_device_trace()
         self.events.append("trace_stop", t=t1, data={
-            "round": self._round, "span_s": round(t1 - t0, 6)})
+            **counts, "span_s": round(t1 - t0, 6)})
         return t0, t1
 
     def _h2d(self, x):
@@ -1755,12 +1790,15 @@ class LLMEngine:
             self._hb = time.monotonic()   # progress heartbeat: a new
                                           # round means the previous
                                           # one completed
-            _pm = obs.phase_metrics() if self._obs_enabled else None
+            _pm = obs.phase_metrics()
             _t0 = self._hb
             # The engine.* TraceAnnotations below put this round's host
-            # phases on a device trace's host plane (same clock as the
-            # device planes), each with the round number; closed, with
-            # no trace running, one costs under a microsecond.
+            # phases on a device trace's host plane, each with the round
+            # number: they label the device's idle gaps, and an
+            # execution that start_trace's order joins to this round
+            # must start after its engine.dispatch_* opened (the planes
+            # share the trace's clock). Closed, with no trace running,
+            # one costs under a microsecond.
             _rnd = self._round
             self._round_info = _ri = _new_round_info()
             self._fire("step")     # global-fault site: escapes to
@@ -1803,6 +1841,7 @@ class LLMEngine:
                     # dispatch earlier. Never blocks.
                     self._drain_fetches_locked(ready_only=True)
             _ta = time.monotonic()
+            _ca = time.thread_time()
             _gap = _ta - _tg
             if self._pending_swap is not None:
                 # drain-mode weight swap: admission is paused; flip
@@ -1835,8 +1874,7 @@ class LLMEngine:
                 plan = self._plan_steps_locked()
             _tpe = time.monotonic()
             _gap += _tpe - _tp
-            if _pm is not None:
-                _pm["plan"].observe(_tpe - _tp)
+            _pm["plan"].observe(_tpe - _tp)
             try:
                 if plan.prefill:
                     with TraceAnnotation("engine.dispatch_prefill",
@@ -1868,14 +1906,15 @@ class LLMEngine:
                 self._contain_fault_locked(e)
                 return True
             _tde = time.monotonic()
-            if _pm is not None:
-                _pm["dispatch"].observe(_tde - _tpe)
+            _cpu = time.thread_time() - _ca
+            _pm["dispatch"].observe(_tde - _tpe)
             # trailing readback: block only on a dispatch OLDER than
             # the one just queued (keep=1), so the fetch round trip
             # overlaps the newest dispatch's compute — never its own
             with TraceAnnotation("engine.readback", round=_rnd):
                 self._drain_fetches_locked(limit=1, keep=1)
             _now = time.monotonic()
+            _cpu_rb = time.thread_time() - _ca - _cpu
             # Per-round pipeline accounting: host_gap is the time the
             # host spent GATING this round's dispatches (pre-plan
             # drain + plan) — the fraction of round wall during which
@@ -1888,8 +1927,12 @@ class LLMEngine:
             # The rest says what the round was: its number (the join
             # key with a device trace's engine.* annotations), where
             # the host's time went (admit/plan/dispatch wait for no
-            # device; readback_s is the trailing drain, which does),
-            # and what was dispatched against the planner's budget.
+            # device, and cpu_s is this thread's CPU time over the
+            # three: their wall less cpu_s is time the loop's thread
+            # did not run; readback_s is the trailing drain, which
+            # waits for the device, so of it readback_cpu_s is what
+            # the thread spent emitting and not waiting), and what
+            # was dispatched against the planner's budget.
             self.events.append("round", data={
                 "host_gap_s": round(_gap, 6),
                 "wall_s": round(_now - _t0, 6),
@@ -1899,10 +1942,11 @@ class LLMEngine:
                 "plan_s": round(_tpe - _tp, 6),
                 "dispatch_s": round(_tde - _tpe, 6),
                 "readback_s": round(_now - _tde, 6),
+                "cpu_s": round(_cpu, 6),
+                "readback_cpu_s": round(_cpu_rb, 6),
                 **_ri, **self._take_moe_info_locked()})
-            if _pm is not None:
-                _pm["round_wall"].observe(_now - _t0)
-                _pm["host_gap"].observe(_gap)
+            _pm["round_wall"].observe(_now - _t0)
+            _pm["host_gap"].observe(_gap)
             self._count_programs_locked(_now - _t0)
             return True
 
@@ -2364,7 +2408,7 @@ class LLMEngine:
                                t=_now,
                                data={"cached": start,
                                      "pages": len(slot.pages)})
-            if self._obs_enabled and not req.generated \
+            if not req.generated \
                     and not req.attempts and not req.preemptions:
                 # first admission only: re-admissions after
                 # preemption/fault would double-count the wait
@@ -3046,9 +3090,7 @@ class LLMEngine:
             self.events.append(
                 "readback",
                 data={"bufs": len(batch) + len(pend_pre)})
-            if self._obs_enabled:
-                obs.phase_metrics()["readback"].observe(
-                    self._hb - _t_rb)
+            obs.phase_metrics()["readback"].observe(self._hb - _t_rb)
             k = len(batch)
             # prefill firsts FIRST: a slot's seeding prefill always
             # precedes its first decode ride, and both can land in
@@ -3172,7 +3214,7 @@ class LLMEngine:
                                          "lane": (LANE_BATCH
                                                   if req.batch
                                                   else LANE_ONLINE)})
-                if self._obs_enabled and not req.batch:
+                if not req.batch:
                     obs.phase_metrics()["ttft"].observe(ttft)
             req.generated.append(t)
             req.out_q.put(t)
@@ -3193,8 +3235,7 @@ class LLMEngine:
             if req.t_last_emit is not None:
                 # mean gap per token over this readback batch
                 gap = max(0.0, _now - req.t_last_emit) / n_put
-                if self._obs_enabled:
-                    obs.phase_metrics()["inter_token"].observe(gap)
+                obs.phase_metrics()["inter_token"].observe(gap)
                 if not req.batch:
                     # online lane only, like the TTFT EWMA: batch
                     # streams run at whatever cadence the backlog
@@ -3298,6 +3339,7 @@ class LLMEngine:
         self.stats["prefills"] += 1
         self.stats["prefill_rows"] += len(rows)
         self._round_info["prefill_rows"] += len(rows)
+        self._round_info["prefill_width"] = T
         self._note_state_slots(len(rows))
         _granted = sum(take for _ix, _s, take in rows)
         self.stats["prefill_tokens"] += _granted

@@ -487,8 +487,10 @@ class LlamaDeployment:
         """Trace this replica's chip from outside: start
         ``jax.profiler`` in the process that holds it (the engine's
         ``start_trace``: device planes plus the ``engine.*`` host
-        annotations, ``trace_start`` in the event log with the round
-        number). Reachable through the serve handle
+        annotations; it starts between two rounds with nothing in
+        flight, and ``trace_start`` in the event log carries the round
+        and the dispatch counts that join the trace's executions to
+        rounds). Reachable through the serve handle
         (``handle.start_trace.remote(dir)``). One engine only: a pool
         of replicas shares the process and the profiler, so trace it
         with ``ray_tpu._private.profiling.start_device_trace``."""

@@ -34,17 +34,23 @@ watchdog, and the autoscaler, plus everything built on top of it:
   (queue_wait, plan, dispatch, readback, round wall, TTFT, inter-token)
   in ``util/metrics`` so the dashboard's ``/metrics`` endpoint exposes
   phase latency distributions.
+- ``watch_gc`` — the process's one ``gc.callbacks`` hook: a collector
+  pass of generation 2, or a longer one, becomes a ``gc`` event in
+  every watching engine's log and a ``host.gc`` span on a device
+  trace's host plane, so a slow round can say why.
 
 ``serve/scheduler.py`` stays device- and obs-free (its import whitelist
 is test-enforced); the engine times the planner call from outside.
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
 import time
 import warnings
+import weakref
 from typing import Any, Dict, Iterable, List, Optional
 
 # Event tuple layout: (seq, t, etype, rid, sid, data)
@@ -650,6 +656,70 @@ def phase_metrics() -> Dict[str, Any]:
                 boundaries=_PHASE_BOUNDS),
         }
     return _METRICS
+
+
+# ---------------------------------------------------- collector passes
+
+# a pass of generation 0 or 1 becomes an event from this length on; a
+# pass of generation 2 (the full one, which walks every container the
+# process holds) always does
+GC_MIN_S = 0.001
+
+
+class _GcWatch:
+    """What ``watch_gc`` appends to ``gc.callbacks``. The collector
+    calls it with "start" and "stop" around every pass, on the thread
+    that triggered the pass, and passes never nest: one open slot is
+    enough. Over each pass it holds ``TraceAnnotation("host.gc",
+    generation=n)`` open (under a microsecond with no trace running),
+    so that a device trace's idle gap can be labelled with it; a pass
+    of generation 2, or one of ``GC_MIN_S`` or longer, is appended to
+    every watching log as a ``gc`` event: ``generation``,
+    ``duration_s``, ``collected`` and the sink's ``round`` as the pass
+    ended, at the time the pass began."""
+
+    def __init__(self):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+        self.sinks: "weakref.WeakSet" = weakref.WeakSet()
+        self._open = None
+
+    def __call__(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            span = self._annotation("host.gc",
+                                    generation=info["generation"])
+            span.__enter__()
+            self._open = (time.monotonic(), span)
+            return
+        opened, self._open = self._open, None
+        if opened is None:        # installed in the middle of a pass
+            return
+        t0, span = opened
+        span.__exit__(None, None, None)
+        duration = time.monotonic() - t0
+        if info["generation"] < 2 and duration < GC_MIN_S:
+            return
+        for sink in list(self.sinks):
+            sink.events.append("gc", t=t0, data={
+                "generation": info["generation"],
+                "duration_s": round(duration, 6),
+                "collected": info.get("collected", 0),
+                "round": sink._round})
+
+
+_GC_WATCH: Optional[_GcWatch] = None
+
+
+def watch_gc(sink) -> None:
+    """Have ``sink`` (an engine: ``events`` is its ``EventLog``,
+    ``_round`` its round counter) told of the collector's passes; see
+    ``_GcWatch``. The hook is installed once a process, by the first
+    call; sinks are held weakly."""
+    global _GC_WATCH
+    if _GC_WATCH is None:
+        _GC_WATCH = _GcWatch()
+        gc.callbacks.append(_GC_WATCH)
+    _GC_WATCH.sinks.add(sink)
 
 
 def mint_trace_id() -> str:

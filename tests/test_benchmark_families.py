@@ -31,3 +31,60 @@ from benchmarks.tests.test_axk1_family import *       # noqa: E402,F401,F403
 # are checked where they were recorded, by `python -m pytest
 # benchmarks/tests`.
 del test_seeded_weights_are_the_parents_bit_for_bit    # noqa: F821
+
+
+# ------------------------------------------- PR 36's four dispatch readers
+
+# test_the_cell_and_doc_sat and test_the_cell_and_longdoc_sat pin their
+# cells' per-layer lists, and the latter BENCHMARK.json's last three
+# entries, as PR 34 left them, in files of the benchmark that a `tracing`
+# PR may not edit. PR 36 appended four readers of the joined table
+# (benchmarks/trace_dispatch.py) to BENCHMARK.json: here the two cases
+# run against the file less those four, and the case below pins the four.
+# (`python -m pytest benchmarks/tests` fails the two until a `benchmark`
+# PR updates their sets: PERF.md section 7.)
+_DISPATCH = ("dispatch_prefill_call_ms", "dispatch_decode_step_ms",
+             "dispatch_prefill_share", "dispatch_prefill_call_ms.open")
+_SAT = ["mistral7b-d16.chat-sat", "olmoe-d8.chat-sat",
+        "solar-open2-d4.doc-sat", "axk1-d5.longdoc-sat"]
+
+
+def _less_the_dispatch_readers(case):
+    def test(monkeypatch):
+        from benchmarks import common
+        bench = common.load_benchmark()
+        bench["per_layer"] = [m for m in bench["per_layer"]
+                              if m["name"] not in _DISPATCH]
+        monkeypatch.setattr(common, "load_benchmark", lambda: bench)
+        case()
+    test.__name__ = case.__name__
+    test.__doc__ = case.__doc__
+    return test
+
+
+test_the_cell_and_doc_sat = _less_the_dispatch_readers(
+    test_the_cell_and_doc_sat)                          # noqa: F821
+test_the_cell_and_longdoc_sat = _less_the_dispatch_readers(
+    test_the_cell_and_longdoc_sat)                      # noqa: F821
+
+
+@pytest.mark.parametrize("name", _DISPATCH)
+def test_dispatch_readers_are_appended_to_the_benchmark(name):
+    from benchmarks import common
+    bench = common.load_benchmark()
+    assert tuple(m["name"] for m in bench["per_layer"][-4:]) == _DISPATCH
+    m = common.find_named(bench["per_layer"], name, "metric")
+    want = {"name": name, "unit": "ms", "better": "lower",
+            "source": "device_trace", "layer": "model step",
+            "moves": "serve_tokens_per_s", "workloads": _SAT}
+    if name == "dispatch_prefill_share":
+        want["unit"] = "%"
+    if name.endswith(".open"):
+        want.update(moves="itl_p50_ms",
+                    workloads=["mistral7b-d16.chat-r80"])
+    assert m == want
+    # every listed cell reports the end-to-end metric it moves, and the
+    # reader loads by its name
+    moved = common.find_named(bench["end_to_end"], m["moves"], "metric")
+    assert set(m["workloads"]) <= set(moved["workloads"])
+    assert callable(common.load_metric_reader(name))

@@ -291,6 +291,174 @@ def test_module_control_refuses_and_recovers(tmp_path):
         profiling.stop_device_trace()
 
 
+# ------------------------- a trace starts on a round's edge, by counts
+
+@pytest.fixture(scope="module")
+def edge(tmp_path_factory):
+    """A trace started and stopped in the middle of a busy engine's
+    work: what was in flight when the profiler started, the marks, the
+    rounds and dispatch events between them, and the host plane."""
+    import gc
+    from jax.profiler import ProfileData
+    log_dir = str(tmp_path_factory.mktemp("edge"))
+    eng = _engine(233)
+    facts = {}
+    real = profiling.start_device_trace
+
+    def start(path):
+        # called by start_trace, which must hold the engine's lock
+        facts["at_start"] = {
+            "locked": eng._lock.locked(),
+            "fetchq": len(eng._fetchq),
+            "pending_prefill": len(eng._pending_prefill),
+            "ready": all(b.is_ready() for b in
+                         (eng._dev_cur, eng._dev_pos))}
+        return real(path)
+    try:
+        eng.submit([1, 2, 3], max_new_tokens=4).result()      # warm
+        hs = [eng.submit(list(range(1, 6 + 3 * i)), max_new_tokens=60)
+              for i in range(12)]
+        while eng.stats["chunks"] < 3:                 # well under way
+            time.sleep(0.001)
+        profiling.start_device_trace = start
+        try:
+            eng.start_trace(log_dir)
+        finally:
+            profiling.start_device_trace = real
+        hs += [eng.submit(list(range(1, 5 + 4 * i)), max_new_tokens=20)
+               for i in range(4)]
+        gc.collect()                       # a full pass, inside the trace
+        for h in hs:
+            h.result()
+        eng.stop_trace()
+    finally:
+        eng.shutdown()
+    evs = eng.events.snapshot()
+    facts["events"] = evs
+    facts["marks"] = [e[5] for e in evs
+                      if e[2] in ("trace_start", "trace_stop")]
+    facts["gc"] = [e for e in evs if e[2] == "gc"]
+    names = set()
+    files = glob.glob(log_dir + "/plugins/profile/*/*.xplane.pb")
+    for plane in ProfileData.from_file(files[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names |= {ev.name for ev in line.events}
+    facts["host_names"] = names
+    return facts
+
+
+@pytest.mark.parametrize("case", [
+    "nothing_in_flight", "counts_add_up", "start_says_its_cost",
+    "prefill_width_is_the_calls_T", "cpu_within_wall",
+    "gc_pass_is_an_event", "gc_pass_is_a_span",
+    "gc_hook_installed_once", "the_log_joins"])
+def test_trace_starts_on_a_rounds_edge(edge, case):
+    from ray_tpu.serve import obs
+    start, stop = edge["marks"]
+    rounds = [(e[5], e[1]) for e in edge["events"] if e[2] == "round"]
+    if case == "nothing_in_flight":
+        assert edge["at_start"] == {"locked": True, "fetchq": 0,
+                                    "pending_prefill": 0, "ready": True}
+    elif case == "counts_add_up":
+        # the marks' cumulative counts differ by exactly what the rounds
+        # between them dispatched: the log says how many executions the
+        # trace may hold
+        between = [d for d, _t in rounds
+                   if start["round"] < d["round"] <= stop["round"]]
+        assert len(between) >= 2
+        assert (start["prefills"]
+                + sum(1 for d in between if d["prefill_rows"])
+                == stop["prefills"])
+        decodes = decoded = 0
+        for e in edge["events"]:
+            if e[2] == "decode":
+                decoded = 1
+            elif e[2] == "round":
+                if start["round"] < e[5]["round"] <= stop["round"]:
+                    decodes += decoded
+                decoded = 0
+        assert start["chunks"] + decodes == stop["chunks"]
+        assert start["verifies"] == stop["verifies"] == 0
+        assert stop["chunks"] > start["chunks"]
+    elif case == "start_says_its_cost":
+        assert start["wait_s"] >= 0 and start["start_s"] >= 0
+        assert "span_s" in stop
+    elif case == "prefill_width_is_the_calls_T":
+        since, seen = [], set()
+        for e in edge["events"]:
+            if e[2] == "prefill":
+                since.append(max(take for _sid, take in e[5]))
+            elif e[2] == "round":
+                want = 0
+                if since:
+                    want = 8                    # page_size: the floor
+                    while want < since[0]:
+                        want *= 2
+                    want = min(want, 16)        # prefill_chunk
+                assert e[5]["prefill_width"] == want
+                seen.add(want)
+                since = []
+        assert {0, 8, 16} <= seen
+    elif case == "cpu_within_wall":
+        for d, _t in rounds:
+            assert 0 <= d["cpu_s"] <= d["wall_s"] + 2e-3
+            assert 0 <= d["readback_cpu_s"]
+            assert d["cpu_s"] + d["readback_cpu_s"] <= d["wall_s"] + 2e-3
+    elif case == "gc_pass_is_an_event":
+        full = [e[5] for e in edge["gc"] if e[5]["generation"] == 2]
+        assert all(d["duration_s"] > 0 for d in full)
+        # the one the fixture forced after the trace's start
+        assert any(d["round"] >= start["round"] for d in full)
+        # shorter passes of the young generations are not events
+        assert all(e[5]["generation"] == 2
+                   or e[5]["duration_s"] >= obs.GC_MIN_S
+                   for e in edge["gc"])
+    elif case == "gc_pass_is_a_span":
+        assert "host.gc" in edge["host_names"]
+    elif case == "gc_hook_installed_once":
+        import gc
+        assert gc.callbacks.count(obs._GC_WATCH) == 1
+    else:
+        # benchmarks/trace_dispatch.py reads this engine's log as it
+        # stands: one execution a dispatch, in order, reconciles with
+        # the marks' counts, and every row says what its round made
+        from benchmarks import trace_dispatch as td
+        made = [d for d in td.rounds_of(edge["events"])
+                if d["round"] > start["round"]]
+        execs, t = [], 1000
+        for d in made:
+            for prog in d["programs"]:
+                execs.append((prog, t, 500))
+                t += 1000
+        # (the chip's last execution is left out as cut by the stop)
+        last = [("jit_seed", t + 1000, 500)]
+        got = td.join(execs + last, td.rounds_of(edge["events"]),
+                      *td.marks_of(edge["events"]))
+        assert got is not None and len(got["rows"]) == len(execs) > 4
+        assert not any(got["tail"].values())
+        by = {d["round"]: d for d in made}
+        for r in got["rows"]:
+            d = by[r["round"]]
+            if r["program"] == "jit_prefill":
+                assert (r["rows"], r["width"]) == (d["prefill_rows"],
+                                                   d["prefill_width"])
+            else:
+                assert (r["steps"], r["riders"]) == (d["decode_steps"],
+                                                     d["decode_riders"])
+        # an execution more than the log has dispatches: refused
+        assert td.join(execs + [("jit_decode", t, 500)] + last,
+                       td.rounds_of(edge["events"]),
+                       *td.marks_of(edge["events"])) is None
+
+
+def test_engine_takes_no_events_argument():
+    """``LLMEngine(events=)`` was the A/B arm of a benchmark deleted in
+    PR 30: the log is always on."""
+    import inspect
+    assert "events" not in inspect.signature(LLMEngine.__init__).parameters
+
+
 # ---------------------------------- the programs' names are an interface
 
 @pytest.mark.parametrize("program", ["jit_decode", "jit_prefill",

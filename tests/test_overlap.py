@@ -389,8 +389,7 @@ def test_round_events_and_histogram_crosscheck(tiny_model):
     model, params = tiny_model
     metrics.clear_registry()
     eng = LLMEngine(model, params, max_slots=2, page_size=8,
-                    n_pages=64, chunk=4, eos_id=-1, overlap=True,
-                    events=True)
+                    n_pages=64, chunk=4, eos_id=-1, overlap=True)
     _run(eng, [[5, 9, 2, 7], [1, 8, 3]], 16)
     rounds = [e for e in eng.events.snapshot() if e[2] == "round"]
     assert rounds, "no round events recorded"

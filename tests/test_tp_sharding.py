@@ -118,29 +118,49 @@ class _Scripted:
         return self.script[self._done:self._done + k]
 
 
-def test_spec_decode_accept_parity(tiny, tp4):
+SPEC_KEYS = ("spec_accepted", "spec_rejected", "spec_proposed")
+
+
+def _spec_run(tiny, sh, prompt, overlap, proposer=None):
+    """One request through a speculating engine: its tokens and the
+    accept counters. The counters are read after ``shutdown()``, when
+    the loop's thread has left its last round."""
+    eng = _engine(tiny, sh, spec_len=4, spec_proposer=proposer,
+                  overlap=overlap)
+    out = eng.submit(prompt, max_new_tokens=16).result()
+    eng.shutdown()
+    return out, {k: eng.stats.get(k, 0) for k in SPEC_KEYS}
+
+
+# Under the overlapped loop the proposer reads the STALE frontier
+# (``req.generated`` as far as the readbacks have come when the round is
+# planned: serve/engine.py ``_propose_spec_locked``), so WHICH rounds
+# speculate, and with them the counters, follow how fast the device
+# finished the dispatch before: a race by design, which changes no
+# token. The counters are compared under the lockstep loop, which
+# drains before it plans; the tokens under both.
+@pytest.mark.parametrize("overlap", [False, True],
+                         ids=["lockstep", "overlapped"])
+def test_spec_decode_accept_parity(tiny, tp4, overlap):
     """Repetitive prompt: prompt-lookup drafts get accepted. The
-    verify argmax runs through the sharded psum path; accept counters
-    must agree exactly across tp widths."""
+    verify argmax runs through the sharded psum path; the tokens agree
+    exactly across tp widths, and so do the accept counters wherever
+    the schedule is the same (the lockstep loop)."""
     rep = ([5, 6, 7, 8] * 8)[:24]
-
-    def run(sh):
-        eng = _engine(tiny, sh, spec_len=4)
-        outs = [eng.submit(rep, max_new_tokens=16).result()]
-        stats = {k: eng.stats.get(k, 0)
-                 for k in ("spec_accepted", "spec_rejected",
-                           "spec_proposed")}
-        eng.shutdown()
-        return outs, stats
-
-    base, base_stats = run(None)
-    tp, tp_stats = run(tp4)
+    base, base_stats = _spec_run(tiny, None, rep, overlap)
+    tp, tp_stats = _spec_run(tiny, tp4, rep, overlap)
     assert base == tp
-    assert base_stats == tp_stats
-    assert base_stats["spec_accepted"] >= 1
+    for st in (base_stats, tp_stats):
+        assert (st["spec_accepted"] + st["spec_rejected"]
+                == st["spec_proposed"])
+    if not overlap:
+        assert base_stats == tp_stats
+        assert base_stats["spec_accepted"] >= 1
 
 
-def test_spec_decode_full_rejection_rollback_parity(tiny, tp4):
+@pytest.mark.parametrize("overlap", [False, True],
+                         ids=["lockstep", "overlapped"])
+def test_spec_decode_full_rejection_rollback_parity(tiny, tp4, overlap):
     """Anti-oracle proposer: every draft is guaranteed wrong, so
     every verify rejects everything and clamps the KV write frontier
     back. Under tp=4 the rollback is a host-side position clamp over
@@ -149,26 +169,24 @@ def test_spec_decode_full_rejection_rollback_parity(tiny, tp4):
     engine."""
     cfg = tiny[0]
     prompt = [5, 9, 2, 7, 11]
-
-    def run(sh, proposer):
-        eng = _engine(tiny, sh, spec_len=4, spec_proposer=proposer)
-        out = eng.submit(prompt, max_new_tokens=16).result()
-        stats = {k: eng.stats.get(k, 0)
-                 for k in ("spec_accepted", "spec_rejected",
-                           "spec_proposed")}
-        eng.shutdown()
-        return out, stats
-
-    ref, _ = run(None, None)   # n-gram default, plain reference
+    # n-gram default, plain reference
+    ref, _ = _spec_run(tiny, None, prompt, overlap)
     wrong = [(t + 1) % cfg.vocab_size for t in ref]
-    base, base_stats = run(
-        None, lambda: _Scripted(len(prompt), wrong))
-    tp, tp_stats = run(tp4, lambda: _Scripted(len(prompt), wrong))
+
+    def scripted():
+        return _Scripted(len(prompt), wrong)
+    base, base_stats = _spec_run(tiny, None, prompt, overlap, scripted)
+    tp, tp_stats = _spec_run(tiny, tp4, prompt, overlap, scripted)
     assert base == ref         # rollback preserved greedy output
     assert tp == ref
-    assert base_stats == tp_stats
-    assert base_stats["spec_rejected"] >= 4
-    assert base_stats["spec_accepted"] == 0
+    for st in (base_stats, tp_stats):
+        assert (st["spec_accepted"] + st["spec_rejected"]
+                == st["spec_proposed"])
+    if not overlap:
+        # (a script read at a stale position may propose a right token)
+        assert base_stats == tp_stats
+        assert base_stats["spec_rejected"] >= 4
+        assert base_stats["spec_accepted"] == 0
 
 
 def test_mixtral_expert_parallel_parity(cpu_mesh_devices):
