@@ -82,11 +82,13 @@ def multi_head_attention(q, k, v, causal: bool = True,
                          impl: str = "auto",
                          bias: Optional[jax.Array] = None) -> jax.Array:
     if impl == "auto":
-        # Measured on v5e (fwd+bwd, H=12 D=64): at T=1024 the pallas
-        # kernel wins for B>=8 (B=24: 43.2% vs 34.3% MFU — XLA's
-        # [B,H,T,T] scores are pure HBM traffic in the backward); tiny
-        # batches favor XLA. At T>=2048 flash always wins and at
-        # T>=8192 it is the only option (scores exhaust HBM).
+        # Measured on v5e (PR 37, tools/flash_bench.py --shape, causal
+        # fwd+bwd, H=12 D=64, ms flash | xla): at T=1024 B=24 3.30 |
+        # 9.90 (XLA's [B,H,T,T] scores are pure HBM traffic in the
+        # backward), B=8 1.06 | 3.36, B=4 0.60 | 0.88, B=2 and B=1 level
+        # at the 0.55 ms a dispatch costs; T=2048 B=4 2.31 | 6.14, B=1
+        # 0.68 | 0.86. At T>=8192 flash is the only option (scores
+        # exhaust HBM).
         T, B = q.shape[1], q.shape[0]
         impl = "flash" if (_on_tpu() and bias is None and
                            T % 128 == 0 and

@@ -57,23 +57,30 @@ def _compile(fn, one_chip, *shapes):
     return compiled
 
 
-# (batch, seq, heads, head_dim): GPT-2-124M train batch and
-# TinyLlama-1.1B train batch (bench.py)
-@pytest.mark.parametrize("B,T,H,D", [(24, 1024, 12, 64),
-                                     (8, 1024, 32, 64)])
+# (batch, seq, heads, head_dim, Pallas calls): GPT-2-124M train batch
+# and TinyLlama-1.1B train batch (bench.py), both down the one-pass
+# backward (forward + one backward call); a long sequence, which the
+# tile plan sends down the two-pass route on a grid of blocks (forward,
+# dq, dk/dv); heads of 128, one a program
+@pytest.mark.parametrize("B,T,H,D,calls", [(24, 1024, 12, 64, 2),
+                                           (8, 1024, 32, 64, 2),
+                                           (1, 8192, 12, 64, 3),
+                                           (8, 1024, 8, 128, 2)])
 def test_flash_attention_fwd_bwd_compiles(one_chip, monkeypatch,
-                                          B, T, H, D):
+                                          B, T, H, D, calls):
     # the kernel picks interpret mode from the attached backend, which
     # is the CPU here; steer it to the compiled path for this compile
     monkeypatch.setattr(flash_mod, "_interpret", lambda: False)
+    assert flash_mod.tile_plan(T, T, D, True).one_pass == (calls == 2)
 
     def loss(q, k, v):
         return flash_mod.flash_attention(q, k, v, causal=True).astype(
             jnp.float32).sum()
 
     qkv = [((B, T, H, D), jnp.bfloat16)] * 3
-    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
-             *qkv)
+    compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                        one_chip, *qkv)
+    assert compiled.as_text().count("tpu_custom_call") == calls
 
 
 # (sorted pairs, what calls with them): OLMoE-1B-7B's experts (64 of

@@ -6,6 +6,9 @@ and achieved attention FLOP/s of the pallas kernel against the plain
 XLA softmax(QK^T)V path across sequence lengths, plus the longest
 sequence each path can run at all (the XLA path materializes the
 [T, T] score matrix; flash never does). Writes FLASH_r05.json on TPU.
+
+``--shape B,T,H,D`` times both paths at that one shape instead (the
+train cell's attention is 24,1024,12,64) and writes nothing.
 """
 from __future__ import annotations
 
@@ -59,6 +62,12 @@ def main():
     import jax
     dev = jax.devices()[0]
     on_tpu = dev.platform == "tpu"
+    if sys.argv[1:2] == ["--shape"]:
+        B, T, H, D = (int(x) for x in sys.argv[2].split(","))
+        for impl in ("xla", "flash"):
+            print(json.dumps({"shape": [B, T, H, D], "impl": impl,
+                              **bench_one(impl, B, H, T, D)}))
+        return
     B, H, D = 4, 8, 64
     seqs = [1024, 2048, 4096, 8192] if on_tpu else [128]
     out = {"device": getattr(dev, "device_kind", "cpu"),
