@@ -340,6 +340,7 @@ class LocalRuntime:
         self._cancelled: set = set()
         self._tasks_by_id: Dict[TaskID, TaskSpec] = {}
         self._task_states: Dict[TaskID, str] = {}
+        self._done_tasks: collections.deque = collections.deque()
         self._lineage: Dict[ObjectID, TaskSpec] = {}
         self._lineage_bytes = 0
         self._pgs: Dict[PlacementGroupID, PlacementGroup] = {}
@@ -424,6 +425,24 @@ class LocalRuntime:
             [r.id for r in refs], num_returns, timeout)
         return ([id_map[i] for i in ready_ids],
                 [id_map[i] for i in rest_ids])
+
+    # Finished tasks kept for the state API: a bounded ring, as the
+    # multiprocess head keeps (runtime/head.py ``_DONE_TASKS_CAP``).
+    # Every task a process ever ran used to stay in the table, and a
+    # serve handle polls a stream's chunks with one actor task a poll:
+    # 565 a second behind 128 slots, six containers each, all of them
+    # walked by every full pass of the collector (PERF.md section 6,
+    # PR 39).
+    _DONE_TASKS_CAP = 2000
+
+    def _task_done(self, task_id: TaskID, state: str) -> None:
+        with self._lock:
+            self._task_states[task_id] = state
+            self._done_tasks.append(task_id)
+            while len(self._done_tasks) > self._DONE_TASKS_CAP:
+                old = self._done_tasks.popleft()
+                self._tasks_by_id.pop(old, None)
+                self._task_states.pop(old, None)
 
     def _on_object_released(self, oid: ObjectID):
         # Out-of-scope objects are evicted (distributed GC capability).
@@ -544,10 +563,10 @@ class LocalRuntime:
                     execution_span(spec.name, "task", spec.trace_ctx):
                 result = spec.func(*args, **kwargs)
             self._store_returns(spec, result)
-            self._task_states[spec.task_id] = "FINISHED"
+            self._task_done(spec.task_id, "FINISHED")
         except TaskCancelledError as e:
             self._store_error(spec, e, wrap=False)
-            self._task_states[spec.task_id] = "CANCELLED"
+            self._task_done(spec.task_id, "CANCELLED")
         except BaseException as e:  # noqa: BLE001
             self._handle_task_failure(spec, e)
         finally:
@@ -589,7 +608,7 @@ class LocalRuntime:
             threading.Thread(target=_resubmit, daemon=True).start()
         else:
             self._store_error(spec, exc)
-            self._task_states[spec.task_id] = "FAILED"
+            self._task_done(spec.task_id, "FAILED")
 
     def _put_return(self, oid: ObjectID, value: Any,
                     is_exception: bool = False):
@@ -763,7 +782,7 @@ class LocalRuntime:
                                    spec.trace_ctx):
                 result = method(*args, **kwargs)
             self._store_returns(spec, result)
-            self._task_states[spec.task_id] = "FINISHED"
+            self._task_done(spec.task_id, "FINISHED")
         except BaseException as e:  # noqa: BLE001
             self._handle_actor_task_failure(st, spec, e)
         finally:
@@ -784,7 +803,7 @@ class LocalRuntime:
                 if inspect.isawaitable(result):
                     result = await result
             self._store_returns(spec, result)
-            self._task_states[spec.task_id] = "FINISHED"
+            self._task_done(spec.task_id, "FINISHED")
         except BaseException as e:  # noqa: BLE001
             self._handle_actor_task_failure(st, spec, e)
         finally:
@@ -803,7 +822,7 @@ class LocalRuntime:
                 st.submit(spec, self)
                 return
         self._store_error(spec, exc)
-        self._task_states[spec.task_id] = "FAILED"
+        self._task_done(spec.task_id, "FAILED")
 
     def kill_actor(self, actor_id: ActorID, no_restart: bool = True):
         """Kill an actor. With no_restart=False this models a *crash* —

@@ -41,7 +41,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.kv_cache import KIND_LATENT, PagedKVLayer
+from ray_tpu.models.kv_cache import (KIND_LATENT, PagedKVLayer,
+                                     live_rows)
 from ray_tpu.models.llama import (LlamaMLP, RMSNorm, block_forward,
                                   transformer_forward)
 from ray_tpu.models.mixtral import MoEFeedForward
@@ -60,11 +61,15 @@ class AXK1Config:
     dim: int = 7168
     n_layers: int = 61
     n_heads: int = 64
-    q_lora_rank: int = 1536
+    # None: no low-rank query, one direct W_q (models/kimi_linear.py)
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # False: NO position encoding (NoPE, models/kimi_linear.py): the
+    # decoupled columns stay, unrotated, as a key every head shares
+    mla_rope: bool = True
     rope_theta: float = 10000.0
     # YaRN (``rope_scaling``)
     rope_factor: float = 32.0
@@ -186,8 +191,13 @@ class MLAttention(nn.Module):
     (the low-rank query: W_qa, its norm, W_qb, rope), ``mla_kv`` (the
     latent entry: W_kva, its norm, rope), ``mla_absorb`` (the key
     up-projection folded into the query and the value up-projection of
-    the read-out), beside ``kv_append`` and the block loop's own."""
-    config: AXK1Config
+    the read-out), beside ``kv_append`` and the block loop's own.
+
+    ``config`` is an ``AXK1Config`` or any config with its attention
+    fields (models/kimi_linear.py's: ``q_lora_rank`` None gives the
+    query one direct matrix ``wq``, ``mla_rope`` false leaves the
+    decoupled columns of query and key unrotated)."""
+    config: Any
 
     @nn.compact
     def __call__(self, x, freqs, positions, kv_cache=None,
@@ -200,22 +210,30 @@ class MLAttention(nn.Module):
         dense = functools.partial(nn.Dense, use_bias=False,
                                   dtype=cfg.dtype,
                                   param_dtype=cfg.param_dtype)
-        inv_freq = yarn_inv_freq(dr, cfg.rope_theta, cfg.rope_factor,
-                                 cfg.rope_original_max_seq_len,
-                                 cfg.rope_beta_fast, cfg.rope_beta_slow)
-        mscale = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
-                  / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+        if cfg.mla_rope:
+            inv_freq = yarn_inv_freq(dr, cfg.rope_theta, cfg.rope_factor,
+                                     cfg.rope_original_max_seq_len,
+                                     cfg.rope_beta_fast, cfg.rope_beta_slow)
+            mscale = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+                      / yarn_mscale(cfg.rope_factor,
+                                    cfg.rope_mscale_all_dim))
+            rope = functools.partial(_rope, inv_freq=inv_freq,
+                                     positions=positions, mscale=mscale)
+        else:
+            rope = lambda x: x                          # noqa: E731
         with jax.named_scope("mla_q"):
-            c_q = RMSNorm(cfg.norm_eps, name="q_norm")(
-                dense(cfg.q_lora_rank, name="wq_a")(x))
-            q = dense(H * (dn + dr), name="wq_b")(c_q).reshape(
-                B, T, H, dn + dr)
-            q_nope, q_rope = q[..., :dn], q[..., dn:]
-            q_rope = _rope(q_rope, inv_freq, positions, mscale)
+            if cfg.q_lora_rank is None:
+                q = dense(H * (dn + dr), name="wq")(x)
+            else:
+                c_q = RMSNorm(cfg.norm_eps, name="q_norm")(
+                    dense(cfg.q_lora_rank, name="wq_a")(x))
+                q = dense(H * (dn + dr), name="wq_b")(c_q)
+            q = q.reshape(B, T, H, dn + dr)
+            q_nope, q_rope = q[..., :dn], rope(q[..., dn:])
         with jax.named_scope("mla_kv"):
             kv = dense(R + dr, name="wkv_a")(x)
             c = RMSNorm(cfg.norm_eps, name="kv_norm")(kv[..., :R])
-            k_rope = _rope(kv[..., None, R:], inv_freq, positions, mscale)
+            k_rope = rope(kv[..., None, R:])
         # W_kvb [R, H, dn + dv]: head i's key up-projection W_UK^i
         # (its first dn columns) and value up-projection W_UV^i
         w_kvb = self.param("wkv_b", nn.initializers.lecun_normal(),
@@ -297,11 +315,7 @@ class AXK1DenseBlock(_Block):
 class AXK1MoEBlock(_Block):
     def feed_forward(self, kv_cache):
         moe = MoEFeedForward(self.config, name="moe")
-        live = None
-        if isinstance(kv_cache, PagedKVLayer):
-            # a row whose page-table row is the null row carries no
-            # request (models/mixtral.py MixtralBlock's rule)
-            live = kv_cache.page_table[:, 0] != 0
+        live = live_rows(kv_cache)
         return lambda h: moe(h, live)
 
 
@@ -321,11 +335,16 @@ class AXK1(nn.Module):
             input_ids, kv_caches, cache_len, rope=False)
 
 
-def mla_param_count(cfg: AXK1Config) -> int:
-    """One layer's latent attention: five matrices and two norms."""
+def mla_param_count(cfg) -> int:
+    """One layer's latent attention: five matrices and two norms, or
+    with a direct query (``q_lora_rank`` None) four and one."""
     H = cfg.n_heads
-    return (cfg.dim * cfg.q_lora_rank + cfg.q_lora_rank
-            + cfg.q_lora_rank * H * cfg.qk_head_dim
+    if cfg.q_lora_rank is None:
+        query = cfg.dim * H * cfg.qk_head_dim
+    else:
+        query = (cfg.dim * cfg.q_lora_rank + cfg.q_lora_rank
+                 + cfg.q_lora_rank * H * cfg.qk_head_dim)
+    return (query
             + cfg.dim * cfg.latent_dim + cfg.kv_lora_rank
             + cfg.kv_lora_rank * H * (cfg.qk_nope_head_dim + cfg.v_head_dim)
             + H * cfg.v_head_dim * cfg.dim)

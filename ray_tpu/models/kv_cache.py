@@ -250,6 +250,19 @@ def kv_layer_view(layer, page_table: jnp.ndarray, slots=None,
     return PagedKVLayer(pk, pv, page_table, sk, sv)
 
 
+def live_rows(kv_cache):
+    """[B] bool, the rows of a paged call that carry a request, as
+    their layer's view shows it: a paged layer's row whose page-table
+    row is not the null row, a recurrent layer's row whose first
+    position is real. None without a paged cache (every row is live).
+    A mixture gives the other rows no expert."""
+    if isinstance(kv_cache, PagedKVLayer):
+        return kv_cache.page_table[:, 0] != 0
+    if isinstance(kv_cache, RecurrentStateView):
+        return kv_cache.valid[:, 0]
+    return None
+
+
 def kv_layer_store(cache: PagedKVLayer):
     """Inverse of kv_layer_view: the storage entry (without the
     call's page table, slots and valid positions) the engine carries

@@ -38,8 +38,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.kv_cache import (KIND_KV, KIND_RECURRENT, PagedKVLayer,
-                                     RecurrentStateView)
+from ray_tpu.models.kv_cache import (KIND_KV, KIND_RECURRENT,
+                                     RecurrentStateView, live_rows)
 from ray_tpu.models.llama import (LlamaAttention, RMSNorm, block_forward,
                                   transformer_forward)
 from ray_tpu.models.mixtral import MoEFeedForward
@@ -64,6 +64,9 @@ class SolarOpen2Config:
     kda_heads: int = 64            # KDA (its keys' and values' heads)
     kda_head_dim: int = 128
     conv_size: int = 4
+    # beta = 2 sigmoid(.) in (0, 2), so that a step's transition may
+    # have negative eigenvalues; False: beta = sigmoid(.)
+    kda_allow_neg_eigval: bool = True
     hidden_dim: int = 1280
     num_experts: int = 320
     num_experts_per_tok: int = 8
@@ -138,8 +141,11 @@ class KDAAttention(nn.Module):
     else. A row whose ``cache_len`` is 0 and whose first position is
     real STARTS A REQUEST: it begins from zeros, whatever its slot held
     (the engine never clears a slot). Positions that are not real move
-    neither the state nor the tail."""
-    config: SolarOpen2Config
+    neither the state nor the tail.
+
+    ``config`` is a ``SolarOpen2Config`` or any config with its KDA
+    fields (models/kimi_linear.py's)."""
+    config: Any
 
     @nn.compact
     def __call__(self, x, freqs, positions, kv_cache=None,
@@ -195,7 +201,9 @@ class KDAAttention(nn.Module):
             f = dense(C, name="f_b")(dense(d, name="f_a")(x))
             g = -jnp.exp(decay_log)[:, None] * jax.nn.softplus(
                 (f.astype(f32) + dt_bias).reshape(B, T, H, d))
-            beta = 2.0 * jax.nn.sigmoid(dense(H, name="wb")(x).astype(f32))
+            beta = jax.nn.sigmoid(dense(H, name="wb")(x).astype(f32))
+            if cfg.kda_allow_neg_eigval:
+                beta = 2.0 * beta
         with jax.named_scope("kda_recurrence"):
             if T == 1:
                 o, state = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
@@ -231,13 +239,7 @@ class _Block(nn.Module):
                  cache_len=None):
         cfg = self.config
         moe = MoEFeedForward(cfg, name="moe")
-        live = None
-        if isinstance(kv_cache, PagedKVLayer):
-            # a row whose page-table row is the null row carries no
-            # request (models/mixtral.py MixtralBlock's rule)
-            live = kv_cache.page_table[:, 0] != 0
-        elif isinstance(kv_cache, RecurrentStateView):
-            live = kv_cache.valid[:, 0]
+        live = live_rows(kv_cache)
         return block_forward(cfg, self.attention(), lambda h: moe(h, live),
                              x, freqs, positions, kv_cache, cache_len)
 

@@ -66,7 +66,16 @@ a third kind: one pool a layer of one latent entry a token, handed
 out, shared and rewound by page id and offset exactly as K/V pages
 are; what interprets, ships or shards a page's payload (int8 pages,
 KV export/pull, tensor sharding) is refused for it
-(``refuse_for_latent_pages``).
+(``refuse_for_latent_pages``). A model of recurrent and latent layers
+ONLY (models/kimi_linear.py: no K/V layer at all) is served by the same
+pool and stands on both refusal lists at once. There a slot costs a
+fixed ``state_bytes_per_slot`` whatever its context and a token only a
+latent entry in the few layers that have pages, so the deployment is
+sized slots first: ``max_slots`` by the state's bytes (and by the
+decode step, which moves every slot's state), ``n_pages`` then so
+generously that pages never bound the slots (docs/serving.md, "Sizing
+``max_slots`` and ``n_pages`` when state, not pages, bounds the
+slots").
 """
 from __future__ import annotations
 
@@ -528,9 +537,14 @@ def _new_round_info() -> Dict[str, int]:
     ``BACKLOG_DECODE_STEPS`` (``stats["backlog_rounds"]`` counts those
     rounds). ``prefill_width`` is the ``T`` of the round's ``[rows, T]``
     prefill call (a power of two up to ``prefill_chunk``; 0 = no
-    call): the shape a call's device time is grouped by."""
+    call): the shape a call's device time is grouped by.
+    ``decode_context_tokens`` is the sum over the decode dispatch's
+    riders of their OWN context lengths after it (what each rider's
+    last step attended), where ``decode_window_tokens`` is the longest
+    rider's, rounded up to a block: the tokens a paged attention MUST
+    read, beside those its block loop does."""
     return {"decode_riders": 0, "decode_steps": 0, "backlog": 0,
-            "decode_window_tokens": 0,
+            "decode_window_tokens": 0, "decode_context_tokens": 0,
             "prefill_tokens": 0, "prefill_budget": 0,
             "prefill_rows": 0, "prefill_window_tokens": 0,
             "prefill_width": 0}
@@ -2788,6 +2802,15 @@ class LLMEngine:
         self._round_info[key] = max(self._round_info[key], window)
         self.stats[key] += window
 
+    def _note_decode_contexts(self, ends) -> None:
+        """Record the sum of the riders' own context lengths when each
+        rider's last query of a decode (or verify) dispatch sits at its
+        ``end - 1``: the ``round`` event's and the stats'
+        ``decode_context_tokens``, from the host's positions."""
+        total = int(sum(ends))
+        self._round_info["decode_context_tokens"] += total
+        self.stats["decode_context_tokens"] += total
+
     def _note_state_slots(self, n: int) -> None:
         """``n`` slots' recurrent state was advanced by a dispatch (a
         prefill call's rows, a decode call's riders): the ``round``
@@ -2841,6 +2864,7 @@ class LLMEngine:
         # step attends (the program widens it step by step)
         self._note_window("decode_window_tokens",
                           max(slot.pos for _i, slot, _t in riders))
+        self._note_decode_contexts(slot.pos for _i, slot, _t in riders)
         self.events.append("decode", data=steps)
         self.stats["chunks"] += 1
         self.stats["decode_steps"] += steps
@@ -2958,6 +2982,7 @@ class LLMEngine:
         self._round_info["decode_steps"] = 1   # one verify forward
         self._note_window("decode_window_tokens",
                           max(slot.pos for _i, slot, _d in rows) + T)
+        self._note_decode_contexts(slot.pos + T for _i, slot, _d in rows)
         m = spec_decode.metrics()
         self.stats["spec_rounds"] += 1
         # surviving slots' device decode state is reseeded with the

@@ -20,10 +20,14 @@ def _family_for(cfg):
     from ray_tpu.models.mixtral import (Mixtral, MixtralConfig,
                                         mixtral_sharding_rules)
     from ray_tpu.models.axk1 import AXK1, AXK1Config
+    from ray_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig
     from ray_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
     if isinstance(cfg, AXK1Config):
         # no partition rules yet: the deployment refuses to shard it
         return AXK1, None
+    if isinstance(cfg, KimiLinearConfig):
+        # no partition rules yet: the deployment refuses to shard it
+        return KimiLinear, None
     if isinstance(cfg, MixtralConfig):
         return Mixtral, mixtral_sharding_rules(fsdp=False)
     if isinstance(cfg, SolarOpen2Config):
@@ -481,6 +485,10 @@ class LlamaDeployment:
                         temperature=self.temperature,
                         sharding=_replica_sharding(0),
                         **opts).start()
+                # a serving process now: its start-up heap stays, and
+                # what a stream allocates dies young
+                from ray_tpu.serve.obs import tune_collector_for_serving
+                tune_collector_for_serving()
             return self._engine
 
     def start_trace(self, log_dir: str) -> float:

@@ -722,6 +722,36 @@ def watch_gc(sink) -> None:
     _GC_WATCH.sinks.add(sink)
 
 
+_COLLECTOR_TUNED = False
+# container allocations between two young passes of the collector in a
+# serving process (CPython's default is 700)
+GC_YOUNG_THRESHOLD = 100_000
+
+
+def tune_collector_for_serving() -> None:
+    """Once a process, when its first engine stands: a serving process
+    is not a script, and CPython's cyclic collector is tuned for
+    scripts. (1) ``gc.freeze()`` after a full pass: the start-up heap
+    (the imports, the weights' trees, the engine) lives as long as the
+    process does, yet every FULL pass walked all of it with every
+    thread stopped: 220-330 ms a pass. (2) A young pass every
+    ``GC_YOUNG_THRESHOLD`` container allocations, not every 700: at
+    4,200 tokens/s over 256 streams a young pass ran every few
+    milliseconds and PROMOTED whatever was alive, a token waiting
+    100 ms in its stream's queue among it; a full pass is due whenever
+    a quarter as many objects were promoted as the last one left, which
+    was every 5.8 s: 7 stalls a 40 s window, the chip idle behind each
+    (PERF.md section 6, PR 39). What lives under a second now dies
+    young. Cyclic garbage among what is frozen is never reclaimed:
+    start-up's, once."""
+    global _COLLECTOR_TUNED
+    if not _COLLECTOR_TUNED:
+        _COLLECTOR_TUNED = True
+        gc.collect()
+        gc.freeze()
+        gc.set_threshold(GC_YOUNG_THRESHOLD, *gc.get_threshold()[1:])
+
+
 def mint_trace_id() -> str:
     """A fresh 16-hex trace id (same shape util/tracing mints)."""
     from ray_tpu.util import tracing

@@ -8,7 +8,11 @@ configuration against its published copy, a chip's share against the
 reference, byte counts, three readers, doc-sat, the rehearsal cell)
 and test_axk1_family.py (the A.X-K1 family: the configuration against
 its published copy, the seeded weights, YaRN by hand, the near-tie
-rule, byte counts, three readers, longdoc-sat, the rehearsal cell),
+rule, byte counts, three readers, longdoc-sat, the rehearsal cell) and
+test_kimi_linear_family.py (the Kimi-Linear family: the configuration
+against its published copy, the program against the reference at a
+share, seeded and balanced weights, byte counts by kind of layer, the
+four readers on a hand-made joined trace, gen-sat, the rehearsal cell),
 collected here so that the suite the driver runs guards them.
 `python -m pytest benchmarks/tests` still runs them where they live."""
 import pytest
@@ -17,7 +21,8 @@ _FILES = ("benchmarks.tests.test_families",
           "benchmarks.tests.test_mixtral_family",
           "benchmarks.tests.test_olmoe_family",
           "benchmarks.tests.test_solar_open2_family",
-          "benchmarks.tests.test_axk1_family")
+          "benchmarks.tests.test_axk1_family",
+          "benchmarks.tests.test_kimi_linear_family")
 pytest.register_assert_rewrite(*_FILES)
 
 from benchmarks.tests.test_families import *          # noqa: E402,F401,F403
@@ -25,6 +30,7 @@ from benchmarks.tests.test_mixtral_family import *    # noqa: E402,F401,F403
 from benchmarks.tests.test_olmoe_family import *      # noqa: E402,F401,F403
 from benchmarks.tests.test_solar_open2_family import *  # noqa: E402,F401,F403
 from benchmarks.tests.test_axk1_family import *       # noqa: E402,F401,F403
+from benchmarks.tests.test_kimi_linear_family import *  # noqa: E402,F401,F403
 
 # Recorded without tier-1's low-optimisation XLA flags (tests/conftest.py),
 # under which the CPU draws a normal's last bits differently: the digests
@@ -42,11 +48,19 @@ del test_seeded_weights_are_the_parents_bit_for_bit    # noqa: F821
 # (benchmarks/trace_dispatch.py) to BENCHMARK.json: here the two cases
 # run against the file less those four, and the case below pins the four.
 # (`python -m pytest benchmarks/tests` fails the two until a `benchmark`
-# PR updates their sets: PERF.md section 7.)
+# PR updates their sets: PERF.md section 7.) PR 39 appended a
+# configuration, a cell and four readers of its own, and the cell to
+# the lists of twelve older metrics: the two cases run against the file
+# less those too, the case of the four dispatch readers pins them FOUR
+# BEFORE the file's last four, and benchmarks/tests/
+# test_kimi_linear_family.py::test_the_cell_and_gen_sat pins PR 39's.
 _DISPATCH = ("dispatch_prefill_call_ms", "dispatch_decode_step_ms",
              "dispatch_prefill_share", "dispatch_prefill_call_ms.open")
+_PR39 = ("state_peak_share", "linear_state_roofline.by_kind",
+         "latent_attn_roofline.by_kind", "moe_experts_roofline.by_kind")
 _SAT = ["mistral7b-d16.chat-sat", "olmoe-d8.chat-sat",
-        "solar-open2-d4.doc-sat", "axk1-d5.longdoc-sat"]
+        "solar-open2-d4.doc-sat", "axk1-d5.longdoc-sat",
+        "kimi-linear-d8.gen-sat"]
 
 
 def _less_the_dispatch_readers(case):
@@ -54,7 +68,7 @@ def _less_the_dispatch_readers(case):
         from benchmarks import common
         bench = common.load_benchmark()
         bench["per_layer"] = [m for m in bench["per_layer"]
-                              if m["name"] not in _DISPATCH]
+                              if m["name"] not in _DISPATCH + _PR39]
         monkeypatch.setattr(common, "load_benchmark", lambda: bench)
         case()
     test.__name__ = case.__name__
@@ -72,7 +86,8 @@ test_the_cell_and_longdoc_sat = _less_the_dispatch_readers(
 def test_dispatch_readers_are_appended_to_the_benchmark(name):
     from benchmarks import common
     bench = common.load_benchmark()
-    assert tuple(m["name"] for m in bench["per_layer"][-4:]) == _DISPATCH
+    assert tuple(m["name"] for m in bench["per_layer"][-8:]) == \
+        _DISPATCH + _PR39
     m = common.find_named(bench["per_layer"], name, "metric")
     want = {"name": name, "unit": "ms", "better": "lower",
             "source": "device_trace", "layer": "model step",

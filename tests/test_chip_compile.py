@@ -411,3 +411,69 @@ def test_latent_step_programs_keep_the_pool_page_major(one_chip,
         convs = re.findall(
             r" convolution\([^\n]*" + scope + "/" + spec, text)
         assert len(convs) >= 2, (name, scope, len(convs))    # one a layer
+
+
+# ---------------------------------------------------------------
+# A model of recurrent and latent layers ONLY at its cell's widths and
+# ITS slots (Kimi-Linear: 32 delta-rule heads of 128 over 128 SLOTS, a
+# 576-wide latent entry, 4,609 pages of 64, a page table 64 wide; one
+# period of four layers, the first dense, with 2 of 256 experts held
+# keeps the compile short): both kinds of state stay where they lie in
+# one program, at four times the slots of the other cells.
+
+def _no_kv_step(name, one_chip):
+    from ray_tpu.models.kimi_linear import KimiLinear, kimi_linear_48b
+    from ray_tpu.models.kv_cache import init_kv_pool
+    from ray_tpu.serve import engine as engine_mod
+    S = 128
+    cfg = kimi_linear_48b(n_layers=4, vocab_size=40960, max_seq_len=4096,
+                          experts_held=(0, 2), param_dtype=jnp.bfloat16)
+    model = KimiLinear(cfg)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                           sharding=one_chip), tree)
+    params = placed({"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]})
+    pages = placed(jax.eval_shape(
+        lambda: init_kv_pool(cfg, 4609, PAGE, n_slots=S)))
+    i32 = jnp.int32
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    table = ((S, cfg.max_seq_len // PAGE), i32)
+    if name == "decode":
+        fn = engine_mod._jit_decode(model, 0.0, 128, S, False, None)
+        rest = [table, ((S,), i32), ((S,), i32),
+                (key.shape, key.dtype), ((), i32)]
+    else:
+        fn = engine_mod._jit_prefill(model, 0.0, 4, False, None)
+        rest = [((4, 256), i32), ((4,), i32), ((4,), i32),
+                ((4, table[0][1]), i32), (key.shape, key.dtype),
+                ((4,), i32)]
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in rest]
+    return fn.lower(params, pages, *rest).compile()
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_a_model_with_no_kv_layer_keeps_both_kinds_in_place(
+        one_chip, monkeypatch, name):
+    from ray_tpu.ops import grouped_matmul as gm
+    monkeypatch.setattr(gm, "_use_kernel", lambda: True)
+    compiled = _no_kv_step(name, one_chip)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text          # the experts' grouped matmul
+    state = r"f32\[128,32,128,128\]"
+    copies = re.findall(r"= " + state + r"(?:\{[^}]*\})? copy\(", text)
+    assert not copies, f"{len(copies)} whole-state copies in {name}"
+    pool = r"bf16\[4609,64,640\]"
+    entry = re.search(pool + r"(\{[^}]*\}) parameter", text)
+    assert entry and entry.group(1).startswith("{2,1,0"), entry
+    copies = re.findall(r"= " + pool + r"(?:\{[^}]*\})? copy\(", text)
+    assert not copies, f"{len(copies)} whole-pool copies in {name}"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    one_state = 128 * 32 * 128 * 128 * 4      # a layer's, 256 MiB
+    # decode: the step's own temporaries and at most one state's worth;
+    # prefill: four rows' chunks, whatever the slots
+    assert temp < 2 * one_state, (name, temp)

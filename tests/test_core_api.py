@@ -307,3 +307,37 @@ def test_runtime_context_in_actor():
         aid, tid = ray_tpu.get(a.ident.remote())
         assert aid == a.actor_id.hex()
         assert tid
+
+
+def test_the_task_table_keeps_a_bounded_ring_of_finished_tasks(
+        rt, monkeypatch):
+    """Finished tasks stay listed for the state API up to a cap, oldest
+    out first (as the multiprocess head keeps them); tasks and actor
+    tasks alike; what is still pending is never dropped. A serve
+    handle polls a stream with an actor task a poll: unbounded, the
+    table grew by 565 specs a second behind 128 slots (PERF.md
+    section 6, PR 39)."""
+    from ray_tpu import state
+    from ray_tpu._private.worker import global_worker
+    runtime = global_worker().runtime
+    monkeypatch.setattr(type(runtime), "_DONE_TASKS_CAP", 50)
+
+    @rt.remote
+    def one(i):
+        return i
+
+    @rt.remote
+    class Counter:
+        def bump(self, i):
+            return i + 1
+
+    assert rt.get([one.remote(i) for i in range(120)]) == list(range(120))
+    c = Counter.remote()
+    assert rt.get([c.bump.remote(i) for i in range(120)])[-1] == 120
+    listed = state.list_tasks()
+    assert 0 < len(listed) <= 50 + 2
+    assert len(runtime._tasks_by_id) == len(runtime._task_states) <= 52
+    assert {t["state"] for t in listed} <= {"FINISHED", "RUNNING",
+                                            "PENDING", "PENDING_ACTOR"}
+    # the newest are the ones kept
+    assert any(t["name"].endswith("bump") for t in listed)

@@ -566,6 +566,50 @@ def test_latent_scope_names_the_benchmark_keys_on(program):
     assert "mla_absorb/wo" not in text and "attention/wo" in text
 
 
+@pytest.mark.parametrize("program", ["jit_decode", "jit_prefill"])
+def test_recurrent_and_latent_scopes_in_one_program(program):
+    """benchmarks/families/kimi_linear.py's table sorts a program's
+    device time by these names (the three ``*_roofline.by_kind``): the
+    step programs of a model with NO K/V layer carry the delta-rule
+    layer's four scopes AND the latent attention's three beside the
+    page window's shared ones, the mixture's and the leading dense
+    layer's module name."""
+    from ray_tpu.models.kimi_linear import KimiLinear, kimi_linear_tiny
+    from ray_tpu.models.kv_cache import init_kv_pool
+    from ray_tpu.serve import engine as engine_mod
+    cfg = kimi_linear_tiny(dtype=jnp.float32, n_layers=4)
+    model = KimiLinear(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    params = {"params": params["params"]}
+    S, i32 = 4, jnp.int32
+    pages = jax.eval_shape(lambda: init_kv_pool(cfg, 17, 8, n_slots=S))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    arr = jax.ShapeDtypeStruct
+    if program == "jit_decode":
+        fn = engine_mod._jit_decode(model, 0.0, 128, S, False, None)
+        text = fn.lower(params, pages, arr((S, 8), i32), arr((S,), i32),
+                        arr((S,), i32), key, arr((), i32)
+                        ).as_text(debug_info=True)
+    else:
+        fn = engine_mod._jit_prefill(model, 0.0, 4, False, None)
+        text = fn.lower(params, pages, arr((4, 16), i32), arr((4,), i32),
+                        arr((4,), i32), arr((4, 8), i32), key,
+                        arr((4,), i32)).as_text(debug_info=True)
+    assert f"module @{program}" in text
+    for scope in ("kda_conv", "kda_gates", "kda_recurrence", "kda_out",
+                  "mla_q", "mla_kv", "mla_absorb", "kv_append",
+                  "kv_gather", "attn_scores", "attn_pv", "feed_forward",
+                  "moe_shared", "moe_router", "moe_dispatch",
+                  "moe_experts", "moe_combine", "moe_stats", "head",
+                  "sample"):
+        assert f"/{scope}" in text, scope
+    # the direct query lies inside its scope, the output projection
+    # outside; no rotation is traced
+    assert "mla_q/wq" in text and "mla_kv/wkv_a" in text
+    assert "attention/wo" in text and "cos" not in text
+
+
 # ------------------------------------------- the cost with tracing off
 
 def test_closed_annotations_cost_nothing_measurable():

@@ -200,12 +200,24 @@ def test_the_routers_rule_matches_the_reference(tiny):
 
 def _share_case(family, tiny):
     """(reference module, config, params, mixture layer, experts a
-    share, the reference's routing arguments) of a family's tiny
-    model."""
+    share, experts in all, the reference's routing arguments) of a
+    family's tiny model."""
     if family == "solar_open2":
         from benchmarks.reference import solar_open2 as ref
         cfg, _model, params = tiny
-        return ref, cfg, params, 2, 4, dict(top_k=4, scaling=1.0)
+        return ref, cfg, params, 2, 4, 16, dict(top_k=4, scaling=1.0)
+    if family == "kimi_linear":
+        # Kimi-Linear: the choice bias AND gates times 2.446; 64 toy
+        # experts of which 8 a token, FOUR shares of 16, as its
+        # benchmark cut has them (64 of 256); layer 3 is a latent layer
+        from benchmarks import common, weights
+        from benchmarks.reference import kimi_linear as ref
+        from ray_tpu.models.kimi_linear import KimiLinear, kimi_linear_tiny
+        cfg = kimi_linear_tiny(dtype=jnp.float32, num_experts=64,
+                               num_experts_per_tok=8, n_layers=4)
+        params = common.load_family("kimi_linear", "serve").seeded(
+            weights.param_shapes(KimiLinear(cfg)), 0)
+        return ref, cfg, params, 3, 16, 64, dict(top_k=8, scaling=2.446)
     # A.X-K1: a sigmoid router without a choice bias, gates times 2.5;
     # SIXTEEN shares of one expert each, as its benchmark cut has them
     from benchmarks import common, weights
@@ -214,19 +226,20 @@ def _share_case(family, tiny):
     cfg = axk1_tiny(dtype=jnp.float32)
     params = common.load_family("axk1", "serve").seeded(
         weights.param_shapes(AXK1(cfg)), 0)
-    return ref, cfg, params, 1, 1, dict(top_k=4, scaling=2.5)
+    return ref, cfg, params, 1, 1, 16, dict(top_k=4, scaling=2.5)
 
 
-@pytest.mark.parametrize("family", ["solar_open2", "axk1"])
+@pytest.mark.parametrize("family", ["solar_open2", "axk1", "kimi_linear"])
 def test_the_shares_add_up_to_the_whole_layer(tiny, family):
     """THE SHARE TEST (model-configs section 4): the chips of a group
-    hold a share of the 16 experts each (four of 4; sixteen of 1).
+    hold a share of the experts each (four of 4 of 16; sixteen of 1;
+    four of 16 of 64).
     What each computes for the same tokens (its own experts' part, the
     router at its full width, the gates normalised over all chosen)
     plus the shared expert, which every chip computes alike, counted
     ONCE, is what the uncut reference gives for the whole layer."""
     from benchmarks import common
-    ref, cfg, params, layer, held, routing = _share_case(family, tiny)
+    ref, cfg, params, layer, held, E, routing = _share_case(family, tiny)
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 31, cfg.dim))
     whole = params["params"][f"layers_{layer}"]["moe"]
     w = {k: jnp.asarray(v, jnp.float32) for k, v in common.load_family(
@@ -236,14 +249,14 @@ def test_the_shares_add_up_to_the_whole_layer(tiny, family):
         shared = ref.shared(x, w)
         want = ref.routed(x, w, lo=0, norm_topk=True, **routing) + shared
     total, landed = jnp.zeros_like(x), 0
-    for lo in range(0, 16, held):
+    for lo in range(0, E, held):
         share_cfg = dataclasses.replace(cfg, experts_held=(lo, held))
         share = {k: (v[lo:lo + held] if k in ("w1", "w2", "w3") else v)
                  for k, v in whole.items()}
         part = MoEFeedForward(share_cfg).apply({"params": share}, x)
         total = total + (part - shared)
         landed += float(jnp.abs(part - shared).max() > 1e-3)
-    assert landed == 16 // held             # every share does some work
+    assert landed == E // held              # every share does some work
     np.testing.assert_allclose(np.asarray(total + shared),
                                np.asarray(want), rtol=RTOL, atol=ATOL)
 
