@@ -9,8 +9,9 @@ through the entry points a user calls:
             from a seed; concurrent, streamed and HTTP requests; greedy
             output checked against the model's cache-free full forward;
             one int8-KV request checked the same way
-  kernels   the Pallas flash-attention kernel (fwd+bwd), compiled,
-            against its XLA reference
+  kernels   the Pallas flash-attention kernel (fwd+bwd) and the
+            one-token delta-rule kernel, compiled, each against its
+            XLA reference
   training  GPT-2-124M at batch 24 x 1024 through shard_state /
             put_batch / make_train_step; flash kernel present in the
             compiled step; loss finite and falling
@@ -53,6 +54,8 @@ INT8_AGREE_FLOOR = 0.8
 # relative L2 error allowed between a compiled kernel and its XLA
 # reference on bf16 inputs (outputs round to bf16: 2**-8 per element)
 KERNEL_REL_TOL = 2e-2
+# float32 against float32, the order of one 128-term sum apart
+KDA_REL_TOL = 1e-5
 # one chip vs four chips, same global batch: per-step loss may differ
 # by reduction order only
 MULTICHIP_LOSS_RTOL = 1e-2
@@ -350,10 +353,14 @@ def _rel_err(a, b) -> float:
 
 def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
                                   (8, 1024, 32, 64)),
+                 kda_shapes=((32, 64, 128),),
                  interpret: bool = False, seed: int = SEED) -> dict:
     """The flash-attention kernel, compiled (not interpreted, unless
     the CPU rehearsal asks) and compared with its XLA reference,
-    forward and backward."""
+    forward and backward; the one-token delta-rule kernel at a serving
+    cell's state (slots, heads, head width) against the ``jax.numpy``
+    form, two chained steps with a row that starts a request and a row
+    that rides nothing."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -393,10 +400,45 @@ def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
             for n, a, b in zip("qkv", g, g_ref):
                 errs[f"{name}_d{n}"] = _rel_err(a, b)
 
+    from ray_tpu.ops import linear_attention as la
+    for slots, H, d in kda_shapes:
+        name = f"kda_step_B{slots}_H{H}_D{d}"
+        q, k, v, g = (jnp.asarray(rng.standard_normal((2, slots, H, d)),
+                                  jnp.float32) for _ in range(4))
+        k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+        q, g = q * d ** -1.0, -jnp.exp(g)
+        beta = jnp.asarray(rng.uniform(0.0, 2.0, (2, slots, H)),
+                           jnp.float32)
+        state = jnp.asarray(rng.standard_normal((slots, H, d, d)),
+                            jnp.float32)
+        valid = jnp.arange(slots) != 1
+        fresh = jnp.arange(slots) == 2
+
+        def two(step):
+            def run(q, k, v, g, beta, s, valid, fresh):
+                o, s = step(q[0], k[0], v[0], g[0], beta[0], s, valid,
+                            fresh)
+                return (o,) + tuple(step(q[1], k[1], v[1], g[1],
+                                         beta[1], s, valid, None))
+            return jax.jit(run)(q, k, v, g, beta, state, valid, fresh)
+
+        with timed(f"kernels: {name}"):
+            got = two(functools.partial(la.kda_step_kernel,
+                                        interpret=interpret))
+            want = two(la._kda_step_xla)
+            rows = np.asarray(valid)
+            for n, a, b in zip(("o1", "o2", "state"), got, want):
+                rides = rows if n != "state" else slice(None)
+                errs[f"{name}_{n}"] = _rel_err(a[rides], b[rides])
+            assert (np.asarray(got[2])[1] == np.asarray(state)[1]).all(), (
+                f"{name}: the row that rides nothing moved its state")
+
     for name, e in errs.items():
         log(f"[kernels] {name}: rel err {e:.2e}")
-    bad = {n: e for n, e in errs.items() if not e <= KERNEL_REL_TOL}
-    assert not bad, f"kernels beyond rel tol {KERNEL_REL_TOL}: {bad}"
+    bad = {n: e for n, e in errs.items()
+           if not e <= (KDA_REL_TOL if n.startswith("kda") else
+                        KERNEL_REL_TOL)}
+    assert not bad, f"kernels beyond their rel tol: {bad}"
     return errs
 
 

@@ -163,7 +163,7 @@ class KDAAttention(nn.Module):
 
         rc = kv_cache
         if rc is None:
-            valid = jnp.ones((B, T), bool)
+            valid, fresh = jnp.ones((B, T), bool), None
             state = jnp.zeros((B,) + cfg.recurrent_state_shape, f32)
             tail = jnp.zeros((B, K - 1, 3 * C), cfg.dtype)
         else:
@@ -177,8 +177,12 @@ class KDAAttention(nn.Module):
             with jax.named_scope("kda_conv"):
                 tail = jnp.where(fresh[:, None, None], 0, rc.take(rc.conv))
             with jax.named_scope("kda_recurrence"):
-                state = jnp.where(fresh[:, None, None, None], 0.0,
-                                  rc.take(rc.state))
+                state = rc.take(rc.state)
+                if T > 1:
+                    # one token's step resets a fresh row itself, in
+                    # the one pass it makes over the state
+                    state = jnp.where(fresh[:, None, None, None], 0.0,
+                                      state)
 
         with jax.named_scope("kda_conv"):
             conv = self.param("conv", nn.initializers.normal(K ** -0.5),
@@ -207,7 +211,7 @@ class KDAAttention(nn.Module):
         with jax.named_scope("kda_recurrence"):
             if T == 1:
                 o, state = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                    beta[:, 0], state, valid[:, 0])
+                                    beta[:, 0], state, valid[:, 0], fresh)
                 o = o[:, None]
             else:
                 o, state = kda_chunked(q, k, v, g, beta, state, valid)

@@ -105,6 +105,54 @@ def test_grouped_matmul_compiles(one_chip, monkeypatch, M):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+# (slots, heads): the recurrent state of kimi-linear-d8.gen-sat and of
+# solar-open2-d4.doc-sat, heads of 128 x 128 float32; the one-token
+# delta-rule kernel updates it where it lies
+@pytest.mark.parametrize("B,H", [(128, 32), (32, 64)],
+                         ids=["gen-sat", "doc-sat"])
+def test_kda_step_kernel_compiles(one_chip, B, H):
+    from ray_tpu.ops import linear_attention as la
+    f32, d = jnp.float32, 128
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in
+            [((B, H, d), f32)] * 4 + [((B, H), f32), ((B, H, d, d), f32),
+                                      ((B,), jnp.bool_), ((B,), jnp.bool_)]]
+    compiled = jax.jit(la.kda_step_kernel, donate_argnums=5).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "output_to_operand_aliasing={{1}: (8, {})}" in text
+    assert not _state_passes(text, (B, H, d, d))
+    # the state is the program's argument and its result: nothing of
+    # its size beside it
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+def _state_passes(text, shape):
+    """Lines of the compiled program, OUTSIDE the delta-rule kernel's
+    custom call, that produce a float32 tensor of one layer's whole
+    recurrent state: a copy, a select (a reset in front of the step),
+    a convert, or a fusion of any of them."""
+    dims = ",".join(str(d) for d in shape)
+    pat = re.compile(
+        r"^\s*(?:ROOT )?%?\S+ = \(?f32\[" + dims + r"\][^\n]*? "
+        r"(copy|select|convert|fusion|multiply|add)\(", re.M)
+    return [m.group(0).strip()[:120] for m in pat.finditer(text)]
+
+
+def _assert_one_kernel_a_layer(text, shape, layers):
+    """The decode program's delta-rule layers: one custom call each,
+    under the ``kda_recurrence`` scope the benchmark's readers sum,
+    its state operand aliased to its result, and no other operation
+    over a whole state."""
+    calls = re.findall(r"custom-call\([^\n]*kda_recurrence/kda_step[^\n]*",
+                       text)
+    assert len(calls) == layers, (len(calls), layers)
+    for call in calls:
+        assert "output_to_operand_aliasing={{1}: (8, {})}" in call, call[:200]
+    passes = _state_passes(text, shape)
+    assert not passes, (len(passes), passes[:4])
+
+
 # ---------------------------------------------------------------
 # The engine's step programs keep the KV pool as it is stored
 # (models/kv_cache.py says what a pool declared otherwise cost).
@@ -324,7 +372,10 @@ def _hybrid_step(name, one_chip):
 def test_hybrid_step_programs_keep_the_state_in_place(one_chip,
                                                       monkeypatch, name):
     from ray_tpu.ops import grouped_matmul as gm
+    from ray_tpu.ops import linear_attention as la
+    # both ops ask the attached backend, which is the CPU here
     monkeypatch.setattr(gm, "_use_kernel", lambda: True)
+    monkeypatch.setattr(la, "_on_one_tpu", lambda: True)
     compiled = _hybrid_step(name, one_chip)
     text = compiled.as_text()
     assert "tpu_custom_call" in text          # the experts' grouped matmul
@@ -333,11 +384,17 @@ def test_hybrid_step_programs_keep_the_state_in_place(one_chip,
     assert not copies, f"{len(copies)} whole-state copies in {name}"
     temp = compiled.memory_analysis().temp_size_in_bytes
     one_state = 32 * 64 * 128 * 128 * 4       # a layer's, 128 MiB
-    # decode: the step's own temporaries and at most one state's worth;
-    # prefill: the chunk's [4, 64, 64, 64, 128] float32 products alone
-    # would be 512 MiB each
-    assert temp < (2 * one_state if name == "decode" else 4 * one_state), (
-        name, temp)
+    if name == "decode":
+        # three delta-rule layers of four, each ONE kernel over its
+        # state in place: the step's own temporaries and no state's
+        # worth (two passes and a write of XLA's took up to one more)
+        _assert_one_kernel_a_layer(text, (32, 64, 128, 128), 3)
+        assert temp < one_state, (name, temp)
+    else:
+        # the chunk's [4, 64, 64, 64, 128] float32 products alone
+        # would be 512 MiB each
+        assert "kda_step" not in text
+        assert temp < 4 * one_state, (name, temp)
 
 
 # ---------------------------------------------------------------
@@ -460,7 +517,9 @@ def _no_kv_step(name, one_chip):
 def test_a_model_with_no_kv_layer_keeps_both_kinds_in_place(
         one_chip, monkeypatch, name):
     from ray_tpu.ops import grouped_matmul as gm
+    from ray_tpu.ops import linear_attention as la
     monkeypatch.setattr(gm, "_use_kernel", lambda: True)
+    monkeypatch.setattr(la, "_on_one_tpu", lambda: True)
     compiled = _no_kv_step(name, one_chip)
     text = compiled.as_text()
     assert "tpu_custom_call" in text          # the experts' grouped matmul
@@ -474,6 +533,11 @@ def test_a_model_with_no_kv_layer_keeps_both_kinds_in_place(
     assert not copies, f"{len(copies)} whole-pool copies in {name}"
     temp = compiled.memory_analysis().temp_size_in_bytes
     one_state = 128 * 32 * 128 * 128 * 4      # a layer's, 256 MiB
-    # decode: the step's own temporaries and at most one state's worth;
-    # prefill: four rows' chunks, whatever the slots
-    assert temp < 2 * one_state, (name, temp)
+    if name == "decode":
+        # three delta-rule layers of four, each one kernel in place
+        _assert_one_kernel_a_layer(text, (128, 32, 128, 128), 3)
+        assert temp < one_state, (name, temp)
+    else:
+        # four rows' chunks, whatever the slots
+        assert "kda_step" not in text
+        assert temp < 2 * one_state, (name, temp)
