@@ -21,6 +21,7 @@ resuming gang.
 """
 from __future__ import annotations
 
+import ctypes
 import hashlib
 import json
 import os
@@ -200,6 +201,14 @@ class Checkpoint:
             raise FileNotFoundError(path)
         ok, reason, _manifest = verify_checkpoint_dir(path)
         if not ok:
+            # a re-commit of the same slot swaps the directory under
+            # a reader that takes the manifest and then looks for its
+            # files: it may hold the old manifest against the new
+            # files (or, where the swap is two renames, against
+            # nothing). Look once more before calling it torn.
+            time.sleep(0.02)
+            ok, reason, _manifest = verify_checkpoint_dir(path)
+        if not ok:
             raise InvalidCheckpointError(path, reason)
         return cls(path=path)
 
@@ -291,14 +300,36 @@ class Checkpoint:
         return f"Checkpoint({src})"
 
 
+_RENAME_EXCHANGE = 2
+_AT_FDCWD = -100
+
+
+def _exchange(a: str, b: str) -> bool:
+    """Swap what two paths name in ONE step (Linux ``renameat2`` with
+    ``RENAME_EXCHANGE``). False where the platform's C library or the
+    filesystem has no such call: the caller then renames twice."""
+    try:
+        renameat2 = ctypes.CDLL(None, use_errno=True).renameat2
+    except (OSError, AttributeError):
+        return False
+    return renameat2(_AT_FDCWD, os.fsencode(a), _AT_FDCWD,
+                     os.fsencode(b), _RENAME_EXCHANGE) == 0
+
+
 def _commit_dir(stage: str, path: str) -> None:
     """Atomically install ``stage`` at ``path``. A pre-existing target
-    (re-save over an old checkpoint, or mkdtemp's empty dir) is swapped
-    out first and removed after — at every instant ``path`` is either
-    the old complete state or the new one."""
+    (re-save over an old checkpoint, or mkdtemp's empty dir) is
+    exchanged with the stage in one step and removed after — at every
+    instant ``path`` is either the old complete state or the new one.
+    Only where the platform cannot exchange is the target renamed away
+    first, and ``path`` names nothing between the two renames."""
     parent = os.path.dirname(path) or "."
     displaced = None
     if os.path.exists(path):
+        if _exchange(stage, path):
+            _fsync_dir(parent)
+            shutil.rmtree(stage, ignore_errors=True)   # the old state
+            return
         displaced = os.path.join(
             parent, f"{_TMP_PREFIX}displaced-{uuid.uuid4().hex[:8]}")
         os.rename(path, displaced)

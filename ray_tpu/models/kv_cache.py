@@ -41,7 +41,7 @@ structure that lets requests join/leave the decode batch per token):
   ops/paged_attention.py; nothing outside it interprets the int8
   payload.
 
-THREE KINDS OF PER-REQUEST STATE. The pool is a list of per-layer
+FOUR KINDS OF PER-REQUEST STATE. The pool is a list of per-layer
 entries BY KIND (``layer_kinds``): a layer of softmax attention has
 K and V pages, as above; a layer of LATENT attention (models/axk1.py's
 MLA) has pages too, handed out by the same allocator through the same
@@ -60,7 +60,16 @@ page of a kind is stored as. Pages are handed out by the allocator as
 a sequence grows; a slot's state simply belongs to the slot. Nothing on the host ever clears it: the layer
 itself starts a row whose write offset is 0 from zeros (as
 ``paged_append`` resets an int8 page's scale at offset 0), and rows
-or positions that carry no request leave it as it was. A model
+or positions that carry no request leave it as it was. A layer of
+SLIDING-WINDOW attention (models/mellum.py) has none either, but a
+``SlidingRing`` a decode slot: the keys and values of its last
+``sliding_ring_len`` positions, position p at ring index p mod that
+length, so its bytes a slot are a constant of the configuration and a
+context eight times the window costs it what the window does. Nothing
+clears a ring either: what a ring index holds is known from the row's
+last written position alone (ops/paged_attention.py
+``ring_attention``), and an index this request has not written is
+never visible. A model
 whose layers are all of one kind declares nothing and gets the pool
 it always had.
 """
@@ -92,6 +101,7 @@ def check_kv_dtype(kv_dtype: Optional[str]) -> str:
 KIND_KV = "kv"                  # a layer with K/V pages
 KIND_RECURRENT = "recurrent"    # a layer with a fixed-size state a slot
 KIND_LATENT = "latent"          # a layer with one pool of latent pages
+KIND_SLIDING = "sliding"        # a layer with a ring of its window a slot
 
 
 def layer_kinds(cfg) -> Tuple[str, ...]:
@@ -108,6 +118,24 @@ def has_recurrent_state(cfg) -> bool:
 
 def has_latent_pages(cfg) -> bool:
     return KIND_LATENT in layer_kinds(cfg)
+
+
+def has_sliding_entries(cfg) -> bool:
+    return KIND_SLIDING in layer_kinds(cfg)
+
+
+def sliding_ring_len(cfg, page_size: int, prefill_chunk: int) -> int:
+    """Positions a sliding layer's ring keeps a slot: the window and
+    one prefill chunk, rounded up to whole pages, and one page more.
+    A chunk's queries then never lose a key they can still see to the
+    chunk's own writes (position p overwrites p - length, which lies
+    more than a window behind every query of a call that holds p),
+    whatever the page or the chunk the engine was given. 0 for a
+    model without such a layer."""
+    if not has_sliding_entries(cfg):
+        return 0
+    span = cfg.sliding_window + prefill_chunk
+    return -(-span // page_size) * page_size + page_size
 
 
 # The minor axis of a TPU array is stored in tiles of 128 lanes.
@@ -136,9 +164,10 @@ def page_layout(cfg, kind: str, page_size: int, kv_dtype: str = "fp"):
     kv fp:   k, v           [Pg, KH, D] cfg.dtype
     kv int8: k, v, sk, sv   [Pg, KH, D] int8 and [KH] fp32 absmax
     latent:  one tensor     [Pg, latent_page_width(cfg)] cfg.dtype
-    recurrent: none (its state belongs to a slot, not to a page)
+    recurrent, sliding: none (a state and a ring belong to a slot, not
+    to a page)
     """
-    if kind == KIND_RECURRENT:
+    if kind in (KIND_RECURRENT, KIND_SLIDING):
         return ()
     quantized = check_kv_dtype(kv_dtype) == "int8"
     if kind == KIND_LATENT:
@@ -185,9 +214,7 @@ class RecurrentStateView(NamedTuple):
 
     def take(self, pool):
         """The rows' entries of ``pool`` (``state`` or ``conv``)."""
-        if self.slots is None:
-            return pool
-        return pool.at[self.slots].get(mode="fill", fill_value=0)
+        return _take(pool, self.slots)
 
     def put(self, pool, rows):
         """``pool`` with the rows' entries replaced."""
@@ -195,6 +222,42 @@ class RecurrentStateView(NamedTuple):
         if self.slots is None:
             return rows
         return pool.at[self.slots].set(rows, mode="drop")
+
+
+def _take(pool, slots):
+    """The rows' entries of a per-slot ``pool``: row i is slot i where
+    ``slots`` is None, zeros for a row that names no slot."""
+    if slots is None:
+        return pool
+    return pool.at[slots].get(mode="fill", fill_value=0)
+
+
+class SlidingRing(NamedTuple):
+    """One sliding-window layer's storage, carried between jitted steps
+    as a paged layer's ``(pages_k, pages_v)`` is: row s is decode slot
+    s's ring, and position p of its request lies at index p mod L.
+
+    k, v: [n_slots, n_kv_heads, L, head_dim] cfg.dtype,
+          L = ``sliding_ring_len``: head-major inside a slot, as the
+          attention's two contractions read it (declared otherwise the
+          chip's compiler copies every ring into this layout on every
+          step: ops/paged_attention.py)
+    """
+    k: jnp.ndarray
+    v: jnp.ndarray
+
+
+class SlidingRingView(NamedTuple):
+    """``SlidingRing`` as a layer sees it in one call of B rows;
+    ``slots`` and ``valid`` as ``RecurrentStateView``'s."""
+    k: jnp.ndarray
+    v: jnp.ndarray
+    slots: Optional[jnp.ndarray]
+    valid: jnp.ndarray
+
+    def take(self, ring):
+        """The rows' rings of ``ring`` (``k`` or ``v``)."""
+        return _take(ring, self.slots)
 
 
 class PagedKVLayer(NamedTuple):
@@ -230,17 +293,19 @@ class PagedKVLayer(NamedTuple):
 def kv_layer_view(layer, page_table: jnp.ndarray, slots=None,
                   valid=None):
     """Wrap one engine layer entry — ``(pk, pv)`` fp,
-    ``(pk, pv, sk, sv)`` int8, ``(pages,)`` latent, or a
-    ``RecurrentState`` — as what its
+    ``(pk, pv, sk, sv)`` int8, ``(pages,)`` latent, a
+    ``RecurrentState`` or a ``SlidingRing`` — as what its
     layer consumes: a PagedKVLayer over ``page_table``, or a
-    RecurrentStateView of the rows' ``slots`` and real positions
-    (``valid``: a function giving the [B, T] mask, which only a
-    recurrent layer calls).
+    RecurrentStateView or SlidingRingView of the rows' ``slots`` and
+    real positions (``valid``: a function giving the [B, T] mask,
+    which only a layer that keeps its entry by slot calls).
     Keeps the jitted engine builders kind- and dtype-agnostic: they
     thread opaque entries and only this view/store pair knows what
     they are."""
     if isinstance(layer, RecurrentState):
         return RecurrentStateView(layer.state, layer.conv, slots, valid())
+    if isinstance(layer, SlidingRing):
+        return SlidingRingView(layer.k, layer.v, slots, valid())
     if len(layer) == 1:
         return PagedKVLayer(layer[0], None, page_table)
     if len(layer) == 2:
@@ -253,12 +318,12 @@ def kv_layer_view(layer, page_table: jnp.ndarray, slots=None,
 def live_rows(kv_cache):
     """[B] bool, the rows of a paged call that carry a request, as
     their layer's view shows it: a paged layer's row whose page-table
-    row is not the null row, a recurrent layer's row whose first
-    position is real. None without a paged cache (every row is live).
-    A mixture gives the other rows no expert."""
+    row is not the null row, a recurrent or sliding layer's row whose
+    first position is real. None without a paged cache (every row is
+    live). A mixture gives the other rows no expert."""
     if isinstance(kv_cache, PagedKVLayer):
         return kv_cache.page_table[:, 0] != 0
-    if isinstance(kv_cache, RecurrentStateView):
+    if isinstance(kv_cache, (RecurrentStateView, SlidingRingView)):
         return kv_cache.valid[:, 0]
     return None
 
@@ -269,6 +334,8 @@ def kv_layer_store(cache: PagedKVLayer):
     between jitted steps."""
     if isinstance(cache, RecurrentStateView):
         return RecurrentState(cache.state, cache.conv)
+    if isinstance(cache, SlidingRingView):
+        return SlidingRing(cache.k, cache.v)
     if cache.pages_v is None:
         return (cache.pages_k,)
     if cache.scales_k is None:
@@ -278,7 +345,8 @@ def kv_layer_store(cache: PagedKVLayer):
 
 
 def init_kv_pool(cfg, n_pages: int, page_size: int,
-                 kv_dtype: str = "fp", n_slots: int = 0):
+                 kv_dtype: str = "fp", n_slots: int = 0,
+                 ring_len: int = 0):
     """One entry per layer, by ``layer_kinds(cfg)``. Page 0 of a paged
     layer is reserved (null).
 
@@ -292,8 +360,16 @@ def init_kv_pool(cfg, n_pages: int, page_size: int,
     latent: (pages,) in cfg.dtype,
           [n_pages, page_size, latent_page_width(cfg)].
     recurrent: RecurrentState of ``n_slots`` rows, zeros.
+    sliding: SlidingRing of ``n_slots`` rings of ``ring_len``
+          (``sliding_ring_len``) positions, zeros: the same bytes
+          whatever ``n_pages``.
     """
     def entry(kind):
+        if kind == KIND_SLIDING:
+            shape = (n_slots, cfg.n_kv_heads, ring_len, cfg.head_dim)
+            # two arrays: the pool is donated, one buffer cannot be twice
+            return SlidingRing(jnp.zeros(shape, cfg.dtype),
+                               jnp.zeros(shape, cfg.dtype))
         if kind == KIND_RECURRENT:
             return RecurrentState(
                 jnp.zeros((n_slots,) + tuple(cfg.recurrent_state_shape),
@@ -318,16 +394,29 @@ def kv_pool_page_bytes(cfg, page_size: int,
                                                kv_dtype))
 
 
-def state_bytes_per_slot(cfg) -> int:
-    """Bytes ONE decode slot's recurrent state costs across the layers
-    that keep one (0 for a model with none): the float32 state and
-    the convolution tail in cfg.dtype."""
-    n = layer_kinds(cfg).count(KIND_RECURRENT)
-    if not n:
+def sliding_bytes_per_slot(cfg, ring_len: int) -> int:
+    """Bytes ONE decode slot's rings cost across the sliding layers (0
+    for a model with none): k and v of ``ring_len`` positions a layer,
+    whatever the slot's context."""
+    if not ring_len:
         return 0
-    return n * (4 * int(np.prod(cfg.recurrent_state_shape))
-                + jnp.dtype(cfg.dtype).itemsize
-                * int(np.prod(cfg.recurrent_conv_shape)))
+    return (layer_kinds(cfg).count(KIND_SLIDING) * 2 * ring_len
+            * cfg.n_kv_heads * cfg.head_dim
+            * jnp.dtype(cfg.dtype).itemsize)
+
+
+def state_bytes_per_slot(cfg, ring_len: int = 0) -> int:
+    """Bytes ONE decode slot holds in the layers that keep their entry
+    by slot and not by page (0 for a model with none): a recurrent
+    layer's float32 state and its convolution tail in cfg.dtype, a
+    sliding layer's ring (``sliding_bytes_per_slot``)."""
+    total = sliding_bytes_per_slot(cfg, ring_len)
+    n = layer_kinds(cfg).count(KIND_RECURRENT)
+    if n:
+        total += n * (4 * int(np.prod(cfg.recurrent_state_shape))
+                      + jnp.dtype(cfg.dtype).itemsize
+                      * int(np.prod(cfg.recurrent_conv_shape)))
+    return total
 
 
 def export_page_bytes(layers, page: int) -> List[List[bytes]]:
@@ -352,8 +441,9 @@ def page_cols_from_bytes(cfg, page_size: int, kv_dtype: str,
     scales ``[KH]`` (``page_layout`` a layer). Validates arity and
     byte counts so a truncated or cross-dtype blob fails typed instead
     of landing garbage KV."""
-    layouts = [page_layout(cfg, kind, page_size, kv_dtype)
-               for kind in layer_kinds(cfg) if kind != KIND_RECURRENT]
+    layouts = [layout for layout in (
+        page_layout(cfg, kind, page_size, kv_dtype)
+        for kind in layer_kinds(cfg)) if layout]
     if len(blobs) != len(layouts):
         raise ValueError(
             f"page payload has {len(blobs)} layers, pool has "

@@ -34,6 +34,18 @@ the layout:
 Inactive slots point at the null page: their writes land there, the
 causal mask hides it from every live query, and their outputs are
 ignored host-side.
+
+A layer of SLIDING-WINDOW attention (models/mellum.py) keeps no pages
+but a ring a decode slot (models/kv_cache.py ``SlidingRing``:
+[n_slots, n_kv_heads, L, head_dim] for k and for v, position p at
+index p mod L), and has its own two operations, ``ring_append`` and
+``ring_attention``: a slot's ring is contiguous, so nothing is gathered
+by page and no loop walks it. The ring is HEAD-major inside a slot
+because that is how the two contractions read it (a KV head is a batch
+dimension of both): declared position-major, as a page is, the chip's
+compiler re-laid every layer's ring out head-major on every decode
+step, a whole-ring copy for k and for v (read off the compiled
+program, PR 42; the pool's lesson of PRs 29 and 34 again).
 """
 from __future__ import annotations
 
@@ -430,6 +442,90 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
         y = y[:, :, :rep]
     # [B, KH, rep, T, Dv] -> [B, T, H, Dv]
     return y.transpose(0, 3, 1, 2, 4).reshape(B, T, H, Dv)
+
+
+# ------------------------------------------------ sliding-window rings
+
+def ring_append(ring_k, ring_v, slots, pos, k, v, valid):
+    """Write a [B, T] chunk of new K/V into the rows' rings: token t of
+    row b, at absolute position ``pos[b] + t``, goes to index
+    ``(pos[b] + t) mod L`` of slot ``slots[b]``'s ring (row b's own
+    where ``slots`` is None: a decode call, whose row i is slot i).
+    Positions that are not ``valid`` [B, T] (padding behind a row's
+    last real token, rows that carry no request, whose ``pos`` may be
+    stale) and rows whose slot is out of range are dropped: a ring is
+    only ever written by its own request's real tokens.
+
+    ring_k/ring_v: [n_slots, KH, L, D]; k/v: [B, T, KH, D]
+    """
+    _, KH, L, D = ring_k.shape
+    B, T = k.shape[:2]
+    if k.shape[2:] != (KH, D) or k.shape != v.shape:
+        raise PagedShapeError(
+            f"chunk {k.shape} / {v.shape} does not fit rings of "
+            f"{ring_k.shape}")
+    if T > L:
+        raise PagedShapeError(
+            f"a chunk of {T} tokens laps a ring of {L}")
+    rows = (jnp.arange(B, dtype=jnp.int32) if slots is None else slots)
+    idx = (pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None]) % L
+    idx = jnp.where(valid, idx, L)              # out of range: dropped
+    # one scattered row a (token, KV head): [D] values at (slot, head,
+    # index). A token's [KH, D] rows scattered as one window wanted
+    # the ring position-major, and the contractions want it head-major:
+    # the compiler then re-laid every ring out on every step.
+    at = (rows[:, None, None], jnp.arange(KH)[None, None, :],
+          idx[:, :, None])
+    return (ring_k.at[at].set(k.astype(ring_k.dtype), mode="drop"),
+            ring_v.at[at].set(v.astype(ring_v.dtype), mode="drop"))
+
+
+def ring_attention(q, ring_k, ring_v, pos, valid, window: int):
+    """Sliding-window grouped-query attention of ``q`` [B, T, H, D]
+    (row b's queries at absolute positions ``pos[b] + t``) over the
+    rows' rings ``ring_k``/``ring_v`` [B, KH, L, D] (row b's own: the
+    caller has taken them by slot) AFTER ``ring_append`` of the same
+    chunk: query at
+    position i sees the keys at i - window < j <= i, its own among
+    them, and nothing else; exact, one softmax over the ring.
+
+    What a ring index holds follows from the row's last written
+    position p (``pos`` + its count of ``valid`` positions - 1) alone:
+    index r holds position ``p - ((p - r) mod L)``, the newest position
+    congruent to r that the request has reached, or, where that is
+    negative, nothing of this request (whatever a previous owner of the
+    slot left there is never visible). A chunk's writes replace
+    positions a whole ring behind them, which no query of the chunk can
+    see as long as ``T <= L - window + 1``: checked here, and what
+    models/kv_cache.py ``sliding_ring_len`` sizes L for.
+
+    The scopes (ring_scores, ring_pv) are metadata only, as
+    ``_paged_window_attention``'s.
+    """
+    B, T, H, D = q.shape
+    KH, L = ring_k.shape[1:3]
+    if T > L - window + 1:
+        raise PagedShapeError(
+            f"a chunk of {T} queries over a window of {window} needs a "
+            f"ring of at least {window + T - 1} positions, got {L}")
+    rep = H // KH
+    qg = q.reshape(B, T, KH, rep, D)
+    with jax.named_scope("ring_scores"):
+        last = pos + jnp.sum(valid, axis=1, dtype=jnp.int32) - 1     # [B]
+        age = (last[:, None] - jnp.arange(L, dtype=jnp.int32)[None]) % L
+        k_pos = last[:, None] - age                              # [B, L]
+        q_pos = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+        seen = ((k_pos[:, None] <= q_pos[:, :, None])
+                & (k_pos[:, None] > q_pos[:, :, None] - window)
+                & (k_pos[:, None] >= 0))                         # [B, T, L]
+        s = jnp.einsum("btkrd,bksd->bkrts", qg, ring_k.astype(qg.dtype),
+                       preferred_element_type=jnp.float32) / np.sqrt(D)
+        s = jnp.where(seen[:, None, None], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+    with jax.named_scope("ring_pv"):
+        y = jnp.einsum("bkrts,bksd->btkrd", p.astype(ring_v.dtype),
+                       ring_v, preferred_element_type=jnp.float32)
+    return y.astype(q.dtype).reshape(B, T, H, D)
 
 
 def dequantize_pages(pages, scales):

@@ -75,7 +75,16 @@ sized slots first: ``max_slots`` by the state's bytes (and by the
 decode step, which moves every slot's state), ``n_pages`` then so
 generously that pages never bound the slots (docs/serving.md, "Sizing
 ``max_slots`` and ``n_pages`` when state, not pages, bounds the
-slots").
+slots"). A model whose layers keep caches of TWO SIZES
+(models/mellum.py: a sliding window in three layers of four) has K/V
+pages in its full layers, byte for byte the other K/V models', and in
+its sliding layers a RING a slot (models/kv_cache.py ``SlidingRing``):
+the last ``sliding_ring_len`` positions' keys and values, kept and
+counted as a recurrent state is (by slot, in ``state_bytes_per_slot``,
+never cleared by the host), so a context eight times the window costs
+those layers what the window does. Entries that age can be neither
+shared by prefix nor shipped, and what rewinds, quantises or shards
+them is not written: ``refuse_for_sliding_entries``.
 """
 from __future__ import annotations
 
@@ -98,10 +107,13 @@ from ray_tpu.models.kv_cache import (BlockAllocator, PagedKVLayer,
                                      check_kv_dtype,
                                      export_page_bytes,
                                      has_latent_pages,
-                                     has_recurrent_state, init_kv_pool,
+                                     has_recurrent_state,
+                                     has_sliding_entries, init_kv_pool,
                                      kv_layer_store, kv_layer_view,
                                      kv_pool_page_bytes,
                                      page_cols_from_bytes,
+                                     sliding_bytes_per_slot,
+                                     sliding_ring_len,
                                      state_bytes_per_slot)
 from ray_tpu.ops.paged_attention import paged_window_block_pages
 from ray_tpu.serve import kv_migration, obs, spec_decode
@@ -528,6 +540,33 @@ def refuse_for_latent_pages(cfg, **asked) -> None:
     }, asked)
 
 
+def refuse_for_sliding_entries(cfg, **asked) -> None:
+    """A model with sliding-window layers keeps, beside its pages, a
+    ring a slot of the window's last keys and values: entries that AGE
+    (a position's key is overwritten a ring's length later). Every
+    option that shares, rewinds, ships, re-codes or shards per-request
+    state is refused by name until it learns to."""
+    if not has_sliding_entries(cfg):
+        return
+    _refuse(cfg, "a ring of their window's keys and values a slot "
+            "instead of K/V pages", {
+        "prefix_cache": "a cached prefix's pages are shared, but the "
+                        "sliding layers' entries for that prefix were "
+                        "overwritten as the request that made them "
+                        "went on: they are gone",
+        "spec_len": "rejected drafts are rolled back by clamping a "
+                    "page offset, and no rule says yet which ring "
+                    "entries a rewound row may still read",
+        "kv_migration": "a KV pull ships pages only, and a slot's "
+                        "rings are not in its frames",
+        "kv_dtype": "the int8 code keeps one absmax scale a (page, KV "
+                    "head), and a ring has no pages: its entries would "
+                    "stay in the model's type beside int8 pages",
+        "sharding": "no partition rules exist for the rings or the "
+                    "layer that keeps them",
+    }, asked)
+
+
 def _new_round_info() -> Dict[str, int]:
     """What a round dispatched, as its ``round`` event reports it.
     ``backlog`` is the planner's (serve/scheduler.py
@@ -707,6 +746,10 @@ class LLMEngine:
         refuse_for_latent_pages(
             self.cfg, kv_dtype=kv_dtype == "int8" and kv_dtype,
             sharding=sharding is not None)
+        refuse_for_sliding_entries(
+            self.cfg, prefix_cache=prefix_cache, spec_len=spec_len,
+            kv_dtype=kv_dtype == "int8" and kv_dtype,
+            sharding=sharding is not None)
         self._sharding = sharding
         self._mesh = sharding.mesh if sharding is not None else None
         if sharding is not None:
@@ -767,9 +810,18 @@ class LLMEngine:
                                     page_bytes=self.page_bytes)
         # the state a SLOT holds in the layers that keep no pages
         # (0 bytes, and nothing below differs, for a model without)
-        self.state_bytes_per_slot = state_bytes_per_slot(self.cfg)
+        # (a sliding layer's ring among them: the window and one
+        # prefill chunk in whole pages, whatever the context)
+        self.ring_len = sliding_ring_len(self.cfg, page_size, self.PC)
+        self.sliding_window = (self.cfg.sliding_window if self.ring_len
+                               else 0)
+        self.sliding_bytes_per_slot = sliding_bytes_per_slot(
+            self.cfg, self.ring_len)
+        self.state_bytes_per_slot = state_bytes_per_slot(self.cfg,
+                                                         self.ring_len)
         self.pages = init_kv_pool(self.cfg, n_pages, page_size,
-                                  self.kv_dtype, n_slots=max_slots)
+                                  self.kv_dtype, n_slots=max_slots,
+                                  ring_len=self.ring_len)
         if sharding is not None:
             self.pages = sharding.place_kv_pool(self.pages)
         # capacity gauge: the whole-pool byte budget this engine holds
@@ -1433,12 +1485,14 @@ class LLMEngine:
                 "kv_bytes_in_use": self.alloc.bytes_in_use(),
                 "kv_bytes_total": self.alloc.bytes_total(),
                 # the other kind of request state: what the slots
-                # hold in layers that keep a recurrent state (0 for
-                # a model with pages only)
+                # hold in layers that keep a recurrent state or a
+                # sliding window's ring (0 for a model with pages
+                # only); a slot's share of it that is rings
                 "state_bytes_in_use": self.state_bytes_per_slot
                 * (len(self.slots) - free_slots),
                 "state_bytes_total": self.state_bytes_per_slot
                 * len(self.slots),
+                "sliding_bytes_per_slot": self.sliding_bytes_per_slot,
                 # Per-lane queue depth. ``queue_depth`` is the ONLINE
                 # lane only — the number routing saturation
                 # (Candidate.saturated vs max_queued) and the
@@ -1500,6 +1554,7 @@ class LLMEngine:
                 "state_bytes_in_use": 0,
                 "state_bytes_total": self.state_bytes_per_slot
                 * len(self.slots),
+                "sliding_bytes_per_slot": self.sliding_bytes_per_slot,
                 "queue_depth": len(self._wait),
                 "queue_depth_online": len(self._wait),
                 "queue_depth_batch": 0,
@@ -2604,6 +2659,7 @@ class LLMEngine:
         not the whole of a request's state."""
         refuse_for_recurrent_state(self.cfg, kv_migration="export")
         refuse_for_latent_pages(self.cfg, kv_migration="export")
+        refuse_for_sliding_entries(self.cfg, kv_migration="export")
         with self._lock:
             if self._stopped:
                 raise kv_migration.KVPullAborted(
@@ -2806,10 +2862,19 @@ class LLMEngine:
         """Record the sum of the riders' own context lengths when each
         rider's last query of a decode (or verify) dispatch sits at its
         ``end - 1``: the ``round`` event's and the stats'
-        ``decode_context_tokens``, from the host's positions."""
-        total = int(sum(ends))
+        ``decode_context_tokens``, from the host's positions. For a
+        model with sliding-window layers also ``decode_sliding_keys``:
+        the same sum with each rider's context cut at the window, the
+        keys ONE sliding layer's last step has to score."""
+        ends = [int(e) for e in ends]
+        total = sum(ends)
         self._round_info["decode_context_tokens"] += total
         self.stats["decode_context_tokens"] += total
+        if self.sliding_window:
+            keys = sum(min(e, self.sliding_window) for e in ends)
+            self._round_info["decode_sliding_keys"] = (
+                self._round_info.get("decode_sliding_keys", 0) + keys)
+            self.stats["decode_sliding_keys"] += keys
 
     def _note_state_slots(self, n: int) -> None:
         """``n`` slots' recurrent state was advanced by a dispatch (a

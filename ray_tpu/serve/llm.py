@@ -21,6 +21,7 @@ def _family_for(cfg):
                                         mixtral_sharding_rules)
     from ray_tpu.models.axk1 import AXK1, AXK1Config
     from ray_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig
+    from ray_tpu.models.mellum import Mellum, MellumConfig
     from ray_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
     if isinstance(cfg, AXK1Config):
         # no partition rules yet: the deployment refuses to shard it
@@ -28,6 +29,9 @@ def _family_for(cfg):
     if isinstance(cfg, KimiLinearConfig):
         # no partition rules yet: the deployment refuses to shard it
         return KimiLinear, None
+    if isinstance(cfg, MellumConfig):
+        # no partition rules yet: the deployment refuses to shard it
+        return Mellum, None
     if isinstance(cfg, MixtralConfig):
         return Mixtral, mixtral_sharding_rules(fsdp=False)
     if isinstance(cfg, SolarOpen2Config):
@@ -125,7 +129,8 @@ class LlamaDeployment:
         # a model whose layers keep a recurrent state beside K/V pages
         # (serve/engine.py says what each refusal waits for)
         from ray_tpu.serve.engine import (refuse_for_latent_pages,
-                                          refuse_for_recurrent_state)
+                                          refuse_for_recurrent_state,
+                                          refuse_for_sliding_entries)
         sharded = (self.tensor_parallel, self.expert_parallel) != (1, 1)
         refuse_for_recurrent_state(
             self.cfg, kv_migration=disaggregate and "disaggregate",
@@ -134,6 +139,12 @@ class LlamaDeployment:
         # a model whose pages hold latent entries, not K and V a head
         refuse_for_latent_pages(
             self.cfg, kv_dtype=kv_dtype == "int8" and kv_dtype,
+            kv_migration=disaggregate and "disaggregate",
+            sharding=sharded)
+        # a model whose sliding-window layers keep entries that age
+        refuse_for_sliding_entries(
+            self.cfg, prefix_cache=prefix_cache, spec_len=spec_len,
+            kv_dtype=kv_dtype == "int8" and kv_dtype,
             kv_migration=disaggregate and "disaggregate",
             sharding=sharded)
         if self.tensor_parallel > 1 or self.expert_parallel > 1:

@@ -12,7 +12,12 @@ rule, byte counts, three readers, longdoc-sat, the rehearsal cell) and
 test_kimi_linear_family.py (the Kimi-Linear family: the configuration
 against its published copy, the program against the reference at a
 share, seeded and balanced weights, byte counts by kind of layer, the
-four readers on a hand-made joined trace, gen-sat, the rehearsal cell),
+four readers on a hand-made joined trace, gen-sat, the rehearsal cell)
+and test_mellum2_family.py (the Mellum 2 family: the configuration
+against its published copy, the program against the reference and the
+reference against its quadratic form, the scored tail, byte counts by
+kind of layer, the six readers on a hand-made joined trace, the cell on
+longdoc-sat as it stands, the rehearsal cell at --trace 0 and 2),
 collected here so that the suite the driver runs guards them.
 `python -m pytest benchmarks/tests` still runs them where they live."""
 import pytest
@@ -22,7 +27,8 @@ _FILES = ("benchmarks.tests.test_families",
           "benchmarks.tests.test_olmoe_family",
           "benchmarks.tests.test_solar_open2_family",
           "benchmarks.tests.test_axk1_family",
-          "benchmarks.tests.test_kimi_linear_family")
+          "benchmarks.tests.test_kimi_linear_family",
+          "benchmarks.tests.test_mellum2_family")
 pytest.register_assert_rewrite(*_FILES)
 
 from benchmarks.tests.test_families import *          # noqa: E402,F401,F403
@@ -31,6 +37,7 @@ from benchmarks.tests.test_olmoe_family import *      # noqa: E402,F401,F403
 from benchmarks.tests.test_solar_open2_family import *  # noqa: E402,F401,F403
 from benchmarks.tests.test_axk1_family import *       # noqa: E402,F401,F403
 from benchmarks.tests.test_kimi_linear_family import *  # noqa: E402,F401,F403
+from benchmarks.tests.test_mellum2_family import *    # noqa: E402,F401,F403
 
 # Recorded without tier-1's low-optimisation XLA flags (tests/conftest.py),
 # under which the CPU draws a normal's last bits differently: the digests
@@ -54,21 +61,48 @@ del test_seeded_weights_are_the_parents_bit_for_bit    # noqa: F821
 # less those too, the case of the four dispatch readers pins them FOUR
 # BEFORE the file's last four, and benchmarks/tests/
 # test_kimi_linear_family.py::test_the_cell_and_gen_sat pins PR 39's.
+# PR 42 appended a configuration, a cell and six readers, and the cell
+# to the lists of thirteen older metrics: the three older cases run
+# against the file less those (its last configuration, its last cell,
+# that cell's name in every list, its last six readers), the case of
+# the four dispatch readers pins them TEN before the file's end, and
+# benchmarks/tests/test_mellum2_family.py::
+# test_the_cell_and_longdoc_sat_as_it_stands pins PR 42's.
 _DISPATCH = ("dispatch_prefill_call_ms", "dispatch_decode_step_ms",
              "dispatch_prefill_share", "dispatch_prefill_call_ms.open")
 _PR39 = ("state_peak_share", "linear_state_roofline.by_kind",
          "latent_attn_roofline.by_kind", "moe_experts_roofline.by_kind")
+_PR42 = ("decode_sliding_attn_ms", "decode_full_attn_ms",
+         "sliding_attn_roofline", "prefill_sliding_attn_share",
+         "prefill_full_attn_share", "sliding_resident_share")
+_PR42_CELL, _PR42_CONFIG = "mellum2-d8.longdoc-sat", "mellum2-12b-a2.5b-d8"
 _SAT = ["mistral7b-d16.chat-sat", "olmoe-d8.chat-sat",
         "solar-open2-d4.doc-sat", "axk1-d5.longdoc-sat",
-        "kimi-linear-d8.gen-sat"]
+        "kimi-linear-d8.gen-sat", _PR42_CELL]
 
 
-def _less_the_dispatch_readers(case):
+def _less_pr42(bench):
+    """BENCHMARK.json as PR 40 left it: without PR 42's configuration,
+    cell and readers, and without the cell's name in any list."""
+    assert bench["configs"][-1]["name"] == _PR42_CONFIG
+    assert bench["workloads"][-1]["name"] == _PR42_CELL
+    bench["configs"], bench["workloads"] = (bench["configs"][:-1],
+                                            bench["workloads"][:-1])
+    for section in ("end_to_end", "per_layer"):
+        bench[section] = [
+            dict(m, workloads=[w for w in m["workloads"]
+                               if w != _PR42_CELL])
+            if "workloads" in m else m
+            for m in bench[section] if m["name"] not in _PR42]
+    return bench
+
+
+def _less_the_dispatch_readers(case, also=_DISPATCH + _PR39):
     def test(monkeypatch):
         from benchmarks import common
-        bench = common.load_benchmark()
+        bench = _less_pr42(common.load_benchmark())
         bench["per_layer"] = [m for m in bench["per_layer"]
-                              if m["name"] not in _DISPATCH + _PR39]
+                              if m["name"] not in also]
         monkeypatch.setattr(common, "load_benchmark", lambda: bench)
         case()
     test.__name__ = case.__name__
@@ -80,14 +114,16 @@ test_the_cell_and_doc_sat = _less_the_dispatch_readers(
     test_the_cell_and_doc_sat)                          # noqa: F821
 test_the_cell_and_longdoc_sat = _less_the_dispatch_readers(
     test_the_cell_and_longdoc_sat)                      # noqa: F821
+test_the_cell_and_gen_sat = _less_the_dispatch_readers(
+    test_the_cell_and_gen_sat, also=())                 # noqa: F821
 
 
 @pytest.mark.parametrize("name", _DISPATCH)
 def test_dispatch_readers_are_appended_to_the_benchmark(name):
     from benchmarks import common
     bench = common.load_benchmark()
-    assert tuple(m["name"] for m in bench["per_layer"][-8:]) == \
-        _DISPATCH + _PR39
+    assert tuple(m["name"] for m in bench["per_layer"][-14:]) == \
+        _DISPATCH + _PR39 + _PR42
     m = common.find_named(bench["per_layer"], name, "metric")
     want = {"name": name, "unit": "ms", "better": "lower",
             "source": "device_trace", "layer": "model step",

@@ -142,10 +142,17 @@ def test_deep_verify_catches_same_size_corruption(tmp_path):
     assert "hash" in reason
 
 
-def test_commit_displaces_existing_directory(tmp_path):
+@pytest.mark.parametrize("exchange", [True, False])
+def test_commit_displaces_existing_directory(tmp_path, monkeypatch,
+                                             exchange):
     """Re-saving over an old checkpoint swaps it atomically — the
-    target is never a half-written mix of the two."""
+    target is never a half-written mix of the two — by one exchange of
+    the two directories, or by two renames where the platform has no
+    such call; neither leaves a staged or displaced directory behind."""
+    from ray_tpu.air import checkpoint as ckpt
     from ray_tpu.air.checkpoint import load_manifest
+    if not exchange:
+        monkeypatch.setattr(ckpt, "_exchange", lambda a, b: False)
     target = tmp_path / "slot"
     Checkpoint.from_dict({"v": 1, "step": 1}).to_directory(
         str(target), step=1)
@@ -153,3 +160,4 @@ def test_commit_displaces_existing_directory(tmp_path):
         str(target), step=2)
     assert load_manifest(str(target))["step"] == 2
     assert Checkpoint.from_directory(str(target)).to_dict()["v"] == 2
+    assert os.listdir(tmp_path) == ["slot"]

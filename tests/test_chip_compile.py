@@ -541,3 +541,89 @@ def test_a_model_with_no_kv_layer_keeps_both_kinds_in_place(
         # four rows' chunks, whatever the slots
         assert "kda_step" not in text
         assert temp < 2 * one_state, (name, temp)
+
+
+# ---------------------------------------------------------------
+# A model whose layers keep caches of two sizes at its cell's widths
+# (Mellum 2: 32 heads over 4 KV heads of 128, a window of 1,024 in a
+# ring of 1,344 positions a slot, 32 slots, 4,481 pages of 64, a page
+# table 256 wide; one period of four layers with 2 of 64 experts held
+# keeps the compile short): the rings stay where they lie, head-major
+# inside a slot, in both programs, and the full layer's pool is the
+# other K/V models'.
+
+def _two_sizes_step(name, one_chip):
+    from ray_tpu.models.kv_cache import init_kv_pool, sliding_ring_len
+    from ray_tpu.models.mellum import Mellum, mellum2_12b
+    from ray_tpu.serve import engine as engine_mod
+    cfg = mellum2_12b(n_layers=4, max_seq_len=16384, experts_held=(0, 2),
+                      param_dtype=jnp.bfloat16)
+    model = Mellum(cfg)
+    ring_len = sliding_ring_len(cfg, PAGE, 256)
+    assert ring_len == 1344
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                           sharding=one_chip), tree)
+    params = placed({"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]})
+    pages = placed(jax.eval_shape(
+        lambda: init_kv_pool(cfg, 4481, PAGE, n_slots=SLOTS,
+                             ring_len=ring_len)))
+    i32 = jnp.int32
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    table = ((SLOTS, cfg.max_seq_len // PAGE), i32)
+    if name == "decode":
+        fn = engine_mod._jit_decode(model, 0.0, 128, SLOTS, False, None)
+        rest = [table, ((SLOTS,), i32), ((SLOTS,), i32),
+                (key.shape, key.dtype), ((), i32)]
+    else:
+        fn = engine_mod._jit_prefill(model, 0.0, 4, False, None)
+        rest = [((4, 256), i32), ((4,), i32), ((4,), i32),
+                ((4, table[0][1]), i32), (key.shape, key.dtype),
+                ((4,), i32)]
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in rest]
+    return fn.lower(params, pages, *rest).compile()
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_sliding_rings_and_pages_stay_in_place(one_chip, monkeypatch,
+                                               name):
+    from ray_tpu.ops import grouped_matmul as gm
+    monkeypatch.setattr(gm, "_use_kernel", lambda: True)
+    compiled = _two_sizes_step(name, one_chip)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text          # the experts' grouped matmul
+    # a ring is [slots, KV heads, positions, head]: as [slots,
+    # positions, KV heads, head] every decode step copied each layer's k
+    # and v ring head-major for the two contractions, and a token's
+    # [KV heads, head] rows scattered as one window asked for the other
+    # layout again (PR 42, read off this text)
+    ring = r"bf16\[32,4,1344,128\]"
+    entry = re.findall(ring + r"(\{[^}]*\}) parameter", text)
+    assert len(entry) >= 6 and all(e.startswith("{3,2,1,0") for e in entry)
+    pool = r"bf16\[4481,64,4,128\]"
+    for what, shape in (("ring", ring), ("pool", pool)):
+        copies = re.findall(
+            r"= " + shape + r"(?:\{[^}]*\})? (?:copy|transpose)\(", text)
+        assert not copies, f"{len(copies)} whole-{what} copies in {name}"
+    moved = _pool_copies(text, (32, 4, 1344, 128)) + _pool_copies(
+        text, (4481, 64, 4, 128))
+    assert not moved, moved[:4]
+    # three sliding layers' attention and one full layer's, by scope
+    for scope in ("attn_sliding/ring_append", "attn_sliding/ring_scores",
+                  "attn_sliding/ring_pv", "attn_full/kv_gather"):
+        assert scope in text, scope
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    one_ring = 2 * 32 * 4 * 1344 * 128 * 2       # a layer's k and v, 88 MB
+    if name == "decode":
+        # the step's own temporaries (the mixture's rows, the head's
+        # logits, a gathered block of pages): not a second ring a layer
+        assert temp < 2 * one_ring, (name, temp)
+    else:
+        # four rows of 256 queries: the block loop's float32 scores
+        # [4, 32, 256, 512] and the mixture's 8,192 routed rows
+        assert temp < 1 << 30, (name, temp)

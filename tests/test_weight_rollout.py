@@ -175,9 +175,18 @@ def test_shutdown_releases_pending_drain_swap(tiny_model):
     waiter typed, not hang it."""
     from ray_tpu.serve.errors import EngineShutdown
     model, params = tiny_model
-    eng = _engine(model, params, fault_injector=_slow_rounds())
+    eng = _engine(model, params, fault_injector=_slow_rounds(0.25))
     prompt = [7, 7, 7, 7]
     eng.submit(list(prompt), max_new_tokens=64, deadline_s=30)
+    # the request must HOLD A SLOT before the swap is asked for: a drain
+    # swap pauses admission, so one that lands while the request still
+    # queues finds the engine settled, applies at once and the waiter
+    # returns (one run in a few failed so, also before PR 42)
+    deadline = time.monotonic() + 10
+    while (all(s is None for s in eng.slots)
+           and time.monotonic() < deadline):
+        time.sleep(0.002)
+    assert any(s is not None for s in eng.slots)
     err = {}
 
     def swapper():
