@@ -9,9 +9,9 @@ through the entry points a user calls:
             from a seed; concurrent, streamed and HTTP requests; greedy
             output checked against the model's cache-free full forward;
             one int8-KV request checked the same way
-  kernels   the Pallas flash-attention kernel (fwd+bwd) and the
-            one-token delta-rule kernel, compiled, each against its
-            XLA reference
+  kernels   the Pallas flash-attention kernel (fwd+bwd), the one-token
+            delta-rule kernel and the latent-page prefill attention
+            kernel, compiled, each against its XLA reference
   training  GPT-2-124M at batch 24 x 1024 through shard_state /
             put_batch / make_train_step; flash kernel present in the
             compiled step; loss finite and falling
@@ -354,13 +354,19 @@ def _rel_err(a, b) -> float:
 def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
                                   (8, 1024, 32, 64)),
                  kda_shapes=((32, 64, 128),),
+                 window_shapes=((256, 64, 640, 512,
+                                 (512, 8192, None, 2304), None),),
                  interpret: bool = False, seed: int = SEED) -> dict:
     """The flash-attention kernel, compiled (not interpreted, unless
     the CPU rehearsal asks) and compared with its XLA reference,
     forward and backward; the one-token delta-rule kernel at a serving
     cell's state (slots, heads, head width) against the ``jax.numpy``
     form, two chained steps with a row that starts a request and a row
-    that rides nothing."""
+    that rides nothing; a prefill chunk's attention over latent pages
+    (``window_shapes``: chunk, heads, entry and value widths, where
+    each row's window ends, None a row no request owns, and a query
+    tile's tokens where not the kernel's own) at a serving cell's shape
+    against the block loop."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -432,6 +438,39 @@ def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
                 errs[f"{name}_{n}"] = _rel_err(a[rides], b[rides])
             assert (np.asarray(got[2])[1] == np.asarray(state)[1]).all(), (
                 f"{name}: the row that rides nothing moved its state")
+
+    from unittest import mock
+
+    from ray_tpu.ops import latent_window_attention as lw
+    from ray_tpu.ops import paged_attention as pa
+    page, max_pages = 64, 256
+    for T, H, D, Dv, ends, tokens in window_shapes:
+        name = f"latent_window_T{T}_H{H}_D{D}"
+        B = len(ends)
+        dtype = jnp.float32 if interpret else jnp.bfloat16
+        ids = 1 + rng.permutation(B * max_pages).reshape(B, max_pages)
+        table = np.zeros((B, max_pages), np.int32)
+        pos = np.full((B,), 10 ** 6, np.int32)      # stale where null
+        for b, end in enumerate(ends):
+            if end is not None:
+                pos[b] = end - T
+                table[b, :-(-end // page)] = ids[b, :-(-end // page)]
+        pages = jnp.asarray(
+            rng.standard_normal((1 + B * max_pages, page, D)), dtype)
+        q = jnp.asarray(rng.standard_normal((B, T, H, D)), dtype)
+        live = [b for b, end in enumerate(ends) if end is not None]
+        with timed(f"kernels: {name}"):
+            got = jax.jit(functools.partial(
+                lw.latent_window_attention, softmax_scale=D ** -0.5,
+                value_dim=Dv, tokens=tokens, interpret=interpret,
+                block_pages=pa.paged_window_block_pages(page, max_pages))
+            )(q, pages, table, pos)
+            with mock.patch.object(lw, "_on_one_tpu", lambda: False):
+                want = jax.jit(functools.partial(
+                    pa._paged_window_attention, softmax_scale=D ** -0.5,
+                    value_dim=Dv))(q, pages, None, None, None, table, pos)
+            errs[name] = _rel_err(np.asarray(got, np.float32)[live],
+                                  np.asarray(want, np.float32)[live])
 
     for name, e in errs.items():
         log(f"[kernels] {name}: rel err {e:.2e}")

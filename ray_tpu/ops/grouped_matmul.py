@@ -28,11 +28,18 @@ import jax.numpy as jnp
 _TILE_M, _TILE_K, _TILE_N = 128, 2048, 1024
 
 
-def _use_kernel() -> bool:
+def on_one_tpu() -> bool:
+    """The backend is a TPU and no multi-device mesh is ambient: one
+    rule for every Mosaic kernel that has an XLA form. Each such module
+    asks it under a name of its own, which is what a test steers."""
     if jax.default_backend() != "tpu":
         return False
     mesh = jax.sharding.get_abstract_mesh()
     return mesh.empty or mesh.size == 1
+
+
+def _use_kernel() -> bool:
+    return on_one_tpu()
 
 
 def grouped_matmul(rows, w, group_sizes):

@@ -60,7 +60,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 # the backend is a TPU and no multi-device mesh is ambient: one rule
 # for every Mosaic kernel that has an XLA form
-from ray_tpu.ops.grouped_matmul import _use_kernel as _on_one_tpu
+from ray_tpu.ops.grouped_matmul import on_one_tpu as _on_one_tpu
 
 F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST

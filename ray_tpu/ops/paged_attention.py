@@ -28,8 +28,22 @@ the layout:
 - ``paged_append`` scatters a chunk of new K/V at each slot's write
   offset;
 - ``_paged_window_attention`` attends a chunk of queries over each
-  slot's pages, gathered a block of pages at a time up to the longest
-  live context.
+  slot's pages, a block of pages at a time. It has TWO FORMS and a rule
+  between them that reads only what the function can see (its
+  arguments' shapes and types, the backend, the ambient mesh; never a
+  flag). The LOOP, plain XLA: blocks gathered by page id up to the
+  longest live context, the online softmax's running statistics and
+  accumulator carried through a loop with a runtime trip count. It
+  serves every K/V pool (fp and int8, one chip or tensor-parallel),
+  every decode step and speculative verify, the CPU and any mesh. The
+  KERNEL (ops/latent_window_attention.py): a PREFILL CHUNK over a
+  LATENT pool on one TPU, where thousands of (token, head) query rows
+  read one key and the loop's float32 scores and accumulator, 134 MB
+  each a block in HBM, held the two contractions at a third of the
+  matrix unit; one Pallas call keeps them in VMEM and walks each row to
+  its own last block (PERF.md section 6, PR 43). The two share the
+  mathematics and no code: a K/V pool with 2-8 query heads a KV head is
+  another regime, with no record that the loop loses there.
 
 Inactive slots point at the null page: their writes land there, the
 causal mask hides it from every live query, and their outputs are
@@ -52,6 +66,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ray_tpu.ops import latent_window_attention as latent_window
 
 # Int8 pages use a symmetric absmax code: value = q * scale / 127 with
 # q in [-127, 127] (-128 unused so the code is symmetric). One fp32
@@ -313,6 +329,13 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
     ONCE and the result is [B, T, H, value_dim]. ``softmax_scale``:
     what the scores are multiplied by, where it is not ``D ** -0.5``.
 
+    A chunk of at least one query tile over a latent pool of bfloat16
+    entries, on a TPU outside any multi-device mesh, is ONE Pallas
+    kernel (``latent_window.applies``; ops/latent_window_attention.py:
+    the same mathematics, the scores and the accumulator in VMEM, each
+    row walked to its own last block), called under the ``attn_scores``
+    scope. Everything else is the loop below.
+
     Work follows the live contexts, not the table's width: a loop with
     a RUNTIME trip count walks blocks of ``block_pages`` logical pages
     up to the block holding the last position any live row can see,
@@ -352,6 +375,15 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
     block_pages = paged_window_block_pages(Pg, max_pages)
     Lb = block_pages * Pg
     max_blocks = -(-max_pages // block_pages)
+    if pv is None and sk is None and latent_window.applies(q, pk, Dv):
+        # under one of the loop's scopes: a device trace's split of a
+        # step by scope keeps counting it as attention
+        with jax.named_scope("attn_scores"):
+            return latent_window.latent_window_attention(
+                q, pk, page_table, pos, value_dim=Dv,
+                softmax_scale=(D ** -0.5 if softmax_scale is None
+                               else softmax_scale),
+                block_pages=block_pages)
     # Grouped-query attention WITHOUT materializing repeated K/V: q
     # reshapes to [B, T, KH, rep, D] and contracts against the grouped
     # cache directly (a repeat would move rep x the KV bytes a step).

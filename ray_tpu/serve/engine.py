@@ -111,10 +111,12 @@ from ray_tpu.models.kv_cache import (BlockAllocator, PagedKVLayer,
                                      has_sliding_entries, init_kv_pool,
                                      kv_layer_store, kv_layer_view,
                                      kv_pool_page_bytes,
+                                     latent_page_width,
                                      page_cols_from_bytes,
                                      sliding_bytes_per_slot,
                                      sliding_ring_len,
                                      state_bytes_per_slot)
+from ray_tpu.ops import latent_window_attention as latent_window
 from ray_tpu.ops.paged_attention import paged_window_block_pages
 from ray_tpu.serve import kv_migration, obs, spec_decode
 # Typed lifecycle errors live in a jax-free module (serve/errors.py)
@@ -581,12 +583,16 @@ def _new_round_info() -> Dict[str, int]:
     riders of their OWN context lengths after it (what each rider's
     last step attended), where ``decode_window_tokens`` is the longest
     rider's, rounded up to a block: the tokens a paged attention MUST
-    read, beside those its block loop does."""
+    read, beside those its block loop does. ``prefill_kernel_blocks``
+    is 0 where the prefill program holds no kernel for its latent
+    layers' attention (ops/latent_window_attention.py), else the key
+    blocks ONE such layer's kernel visits over the call's live rows,
+    each row to the block of its own last query."""
     return {"decode_riders": 0, "decode_steps": 0, "backlog": 0,
             "decode_window_tokens": 0, "decode_context_tokens": 0,
             "prefill_tokens": 0, "prefill_budget": 0,
             "prefill_rows": 0, "prefill_window_tokens": 0,
-            "prefill_width": 0}
+            "prefill_kernel_blocks": 0, "prefill_width": 0}
 
 
 class LLMEngine:
@@ -2846,6 +2852,15 @@ class LLMEngine:
                                           else LANE_ONLINE)})
         self._wait.appendleft(slot.req)   # front: re-admit first
 
+    def _prefill_kernel_serves(self, T: int) -> bool:
+        """Whether the ``[rows, T]`` prefill program's latent layers
+        attend through the kernel: the question
+        ``_paged_window_attention`` asks of the same shapes."""
+        cfg = self.cfg
+        return has_latent_pages(cfg) and latent_window.serves(
+            T, cfg.n_heads, latent_page_width(cfg), cfg.kv_lora_rank,
+            self.Pg, cfg.dtype)
+
     def _note_window(self, key: str, end: int) -> None:
         """Record under ``key`` the positions a dispatch's paged
         attention gathers and attends when its longest live row's last
@@ -3437,6 +3452,12 @@ class LLMEngine:
         # every row's queries run to start + T, padding and all
         self._note_window("prefill_window_tokens",
                           int(start[:len(rows)].max()) + T)
+        if self._prefill_kernel_serves(T):
+            blocks = latent_window.kernel_blocks(
+                start[:len(rows)], T, self._window_block,
+                -(-self.max_pages * self.Pg // self._window_block))
+            self._round_info["prefill_kernel_blocks"] += blocks
+            self.stats["prefill_kernel_blocks"] += blocks
         self.stats["prefilled_seqs"] += len(placements)
         self._hb = time.monotonic()   # dispatch completed: a long
                                       # prompt prefilling chunk by

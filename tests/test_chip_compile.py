@@ -544,6 +544,68 @@ def test_a_model_with_no_kv_layer_keeps_both_kinds_in_place(
 
 
 # ---------------------------------------------------------------
+# Both latent families' [4, 256] prefill program with the chunk's
+# attention over latent pages as ONE kernel a latent layer
+# (ops/latent_window_attention.py), which a TPU outside any mesh
+# chooses: nothing of a block's float32 scores or accumulator
+# ([4, 1, heads, 256, 512] in the loop's form: 134 MB at 64 heads) is
+# among the program's temporaries or a loop's carry, and the pool is
+# read by page id where it lies.
+
+def _assert_prefill_attends_in_the_kernel(compiled, heads, layers, pool,
+                                          temp_limit):
+    text = compiled.as_text()
+    calls = re.findall(
+        r"custom-call\([^\n]*/attn_scores/latent_window[^\n]*", text)
+    assert len(calls) == layers, (len(calls), layers)
+    for call in calls:
+        # the pool goes in as it is stored, a page an operand block
+        assert call.count("bf16[%s]{2,1,0}" % pool) == 8, call[:400]
+    block = 4 * heads * 256 * 512
+    scores = sorted({m.group(0) for m in re.finditer(
+        r"= f32\[([0-9,]+)\]", text)
+        if math.prod(int(d) for d in m.group(1).split(",")) == block})
+    assert not scores, scores
+    assert "attn_pv" not in text and "bskd->bkrts" not in text
+    entry = re.search(r"bf16\[%s\](\{[^}]*\}) parameter" % pool, text)
+    assert entry and entry.group(1).startswith("{2,1,0"), entry
+    copies = re.findall(
+        r"= bf16\[%s\](?:\{[^}]*\})? copy\(" % pool, text)
+    assert not copies, f"{len(copies)} whole-pool copies"
+    # every layer's pool is the program's argument and its result
+    assert text.count("may-alias") >= layers, text[:400]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < temp_limit, temp
+
+
+@pytest.mark.parametrize("family", ["axk1", "kimi_linear"])
+def test_latent_prefill_attends_in_one_kernel_a_layer(one_chip,
+                                                      monkeypatch, family):
+    from ray_tpu.ops import grouped_matmul as gm
+    from ray_tpu.ops import latent_window_attention as lw
+    from ray_tpu.ops import linear_attention as la
+    from ray_tpu.serve import engine as engine_mod
+    for mod, name in ((gm, "_use_kernel"), (la, "_on_one_tpu"),
+                      (lw, "_on_one_tpu")):
+        monkeypatch.setattr(mod, name, lambda: True)
+    # the programs are cached by (model, knobs): the cases above traced
+    # these models' with the loop, and no later one may find the kernel's
+    monkeypatch.setattr(engine_mod, "_jit_prefill",
+                        engine_mod._jit_prefill.__wrapped__)
+    if family == "axk1":
+        # two latent layers; the loop's form takes 526 MB of
+        # temporaries here, this one 188
+        _assert_prefill_attends_in_the_kernel(
+            _latent_step("prefill", one_chip), 64, 2, "4353,64,640",
+            256 << 20)
+    else:
+        # one latent layer of four; the rest is the delta-rule layers'
+        _assert_prefill_attends_in_the_kernel(
+            _no_kv_step("prefill", one_chip), 32, 1, "4609,64,640",
+            2 * 128 * 32 * 128 * 128 * 4)
+
+
+# ---------------------------------------------------------------
 # A model whose layers keep caches of two sizes at its cell's widths
 # (Mellum 2: 32 heads over 4 KV heads of 128, a window of 1,024 in a
 # ring of 1,344 positions a slot, 32 slots, 4,481 pages of 64, a page

@@ -27,8 +27,10 @@ def test_serving_phase_rehearsal(rt):
 def test_kernel_phase_rehearsal():
     errs = chip_smoke.kernel_phase(flash_shapes=((1, 128, 2, 64),),
                                    kda_shapes=((3, 8, 128),),
+                                   window_shapes=((32, 16, 256, 128,
+                                                   (64, None, 600), 16),),
                                    interpret=True)
-    assert {n.split("_")[0] for n in errs} == {"flash", "kda"}
+    assert {n.split("_")[0] for n in errs} == {"flash", "kda", "latent"}
 
 
 def test_training_phase_rehearsal():
