@@ -10,8 +10,9 @@ through the entry points a user calls:
             output checked against the model's cache-free full forward;
             one int8-KV request checked the same way
   kernels   the Pallas flash-attention kernel (fwd+bwd), the one-token
-            delta-rule kernel and the latent-page prefill attention
-            kernel, compiled, each against its XLA reference
+            delta-rule kernel, the latent-page prefill attention kernel
+            and the mixture's grouped matmul under its tile plan,
+            compiled, each against its XLA reference
   training  GPT-2-124M at batch 24 x 1024 through shard_state /
             put_batch / make_train_step; flash kernel present in the
             compiled step; loss finite and falling
@@ -356,6 +357,7 @@ def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
                  kda_shapes=((32, 64, 128),),
                  window_shapes=((256, 64, 640, 512,
                                  (512, 8192, None, 2304), None),),
+                 gmm_shapes=((64, 2304, 1024, 256, 200),),
                  interpret: bool = False, seed: int = SEED) -> dict:
     """The flash-attention kernel, compiled (not interpreted, unless
     the CPU rehearsal asks) and compared with its XLA reference,
@@ -366,7 +368,10 @@ def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
     (``window_shapes``: chunk, heads, entry and value widths, where
     each row's window ends, None a row no request owns, and a query
     tile's tokens where not the kernel's own) at a serving cell's shape
-    against the block loop."""
+    against the block loop; the mixture's grouped matmul
+    (``gmm_shapes``: experts, K, N, sorted pairs, pairs that have an
+    expert) at a contraction the tile plan takes whole where constants
+    left a remainder, against ``jax.lax.ragged_dot`` in float32."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -471,6 +476,23 @@ def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
                     value_dim=Dv))(q, pages, None, None, None, table, pos)
             errs[name] = _rel_err(np.asarray(got, np.float32)[live],
                                   np.asarray(want, np.float32)[live])
+
+    from ray_tpu.ops import grouped_matmul as gm
+    for E, K, N, M, held in gmm_shapes:
+        name = f"grouped_matmul_E{E}_K{K}_N{N}_M{M}"
+        dtype = jnp.float32 if interpret else jnp.bfloat16
+        rows = jnp.asarray(rng.standard_normal((M, K)), dtype)
+        w = jnp.asarray(rng.standard_normal((E, K, N)) * K ** -0.5, dtype)
+        sizes = jnp.asarray(rng.multinomial(held, np.full(E, 1.0 / E)),
+                            jnp.int32)
+        with timed(f"kernels: {name}"):
+            got = jax.jit(functools.partial(
+                gm.grouped_matmul_kernel, interpret=interpret))(
+                    rows, w, sizes)
+            want = jax.jit(functools.partial(
+                jax.lax.ragged_dot, precision="highest"))(
+                    rows.astype(jnp.float32), w.astype(jnp.float32), sizes)
+            errs[name] = _rel_err(got[:held], want[:held])
 
     for name, e in errs.items():
         log(f"[kernels] {name}: rel err {e:.2e}")

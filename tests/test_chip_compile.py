@@ -83,15 +83,22 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, monkeypatch,
     assert compiled.as_text().count("tpu_custom_call") == calls
 
 
-# (sorted pairs, what calls with them): OLMoE-1B-7B's experts (64 of
-# 2048 x 1024, 8 a token) in a 32-slot decode call and in a 4 x 256
-# prefill call, the three matmuls of the mixture
-@pytest.mark.parametrize("M", [256, 8192], ids=["decode", "prefill"])
-def test_grouped_matmul_compiles(one_chip, monkeypatch, M):
+# (experts held, D, F, the sorted pairs of a decode call: 8 a token of
+# 32 slots, Kimi-Linear's 128) of the five mixtures the serving cells
+# run; a 4 x 256 prefill call sorts 8,192; the three matmuls of the
+# mixture, each under its own tile plan, none past the memory a kernel
+# may use
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+@pytest.mark.parametrize("E,D,F,decode_pairs", [
+    (64, 2048, 1024, 256), (40, 4096, 1280, 256), (12, 7168, 2048, 256),
+    (64, 2304, 1024, 1024), (64, 2304, 896, 256)],
+    ids=["olmoe", "solar-open2", "axk1", "kimi-linear", "mellum2"])
+def test_grouped_matmul_compiles(one_chip, monkeypatch, E, D, F,
+                                 decode_pairs, kind):
     from ray_tpu.ops import grouped_matmul as gm
     # the op asks the attached backend, which is the CPU here
     monkeypatch.setattr(gm, "_use_kernel", lambda: True)
-    E, D, F = 64, 2048, 1024
+    M = 8192 if kind == "prefill" else decode_pairs
 
     def experts(rows, w1, w3, w2, group_sizes):
         h = jax.nn.silu(gm.grouped_matmul(rows, w1, group_sizes)) * \
@@ -102,7 +109,7 @@ def test_grouped_matmul_compiles(one_chip, monkeypatch, M):
         experts, one_chip, ((M, D), jnp.bfloat16),
         ((E, D, F), jnp.bfloat16), ((E, D, F), jnp.bfloat16),
         ((E, F, D), jnp.bfloat16), ((E,), jnp.int32))
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert compiled.as_text().count("tpu_custom_call") == 3
 
 
 # (slots, heads): the recurrent state of kimi-linear-d8.gen-sat and of

@@ -29,8 +29,10 @@ def test_kernel_phase_rehearsal():
                                    kda_shapes=((3, 8, 128),),
                                    window_shapes=((32, 16, 256, 128,
                                                    (64, None, 600), 16),),
+                                   gmm_shapes=((4, 384, 256, 200, 150),),
                                    interpret=True)
-    assert {n.split("_")[0] for n in errs} == {"flash", "kda", "latent"}
+    assert {n.split("_")[0] for n in errs} == {"flash", "kda", "latent",
+                                               "grouped"}
 
 
 def test_training_phase_rehearsal():
