@@ -106,9 +106,8 @@ def main(model_name: str = "gpt2"):
         from ray_tpu.models.gpt2 import (flops_per_token,
                                          linear_cross_entropy)
         # batch 24 + packed flash attention (blk 1024) + lse-gather CE
-        # was the per-chip sweet spot of the pre-PR-1 sweeps
-        # (tools/mfu_sweep.py / mfu_round2.py); not re-measured on the
-        # current machine.
+        # was the per-chip sweet spot of the pre-PR-1 sweeps; not
+        # re-measured on the current machine.
         batch = 24 * n_chips
         cfg = gpt2_124m()
         model = GPT2(cfg)

@@ -64,8 +64,7 @@ define_flag("bulk_pull_global_slots", int, 2,
             "Cluster-wide cap on concurrent bulk pulls. On shared/"
             "virtualized hosts concurrent bulk memory traffic "
             "degrades superlinearly (originally 0.8s solo vs 28s x4 "
-            "for a 1 GiB copy; reproduce on any host with "
-            "tools/bench_broadcast_degradation.py), so transfers are "
+            "for a 1 GiB copy), so transfers are "
             "serialized near the host's effective bandwidth; raise "
             "on real multi-host clusters where each node has its own "
             "memory bus.")

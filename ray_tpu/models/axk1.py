@@ -100,6 +100,12 @@ class AXK1Config:
         return (KIND_LATENT,) * self.n_layers
 
     @property
+    def model_class(self):
+        """What a deployment builds (models/llama.py ``LlamaConfig``);
+        it declares no partition rules: none exist yet."""
+        return AXK1
+
+    @property
     def latent_dim(self) -> int:
         """A cached token's entry a layer: ``[c | k_r]``."""
         return self.kv_lora_rank + self.qk_rope_head_dim

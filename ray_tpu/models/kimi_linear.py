@@ -97,6 +97,12 @@ class KimiLinearConfig:
                      for i in range(self.n_layers))
 
     @property
+    def model_class(self):
+        """What a deployment builds (models/llama.py ``LlamaConfig``);
+        it declares no partition rules: none exist yet."""
+        return KimiLinear
+
+    @property
     def latent_dim(self) -> int:
         """A cached token's entry an MLA layer: ``[c | r]``."""
         return self.kv_lora_rank + self.qk_rope_head_dim

@@ -112,16 +112,91 @@ def layer_kinds(cfg) -> Tuple[str, ...]:
                  or (KIND_KV,) * cfg.n_layers)
 
 
-def has_recurrent_state(cfg) -> bool:
-    return KIND_RECURRENT in layer_kinds(cfg)
-
-
 def has_latent_pages(cfg) -> bool:
     return KIND_LATENT in layer_kinds(cfg)
 
 
 def has_sliding_entries(cfg) -> bool:
     return KIND_SLIDING in layer_kinds(cfg)
+
+
+# What a kind of request state CANNOT do yet, the other half of
+# ``page_layout``'s table: for each kind, what its layers keep and, for
+# every option that shares, rewinds, ships, re-codes or shards
+# per-request state and cannot handle that, the reason. A recurrent
+# state can be neither snapshotted at a page boundary nor rewound. A
+# latent page is handed out and shared by page id as any other (so the
+# prefix cache and speculative decoding, which deal in page ids and a
+# page offset only, serve it), but its payload is one latent entry a
+# token and not K and V a head. A ring's entries AGE (a position's key
+# is overwritten a ring's length later). A kind's options stand in the
+# order a deployment is asked them: the first one set is the one its
+# refusal names (docs/serving.md says what would lift each).
+KIND_REFUSALS = {
+    KIND_KV: ("K/V pages", {}),
+    KIND_RECURRENT: ("a recurrent state a slot instead of K/V pages", {
+        "kv_migration": "a KV pull ships pages only, and the recurrent "
+                        "state is not in its frames",
+        "prefix_cache": "a cached prefix's pages are shared, but the "
+                        "recurrent state after that prefix was never "
+                        "snapshotted",
+        "spec_len": "rejected drafts are rolled back by clamping a "
+                    "page offset, and a recurrent state cannot be "
+                    "rewound",
+        "sharding": "no partition rules exist for the recurrent state "
+                    "or the layer that keeps it",
+    }),
+    KIND_LATENT: ("latent pages instead of K/V pages", {
+        "kv_dtype": "the int8 code keeps one absmax scale a (page, KV "
+                    "head), and a latent entry has no heads: one scale "
+                    "would span the compressed vector and the rope key "
+                    "alike",
+        "kv_migration": "a KV pull's frames carry K and V a head, and "
+                        "no frame exists for a latent page",
+        "sharding": "the pool shards over the KV-head axis, and the "
+                    "one latent entry every head reads cannot be split "
+                    "over it; no partition rules exist for the layer",
+    }),
+    KIND_SLIDING: ("a ring of their window's keys and values a slot "
+                   "instead of K/V pages", {
+        "prefix_cache": "a cached prefix's pages are shared, but the "
+                        "sliding layers' entries for that prefix were "
+                        "overwritten as the request that made them "
+                        "went on: they are gone",
+        "spec_len": "rejected drafts are rolled back by clamping a "
+                    "page offset, and no rule says yet which ring "
+                    "entries a rewound row may still read",
+        "kv_dtype": "the int8 code keeps one absmax scale a (page, KV "
+                    "head), and a ring has no pages: its entries would "
+                    "stay in the model's type beside int8 pages",
+        "kv_migration": "a KV pull ships pages only, and a slot's "
+                        "rings are not in its frames",
+        "sharding": "no partition rules exist for the rings or the "
+                    "layer that keeps them",
+    }),
+}
+
+
+def _refusal(cfg, kind: str, option: str, value) -> ValueError:
+    keeps, why = KIND_REFUSALS[kind]
+    return ValueError(
+        f"{option}={value!r} is not supported for "
+        f"{type(cfg).__name__}: it has layers that keep "
+        f"{keeps}; {why[option]}")
+
+
+def refuse_unsupported(cfg, **asked) -> None:
+    """ValueError for the first kind of ``cfg``'s layers (in
+    ``KIND_REFUSALS``' order) that cannot handle an option of ``asked``
+    that is set, naming the option, what the layers keep and why; a
+    model of K/V pages only passes whatever is asked."""
+    kinds = layer_kinds(cfg)
+    for kind, (_, why) in KIND_REFUSALS.items():
+        if kind not in kinds:
+            continue
+        for option in why:
+            if asked.get(option):
+                raise _refusal(cfg, kind, option, asked[option])
 
 
 def sliding_ring_len(cfg, page_size: int, prefill_chunk: int) -> int:
@@ -172,12 +247,7 @@ def page_layout(cfg, kind: str, page_size: int, kv_dtype: str = "fp"):
     quantized = check_kv_dtype(kv_dtype) == "int8"
     if kind == KIND_LATENT:
         if quantized:
-            raise ValueError(
-                f"kv_dtype='int8' is not supported for "
-                f"{type(cfg).__name__}: it has layers that keep latent "
-                f"pages, and the per-(page, KV head) absmax scale has "
-                f"no meaning for a latent entry (one scale would span "
-                f"the compressed vector and the rope key alike)")
+            raise _refusal(cfg, kind, "kv_dtype", kv_dtype)
         return (((page_size, latent_page_width(cfg)),
                  jnp.dtype(cfg.dtype)),)
     shape = (page_size, cfg.n_kv_heads, cfg.head_dim)

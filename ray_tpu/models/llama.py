@@ -54,6 +54,26 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.dim // self.n_heads
 
+    # What serving asks of a family's config: the class a deployment
+    # builds (serve/llm.py) and, where the family can be sharded, its
+    # partition rules and divisibility check (serve/sharding.py
+    # ``family_sharding_rules`` / ``validate_tp``; a config without
+    # them is refused there by name).
+    @property
+    def model_class(self):
+        return Llama
+
+    @property
+    def serving_rules(self) -> ShardingRules:
+        return llama_sharding_rules(fsdp=False)
+
+    def tp_validate(self, tp: int, ep: int = 1) -> None:
+        if ep != 1:
+            raise ValueError(
+                f"expert parallelism ep={ep} needs an MoE config, "
+                f"got {type(self).__name__}")
+        llama_tp_validate(self, tp)
+
 
 def llama2_7b(**overrides) -> LlamaConfig:
     return LlamaConfig(**overrides)

@@ -108,6 +108,12 @@ class MellumConfig:
         return tuple(KIND_SLIDING if t == SLIDING else KIND_KV
                      for t in self.layer_types[:self.n_layers])
 
+    @property
+    def model_class(self):
+        """What a deployment builds (models/llama.py ``LlamaConfig``);
+        it declares no partition rules: none exist yet."""
+        return Mellum
+
 
 def mellum2_12b(**overrides) -> MellumConfig:
     return MellumConfig(**overrides)

@@ -80,6 +80,18 @@ class MixtralConfig:
             tie_word_embeddings=self.tie_word_embeddings,
             qk_norm=self.qk_norm)
 
+    # what serving asks of a family's config (models/llama.py)
+    @property
+    def model_class(self):
+        return Mixtral
+
+    @property
+    def serving_rules(self) -> ShardingRules:
+        return mixtral_sharding_rules(fsdp=False)
+
+    def tp_validate(self, tp: int, ep: int = 1) -> None:
+        mixtral_tp_validate(self, tp, ep)
+
 
 def mixtral_8x7b(**overrides) -> MixtralConfig:
     return MixtralConfig(**overrides)
@@ -304,6 +316,13 @@ def moe_stats_vector(stats, live, num_experts: int, held=None):
     tail = [touched, fullest, layers] + (
         [] if held is None else [routed])
     return jnp.concatenate([counts, jnp.stack(tail)])
+
+
+def moe_stats_len(num_experts: int, held=None) -> int:
+    """Entries of ``moe_stats_vector``'s result: the counted experts,
+    the three sums over the layers, and ``pairs_routed`` where the
+    mixture holds a share."""
+    return num_experts + 3 if held is None else held[1] + 4
 
 
 class MixtralBlock(nn.Module):

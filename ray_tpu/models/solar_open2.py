@@ -92,6 +92,12 @@ class SolarOpen2Config:
                      else KIND_RECURRENT for i in range(self.n_layers))
 
     @property
+    def model_class(self):
+        """What a deployment builds (models/llama.py ``LlamaConfig``);
+        it declares no partition rules: none exist yet."""
+        return SolarOpen2
+
+    @property
     def kda_width(self) -> int:
         return self.kda_heads * self.kda_head_dim
 

@@ -60,15 +60,16 @@ and slots that carry no request ride a decode call without moving
 theirs. What a recurrent state cannot do yet is be snapshotted or
 rewound, so the engine refuses ``prefix_cache``, ``spec_len > 0``,
 KV export/pull and tensor/expert sharding for such a model
-(``refuse_for_recurrent_state``; docs/serving.md says what would
-lift each). A model of LATENT attention (models/axk1.py) has pages of
-a third kind: one pool a layer of one latent entry a token, handed
+(models/kv_cache.py ``refuse_unsupported``, the one table of what a
+kind of state cannot do; docs/serving.md says what would lift each).
+A model of LATENT attention (models/axk1.py) has pages of a third
+kind: one pool a layer of one latent entry a token, handed
 out, shared and rewound by page id and offset exactly as K/V pages
 are; what interprets, ships or shards a page's payload (int8 pages,
-KV export/pull, tensor sharding) is refused for it
-(``refuse_for_latent_pages``). A model of recurrent and latent layers
-ONLY (models/kimi_linear.py: no K/V layer at all) is served by the same
-pool and stands on both refusal lists at once. There a slot costs a
+KV export/pull, tensor sharding) is refused for it (the same table's
+latent row). A model of recurrent and latent layers ONLY
+(models/kimi_linear.py: no K/V layer at all) is served by the same
+pool and stands on both rows at once. There a slot costs a
 fixed ``state_bytes_per_slot`` whatever its context and a token only a
 latent entry in the few layers that have pages, so the deployment is
 sized slots first: ``max_slots`` by the state's bytes (and by the
@@ -84,14 +85,12 @@ counted as a recurrent state is (by slot, in ``state_bytes_per_slot``,
 never cleared by the host), so a context eight times the window costs
 those layers what the window does. Entries that age can be neither
 shared by prefix nor shipped, and what rewinds, quantises or shards
-them is not written: ``refuse_for_sliding_entries``.
+them is not written: the table's sliding row.
 """
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
-import functools
 import itertools
 import queue
 import threading
@@ -106,13 +105,11 @@ from jax.profiler import TraceAnnotation
 from ray_tpu.models.kv_cache import (BlockAllocator, PagedKVLayer,
                                      check_kv_dtype,
                                      export_page_bytes,
-                                     has_latent_pages,
-                                     has_recurrent_state,
-                                     has_sliding_entries, init_kv_pool,
-                                     kv_layer_store, kv_layer_view,
+                                     has_latent_pages, init_kv_pool,
                                      kv_pool_page_bytes,
                                      latent_page_width,
                                      page_cols_from_bytes,
+                                     refuse_unsupported,
                                      sliding_bytes_per_slot,
                                      sliding_ring_len,
                                      state_bytes_per_slot)
@@ -131,6 +128,10 @@ from ray_tpu.serve.scheduler import (LANE_BATCH, LANE_ONLINE,
                                      REPLICA_ROLES, ROLE_UNIFIED,
                                      StepPlan, SlotView, plan_step,
                                      role_plan_caps)
+from ray_tpu.serve.step_programs import (_jit_copy_page, _jit_decode,
+                                         _jit_prefill, _jit_seed,
+                                         _jit_verify, _jit_write_page,
+                                         _moe_vector_of)
 from ray_tpu.util.compile_cache import metadata_keyed
 
 _DONE = object()
@@ -467,108 +468,6 @@ def _new_moe_info() -> Dict[str, int]:
             for key in _MOE_SUMS + (_MOE_ROUTED,)}
 
 
-def _moe_vector_of(model):
-    """(experts counted, held, length) of the routing vector the
-    model's step programs return: ``held`` is the config's
-    ``experts_held`` share (lo, n) or None where the mixture holds
-    every expert; (0, None, 0) for a dense model."""
-    cfg = model.config
-    if not getattr(cfg, "num_experts", 0):
-        return 0, None, 0
-    if cfg.experts_held is None:
-        return cfg.num_experts, None, cfg.num_experts + len(_MOE_SUMS) - 1
-    n = cfg.experts_held[1]
-    return n, cfg.experts_held, n + len(_MOE_SUMS)
-
-
-def _moe_experts_of(model) -> int:
-    """Experts whose routing the model's step programs report: those
-    its mixture holds, 0 for a dense model."""
-    return _moe_vector_of(model)[0]
-
-
-def _refuse(cfg, keeps: str, why: Dict[str, str], asked) -> None:
-    """ValueError for the first option of ``asked`` that is set: the
-    model ``keeps`` a kind of request state the option cannot handle,
-    for the reason ``why`` gives."""
-    for option, value in asked.items():
-        if value:
-            raise ValueError(
-                f"{option}={value!r} is not supported for "
-                f"{type(cfg).__name__}: it has layers that keep "
-                f"{keeps}; {why[option]}")
-
-
-def refuse_for_recurrent_state(cfg, **asked) -> None:
-    """A model with recurrent layers keeps, beside its pages, a state
-    a slot that can be neither snapshotted at a page boundary nor
-    rewound: every option that shares, rewinds, ships or shards
-    per-request state is refused by name until it learns to."""
-    if not has_recurrent_state(cfg):
-        return
-    _refuse(cfg, "a recurrent state a slot instead of K/V pages", {
-        "prefix_cache": "a cached prefix's pages are shared, but the "
-                        "recurrent state after that prefix was never "
-                        "snapshotted",
-        "spec_len": "rejected drafts are rolled back by clamping a "
-                    "page offset, and a recurrent state cannot be "
-                    "rewound",
-        "kv_migration": "a KV pull ships pages only, and the recurrent "
-                        "state is not in its frames",
-        "sharding": "no partition rules exist for the recurrent state "
-                    "or the layer that keeps it",
-    }, asked)
-
-
-def refuse_for_latent_pages(cfg, **asked) -> None:
-    """A model with latent-attention layers keeps pages, handed out
-    and shared by page id as any other (so the prefix cache and
-    speculative decoding, which deal in page ids and a page offset
-    only, serve it), but a page's payload is one latent entry a token
-    and not K and V a head: every option that interprets, ships or
-    shards that payload is refused by name until it learns to."""
-    if not has_latent_pages(cfg):
-        return
-    _refuse(cfg, "latent pages instead of K/V pages", {
-        "kv_dtype": "the int8 code keeps one absmax scale a (page, KV "
-                    "head), and a latent entry has no heads: one scale "
-                    "would span the compressed vector and the rope key "
-                    "alike",
-        "kv_migration": "a KV pull's frames carry K and V a head, and "
-                        "no frame exists for a latent page",
-        "sharding": "the pool shards over the KV-head axis, and the "
-                    "one latent entry every head reads cannot be split "
-                    "over it; no partition rules exist for the layer",
-    }, asked)
-
-
-def refuse_for_sliding_entries(cfg, **asked) -> None:
-    """A model with sliding-window layers keeps, beside its pages, a
-    ring a slot of the window's last keys and values: entries that AGE
-    (a position's key is overwritten a ring's length later). Every
-    option that shares, rewinds, ships, re-codes or shards per-request
-    state is refused by name until it learns to."""
-    if not has_sliding_entries(cfg):
-        return
-    _refuse(cfg, "a ring of their window's keys and values a slot "
-            "instead of K/V pages", {
-        "prefix_cache": "a cached prefix's pages are shared, but the "
-                        "sliding layers' entries for that prefix were "
-                        "overwritten as the request that made them "
-                        "went on: they are gone",
-        "spec_len": "rejected drafts are rolled back by clamping a "
-                    "page offset, and no rule says yet which ring "
-                    "entries a rewound row may still read",
-        "kv_migration": "a KV pull ships pages only, and a slot's "
-                        "rings are not in its frames",
-        "kv_dtype": "the int8 code keeps one absmax scale a (page, KV "
-                    "head), and a ring has no pages: its entries would "
-                    "stay in the model's type beside int8 pages",
-        "sharding": "no partition rules exist for the rings or the "
-                    "layer that keeps them",
-    }, asked)
-
-
 def _new_round_info() -> Dict[str, int]:
     """What a round dispatched, as its ``round`` event reports it.
     ``backlog`` is the planner's (serve/scheduler.py
@@ -746,13 +645,7 @@ class LLMEngine:
         # every host->device operand commits replicated via _h2d.
         # Everything below the placement layer is sharding-oblivious —
         # same planner, same jitted step structure, same page tables.
-        refuse_for_recurrent_state(
-            self.cfg, prefix_cache=prefix_cache, spec_len=spec_len,
-            sharding=sharding is not None)
-        refuse_for_latent_pages(
-            self.cfg, kv_dtype=kv_dtype == "int8" and kv_dtype,
-            sharding=sharding is not None)
-        refuse_for_sliding_entries(
+        refuse_unsupported(
             self.cfg, prefix_cache=prefix_cache, spec_len=spec_len,
             kv_dtype=kv_dtype == "int8" and kv_dtype,
             sharding=sharding is not None)
@@ -904,7 +797,7 @@ class LLMEngine:
         # live rows only: [(vector_dev, is_decode)], read back behind
         # the tokens of the same dispatch, never waited for. 0 experts
         # = a dense model: nothing is returned, queued or reported.
-        self._moe_experts = _moe_experts_of(model)
+        self._moe_experts = _moe_vector_of(model)[0]
         self._moe_pending: "collections.deque" = collections.deque()
         self._moe_expert_pairs = np.zeros((self._moe_experts,), np.int64)
         self._moe_unreported = _new_moe_info()
@@ -2663,9 +2556,7 @@ class LLMEngine:
         the socket, or chaos kills would "succeed" off a corpse.
         A model with recurrent layers exports nothing: its pages are
         not the whole of a request's state."""
-        refuse_for_recurrent_state(self.cfg, kv_migration="export")
-        refuse_for_latent_pages(self.cfg, kv_migration="export")
-        refuse_for_sliding_entries(self.cfg, kv_migration="export")
+        refuse_unsupported(self.cfg, kv_migration="export")
         with self._lock:
             if self._stopped:
                 raise kv_migration.KVPullAborted(
@@ -3462,265 +3353,3 @@ class LLMEngine:
         self._hb = time.monotonic()   # dispatch completed: a long
                                       # prompt prefilling chunk by
                                       # chunk is moving, not wedged
-
-
-# ------------------------------------------------------ jitted steps
-#
-# The step programs close over nothing of one engine but its static
-# shape/sampling knobs, so they are built once per distinct knob set
-# and shared by every engine in the process: a pool's replicas (and a
-# restarted replica) trace each step once instead of once per engine,
-# and jit's own cache keys the executables by shape, dtype and device.
-# ``mesh`` is the replica's EngineSharding mesh or None; it only
-# decides the KV-pool sharding constraint, and a replica rebuilt over
-# the same devices hashes to the same entry.
-
-def _moe_apply(model, mesh):
-    """``model.apply`` for a step program. For a mixture-of-experts
-    model the third result is (the int32 vector,) of what the router
-    chose over the program's live tokens (models/mixtral.py
-    moe_stats_vector; ``live()`` gives the [B, T] mask); for a dense
-    model it is () and the program is what it was. A sharded
-    replica's mesh is made ambient while the mixture is traced: its
-    grouped matmul asks for it (ops/grouped_matmul.py: no Mosaic
-    kernel under a mesh)."""
-    E, held, _ = _moe_vector_of(model)
-    if not E:
-        def apply(params, ids, kv, start, live):
-            logits, new_kv = model.apply(params, ids, kv_caches=kv,
-                                         cache_len=start)
-            return logits, new_kv, ()
-        return apply
-    from ray_tpu.models.mixtral import MOE_STATS, moe_stats_vector
-
-    ambient = (contextlib.nullcontext if mesh is None else
-               functools.partial(jax.sharding.use_abstract_mesh,
-                                 mesh.abstract_mesh))
-
-    def apply(params, ids, kv, start, live):
-        with ambient():
-            (logits, new_kv), sown = model.apply(
-                params, ids, kv_caches=kv, cache_len=start,
-                mutable=[MOE_STATS])
-        with jax.named_scope("moe_stats"):
-            vec = moe_stats_vector(sown[MOE_STATS], live(),
-                                   model.config.num_experts, held)
-        return logits, new_kv, (vec,)
-    return apply
-
-
-def _views(pages, page_table, live, slots=None):
-    """Every layer's entry of the pool as its layer consumes it
-    (models/kv_cache.py kv_layer_view): a paged layer over the call's
-    page table, a recurrent layer over the rows' ``slots`` (None: row
-    i is slot i) and the [B, T] positions ``live()`` gives (nothing
-    calls it for a model with pages only). kv_layer_view/store
-    keep the builders kind- and dtype-agnostic: fp layers are
-    (pk, pv), int8 layers (pk, pv, sk, sv) — the scales ride the same
-    donated tuple through the step."""
-    return [kv_layer_view(layer, page_table, slots, live)
-            for layer in pages]
-
-
-def _constrain_for(mesh):
-    """Pin a jitted step's output KV pool to the head-sharded layout
-    (identity unsharded). Keeps GSPMD from ever resharding the pool
-    mid-graph — resharding would break the donate-and-alias
-    discipline AND introduce KV collectives."""
-    if mesh is None:
-        return lambda pages: pages
-    from ray_tpu.serve.sharding import constrain_kv_pool
-    return functools.partial(constrain_kv_pool, mesh)
-
-
-@functools.lru_cache(maxsize=64)
-def _jit_write_page(mesh):
-    """Jitted whole-page landing write: scatter one pulled page's
-    per-layer columns (k/v payload and, for int8 pools, their
-    per-page scales — they travel together) into physical page
-    ``dst`` across every layer. dst is a traced scalar: one
-    executable for the whole pull. The donated pool update is the
-    same in-place discipline every other jitted step uses."""
-    constrain = _constrain_for(mesh)
-
-    def write(pages, dst, cols):
-        return constrain(
-            [tuple(t.at[dst].set(c)
-                   for t, c in zip(layer, layer_cols))
-             for layer, layer_cols in zip(pages, cols)])
-    return jax.jit(write, donate_argnums=(0,))
-
-
-@functools.lru_cache(maxsize=64)
-def _jit_prefill(model, temp, B, capture, mesh):
-    """The chunked-prefill program: [B, T] token ids at per-row start
-    offsets scatter into the rows' pages (append-at-offset) and
-    attend causally over each row's own page window. The row's last
-    real position samples a candidate first token — junk for rows
-    mid-prompt, consumed only for rows that just finished their
-    prompt."""
-    constrain = _constrain_for(mesh)
-    apply = _moe_apply(model, mesh)
-    from ray_tpu.models.llama import _pick_token
-
-    def prefill(params, pages, ids, start, last_idx, page_table,
-                rng, slots=None):
-        rng, sub = jax.random.split(rng)
-        # live tokens: a real row's positions up to its last real one
-        # (dummy rows point at the null page; the rest is padding).
-        # ``slots`` [B]: the decode slot each row belongs to, which
-        # only a model with recurrent layers reads (a dummy row's is
-        # out of range: it reads zeros and writes nothing)
-        def live():
-            return (page_table[:, :1] != 0) & (
-                jnp.arange(ids.shape[1])[None] <= last_idx[:, None])
-        logits, new_kv, moe = apply(
-            params, ids, _views(pages, page_table, live, slots), start,
-            live)
-        new_pages = constrain([kv_layer_store(c) for c in new_kv])
-        last = logits[jnp.arange(B), last_idx]        # [B, V]
-        with jax.named_scope("sample"):
-            firsts = _pick_token(last, sub, temp)
-        if capture:
-            # Score under the SAMPLING distribution (temperature-
-            # scaled at temp > 0) — the behavior policy an RL
-            # learner's importance ratio needs, not the raw model
-            # distribution.
-            slog = (last.astype(jnp.float32) / temp if temp > 0.0
-                    else last.astype(jnp.float32))
-            lp = jnp.take_along_axis(
-                jax.nn.log_softmax(slog),
-                firsts[:, None], axis=-1)[:, 0]
-            return ((firsts, lp), new_pages, rng) + moe
-        return (firsts, new_pages, rng) + moe
-
-    return jax.jit(prefill, donate_argnums=(1,))
-
-
-@functools.lru_cache(maxsize=64)
-def _jit_verify(model, mesh):
-    """The spec-verify program for rows of ``spec_len + 1``: [S, T]
-    rows of [cur, drafts...] scatter into each slot's pages at its
-    own offset and attend causally over the slot's page window — the
-    exact chunked-prefill path, reused at decode offsets. Greedy by
-    construction: position j's argmax is the token plain
-    temperature-0 decode would have emitted after input j, so
-    acceptance is a pure prefix compare on the host. No rng
-    threading — speculation is disabled at temperature > 0."""
-    constrain = _constrain_for(mesh)
-    apply = _moe_apply(model, mesh)
-
-    def verify(params, pages, ids, start, page_table):
-        # every position of a verified slot's row is a forward pass,
-        # unused draft places included; row i is slot i
-        def live():
-            return jnp.broadcast_to(page_table[:, :1] != 0, ids.shape)
-        logits, new_kv, moe = apply(
-            params, ids, _views(pages, page_table, live), start, live)
-        new_pages = constrain([kv_layer_store(c) for c in new_kv])
-        return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                new_pages) + moe
-
-    return jax.jit(verify, donate_argnums=(1,))
-
-
-@functools.lru_cache(maxsize=64)
-def _jit_decode(model, temp, KMAX, S, capture, mesh):
-    constrain = _constrain_for(mesh)
-    apply = _moe_apply(model, mesh)
-    moe_len = _moe_vector_of(model)[2]
-    from ray_tpu.models.llama import _pick_token
-
-    def decode(params, pages, page_table, pos, cur, rng, steps):
-        # fori_loop with a RUNTIME bound: one executable serves
-        # every dispatch length (chunk-sized quick syncs and full
-        # run-ahead alike); tokens land in a fixed [KMAX, S]
-        # buffer, rows past `steps` stay zero and are never read.
-        # pos/cur are the DEVICE-authoritative per-slot state:
-        # they chain dispatch-to-dispatch (admission seeds rows
-        # via _jit_seed's scatter), so no host readback ever
-        # sits between two dispatches. With logprob capture a
-        # float32 [KMAX, S] buffer of the chosen tokens' logprobs
-        # rides the same carry and the same trailing readback.
-        buf0 = jnp.zeros((KMAX, S), jnp.int32)
-        lp0 = jnp.zeros((KMAX, S), jnp.float32)
-
-        # a mixture-of-experts model's routing counters ride the
-        # carry too, summed over the steps (riders only: the other
-        # slots' page-table rows are null)
-        moe0 = (jnp.zeros((moe_len,), jnp.int32),) if moe_len else ()
-        # row i is slot i; a slot that rides without a request (its
-        # page-table row is null) moves no recurrent state either
-        def live():
-            return page_table[:, :1] != 0
-
-        def body(i, carry):
-            pages, pos, cur, key, buf, lps, *moe = carry
-            key, sub = jax.random.split(key)
-            logits, new_kv, vec = apply(
-                params, cur[:, None], _views(pages, page_table, live),
-                pos, live)
-            moe = tuple(m + v for m, v in zip(moe, vec))
-            with jax.named_scope("sample"):
-                nxt = _pick_token(logits[:, -1], sub, temp)
-            if capture:
-                # Behavior-policy logprob: temperature-scaled to
-                # match what _pick_token actually sampled from.
-                slog = (logits[:, -1].astype(jnp.float32) / temp
-                        if temp > 0.0
-                        else logits[:, -1].astype(jnp.float32))
-                lp = jnp.take_along_axis(
-                    jax.nn.log_softmax(slog),
-                    nxt[:, None], axis=-1)[:, 0]
-                lps = lps.at[i].set(lp)
-            # pin the loop-carried pool to the head-sharded layout
-            # so the carry's sharding is loop-invariant (GSPMD
-            # would otherwise be free to reshard mid-carry)
-            new_pages = constrain(
-                [kv_layer_store(c) for c in new_kv])
-            return (new_pages, pos + 1, nxt, key,
-                    buf.at[i].set(nxt), lps) + moe
-        pages, pos, cur, key, buf, lps, *moe = jax.lax.fori_loop(
-            0, steps, body, (pages, pos, cur, rng, buf0, lp0) + moe0)
-        # key/pos/cur return as device state: the host never syncs
-        # on them between dispatches
-        out = (buf, lps) if capture else buf
-        return (out, pages, key, pos, cur) + tuple(moe)  # buf: [KMAX, S]
-
-    return jax.jit(decode, donate_argnums=(1, 3, 4))
-
-
-@functools.lru_cache(maxsize=64)
-def _jit_copy_page(mesh):
-    """Jitted whole-page copy across every layer's K and V pool:
-    the prefix cache's one COW copy, used when an admission's
-    prompt is FULLY cached — the final matched page is duplicated
-    into a private page so the one-token re-prefill (the model
-    needs the last position's logits) never scatters into a
-    shared page. src/dst are traced scalars: one executable.
-    Under tensor parallelism the copy stays device-local: the
-    sharded kv-head axis is untouched, each device duplicates its
-    own head shard of the page."""
-    constrain = _constrain_for(mesh)
-
-    def copy(pages, src, dst):
-        # int8 layers are 4-tuples whose trailing scale tensors
-        # copy their (rank-2) page row the same way — COW gets
-        # the page's quantization scale for free, so a COW'd page
-        # dequantizes identically to its source
-        return constrain([tuple(t.at[dst].set(t[src])
-                                for t in layer)
-                          for layer in pages])
-    return jax.jit(copy, donate_argnums=(0,))
-
-
-@functools.lru_cache(maxsize=64)
-def _jit_seed():
-    """Jitted admission seeding: scatter a prefill batch's first
-    tokens and write positions into the device decode state.
-    Rows padded with ix == S drop (mode='drop') — one executable
-    regardless of how many slots the group filled."""
-    def seed(dev_cur, dev_pos, firsts, ixs, rows, posv):
-        return (dev_cur.at[ixs].set(firsts[rows], mode="drop"),
-                dev_pos.at[ixs].set(posv, mode="drop"))
-    return jax.jit(seed, donate_argnums=(0, 1))

@@ -12,34 +12,6 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional
 
 
-def _family_for(cfg):
-    """ONE config-type -> (model class, serving sharding rules) map so
-    model construction and mesh sharding can never disagree (a missed
-    dispatch site would silently replicate expert weights)."""
-    from ray_tpu.models.llama import Llama, llama_sharding_rules
-    from ray_tpu.models.mixtral import (Mixtral, MixtralConfig,
-                                        mixtral_sharding_rules)
-    from ray_tpu.models.axk1 import AXK1, AXK1Config
-    from ray_tpu.models.kimi_linear import KimiLinear, KimiLinearConfig
-    from ray_tpu.models.mellum import Mellum, MellumConfig
-    from ray_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
-    if isinstance(cfg, AXK1Config):
-        # no partition rules yet: the deployment refuses to shard it
-        return AXK1, None
-    if isinstance(cfg, KimiLinearConfig):
-        # no partition rules yet: the deployment refuses to shard it
-        return KimiLinear, None
-    if isinstance(cfg, MellumConfig):
-        # no partition rules yet: the deployment refuses to shard it
-        return Mellum, None
-    if isinstance(cfg, MixtralConfig):
-        return Mixtral, mixtral_sharding_rules(fsdp=False)
-    if isinstance(cfg, SolarOpen2Config):
-        # no partition rules yet: the deployment refuses to shard it
-        return SolarOpen2, None
-    return Llama, llama_sharding_rules(fsdp=False)
-
-
 class LlamaDeployment:
     """Deployment-ready Llama wrapper: __init__ builds/loads the model,
     __call__ generates. Wrap with @serve.deployment at use site so
@@ -81,9 +53,9 @@ class LlamaDeployment:
         import jax
         from ray_tpu.models.llama import llama_tiny
         self.cfg = config or llama_tiny()
-        # any Llama-shaped family serves through the same decode stack
-        model_cls, self._sharding_rules = _family_for(self.cfg)
-        self.model = model_cls(self.cfg)
+        # any family serves through the same decode stack: its config
+        # says which class it builds (models/llama.py ``model_class``)
+        self.model = self.cfg.model_class(self.cfg)
         if params is None:
             import jax.numpy as jnp
             # jitted: un-jitted, flax runs every initialiser op by op
@@ -126,27 +98,16 @@ class LlamaDeployment:
                              "be >= 1")
         self.tensor_parallel = int(tensor_parallel)
         self.expert_parallel = int(expert_parallel)
-        # a model whose layers keep a recurrent state beside K/V pages
-        # (serve/engine.py says what each refusal waits for)
-        from ray_tpu.serve.engine import (refuse_for_latent_pages,
-                                          refuse_for_recurrent_state,
-                                          refuse_for_sliding_entries)
-        sharded = (self.tensor_parallel, self.expert_parallel) != (1, 1)
-        refuse_for_recurrent_state(
-            self.cfg, kv_migration=disaggregate and "disaggregate",
-            prefix_cache=prefix_cache, spec_len=spec_len,
-            sharding=sharded)
-        # a model whose pages hold latent entries, not K and V a head
-        refuse_for_latent_pages(
-            self.cfg, kv_dtype=kv_dtype == "int8" and kv_dtype,
-            kv_migration=disaggregate and "disaggregate",
-            sharding=sharded)
-        # a model whose sliding-window layers keep entries that age
-        refuse_for_sliding_entries(
+        # a model whose layers keep another kind of request state
+        # than K/V pages (models/kv_cache.py KIND_REFUSALS says what
+        # each kind cannot do, and why)
+        from ray_tpu.models.kv_cache import refuse_unsupported
+        refuse_unsupported(
             self.cfg, prefix_cache=prefix_cache, spec_len=spec_len,
             kv_dtype=kv_dtype == "int8" and kv_dtype,
             kv_migration=disaggregate and "disaggregate",
-            sharding=sharded)
+            sharding=(self.tensor_parallel,
+                      self.expert_parallel) != (1, 1))
         if self.tensor_parallel > 1 or self.expert_parallel > 1:
             from ray_tpu.serve.sharding import validate_tp
             validate_tp(self.cfg, self.tensor_parallel,
@@ -290,9 +251,10 @@ class LlamaDeployment:
         params over the replica's mesh (tensor-parallel; for Mixtral
         also expert-parallel)."""
         from ray_tpu.mesh.sharding import shard_params
+        from ray_tpu.serve.sharding import family_sharding_rules
         self.mesh = mesh
-        self.params = shard_params(self.params, self._sharding_rules,
-                                   mesh)
+        self.params = shard_params(
+            self.params, family_sharding_rules(self.cfg), mesh)
 
     def engine(self):
         """The replica's continuous-batching engine (lazy: params may

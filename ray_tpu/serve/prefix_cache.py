@@ -37,8 +37,9 @@ deals only in page NUMBERS, and the per-page scale tensors live in
 device arrays indexed by the same physical page id — a cached page's
 scale is refcounted/evicted/realloc'd implicitly with its id, the
 engine's jitted COW copy duplicates the scale column alongside the
-page (``_jit_copy_page``), and a freed page's stale scale is
-zeroed on first reuse by ``paged_append``'s reset-on-offset-0 rule.
+page (serve/step_programs.py ``_jit_copy_page``), and a freed page's
+stale scale is zeroed on first reuse by ``paged_append``'s
+reset-on-offset-0 rule.
 
 Metrics (util/metrics.py Counter/Gauge, served by the dashboard's
 Prometheus exposition): hit/miss tokens, evictions, resident pages.

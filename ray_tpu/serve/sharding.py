@@ -80,35 +80,36 @@ class ShardingConfigError(ValueError):
     cover it, or rules that leave a large tensor unmatched."""
 
 
+def _declared(cfg, name: str):
+    """What a family's config declares for sharding
+    (models/llama.py ``serving_rules`` / ``tp_validate``); a config
+    that declares none cannot be sharded, and never gets another
+    family's."""
+    declared = getattr(cfg, name, None)
+    if declared is None:
+        raise ShardingConfigError(
+            f"{type(cfg).__name__} declares no {name}: no partition "
+            f"rules exist for this family, so it cannot be sharded")
+    return declared
+
+
 def family_sharding_rules(cfg) -> ShardingRules:
-    """Serving partition rules for a model config, by family.
+    """Serving partition rules for a model config, as its family
+    declares them.
 
     fsdp=False on purpose: a serving replica shards over ``tensor``
     (and ``expert`` for MoE) only — data parallelism is the replica
     POOL's job (one whole mesh per replica), not an in-mesh axis.
     """
-    from ray_tpu.models.mixtral import (MixtralConfig,
-                                        mixtral_sharding_rules)
-    if isinstance(cfg, MixtralConfig):
-        return mixtral_sharding_rules(fsdp=False)
-    from ray_tpu.models.llama import llama_sharding_rules
-    return llama_sharding_rules(fsdp=False)
+    return _declared(cfg, "serving_rules")
 
 
 def validate_tp(cfg, tp: int, ep: int = 1) -> None:
-    """Family-dispatched divisibility check; ShardingConfigError on
+    """The family's own divisibility check; ShardingConfigError on
     any dimension that does not divide the mesh."""
-    from ray_tpu.models.mixtral import MixtralConfig, mixtral_tp_validate
+    check = _declared(cfg, "tp_validate")
     try:
-        if isinstance(cfg, MixtralConfig):
-            mixtral_tp_validate(cfg, tp, ep)
-        else:
-            from ray_tpu.models.llama import llama_tp_validate
-            if ep != 1:
-                raise ValueError(
-                    f"expert parallelism ep={ep} needs an MoE config, "
-                    f"got {type(cfg).__name__}")
-            llama_tp_validate(cfg, tp)
+        check(tp, ep)
     except ValueError as e:
         raise ShardingConfigError(str(e)) from None
 

@@ -1,0 +1,289 @@
+"""The serving engine's device programs.
+
+What ``serve/engine.py``'s host loop dispatches, and nothing of the
+loop itself: the chunked-prefill, decode and spec-verify step programs
+(``jit_prefill``, ``jit_decode``, ``jit_verify`` in a device trace) and
+the three small ones that move a page or seed a slot.
+
+The step programs close over nothing of one engine but its static
+shape/sampling knobs, so they are built once per distinct knob set
+and shared by every engine in the process: a pool's replicas (and a
+restarted replica) trace each step once instead of once per engine,
+and jit's own cache keys the executables by shape, dtype and device.
+``mesh`` is the replica's EngineSharding mesh or None; it only
+decides the KV-pool sharding constraint, and a replica rebuilt over
+the same devices hashes to the same entry.
+"""
+from __future__ import annotations
+
+import contextlib
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.kv_cache import kv_layer_store, kv_layer_view
+
+
+def _moe_vector_of(model):
+    """(experts counted, held, length) of the routing vector the
+    model's step programs return: ``held`` is the config's
+    ``experts_held`` share (lo, n) or None where the mixture holds
+    every expert; (0, None, 0) for a dense model."""
+    cfg = model.config
+    if not getattr(cfg, "num_experts", 0):
+        return 0, None, 0
+    from ray_tpu.models.mixtral import experts_held, moe_stats_len
+    return (experts_held(cfg)[1], cfg.experts_held,
+            moe_stats_len(cfg.num_experts, cfg.experts_held))
+
+
+def _moe_apply(model, mesh):
+    """``model.apply`` for a step program. For a mixture-of-experts
+    model the third result is (the int32 vector,) of what the router
+    chose over the program's live tokens (models/mixtral.py
+    moe_stats_vector; ``live()`` gives the [B, T] mask); for a dense
+    model it is () and the program is what it was. A sharded
+    replica's mesh is made ambient while the mixture is traced: its
+    grouped matmul asks for it (ops/grouped_matmul.py: no Mosaic
+    kernel under a mesh)."""
+    E, held, _ = _moe_vector_of(model)
+    if not E:
+        def apply(params, ids, kv, start, live):
+            logits, new_kv = model.apply(params, ids, kv_caches=kv,
+                                         cache_len=start)
+            return logits, new_kv, ()
+        return apply
+    from ray_tpu.models.mixtral import MOE_STATS, moe_stats_vector
+
+    ambient = (contextlib.nullcontext if mesh is None else
+               functools.partial(jax.sharding.use_abstract_mesh,
+                                 mesh.abstract_mesh))
+
+    def apply(params, ids, kv, start, live):
+        with ambient():
+            (logits, new_kv), sown = model.apply(
+                params, ids, kv_caches=kv, cache_len=start,
+                mutable=[MOE_STATS])
+        with jax.named_scope("moe_stats"):
+            vec = moe_stats_vector(sown[MOE_STATS], live(),
+                                   model.config.num_experts, held)
+        return logits, new_kv, (vec,)
+    return apply
+
+
+def _views(pages, page_table, live, slots=None):
+    """Every layer's entry of the pool as its layer consumes it
+    (models/kv_cache.py kv_layer_view): a paged layer over the call's
+    page table, a recurrent layer over the rows' ``slots`` (None: row
+    i is slot i) and the [B, T] positions ``live()`` gives (nothing
+    calls it for a model with pages only). kv_layer_view/store
+    keep the builders kind- and dtype-agnostic: fp layers are
+    (pk, pv), int8 layers (pk, pv, sk, sv) — the scales ride the same
+    donated tuple through the step."""
+    return [kv_layer_view(layer, page_table, slots, live)
+            for layer in pages]
+
+
+def _constrain_for(mesh):
+    """Pin a jitted step's output KV pool to the head-sharded layout
+    (identity unsharded). Keeps GSPMD from ever resharding the pool
+    mid-graph — resharding would break the donate-and-alias
+    discipline AND introduce KV collectives."""
+    if mesh is None:
+        return lambda pages: pages
+    from ray_tpu.serve.sharding import constrain_kv_pool
+    return functools.partial(constrain_kv_pool, mesh)
+
+
+@functools.lru_cache(maxsize=64)
+def _jit_write_page(mesh):
+    """Jitted whole-page landing write: scatter one pulled page's
+    per-layer columns (k/v payload and, for int8 pools, their
+    per-page scales — they travel together) into physical page
+    ``dst`` across every layer. dst is a traced scalar: one
+    executable for the whole pull. The donated pool update is the
+    same in-place discipline every other jitted step uses."""
+    constrain = _constrain_for(mesh)
+
+    def write(pages, dst, cols):
+        return constrain(
+            [tuple(t.at[dst].set(c)
+                   for t, c in zip(layer, layer_cols))
+             for layer, layer_cols in zip(pages, cols)])
+    return jax.jit(write, donate_argnums=(0,))
+
+
+@functools.lru_cache(maxsize=64)
+def _jit_prefill(model, temp, B, capture, mesh):
+    """The chunked-prefill program: [B, T] token ids at per-row start
+    offsets scatter into the rows' pages (append-at-offset) and
+    attend causally over each row's own page window. The row's last
+    real position samples a candidate first token — junk for rows
+    mid-prompt, consumed only for rows that just finished their
+    prompt."""
+    constrain = _constrain_for(mesh)
+    apply = _moe_apply(model, mesh)
+    from ray_tpu.models.llama import _pick_token
+
+    def prefill(params, pages, ids, start, last_idx, page_table,
+                rng, slots=None):
+        rng, sub = jax.random.split(rng)
+        # live tokens: a real row's positions up to its last real one
+        # (dummy rows point at the null page; the rest is padding).
+        # ``slots`` [B]: the decode slot each row belongs to, which
+        # only a model with recurrent layers reads (a dummy row's is
+        # out of range: it reads zeros and writes nothing)
+        def live():
+            return (page_table[:, :1] != 0) & (
+                jnp.arange(ids.shape[1])[None] <= last_idx[:, None])
+        logits, new_kv, moe = apply(
+            params, ids, _views(pages, page_table, live, slots), start,
+            live)
+        new_pages = constrain([kv_layer_store(c) for c in new_kv])
+        last = logits[jnp.arange(B), last_idx]        # [B, V]
+        with jax.named_scope("sample"):
+            firsts = _pick_token(last, sub, temp)
+        if capture:
+            # Score under the SAMPLING distribution (temperature-
+            # scaled at temp > 0) — the behavior policy an RL
+            # learner's importance ratio needs, not the raw model
+            # distribution.
+            slog = (last.astype(jnp.float32) / temp if temp > 0.0
+                    else last.astype(jnp.float32))
+            lp = jnp.take_along_axis(
+                jax.nn.log_softmax(slog),
+                firsts[:, None], axis=-1)[:, 0]
+            return ((firsts, lp), new_pages, rng) + moe
+        return (firsts, new_pages, rng) + moe
+
+    return jax.jit(prefill, donate_argnums=(1,))
+
+
+@functools.lru_cache(maxsize=64)
+def _jit_verify(model, mesh):
+    """The spec-verify program for rows of ``spec_len + 1``: [S, T]
+    rows of [cur, drafts...] scatter into each slot's pages at its
+    own offset and attend causally over the slot's page window — the
+    exact chunked-prefill path, reused at decode offsets. Greedy by
+    construction: position j's argmax is the token plain
+    temperature-0 decode would have emitted after input j, so
+    acceptance is a pure prefix compare on the host. No rng
+    threading — speculation is disabled at temperature > 0."""
+    constrain = _constrain_for(mesh)
+    apply = _moe_apply(model, mesh)
+
+    def verify(params, pages, ids, start, page_table):
+        # every position of a verified slot's row is a forward pass,
+        # unused draft places included; row i is slot i
+        def live():
+            return jnp.broadcast_to(page_table[:, :1] != 0, ids.shape)
+        logits, new_kv, moe = apply(
+            params, ids, _views(pages, page_table, live), start, live)
+        new_pages = constrain([kv_layer_store(c) for c in new_kv])
+        return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                new_pages) + moe
+
+    return jax.jit(verify, donate_argnums=(1,))
+
+
+@functools.lru_cache(maxsize=64)
+def _jit_decode(model, temp, KMAX, S, capture, mesh):
+    constrain = _constrain_for(mesh)
+    apply = _moe_apply(model, mesh)
+    moe_len = _moe_vector_of(model)[2]
+    from ray_tpu.models.llama import _pick_token
+
+    def decode(params, pages, page_table, pos, cur, rng, steps):
+        # fori_loop with a RUNTIME bound: one executable serves
+        # every dispatch length (chunk-sized quick syncs and full
+        # run-ahead alike); tokens land in a fixed [KMAX, S]
+        # buffer, rows past `steps` stay zero and are never read.
+        # pos/cur are the DEVICE-authoritative per-slot state:
+        # they chain dispatch-to-dispatch (admission seeds rows
+        # via _jit_seed's scatter), so no host readback ever
+        # sits between two dispatches. With logprob capture a
+        # float32 [KMAX, S] buffer of the chosen tokens' logprobs
+        # rides the same carry and the same trailing readback.
+        buf0 = jnp.zeros((KMAX, S), jnp.int32)
+        lp0 = jnp.zeros((KMAX, S), jnp.float32)
+
+        # a mixture-of-experts model's routing counters ride the
+        # carry too, summed over the steps (riders only: the other
+        # slots' page-table rows are null)
+        moe0 = (jnp.zeros((moe_len,), jnp.int32),) if moe_len else ()
+        # row i is slot i; a slot that rides without a request (its
+        # page-table row is null) moves no recurrent state either
+        def live():
+            return page_table[:, :1] != 0
+
+        def body(i, carry):
+            pages, pos, cur, key, buf, lps, *moe = carry
+            key, sub = jax.random.split(key)
+            logits, new_kv, vec = apply(
+                params, cur[:, None], _views(pages, page_table, live),
+                pos, live)
+            moe = tuple(m + v for m, v in zip(moe, vec))
+            with jax.named_scope("sample"):
+                nxt = _pick_token(logits[:, -1], sub, temp)
+            if capture:
+                # Behavior-policy logprob: temperature-scaled to
+                # match what _pick_token actually sampled from.
+                slog = (logits[:, -1].astype(jnp.float32) / temp
+                        if temp > 0.0
+                        else logits[:, -1].astype(jnp.float32))
+                lp = jnp.take_along_axis(
+                    jax.nn.log_softmax(slog),
+                    nxt[:, None], axis=-1)[:, 0]
+                lps = lps.at[i].set(lp)
+            # pin the loop-carried pool to the head-sharded layout
+            # so the carry's sharding is loop-invariant (GSPMD
+            # would otherwise be free to reshard mid-carry)
+            new_pages = constrain(
+                [kv_layer_store(c) for c in new_kv])
+            return (new_pages, pos + 1, nxt, key,
+                    buf.at[i].set(nxt), lps) + moe
+        pages, pos, cur, key, buf, lps, *moe = jax.lax.fori_loop(
+            0, steps, body, (pages, pos, cur, rng, buf0, lp0) + moe0)
+        # key/pos/cur return as device state: the host never syncs
+        # on them between dispatches
+        out = (buf, lps) if capture else buf
+        return (out, pages, key, pos, cur) + tuple(moe)  # buf: [KMAX, S]
+
+    return jax.jit(decode, donate_argnums=(1, 3, 4))
+
+
+@functools.lru_cache(maxsize=64)
+def _jit_copy_page(mesh):
+    """Jitted whole-page copy across every layer's K and V pool:
+    the prefix cache's one COW copy, used when an admission's
+    prompt is FULLY cached — the final matched page is duplicated
+    into a private page so the one-token re-prefill (the model
+    needs the last position's logits) never scatters into a
+    shared page. src/dst are traced scalars: one executable.
+    Under tensor parallelism the copy stays device-local: the
+    sharded kv-head axis is untouched, each device duplicates its
+    own head shard of the page."""
+    constrain = _constrain_for(mesh)
+
+    def copy(pages, src, dst):
+        # int8 layers are 4-tuples whose trailing scale tensors
+        # copy their (rank-2) page row the same way — COW gets
+        # the page's quantization scale for free, so a COW'd page
+        # dequantizes identically to its source
+        return constrain([tuple(t.at[dst].set(t[src])
+                                for t in layer)
+                          for layer in pages])
+    return jax.jit(copy, donate_argnums=(0,))
+
+
+@functools.lru_cache(maxsize=64)
+def _jit_seed():
+    """Jitted admission seeding: scatter a prefill batch's first
+    tokens and write positions into the device decode state.
+    Rows padded with ix == S drop (mode='drop') — one executable
+    regardless of how many slots the group filled."""
+    def seed(dev_cur, dev_pos, firsts, ixs, rows, posv):
+        return (dev_cur.at[ixs].set(firsts[rows], mode="drop"),
+                dev_pos.at[ixs].set(posv, mode="drop"))
+    return jax.jit(seed, donate_argnums=(0, 1))

@@ -184,23 +184,23 @@ def _cell_cfg(kv_heads):
 def _program(name, model, mesh):
     """(jitted program, its arguments after params and pages) as the
     engine builds and calls them."""
-    from ray_tpu.serve import engine as engine_mod
+    from ray_tpu.serve import step_programs
     i32 = jnp.int32
     table = ((SLOTS, model.config.max_seq_len // PAGE), i32)
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     key = (key.shape, key.dtype)
     if name == "decode":
-        return (engine_mod._jit_decode(model, 0.0, KMAX, SLOTS, False,
+        return (step_programs._jit_decode(model, 0.0, KMAX, SLOTS, False,
                                        mesh),
                 [table, ((SLOTS,), i32), ((SLOTS,), i32), key,
                  ((), i32)])
     if name == "prefill":
         B, T = 4, 256
-        return (engine_mod._jit_prefill(model, 0.0, B, False, mesh),
+        return (step_programs._jit_prefill(model, 0.0, B, False, mesh),
                 [((B, T), i32), ((B,), i32), ((B,), i32),
                  ((B, table[0][1]), i32), key])
     T = 5                                   # spec_len 4
-    return (engine_mod._jit_verify(model, mesh),
+    return (step_programs._jit_verify(model, mesh),
             [((SLOTS, T), i32), ((SLOTS,), i32), table])
 
 
@@ -344,7 +344,7 @@ def test_decode_copies_no_pool_shard_under_tp4(topo):
 def _hybrid_step(name, one_chip):
     from ray_tpu.models.kv_cache import init_kv_pool
     from ray_tpu.models.solar_open2 import SolarOpen2, solar_open2_250b
-    from ray_tpu.serve import engine as engine_mod
+    from ray_tpu.serve import step_programs
     cfg = solar_open2_250b(n_layers=4, vocab_size=24576, max_seq_len=4096,
                            experts_held=(0, 8), param_dtype=jnp.bfloat16)
     model = SolarOpen2(cfg)
@@ -362,11 +362,11 @@ def _hybrid_step(name, one_chip):
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     table = ((SLOTS, cfg.max_seq_len // PAGE), i32)
     if name == "decode":
-        fn = engine_mod._jit_decode(model, 0.0, 128, SLOTS, False, None)
+        fn = step_programs._jit_decode(model, 0.0, 128, SLOTS, False, None)
         rest = [table, ((SLOTS,), i32), ((SLOTS,), i32),
                 (key.shape, key.dtype), ((), i32)]
     else:
-        fn = engine_mod._jit_prefill(model, 0.0, 4, False, None)
+        fn = step_programs._jit_prefill(model, 0.0, 4, False, None)
         rest = [((4, 256), i32), ((4,), i32), ((4,), i32),
                 ((4, table[0][1]), i32), (key.shape, key.dtype),
                 ((4,), i32)]
@@ -415,7 +415,7 @@ def test_hybrid_step_programs_keep_the_state_in_place(one_chip,
 def _latent_step(name, one_chip):
     from ray_tpu.models.axk1 import AXK1, axk1
     from ray_tpu.models.kv_cache import init_kv_pool
-    from ray_tpu.serve import engine as engine_mod
+    from ray_tpu.serve import step_programs
     cfg = axk1(n_layers=2, vocab_size=20480, max_seq_len=16384,
                experts_held=(0, 2), param_dtype=jnp.bfloat16)
     model = AXK1(cfg)
@@ -432,11 +432,11 @@ def _latent_step(name, one_chip):
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     table = ((SLOTS, cfg.max_seq_len // PAGE), i32)
     if name == "decode":
-        fn = engine_mod._jit_decode(model, 0.0, 128, SLOTS, False, None)
+        fn = step_programs._jit_decode(model, 0.0, 128, SLOTS, False, None)
         rest = [table, ((SLOTS,), i32), ((SLOTS,), i32),
                 (key.shape, key.dtype), ((), i32)]
     else:
-        fn = engine_mod._jit_prefill(model, 0.0, 4, False, None)
+        fn = step_programs._jit_prefill(model, 0.0, 4, False, None)
         rest = [((4, 256), i32), ((4,), i32), ((4,), i32),
                 ((4, table[0][1]), i32), (key.shape, key.dtype)]
     rest = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
@@ -488,7 +488,7 @@ def test_latent_step_programs_keep_the_pool_page_major(one_chip,
 def _no_kv_step(name, one_chip):
     from ray_tpu.models.kimi_linear import KimiLinear, kimi_linear_48b
     from ray_tpu.models.kv_cache import init_kv_pool
-    from ray_tpu.serve import engine as engine_mod
+    from ray_tpu.serve import step_programs
     S = 128
     cfg = kimi_linear_48b(n_layers=4, vocab_size=40960, max_seq_len=4096,
                           experts_held=(0, 2), param_dtype=jnp.bfloat16)
@@ -507,11 +507,11 @@ def _no_kv_step(name, one_chip):
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     table = ((S, cfg.max_seq_len // PAGE), i32)
     if name == "decode":
-        fn = engine_mod._jit_decode(model, 0.0, 128, S, False, None)
+        fn = step_programs._jit_decode(model, 0.0, 128, S, False, None)
         rest = [table, ((S,), i32), ((S,), i32),
                 (key.shape, key.dtype), ((), i32)]
     else:
-        fn = engine_mod._jit_prefill(model, 0.0, 4, False, None)
+        fn = step_programs._jit_prefill(model, 0.0, 4, False, None)
         rest = [((4, 256), i32), ((4,), i32), ((4,), i32),
                 ((4, table[0][1]), i32), (key.shape, key.dtype),
                 ((4,), i32)]
@@ -591,14 +591,14 @@ def test_latent_prefill_attends_in_one_kernel_a_layer(one_chip,
     from ray_tpu.ops import grouped_matmul as gm
     from ray_tpu.ops import latent_window_attention as lw
     from ray_tpu.ops import linear_attention as la
-    from ray_tpu.serve import engine as engine_mod
+    from ray_tpu.serve import step_programs
     for mod, name in ((gm, "_use_kernel"), (la, "_on_one_tpu"),
                       (lw, "_on_one_tpu")):
         monkeypatch.setattr(mod, name, lambda: True)
     # the programs are cached by (model, knobs): the cases above traced
     # these models' with the loop, and no later one may find the kernel's
-    monkeypatch.setattr(engine_mod, "_jit_prefill",
-                        engine_mod._jit_prefill.__wrapped__)
+    monkeypatch.setattr(step_programs, "_jit_prefill",
+                        step_programs._jit_prefill.__wrapped__)
     if family == "axk1":
         # two latent layers; the loop's form takes 526 MB of
         # temporaries here, this one 188
@@ -624,7 +624,7 @@ def test_latent_prefill_attends_in_one_kernel_a_layer(one_chip,
 def _two_sizes_step(name, one_chip):
     from ray_tpu.models.kv_cache import init_kv_pool, sliding_ring_len
     from ray_tpu.models.mellum import Mellum, mellum2_12b
-    from ray_tpu.serve import engine as engine_mod
+    from ray_tpu.serve import step_programs
     cfg = mellum2_12b(n_layers=4, max_seq_len=16384, experts_held=(0, 2),
                       param_dtype=jnp.bfloat16)
     model = Mellum(cfg)
@@ -645,11 +645,11 @@ def _two_sizes_step(name, one_chip):
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     table = ((SLOTS, cfg.max_seq_len // PAGE), i32)
     if name == "decode":
-        fn = engine_mod._jit_decode(model, 0.0, 128, SLOTS, False, None)
+        fn = step_programs._jit_decode(model, 0.0, 128, SLOTS, False, None)
         rest = [table, ((SLOTS,), i32), ((SLOTS,), i32),
                 (key.shape, key.dtype), ((), i32)]
     else:
-        fn = engine_mod._jit_prefill(model, 0.0, 4, False, None)
+        fn = step_programs._jit_prefill(model, 0.0, 4, False, None)
         rest = [((4, 256), i32), ((4,), i32), ((4,), i32),
                 ((4, table[0][1]), i32), (key.shape, key.dtype),
                 ((4,), i32)]

@@ -477,11 +477,11 @@ def test_program_names_the_benchmark_keys_on(program):
         text = step.lower(state, {"x": jnp.ones((4,))}).as_text()
         assert "module @jit_step_fn" in text
         return
-    from ray_tpu.serve import engine as engine_mod
+    from ray_tpu.serve import step_programs
     model, _ = _model(251)
-    fn = {"jit_decode": lambda: engine_mod._jit_decode(
+    fn = {"jit_decode": lambda: step_programs._jit_decode(
               model, 0.0, 128, 4, False, None),
-          "jit_prefill": lambda: engine_mod._jit_prefill(
+          "jit_prefill": lambda: step_programs._jit_prefill(
               model, 0.0, 4, False, None)}[program]()
     assert "jit_" + fn.__name__ == program
 
@@ -496,7 +496,7 @@ def test_scope_names_the_benchmark_keys_on(program):
     four, the GQA layer's gate and the shared expert's among them."""
     from ray_tpu.models.kv_cache import init_kv_pool
     from ray_tpu.models.solar_open2 import SolarOpen2, solar_open2_tiny
-    from ray_tpu.serve import engine as engine_mod
+    from ray_tpu.serve import step_programs
     cfg = solar_open2_tiny(dtype=jnp.float32, n_layers=4)
     model = SolarOpen2(cfg)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
@@ -507,12 +507,12 @@ def test_scope_names_the_benchmark_keys_on(program):
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     arr = jax.ShapeDtypeStruct
     if program == "jit_decode":
-        fn = engine_mod._jit_decode(model, 0.0, 128, S, False, None)
+        fn = step_programs._jit_decode(model, 0.0, 128, S, False, None)
         text = fn.lower(params, pages, arr((S, 8), i32), arr((S,), i32),
                         arr((S,), i32), key, arr((), i32)
                         ).as_text(debug_info=True)
     else:
-        fn = engine_mod._jit_prefill(model, 0.0, 4, False, None)
+        fn = step_programs._jit_prefill(model, 0.0, 4, False, None)
         text = fn.lower(params, pages, arr((4, 16), i32), arr((4,), i32),
                         arr((4,), i32), arr((4, 8), i32), key,
                         arr((4,), i32)).as_text(debug_info=True)
@@ -533,7 +533,7 @@ def test_latent_scope_names_the_benchmark_keys_on(program):
     the mixture's and the leading dense layer's module name."""
     from ray_tpu.models.axk1 import AXK1, axk1_tiny
     from ray_tpu.models.kv_cache import init_kv_pool
-    from ray_tpu.serve import engine as engine_mod
+    from ray_tpu.serve import step_programs
     cfg = axk1_tiny(dtype=jnp.float32, n_layers=2)
     model = AXK1(cfg)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
@@ -544,12 +544,12 @@ def test_latent_scope_names_the_benchmark_keys_on(program):
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     arr = jax.ShapeDtypeStruct
     if program == "jit_decode":
-        fn = engine_mod._jit_decode(model, 0.0, 128, S, False, None)
+        fn = step_programs._jit_decode(model, 0.0, 128, S, False, None)
         text = fn.lower(params, pages, arr((S, 8), i32), arr((S,), i32),
                         arr((S,), i32), key, arr((), i32)
                         ).as_text(debug_info=True)
     else:
-        fn = engine_mod._jit_prefill(model, 0.0, 4, False, None)
+        fn = step_programs._jit_prefill(model, 0.0, 4, False, None)
         text = fn.lower(params, pages, arr((4, 16), i32), arr((4,), i32),
                         arr((4,), i32), arr((4, 8), i32), key
                         ).as_text(debug_info=True)
@@ -576,7 +576,7 @@ def test_recurrent_and_latent_scopes_in_one_program(program):
     layer's module name."""
     from ray_tpu.models.kimi_linear import KimiLinear, kimi_linear_tiny
     from ray_tpu.models.kv_cache import init_kv_pool
-    from ray_tpu.serve import engine as engine_mod
+    from ray_tpu.serve import step_programs
     cfg = kimi_linear_tiny(dtype=jnp.float32, n_layers=4)
     model = KimiLinear(cfg)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
@@ -587,12 +587,12 @@ def test_recurrent_and_latent_scopes_in_one_program(program):
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     arr = jax.ShapeDtypeStruct
     if program == "jit_decode":
-        fn = engine_mod._jit_decode(model, 0.0, 128, S, False, None)
+        fn = step_programs._jit_decode(model, 0.0, 128, S, False, None)
         text = fn.lower(params, pages, arr((S, 8), i32), arr((S,), i32),
                         arr((S,), i32), key, arr((), i32)
                         ).as_text(debug_info=True)
     else:
-        fn = engine_mod._jit_prefill(model, 0.0, 4, False, None)
+        fn = step_programs._jit_prefill(model, 0.0, 4, False, None)
         text = fn.lower(params, pages, arr((4, 16), i32), arr((4,), i32),
                         arr((4,), i32), arr((4, 8), i32), key,
                         arr((4,), i32)).as_text(debug_info=True)

@@ -1,0 +1,191 @@
+"""A model arrives as one module (PR 45): what serving needs to know of
+a family it asks the family's CONFIG (the class to build, the
+partition rules where any exist), what a kind of request state cannot
+do it reads from one table beside ``page_layout``, and the host loop's
+file holds no device program. A new model that composes kinds that
+exist touches nothing under ``ray_tpu/serve/``.
+"""
+import ast
+import pathlib
+import types
+
+import pytest
+
+from ray_tpu.models import kv_cache
+from ray_tpu.models.kv_cache import (KIND_KV, KIND_LATENT, KIND_RECURRENT,
+                                     KIND_SLIDING, refuse_unsupported)
+
+SERVE = pathlib.Path(__file__).resolve().parent.parent / "ray_tpu" / "serve"
+# the family modules serve/ may import: sampling and the tiny default
+# (llama), the mixture's counters every mixture family shares (mixtral)
+_FAMILY_MODULES = {"axk1", "kimi_linear", "mellum", "solar_open2"}
+
+
+def _trees():
+    for path in sorted(SERVE.rglob("*.py")):
+        yield path, ast.parse(path.read_text(), filename=str(path))
+
+
+def test_serve_asks_no_config_its_type():
+    hits = []
+    for path, tree in _trees():
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) and \
+                    getattr(node.func, "id", "") == "isinstance" and \
+                    "Config" in ast.unparse(node.args[1]):
+                hits.append((path.name, node.lineno, ast.unparse(node)))
+    assert not hits, hits
+
+
+def test_serve_imports_no_family_but_llama_and_mixtral():
+    hits = []
+    for path, tree in _trees():
+        for node in ast.walk(tree):
+            names = []
+            if isinstance(node, ast.ImportFrom):
+                names = [node.module or ""] + [
+                    f"{node.module}.{a.name}" for a in node.names]
+            elif isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            hits += [(path.name, node.lineno, n) for n in names
+                     if n.startswith("ray_tpu.models.")
+                     and n.split(".")[2] in _FAMILY_MODULES]
+    assert not hits, hits
+
+
+def test_the_host_loop_holds_no_device_program():
+    """``serve/engine.py`` builds no program: the step programs live in
+    ``serve/step_programs.py``, and what is left of ``jax.jit`` in the
+    scheduler's file is nothing."""
+    engine = ast.parse((SERVE / "engine.py").read_text())
+    jits = [node.lineno for node in ast.walk(engine)
+            if isinstance(node, ast.Attribute) and node.attr == "jit"]
+    assert not jits, jits
+    programs = (SERVE / "step_programs.py").read_text()
+    for name in ("prefill", "decode", "verify", "write", "copy", "seed"):
+        assert f"    def {name}(" in programs, name
+
+
+# ----------------------------------------------- the family's facts
+
+def _families():
+    from ray_tpu.models.axk1 import AXK1, axk1_tiny
+    from ray_tpu.models.kimi_linear import KimiLinear, kimi_linear_tiny
+    from ray_tpu.models.llama import Llama, llama_tiny
+    from ray_tpu.models.mellum import Mellum, mellum_tiny
+    from ray_tpu.models.mixtral import Mixtral, mixtral_tiny
+    from ray_tpu.models.solar_open2 import SolarOpen2, solar_open2_tiny
+    return {"llama": (llama_tiny, Llama, "feed_forward"),
+            "mixtral": (mixtral_tiny, Mixtral, "moe/w2"),
+            "axk1": (axk1_tiny, AXK1, None),
+            "kimi_linear": (kimi_linear_tiny, KimiLinear, None),
+            "mellum": (mellum_tiny, Mellum, None),
+            "solar_open2": (solar_open2_tiny, SolarOpen2, None)}
+
+
+FAMILIES = ("llama", "mixtral", "axk1", "kimi_linear", "mellum",
+            "solar_open2")
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_deployment_builds_the_class_the_config_names(family):
+    from ray_tpu.serve.llm import LlamaDeployment
+    tiny, cls, _rule = _families()[family]
+    cfg = tiny()
+    assert cfg.model_class is cls
+    # the engine is lazy: nothing is initialised or compiled here
+    dep = LlamaDeployment(config=cfg, params={})
+    assert type(dep.model) is cls and dep.model.config is cfg
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_partition_rules_come_from_the_config_or_not_at_all(family):
+    """A family with rules gets ITS rules and its own divisibility
+    check; one that declares none is refused by name, by
+    ``family_sharding_rules`` itself (it handed Llama's rules to all
+    four before PR 45, and only the kinds' refusals kept them unused)."""
+    from ray_tpu.serve.sharding import (EngineSharding,
+                                        ShardingConfigError,
+                                        family_sharding_rules, validate_tp)
+    tiny, _cls, rule = _families()[family]
+    cfg = tiny()
+    if rule is None:
+        for ask in (lambda: family_sharding_rules(cfg),
+                    lambda: validate_tp(cfg, 2),
+                    lambda: EngineSharding.build(cfg, tp=1)):
+            with pytest.raises(ShardingConfigError,
+                               match=type(cfg).__name__):
+                ask()
+        return
+    got = family_sharding_rules(cfg)
+    assert got._rules == cfg.serving_rules._rules
+    assert any(rule in pat.pattern for pat, _spec in got._rules)
+    validate_tp(cfg, 2)
+    with pytest.raises(ShardingConfigError, match="tp=3"):
+        validate_tp(cfg, 3)
+
+
+def test_the_readers_hold_no_list_of_families():
+    """Whatever declares the facts is served by them: no type is looked
+    up on the way."""
+    from ray_tpu.serve.sharding import (ShardingConfigError,
+                                        family_sharding_rules, validate_tp)
+    asked = []
+    cfg = types.SimpleNamespace(
+        serving_rules="these", tp_validate=lambda tp, ep: asked.append(
+            (tp, ep)))
+    assert family_sharding_rules(cfg) == "these"
+    validate_tp(cfg, 4, 2)
+    assert asked == [(4, 2)]
+    with pytest.raises(ShardingConfigError, match="SimpleNamespace"):
+        family_sharding_rules(types.SimpleNamespace())
+
+
+# ------------------------------------- what a kind of state cannot do
+
+def _config_of(kind):
+    from ray_tpu.models.axk1 import axk1_tiny
+    from ray_tpu.models.mellum import mellum_tiny
+    from ray_tpu.models.solar_open2 import solar_open2_tiny
+    return {KIND_RECURRENT: solar_open2_tiny, KIND_LATENT: axk1_tiny,
+            KIND_SLIDING: mellum_tiny}[kind]()
+
+
+@pytest.mark.parametrize("kind,option", [
+    (kind, option) for kind in (KIND_RECURRENT, KIND_LATENT, KIND_SLIDING)
+    for option in kv_cache.KIND_REFUSALS[kind][1]])
+def test_the_tables_words_reach_the_refusal(kind, option):
+    keeps, why = kv_cache.KIND_REFUSALS[kind]
+    cfg = _config_of(kind)
+    with pytest.raises(ValueError) as refused:
+        refuse_unsupported(cfg, **{option: "asked"})
+    assert str(refused.value) == (
+        f"{option}='asked' is not supported for {type(cfg).__name__}: "
+        f"it has layers that keep {keeps}; {why[option]}")
+    refuse_unsupported(cfg, **{option: False})
+
+
+def test_the_table_is_twelve_refusals_over_four_kinds():
+    kinds = {getattr(kv_cache, name) for name in dir(kv_cache)
+             if name.startswith("KIND_") and name != "KIND_REFUSALS"}
+    assert kinds == set(kv_cache.KIND_REFUSALS)
+    assert {kind: len(why) for kind, (_keeps, why)
+            in kv_cache.KIND_REFUSALS.items()} == {
+        KIND_KV: 0, KIND_RECURRENT: 4, KIND_LATENT: 3, KIND_SLIDING: 5}
+
+
+def test_pages_of_keys_and_values_are_refused_nothing():
+    from ray_tpu.models.llama import llama_tiny
+    from ray_tpu.models.mixtral import olmoe_tiny
+    everything = dict(prefix_cache=True, spec_len=4, kv_dtype="int8",
+                      kv_migration="disaggregate", sharding=True)
+    refuse_unsupported(llama_tiny(), **everything)
+    refuse_unsupported(olmoe_tiny(), **everything)
+
+
+def test_a_page_of_latent_entries_takes_its_int8_refusal_from_the_table():
+    cfg = _config_of(KIND_LATENT)
+    with pytest.raises(ValueError) as refused:
+        kv_cache.page_layout(cfg, KIND_LATENT, 8, "int8")
+    assert kv_cache.KIND_REFUSALS[KIND_LATENT][1]["kv_dtype"] in str(
+        refused.value)

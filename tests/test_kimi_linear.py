@@ -553,20 +553,24 @@ def test_the_deployment_refuses_at_construction(tiny, option, match):
         LlamaDeployment(config=cfg, params=params, **option)
 
 
-def test_each_list_alone_refuses_what_is_its_own(tiny):
-    """Were the recurrent layers' list lifted, the latent pages' list
+def test_each_list_alone_refuses_what_is_its_own(tiny, monkeypatch):
+    """Were the recurrent layers' row lifted, the latent pages' row
     would still refuse KV export and sharding for this config, and the
     other way round: neither leans on the other."""
-    from ray_tpu.serve.engine import (refuse_for_latent_pages,
-                                      refuse_for_recurrent_state)
+    from ray_tpu.models import kv_cache
+    from ray_tpu.models.kv_cache import (KIND_LATENT, KIND_RECURRENT,
+                                         refuse_unsupported)
     cfg, _model, _params = tiny
-    for option in ("kv_migration", "sharding"):
-        with pytest.raises(ValueError, match="latent pages"):
-            refuse_for_latent_pages(cfg, **{option: True})
-        with pytest.raises(ValueError, match="recurrent state"):
-            refuse_for_recurrent_state(cfg, **{option: True})
-    refuse_for_latent_pages(cfg, kv_dtype=False, sharding=False)
-    refuse_for_recurrent_state(cfg, prefix_cache=False, spec_len=0)
+    for lifted, match in ((KIND_RECURRENT, "latent pages"),
+                          (KIND_LATENT, "recurrent state")):
+        with monkeypatch.context() as m:
+            m.setitem(kv_cache.KIND_REFUSALS, lifted,
+                      (kv_cache.KIND_REFUSALS[lifted][0], {}))
+            for option in ("kv_migration", "sharding"):
+                with pytest.raises(ValueError, match=match):
+                    refuse_unsupported(cfg, **{option: True})
+    refuse_unsupported(cfg, kv_dtype=False, sharding=False,
+                       prefix_cache=False, spec_len=0)
 
 
 def test_kv_export_is_refused(tiny):
@@ -589,9 +593,10 @@ def test_serve_run_serves_it_through_the_deployment(tiny, rt):
     """ray_tpu.init() -> serve.run() of LlamaDeployment, as a user
     deploys it: no side script, no option that selects a path."""
     from ray_tpu import serve
-    from ray_tpu.serve.llm import LlamaDeployment, _family_for
+    from ray_tpu.serve.llm import LlamaDeployment
     cfg, _model, params = tiny
-    assert _family_for(cfg) == (KimiLinear, None)
+    assert cfg.model_class is KimiLinear
+    assert not hasattr(cfg, "serving_rules")
     holder = {}
 
     @serve.deployment

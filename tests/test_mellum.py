@@ -574,17 +574,17 @@ def test_the_scopes_by_layer_type_reach_both_programs(tiny):
     """``attn_sliding`` and ``attn_full`` with their parts inside, in
     the lowering of both step programs: what the benchmark's readers
     split a device trace by."""
-    from ray_tpu.serve import engine as engine_mod
+    from ray_tpu.serve import step_programs
     cfg, model, params = tiny
     L = sliding_ring_len(cfg, PAGE, CHUNK)
     pool = init_kv_pool(cfg, 16, PAGE, n_slots=2, ring_len=L)
     i32 = jnp.int32
     key = jax.random.PRNGKey(0)
     table = jnp.zeros((2, 8), i32)
-    decode = engine_mod._jit_decode(model, 0.0, 8, 2, False, None).lower(
+    decode = step_programs._jit_decode(model, 0.0, 8, 2, False, None).lower(
         params, pool, table, jnp.zeros((2,), i32), jnp.zeros((2,), i32),
         key, jnp.int32(1))
-    prefill = engine_mod._jit_prefill(model, 0.0, 2, False, None).lower(
+    prefill = step_programs._jit_prefill(model, 0.0, 2, False, None).lower(
         params, pool, jnp.zeros((2, CHUNK), i32), jnp.zeros((2,), i32),
         jnp.zeros((2,), i32), table, key, jnp.zeros((2,), i32))
     for lowered in (decode, prefill):
@@ -624,30 +624,31 @@ def test_the_deployment_refuses_at_construction(tiny, option, name):
         LlamaDeployment(config=cfg, params=params, **option)
 
 
-def test_each_list_alone_holds_to_its_own_kind(tiny):
+def test_each_list_alone_holds_to_its_own_kind(tiny, monkeypatch):
     """The sliding entries' list refuses five options for this config
     and nothing for a model without such layers; the two older lists
     refuse nothing for this config: none leans on another."""
+    from ray_tpu.models import kv_cache
     from ray_tpu.models.kimi_linear import kimi_linear_tiny
+    from ray_tpu.models.kv_cache import KIND_SLIDING, refuse_unsupported
     from ray_tpu.models.llama import llama_tiny
-    from ray_tpu.serve.engine import (refuse_for_latent_pages,
-                                      refuse_for_recurrent_state,
-                                      refuse_for_sliding_entries)
     cfg, _model, _params = tiny
     options = ("prefix_cache", "spec_len", "kv_migration", "kv_dtype",
                "sharding")
     for option in options:
         with pytest.raises(ValueError,
                            match=f"{option}=True.*MellumConfig.*ring"):
-            refuse_for_sliding_entries(cfg, **{option: True})
-    refuse_for_sliding_entries(cfg, **dict.fromkeys(options, False))
+            refuse_unsupported(cfg, **{option: True})
+    refuse_unsupported(cfg, **dict.fromkeys(options, False))
     everything = dict.fromkeys(options, True)
-    refuse_for_sliding_entries(llama_tiny(), **everything)
-    refuse_for_sliding_entries(kimi_linear_tiny(), **everything)
-    refuse_for_recurrent_state(cfg, prefix_cache=True, spec_len=2,
-                               kv_migration=True, sharding=True)
-    refuse_for_latent_pages(cfg, kv_dtype="int8", kv_migration=True,
-                            sharding=True)
+    refuse_unsupported(llama_tiny(), **everything)
+    with pytest.raises(ValueError) as other:
+        refuse_unsupported(kimi_linear_tiny(), **everything)
+    assert "ring" not in str(other.value)
+    # with the sliding row lifted, the older rows refuse it nothing
+    monkeypatch.setitem(kv_cache.KIND_REFUSALS, KIND_SLIDING,
+                        (kv_cache.KIND_REFUSALS[KIND_SLIDING][0], {}))
+    refuse_unsupported(cfg, **dict(everything, kv_dtype="int8"))
 
 
 def test_kv_export_is_refused(tiny):
@@ -670,9 +671,10 @@ def test_serve_run_serves_it_through_the_deployment(tiny, rt):
     """ray_tpu.init() -> serve.run() of LlamaDeployment, as a user
     deploys it: no side script, no option that selects a path."""
     from ray_tpu import serve
-    from ray_tpu.serve.llm import LlamaDeployment, _family_for
+    from ray_tpu.serve.llm import LlamaDeployment
     cfg, _model, params = tiny
-    assert _family_for(cfg) == (Mellum, None)
+    assert cfg.model_class is Mellum
+    assert not hasattr(cfg, "serving_rules")
     holder = {}
 
     @serve.deployment

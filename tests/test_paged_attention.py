@@ -326,7 +326,7 @@ def test_page_bytes_round_trip_lands_identical_pages(kv_dtype):
     from ray_tpu.models.kv_cache import (export_page_bytes, init_kv_pool,
                                          page_cols_from_bytes)
     from ray_tpu.models.llama import llama_tiny
-    from ray_tpu.serve.engine import _jit_write_page
+    from ray_tpu.serve.step_programs import _jit_write_page
     cfg = llama_tiny(dtype=jnp.float32)
     Pg, n_pages, src, dst = 4, 8, 5, 2
     rng = np.random.default_rng(12)

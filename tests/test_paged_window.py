@@ -19,7 +19,7 @@ from ray_tpu.models import llama as llama_mod
 from ray_tpu.models.kv_cache import init_kv_pool
 from ray_tpu.models.llama import Llama, llama_tiny
 from ray_tpu.ops import paged_attention as paged_mod
-from ray_tpu.serve import engine as engine_mod
+from ray_tpu.serve import step_programs
 from ray_tpu.serve.engine import LLMEngine
 
 PAGE, BLOCK, N_PAGES = 8, 16, 65
@@ -41,8 +41,8 @@ def block_tokens(monkeypatch):
     """set(n): the window loop's block is n tokens for programs built
     from here on; the shared program caches are cleared now and after."""
     def clear():
-        engine_mod._jit_decode.cache_clear()
-        engine_mod._jit_prefill.cache_clear()
+        step_programs._jit_decode.cache_clear()
+        step_programs._jit_prefill.cache_clear()
 
     def set_(n):
         monkeypatch.setattr(paged_mod, "_WINDOW_BLOCK_TOKENS", n)
@@ -74,7 +74,7 @@ def _filled_pool(model, params, pt, contexts, kv_dtype):
     ids = jax.random.randint(jax.random.PRNGKey(3),
                              (len(contexts), width), 0, CFG.vocab_size)
     pages = init_kv_pool(CFG, N_PAGES, PAGE, kv_dtype)
-    pre = engine_mod._jit_prefill(model, 0.0, len(contexts), False, None)
+    pre = step_programs._jit_prefill(model, 0.0, len(contexts), False, None)
     _, pages, _ = pre(params, pages, ids,
                       jnp.zeros((len(contexts),), jnp.int32),
                       jnp.zeros((len(contexts),), jnp.int32),
@@ -94,7 +94,7 @@ def _run(case, model, params, pages, pt):
         ids = jax.random.randint(jax.random.PRNGKey(5), (rows, T), 0,
                                  CFG.vocab_size)
         start = np.asarray([c or 0 for c in case["contexts"]], np.int32)
-        pre = engine_mod._jit_prefill(model, 0.0, rows, True, None)
+        pre = step_programs._jit_prefill(model, 0.0, rows, True, None)
         (toks, lps), pages, _ = pre(
             params, pages, ids, jnp.asarray(start),
             jnp.full((rows,), T - 1, jnp.int32), jnp.asarray(pt),
@@ -104,7 +104,7 @@ def _run(case, model, params, pages, pt):
         steps = case["steps"]
         pos = np.asarray([case.get("stale", 0) if c is None else c
                           for c in case["contexts"]], np.int32)
-        dec = engine_mod._jit_decode(model, 0.0, KMAX, rows, True, None)
+        dec = step_programs._jit_decode(model, 0.0, KMAX, rows, True, None)
         (toks, lps), pages, _, _, _ = dec(
             params, pages, jnp.asarray(pt), jnp.asarray(pos),
             jnp.full((rows,), 7, jnp.int32), jax.random.PRNGKey(0),
