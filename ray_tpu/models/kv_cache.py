@@ -235,12 +235,11 @@ def page_layout(cfg, kind: str, page_size: int, kv_dtype: str = "fp"):
     (shape, dtype) a tensor, in storage order. The pool
     (``init_kv_pool``), its bytes (``kv_pool_page_bytes``) and a
     shipped page's frames (``page_cols_from_bytes``) all read it here.
-
     kv fp:   k, v           [Pg, KH, D] cfg.dtype
     kv int8: k, v, sk, sv   [Pg, KH, D] int8 and [KH] fp32 absmax
+             (each behind ``kv_entries_per_layer``'s pass axis, if any)
     latent:  one tensor     [Pg, latent_page_width(cfg)] cfg.dtype
-    recurrent, sliding: none (a state and a ring belong to a slot, not
-    to a page)
+    recurrent, sliding: none (they belong to a slot, not to a page)
     """
     if kind in (KIND_RECURRENT, KIND_SLIDING):
         return ()
@@ -250,9 +249,10 @@ def page_layout(cfg, kind: str, page_size: int, kv_dtype: str = "fp"):
             raise _refusal(cfg, kind, "kv_dtype", kv_dtype)
         return (((page_size, latent_page_width(cfg)),
                  jnp.dtype(cfg.dtype)),)
-    shape = (page_size, cfg.n_kv_heads, cfg.head_dim)
+    passes = kv_entries_per_layer(cfg)
+    shape = passes + (page_size, cfg.n_kv_heads, cfg.head_dim)
     if quantized:
-        scale = ((cfg.n_kv_heads,), jnp.dtype(KV_SCALE_DTYPE))
+        scale = (passes + (cfg.n_kv_heads,), jnp.dtype(KV_SCALE_DTYPE))
         return ((shape, jnp.dtype(jnp.int8)),) * 2 + (scale,) * 2
     return ((shape, jnp.dtype(cfg.dtype)),) * 2
 
@@ -623,3 +623,19 @@ class BlockAllocator:
             seen.add(p)
         self._free.extend(pages)
         self._free_set.update(pages)
+
+
+def kv_entries_per_layer(cfg) -> Tuple[int, ...]:
+    """The pass axis of a K/V layer's page, as a shape prefix: () for a
+    model whose layers run once a token (one cache entry a layer, the
+    pool it always had), ``(T,)`` for one whose config declares
+    ``kv_entries_per_layer`` = T (models/ouro.py: the one stack of
+    layers runs T times, and a token keeps T entries a layer of
+    weights). The number of cache entries is the CACHE's, not the
+    weights': ``layer_kinds`` stays ``n_layers`` long and the page
+    carries the factor, so a page id still names one page of every
+    entry. (Defined at the file's end, and ``page_layout`` kept to its
+    lines: the step programs' compile-cache key carries the line of
+    every function above that makes an operation, PERF.md section 7.)"""
+    passes = getattr(cfg, "kv_entries_per_layer", None)
+    return () if passes is None else (int(passes),)

@@ -17,7 +17,12 @@ and test_mellum2_family.py (the Mellum 2 family: the configuration
 against its published copy, the program against the reference and the
 reference against its quadratic form, the scored tail, byte counts by
 kind of layer, the six readers on a hand-made joined trace, the cell on
-longdoc-sat as it stands, the rehearsal cell at --trace 0 and 2),
+longdoc-sat as it stands, the rehearsal cell at --trace 0 and 2) and
+test_ouro_family.py (the Ouro family: the configuration whole against
+its published copy, the program against the reference and the margin
+rule against the reference's controls, byte counts with the weights
+once a pass, the two readers on a hand-made joined trace with a nested
+loop, the cell on chat-sat as it stands, the rehearsal cell),
 collected here so that the suite the driver runs guards them.
 `python -m pytest benchmarks/tests` still runs them where they live."""
 import pytest
@@ -28,7 +33,8 @@ _FILES = ("benchmarks.tests.test_families",
           "benchmarks.tests.test_solar_open2_family",
           "benchmarks.tests.test_axk1_family",
           "benchmarks.tests.test_kimi_linear_family",
-          "benchmarks.tests.test_mellum2_family")
+          "benchmarks.tests.test_mellum2_family",
+          "benchmarks.tests.test_ouro_family")
 pytest.register_assert_rewrite(*_FILES)
 
 from benchmarks.tests.test_families import *          # noqa: E402,F401,F403
@@ -38,6 +44,7 @@ from benchmarks.tests.test_solar_open2_family import *  # noqa: E402,F401,F403
 from benchmarks.tests.test_axk1_family import *       # noqa: E402,F401,F403
 from benchmarks.tests.test_kimi_linear_family import *  # noqa: E402,F401,F403
 from benchmarks.tests.test_mellum2_family import *    # noqa: E402,F401,F403
+from benchmarks.tests.test_ouro_family import *       # noqa: E402,F401,F403
 
 # Recorded without tier-1's low-optimisation XLA flags (tests/conftest.py),
 # under which the CPU draws a normal's last bits differently: the digests
@@ -68,6 +75,12 @@ del test_seeded_weights_are_the_parents_bit_for_bit    # noqa: F821
 # the four dispatch readers pins them TEN before the file's end, and
 # benchmarks/tests/test_mellum2_family.py::
 # test_the_cell_and_longdoc_sat_as_it_stands pins PR 42's.
+# PR 46 appended a configuration, a cell and two readers, and the cell
+# to the lists of ten older metrics: every older case (PR 42's pin
+# among them) runs against the file less those too, the case of the
+# four dispatch readers pins them TWELVE before the file's end, and
+# benchmarks/tests/test_ouro_family.py::
+# test_the_cell_and_chat_sat_as_it_stands pins PR 46's.
 _DISPATCH = ("dispatch_prefill_call_ms", "dispatch_decode_step_ms",
              "dispatch_prefill_share", "dispatch_prefill_call_ms.open")
 _PR39 = ("state_peak_share", "linear_state_roofline.by_kind",
@@ -76,25 +89,36 @@ _PR42 = ("decode_sliding_attn_ms", "decode_full_attn_ms",
          "sliding_attn_roofline", "prefill_sliding_attn_share",
          "prefill_full_attn_share", "sliding_resident_share")
 _PR42_CELL, _PR42_CONFIG = "mellum2-d8.longdoc-sat", "mellum2-12b-a2.5b-d8"
+_PR46 = ("loop_step_roofline", "loop_attn_share")
+_PR46_CELL, _PR46_CONFIG = "ouro-2.6b.chat-sat", "ouro-2.6b"
 _SAT = ["mistral7b-d16.chat-sat", "olmoe-d8.chat-sat",
         "solar-open2-d4.doc-sat", "axk1-d5.longdoc-sat",
-        "kimi-linear-d8.gen-sat", _PR42_CELL]
+        "kimi-linear-d8.gen-sat", _PR42_CELL, _PR46_CELL]
 
 
-def _less_pr42(bench):
-    """BENCHMARK.json as PR 40 left it: without PR 42's configuration,
-    cell and readers, and without the cell's name in any list."""
-    assert bench["configs"][-1]["name"] == _PR42_CONFIG
-    assert bench["workloads"][-1]["name"] == _PR42_CELL
+def _less_a_pr(bench, config, cell, readers):
+    """BENCHMARK.json without its LAST configuration and cell (which
+    must be these), those readers, and the cell's name in any list."""
+    assert bench["configs"][-1]["name"] == config
+    assert bench["workloads"][-1]["name"] == cell
     bench["configs"], bench["workloads"] = (bench["configs"][:-1],
                                             bench["workloads"][:-1])
     for section in ("end_to_end", "per_layer"):
         bench[section] = [
-            dict(m, workloads=[w for w in m["workloads"]
-                               if w != _PR42_CELL])
+            dict(m, workloads=[w for w in m["workloads"] if w != cell])
             if "workloads" in m else m
-            for m in bench[section] if m["name"] not in _PR42]
+            for m in bench[section] if m["name"] not in readers]
     return bench
+
+
+def _less_pr46(bench):
+    """BENCHMARK.json as PR 45 left it."""
+    return _less_a_pr(bench, _PR46_CONFIG, _PR46_CELL, _PR46)
+
+
+def _less_pr42(bench):
+    """BENCHMARK.json as PR 40 left it."""
+    return _less_a_pr(_less_pr46(bench), _PR42_CONFIG, _PR42_CELL, _PR42)
 
 
 def _less_the_dispatch_readers(case, also=_DISPATCH + _PR39):
@@ -118,12 +142,27 @@ test_the_cell_and_gen_sat = _less_the_dispatch_readers(
     test_the_cell_and_gen_sat, also=())                 # noqa: F821
 
 
+def _as_pr45_left_it(case):
+    def test(monkeypatch):
+        from benchmarks import common
+        bench = _less_pr46(common.load_benchmark())
+        monkeypatch.setattr(common, "load_benchmark", lambda: bench)
+        case()
+    test.__name__ = case.__name__
+    test.__doc__ = case.__doc__
+    return test
+
+
+test_the_cell_and_longdoc_sat_as_it_stands = _as_pr45_left_it(
+    test_the_cell_and_longdoc_sat_as_it_stands)         # noqa: F821
+
+
 @pytest.mark.parametrize("name", _DISPATCH)
 def test_dispatch_readers_are_appended_to_the_benchmark(name):
     from benchmarks import common
     bench = common.load_benchmark()
-    assert tuple(m["name"] for m in bench["per_layer"][-14:]) == \
-        _DISPATCH + _PR39 + _PR42
+    assert tuple(m["name"] for m in bench["per_layer"][-16:]) == \
+        _DISPATCH + _PR39 + _PR42 + _PR46
     m = common.find_named(bench["per_layer"], name, "metric")
     want = {"name": name, "unit": "ms", "better": "lower",
             "source": "device_trace", "layer": "model step",

@@ -696,3 +696,95 @@ def test_sliding_rings_and_pages_stay_in_place(one_chip, monkeypatch,
         # four rows of 256 queries: the block loop's float32 scores
         # [4, 32, 256, 512] and the mixture's 8,192 routed rows
         assert temp < 1 << 30, (name, temp)
+
+
+# ---------------------------------------------------------------
+# A model whose layers run several times at its cell's sizes, WHOLE
+# (Ouro-2.6B: 48 layers of 16 heads over 16 KV heads of 128 run 4 times,
+# the whole vocabulary, 16 slots, 97 pages of 64 tokens whose pages
+# carry the pass axis: 192 cache entries a token behind 48 layers of
+# weights): test_step_programs_copy_no_pool's rule on the pool inside a
+# loop over passes inside the loop over steps. The passes are ONE loop
+# on the device, so the programs hold one copy of the stack, the pool is
+# each program's argument and its result in place, and weights, pool
+# and temporaries fit the chip together.
+
+OURO_PAGES, OURO_SLOTS = 97, 16
+
+
+def _looped_step(name, one_chip):
+    from ray_tpu.models.kv_cache import init_kv_pool
+    from ray_tpu.models.ouro import Ouro, ouro_2_6b
+    from ray_tpu.serve import step_programs
+    cfg = ouro_2_6b(max_seq_len=4096, param_dtype=jnp.bfloat16)
+    model = Ouro(cfg)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                           sharding=one_chip), tree)
+    params = placed({"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]})
+    pages = placed(jax.eval_shape(
+        lambda: init_kv_pool(cfg, OURO_PAGES, PAGE)))
+    i32 = jnp.int32
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    table = ((OURO_SLOTS, cfg.max_seq_len // PAGE), i32)
+    if name == "decode":
+        fn = step_programs._jit_decode(model, 0.0, 128, OURO_SLOTS, False,
+                                       None)
+        rest = [table, ((OURO_SLOTS,), i32), ((OURO_SLOTS,), i32),
+                (key.shape, key.dtype), ((), i32)]
+    else:
+        fn = step_programs._jit_prefill(model, 0.0, 4, False, None)
+        rest = [((4, 256), i32), ((4,), i32), ((4,), i32),
+                ((4, table[0][1]), i32), (key.shape, key.dtype)]
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in rest]
+    return fn.lower(params, pages, *rest).compile()
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_looped_step_programs_copy_no_pool_and_hold_one_stack(one_chip,
+                                                              name):
+    compiled = _looped_step(name, one_chip)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text        # XLA's from end to end
+    # a layer's pool as it is stored, and as a pass's layer sees it
+    stored, seen = (OURO_PAGES, 4, PAGE, 16, 128), (OURO_PAGES * 4, PAGE,
+                                                    16, 128)
+    entry = re.findall(r"bf16\[%s\](\{[^}]*\}) parameter" % ",".join(
+        str(d) for d in stored), text)
+    assert len(entry) >= 96 and all(e.startswith("{4,3,2,1,0")
+                                    for e in entry), entry[:2]
+    for shape in (stored, seen):
+        dims = ",".join(str(d) for d in shape)
+        moved = _pool_copies(text, shape) + re.findall(
+            r"= bf16\[" + dims + r"\](?:\{[^}]*\})? transpose\(", text)
+        assert not moved, (len(moved), moved[:2])
+    # ONE copy of the stack: each of the 48 layers' block loop appears
+    # once, not once a pass
+    for scope, spec in (("attn_scores", "btkrd,bskd->bkrts"),
+                        ("attn_pv", "bkrts,bskd->bkrtd")):
+        convs = re.findall(
+            r" convolution\([^\n]*ut_pass/layers_\d+/attention/[^\n\"]*"
+            + scope + "/" + spec, text)
+        assert len(convs) == 48, (scope, len(convs))
+    mem = compiled.memory_analysis()
+    pool = 48 * 2 * math.prod(stored) * 2               # 9.76 GB
+    weights = 2 * (48 * (4 * 2048 * 2048 + 3 * 2048 * 5632)
+                   + 2 * 49152 * 2048)                  # 5.34 GB
+    # the pool is the program's argument and its result, in place
+    assert mem.alias_size_in_bytes >= pool
+    assert mem.argument_size_in_bytes >= pool + weights
+    # no temporary of a layer's pool of one pass's size a layer, let
+    # alone of the pool's: the decode program's are the projections'
+    # layout copies, hoisted out of both loops (8.4 MB each, three a
+    # layer), the prefill call's its logits and the block loop's scores
+    temp = mem.temp_size_in_bytes
+    assert temp < (1400 << 20 if name == "decode" else 512 << 20), temp
+    # weights, pool and temporaries together fit a chip of 15.75 GiB
+    held = (mem.argument_size_in_bytes + temp + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes)
+    assert held < 15.75 * 2 ** 30, held

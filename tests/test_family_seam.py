@@ -18,7 +18,7 @@ from ray_tpu.models.kv_cache import (KIND_KV, KIND_LATENT, KIND_RECURRENT,
 SERVE = pathlib.Path(__file__).resolve().parent.parent / "ray_tpu" / "serve"
 # the family modules serve/ may import: sampling and the tiny default
 # (llama), the mixture's counters every mixture family shares (mixtral)
-_FAMILY_MODULES = {"axk1", "kimi_linear", "mellum", "solar_open2"}
+_FAMILY_MODULES = {"axk1", "kimi_linear", "mellum", "ouro", "solar_open2"}
 
 
 def _trees():
@@ -74,16 +74,18 @@ def _families():
     from ray_tpu.models.llama import Llama, llama_tiny
     from ray_tpu.models.mellum import Mellum, mellum_tiny
     from ray_tpu.models.mixtral import Mixtral, mixtral_tiny
+    from ray_tpu.models.ouro import Ouro, ouro_tiny
     from ray_tpu.models.solar_open2 import SolarOpen2, solar_open2_tiny
     return {"llama": (llama_tiny, Llama, "feed_forward"),
             "mixtral": (mixtral_tiny, Mixtral, "moe/w2"),
             "axk1": (axk1_tiny, AXK1, None),
             "kimi_linear": (kimi_linear_tiny, KimiLinear, None),
             "mellum": (mellum_tiny, Mellum, None),
+            "ouro": (ouro_tiny, Ouro, None),
             "solar_open2": (solar_open2_tiny, SolarOpen2, None)}
 
 
-FAMILIES = ("llama", "mixtral", "axk1", "kimi_linear", "mellum",
+FAMILIES = ("llama", "mixtral", "axk1", "kimi_linear", "mellum", "ouro",
             "solar_open2")
 
 
@@ -189,3 +191,54 @@ def test_a_page_of_latent_entries_takes_its_int8_refusal_from_the_table():
         kv_cache.page_layout(cfg, KIND_LATENT, 8, "int8")
     assert kv_cache.KIND_REFUSALS[KIND_LATENT][1]["kv_dtype"] in str(
         refused.value)
+
+
+# --------------------------- the cache's entries are the cache's to count
+
+def test_cache_entries_that_outnumber_the_layers_are_the_caches_to_count():
+    """A config whose layers run several times a token declares
+    ``kv_entries_per_layer``: its pool, its page bytes and its exported
+    page come from the cache's own count (a pass axis inside the page),
+    while ``layer_kinds`` stays as long as the weights' layers, so a
+    page id still names ONE page of every entry; a config that declares
+    nothing keeps the pool it always had."""
+    import jax.numpy as jnp
+    import numpy as np
+    from ray_tpu.models.llama import llama_tiny
+    once = llama_tiny(n_kv_heads=4)
+    thrice = types.SimpleNamespace(
+        n_layers=once.n_layers, n_kv_heads=4, head_dim=once.head_dim,
+        dtype=jnp.bfloat16, kv_entries_per_layer=3)
+    assert kv_cache.kv_entries_per_layer(once) == ()
+    assert kv_cache.kv_entries_per_layer(thrice) == (3,)
+    assert kv_cache.layer_kinds(thrice) == (KIND_KV,) * once.n_layers
+    Pg, KH, D = 8, 4, once.head_dim
+    for dtype, tensors in (("fp", 2), ("int8", 4)):
+        pool = kv_cache.init_kv_pool(thrice, 5, Pg, dtype)
+        plain = kv_cache.init_kv_pool(once, 5, Pg, dtype)
+        assert len(pool) == len(plain) == once.n_layers
+        assert len(pool[0]) == len(plain[0]) == tensors
+        assert pool[0][0].shape == (5, 3, Pg, KH, D)
+        assert plain[0][0].shape == (5, Pg, KH, D)
+        if dtype == "int8":
+            assert pool[0][2].shape == (5, 3, KH)
+            assert plain[0][2].shape == (5, KH)
+        # three entries a layer: three times the bytes a page
+        scale = 1 if dtype == "fp" else 2
+        one = kv_cache.kv_pool_page_bytes(
+            types.SimpleNamespace(**{**vars(thrice),
+                                     "kv_entries_per_layer": 1}), Pg, dtype)
+        assert kv_cache.kv_pool_page_bytes(thrice, Pg, dtype) == 3 * one
+        assert one == once.n_layers * (
+            2 * Pg * KH * D * (2 // scale) + (2 * KH * 4 if scale == 2
+                                             else 0))
+        # one page id: the page of all three entries of every layer
+        blobs = kv_cache.export_page_bytes(pool, 2)
+        assert sum(len(b) for layer in blobs for b in layer) == \
+            kv_cache.kv_pool_page_bytes(thrice, Pg, dtype)
+        cols = kv_cache.page_cols_from_bytes(thrice, Pg, dtype, blobs)
+        assert cols[0][0].shape == (3, Pg, KH, D)
+        with pytest.raises(ValueError, match="expected"):
+            kv_cache.page_cols_from_bytes(once, Pg, dtype, blobs)
+        assert np.asarray(cols[0][0]).dtype == np.asarray(
+            pool[0][0]).dtype
