@@ -20,7 +20,10 @@ through the entry points a user calls:
 With ``--chips 4`` it runs ONLY the four-chip phase and what that is
 compared with: four one-chip engine replicas on four distinct devices,
 one tensor_parallel=4 engine, and the train step on a {"data": 4}
-mesh, each against its one-chip twin.
+mesh, each against its one-chip twin. The engines there serve heads of
+128, so that a one-chip replica's decode step is the Pallas kernel of
+ops/paged_decode_attention.py and the tensor-parallel replica's is the
+block loop.
 
 There is no CPU branch: without a TPU the script exits non-zero at the
 device check. A failed phase raises, and the script exits non-zero
@@ -122,12 +125,15 @@ class timed:
 
 # ------------------------------------------------------------ configs
 
-def llama_1b_config(max_seq_len: int = 1024):
-    """TinyLlama-1.1B (bench.py's llama-1.1b shape), bf16 weights."""
+def llama_1b_config(max_seq_len: int = 1024, n_heads: int = 32):
+    """TinyLlama-1.1B (bench.py's llama-1.1b shape), bf16 weights.
+    ``n_heads`` 16: the same width in heads of 128, which a decode
+    step's Pallas kernel serves (ops/paged_decode_attention.py) where
+    heads of 64 keep the block loop."""
     import jax.numpy as jnp
     from ray_tpu.models.llama import LlamaConfig
     return LlamaConfig(vocab_size=32000, max_seq_len=max_seq_len,
-                       dim=2048, n_layers=22, n_heads=32, n_kv_heads=4,
+                       dim=2048, n_layers=22, n_heads=n_heads, n_kv_heads=4,
                        hidden_dim=5632, dtype=jnp.bfloat16,
                        param_dtype=jnp.bfloat16)
 
@@ -358,6 +364,8 @@ def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
                  window_shapes=((256, 64, 640, 512,
                                  (512, 8192, None, 2304), None),),
                  gmm_shapes=((64, 2304, 1024, 256, 200),),
+                 decode_shapes=((16, 16, (300, 352, None, 64, 1)),
+                                (32, 4, (8704, None, 70))),
                  interpret: bool = False, seed: int = SEED) -> dict:
     """The flash-attention kernel, compiled (not interpreted, unless
     the CPU rehearsal asks) and compared with its XLA reference,
@@ -368,7 +376,10 @@ def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
     (``window_shapes``: chunk, heads, entry and value widths, where
     each row's window ends, None a row no request owns, and a query
     tile's tokens where not the kernel's own) at a serving cell's shape
-    against the block loop; the mixture's grouped matmul
+    against the block loop; a decode step's attention over K/V pages
+    (``decode_shapes``: heads, KV heads, each row's context, None a row
+    no request owns) against the block loop too; the mixture's grouped
+    matmul
     (``gmm_shapes``: experts, K, N, sorted pairs, pairs that have an
     expert) at a contraction the tile plan takes whole where constants
     left a remainder, against ``jax.lax.ragged_dot`` in float32."""
@@ -449,21 +460,30 @@ def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
     from ray_tpu.ops import latent_window_attention as lw
     from ray_tpu.ops import paged_attention as pa
     page, max_pages = 64, 256
-    for T, H, D, Dv, ends, tokens in window_shapes:
-        name = f"latent_window_T{T}_H{H}_D{D}"
+
+    def rows_of(ends, T):
+        """(page table, first query's position, live rows) of rows whose
+        ``T`` queries end at ``ends`` (None: a row no request owns, its
+        table row null and its position stale), their pages scattered."""
         B = len(ends)
-        dtype = jnp.float32 if interpret else jnp.bfloat16
         ids = 1 + rng.permutation(B * max_pages).reshape(B, max_pages)
         table = np.zeros((B, max_pages), np.int32)
-        pos = np.full((B,), 10 ** 6, np.int32)      # stale where null
+        pos = np.full((B,), 10 ** 6, np.int32)
         for b, end in enumerate(ends):
             if end is not None:
                 pos[b] = end - T
                 table[b, :-(-end // page)] = ids[b, :-(-end // page)]
+        return table, pos, [b for b, end in enumerate(ends)
+                            if end is not None]
+
+    for T, H, D, Dv, ends, tokens in window_shapes:
+        name = f"latent_window_T{T}_H{H}_D{D}"
+        B = len(ends)
+        dtype = jnp.float32 if interpret else jnp.bfloat16
+        table, pos, live = rows_of(ends, T)
         pages = jnp.asarray(
             rng.standard_normal((1 + B * max_pages, page, D)), dtype)
         q = jnp.asarray(rng.standard_normal((B, T, H, D)), dtype)
-        live = [b for b, end in enumerate(ends) if end is not None]
         with timed(f"kernels: {name}"):
             got = jax.jit(functools.partial(
                 lw.latent_window_attention, softmax_scale=D ** -0.5,
@@ -474,6 +494,25 @@ def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
                 want = jax.jit(functools.partial(
                     pa._paged_window_attention, softmax_scale=D ** -0.5,
                     value_dim=Dv))(q, pages, None, None, None, table, pos)
+            errs[name] = _rel_err(np.asarray(got, np.float32)[live],
+                                  np.asarray(want, np.float32)[live])
+
+    from ray_tpu.ops import paged_decode_attention as pd
+    for H, KH, contexts in decode_shapes:
+        name = f"paged_decode_H{H}_KH{KH}"
+        B, D = len(contexts), 128
+        dtype = jnp.float32 if interpret else jnp.bfloat16
+        table, pos, live = rows_of(contexts, 1)
+        pk, pv = (jnp.asarray(rng.standard_normal(
+            (1 + B * max_pages, page, KH, D)), dtype) for _ in range(2))
+        q = jnp.asarray(rng.standard_normal((B, 1, H, D)), dtype)
+        with timed(f"kernels: {name}"):
+            got = pd.paged_decode_attention(
+                q, pk, pv, table, pos, softmax_scale=D ** -0.5,
+                interpret=interpret)
+            with mock.patch.object(pd, "_on_one_tpu", lambda: False):
+                want = jax.jit(pa._paged_window_attention)(
+                    q, pk, pv, None, None, table, pos)
             errs[name] = _rel_err(np.asarray(got, np.float32)[live],
                                   np.asarray(want, np.float32)[live])
 
@@ -600,6 +639,7 @@ def multichip_phase(cfg, gpt2_cfg=None, *, n_chips: int = 4,
     with timed("4chip: one-chip engine (the twin)"):
         one = LlamaDeployment(**kw)
         want = _engine_outputs(one, prompts, new_tokens)
+        one_pages = one.engine().stats["decode_kernel_pages"]
         one.engine().shutdown()
     assert all(len(g) == new_tokens for g in want)
 
@@ -646,11 +686,18 @@ def multichip_phase(cfg, gpt2_cfg=None, *, n_chips: int = 4,
             # page-major pool [n_pages, Pg, KH, D]: the head axis is 2
             assert shard[2] * n_chips == cfg.n_kv_heads, (
                 f"KV pool not head-sharded: shard {shard}")
+            # GSPMD cannot partition a Mosaic kernel: a sharded
+            # replica's decode step is the block loop whatever its
+            # shapes (serve/step_programs.py ambient_mesh)
+            tp_pages = eng.stats["decode_kernel_pages"]
+            assert tp_pages == 0, tp_pages
         finally:
             eng.shutdown()
     same = sum(a == b for a, b in zip(got, want))
     log(f"[4chip] tp={n_chips}: pool shard {shard} per chip; "
-        f"{same}/{n_prompts} prompts token-identical to one chip")
+        f"{same}/{n_prompts} prompts token-identical to one chip; "
+        f"decode_kernel_pages {one_pages} on one chip, {tp_pages} "
+        f"under tp")
     parity = check_greedy_parity(f"4chip tp={n_chips}", model, params,
                                  prompts, got)
     del params, one, pooled, tp, pool, eng
@@ -667,6 +714,7 @@ def multichip_phase(cfg, gpt2_cfg=None, *, n_chips: int = 4,
     log(f"[4chip] train losses 1 chip {t1['losses']} vs {n_chips} "
         f"chips {tn['losses']} (rtol {MULTICHIP_LOSS_RTOL})")
     return {"placed": [str(d) for d in placed], "tp_parity": parity,
+            "one_chip_kernel_pages": one_pages,
             "losses_1": t1["losses"], "losses_n": tn["losses"]}
 
 
@@ -726,7 +774,11 @@ def main(argv=None) -> int:
             f"ray_tpu.init() reports TPU={tpus}, JAX has {len(devs)}")
         if args.chips == 4:
             with timed("4chip phase"):
-                multichip_phase(llama_1b_config())
+                # heads of 128: the one-chip engines decode through
+                # the Pallas kernel, the tensor-parallel one through
+                # the loop, and their tokens are compared
+                out = multichip_phase(llama_1b_config(n_heads=16))
+                assert out["one_chip_kernel_pages"] > 0, out
         else:
             with timed("serving phase"):
                 serving_phase(llama_1b_config())

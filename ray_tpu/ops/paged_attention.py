@@ -28,22 +28,34 @@ the layout:
 - ``paged_append`` scatters a chunk of new K/V at each slot's write
   offset;
 - ``_paged_window_attention`` attends a chunk of queries over each
-  slot's pages, a block of pages at a time. It has TWO FORMS and a rule
-  between them that reads only what the function can see (its
-  arguments' shapes and types, the backend, the ambient mesh; never a
-  flag). The LOOP, plain XLA: blocks gathered by page id up to the
-  longest live context, the online softmax's running statistics and
-  accumulator carried through a loop with a runtime trip count. It
-  serves every K/V pool (fp and int8, one chip or tensor-parallel),
-  every decode step and speculative verify, the CPU and any mesh. The
-  KERNEL (ops/latent_window_attention.py): a PREFILL CHUNK over a
-  LATENT pool on one TPU, where thousands of (token, head) query rows
-  read one key and the loop's float32 scores and accumulator, 134 MB
-  each a block in HBM, held the two contractions at a third of the
-  matrix unit; one Pallas call keeps them in VMEM and walks each row to
-  its own last block (PERF.md section 6, PR 43). The two share the
-  mathematics and no code: a K/V pool with 2-8 query heads a KV head is
-  another regime, with no record that the loop loses there.
+  slot's pages. It has THREE FORMS and a rule between them that reads
+  only what the function can see (its arguments' shapes and types, the
+  backend, the ambient mesh; never a flag). The LOOP, plain XLA: blocks
+  of pages gathered by page id up to the longest live context, the
+  online softmax's running statistics and accumulator carried through
+  a loop with a runtime trip count. It serves every prefill chunk and
+  speculative verify over a K/V pool, every int8 pool, every latent
+  pool's decode step, the CPU and any mesh. The LATENT KERNEL
+  (ops/latent_window_attention.py): a PREFILL CHUNK over a LATENT pool
+  on one TPU, where thousands of (token, head) query rows read one key
+  and the loop's float32 scores and accumulator, 134 MB each a block in
+  HBM, held the two contractions at a third of the matrix unit; one
+  Pallas call keeps them in VMEM and walks each row to its own last
+  block (PERF.md section 6, PR 43). The DECODE KERNEL
+  (ops/paged_decode_attention.py): a DECODE STEP (one query a row) over
+  a bfloat16 K/V pool on one TPU, where the bytes fetched are all the
+  step costs and the loop gathers a whole 512-token block for every
+  row up to the longest rider's context (67 MB a layer where 40 are
+  live at 16 riders of 256-352 tokens: 38 % of Ouro's decode step,
+  PERF.md section 6, PRs 46 and 47); one Pallas call reads each
+  rider's own pages once, where they lie, and no page of a row without
+  a rider. A Pallas decode kernel lost to the loop once (PR 30: 32.8
+  against 10.6-11.1 ms a step) for two reasons, and this one answers
+  both: that grid ran 32 slots x 64 table columns whatever the context
+  (this one's length is a value, each row walked to its own last page),
+  and it wanted a head-major pool, 32 transposed copies a step (this
+  one reads a page as the [page_size x KH, D] matrix it already is).
+  The three share the mathematics and no code.
 
 Inactive slots point at the null page: their writes land there, the
 causal mask hides it from every live query, and their outputs are
@@ -68,6 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.ops import latent_window_attention as latent_window
+from ray_tpu.ops import paged_decode_attention as paged_decode
 
 # Int8 pages use a symmetric absmax code: value = q * scale / 127 with
 # q in [-127, 127] (-128 unused so the code is symmetric). One fp32
@@ -329,12 +342,19 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
     ONCE and the result is [B, T, H, value_dim]. ``softmax_scale``:
     what the scores are multiplied by, where it is not ``D ** -0.5``.
 
-    A chunk of at least one query tile over a latent pool of bfloat16
-    entries, on a TPU outside any multi-device mesh, is ONE Pallas
-    kernel (``latent_window.applies``; ops/latent_window_attention.py:
-    the same mathematics, the scores and the accumulator in VMEM, each
-    row walked to its own last block), called under the ``attn_scores``
-    scope. Everything else is the loop below.
+    Three forms, chosen by what the arguments show. A chunk of at
+    least one query tile over a latent pool of bfloat16 entries, on a
+    TPU outside any multi-device mesh, is ONE Pallas kernel
+    (``latent_window.applies``; ops/latent_window_attention.py: the
+    scores and the accumulator in VMEM, each row walked to its own last
+    block). A decode step (``T == 1``) over a bfloat16 K/V pool without
+    int8 scales, there too, is ANOTHER (``paged_decode.applies``;
+    ops/paged_decode_attention.py: each rider's own pages read once,
+    where they lie, a row without a rider not at all, and zeros read
+    out for it). Both are the same mathematics and are called under
+    the ``attn_scores`` scope. Everything else is the loop below:
+    prefill chunks and verifies over K/V pools, int8 pools, a latent
+    pool's decode step, the CPU, a mesh.
 
     Work follows the live contexts, not the table's width: a loop with
     a RUNTIME trip count walks blocks of ``block_pages`` logical pages
@@ -384,6 +404,12 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
                 softmax_scale=(D ** -0.5 if softmax_scale is None
                                else softmax_scale),
                 block_pages=block_pages)
+    if paged_decode.applies(q, pk, pv, sk, page_table):
+        with jax.named_scope("attn_scores"):
+            return paged_decode.paged_decode_attention(
+                q, pk, pv, page_table, pos,
+                softmax_scale=float(D ** -0.5 if softmax_scale is None
+                                    else softmax_scale))
     # Grouped-query attention WITHOUT materializing repeated K/V: q
     # reshapes to [B, T, KH, rep, D] and contracts against the grouped
     # cache directly (a repeat would move rep x the KV bytes a step).

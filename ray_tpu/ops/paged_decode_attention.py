@@ -1,0 +1,333 @@
+"""A decode step's attention over a K/V page pool as one Pallas kernel.
+
+ops/paged_attention.py ``_paged_window_attention`` walks the batch's
+pages a block of 512 tokens at a time, for EVERY row up to the longest
+live context: a decode step of 16 riders at 256-352 tokens gathers
+16 x 512 x 8,192 B = 67 MB a layer where 40 MB are live, first into a
+block in page order and then, re-laid head-major, into a second one
+(PERF.md section 6, PRs 46 and 47). A decode step is the one shape
+where the bytes are all there is: a query row a head against each key
+is 2 FLOPs a byte fetched, so what a step costs is what it fetches and
+how often it fetches it.
+
+The kernel is the same mathematics (bfloat16 operands as stored,
+float32 accumulation in both contractions, float32 scores x the scale,
+the causal mask on absolute positions, the online softmax in float32,
+``p`` cast to the pool's type before the read-out), each rider's own
+pages read once, where they lie:
+
+- the grid is the call's VISITS, one (row, group of ``pages`` logical
+  pages) each, a row's groups in order, and its length is a value
+  computed on the device from ``page_table`` and ``pos``: a rider is
+  walked to ``ceil((pos + 1) / page_size)`` pages and no further, a
+  row whose page-table row is null gets one visit that fetches nothing,
+  scores nothing and writes zeros. (A grid of fixed length with the
+  visits left over skipped is what PR 30's kernel ran, 32 slots x 64
+  table columns whatever the context: 15.7 ms a step.)
+- the page ids and the visits' schedule go in by scalar prefetch and
+  the index maps fetch a page BY ITS ID, as it lies: a page
+  ``[page_size, KH, D]`` is one contiguous slab, read as the
+  ``[page_size x KH, D]`` matrix the same bytes are (a free view of
+  the page-major pool: nothing transposed, nothing copied; PR 30's
+  kernel wanted a head-major pool, 5.1 ms a step in copies). A slot of
+  a row's last group past its last page repeats the page that slot
+  fetched last, which the pipeline does not fetch again;
+- both contractions run on the matrix unit over the WHOLE page: every
+  query head against every (token, KV head) row of it, the products of
+  a head with another KV head's keys masked out of the softmax like
+  the keys past the row's position, so that their ``p`` is 0 and the
+  read-out ``p @ page`` sums a head's own KV head alone. No head is
+  sliced out of a page (a strided read across its sublanes) and no
+  page is copied to float32 for the vector unit, which has no
+  bfloat16 on a TPU v5e (PR 33's finding, inside a kernel too). It is
+  ``KH`` times the needed FLOPs, which are nothing here: 22 MFLOP a
+  layer against 40 MB fetched at Ouro's shape.
+
+How many pages a visit fetches and folds follows from the shapes
+(``pages_per_visit``: what a visit's float32 scores come to), and
+which calls the kernel serves is ``applies``'s rule, read by
+``_paged_window_attention``: shapes, types, the backend and the ambient
+mesh (a step program makes its replica's ambient for every model:
+serve/step_programs.py ``ambient_mesh``), never a flag.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# the backend is a TPU and no multi-device mesh is ambient: one rule
+# for every Mosaic kernel that has an XLA form
+from ray_tpu.ops.grouped_matmul import on_one_tpu as _on_one_tpu
+
+_NEG_INF = -1e30
+_LANES = 128
+_NT = (((1,), (1,)), ((), ()))        # a @ b.T
+_NN = (((1,), (0,)), ((), ()))        # a @ b
+
+# Bytes of float32 scores one visit folds at the most: every query head
+# against every (token, KV head) row of the visit's pages. Twice the
+# vector registers' 256 KiB: measured on v5e (PR 47, PERF.md section 6;
+# pages a visit 2 | 4 | 8 | 16, ms a layer-step at the cells' shapes and
+# contexts) Ouro's 16 heads on pages of 1,024 rows 0.0703 | 0.0733 |
+# **0.0687** | 0.0785, Mistral's 32 on 512 0.0886 | 0.0890 | **0.0688**
+# | 0.1012, Mellum 2's 32 on 256 1.023 | 0.710 | 0.603 | **0.604**,
+# Solar-Open2's 64 on 512 0.277 | **0.233** | 0.247 | 0.282: each best
+# where a visit's scores come to 512 KiB.
+_SCORE_BYTES = 512 << 10
+_MAX_PAGES_A_VISIT = 16
+
+
+def pages_per_visit(H: int, page_size: int, kv_heads: int,
+                    max_pages: int) -> int:
+    """Logical pages of a row one visit fetches (of K and of V each) and
+    folds in one step of the online softmax: the power of two whose
+    scores, ``H`` query heads x ``page_size x kv_heads`` rows a page in
+    float32, fill ``_SCORE_BYTES``, inside the table. More pages a visit
+    pay a visit's fixed cost and the fold's latency less often (a fold
+    is a chain: scores, max, exp, sum, read-out; 0.5 us a page folded
+    alone, whatever its size); past what the registers and their spills
+    hold the fold itself slows. A slot past a row's last page costs no
+    fetch, so a short context loses nothing to a wide visit. 16 heads
+    on 16 KV heads (Ouro, OLMoE) and 32 on 8 (Mistral) go eight pages a
+    visit, 32 on 4 (Mellum 2) sixteen, 64 on 8 (Solar-Open2) four."""
+    want = max(1, _SCORE_BYTES // (H * page_size * kv_heads * 4))
+    return min(1 << (want.bit_length() - 1), _MAX_PAGES_A_VISIT,
+               max_pages)
+
+
+# Scalar memory the visits' schedule may take. It goes in by scalar
+# prefetch (``visit_schedule``: a page id a slot, a row and a group a
+# visit, a count and a position a row), and a TPU v5e has 1 MiB of it
+# for the whole program: compiled for a described v5e (PR 47) a table
+# of 32 rows x 4,096 pages (590 KB of schedule at 16 pages a visit)
+# builds and one of 32 x 8,192 "ran out of memory in memory space
+# smem: used 1.25M of 1.00M". Half of it is the kernel's: 32 rows x
+# 3,584 pages of 64 tokens, 229,376 tokens a row, and
+# tests/test_chip_compile.py builds that table. A wider one keeps the
+# loop.
+_SCHEDULE_BYTES = 512 << 10
+
+
+def schedule_bytes(rows: int, max_pages: int, pages: int) -> int:
+    """Bytes of int32 scalars ``visit_schedule`` hands the kernel for a
+    table of ``rows`` x ``max_pages`` at ``pages`` a visit."""
+    visits = rows * -(-max_pages // pages)
+    return 4 * (visits * (pages + 2) + 2 * rows)
+
+
+def applies(q, pk, pv, sk, page_table) -> bool:
+    """Whether the kernel serves ``q`` [B, T, H, D] over the pool
+    ``pk``/``pv`` [n_pages, Pg, KH, D] (``pv`` None: latent pages, the
+    loop's) with the int8 scales ``sk`` (the loop's too) under
+    ``page_table`` [B, max_pages]: one query a row (a decode step; a
+    prefill chunk and a speculative verify keep the loop), queries and
+    pool bfloat16, whole query groups, a head of whole 128-lane tiles,
+    query heads and a page's rows in whole sublane tiles, a schedule
+    that fits the scalar memory, and a TPU outside any multi-device
+    mesh. Only shapes and types are read: ``_paged_window_attention``
+    asks it of its arguments, and the engine of the same shapes for its
+    ``decode_kernel_pages``."""
+    if pv is None or sk is not None or pk.ndim != 4:
+        return False
+    (T, H, D), (Pg, KH) = q.shape[1:], pk.shape[1:3]
+    B, max_pages = page_table.shape
+    return (T == 1 and q.dtype == pk.dtype == pv.dtype == jnp.bfloat16
+            and H % KH == 0 and D % _LANES == 0 and H % 16 == 0
+            and (Pg * KH) % 16 == 0
+            and schedule_bytes(B, max_pages,
+                               pages_per_visit(H, Pg, KH, max_pages))
+            <= _SCHEDULE_BYTES
+            and _on_one_tpu())
+
+
+def kernel_pages(ends, page_size: int, max_pages: int) -> int:
+    """Pages ONE K/V layer's kernel visits (of K and of V each) for
+    riders whose last query of a decode dispatch sits at ``end - 1``
+    (host integers): each rider to its own last page, inside the table.
+    The engine's ``decode_kernel_pages``."""
+    return sum(min(-(-int(e) // page_size), max_pages) for e in ends)
+
+
+def visit_schedule(page_table, pos, page_size: int, pages: int):
+    """The call's visits, from ``page_table`` [B, max_pages] and ``pos``
+    [B] on the device: (the page id each of a visit's ``pages`` slots
+    fetches [visits x pages], a visit's row, its group of pages within
+    the row, the pages a row is walked to [B], the number of visits).
+    The arrays are as long as the most visits the table allows; only
+    the first ``n_visits`` are run."""
+    B, max_pages = page_table.shape
+    max_groups = -(-max_pages // pages)
+    i32 = jnp.int32
+    pos = pos.astype(i32)
+    live = page_table[:, 0] != 0
+    count = jnp.where(live, jnp.minimum(pos // page_size + 1, max_pages),
+                      0)
+    # visit v is group ``group_of[v]`` of row ``row_of[v]``: a row has as
+    # many visits as groups of pages, and one where it has none
+    visits = jnp.maximum(-(-count // pages), 1)
+    after = jnp.cumsum(visits, dtype=i32)
+    visit = jnp.arange(B * max_groups, dtype=i32)
+    row_of = jnp.minimum(
+        jnp.searchsorted(after, visit, side="right",
+                         method="compare_all").astype(i32), B - 1)
+    group_of = visit - (after - visits)[row_of]
+    # the page each of a visit's ``pages`` slots fetches: the row's own,
+    # by id; past its last page, the one the slot fetched last (a block
+    # whose index has not changed is not fetched again)
+    at = group_of[:, None] * pages + jnp.arange(pages, dtype=i32)[None]
+    held = at < count[row_of][:, None]
+    ids = jnp.take_along_axis(
+        page_table.astype(i32)[row_of],
+        jnp.minimum(at, max_pages - 1), axis=1)
+    latest = jax.lax.cummax(jnp.where(held, visit[:, None], 0), axis=0)
+    ids = jnp.take_along_axis(ids, latest, axis=0).reshape(-1)
+    return ids, row_of, group_of, count, after[-1]
+
+
+def _split(col, n: int):
+    """(col // n, col % n) of non-negative ``col``."""
+    if n & (n - 1) == 0:
+        return col >> (n.bit_length() - 1), col & (n - 1)
+    return jax.lax.div(col, n), jax.lax.rem(col, n)
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _decode_kernel(ids_ref, row_ref, group_ref, count_ref, pos_ref,
+                   q_ref, *rest, scale: float, pages: int, kv_heads: int):
+    del ids_ref                                 # the index maps' alone
+    k_refs, v_refs = rest[:pages], rest[pages:2 * pages]
+    o_ref, m_scr, l_scr, acc_scr = rest[2 * pages:]
+    v = pl.program_id(0)
+    row, group = row_ref[v], group_ref[v]  # this visit's row, page group
+    count = count_ref[row]                 # pages this row visits
+    last = pos_ref[row]                    # its query's position
+    H = q_ref.shape[2]
+    page_rows = k_refs[0].shape[1]         # (token, KV head) rows a page
+    page_size = page_rows // kv_heads
+
+    @pl.when(group == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    q = q_ref[0, 0]                                          # [H, D]
+    # column c of a page's scores is token c // KH under KV head c % KH;
+    # query head h reads KV head h // rep
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, page_rows), 1)
+    token, kv_head = _split(col, kv_heads)
+    head = jax.lax.broadcasted_iota(jnp.int32, (H, page_rows), 0)
+    own = kv_head == _split(head, H // kv_heads)[0]
+
+    @pl.when(count > 0)
+    def _():
+        # Fold the row's scores against the visit's pages into its
+        # running statistics and accumulator, all pages in ONE fold: a
+        # fold is a chain (scores, max, exp, sum, read-out) whose
+        # latency, not its work, is what a page of a few hundred rows
+        # pays (0.5 us a page folded alone, whatever its size). A slot
+        # past the row's last page holds a page fetched earlier, whose
+        # every position lies past the row's: masked like any other.
+        scores = []
+        for c in range(pages):
+            s = _dot(q, k_refs[c][0], _NT) * scale           # [H, rows]
+            first = (group * pages + c) * page_size
+            scores.append(jnp.where(own & (first + token <= last), s,
+                                    _NEG_INF))
+        # across the pages elementwise first: one reduction over the
+        # lanes a visit, not one a page
+        m_prev = m_scr[:, :1]
+        m = jnp.maximum(m_prev, jnp.max(
+            functools.reduce(jnp.maximum, scores), axis=-1, keepdims=True))
+        ps = [jnp.exp(s - m) for s in scores]
+        alpha = jnp.exp(m_prev - m)
+        l = l_scr[:, :1] * alpha + jnp.sum(
+            functools.reduce(jnp.add, ps), axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + sum(
+            _dot(p.astype(v_refs[c].dtype), v_refs[c][0], _NN)
+            for c, p in enumerate(ps))
+        m_scr[...] = jnp.broadcast_to(m, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l, l_scr.shape)
+
+    @pl.when(group == jnp.maximum(pl.cdiv(count, pages), 1) - 1)
+    def _():
+        # key 0 is visible to a live row's query; a row that is not
+        # live scored nothing and reads out zeros
+        l = l_scr[:, :1]
+        y = acc_scr[...] / jnp.where(l > 0.0, l, 1.0)
+        o_ref[0, 0] = y.astype(o_ref.dtype)
+
+
+def _attend(q, pk, pv, page_table, pos, *, softmax_scale: float,
+            pages: int, interpret: bool = False):
+    """``paged_decode_attention`` at ``pages`` logical pages a visit:
+    what it calls with ``pages_per_visit``'s plan, and what
+    tools/paged_decode_bench.py and the tests call with another to
+    measure or check the plan."""
+    B, T, H, D = q.shape
+    n_pages, Pg, KH = pk.shape[:3]
+    assert T == 1 and pv.shape == pk.shape and H % KH == 0, (
+        q.shape, pk.shape, pv.shape)
+    ids, row_of, group_of, count, n_visits = visit_schedule(
+        page_table, pos, Pg, pages)
+
+    def page(c):
+        return pl.BlockSpec(
+            (1, Pg * KH, D), lambda v, ids, *_: (ids[v * pages + c], 0, 0))
+
+    row = pl.BlockSpec(
+        (1, 1, H, D), lambda v, ids, row_of, *_: (row_of[v], 0, 0, 0))
+    page_bytes = Pg * KH * D * pk.dtype.itemsize
+    # a page-major page IS the [Pg x KH, D] matrix of its (token, KV
+    # head) rows: a free view
+    flat = (n_pages, Pg * KH, D)
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, scale=softmax_scale,
+                          pages=pages, kv_heads=KH),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(n_visits,),
+            in_specs=[row] + [page(c) for c in range(pages)] * 2,
+            out_specs=row,
+            scratch_shapes=[
+                pltpu.VMEM((H, _LANES), jnp.float32),          # m
+                pltpu.VMEM((H, _LANES), jnp.float32),          # l
+                pltpu.VMEM((H, D), jnp.float32)]),             # acc
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # a row's groups in order: its scratch carries them
+            dimension_semantics=("arbitrary",),
+            # K and V pages double-buffered, a visit's scores (float32,
+            # their exponentials, those in the pool's type), and room
+            # for what the compiler spills
+            vmem_limit_bytes=(4 * pages * page_bytes
+                              + 12 * pages * H * Pg * KH + (8 << 20))),
+        interpret=interpret, name="paged_decode",
+    )(ids, row_of, group_of, count, pos.astype(jnp.int32), q,
+      *([pk.reshape(flat)] * pages), *([pv.reshape(flat)] * pages))
+
+
+@functools.partial(jax.jit, static_argnames=("softmax_scale",
+                                             "interpret"))
+def paged_decode_attention(q, pk, pv, page_table, pos, *,
+                           softmax_scale: float, interpret: bool = False):
+    """Causal grouped-query attention of ``q`` [B, 1, H, D] (row b's
+    query at absolute position ``pos[b]``) over its page-table row's
+    K/V in the page-major pool ``pk``/``pv`` [n_pages, Pg, KH, D].
+    Returns [B, 1, H, D] in ``q``'s type. A row whose page-table row is
+    null (its first page is page 0) reads out zeros.
+
+    One jitted function: the layers of a step program that call it
+    with equal shapes share one trace and one lowering."""
+    H, (Pg, KH) = q.shape[2], pk.shape[1:3]
+    return _attend(q, pk, pv, page_table, pos, softmax_scale=softmax_scale,
+                   pages=pages_per_visit(H, Pg, KH, page_table.shape[1]),
+                   interpret=interpret)

@@ -102,18 +102,19 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from ray_tpu.models.kv_cache import (BlockAllocator, PagedKVLayer,
-                                     check_kv_dtype,
+from ray_tpu.models.kv_cache import (KIND_KV, BlockAllocator,
+                                     PagedKVLayer, check_kv_dtype,
                                      export_page_bytes,
                                      has_latent_pages, init_kv_pool,
                                      kv_pool_page_bytes,
-                                     latent_page_width,
-                                     page_cols_from_bytes,
+                                     latent_page_width, layer_kinds,
+                                     page_cols_from_bytes, page_layout,
                                      refuse_unsupported,
                                      sliding_bytes_per_slot,
                                      sliding_ring_len,
                                      state_bytes_per_slot)
 from ray_tpu.ops import latent_window_attention as latent_window
+from ray_tpu.ops import paged_decode_attention as paged_decode
 from ray_tpu.ops.paged_attention import paged_window_block_pages
 from ray_tpu.serve import kv_migration, obs, spec_decode
 # Typed lifecycle errors live in a jax-free module (serve/errors.py)
@@ -131,7 +132,7 @@ from ray_tpu.serve.scheduler import (LANE_BATCH, LANE_ONLINE,
 from ray_tpu.serve.step_programs import (_jit_copy_page, _jit_decode,
                                          _jit_prefill, _jit_seed,
                                          _jit_verify, _jit_write_page,
-                                         _moe_vector_of)
+                                         _moe_vector_of, ambient_mesh)
 from ray_tpu.util.compile_cache import metadata_keyed
 
 _DONE = object()
@@ -486,9 +487,16 @@ def _new_round_info() -> Dict[str, int]:
     is 0 where the prefill program holds no kernel for its latent
     layers' attention (ops/latent_window_attention.py), else the key
     blocks ONE such layer's kernel visits over the call's live rows,
-    each row to the block of its own last query."""
+    each row to the block of its own last query.
+    ``decode_kernel_pages`` is 0 where the decode program holds no
+    kernel for its K/V layers' attention
+    (ops/paged_decode_attention.py), else the pages ONE such layer's
+    kernel visits at the dispatch's last step, each rider to its own
+    last page: beside ``decode_riders`` x ``decode_window_tokens`` it
+    says how far the visited pages sit from the block loop's."""
     return {"decode_riders": 0, "decode_steps": 0, "backlog": 0,
             "decode_window_tokens": 0, "decode_context_tokens": 0,
+            "decode_kernel_pages": 0,
             "prefill_tokens": 0, "prefill_budget": 0,
             "prefill_rows": 0, "prefill_window_tokens": 0,
             "prefill_kernel_blocks": 0, "prefill_width": 0}
@@ -2746,11 +2754,35 @@ class LLMEngine:
     def _prefill_kernel_serves(self, T: int) -> bool:
         """Whether the ``[rows, T]`` prefill program's latent layers
         attend through the kernel: the question
-        ``_paged_window_attention`` asks of the same shapes."""
+        ``_paged_window_attention`` asks of the same shapes, under the
+        mesh the program is traced under."""
         cfg = self.cfg
-        return has_latent_pages(cfg) and latent_window.serves(
-            T, cfg.n_heads, latent_page_width(cfg), cfg.kv_lora_rank,
-            self.Pg, cfg.dtype)
+        with ambient_mesh(self._mesh):
+            return has_latent_pages(cfg) and latent_window.serves(
+                T, cfg.n_heads, latent_page_width(cfg), cfg.kv_lora_rank,
+                self.Pg, cfg.dtype)
+
+    def _decode_kernel_serves(self) -> bool:
+        """Whether the decode program's K/V layers attend through the
+        kernel: ``paged_decode.applies``, the very question
+        ``_paged_window_attention`` asks, of a decode step's queries
+        and one layer's pages as ``page_layout`` stores them (what the
+        pool is built from: its type and int8 scales are read there,
+        not decided again here), under the mesh the program is traced
+        under."""
+        cfg = self.cfg
+        if KIND_KV not in layer_kinds(cfg):
+            return False
+        k, v, *scales = (
+            jax.ShapeDtypeStruct((1,) + shape[-3:], dtype)
+            for shape, dtype in page_layout(cfg, KIND_KV, self.Pg,
+                                            self.kv_dtype))
+        q = jax.ShapeDtypeStruct((self.S, 1, cfg.n_heads, cfg.head_dim),
+                                 cfg.dtype)
+        table = jax.ShapeDtypeStruct((self.S, self.max_pages), jnp.int32)
+        with ambient_mesh(self._mesh):
+            return paged_decode.applies(
+                q, k, v, scales[0] if scales else None, table)
 
     def _note_window(self, key: str, end: int) -> None:
         """Record under ``key`` the positions a dispatch's paged
@@ -2836,6 +2868,12 @@ class LLMEngine:
         self._note_window("decode_window_tokens",
                           max(slot.pos for _i, slot, _t in riders))
         self._note_decode_contexts(slot.pos for _i, slot, _t in riders)
+        if self._decode_kernel_serves():
+            pages = paged_decode.kernel_pages(
+                (slot.pos for _i, slot, _t in riders), self.Pg,
+                self.max_pages)
+            self._round_info["decode_kernel_pages"] += pages
+            self.stats["decode_kernel_pages"] += pages
         self.events.append("decode", data=steps)
         self.stats["chunks"] += 1
         self.stats["decode_steps"] += steps

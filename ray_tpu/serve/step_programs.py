@@ -10,9 +10,10 @@ shape/sampling knobs, so they are built once per distinct knob set
 and shared by every engine in the process: a pool's replicas (and a
 restarted replica) trace each step once instead of once per engine,
 and jit's own cache keys the executables by shape, dtype and device.
-``mesh`` is the replica's EngineSharding mesh or None; it only
-decides the KV-pool sharding constraint, and a replica rebuilt over
-the same devices hashes to the same entry.
+``mesh`` is the replica's EngineSharding mesh or None: it decides the
+KV-pool sharding constraint and is ambient while the model is traced
+(``ambient_mesh``), and a replica rebuilt over the same devices hashes
+to the same entry.
 """
 from __future__ import annotations
 
@@ -38,30 +39,37 @@ def _moe_vector_of(model):
             moe_stats_len(cfg.num_experts, cfg.experts_held))
 
 
+def ambient_mesh(mesh):
+    """The context a step program's model is traced in: a sharded
+    replica's mesh made ambient, nothing for ``mesh`` None. Every rule
+    that chooses between a Mosaic kernel and its XLA form asks for the
+    ambient mesh (ops/grouped_matmul.py ``on_one_tpu``: GSPMD cannot
+    partition a Mosaic kernel, so under a multi-device mesh the XLA
+    form serves), and the engine asks the same rules under the same
+    context for its counters."""
+    return (contextlib.nullcontext() if mesh is None else
+            jax.sharding.use_abstract_mesh(mesh.abstract_mesh))
+
+
 def _moe_apply(model, mesh):
-    """``model.apply`` for a step program. For a mixture-of-experts
-    model the third result is (the int32 vector,) of what the router
-    chose over the program's live tokens (models/mixtral.py
-    moe_stats_vector; ``live()`` gives the [B, T] mask); for a dense
-    model it is () and the program is what it was. A sharded
-    replica's mesh is made ambient while the mixture is traced: its
-    grouped matmul asks for it (ops/grouped_matmul.py: no Mosaic
-    kernel under a mesh)."""
+    """``model.apply`` for a step program, traced under the replica's
+    mesh (``ambient_mesh``), a dense model's as a mixture's. For a
+    mixture-of-experts model the third result is (the int32 vector,) of
+    what the router chose over the program's live tokens
+    (models/mixtral.py moe_stats_vector; ``live()`` gives the [B, T]
+    mask); for a dense model it is ()."""
     E, held, _ = _moe_vector_of(model)
     if not E:
         def apply(params, ids, kv, start, live):
-            logits, new_kv = model.apply(params, ids, kv_caches=kv,
-                                         cache_len=start)
+            with ambient_mesh(mesh):
+                logits, new_kv = model.apply(params, ids, kv_caches=kv,
+                                             cache_len=start)
             return logits, new_kv, ()
         return apply
     from ray_tpu.models.mixtral import MOE_STATS, moe_stats_vector
 
-    ambient = (contextlib.nullcontext if mesh is None else
-               functools.partial(jax.sharding.use_abstract_mesh,
-                                 mesh.abstract_mesh))
-
     def apply(params, ids, kv, start, live):
-        with ambient():
+        with ambient_mesh(mesh):
             (logits, new_kv), sown = model.apply(
                 params, ids, kv_caches=kv, cache_len=start,
                 mutable=[MOE_STATS])

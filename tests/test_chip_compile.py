@@ -316,11 +316,94 @@ def test_block_loop_contracts_on_the_matrix_unit(step_program, name,
         assert len(convs) >= 2, (scope, len(convs))
 
 
-def test_decode_copies_no_pool_shard_under_tp4(topo):
+# a decode step over a bfloat16 K/V pool on one TPU attends through the
+# Pallas kernel of ops/paged_decode_attention.py (the tests above run
+# the rule on the CPU, where it keeps the loop): one call a layer with
+# the pool going in as it is stored, the visits' schedule computed once
+# a step and shared by the layers, and the chunked prefill and the
+# verify left to the loop
+@pytest.mark.parametrize("kv_heads", [8, 16], ids=["mistral", "olmoe"])
+def test_decode_attends_in_one_kernel_a_layer(one_chip, monkeypatch,
+                                              kv_heads):
+    from ray_tpu.ops import paged_decode_attention as pd
+    from ray_tpu.serve import step_programs
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
+    # the programs are cached by (model, knobs): the cases above traced
+    # them with the loop, and no later one may find the kernel's
+    for name in ("_jit_decode", "_jit_prefill", "_jit_verify"):
+        monkeypatch.setattr(step_programs, name,
+                            getattr(step_programs, name).__wrapped__)
+    cfg = _cell_cfg(kv_heads)
+
+    def compiled(name):
+        return _compile_step(
+            name, cfg, one_chip,
+            lambda params: jax.tree_util.tree_map(lambda _: one_chip,
+                                                  params), one_chip)
+    decode, pool = compiled("decode")
+    text = decode.as_text()
+    calls = re.findall(
+        r"custom-call\([^\n]*/attn_scores/[^\n]*paged_decode[^\n]*", text)
+    assert len(calls) == cfg.n_layers, len(calls)
+    flat = "bf16[%d,%d,128]" % (N_PAGES, PAGE * kv_heads)
+    pages = pd.pages_per_visit(cfg.n_heads, PAGE, kv_heads,
+                               cfg.max_seq_len // PAGE)
+    for call in calls:
+        # K's pages and V's, each a free view of the pool as it lies
+        assert call.count(flat) == 2 * pages, call[:400]
+    assert not _pool_copies(text, pool.shape)
+    assert not _pool_copies(text, (N_PAGES, PAGE * kv_heads, 128))
+    assert "kv_gather" not in text and "attn_pv" not in text
+    # every layer's pool is the program's argument and its result
+    one_pool = 2 * 2 * math.prod(pool.shape) * 2
+    assert decode.memory_analysis().alias_size_in_bytes >= one_pool
+    assert decode.memory_analysis().temp_size_in_bytes < one_pool
+    # the schedule is the first layer's alone: the others share it
+    assert "layers_0/attention/attn_scores/jit(paged_decode_attention)" \
+        "/cumsum" in text or "layers_0/attention/attn_scores/" \
+        "jit(paged_decode_attention)/reduce_window" in text
+    assert not re.search(
+        r"layers_1/attention/attn_scores/jit\(paged_decode_attention\)"
+        r"/(cumsum|reduce_window|cummax)", text)
+    for name in ("prefill", "verify"):
+        assert "tpu_custom_call" not in compiled(name)[0].as_text()
+
+
+# one layer-step at each serving cell's shape: (rows, heads, KV heads,
+# table columns)
+# and the widest table the rule hands the kernel (its schedule goes in
+# by scalar prefetch: half of the chip's 1 MiB of scalar memory)
+@pytest.mark.parametrize("B,H,KH,max_pages", [
+    (16, 16, 16, 64), (32, 32, 8, 64), (32, 32, 4, 256), (32, 64, 8, 64),
+    (32, 32, 4, 3584)],
+    ids=["ouro", "mistral", "mellum2", "solar_open2", "widest_table"])
+def test_paged_decode_kernel_compiles(one_chip, B, H, KH, max_pages):
+    from ray_tpu.ops import paged_decode_attention as pd
+    assert pd.schedule_bytes(
+        B, max_pages, pd.pages_per_visit(H, PAGE, KH, max_pages)
+    ) <= pd._SCHEDULE_BYTES
+    pool = ((1025, PAGE, KH, 128), jnp.bfloat16)
+    compiled = _compile(
+        lambda *a: pd.paged_decode_attention(*a, softmax_scale=0.088),
+        one_chip, ((B, 1, H, 128), jnp.bfloat16), pool, pool,
+        ((B, max_pages), jnp.int32), ((B,), jnp.int32))
+    assert not _pool_copies(compiled.as_text(), pool[0])
+    assert not _pool_copies(compiled.as_text(), (1025, PAGE * KH, 128))
+
+
+def test_decode_copies_no_pool_shard_under_tp4(topo, monkeypatch):
     """Tensor-parallel over the four described chips: each chip holds
-    2 of Mistral's 8 KV heads of every page, and copies none of it."""
+    2 of Mistral's 8 KV heads of every page, and copies none of it.
+    Traced as on a TPU (the rule's backend steered; its mesh is the
+    program's own, serve/step_programs.py ``ambient_mesh``): GSPMD
+    cannot partition a Mosaic kernel, so the program holds the loop
+    and no custom call."""
     from ray_tpu.mesh.sharding import infer_sharding
+    from ray_tpu.serve import step_programs
     from ray_tpu.serve.sharding import EngineSharding
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(step_programs, "_jit_decode",
+                        step_programs._jit_decode.__wrapped__)
     cfg = _cell_cfg(8)
     try:
         sh = EngineSharding.build(cfg, tp=4, devices=topo.devices)
@@ -785,6 +868,44 @@ def test_looped_step_programs_copy_no_pool_and_hold_one_stack(one_chip,
     temp = mem.temp_size_in_bytes
     assert temp < (1400 << 20 if name == "decode" else 512 << 20), temp
     # weights, pool and temporaries together fit a chip of 15.75 GiB
+    held = (mem.argument_size_in_bytes + temp + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes)
+    assert held < 15.75 * 2 ** 30, held
+
+
+def test_looped_decode_attends_in_one_kernel_a_layer(one_chip,
+                                                     monkeypatch):
+    """On one TPU the looped model's decode step attends through the
+    Pallas kernel: 48 calls (one a layer of the one stack, not one a
+    pass), each reading a pass's view of the pool as it is stored, the
+    pool still the program's argument and its result, and the whole no
+    larger than the chip."""
+    from ray_tpu.ops import paged_decode_attention as pd
+    from ray_tpu.serve import step_programs
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
+    monkeypatch.setattr(step_programs, "_jit_decode",
+                        step_programs._jit_decode.__wrapped__)
+    compiled = _looped_step("decode", one_chip)
+    text = compiled.as_text()
+    calls = re.findall(
+        r"custom-call\([^\n]*ut_pass/layers_\d+/attention/attn_scores/"
+        r"[^\n]*paged_decode[^\n]*", text)
+    assert len(calls) == 48, len(calls)
+    seen = "bf16[%d,%d,128]" % (OURO_PAGES * 4, PAGE * 16)
+    assert all(call.count(seen) == 16 for call in calls), calls[0][:400]
+    assert "kv_gather" not in text and "attn_pv" not in text
+    for shape in ((OURO_PAGES, 4, PAGE, 16, 128),
+                  (OURO_PAGES * 4, PAGE, 16, 128),
+                  (OURO_PAGES * 4, PAGE * 16, 128)):
+        dims = ",".join(str(d) for d in shape)
+        moved = _pool_copies(text, shape) + re.findall(
+            r"= bf16\[" + dims + r"\](?:\{[^}]*\})? transpose\(", text)
+        assert not moved, (len(moved), moved[:2])
+    mem = compiled.memory_analysis()
+    pool = 48 * 2 * OURO_PAGES * 4 * PAGE * 16 * 128 * 2
+    assert mem.alias_size_in_bytes >= pool
+    temp = mem.temp_size_in_bytes
+    assert temp < 1400 << 20, temp
     held = (mem.argument_size_in_bytes + temp + mem.output_size_in_bytes
             - mem.alias_size_in_bytes)
     assert held < 15.75 * 2 ** 30, held

@@ -30,9 +30,11 @@ def test_kernel_phase_rehearsal():
                                    window_shapes=((32, 16, 256, 128,
                                                    (64, None, 600), 16),),
                                    gmm_shapes=((4, 384, 256, 200, 150),),
+                                   decode_shapes=((16, 4,
+                                                   (70, None, 1)),),
                                    interpret=True)
     assert {n.split("_")[0] for n in errs} == {"flash", "kda", "latent",
-                                               "grouped"}
+                                               "grouped", "paged"}
 
 
 def test_training_phase_rehearsal():

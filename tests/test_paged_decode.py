@@ -1,0 +1,458 @@
+"""A decode step's attention over K/V pages: the Pallas kernel
+(ops/paged_decode_attention.py) in interpret mode against the block loop
+the CPU serves (ops/paged_attention.py ``_paged_window_attention``) on
+identical inputs, the rule that chooses between them, and the engine's
+count of the pages the kernel visits.
+
+Pages and blocks are a deployment's (64 tokens, 512). float32 agrees to
+rtol 1e-4 as the other window tests; bfloat16 outputs (of order 1, one
+unit in the last place 2**-7) to one such unit and a half.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import paged_attention as paged_mod
+from ray_tpu.ops import paged_decode_attention as pd
+
+PAGE, D = 64, 128
+TOL = {jnp.float32: dict(rtol=1e-4, atol=1e-5),
+       jnp.bfloat16: dict(rtol=1e-2, atol=1.2e-2)}
+# (query heads, KV heads): one, four and eight rows a KV head
+GROUPS = {"rows_1": (16, 16), "rows_4": (32, 8), "rows_8": (32, 4)}
+
+
+def _inputs(contexts, dtype, max_pages, H=16, KH=16, seed=0,
+            stale=100_000):
+    """Rows whose contexts hold ``contexts`` tokens, the query's among
+    them (None: a row no request owns, its page-table row null and its
+    position stale), over a pool whose pages lie scattered."""
+    rng = np.random.default_rng(seed)
+    B = len(contexts)
+    n_pages = 1 + B * max_pages
+    ids = 1 + rng.permutation(B * max_pages).reshape(B, max_pages)
+    pt = np.zeros((B, max_pages), np.int32)
+    pos = np.zeros((B,), np.int32)
+    for b, n in enumerate(contexts):
+        if n is None:
+            pos[b] = stale
+            continue
+        pos[b] = n - 1
+        held = -(-n // PAGE)
+        pt[b, :held] = ids[b, :held]
+    pk, pv = (jnp.asarray(
+        0.5 * rng.standard_normal((n_pages, PAGE, KH, D)), dtype)
+        for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((B, 1, H, D)), dtype)
+    return q, pk, pv, jnp.asarray(pt), jnp.asarray(pos)
+
+
+@jax.jit
+def _loop(q, pk, pv, pt, pos):
+    return paged_mod._paged_window_attention(q, pk, pv, None, None, pt,
+                                             pos)
+
+
+def _kernel(q, pk, pv, pt, pos, pages=0):
+    """The kernel by its plan, or at ``pages`` a visit."""
+    if not pages:
+        return pd.paged_decode_attention(
+            q, pk, pv, pt, pos, softmax_scale=D ** -0.5, interpret=True)
+    return jax.jit(functools.partial(
+        pd._attend, softmax_scale=D ** -0.5, pages=pages,
+        interpret=True))(q, pk, pv, pt, pos)
+
+
+def _agree(got, want, live, dtype):
+    got, want = (np.asarray(a, np.float32)[live] for a in (got, want))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, **TOL[dtype])
+
+
+# the rows' contexts, the query's token among them; pages of 64, blocks
+# of 512
+CONTEXTS = {
+    # on, one short of and one past a page's edge: the query sits at
+    # 63 | 62 | 64 and at 319 | 318 | 320
+    "page_edge": [64, 63, 65, 320, 319, 321],
+    # the same at a block's edge
+    "block_edge": [512, 511, 513],
+    "a_row_of_one_token": [1, 300],
+    "the_cell_s_contexts": [256, 288, 352, 301],
+    "mixed_16_to_2560": [16, 2560, 700, 129],
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("group", list(GROUPS))
+@pytest.mark.parametrize("name", list(CONTEXTS))
+def test_kernel_equals_the_block_loop(name, group, dtype):
+    H, KH = GROUPS[group]
+    args = _inputs(CONTEXTS[name], dtype, max_pages=64, H=H, KH=KH)
+    _agree(_kernel(*args), _loop(*args), slice(None), dtype)
+
+
+@pytest.mark.parametrize("group", list(GROUPS))
+def test_the_table_s_last_page(group):
+    """A context that fills the table, beside a short one: the last
+    group of the widest row is the table's last columns."""
+    H, KH = GROUPS[group]
+    args = _inputs([64 * PAGE, 70], jnp.float32, max_pages=64, H=H,
+                   KH=KH)
+    _agree(_kernel(*args), _loop(*args), slice(None), jnp.float32)
+
+
+@pytest.mark.parametrize("pages", [1, 2, 4, 8, 16])
+def test_any_number_of_pages_a_visit(pages):
+    """The plan's count is a price, not a meaning: one page a visit or
+    more than the table holds a row's groups read the same."""
+    args = _inputs([1, 64, 65, 700, 129], jnp.float32, max_pages=12)
+    _agree(_kernel(*args, pages=pages), _loop(*args), slice(None),
+           jnp.float32)
+
+
+def test_a_null_row_with_a_stale_position_changes_nothing():
+    """A row no request owns is not walked, whatever its position says:
+    it reads out zeros, and the live rows read what they read beside a
+    calm one."""
+    contexts = [1024, None, 300, None]
+    args = _inputs(contexts, jnp.float32, max_pages=64)
+    got = np.asarray(_kernel(*args))
+    _agree(got, _loop(*args), [0, 2], jnp.float32)
+    assert not got[[1, 3]].any()
+    calm = _inputs(contexts, jnp.float32, max_pages=64, stale=0)
+    np.testing.assert_array_equal(got, np.asarray(_kernel(*calm)))
+
+
+@pytest.mark.parametrize("pages,want", [(1, 16 + 1 + 5 + 1),
+                                        (2, 8 + 1 + 3 + 1),
+                                        (8, 2 + 1 + 1 + 1)])
+def test_the_grid_is_each_row_s_own_pages(pages, want):
+    """A rider is walked to its own last page and fetches its own pages
+    alone; a null row gets one empty visit whatever its stale
+    position."""
+    contexts = [1024, None, 300, None]
+    _q, _pk, _pv, pt, pos = _inputs(contexts, jnp.float32, max_pages=64)
+    ids, row_of, group_of, count, n = (np.asarray(a) for a in jax.jit(
+        functools.partial(pd.visit_schedule, page_size=PAGE,
+                          pages=pages))(pt, pos))
+    assert int(n) == want
+    assert count.tolist() == [16, 0, 5, 0]
+    ids = ids.reshape(-1, pages)[:n]
+    rows, groups = row_of[:n], group_of[:n]
+    assert rows.tolist() == sorted(rows.tolist())
+    table = np.asarray(pt)
+    for b in range(4):
+        mine = ids[rows == b]
+        assert groups[rows == b].tolist() == list(range(len(mine)))
+        held = mine.reshape(-1)[:count[b]]
+        assert held.tolist() == table[b, :count[b]].tolist()
+    # a slot past a row's last page repeats what it fetched last: the
+    # pipeline fetches no page for it
+    at = groups[:, None] * pages + np.arange(pages)[None]
+    past = at >= count[rows][:, None]
+    assert (ids[1:][past[1:]] == ids[:-1][past[1:]]).all()
+
+
+def test_pos_advanced_inside_a_loop_across_a_page_s_edge():
+    """The decode dispatch's case: the schedule is part of the program,
+    recomputed from ``pos`` every step, so a context that crosses a
+    page's edge (and a group's) in the middle of a dispatch is still
+    attended whole."""
+    q, pk, pv, pt, pos = _inputs([126, 60, None], jnp.float32,
+                                 max_pages=8)
+    # the rows' tables hold the pages the steps walk into
+    pt = pt.at[0, 2].set(5).at[1, 1].set(6)
+
+    def steps(attend):
+        def body(i, carry):
+            pos, out = carry
+            y = attend(q, pk, pv, pt, pos)
+            return pos + 1, out.at[i].set(y)
+        out = jnp.zeros((6,) + q.shape, q.dtype)
+        return jax.lax.fori_loop(0, 6, body, (pos, out))[1]
+
+    got = jax.jit(lambda: steps(functools.partial(_kernel, pages=2)))()
+    want = jax.jit(lambda: steps(_loop))()
+    _agree(got, want, (slice(None), [0, 1]), jnp.float32)
+    assert not np.asarray(got)[:, 2].any()
+
+
+def test_pages_per_visit_follows_the_visit_s_scores():
+    plan = functools.partial(pd.pages_per_visit, page_size=64,
+                             max_pages=64)
+    assert plan(16, kv_heads=16) == 8       # Ouro, OLMoE
+    assert plan(32, kv_heads=8) == 8        # Mistral
+    assert plan(32, kv_heads=4) == 16       # Mellum 2
+    assert plan(64, kv_heads=8) == 4        # Solar-Open2
+    assert plan(16, kv_heads=1) == 16       # no more operands than that
+    assert plan(128, kv_heads=16) == 1
+    assert pd.pages_per_visit(16, 64, 16, max_pages=4) == 4
+
+
+# ------------------------------------------------------------ the choice
+
+def _spied(monkeypatch):
+    """``calls``: the kernel's calls from here on (it returns zeros)."""
+    calls = []
+
+    def spy(q, pk, pv, page_table, pos, **kw):
+        calls.append((q.shape, pk.shape))
+        return jnp.zeros(q.shape, q.dtype)
+    monkeypatch.setattr(pd, "paged_decode_attention", spy)
+    return calls
+
+
+def _call(T=1, H=32, KH=8, D=128, int8=False, latent=False,
+          dtype=jnp.bfloat16, pool_dtype=None, page=PAGE, max_pages=64):
+    """Trace one ``_paged_window_attention`` call of 32 rows of T
+    queries."""
+    pool_dtype = pool_dtype or dtype
+    q = jax.ShapeDtypeStruct((32, T, H, D), dtype)
+    pt = jax.ShapeDtypeStruct((32, max_pages), jnp.int32)
+    pos = jax.ShapeDtypeStruct((32,), jnp.int32)
+    if latent:
+        pk = jax.ShapeDtypeStruct((513, page, D), pool_dtype)
+        return jax.eval_shape(
+            lambda q, pk, pt, pos: paged_mod._paged_window_attention(
+                q, pk, None, None, None, pt, pos, softmax_scale=0.1,
+                value_dim=D), q, pk, pt, pos)
+    pk = jax.ShapeDtypeStruct((513, page, KH, D),
+                              jnp.int8 if int8 else pool_dtype)
+    sk = jax.ShapeDtypeStruct((513, KH), jnp.float32) if int8 else None
+    return jax.eval_shape(
+        lambda q, pk, sk, pt, pos: paged_mod._paged_window_attention(
+            q, pk, pk, sk, sk, pt, pos), q, pk, sk, pt, pos)
+
+
+LOOP_CASES = {
+    "a_prefill_chunk": dict(T=256),
+    "a_verify_of_five_tokens": dict(T=5),
+    "int8_scales": dict(int8=True),
+    "a_latent_pool": dict(latent=True, H=64),
+    "float32_operands": dict(dtype=jnp.float32),
+    "a_pool_of_another_type": dict(pool_dtype=jnp.float32),
+    "a_head_of_half_a_lane_tile": dict(D=64),
+    "heads_that_fill_no_whole_sublane_tile": dict(H=8, KH=8),
+    "a_page_of_no_whole_sublane_tile": dict(page=2, KH=4),
+    # 32 x 4,096 pages: a schedule of 590 KB of the chip's 1 MiB of
+    # scalar memory
+    "a_table_wider_than_the_scalar_memory": dict(max_pages=4096),
+}
+
+
+@pytest.mark.parametrize("name", list(LOOP_CASES))
+def test_the_loop_keeps_what_the_kernel_is_not_for(name, monkeypatch):
+    calls = _spied(monkeypatch)
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
+    case = LOOP_CASES[name]
+    out = _call(**case)
+    assert not calls
+    assert out.shape == (32, case.get("T", 1), case.get("H", 32),
+                         case.get("D", 128))
+
+
+@pytest.mark.parametrize("H,KH", [(16, 16), (32, 8), (32, 4), (64, 8)])
+def test_a_decode_step_over_kv_pages_on_one_tpu_takes_the_kernel(
+        H, KH, monkeypatch):
+    calls = _spied(monkeypatch)
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
+    assert _call(H=H, KH=KH).shape == (32, 1, H, 128)
+    assert calls == [((32, 1, H, 128), (513, PAGE, KH, 128))]
+
+
+def test_the_widest_table_the_kernel_serves(monkeypatch):
+    """The schedule goes in by scalar prefetch, so the rule bounds it:
+    32 rows x 3,584 pages (229,376 tokens a row) is the kernel's,
+    which tests/test_chip_compile.py builds for the chip (32 x 4,096
+    is among the loop's cases above)."""
+    calls = _spied(monkeypatch)
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
+    _call(H=32, KH=4, max_pages=3584)
+    assert len(calls) == 1
+    assert pd.schedule_bytes(32, 3584, 16) == 4 * (
+        32 * 224 * (16 + 1 + 1) + 2 * 32) <= pd._SCHEDULE_BYTES
+    # what visit_schedule hands over is what the rule counted
+    out = jax.eval_shape(
+        functools.partial(pd.visit_schedule, page_size=PAGE, pages=16),
+        jax.ShapeDtypeStruct((32, 3584), jnp.int32),
+        jax.ShapeDtypeStruct((32,), jnp.int32))
+    ids, row_of, group_of, count, _n = out
+    assert 4 * (ids.size + row_of.size + group_of.size + count.size
+                + 32) == pd.schedule_bytes(32, 3584, 16)
+
+
+def test_the_cpu_and_a_mesh_keep_the_loop(monkeypatch, cpu_mesh_devices):
+    """The backend and the ambient mesh decide, by grouped_matmul's
+    rule: the CPU (every other test here), and a multi-device mesh on a
+    TPU, which GSPMD cannot partition a Mosaic kernel for."""
+    from jax.sharding import Mesh
+    calls = _spied(monkeypatch)
+    _call()                                           # the CPU
+    assert not calls
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(cpu_mesh_devices[:2]), ("tensor",))
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        _call()
+    assert not calls
+    _call()
+    assert len(calls) == 1
+
+
+def _kernel_shaped():
+    """A dense model whose decode step the kernel serves on one TPU:
+    bfloat16, 16 heads of 128 on 4 KV heads (tp=4 divides them)."""
+    from ray_tpu.models.llama import Llama, llama_tiny
+    cfg = llama_tiny(dim=2048, n_layers=1, n_heads=16, n_kv_heads=4,
+                     hidden_dim=128, max_seq_len=256,
+                     dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    return cfg, Llama(cfg)
+
+
+@pytest.mark.parametrize("tp", [1, 4])
+def test_a_sharded_replica_s_decode_program_keeps_the_loop(
+        tp, monkeypatch, cpu_mesh_devices):
+    """The rule reads the AMBIENT mesh, and a step program makes its
+    replica's mesh ambient for a dense model as for a mixture
+    (serve/step_programs.py ``ambient_mesh``): traced as the engine
+    builds it on a TPU, a tensor-parallel replica's decode program
+    holds no Pallas call, and a one-chip replica's holds the
+    kernel."""
+    from ray_tpu.models.kv_cache import init_kv_pool
+    from ray_tpu.serve import step_programs
+    from ray_tpu.serve.sharding import EngineSharding
+    cfg, model = _kernel_shaped()
+    mesh = None if tp == 1 else EngineSharding.build(
+        cfg, tp=tp, devices=cpu_mesh_devices[:tp]).mesh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    S = 4
+    # the programs are cached by (model, knobs): nothing traced here
+    # may be found by another test
+    decode = step_programs._jit_decode.__wrapped__(
+        model, 0.0, 8, S, False, mesh)
+    i32 = jnp.int32
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), i32))
+    pages = jax.eval_shape(lambda: init_kv_pool(cfg, 17, PAGE))
+    text = str(jax.make_jaxpr(decode)(
+        params, pages, jax.ShapeDtypeStruct((S, 4), i32),
+        jax.ShapeDtypeStruct((S,), i32), jax.ShapeDtypeStruct((S,), i32),
+        jax.eval_shape(lambda: jax.random.PRNGKey(0)),
+        jax.ShapeDtypeStruct((), i32)))
+    assert ("pallas_call" in text) == (tp == 1)
+
+
+def test_the_kernel_sits_under_the_loop_s_scope(monkeypatch):
+    """A device trace's split of a step by scope keeps counting the
+    call as attention: it is named under ``attn_scores``."""
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
+    q = jnp.zeros((2, 1, 16, 128), jnp.bfloat16)
+    pool = jnp.zeros((9, PAGE, 16, 128), jnp.bfloat16)
+    pt, pos = jnp.ones((2, 4), jnp.int32), jnp.zeros((2,), jnp.int32)
+    jaxpr = jax.make_jaxpr(paged_mod._paged_window_attention)(
+        q, pool, pool, None, None, pt, pos)
+    scopes = [str(e.source_info.name_stack) for e in jaxpr.eqns]
+    assert scopes and set(scopes) == {"attn_scores"}, scopes
+    inner = [e for e in jaxpr.eqns
+             if e.primitive.name in ("jit", "pjit")]
+    assert len(inner) == 1 and inner[0].params["name"] == (
+        "paged_decode_attention")
+    calls = [e for e in inner[0].params["jaxpr"].eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert "paged_decode" in str(calls[0].params)
+
+
+# ----------------------------------------------- the engine's counter
+
+def test_kernel_pages_counts_each_rider_to_its_own_last_page():
+    count = functools.partial(pd.kernel_pages, page_size=64,
+                              max_pages=64)
+    assert count([1]) == 1
+    assert count([64]) == 1
+    assert count([65]) == 2
+    assert count([256, 300, 352]) == 4 + 5 + 6
+    assert count([4096, 9999]) == 64 + 64       # inside the table
+    assert count([]) == 0
+
+
+def test_the_round_event_carries_decode_kernel_pages(monkeypatch):
+    """0 where the decode program holds no kernel (the CPU); each
+    rider's pages to its own last one where it does, beside
+    ``decode_context_tokens``."""
+    from ray_tpu.models.llama import Llama, llama_tiny
+    from ray_tpu.serve.engine import LLMEngine
+    cfg = llama_tiny(dtype=jnp.float32)
+    model = Llama(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
+    eng = LLMEngine(model, params, max_slots=4, page_size=16, n_pages=33,
+                    chunk=4).start()
+    try:
+        def rounds():
+            return [(e[5]["decode_kernel_pages"],
+                     e[5]["decode_context_tokens"])
+                    for e in eng.events.snapshot()
+                    if e[2] == "round" and e[5]["decode_steps"]]
+
+        eng.submit(list(range(1, 40)), max_new_tokens=6).result()
+        assert eng.wait_idle(10)
+        before = rounds()
+        assert before and not any(k for k, _c in before)
+        assert eng.stats["decode_kernel_pages"] == 0
+        asked = []
+        monkeypatch.setattr(
+            pd, "applies", lambda *a: asked.append(a) or True)
+        eng.submit(list(range(1, 40)), max_new_tokens=6).result()
+        assert eng.wait_idle(10)
+        after = rounds()[len(before):]
+        # one rider: the pages of 16 that hold its context
+        assert after and all(k == -(-c // 16) for k, c in after)
+        assert eng.stats["decode_kernel_pages"] == sum(
+            k for k, _c in after)
+        # the program's own question, of the pool's own layout
+        q, k, v, sk, table = asked[0]
+        assert (q.shape, q.dtype) == (
+            (4, 1, cfg.n_heads, cfg.head_dim), jnp.float32)
+        assert k.shape == v.shape == (1, 16, cfg.n_kv_heads, cfg.head_dim)
+        assert k.dtype == v.dtype == jnp.float32 and sk is None
+        assert table.shape == (4, eng.max_pages)
+    finally:
+        eng.shutdown()
+
+
+def test_a_sharded_engine_counts_no_kernel_pages(monkeypatch,
+                                                 cpu_mesh_devices):
+    """On a TPU a tensor-parallel replica's decode program holds the
+    loop, and the engine's counter says so: it asks the rule under the
+    replica's mesh, as the program did. (That the engine decodes at
+    all here says the same: a Pallas call could not run on these
+    devices.) The same model on one chip would count its pages, and an
+    int8 pool would not: the pool's own layout is what is asked."""
+    from ray_tpu.serve.engine import LLMEngine
+    from ray_tpu.serve.sharding import EngineSharding
+    cfg, model = _kernel_shaped()
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
+    sh = EngineSharding.build(cfg, tp=4, devices=cpu_mesh_devices[:4])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = LLMEngine(model, params, sharding=sh, max_slots=4, page_size=16,
+                    n_pages=33, chunk=4).start()
+    try:
+        eng.submit(list(range(1, 40)), max_new_tokens=6).result()
+        assert eng.wait_idle(10)
+        assert eng.stats["decode_steps"] and not eng.stats[
+            "decode_kernel_pages"]
+        assert not any(e[5]["decode_kernel_pages"]
+                       for e in eng.events.snapshot() if e[2] == "round")
+        assert not eng._decode_kernel_serves()
+        monkeypatch.setattr(eng, "_mesh", None)
+        assert eng._decode_kernel_serves()
+        monkeypatch.setattr(eng, "kv_dtype", "int8")
+        assert not eng._decode_kernel_serves()
+    finally:
+        eng.shutdown()
