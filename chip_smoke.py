@@ -365,7 +365,9 @@ def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
                                  (512, 8192, None, 2304), None),),
                  gmm_shapes=((64, 2304, 1024, 256, 200),),
                  decode_shapes=((16, 16, (300, 352, None, 64, 1)),
-                                (32, 4, (8704, None, 70))),
+                                (32, 4, (8704, None, 70)),
+                                (64, None, (8704, None, 8200, 70)),
+                                (32, None, (2048, 1030, None, 1024))),
                  interpret: bool = False, seed: int = SEED) -> dict:
     """The flash-attention kernel, compiled (not interpreted, unless
     the CPU rehearsal asks) and compared with its XLA reference,
@@ -378,7 +380,9 @@ def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
     tile's tokens where not the kernel's own) at a serving cell's shape
     against the block loop; a decode step's attention over K/V pages
     (``decode_shapes``: heads, KV heads, each row's context, None a row
-    no request owns) against the block loop too; the mixture's grouped
+    no request owns; KV heads None: over latent pages as
+    ``window_shapes``' first, A.X-K1's 64 heads and Kimi-Linear's 32 at
+    their cells' contexts) against the block loop too; the mixture's grouped
     matmul
     (``gmm_shapes``: experts, K, N, sorted pairs, pairs that have an
     expert) at a contraction the tile plan takes whole where constants
@@ -498,21 +502,31 @@ def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
                                   np.asarray(want, np.float32)[live])
 
     from ray_tpu.ops import paged_decode_attention as pd
+    _T, _H, latent_d, latent_dv = window_shapes[0][:4]
     for H, KH, contexts in decode_shapes:
-        name = f"paged_decode_H{H}_KH{KH}"
-        B, D = len(contexts), 128
+        name = f"paged_decode_H{H}_KH{KH or 'latent'}"
+        B = len(contexts)
         dtype = jnp.float32 if interpret else jnp.bfloat16
         table, pos, live = rows_of(contexts, 1)
-        pk, pv = (jnp.asarray(rng.standard_normal(
-            (1 + B * max_pages, page, KH, D)), dtype) for _ in range(2))
+        if KH is None:
+            # an absorbed query is as wide as a stored entry; the scale
+            # is not the width's (A.X-K1's under YaRN)
+            D, pools = latent_d, (jnp.asarray(rng.standard_normal(
+                (1 + B * max_pages, page, latent_d)), dtype), None)
+            how = dict(softmax_scale=0.1309, value_dim=latent_dv)
+        else:
+            D, how = 128, dict(softmax_scale=128 ** -0.5)
+            pools = tuple(jnp.asarray(rng.standard_normal(
+                (1 + B * max_pages, page, KH, D)), dtype) for _ in range(2))
         q = jnp.asarray(rng.standard_normal((B, 1, H, D)), dtype)
         with timed(f"kernels: {name}"):
             got = pd.paged_decode_attention(
-                q, pk, pv, table, pos, softmax_scale=D ** -0.5,
-                interpret=interpret)
+                q, *pools, table, pos, interpret=interpret, **how)
             with mock.patch.object(pd, "_on_one_tpu", lambda: False):
-                want = jax.jit(pa._paged_window_attention)(
-                    q, pk, pv, None, None, table, pos)
+                want = jax.jit(functools.partial(
+                    pa._paged_window_attention, **(
+                        how if KH is None else {})))(
+                    q, *pools, None, None, table, pos)
             errs[name] = _rel_err(np.asarray(got, np.float32)[live],
                                   np.asarray(want, np.float32)[live])
 

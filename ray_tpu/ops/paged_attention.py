@@ -34,8 +34,8 @@ the layout:
   of pages gathered by page id up to the longest live context, the
   online softmax's running statistics and accumulator carried through
   a loop with a runtime trip count. It serves every prefill chunk and
-  speculative verify over a K/V pool, every int8 pool, every latent
-  pool's decode step, the CPU and any mesh. The LATENT KERNEL
+  speculative verify over a K/V pool, a verify over a latent one, every
+  int8 and float32 pool, the CPU and any mesh. The LATENT KERNEL
   (ops/latent_window_attention.py): a PREFILL CHUNK over a LATENT pool
   on one TPU, where thousands of (token, head) query rows read one key
   and the loop's float32 scores and accumulator, 134 MB each a block in
@@ -49,7 +49,12 @@ the layout:
   live at 16 riders of 256-352 tokens: 38 % of Ouro's decode step,
   PERF.md section 6, PRs 46 and 47); one Pallas call reads each
   rider's own pages once, where they lie, and no page of a row without
-  a rider. A Pallas decode kernel lost to the loop once (PR 30: 32.8
+  a rider. A decode step over a bfloat16 LATENT pool is the same
+  kernel's ``KH = 1`` case (PR 48): every query head reads the one
+  entry a token, whose value is a slice of the same fetched page, where
+  the loop wrote a ``[rows, 512, 640]`` block by page id and read it
+  back twice (2.4 of A.X-K1's 4.2 ms of attention a step). A Pallas
+  decode kernel lost to the loop once (PR 30: 32.8
   against 10.6-11.1 ms a step) for two reasons, and this one answers
   both: that grid ran 32 slots x 64 table columns whatever the context
   (this one's length is a value, each row walked to its own last page),
@@ -348,13 +353,14 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
     (``latent_window.applies``; ops/latent_window_attention.py: the
     scores and the accumulator in VMEM, each row walked to its own last
     block). A decode step (``T == 1``) over a bfloat16 K/V pool without
-    int8 scales, there too, is ANOTHER (``paged_decode.applies``;
-    ops/paged_decode_attention.py: each rider's own pages read once,
-    where they lie, a row without a rider not at all, and zeros read
-    out for it). Both are the same mathematics and are called under
-    the ``attn_scores`` scope. Everything else is the loop below:
-    prefill chunks and verifies over K/V pools, int8 pools, a latent
-    pool's decode step, the CPU, a mesh.
+    int8 scales or over a bfloat16 latent pool, there too, is ANOTHER
+    (``paged_decode.applies``; ops/paged_decode_attention.py: each
+    rider's own pages read once, where they lie, a row without a rider
+    not at all, and zeros read out for it). Both are the same
+    mathematics and are called under the ``attn_scores`` scope.
+    Everything else is the loop below: prefill chunks and verifies over
+    K/V pools, a verify over a latent pool, int8 and float32 pools, the
+    CPU, a mesh.
 
     Work follows the live contexts, not the table's width: a loop with
     a RUNTIME trip count walks blocks of ``block_pages`` logical pages
@@ -404,12 +410,13 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
                 softmax_scale=(D ** -0.5 if softmax_scale is None
                                else softmax_scale),
                 block_pages=block_pages)
-    if paged_decode.applies(q, pk, pv, sk, page_table):
+    if paged_decode.applies(q, pk, pv, sk, page_table, value_dim):
         with jax.named_scope("attn_scores"):
             return paged_decode.paged_decode_attention(
                 q, pk, pv, page_table, pos,
                 softmax_scale=float(D ** -0.5 if softmax_scale is None
-                                    else softmax_scale))
+                                    else softmax_scale),
+                value_dim=value_dim)
     # Grouped-query attention WITHOUT materializing repeated K/V: q
     # reshapes to [B, T, KH, rep, D] and contracts against the grouped
     # cache directly (a repeat would move rep x the KV bytes a step).

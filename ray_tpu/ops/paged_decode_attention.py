@@ -1,4 +1,5 @@
-"""A decode step's attention over a K/V page pool as one Pallas kernel.
+"""A decode step's attention over a K/V or a latent page pool as one
+Pallas kernel.
 
 ops/paged_attention.py ``_paged_window_attention`` walks the batch's
 pages a block of 512 tokens at a time, for EVERY row up to the longest
@@ -43,6 +44,23 @@ pages read once, where they lie:
   ``KH`` times the needed FLOPs, which are nothing here: 22 MFLOP a
   layer against 40 MB fetched at Ouro's shape.
 
+A pool of LATENT pages ``[n_pages, page_size, W]`` (``pv`` None;
+models/axk1.py, models/kimi_linear.py: one entry ``[c | k_r]`` a token
+that every query head reads, absorbed) is the ``KH = 1`` case of the
+same kernel: a page is already the matrix it is read as, no product is
+another head's, the value is the first ``value_dim`` columns of the
+SAME fetched page (whole lane tiles: a free slice), so a visit fetches
+``pages`` operand blocks and not twice that, and the accumulator and
+the result are ``value_dim`` wide. Here the bytes are NOT all there is:
+64 (or 32) query rows against an entry are 121 (60) FLOPs a byte
+fetched where the chip's ridge is 240, and with so few rows streamed
+against each 128 x 128 tile of entries the matrix unit is bound by its
+tile loads, about the bytes' own time. So a page of 64 entries, half a
+tile, is not contracted alone: the visit's pages are one ``[pages x
+page_size, W]`` matrix (``pages_per_dot``; a layer-step at A.X-K1's
+shape 0.428 ms so, 0.575 a page a ``dot``, the loop 0.804: PERF.md
+section 6, PR 48).
+
 How many pages a visit fetches and folds follows from the shapes
 (``pages_per_visit``: what a visit's float32 scores come to), and
 which calls the kernel serves is ``applies``'s rule, read by
@@ -53,6 +71,7 @@ serve/step_programs.py ``ambient_mesh``), never a flag.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -76,7 +95,13 @@ _NN = (((1,), (0,)), ((), ()))        # a @ b
 # **0.0687** | 0.0785, Mistral's 32 on 512 0.0886 | 0.0890 | **0.0688**
 # | 0.1012, Mellum 2's 32 on 256 1.023 | 0.710 | 0.603 | **0.604**,
 # Solar-Open2's 64 on 512 0.277 | **0.233** | 0.247 | 0.282: each best
-# where a visit's scores come to 512 KiB.
+# where a visit's scores come to 512 KiB. A latent pool's pages of 64
+# rows reach the limit of sixteen first (PR 48; 4 | 8 | 16 | 32: A.X-K1's
+# 64 heads at 8,192-8,704 tokens 0.657 | 0.472 | **0.428** | 0.440,
+# Kimi-Linear's 32 at 1,024-2,048 0.618 | 0.481 | **0.456** | 0.396: a
+# visit folds all its slots, a row's last visit the empty ones too, so
+# past sixteen a long row's last visit wastes what a short row's one
+# visit saves).
 _SCORE_BYTES = 512 << 10
 _MAX_PAGES_A_VISIT = 16
 
@@ -93,10 +118,25 @@ def pages_per_visit(H: int, page_size: int, kv_heads: int,
     hold the fold itself slows. A slot past a row's last page costs no
     fetch, so a short context loses nothing to a wide visit. 16 heads
     on 16 KV heads (Ouro, OLMoE) and 32 on 8 (Mistral) go eight pages a
-    visit, 32 on 4 (Mellum 2) sixteen, 64 on 8 (Solar-Open2) four."""
+    visit, 32 on 4 (Mellum 2) sixteen, 64 on 8 (Solar-Open2) four, 64
+    or 32 on a latent pool's one (A.X-K1, Kimi-Linear) sixteen."""
     want = max(1, _SCORE_BYTES // (H * page_size * kv_heads * 4))
     return min(1 << (want.bit_length() - 1), _MAX_PAGES_A_VISIT,
                max_pages)
+
+
+def pages_per_dot(page_rows: int, pages: int) -> int:
+    """Pages of a visit ONE contraction spans. A page of at least a
+    lane tile's rows (every K/V cell's: 64 tokens x 4-16 KV heads) is
+    contracted as it was fetched. A page of fewer (a latent pool's 64
+    entries) would fill half the matrix unit's tile in the scores'
+    token dimension and half the read-out's contracted depth, so the
+    visit's pages are contracted as one ``[pages x page_rows, D]``
+    matrix, copied once inside VMEM (1.3 MB a visit beside its fetch).
+    Measured on v5e (PR 48; ms a layer-step, 16 pages a visit
+    contracted 1 | 2 | 16 at a time): A.X-K1's shape 0.575 | 0.427 |
+    **0.428**, Kimi-Linear's 0.486 | 0.456 | **0.456**."""
+    return 1 if page_rows >= _LANES else pages
 
 
 # Scalar memory the visits' schedule may take. It goes in by scalar
@@ -119,23 +159,29 @@ def schedule_bytes(rows: int, max_pages: int, pages: int) -> int:
     return 4 * (visits * (pages + 2) + 2 * rows)
 
 
-def applies(q, pk, pv, sk, page_table) -> bool:
+def applies(q, pk, pv, sk, page_table, value_dim=None) -> bool:
     """Whether the kernel serves ``q`` [B, T, H, D] over the pool
-    ``pk``/``pv`` [n_pages, Pg, KH, D] (``pv`` None: latent pages, the
-    loop's) with the int8 scales ``sk`` (the loop's too) under
+    ``pk``/``pv`` [n_pages, Pg, KH, D], or over the latent pages ``pk``
+    [n_pages, Pg, D] whose values are their first ``value_dim`` columns
+    (``pv`` None), with the int8 scales ``sk`` (the loop's) under
     ``page_table`` [B, max_pages]: one query a row (a decode step; a
     prefill chunk and a speculative verify keep the loop), queries and
-    pool bfloat16, whole query groups, a head of whole 128-lane tiles,
-    query heads and a page's rows in whole sublane tiles, a schedule
-    that fits the scalar memory, and a TPU outside any multi-device
-    mesh. Only shapes and types are read: ``_paged_window_attention``
-    asks it of its arguments, and the engine of the same shapes for its
-    ``decode_kernel_pages``."""
-    if pv is None or sk is not None or pk.ndim != 4:
+    pool bfloat16, whole query groups, a head and a value of whole
+    128-lane tiles, query heads and a page's rows in whole sublane
+    tiles, a schedule that fits the scalar memory, and a TPU outside
+    any multi-device mesh. Only shapes and types are read:
+    ``_paged_window_attention`` asks it of its arguments, and the
+    engine of the same shapes for its ``decode_kernel_pages``."""
+    latent = pv is None
+    if sk is not None or pk.ndim != (3 if latent else 4):
         return False
-    (T, H, D), (Pg, KH) = q.shape[1:], pk.shape[1:3]
+    (T, H, D), Pg = q.shape[1:], pk.shape[1]
+    if latent and (not value_dim or value_dim > D or value_dim % _LANES):
+        return False
+    KH = 1 if latent else pk.shape[2]
     B, max_pages = page_table.shape
-    return (T == 1 and q.dtype == pk.dtype == pv.dtype == jnp.bfloat16
+    return (T == 1 and q.dtype == pk.dtype == jnp.bfloat16
+            and (latent or pv.dtype == jnp.bfloat16)
             and H % KH == 0 and D % _LANES == 0 and H % 16 == 0
             and (Pg * KH) % 16 == 0
             and schedule_bytes(B, max_pages,
@@ -145,7 +191,8 @@ def applies(q, pk, pv, sk, page_table) -> bool:
 
 
 def kernel_pages(ends, page_size: int, max_pages: int) -> int:
-    """Pages ONE K/V layer's kernel visits (of K and of V each) for
+    """Pages ONE K/V or latent layer's kernel visits (of K and of V
+    each, where there is a V) for
     riders whose last query of a decode dispatch sits at ``end - 1``
     (host integers): each rider to its own last page, inside the table.
     The engine's ``decode_kernel_pages``."""
@@ -201,15 +248,18 @@ def _dot(a, b, dims):
 
 
 def _decode_kernel(ids_ref, row_ref, group_ref, count_ref, pos_ref,
-                   q_ref, *rest, scale: float, pages: int, kv_heads: int):
+                   q_ref, *rest, scale: float, pages: int, kv_heads: int,
+                   span: int):
     del ids_ref                                 # the index maps' alone
-    k_refs, v_refs = rest[:pages], rest[pages:2 * pages]
-    o_ref, m_scr, l_scr, acc_scr = rest[2 * pages:]
+    # a latent pool has no V pages: an entry's value is its own first
+    # columns, as many as the accumulator is wide
+    k_refs, v_refs = rest[:pages], rest[pages:-4]
+    o_ref, m_scr, l_scr, acc_scr = rest[-4:]
     v = pl.program_id(0)
     row, group = row_ref[v], group_ref[v]  # this visit's row, page group
     count = count_ref[row]                 # pages this row visits
     last = pos_ref[row]                    # its query's position
-    H = q_ref.shape[2]
+    H, dv = q_ref.shape[2], acc_scr.shape[1]
     page_rows = k_refs[0].shape[1]         # (token, KV head) rows a page
     page_size = page_rows // kv_heads
 
@@ -220,12 +270,15 @@ def _decode_kernel(ids_ref, row_ref, group_ref, count_ref, pos_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0]                                          # [H, D]
-    # column c of a page's scores is token c // KH under KV head c % KH;
-    # query head h reads KV head h // rep
-    col = jax.lax.broadcasted_iota(jnp.int32, (H, page_rows), 1)
+    # column c of a contraction's scores (``span`` pages' rows, one
+    # after another) is token c // KH under KV head c % KH; query head
+    # h reads KV head h // rep, and where there is one KV head every
+    # head reads every row
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, span * page_rows), 1)
     token, kv_head = _split(col, kv_heads)
-    head = jax.lax.broadcasted_iota(jnp.int32, (H, page_rows), 0)
-    own = kv_head == _split(head, H // kv_heads)[0]
+    if kv_heads > 1:
+        head = jax.lax.broadcasted_iota(jnp.int32, col.shape, 0)
+        own = kv_head == _split(head, H // kv_heads)[0]
 
     @pl.when(count > 0)
     def _():
@@ -236,12 +289,22 @@ def _decode_kernel(ids_ref, row_ref, group_ref, count_ref, pos_ref,
         # pays (0.5 us a page folded alone, whatever its size). A slot
         # past the row's last page holds a page fetched earlier, whose
         # every position lies past the row's: masked like any other.
-        scores = []
-        for c in range(pages):
-            s = _dot(q, k_refs[c][0], _NT) * scale           # [H, rows]
-            first = (group * pages + c) * page_size
-            scores.append(jnp.where(own & (first + token <= last), s,
-                                    _NEG_INF))
+        def block(refs, c):
+            # a page of fewer rows than the matrix unit's tile is wide
+            # is contracted together with its neighbours: one matrix of
+            # ``span`` pages, copied once inside VMEM
+            return (refs[c][0] if span == 1 else jnp.concatenate(
+                [r[0] for r in refs[c * span:(c + 1) * span]], axis=0))
+
+        keys, scores = [], []
+        for c in range(pages // span):
+            k = block(k_refs, c)
+            s = _dot(q, k, _NT) * scale                      # [H, rows]
+            first = (group * pages + c * span) * page_size
+            seen = first + token <= last
+            scores.append(jnp.where(own & seen if kv_heads > 1 else seen,
+                                    s, _NEG_INF))
+            keys.append(k)
         # across the pages elementwise first: one reduction over the
         # lanes a visit, not one a page
         m_prev = m_scr[:, :1]
@@ -252,7 +315,8 @@ def _decode_kernel(ids_ref, row_ref, group_ref, count_ref, pos_ref,
         l = l_scr[:, :1] * alpha + jnp.sum(
             functools.reduce(jnp.add, ps), axis=-1, keepdims=True)
         acc_scr[...] = acc_scr[...] * alpha + sum(
-            _dot(p.astype(v_refs[c].dtype), v_refs[c][0], _NN)
+            _dot(p.astype(k_refs[c].dtype),
+                 block(v_refs, c) if v_refs else keys[c][:, :dv], _NN)
             for c, p in enumerate(ps))
         m_scr[...] = jnp.broadcast_to(m, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l, l_scr.shape)
@@ -267,15 +331,26 @@ def _decode_kernel(ids_ref, row_ref, group_ref, count_ref, pos_ref,
 
 
 def _attend(q, pk, pv, page_table, pos, *, softmax_scale: float,
-            pages: int, interpret: bool = False):
-    """``paged_decode_attention`` at ``pages`` logical pages a visit:
-    what it calls with ``pages_per_visit``'s plan, and what
-    tools/paged_decode_bench.py and the tests call with another to
-    measure or check the plan."""
+            pages: Optional[int] = None, value_dim: Optional[int] = None,
+            span: Optional[int] = None, interpret: bool = False):
+    """``paged_decode_attention`` at ``pages`` logical pages a visit,
+    ``span`` of them a contraction (None: ``pages_per_visit``'s and
+    ``pages_per_dot``'s plan): what tools/paged_decode_bench.py and the
+    tests call with another plan to measure or check the rule's."""
     B, T, H, D = q.shape
-    n_pages, Pg, KH = pk.shape[:3]
-    assert T == 1 and pv.shape == pk.shape and H % KH == 0, (
-        q.shape, pk.shape, pv.shape)
+    n_pages, Pg = pk.shape[:2]
+    if pv is None:
+        # latent pages [n_pages, Pg, D]: one KV head without an axis
+        KH, Dv = 1, value_dim
+        assert T == 1 and pk.ndim == 3 and 0 < Dv <= D, (
+            q.shape, pk.shape, value_dim)
+    else:
+        KH, Dv = pk.shape[2], D
+        assert (T == 1 and pv.shape == pk.shape and H % KH == 0
+                and value_dim is None), (q.shape, pk.shape, pv.shape)
+    pages = pages or pages_per_visit(H, Pg, KH, page_table.shape[1])
+    span = span or pages_per_dot(Pg * KH, pages)
+    assert pages % span == 0, (pages, span)
     ids, row_of, group_of, count, n_visits = visit_schedule(
         page_table, pos, Pg, pages)
 
@@ -283,51 +358,58 @@ def _attend(q, pk, pv, page_table, pos, *, softmax_scale: float,
         return pl.BlockSpec(
             (1, Pg * KH, D), lambda v, ids, *_: (ids[v * pages + c], 0, 0))
 
-    row = pl.BlockSpec(
-        (1, 1, H, D), lambda v, ids, row_of, *_: (row_of[v], 0, 0, 0))
+    def row(width):
+        return pl.BlockSpec(
+            (1, 1, H, width),
+            lambda v, ids, row_of, *_: (row_of[v], 0, 0, 0))
     page_bytes = Pg * KH * D * pk.dtype.itemsize
     # a page-major page IS the [Pg x KH, D] matrix of its (token, KV
     # head) rows: a free view
     flat = (n_pages, Pg * KH, D)
+    pools = (pk,) if pv is None else (pk, pv)
     return pl.pallas_call(
         functools.partial(_decode_kernel, scale=softmax_scale,
-                          pages=pages, kv_heads=KH),
+                          pages=pages, kv_heads=KH, span=span),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(n_visits,),
-            in_specs=[row] + [page(c) for c in range(pages)] * 2,
-            out_specs=row,
+            in_specs=([row(D)]
+                      + [page(c) for c in range(pages)] * len(pools)),
+            out_specs=row(Dv),
             scratch_shapes=[
                 pltpu.VMEM((H, _LANES), jnp.float32),          # m
                 pltpu.VMEM((H, _LANES), jnp.float32),          # l
-                pltpu.VMEM((H, D), jnp.float32)]),             # acc
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+                pltpu.VMEM((H, Dv), jnp.float32)]),            # acc
+        out_shape=jax.ShapeDtypeStruct((B, 1, H, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             # a row's groups in order: its scratch carries them
             dimension_semantics=("arbitrary",),
-            # K and V pages double-buffered, a visit's scores (float32,
+            # the pages double-buffered (K's and V's, or a latent
+            # pool's and their one matrix), a visit's scores (float32,
             # their exponentials, those in the pool's type), and room
             # for what the compiler spills
             vmem_limit_bytes=(4 * pages * page_bytes
                               + 12 * pages * H * Pg * KH + (8 << 20))),
         interpret=interpret, name="paged_decode",
     )(ids, row_of, group_of, count, pos.astype(jnp.int32), q,
-      *([pk.reshape(flat)] * pages), *([pv.reshape(flat)] * pages))
+      *(x for pool in pools for x in [pool.reshape(flat)] * pages))
 
 
-@functools.partial(jax.jit, static_argnames=("softmax_scale",
+@functools.partial(jax.jit, static_argnames=("softmax_scale", "value_dim",
                                              "interpret"))
 def paged_decode_attention(q, pk, pv, page_table, pos, *,
-                           softmax_scale: float, interpret: bool = False):
+                           softmax_scale: float,
+                           value_dim: Optional[int] = None,
+                           interpret: bool = False):
     """Causal grouped-query attention of ``q`` [B, 1, H, D] (row b's
     query at absolute position ``pos[b]``) over its page-table row's
     K/V in the page-major pool ``pk``/``pv`` [n_pages, Pg, KH, D].
     Returns [B, 1, H, D] in ``q``'s type. A row whose page-table row is
-    null (its first page is page 0) reads out zeros.
+    null (its first page is page 0) reads out zeros. ``pv`` None: ``pk``
+    is a pool of latent pages [n_pages, Pg, D], an entry's value its
+    first ``value_dim`` columns, and [B, 1, H, value_dim] comes back.
 
     One jitted function: the layers of a step program that call it
     with equal shapes share one trace and one lowering."""
-    H, (Pg, KH) = q.shape[2], pk.shape[1:3]
     return _attend(q, pk, pv, page_table, pos, softmax_scale=softmax_scale,
-                   pages=pages_per_visit(H, Pg, KH, page_table.shape[1]),
-                   interpret=interpret)
+                   value_dim=value_dim, interpret=interpret)

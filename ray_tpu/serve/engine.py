@@ -102,7 +102,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from ray_tpu.models.kv_cache import (KIND_KV, BlockAllocator,
+from ray_tpu.models.kv_cache import (KIND_KV, KIND_LATENT, BlockAllocator,
                                      PagedKVLayer, check_kv_dtype,
                                      export_page_bytes,
                                      has_latent_pages, init_kv_pool,
@@ -489,7 +489,7 @@ def _new_round_info() -> Dict[str, int]:
     blocks ONE such layer's kernel visits over the call's live rows,
     each row to the block of its own last query.
     ``decode_kernel_pages`` is 0 where the decode program holds no
-    kernel for its K/V layers' attention
+    kernel for its K/V or latent layers' attention
     (ops/paged_decode_attention.py), else the pages ONE such layer's
     kernel visits at the dispatch's last step, each rider to its own
     last page: beside ``decode_riders`` x ``decode_window_tokens`` it
@@ -2763,7 +2763,8 @@ class LLMEngine:
                 self.Pg, cfg.dtype)
 
     def _decode_kernel_serves(self) -> bool:
-        """Whether the decode program's K/V layers attend through the
+        """Whether the decode program's paged layers (the latent ones
+        where the model has them, else the K/V ones) attend through the
         kernel: ``paged_decode.applies``, the very question
         ``_paged_window_attention`` asks, of a decode step's queries
         and one layer's pages as ``page_layout`` stores them (what the
@@ -2771,18 +2772,26 @@ class LLMEngine:
         not decided again here), under the mesh the program is traced
         under."""
         cfg = self.cfg
-        if KIND_KV not in layer_kinds(cfg):
+        latent = has_latent_pages(cfg)
+        if not latent and KIND_KV not in layer_kinds(cfg):
             return False
-        k, v, *scales = (
-            jax.ShapeDtypeStruct((1,) + shape[-3:], dtype)
-            for shape, dtype in page_layout(cfg, KIND_KV, self.Pg,
-                                            self.kv_dtype))
-        q = jax.ShapeDtypeStruct((self.S, 1, cfg.n_heads, cfg.head_dim),
+        # a page without its pass axis, if any: [Pg, KH, D] of K and of
+        # V (and an int8 pool's scales), or a latent pool's one [Pg, W]
+        k, v, sk = ([
+            jax.ShapeDtypeStruct((1,) + shape[-(2 if latent else 3):],
+                                 dtype)
+            for shape, dtype in page_layout(
+                cfg, KIND_LATENT if latent else KIND_KV, self.Pg,
+                self.kv_dtype)] + [None, None])[:3]
+        # a head's query is as wide as what it is scored against: a KV
+        # head's key, or (absorbed) a stored latent entry, whose value
+        # is its latent
+        q = jax.ShapeDtypeStruct((self.S, 1, cfg.n_heads, k.shape[-1]),
                                  cfg.dtype)
         table = jax.ShapeDtypeStruct((self.S, self.max_pages), jnp.int32)
         with ambient_mesh(self._mesh):
             return paged_decode.applies(
-                q, k, v, scales[0] if scales else None, table)
+                q, k, v, sk, table, cfg.kv_lora_rank if latent else None)
 
     def _note_window(self, key: str, end: int) -> None:
         """Record under ``key`` the positions a dispatch's paged

@@ -370,25 +370,37 @@ def test_decode_attends_in_one_kernel_a_layer(one_chip, monkeypatch,
 
 
 # one layer-step at each serving cell's shape: (rows, heads, KV heads,
-# table columns)
+# table columns), KV heads None over LATENT pages [64, 640] whose value
+# is their first 512 columns (A.X-K1: a table of 16,384 tokens, its
+# riders at 8,192-8,704; Kimi-Linear at its 128 slots)
 # and the widest table the rule hands the kernel (its schedule goes in
 # by scalar prefetch: half of the chip's 1 MiB of scalar memory)
 @pytest.mark.parametrize("B,H,KH,max_pages", [
     (16, 16, 16, 64), (32, 32, 8, 64), (32, 32, 4, 256), (32, 64, 8, 64),
-    (32, 32, 4, 3584)],
-    ids=["ouro", "mistral", "mellum2", "solar_open2", "widest_table"])
+    (32, 32, 4, 3584), (32, 64, None, 256), (128, 32, None, 64)],
+    ids=["ouro", "mistral", "mellum2", "solar_open2", "widest_table",
+         "axk1_latent", "kimi_linear_latent"])
 def test_paged_decode_kernel_compiles(one_chip, B, H, KH, max_pages):
     from ray_tpu.ops import paged_decode_attention as pd
     assert pd.schedule_bytes(
-        B, max_pages, pd.pages_per_visit(H, PAGE, KH, max_pages)
+        B, max_pages, pd.pages_per_visit(H, PAGE, KH or 1, max_pages)
     ) <= pd._SCHEDULE_BYTES
-    pool = ((1025, PAGE, KH, 128), jnp.bfloat16)
-    compiled = _compile(
-        lambda *a: pd.paged_decode_attention(*a, softmax_scale=0.088),
-        one_chip, ((B, 1, H, 128), jnp.bfloat16), pool, pool,
-        ((B, max_pages), jnp.int32), ((B,), jnp.int32))
+    table = [((B, max_pages), jnp.int32), ((B,), jnp.int32)]
+    if KH is None:
+        pool = ((1025, PAGE, 640), jnp.bfloat16)
+        compiled = _compile(
+            lambda q, pk, pt, pos: pd.paged_decode_attention(
+                q, pk, None, pt, pos, softmax_scale=0.1309, value_dim=512),
+            one_chip, ((B, 1, H, 640), jnp.bfloat16), pool, *table)
+        assert "bf16[%d,1,%d,512]" % (B, H) in compiled.as_text()
+    else:
+        pool = ((1025, PAGE, KH, 128), jnp.bfloat16)
+        compiled = _compile(
+            lambda *a: pd.paged_decode_attention(*a, softmax_scale=0.088),
+            one_chip, ((B, 1, H, 128), jnp.bfloat16), pool, pool, *table)
+        assert not _pool_copies(compiled.as_text(),
+                                (1025, PAGE * KH, 128))
     assert not _pool_copies(compiled.as_text(), pool[0])
-    assert not _pool_copies(compiled.as_text(), (1025, PAGE * KH, 128))
 
 
 def test_decode_copies_no_pool_shard_under_tp4(topo, monkeypatch):
@@ -693,6 +705,53 @@ def test_latent_prefill_attends_in_one_kernel_a_layer(one_chip,
         _assert_prefill_attends_in_the_kernel(
             _no_kv_step("prefill", one_chip), 32, 1, "4609,64,640",
             2 * 128 * 32 * 128 * 128 * 4)
+
+
+# Both latent families' decode program with a latent layer's attention
+# as ONE ``paged_decode`` call (ops/paged_decode_attention.py), which a
+# TPU outside any mesh chooses: the pool goes in as it is stored, 16
+# operand blocks of one page each, nothing of the loop's gathered block
+# [rows, 512, 640] is left, and the prefill program keeps its own
+# kernel.
+@pytest.mark.parametrize("family", ["axk1", "kimi_linear"])
+def test_latent_decode_attends_in_one_kernel_a_layer(one_chip,
+                                                     monkeypatch, family):
+    from ray_tpu.ops import grouped_matmul as gm
+    from ray_tpu.ops import linear_attention as la
+    from ray_tpu.ops import paged_decode_attention as pd
+    from ray_tpu.serve import step_programs
+    for mod, name in ((gm, "_use_kernel"), (la, "_on_one_tpu"),
+                      (pd, "_on_one_tpu")):
+        monkeypatch.setattr(mod, name, lambda: True)
+    # the programs are cached by (model, knobs): the cases above traced
+    # these models' with the loop, and no later one may find the kernel's
+    monkeypatch.setattr(step_programs, "_jit_decode",
+                        step_programs._jit_decode.__wrapped__)
+    if family == "axk1":
+        compiled, rows, heads, layers, pool = (
+            _latent_step("decode", one_chip), SLOTS, 64, 2, "4353,64,640")
+    else:
+        compiled, rows, heads, layers, pool = (
+            _no_kv_step("decode", one_chip), 128, 32, 1, "4609,64,640")
+    text = compiled.as_text()
+    calls = [line for line in re.findall(
+        r"[^\n]*custom-call\([^\n]*/attn_scores/[^\n]*paged_decode[^\n]*",
+        text) if "tpu_custom_call" in line]
+    assert len(calls) == layers, (len(calls), layers)
+    for call in calls:
+        assert call.count("bf16[%s]{2,1,0}" % pool) == 16, call[:400]
+        assert "= bf16[%d,1,%d,512]" % (rows, heads) in call, call[:400]
+    assert "kv_gather" not in text and "attn_pv" not in text
+    assert "bskd->bkrts" not in text
+    block = [m.group(0) for m in re.finditer(
+        r"= (?:bf16|f32)\[%d,512,640\]" % rows, text)]
+    assert not block, sorted(set(block))
+    entry = re.search(r"bf16\[%s\](\{[^}]*\}) parameter" % pool, text)
+    assert entry and entry.group(1).startswith("{2,1,0"), entry
+    copies = re.findall(
+        r"= bf16\[%s\](?:\{[^}]*\})? copy\(" % pool, text)
+    assert not copies, f"{len(copies)} whole-pool copies"
+    assert text.count("may-alias") >= layers, text[:400]
 
 
 # ---------------------------------------------------------------

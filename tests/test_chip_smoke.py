@@ -31,7 +31,9 @@ def test_kernel_phase_rehearsal():
                                                    (64, None, 600), 16),),
                                    gmm_shapes=((4, 384, 256, 200, 150),),
                                    decode_shapes=((16, 4,
-                                                   (70, None, 1)),),
+                                                   (70, None, 1)),
+                                                  (16, None,
+                                                   (70, None, 129))),
                                    interpret=True)
     assert {n.split("_")[0] for n in errs} == {"flash", "kda", "latent",
                                                "grouped", "paged"}

@@ -1,8 +1,8 @@
-"""A decode step's attention over K/V pages: the Pallas kernel
-(ops/paged_decode_attention.py) in interpret mode against the block loop
-the CPU serves (ops/paged_attention.py ``_paged_window_attention``) on
-identical inputs, the rule that chooses between them, and the engine's
-count of the pages the kernel visits.
+"""A decode step's attention over K/V or latent pages: the Pallas
+kernel (ops/paged_decode_attention.py) in interpret mode against the
+block loop the CPU serves (ops/paged_attention.py
+``_paged_window_attention``) on identical inputs, the rule that chooses
+between them, and the engine's count of the pages the kernel visits.
 
 Pages and blocks are a deployment's (64 tokens, 512). float32 agrees to
 rtol 1e-4 as the other window tests; bfloat16 outputs (of order 1, one
@@ -25,14 +25,12 @@ TOL = {jnp.float32: dict(rtol=1e-4, atol=1e-5),
 GROUPS = {"rows_1": (16, 16), "rows_4": (32, 8), "rows_8": (32, 4)}
 
 
-def _inputs(contexts, dtype, max_pages, H=16, KH=16, seed=0,
-            stale=100_000):
-    """Rows whose contexts hold ``contexts`` tokens, the query's among
-    them (None: a row no request owns, its page-table row null and its
-    position stale), over a pool whose pages lie scattered."""
-    rng = np.random.default_rng(seed)
+def _rows(contexts, max_pages, rng, stale):
+    """(page table, positions) of rows whose contexts hold ``contexts``
+    tokens, the query's among them (None: a row no request owns, its
+    page-table row null and its position ``stale``), their pages
+    scattered over a pool of ``1 + len(contexts) * max_pages``."""
     B = len(contexts)
-    n_pages = 1 + B * max_pages
     ids = 1 + rng.permutation(B * max_pages).reshape(B, max_pages)
     pt = np.zeros((B, max_pages), np.int32)
     pos = np.zeros((B,), np.int32)
@@ -43,11 +41,21 @@ def _inputs(contexts, dtype, max_pages, H=16, KH=16, seed=0,
         pos[b] = n - 1
         held = -(-n // PAGE)
         pt[b, :held] = ids[b, :held]
+    return jnp.asarray(pt), jnp.asarray(pos)
+
+
+def _inputs(contexts, dtype, max_pages, H=16, KH=16, seed=0,
+            stale=100_000):
+    """Queries of ``H`` heads for ``_rows`` over a K/V pool of ``KH``
+    heads."""
+    rng = np.random.default_rng(seed)
+    B = len(contexts)
+    pt, pos = _rows(contexts, max_pages, rng, stale)
     pk, pv = (jnp.asarray(
-        0.5 * rng.standard_normal((n_pages, PAGE, KH, D)), dtype)
+        0.5 * rng.standard_normal((1 + B * max_pages, PAGE, KH, D)), dtype)
         for _ in range(2))
     q = jnp.asarray(rng.standard_normal((B, 1, H, D)), dtype)
-    return q, pk, pv, jnp.asarray(pt), jnp.asarray(pos)
+    return q, pk, pv, pt, pos
 
 
 @jax.jit
@@ -194,21 +202,145 @@ def test_pages_per_visit_follows_the_visit_s_scores():
     assert pd.pages_per_visit(16, 64, 16, max_pages=4) == 4
 
 
+# ------------------------------------------------------- latent pages
+# An entry [c | k_r] in whole lane tiles, its value the first columns,
+# and A.X-K1's scale under YaRN (not ``W ** -0.5``)
+W, DV, SCALE = 640, 512, 0.1309
+# both latent cells' query heads: A.X-K1's, Kimi-Linear's
+LATENT_HEADS = [64, 32]
+
+
+def _latent_inputs(contexts, dtype, max_pages, H, seed=0, stale=100_000):
+    """``_inputs`` over a pool of latent pages [n_pages, PAGE, W]."""
+    rng = np.random.default_rng(seed)
+    B = len(contexts)
+    pt, pos = _rows(contexts, max_pages, rng, stale)
+    pages = jnp.asarray(
+        0.5 * rng.standard_normal((1 + B * max_pages, PAGE, W)), dtype)
+    q = jnp.asarray(0.3 * rng.standard_normal((B, 1, H, W)), dtype)
+    return q, pages, pt, pos
+
+
+@jax.jit
+def _latent_loop(q, pages, pt, pos):
+    return paged_mod._paged_window_attention(
+        q, pages, None, None, None, pt, pos, softmax_scale=SCALE,
+        value_dim=DV)
+
+
+def _latent_kernel(q, pages, pt, pos, pages_a_visit=0, span=None):
+    """The kernel by its plan, or at ``pages_a_visit`` pages a visit,
+    ``span`` of them a contraction."""
+    if not pages_a_visit:
+        return pd.paged_decode_attention(
+            q, pages, None, pt, pos, softmax_scale=SCALE, value_dim=DV,
+            interpret=True)
+    return jax.jit(functools.partial(
+        pd._attend, softmax_scale=SCALE, value_dim=DV, pages=pages_a_visit,
+        span=span, interpret=True))(q, pages, None, pt, pos)
+
+
+# a visit is 16 pages of 64 at both cells' heads: 1,024 tokens
+LATENT_CONTEXTS = {
+    # inside a page, on its edge, one short of and one past it
+    "page_edge": [40, 64, 63, 65, 320, 321],
+    # the same at a visit's edge
+    "visit_edge": [1024, 1023, 1025, 2048, 2049],
+    "a_row_of_one_token": [1, 300],
+    "mixed_16_to_2560": [16, 2560, 700, 129],
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("H", LATENT_HEADS)
+@pytest.mark.parametrize("name", list(LATENT_CONTEXTS))
+def test_kernel_equals_the_block_loop_over_latent_pages(name, H, dtype):
+    args = _latent_inputs(LATENT_CONTEXTS[name], dtype, max_pages=64, H=H)
+    assert pd.pages_per_visit(H, PAGE, 1, 64) == 16
+    got = _latent_kernel(*args)
+    assert got.shape == (len(LATENT_CONTEXTS[name]), 1, H, DV)
+    _agree(got, _latent_loop(*args), slice(None), dtype)
+
+
+@pytest.mark.parametrize("H", LATENT_HEADS)
+def test_the_latent_table_s_last_page(H):
+    args = _latent_inputs([64 * PAGE, 70], jnp.float32, max_pages=64, H=H)
+    _agree(_latent_kernel(*args), _latent_loop(*args), slice(None),
+           jnp.float32)
+
+
+@pytest.mark.parametrize("pages,span", [(1, 1), (4, 1), (4, 2), (8, 8),
+                                        (16, 2), (32, 32)])
+def test_any_pages_a_visit_and_a_contraction_over_latent_pages(pages,
+                                                               span):
+    """How many pages a visit fetches, and how many of them one
+    contraction spans, are prices, not meanings."""
+    args = _latent_inputs([1, 64, 65, 700, 129], jnp.float32,
+                          max_pages=12, H=32)
+    _agree(_latent_kernel(*args, pages_a_visit=pages, span=span),
+           _latent_loop(*args), slice(None), jnp.float32)
+
+
+def test_pages_a_contraction_over_kv_pages():
+    """The same over K/V pages, whose plan is a page a contraction."""
+    args = _inputs([1, 64, 65, 700, 129], jnp.float32, max_pages=12)
+    assert pd.pages_per_dot(PAGE * 16, 8) == 1
+    assert pd.pages_per_dot(PAGE, 16) == 16        # a latent page's rows
+    got = jax.jit(functools.partial(
+        pd._attend, softmax_scale=D ** -0.5, pages=4, span=2,
+        interpret=True))(*args)
+    _agree(got, _loop(*args), slice(None), jnp.float32)
+
+
+@pytest.mark.parametrize("H", LATENT_HEADS)
+def test_a_null_latent_row_with_a_stale_position_changes_nothing(H):
+    contexts = [1100, None, 300, None]
+    args = _latent_inputs(contexts, jnp.float32, max_pages=64, H=H)
+    got = np.asarray(_latent_kernel(*args))
+    _agree(got, _latent_loop(*args), [0, 2], jnp.float32)
+    assert not got[[1, 3]].any()
+    calm = _latent_inputs(contexts, jnp.float32, max_pages=64, H=H,
+                          stale=0)
+    np.testing.assert_array_equal(got, np.asarray(_latent_kernel(*calm)))
+
+
+def test_latent_pos_advanced_inside_a_loop_across_a_page_s_edge():
+    q, pages, pt, pos = _latent_inputs([126, 60, None], jnp.float32,
+                                       max_pages=8, H=32)
+    pt = pt.at[0, 2].set(5).at[1, 1].set(6)
+
+    def steps(attend):
+        def body(i, carry):
+            pos, out = carry
+            return pos + 1, out.at[i].set(attend(q, pages, pt, pos))
+        out = jnp.zeros((6,) + q.shape[:3] + (DV,), q.dtype)
+        return jax.lax.fori_loop(0, 6, body, (pos, out))[1]
+
+    got = jax.jit(lambda: steps(functools.partial(
+        _latent_kernel, pages_a_visit=2)))()
+    want = jax.jit(lambda: steps(_latent_loop))()
+    _agree(got, want, (slice(None), [0, 1]), jnp.float32)
+    assert not np.asarray(got)[:, 2].any()
+
+
 # ------------------------------------------------------------ the choice
 
 def _spied(monkeypatch):
     """``calls``: the kernel's calls from here on (it returns zeros)."""
     calls = []
 
-    def spy(q, pk, pv, page_table, pos, **kw):
+    def spy(q, pk, pv, page_table, pos, *, softmax_scale, value_dim):
         calls.append((q.shape, pk.shape))
-        return jnp.zeros(q.shape, q.dtype)
+        return jnp.zeros(q.shape[:3] + (value_dim or q.shape[3],),
+                         q.dtype)
     monkeypatch.setattr(pd, "paged_decode_attention", spy)
     return calls
 
 
 def _call(T=1, H=32, KH=8, D=128, int8=False, latent=False,
-          dtype=jnp.bfloat16, pool_dtype=None, page=PAGE, max_pages=64):
+          dtype=jnp.bfloat16, pool_dtype=None, page=PAGE, max_pages=64,
+          value_dim=DV):
     """Trace one ``_paged_window_attention`` call of 32 rows of T
     queries."""
     pool_dtype = pool_dtype or dtype
@@ -220,7 +352,7 @@ def _call(T=1, H=32, KH=8, D=128, int8=False, latent=False,
         return jax.eval_shape(
             lambda q, pk, pt, pos: paged_mod._paged_window_attention(
                 q, pk, None, None, None, pt, pos, softmax_scale=0.1,
-                value_dim=D), q, pk, pt, pos)
+                value_dim=value_dim), q, pk, pt, pos)
     pk = jax.ShapeDtypeStruct((513, page, KH, D),
                               jnp.int8 if int8 else pool_dtype)
     sk = jax.ShapeDtypeStruct((513, KH), jnp.float32) if int8 else None
@@ -233,7 +365,14 @@ LOOP_CASES = {
     "a_prefill_chunk": dict(T=256),
     "a_verify_of_five_tokens": dict(T=5),
     "int8_scales": dict(int8=True),
-    "a_latent_pool": dict(latent=True, H=64),
+    # what the loop keeps of a latent pool
+    "a_latent_verify_of_five_tokens": dict(latent=True, H=64, D=W, T=5),
+    "a_float32_latent_pool": dict(latent=True, H=64, D=W,
+                                  dtype=jnp.float32),
+    "a_latent_value_of_no_whole_lane_tile": dict(latent=True, H=64, D=W,
+                                                 value_dim=192),
+    "latent_heads_that_fill_no_whole_sublane_tile": dict(
+        latent=True, H=8, D=W),
     "float32_operands": dict(dtype=jnp.float32),
     "a_pool_of_another_type": dict(pool_dtype=jnp.float32),
     "a_head_of_half_a_lane_tile": dict(D=64),
@@ -252,8 +391,9 @@ def test_the_loop_keeps_what_the_kernel_is_not_for(name, monkeypatch):
     case = LOOP_CASES[name]
     out = _call(**case)
     assert not calls
-    assert out.shape == (32, case.get("T", 1), case.get("H", 32),
-                         case.get("D", 128))
+    width = (case.get("value_dim", DV) if case.get("latent")
+             else case.get("D", 128))
+    assert out.shape == (32, case.get("T", 1), case.get("H", 32), width)
 
 
 @pytest.mark.parametrize("H,KH", [(16, 16), (32, 8), (32, 4), (64, 8)])
@@ -263,6 +403,28 @@ def test_a_decode_step_over_kv_pages_on_one_tpu_takes_the_kernel(
     monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
     assert _call(H=H, KH=KH).shape == (32, 1, H, 128)
     assert calls == [((32, 1, H, 128), (513, PAGE, KH, 128))]
+
+
+@pytest.mark.parametrize("rows,H,max_pages", [(32, 64, 256),
+                                              (128, 32, 64)],
+                         ids=["axk1", "kimi_linear"])
+def test_a_decode_step_over_latent_pages_on_one_tpu_takes_the_kernel(
+        rows, H, max_pages, monkeypatch):
+    """Both latent cells' decode steps: the absorbed queries [rows, 1,
+    H, 640] over pages [64, 640] whose value is 512 wide."""
+    calls = _spied(monkeypatch)
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    out = jax.eval_shape(
+        lambda q, pk, pt, pos: paged_mod._paged_window_attention(
+            q, pk, None, None, None, pt, pos, softmax_scale=SCALE,
+            value_dim=DV),
+        jax.ShapeDtypeStruct((rows, 1, H, W), bf16),
+        jax.ShapeDtypeStruct((513, PAGE, W), bf16),
+        jax.ShapeDtypeStruct((rows, max_pages), i32),
+        jax.ShapeDtypeStruct((rows,), i32))
+    assert out.shape == (rows, 1, H, DV)
+    assert calls == [((rows, 1, H, W), (513, PAGE, W))]
 
 
 def test_the_widest_table_the_kernel_serves(monkeypatch):
@@ -286,20 +448,23 @@ def test_the_widest_table_the_kernel_serves(monkeypatch):
                 + 32) == pd.schedule_bytes(32, 3584, 16)
 
 
-def test_the_cpu_and_a_mesh_keep_the_loop(monkeypatch, cpu_mesh_devices):
+@pytest.mark.parametrize("pool", [dict(), dict(latent=True, H=64, D=W)],
+                         ids=["kv_pages", "latent_pages"])
+def test_the_cpu_and_a_mesh_keep_the_loop(pool, monkeypatch,
+                                          cpu_mesh_devices):
     """The backend and the ambient mesh decide, by grouped_matmul's
     rule: the CPU (every other test here), and a multi-device mesh on a
     TPU, which GSPMD cannot partition a Mosaic kernel for."""
     from jax.sharding import Mesh
     calls = _spied(monkeypatch)
-    _call()                                           # the CPU
+    _call(**pool)                                     # the CPU
     assert not calls
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = Mesh(np.asarray(cpu_mesh_devices[:2]), ("tensor",))
     with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
-        _call()
+        _call(**pool)
     assert not calls
-    _call()
+    _call(**pool)
     assert len(calls) == 1
 
 
@@ -415,7 +580,8 @@ def test_the_round_event_carries_decode_kernel_pages(monkeypatch):
         assert eng.stats["decode_kernel_pages"] == sum(
             k for k, _c in after)
         # the program's own question, of the pool's own layout
-        q, k, v, sk, table = asked[0]
+        q, k, v, sk, table, value_dim = asked[0]
+        assert value_dim is None
         assert (q.shape, q.dtype) == (
             (4, 1, cfg.n_heads, cfg.head_dim), jnp.float32)
         assert k.shape == v.shape == (1, 16, cfg.n_kv_heads, cfg.head_dim)
@@ -456,3 +622,69 @@ def test_a_sharded_engine_counts_no_kernel_pages(monkeypatch,
         assert not eng._decode_kernel_serves()
     finally:
         eng.shutdown()
+
+
+def test_the_round_event_carries_decode_kernel_pages_of_latent_pages(
+        monkeypatch, cpu_mesh_devices):
+    """A latent-attention engine's counter: 0 where the decode program
+    holds the loop (the CPU), each rider's pages to its own last one
+    where the rule says the kernel serves, and the rule is asked of the
+    latent pool's own layout: absorbed queries as wide as a stored
+    entry, no V pages, the value the entry's latent. Under a
+    multi-device mesh the same question reads no."""
+    from jax.sharding import Mesh
+    from ray_tpu.models.axk1 import AXK1, axk1_tiny
+    from ray_tpu.models.kv_cache import latent_page_width
+    from ray_tpu.serve.engine import LLMEngine
+
+    def engine(cfg):
+        model = AXK1(cfg)
+        params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                     jnp.zeros((1, 8), jnp.int32))
+        return LLMEngine(model, params, max_slots=4, page_size=16,
+                         n_pages=33, chunk=4)
+
+    cfg = axk1_tiny(dtype=jnp.float32, n_layers=2)
+    eng = engine(cfg).start()
+    try:
+        def rounds():
+            return [(e[5]["decode_kernel_pages"],
+                     e[5]["decode_context_tokens"])
+                    for e in eng.events.snapshot()
+                    if e[2] == "round" and e[5]["decode_steps"]]
+
+        eng.submit(list(range(1, 40)), max_new_tokens=6).result()
+        assert eng.wait_idle(10)
+        before = rounds()
+        assert before and not any(k for k, _c in before)
+        asked = []
+        monkeypatch.setattr(
+            pd, "applies", lambda *a: asked.append(a) or True)
+        eng.submit(list(range(1, 40)), max_new_tokens=6).result()
+        assert eng.wait_idle(10)
+        after = rounds()[len(before):]
+        assert after and all(k == -(-c // 16) for k, c in after)
+        assert eng.stats["decode_kernel_pages"] == sum(
+            k for k, _c in after)
+        q, pages, v, sk, table, value_dim = asked[0]
+        width = latent_page_width(cfg)
+        assert (q.shape, q.dtype) == ((4, 1, cfg.n_heads, width),
+                                      jnp.float32)
+        assert (pages.shape, pages.dtype) == ((1, 16, width), jnp.float32)
+        assert v is None and sk is None
+        assert value_dim == cfg.kv_lora_rank
+        assert table.shape == (4, eng.max_pages)
+    finally:
+        eng.shutdown()
+    monkeypatch.undo()
+    # a latent model at widths the kernel takes (bfloat16, 16 heads, an
+    # entry of 128 + 16 columns in two lane tiles), never run here
+    shaped = engine(axk1_tiny(
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, n_layers=1,
+        n_heads=16, kv_lora_rank=128))
+    assert not shaped._decode_kernel_serves()             # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert shaped._decode_kernel_serves()
+    monkeypatch.setattr(shaped, "_mesh", Mesh(
+        np.asarray(cpu_mesh_devices[:2]), ("tensor",)))
+    assert not shaped._decode_kernel_serves()
