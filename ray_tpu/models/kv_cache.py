@@ -250,9 +250,9 @@ def page_layout(cfg, kind: str, page_size: int, kv_dtype: str = "fp"):
         return (((page_size, latent_page_width(cfg)),
                  jnp.dtype(cfg.dtype)),)
     passes = kv_entries_per_layer(cfg)
-    shape = passes + (page_size, cfg.n_kv_heads, cfg.head_dim)
+    shape = passes + (page_size, kv_page_heads(cfg), cfg.head_dim)
     if quantized:
-        scale = (passes + (cfg.n_kv_heads,), jnp.dtype(KV_SCALE_DTYPE))
+        scale = (passes + (kv_page_heads(cfg),), jnp.dtype(KV_SCALE_DTYPE))
         return ((shape, jnp.dtype(jnp.int8)),) * 2 + (scale,) * 2
     return ((shape, jnp.dtype(cfg.dtype)),) * 2
 
@@ -639,3 +639,23 @@ def kv_entries_per_layer(cfg) -> Tuple[int, ...]:
     every function above that makes an operation, PERF.md section 7.)"""
     passes = getattr(cfg, "kv_entries_per_layer", None)
     return () if passes is None else (int(passes),)
+
+
+def kv_page_heads(cfg) -> int:
+    """Head rows a K/V page stores a token: ``cfg.n_kv_heads``, or the
+    config's own ``kv_page_heads`` where it declares more (models/
+    olmo_hybrid.py: 30 K/V heads stored as 32, the last two zeros). A
+    page's two minor axes are (heads, head_dim), and the chip tiles a
+    bfloat16 array's in 16 x 128: with 30 heads it pads every token's
+    entry to 32 in memory whatever is declared, and the compiler's way
+    around that padding is a pool with the page's POSITIONS second-minor
+    ({3,1,2,0}), which no page can be appended to or gathered from as it
+    lies: every step program then copies every layer's K and V pool on
+    entry and back on exit (16 whole-pool copies a dispatch and a 3.4 GB
+    temporary at Olmo-Hybrid's widths, compiled for a described v5e:
+    PERF.md section 6, PR 49; ``latent_page_width``'s lesson for a head
+    axis). Declared as it is kept, no program copies it. Whole query
+    heads ride the padding (the layer pads q, k and v alike), so the
+    decode kernel's rule of whole sublane tiles holds too. (Defined at
+    the file's end for ``kv_entries_per_layer``'s reason.)"""
+    return int(getattr(cfg, "kv_page_heads", None) or cfg.n_kv_heads)

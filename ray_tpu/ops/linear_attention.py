@@ -1,33 +1,48 @@
-"""Gated delta-rule linear attention with a per-channel decay (Kimi
-Delta Attention): the recurrence a linear-attention layer keeps in
-place of a K/V cache.
+"""Gated delta-rule linear attention: the recurrence a linear-attention
+layer keeps in place of a K/V cache, under either of two gates.
 
-Per head, with keys of width ``dk`` and values of width ``dv``, the
-state ``S`` [dk, dv] (float32) moves one token at a time:
+Per head, with keys of width ``dk`` and values of width ``dv`` (they
+need not be equal), the state ``S`` [dk, dv] (float32) moves one token
+at a time:
 
   S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
   o_t = S_t^T q_t
 
-``g_t`` [dk] <= 0 is the log of the channel's decay, ``beta_t`` the
-write strength (in (0, 2) where negative eigenvalues are allowed).
-Two entry points compute it, and tests/test_linear_attention.py holds
-them equal to each other and to a token-by-token scan:
+``beta_t`` is the write strength (in (0, 2) where negative eigenvalues
+are allowed) and ``g_t`` <= 0 the log of the decay. THE GATE'S SHAPE
+SAYS WHICH RULE IT IS: one more axis than ``beta`` ([.., H, dk]) is a
+decay a CHANNEL (Kimi Delta Attention: models/solar_open2.py); the
+shape of ``beta`` ([.., H]) is ONE decay a head, ``Diag(exp(g_t))`` a
+multiple of the identity (Gated DeltaNet: models/olmo_hybrid.py). Two
+entry points take both, and tests/test_linear_attention.py holds them
+equal to each other, to a token-by-token scan and, under a gate a head,
+to the per-channel form fed that gate broadcast over the channels:
 
-- ``kda_step``: one token (a decode step). Elementwise products and
-  sums over the state, in float32 on the vector unit. On a TPU,
-  outside any multi-device mesh and where heads tile (``dk`` and ``dv``
-  multiples of 128), it is ONE Pallas kernel that updates the state
-  where it lies: a block of a slot's heads comes into VMEM once, both
-  read-outs and the rank-one write are computed there, and the block
-  goes back over its input (``input_output_aliases``); the reset of a
-  row that starts a request and the mask of a row that rides nothing
-  are applied to the block in VMEM, and a row that rides nothing is
-  neither fetched nor written. Everywhere else (the CPU; under a mesh,
-  where GSPMD cannot partition a Mosaic kernel) it is the ``jax.numpy``
-  form, which XLA compiles to two reads of the state and one write (a
-  sum followed by a consumer of the sum cannot be one fusion; PERF.md
-  section 6, PR 40 has the chip's readings of both). What decides is
-  the backend, the ambient mesh and the shapes, never a flag.
+- ``kda_step``: one token (a decode step), either gate, any ``dk`` and
+  ``dv``. Elementwise products and sums over the state, in float32 on
+  the vector unit. On a TPU, outside any multi-device mesh, a float32
+  state whose heads tile (``dk`` and ``dv`` multiples of 128: 128 x 128
+  in both per-channel models) goes through ONE Pallas kernel that
+  updates the state where it lies (``kda_step_kernel``): a block of a
+  slot's heads comes into VMEM once, both read-outs and the rank-one
+  write are computed there, and the block goes back over its input
+  (``input_output_aliases``); the reset of a row that starts a request
+  and the mask of a row that rides nothing are applied to the block in
+  VMEM, and a row that rides nothing is neither fetched nor written.
+  A state whose values are NO whole lane tile (96 x 192) is stored
+  several heads side by side (``pack_heads``: [B, H / p, dk, p x dv],
+  which ``kda_step`` reads off the state's shape), and under one decay
+  a head such a state has the same kernel in its own layout
+  (``kda_step_packed_kernel``: a slot's whole state a visit). Every
+  other state (a head at a time at 96 x 192, a packed state under a
+  decay a channel, the toy sizes, another type), the CPU, and any call
+  under a mesh (GSPMD cannot partition a Mosaic kernel) gets the
+  ``jax.numpy`` form of its layout, which XLA compiles to two reads of
+  the state and one write (a sum followed by a consumer of the sum
+  cannot be one fusion; PERF.md section 6, PR 40 has the chip's
+  readings of both at 128 x 128, PR 49 of all three at 96 x 192). What
+  decides is the backend, the ambient mesh, the gate's and the state's
+  shape and type, never a flag.
 - ``kda_chunked``: a row of T tokens in chunks (a prefill chunk). Inside
   a chunk the T x T interactions are solved at once (the WY/UT form: a
   unit lower-triangular system in the writes ``u``); the state is
@@ -35,14 +50,19 @@ them equal to each other and to a token-by-token scan:
 
 STABLE FOR ANY GATE: the only exponentials taken are of differences
 ``G_t - G_i`` of cumulative log-decays with t >= i, which are <= 0.
-The factored form ``(k_t exp(G_t)) . (k_i exp(-G_i))`` that would make
-the interaction a matmul overflows as soon as a channel decays hard
-(exp(+20 x 64)), so the interaction is summed over channels directly.
-That is a [C, C, dk] product per head and chunk on the vector unit:
-the chunk length trades it against the number of scan steps and the
-size of their matmuls. On a TPU v5e a [4, 256] row of 64 heads of 128
-took 5.93 ms at C = 64, 3.22 at 32 and 2.90 at 16 (PERF.md section 6,
-PR 32), hence the default.
+A decay a channel: the factored form ``(k_t exp(G_t)) . (k_i exp(-G_i))``
+that would make the interaction a matmul overflows as soon as a
+channel decays hard (exp(+20 x 64)), so the interaction is summed over
+channels directly: a [C, C, dk] product per head and chunk on the
+vector unit, and the chunk length trades it against the number of scan
+steps and the size of their matmuls. On a TPU v5e a [4, 256] row of 64
+heads of 128 took 5.93 ms at C = 64, 3.22 at 32 and 2.90 at 16
+(PERF.md section 6, PR 32), hence that form's chunk. ONE decay a head:
+the decay between two positions of a chunk is a [C, C] matrix
+``exp(G_t - G_i)`` that no channel enters, so ``q k^T`` and ``k k^T``
+are matrix-unit products masked by it, nothing of [C, C, dk] exists,
+and the chunk is as long as the matmuls like (PERF.md section 6, PR 49
+has the chip's readings by chunk).
 
 ``valid`` marks real positions: an invalid one (padding inside a
 prefill row, a free slot riding a decode call) has beta 0 and g 0,
@@ -51,7 +71,7 @@ which leaves the state exactly as it was.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -261,32 +281,64 @@ def kda_step_kernel(q, k, v, g, beta, state, valid=None, fresh=None, *,
 
 
 def _use_kernel(state) -> bool:
+    """Whether ``kda_step`` on ``state`` [B, H, dk, dv] is the kernel:
+    a float32 state (the kernel's arithmetic), ``dk`` and ``dv`` whole
+    128-lane tiles (a head's [dk, dv] block and its [dv, dk] transposed
+    columns are then whole tiles of VMEM: 128 x 128 in both per-channel
+    models; 96 x 192 is one and a half lane tiles and gets the
+    ``jax.numpy`` form), and one TPU outside any multi-device mesh
+    (GSPMD cannot partition a Mosaic kernel)."""
     dk, dv = state.shape[-2:]
     return (state.dtype == F32 and dk % _LANES == 0 and dv % _LANES == 0
             and _on_one_tpu())
 
 
 def kda_step(q, k, v, g, beta, state, valid=None, fresh=None):
-    """One token. q, k, g [B, H, dk]; v [B, H, dv]; beta [B, H]; state
-    [B, H, dk, dv] float32; valid, fresh [B] bool or None. A ``fresh``
-    row starts from zeros, whatever ``state`` holds for it; a row that
-    is not ``valid`` leaves its state as it was. Returns (o [B, H, dv]
-    float32, the new state); ``o`` of a row that is not valid means
-    nothing."""
+    """One token. q, k [B, H, dk]; v [B, H, dv]; beta [B, H]; g
+    [B, H, dk] (a decay a channel) or [B, H] (one a head); state
+    [B, H, dk, dv] float32, or [B, H / p, dk, p x dv] as ``pack_heads``
+    stores it (fewer rows of heads than ``q`` has say so); valid, fresh
+    [B] bool or None. A ``fresh`` row starts from zeros, whatever
+    ``state`` holds for it; a row that is not ``valid`` leaves its state
+    as it was. Returns (o [B, H, dv] float32, the new state, stored as
+    it came); ``o`` of a row that is not valid means nothing."""
     q, k, v, g, beta = (a.astype(F32) for a in (q, k, v, g, beta))
+    per_head = g.ndim == beta.ndim
+    if per_head:
+        # one decay a head: the same products, the gate broadcast over
+        # the channels where it meets them
+        g = g[..., None]
+    if state.shape[1] != q.shape[1]:
+        if _use_packed_kernel(state, g):
+            return kda_step_packed_kernel(q, k, v, g[..., 0], beta, state,
+                                          valid, fresh)
+        return _kda_step_packed(q, k, v, g, beta, state, valid, fresh)
     if _use_kernel(state):
+        if per_head:
+            g = jnp.broadcast_to(g, k.shape)
         return kda_step_kernel(q, k, v, g, beta, state, valid, fresh)
     return _kda_step_xla(q, k, v, g, beta, state, valid, fresh)
 
 
-def kda_chunked(q, k, v, g, beta, state, valid=None, chunk: int = 16):
-    """A row of T tokens. q, k, g [B, T, H, dk]; v [B, T, H, dv]; beta
-    [B, T, H]; state [B, H, dk, dv] float32; valid [B, T] bool or
-    None. Returns (o [B, T, H, dv] float32, the state after the row's
-    last valid token). T need not divide by ``chunk``: the row is
-    padded with invalid positions."""
+# a chunk's length by the gate (the module's docstring has the readings)
+_CHUNK_PER_CHANNEL, _CHUNK_PER_HEAD = 16, 64
+
+
+def kda_chunked(q, k, v, g, beta, state, valid=None,
+                chunk: Optional[int] = None):
+    """A row of T tokens. q, k [B, T, H, dk]; v [B, T, H, dv]; beta
+    [B, T, H]; g [B, T, H, dk] (a decay a channel) or [B, T, H] (one a
+    head); state [B, H, dk, dv] float32; valid [B, T] bool or None.
+    Returns (o [B, T, H, dv] float32, the state after the row's last
+    valid token). T need not divide by ``chunk`` (the gate's own where
+    None): the row is padded with invalid positions."""
     B, T, H, dk = q.shape
     q, k, v, g, beta = (a.astype(F32) for a in (q, k, v, g, beta))
+    per_head = g.ndim == beta.ndim
+    if per_head:
+        g = g[..., None]        # [B, T, H, 1]: broadcasts over channels
+    if chunk is None:
+        chunk = _CHUNK_PER_HEAD if per_head else _CHUNK_PER_CHANNEL
     g, beta = _masked(g, beta, valid)
     C = min(chunk, T)
     n = -(-T // C)
@@ -310,9 +362,17 @@ def kda_chunked(q, k, v, g, beta, state, valid=None, chunk: int = 16):
         # never an exponential of a positive number
         diff = G[:, :, :, None, :] - G[:, :, None, :, :]
         decay = jnp.exp(jnp.where(lower[:, :, None], diff, -jnp.inf))
-        k_decayed = k[:, :, None, :, :] * decay         # [B,H,C,C,dk]
-        kk = jnp.sum(k[:, :, :, None, :] * k_decayed, axis=-1)
-        qk = jnp.sum(q[:, :, :, None, :] * k_decayed, axis=-1)
+        if per_head:
+            # [B, H, C, C, 1]: no channel enters it, so the two
+            # interactions are matmuls under it
+            kk = jnp.einsum("bhtc,bhic->bhti", k, k,
+                            precision=_HI) * decay[..., 0]
+            qk = jnp.einsum("bhtc,bhic->bhti", q, k,
+                            precision=_HI) * decay[..., 0]
+        else:
+            k_decayed = k[:, :, None, :, :] * decay     # [B,H,C,C,dk]
+            kk = jnp.sum(k[:, :, :, None, :] * k_decayed, axis=-1)
+            qk = jnp.sum(q[:, :, :, None, :] * k_decayed, axis=-1)
         into = jnp.exp(G)                               # from S into t
         # (I + Diag(beta) kk_strict) U = Diag(beta) (V - (K.into) S)
         rhs = beta[..., None] * (v - jnp.einsum(
@@ -334,3 +394,203 @@ def kda_chunked(q, k, v, g, beta, state, valid=None, chunk: int = 16):
     # [n, B, H, C, dv] -> [B, T, H, dv]
     o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)
     return o.reshape((B, n * C) + o.shape[3:])[:, :T], state
+
+
+# ------------------------------------- heads side by side on the lanes
+#
+# A head whose values are no whole number of 128-lane tiles (192: one
+# and a half) is PADDED by the chip to the next tile in memory (256: a
+# third more bytes kept and moved, every step). ``p`` heads side by side
+# fill whole tiles where ``p * dv`` does (two heads of 192 are three
+# tiles): the state is then stored, and stepped, as [B, H / p, dk,
+# p * dv], lane l of group j belonging to head j * p + l // dv.
+
+def pack_heads(state, p: int):
+    """[B, H, dk, dv] -> [B, H / p, dk, p * dv]."""
+    B, H, dk, dv = state.shape
+    return jnp.swapaxes(state.reshape(B, H // p, p, dk, dv), 2, 3).reshape(
+        B, H // p, dk, p * dv)
+
+
+def unpack_heads(packed, p: int):
+    """[B, H / p, dk, p * dv] -> [B, H, dk, dv]: ``pack_heads``'s
+    inverse."""
+    B, G, dk, W = packed.shape
+    return jnp.swapaxes(packed.reshape(B, G, dk, p, W // p), 2, 3).reshape(
+        B, G * p, dk, W // p)
+
+
+def _kda_step_packed(q, k, v, g, beta, state, valid, fresh):
+    """``_kda_step_xla`` over a state stored ``pack_heads``-wise: the
+    same products, a head's row or column laid across its own lanes of
+    its group (small arrays: nothing of the state's size is made but
+    the new state). g [B, H, dk] or [B, H, 1]."""
+    B, G, dk, W = state.shape
+    p = q.shape[1] // G
+    dv = W // p
+    if fresh is not None:
+        state = jnp.where(fresh[:, None, None, None], 0.0, state)
+    g, beta = _masked(g, beta, valid)
+    head_of = jax.lax.broadcasted_iota(jnp.int32, (W,), 0) // dv
+
+    def row(a):
+        """[B, H] -> [B, G, W]: a head's number on each of its lanes."""
+        return jnp.repeat(a.reshape(B, G, p), dv, axis=-1)
+
+    def column(a):
+        """[B, H, dk] -> [B, G, dk, W]: a head's column down the
+        sublanes of each of its lanes (a select among the group's p
+        columns, which fuses into what reads it)."""
+        a = a.reshape(B, G, p, dk)
+        out = a[:, :, 0, :, None]
+        for j in range(1, p):
+            out = jnp.where(head_of == j, a[:, :, j, :, None], out)
+        return out
+    decay = jnp.exp(g)
+    decayed = state * (row(decay[..., 0])[:, :, None, :]
+                       if g.shape[-1] == 1 else column(decay))
+    k_col = column(k)
+    from_k = jnp.sum(decayed * k_col, axis=-2)
+    from_q = jnp.sum(decayed * column(q), axis=-2)
+    u = row(beta) * (v.reshape(B, G, W) - from_k)
+    o = from_q + u * row(jnp.sum(k * q, axis=-1))
+    return o.reshape(B, G * p, dv), decayed + k_col * u[..., None, :]
+
+
+# A packed state under ONE decay a head, on one TPU: the kernel above in
+# the packed layout. A visit is one slot's whole state ([G, dk, W]), a
+# loop step a group of p heads: each head's key (with the head's three
+# numbers after it: decay, beta, k . q) and query come in as lane rows,
+# are turned into sublane columns by one transpose each, and are laid
+# across the group's lanes by selects; the row after the key's columns
+# IS the head's decay on every lane, and so on.
+
+# a slot's state in one block, each way, double-buffered
+_PACKED_BLOCK_BYTES = 4 << 20
+
+
+def _packed_step_kernel(mode_ref, row_ref, o_row_ref, q_ref, k_ref, v_ref,
+                        s_ref, o_ref, s_out_ref, *, p, unroll):
+    del row_ref, o_row_ref                      # the index maps' alone
+    mode = mode_ref[pl.program_id(0)]
+    G, dk, W = s_ref.shape[1:]
+    dv = W // p
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+
+    def column(ref, h):
+        """Row ``h`` of ``ref`` [1, H, L] -> [L, 128] with entry [c, :]
+        = row[c]."""
+        row = ref[0, pl.ds(h, 1), :]
+        return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
+
+    def across(pieces, rows):
+        """p arrays whose lanes are all alike (rows ``rows`` of each) ->
+        [n, W]: head j's on its own lanes of the group."""
+        tiles = []
+        for t in range(W // _LANES):
+            first = t * _LANES // dv
+            cur = pieces[first][rows]
+            for j in range(first + 1, min(p - 1, (t * _LANES + _LANES - 1)
+                                          // dv) + 1):
+                cur = jnp.where(lane + t * _LANES >= j * dv,
+                                pieces[j][rows], cur)
+            tiles.append(cur)
+        return jnp.concatenate(tiles, axis=1)
+
+    @pl.when(mode == _SKIP)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(mode == _COPY)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        s_out_ref[...] = s_ref[...]
+
+    @pl.when((mode == _STEP) | (mode == _FRESH))
+    def _():
+        def group(g):
+            state = jnp.where(mode == _FRESH, 0.0, s_ref[0, g])
+            ks = [column(k_ref, g * p + j) for j in range(p)]
+            qs = [column(q_ref, g * p + j) for j in range(p)]
+            k_col = across(ks, slice(0, dk))
+            decayed = state * across(ks, slice(dk, dk + 1))
+            from_k = jnp.sum(decayed * k_col, axis=0, keepdims=True)
+            from_q = jnp.sum(decayed * across(qs, slice(0, dk)), axis=0,
+                             keepdims=True)
+            u = across(ks, slice(dk + 1, dk + 2)) * (
+                v_ref[0, pl.ds(g, 1), :] - from_k)
+            o_ref[0, pl.ds(g, 1), :] = from_q + u * across(
+                ks, slice(dk + 2, dk + 3))
+            s_out_ref[0, g] = decayed + k_col * u
+
+        def groups(i, carry):
+            for j in range(unroll):
+                group(i * unroll + j)
+            return carry
+
+        jax.lax.fori_loop(0, G // unroll, groups, 0)
+
+
+def kda_step_packed_kernel(q, k, v, g, beta, state, valid=None, fresh=None,
+                           *, unroll=None, interpret=False):
+    """``kda_step`` on a state stored ``pack_heads``-wise under ONE
+    decay a head (g [B, H]) as one Pallas TPU kernel over the visits of
+    ``kda_step_kernel`` (riding rows first, the others skipped); float32
+    operands. The state is aliased to the new state. A row that is not
+    ``valid`` keeps its state bit for bit and reads out zeros."""
+    B, G, dk, W = state.shape
+    H = q.shape[1]
+    p = H // G
+    if unroll is None:
+        unroll = next(u for u in (3, 2, 1) if G % u == 0)
+    assert G % unroll == 0 and W % _LANES == 0, (G, unroll, W)
+    if valid is None:
+        valid = jnp.ones((B,), bool)
+    if fresh is None:
+        fresh = jnp.zeros((B,), bool)
+    mode, row, o_row = _visits(valid, fresh)
+
+    def padded(a):
+        return jnp.pad(a, ((0, 0), (0, 0), (0, -a.shape[-1] % _LANES)))
+    # a head's key, then its decay, its beta and k . q
+    k_aux = padded(jnp.concatenate(
+        [k, jnp.exp(g)[..., None], beta[..., None],
+         jnp.sum(k * q, axis=-1, keepdims=True)], axis=-1))
+    q = padded(q)
+
+    def block(*shape):
+        return pl.BlockSpec((1,) + shape, lambda i, mode, row, o_row:
+                            (row[i],) + (0,) * len(shape))
+    o, state = pl.pallas_call(
+        functools.partial(_packed_step_kernel, p=p, unroll=unroll),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B,),
+            in_specs=[block(H, q.shape[-1]), block(H, k_aux.shape[-1]),
+                      block(G, W), block(G, dk, W)],
+            out_specs=[
+                pl.BlockSpec((1, G, W), lambda i, mode, row, o_row:
+                             (o_row[i], 0, 0)),
+                block(G, dk, W)]),
+        out_shape=[jax.ShapeDtypeStruct((B, G, W), F32),
+                   jax.ShapeDtypeStruct(state.shape, F32)],
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            # in order: a repeated block index is a visit skipped
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=4 * G * dk * W * 4 + (8 << 20)),
+        interpret=interpret, name="kda_step_packed",
+    )(mode, row, o_row, q, k_aux, v.reshape(B, G, W), state)
+    return o.reshape(B, H, W // p), state
+
+
+def _use_packed_kernel(state, g) -> bool:
+    """Whether ``kda_step`` on a packed ``state`` [B, H / p, dk, p x dv]
+    is the kernel: ONE decay a head (g [B, H, 1]: no model packs a state
+    under a decay a channel), a float32 state whose packed values are
+    whole 128-lane tiles and whose keys are whole sublane tiles, a
+    slot's state within a block, and one TPU outside any multi-device
+    mesh."""
+    G, dk, W = state.shape[1:]
+    return (g.shape[-1] == 1 and state.dtype == F32 and W % _LANES == 0
+            and dk % 8 == 0 and G * dk * W * 4 <= _PACKED_BLOCK_BYTES
+            and _on_one_tpu())

@@ -22,7 +22,12 @@ test_ouro_family.py (the Ouro family: the configuration whole against
 its published copy, the program against the reference and the margin
 rule against the reference's controls, byte counts with the weights
 once a pass, the two readers on a hand-made joined trace with a nested
-loop, the cell on chat-sat as it stands, the rehearsal cell),
+loop, the cell on chat-sat as it stands, the rehearsal cell) and
+test_olmo_hybrid_family.py (the Olmo-Hybrid family: the configuration
+against its published copy, the program against the reference and the
+margin rule against the reference's controls, byte counts by kind of
+layer, the four new readers and the older ones on a hand-made joined
+trace, the cell on sample-sat as it stands, the rehearsal cell),
 collected here so that the suite the driver runs guards them.
 `python -m pytest benchmarks/tests` still runs them where they live."""
 import pytest
@@ -34,7 +39,8 @@ _FILES = ("benchmarks.tests.test_families",
           "benchmarks.tests.test_axk1_family",
           "benchmarks.tests.test_kimi_linear_family",
           "benchmarks.tests.test_mellum2_family",
-          "benchmarks.tests.test_ouro_family")
+          "benchmarks.tests.test_ouro_family",
+          "benchmarks.tests.test_olmo_hybrid_family")
 pytest.register_assert_rewrite(*_FILES)
 
 from benchmarks.tests.test_families import *          # noqa: E402,F401,F403
@@ -45,6 +51,7 @@ from benchmarks.tests.test_axk1_family import *       # noqa: E402,F401,F403
 from benchmarks.tests.test_kimi_linear_family import *  # noqa: E402,F401,F403
 from benchmarks.tests.test_mellum2_family import *    # noqa: E402,F401,F403
 from benchmarks.tests.test_ouro_family import *       # noqa: E402,F401,F403
+from benchmarks.tests.test_olmo_hybrid_family import *  # noqa: E402,F401,F403
 
 # Recorded without tier-1's low-optimisation XLA flags (tests/conftest.py),
 # under which the CPU draws a normal's last bits differently: the digests
@@ -81,6 +88,12 @@ del test_seeded_weights_are_the_parents_bit_for_bit    # noqa: F821
 # four dispatch readers pins them TWELVE before the file's end, and
 # benchmarks/tests/test_ouro_family.py::
 # test_the_cell_and_chat_sat_as_it_stands pins PR 46's.
+# PR 49 appended a configuration, a cell and four readers, and the cell
+# to the lists of thirteen older metrics: every older case (PR 46's pin
+# among them) runs against the file less those too, the case of the
+# four dispatch readers pins them SIXTEEN before the file's end, and
+# benchmarks/tests/test_olmo_hybrid_family.py::
+# test_the_cell_and_sample_sat_as_it_stands pins PR 49's.
 _DISPATCH = ("dispatch_prefill_call_ms", "dispatch_decode_step_ms",
              "dispatch_prefill_share", "dispatch_prefill_call_ms.open")
 _PR39 = ("state_peak_share", "linear_state_roofline.by_kind",
@@ -91,9 +104,12 @@ _PR42 = ("decode_sliding_attn_ms", "decode_full_attn_ms",
 _PR42_CELL, _PR42_CONFIG = "mellum2-d8.longdoc-sat", "mellum2-12b-a2.5b-d8"
 _PR46 = ("loop_step_roofline", "loop_attn_share")
 _PR46_CELL, _PR46_CONFIG = "ouro-2.6b.chat-sat", "ouro-2.6b"
+_PR49 = ("hybrid_step_roofline", "prefill_linear_attn_share",
+         "state_kv_bytes_ratio", "kda_step_packed_roofline")
+_PR49_CELL, _PR49_CONFIG = "olmo-hybrid-d16.sample-sat", "olmo-hybrid-7b-d16"
 _SAT = ["mistral7b-d16.chat-sat", "olmoe-d8.chat-sat",
         "solar-open2-d4.doc-sat", "axk1-d5.longdoc-sat",
-        "kimi-linear-d8.gen-sat", _PR42_CELL, _PR46_CELL]
+        "kimi-linear-d8.gen-sat", _PR42_CELL, _PR46_CELL, _PR49_CELL]
 
 
 def _less_a_pr(bench, config, cell, readers):
@@ -111,9 +127,14 @@ def _less_a_pr(bench, config, cell, readers):
     return bench
 
 
+def _less_pr49(bench):
+    """BENCHMARK.json as PR 48 left it."""
+    return _less_a_pr(bench, _PR49_CONFIG, _PR49_CELL, _PR49)
+
+
 def _less_pr46(bench):
     """BENCHMARK.json as PR 45 left it."""
-    return _less_a_pr(bench, _PR46_CONFIG, _PR46_CELL, _PR46)
+    return _less_a_pr(_less_pr49(bench), _PR46_CONFIG, _PR46_CELL, _PR46)
 
 
 def _less_pr42(bench):
@@ -157,12 +178,27 @@ test_the_cell_and_longdoc_sat_as_it_stands = _as_pr45_left_it(
     test_the_cell_and_longdoc_sat_as_it_stands)         # noqa: F821
 
 
+def _as_pr48_left_it(case):
+    def test(monkeypatch):
+        from benchmarks import common
+        bench = _less_pr49(common.load_benchmark())
+        monkeypatch.setattr(common, "load_benchmark", lambda: bench)
+        case()
+    test.__name__ = case.__name__
+    test.__doc__ = case.__doc__
+    return test
+
+
+test_the_cell_and_chat_sat_as_it_stands = _as_pr48_left_it(
+    test_the_cell_and_chat_sat_as_it_stands)            # noqa: F821
+
+
 @pytest.mark.parametrize("name", _DISPATCH)
 def test_dispatch_readers_are_appended_to_the_benchmark(name):
     from benchmarks import common
     bench = common.load_benchmark()
-    assert tuple(m["name"] for m in bench["per_layer"][-16:]) == \
-        _DISPATCH + _PR39 + _PR42 + _PR46
+    assert tuple(m["name"] for m in bench["per_layer"][-20:]) == \
+        _DISPATCH + _PR39 + _PR42 + _PR46 + _PR49
     m = common.find_named(bench["per_layer"], name, "metric")
     want = {"name": name, "unit": "ms", "better": "lower",
             "source": "device_trace", "layer": "model step",

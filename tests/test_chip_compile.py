@@ -134,6 +134,26 @@ def test_kda_step_kernel_compiles(one_chip, B, H):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
 
 
+# the recurrent state of olmo-hybrid-d16.sample-sat: 96 slots of 30
+# heads of 96 x 192 float32 stored two side by side, [96, 15, 96, 384];
+# the packed one-token kernel updates it where it lies
+def test_kda_step_packed_kernel_compiles(one_chip):
+    from ray_tpu.ops import linear_attention as la
+    f32, (B, H, dk, dv, p) = jnp.float32, (96, 30, 96, 192, 2)
+    shape = (B, H // p, dk, p * dv)
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in
+            [((B, H, dk), f32)] * 2 + [((B, H, dv), f32)]
+            + [((B, H), f32)] * 2 + [(shape, f32), ((B,), jnp.bool_),
+                                     ((B,), jnp.bool_)]]
+    compiled = jax.jit(la.kda_step_packed_kernel, donate_argnums=5).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "output_to_operand_aliasing={{1}: (6, {})}" in text
+    assert not _state_passes(text, shape)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
 def _state_passes(text, shape):
     """Lines of the compiled program, OUTSIDE the delta-rule kernel's
     custom call, that produce a float32 tensor of one layer's whole
@@ -968,3 +988,108 @@ def test_looped_decode_attends_in_one_kernel_a_layer(one_chip,
     held = (mem.argument_size_in_bytes + temp + mem.output_size_in_bytes
             - mem.alias_size_in_bytes)
     assert held < 15.75 * 2 ** 30, held
+
+
+# ---------------------------------------------------------------
+# A dense hybrid whose shapes are no whole tiles at its cell's widths
+# and ITS slots (Olmo-Hybrid: 30 delta-rule heads of 96 x 192 over 96
+# SLOTS, 30 K/V heads of 128 in 769 pages of 64, a page table 16 wide;
+# one period of four layers keeps the compile short). A page stores 32
+# head rows and the state two heads side by side ([96, 15, 96, 384]):
+# declared so, both stay where they lie, the chip keeps what
+# ``state_bytes_per_slot`` and ``kv_pool_page_bytes`` count, and on one
+# TPU the full layer decodes through the paged-decode kernel (30 heads
+# alone fail its rule of whole sublane tiles) and each delta-rule layer
+# steps through the packed kernel.
+
+OLMO_SLOTS, OLMO_PAGES = 96, 769
+
+
+def _dense_hybrid_step(name, one_chip):
+    from ray_tpu.models.kv_cache import init_kv_pool
+    from ray_tpu.models.olmo_hybrid import OlmoHybrid, olmo_hybrid_7b
+    from ray_tpu.serve import step_programs
+    S = OLMO_SLOTS
+    cfg = olmo_hybrid_7b(n_layers=4, max_seq_len=1024,
+                         param_dtype=jnp.bfloat16)
+    model = OlmoHybrid(cfg)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                           sharding=one_chip), tree)
+    params = placed({"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]})
+    pages = placed(jax.eval_shape(
+        lambda: init_kv_pool(cfg, OLMO_PAGES, PAGE, n_slots=S)))
+    i32 = jnp.int32
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    table = ((S, cfg.max_seq_len // PAGE), i32)
+    if name == "decode":
+        fn = step_programs._jit_decode(model, 0.0, 128, S, False, None)
+        rest = [table, ((S,), i32), ((S,), i32),
+                (key.shape, key.dtype), ((), i32)]
+    else:
+        fn = step_programs._jit_prefill(model, 0.0, 4, False, None)
+        rest = [((4, 256), i32), ((4,), i32), ((4,), i32),
+                ((4, table[0][1]), i32), (key.shape, key.dtype),
+                ((4,), i32)]
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in rest]
+    return cfg, fn.lower(params, pages, *rest).compile()
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_a_dense_hybrid_keeps_its_state_and_pages_as_the_chip_tiles_them(
+        one_chip, monkeypatch, name):
+    from ray_tpu.models.kv_cache import (kv_pool_page_bytes,
+                                         state_bytes_per_slot)
+    from ray_tpu.ops import linear_attention as la
+    from ray_tpu.ops import paged_decode_attention as pd
+    from ray_tpu.serve import step_programs
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
+    monkeypatch.setattr(la, "_on_one_tpu", lambda: True)
+    for builder in ("_jit_decode", "_jit_prefill"):
+        monkeypatch.setattr(step_programs, builder,
+                            getattr(step_programs, builder).__wrapped__)
+    cfg, compiled = _dense_hybrid_step(name, one_chip)
+    text = compiled.as_text()
+    assert cfg.recurrent_state_shape == (15, 96, 384)
+    state = r"f32\[96,15,96,384\]"
+    entry = re.search(state + r"(\{[^}]*\}) parameter", text)
+    assert entry and entry.group(1).startswith("{3,2,1,0"), entry
+    copies = re.findall(r"= " + state + r"(?:\{[^}]*\})? copy\(", text)
+    assert not copies, f"{len(copies)} whole-state copies in {name}"
+    assert cfg.kv_page_heads == 32
+    shape = (OLMO_PAGES, PAGE, 32, 128)
+    pool = r"bf16\[%s\]" % ",".join(str(d) for d in shape)
+    entry = re.search(pool + r"(\{[^}]*\}) parameter", text)
+    assert entry and entry.group(1).startswith("{3,2,1,0"), entry
+    assert not _pool_copies(text, shape)
+    # what the chip keeps is what load_report() counts: weights apart,
+    # the program's arguments are the pool and the slots' state
+    mem = compiled.memory_analysis()
+    kept = (OLMO_PAGES * kv_pool_page_bytes(cfg, PAGE)
+            + OLMO_SLOTS * state_bytes_per_slot(cfg))
+    assert mem.alias_size_in_bytes == pytest.approx(kept, rel=1e-3)
+    one_state = OLMO_SLOTS * 15 * 96 * 384 * 4      # a layer's, 203 MiB
+    calls = [c for c in re.findall(
+        r"custom-call\([^\n]*attn_scores/[^\n]*paged_decode[^\n]*", text)
+        if 'custom_call_target="tpu_custom_call"' in c]
+    steps = re.findall(
+        r"custom-call\([^\n]*kda_recurrence/kda_step_packed[^\n]*", text)
+    if name == "decode":
+        assert len(calls) == 1, len(calls)      # the one full layer
+        assert "kv_gather" not in text
+        # three delta-rule layers, each ONE kernel over its state in
+        # place, and nothing else over a whole state
+        assert len(steps) == 3, len(steps)
+        assert all("output_to_operand_aliasing={{1}: (6, {})}" in c
+                   for c in steps), steps[0][:300]
+        passes = _state_passes(text, (OLMO_SLOTS, 15, 96, 384))
+        assert not passes, (len(passes), passes[:4])
+        assert mem.temp_size_in_bytes < one_state, mem.temp_size_in_bytes
+    else:
+        assert not calls and not steps
+        assert mem.temp_size_in_bytes < 4 * one_state, mem.temp_size_in_bytes

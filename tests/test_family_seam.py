@@ -18,7 +18,8 @@ from ray_tpu.models.kv_cache import (KIND_KV, KIND_LATENT, KIND_RECURRENT,
 SERVE = pathlib.Path(__file__).resolve().parent.parent / "ray_tpu" / "serve"
 # the family modules serve/ may import: sampling and the tiny default
 # (llama), the mixture's counters every mixture family shares (mixtral)
-_FAMILY_MODULES = {"axk1", "kimi_linear", "mellum", "ouro", "solar_open2"}
+_FAMILY_MODULES = {"axk1", "kimi_linear", "mellum", "olmo_hybrid", "ouro",
+                   "solar_open2"}
 
 
 def _trees():
@@ -74,6 +75,7 @@ def _families():
     from ray_tpu.models.llama import Llama, llama_tiny
     from ray_tpu.models.mellum import Mellum, mellum_tiny
     from ray_tpu.models.mixtral import Mixtral, mixtral_tiny
+    from ray_tpu.models.olmo_hybrid import OlmoHybrid, olmo_hybrid_tiny
     from ray_tpu.models.ouro import Ouro, ouro_tiny
     from ray_tpu.models.solar_open2 import SolarOpen2, solar_open2_tiny
     return {"llama": (llama_tiny, Llama, "feed_forward"),
@@ -81,12 +83,13 @@ def _families():
             "axk1": (axk1_tiny, AXK1, None),
             "kimi_linear": (kimi_linear_tiny, KimiLinear, None),
             "mellum": (mellum_tiny, Mellum, None),
+            "olmo_hybrid": (olmo_hybrid_tiny, OlmoHybrid, None),
             "ouro": (ouro_tiny, Ouro, None),
             "solar_open2": (solar_open2_tiny, SolarOpen2, None)}
 
 
-FAMILIES = ("llama", "mixtral", "axk1", "kimi_linear", "mellum", "ouro",
-            "solar_open2")
+FAMILIES = ("llama", "mixtral", "axk1", "kimi_linear", "mellum",
+            "olmo_hybrid", "ouro", "solar_open2")
 
 
 @pytest.mark.parametrize("family", FAMILIES)

@@ -28,25 +28,41 @@ from ray_tpu.ops.linear_attention import kda_chunked, kda_step
 
 RTOL, ATOL = 1e-4, 2e-5
 B, H, D = 2, 3, 16
+# The two gates, each at its model's kind of head: a decay a CHANNEL
+# over square heads (Kimi Delta Attention: 128 x 128 served), ONE decay
+# a head over heads whose values are twice as wide as their keys and
+# neither a multiple of the other's tile (Gated DeltaNet: 96 x 192
+# served).
+GATES = {"channel": (D, D), "head": (12, 24)}
+BOTH = pytest.mark.parametrize("gate", sorted(GATES))
 
 
-def _inputs(T, seed=0, hard=True):
-    """Unit keys, values of order 1, beta up to 2 and per-channel
-    log-decays from -0.001 down to -20 a step where ``hard``."""
+def _inputs(T, seed=0, hard=True, gate="channel"):
+    """Unit keys, values of order 1, beta up to 2 and log-decays (a
+    channel, or a head) from -0.001 down to -20 a step where ``hard``."""
+    dk, dv = GATES[gate]
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    q = l2norm(jax.random.normal(ks[0], (B, T, H, D))) * D ** -0.5
-    k = l2norm(jax.random.normal(ks[1], (B, T, H, D)))
-    v = jax.random.normal(ks[2], (B, T, H, D))
-    g = -jnp.exp(jax.random.uniform(ks[3], (B, T, H, D), minval=-7.0,
+    q = l2norm(jax.random.normal(ks[0], (B, T, H, dk))) * dk ** -0.5
+    k = l2norm(jax.random.normal(ks[1], (B, T, H, dk)))
+    v = jax.random.normal(ks[2], (B, T, H, dv))
+    shape = (B, T, H, dk) if gate == "channel" else (B, T, H)
+    g = -jnp.exp(jax.random.uniform(ks[3], shape, minval=-7.0,
                                     maxval=3.0 if hard else -3.0))
     beta = 2.0 * jax.nn.sigmoid(3.0 * jax.random.normal(ks[4], (B, T, H)))
-    state = jax.random.normal(ks[5], (B, H, D, D))
+    state = jax.random.normal(ks[5], (B, H, dk, dv))
     return q, k, v, g, beta, state
 
 
-def _scan(*args):
+def _a_channel(g, k):
+    """A gate a head as the per-channel form and the scan take it:
+    broadcast over the key's channels."""
+    return g if g.ndim == k.ndim else jnp.broadcast_to(g[..., None],
+                                                       k.shape)
+
+
+def _scan(q, k, v, g, beta, state):
     with jax.default_matmul_precision("highest"):
-        return delta_rule_scan(*args)
+        return delta_rule_scan(q, k, v, _a_channel(g, k), beta, state)
 
 
 def _close(got, want):
@@ -54,15 +70,17 @@ def _close(got, want):
                                rtol=RTOL, atol=ATOL)
 
 
+@BOTH
 @pytest.mark.parametrize("T,chunk", [(150, 64), (64, 64), (40, 16),
-                                     (7, 64), (129, 32)])
-def test_chunked_matches_the_scan(T, chunk):
+                                     (7, 64), (129, 32), (150, None)])
+def test_chunked_matches_the_scan(T, chunk, gate):
     """T not a multiple of the chunk, hard decays (g down to -20 a
     step: exp(+20 x 64) would overflow a factored form), beta to 2, a
-    state that is not zero."""
-    q, k, v, g, beta, state = _inputs(T)
+    state that is not zero; the gate's own chunk where none is given."""
+    q, k, v, g, beta, state = _inputs(T, gate=gate)
     if T >= 40:
-        assert float(g.min()) < -19.0 and float(beta.max()) > 1.99
+        assert float(g.min()) < (-19.0 if gate == "channel" else -15.0)
+        assert float(beta.max()) > 1.99
     o, s = jax.jit(kda_chunked, static_argnames="chunk")(
         q, k, v, g, beta, state, chunk=chunk)
     want_o, want_s = _scan(q, k, v, g, beta, state)
@@ -71,9 +89,10 @@ def test_chunked_matches_the_scan(T, chunk):
     _close(s, want_s)
 
 
-def test_step_looped_matches_the_scan():
+@BOTH
+def test_step_looped_matches_the_scan(gate):
     T = 70
-    q, k, v, g, beta, state = _inputs(T, seed=1)
+    q, k, v, g, beta, state = _inputs(T, seed=1, gate=gate)
     step = jax.jit(kda_step)
     outs, s = [], state
     for t in range(T):
@@ -84,12 +103,14 @@ def test_step_looped_matches_the_scan():
     _close(s, want_s)
 
 
-def test_padding_inside_a_row_moves_nothing():
+@BOTH
+def test_padding_inside_a_row_moves_nothing(gate):
     """Rows of 100 and 37 real positions in a call of 150: the outputs
     at the real positions and the state left behind are those of the
-    rows alone; a step that is not valid leaves the state as it was."""
+    rows alone; a step that is not valid leaves the state as it was,
+    and one that is ``fresh`` starts from zeros whatever it held."""
     T, real = 150, (100, 37)
-    q, k, v, g, beta, state = _inputs(T, seed=2)
+    q, k, v, g, beta, state = _inputs(T, seed=2, gate=gate)
     valid = jnp.arange(T)[None] < jnp.asarray(real)[:, None]
     o, s = kda_chunked(q, k, v, g, beta, state, valid, chunk=64)
     for b, n in enumerate(real):
@@ -101,13 +122,20 @@ def test_padding_inside_a_row_moves_nothing():
                       state, jnp.asarray([True, False]))
     assert (np.asarray(s1[1]) == np.asarray(state[1])).all()
     assert not (np.asarray(s1[0]) == np.asarray(state[0])).all()
+    one = tuple(a[:, 0] for a in (q, k, v, g, beta))
+    held = state.at[0].set(jnp.inf)         # what a slot held is unread
+    o2, s2 = kda_step(*one, held, None, jnp.asarray([True, False]))
+    o0, s0 = kda_step(*one, state.at[0].set(0.0))
+    assert (np.asarray(s2) == np.asarray(s0)).all()
+    assert (np.asarray(o2) == np.asarray(o0)).all()
 
 
-def test_two_calls_with_the_state_handed_over():
+@BOTH
+def test_two_calls_with_the_state_handed_over(gate):
     """A row in two calls (two engine rounds), then decode steps: the
     state carries everything."""
     T, cut = 120, 72
-    q, k, v, g, beta, state = _inputs(T + 3, seed=3)
+    q, k, v, g, beta, state = _inputs(T + 3, seed=3, gate=gate)
     first = tuple(a[:, :cut] for a in (q, k, v, g, beta))
     second = tuple(a[:, cut:T] for a in (q, k, v, g, beta))
     o1, s1 = kda_chunked(*first, state, chunk=64)
@@ -121,12 +149,13 @@ def test_two_calls_with_the_state_handed_over():
     _close(s, want_s)
 
 
-def test_a_bfloat16_state_would_fail():
+@BOTH
+def test_a_bfloat16_state_would_fail(gate):
     """The same two calls with the state rounded to bfloat16 between
     them: outside the tolerance by far (mild decays, so the first
     call's state still matters in the second)."""
     T, cut = 120, 72
-    q, k, v, g, beta, state = _inputs(T, seed=3, hard=False)
+    q, k, v, g, beta, state = _inputs(T, seed=3, hard=False, gate=gate)
     first = tuple(a[:, :cut] for a in (q, k, v, g, beta))
     second = tuple(a[:, cut:] for a in (q, k, v, g, beta))
     _, s1 = kda_chunked(*first, state, chunk=64)
@@ -135,6 +164,70 @@ def test_a_bfloat16_state_would_fail():
     want_o, _ = _scan(q, k, v, g, beta, state)
     gap = np.abs(np.asarray(o2) - np.asarray(want_o[:, cut:])).max()
     assert gap > 50 * ATOL, gap
+
+
+@pytest.mark.parametrize("T,chunk", [(150, 64), (40, 16), (1, None)])
+def test_a_gate_a_head_is_the_per_channel_form_fed_it_broadcast(T, chunk):
+    """ONE decay a head through its own form (matmuls under a [C, C]
+    mask; a step) against the per-channel form handed the same gate
+    repeated over the 12 channels: one recurrence, two programs."""
+    q, k, v, g, beta, state = _inputs(T, seed=7, gate="head")
+    wide = _a_channel(g, k)
+    if T == 1:
+        one = tuple(a[:, 0] for a in (q, k, v, g, beta))
+        got = kda_step(*one, state)
+        want = kda_step(*one[:3], wide[:, 0], one[4], state)
+    else:
+        got = kda_chunked(q, k, v, g, beta, state, chunk=chunk)
+        want = kda_chunked(q, k, v, wide, beta, state, chunk=chunk)
+    _close(got[0], want[0])
+    _close(got[1], want[1])
+
+
+@BOTH
+@pytest.mark.parametrize("p", [1, 3])
+def test_a_packed_state_steps_as_the_state_a_head_at_a_time(gate, p):
+    """``p`` heads side by side on the lanes ([B, H / p, dk, p x dv], as
+    a state whose values are no whole lane tile is stored): packing is
+    undone by unpacking, and a step over the packed state, with a row
+    that rides nothing and a row that starts from zeros whatever it
+    held, is the step over the heads one at a time, packed."""
+    q, k, v, g, beta, state = _inputs(1, seed=8, gate=gate)
+    one = tuple(a[:, 0] for a in (q, k, v, g, beta))
+    packed = la.pack_heads(state, p)
+    dk, dv = GATES[gate]
+    assert packed.shape == (B, H // p, dk, p * dv)
+    assert (np.asarray(la.unpack_heads(packed, p))
+            == np.asarray(state)).all()
+    for valid, fresh in [(None, None),
+                         (jnp.asarray([True, False]),
+                          jnp.asarray([True, False]))]:
+        held = state if fresh is None else state.at[0].set(jnp.inf)
+        want_o, want_s = kda_step(*one, held, valid, fresh)
+        o, s = jax.jit(kda_step)(*one, la.pack_heads(held, p), valid, fresh)
+        rows = slice(None) if valid is None else np.asarray(valid)
+        _close(o[rows], want_o[rows])
+        _close(la.unpack_heads(s, p), want_s)
+    assert (np.asarray(s[1]) == np.asarray(packed[1])).all()
+
+
+def test_the_per_head_chunk_holds_no_product_over_channels():
+    """What the gate a head buys: the chunked program makes no array of
+    [C, C, dk] (the per-channel form's decay products on the vector
+    unit), and its own chunk is 64 where that form's is 16."""
+    def largest(gate):
+        args = _inputs(256, gate=gate)
+        jaxpr = jax.make_jaxpr(kda_chunked)(*args)
+        scan, = (eq for eq in jaxpr.jaxpr.eqns
+                 if eq.primitive.name == "scan")
+        return max(int(np.prod(v.aval.shape))
+                   for e in scan.params["jaxpr"].jaxpr.eqns
+                   for v in e.outvars)
+    dk, dv = GATES["head"]
+    assert la._CHUNK_PER_HEAD == 64 and la._CHUNK_PER_CHANNEL == 16
+    # the scan's body: nothing larger than a chunk's [C, C] or [C, dv]
+    assert largest("head") <= B * H * 64 * max(64, dv)
+    assert largest("channel") == B * H * 16 * 16 * D
 
 
 # ------------------------------------------------ the one-token kernel
@@ -233,6 +326,68 @@ def test_eight_kernel_steps_match_the_chunked_form():
     _close(s, scan_s)
 
 
+def _packed_inputs(n, H, dk, dv, seed=9):
+    """One token for n slots of H heads of dk x dv under ONE decay a
+    head: unit keys, beta up to 2, log-decays from -0.001 down to -20,
+    a state that is not zero."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = l2norm(jax.random.normal(ks[0], (n, H, dk))) * dk ** -0.5
+    k = l2norm(jax.random.normal(ks[1], (n, H, dk)))
+    v = jax.random.normal(ks[2], (n, H, dv))
+    g = -jnp.exp(jax.random.uniform(ks[3], (n, H), minval=-7.0, maxval=3.0))
+    beta = 2.0 * jax.nn.sigmoid(3.0 * jax.random.normal(ks[4], (n, H)))
+    return (q, k, v, g, beta), jax.random.normal(ks[5], (n, H, dk, dv))
+
+
+# (heads, dk, dv, heads side by side, groups a loop step): the served
+# 96 x 192 two by two (a lane tile shared by both heads of a group) at
+# the rule's own unroll and at one; four heads of 32 to a single tile;
+# two heads of whole tiles each; keys of a whole lane tile (the head's
+# three numbers then lie in a second tile of the key's row)
+@pytest.mark.parametrize("H,dk,dv,p,unroll", [
+    (6, 96, 192, 2, None), (6, 96, 192, 2, 1), (8, 16, 32, 4, 2),
+    (4, 8, 128, 2, None), (2, 128, 64, 2, None)])
+def test_packed_kernel_matches_the_step(H, dk, dv, p, unroll):
+    """Five slots: two plain, one that STARTS a request (from zeros,
+    whatever the slot held), one that rides nothing (its state comes
+    back bit for bit and its read-out is zeros), one plain after it."""
+    one, state = _packed_inputs(5, H, dk, dv)
+    assert float(one[4].max()) > 1.9 and float(one[3].min()) < -4.0
+    state = state.at[2, 0].set(jnp.inf)     # what a slot held is unread
+    valid = jnp.asarray([True, True, True, False, True])
+    fresh = jnp.asarray([False, False, True, False, False])
+    packed = la.pack_heads(state, p)
+    o, s = la.kda_step_packed_kernel(*one, packed, valid, fresh,
+                                     unroll=unroll, interpret=True)
+    want_o, want_s = kda_step(*one, state, valid, fresh)
+    rides = np.asarray(valid)
+    _close(o[rides], want_o[rides])
+    _close(la.unpack_heads(s, p), want_s)
+    assert (np.asarray(s[3]) == np.asarray(packed[3])).all()
+    assert not np.asarray(o[3]).any()
+    # the jax.numpy form over the same packed state: one recurrence
+    xla_o, xla_s = la._kda_step_packed(*one[:3], one[3][..., None], one[4],
+                                       packed, valid, fresh)
+    _close(o[rides], xla_o[rides])
+    _close(s, xla_s)
+
+
+@pytest.mark.parametrize("riding", [(), (1,), (0, 2)])
+def test_packed_kernel_visits_the_riding_rows_whichever_they_are(riding):
+    one, state = _packed_inputs(3, 4, 16, 64, seed=10)
+    valid = jnp.zeros((3,), bool).at[jnp.asarray(riding, int)].set(True)
+    packed = la.pack_heads(state, 2)
+    o, s = la.kda_step_packed_kernel(*one, packed, valid, None,
+                                     interpret=True)
+    want_o, want_s = kda_step(*one, state, valid, None)
+    for row in range(3):
+        if row in riding:
+            _close(o[row], want_o[row])
+            _close(la.unpack_heads(s, 2)[row], want_s[row])
+        else:
+            assert (np.asarray(s[row]) == np.asarray(packed[row])).all()
+
+
 def test_the_kernel_is_chosen_by_what_the_code_can_observe(monkeypatch):
     """On the CPU ``kda_step`` is the ``jax.numpy`` form; on one TPU it
     is the kernel where the heads tile, and only there."""
@@ -241,6 +396,22 @@ def test_the_kernel_is_chosen_by_what_the_code_can_observe(monkeypatch):
     monkeypatch.setattr(la, "_on_one_tpu", lambda: True)
     assert la._use_kernel(tiles)
     assert not la._use_kernel(jnp.zeros((1, 2, 16, 16), jnp.float32))
+    # one and a half lane tiles: 96 x 192 a head at a time keeps the
+    # XLA form; two heads side by side under ONE decay a head get the
+    # packed kernel, under a decay a channel, in another type, at keys
+    # of no whole sublane tile or past a block they do not
+    assert not la._use_kernel(jnp.zeros((1, 30, 96, 192), jnp.float32))
+    served = jax.ShapeDtypeStruct((1, 15, 96, 384), jnp.float32)
+    a_head = jax.ShapeDtypeStruct((1, 30, 1), jnp.float32)
+    assert la._use_packed_kernel(served, a_head)
+    assert not la._use_packed_kernel(
+        served, jax.ShapeDtypeStruct((1, 30, 96), jnp.float32))
+    for shape, dtype in (((1, 15, 96, 384), jnp.bfloat16),
+                         ((1, 15, 96, 320), jnp.float32),
+                         ((1, 15, 12, 384), jnp.float32),
+                         ((1, 30, 96, 384), jnp.float32)):
+        assert not la._use_packed_kernel(
+            jax.ShapeDtypeStruct(shape, dtype), a_head), (shape, dtype)
     assert not la._use_kernel(tiles.astype(jnp.bfloat16))
     for H in (3, 8, 32, 40, 64, 96):
         plan = la.step_plan(H, 128, 128)
