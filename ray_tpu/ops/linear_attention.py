@@ -44,9 +44,39 @@ to the per-channel form fed that gate broadcast over the channels:
   decides is the backend, the ambient mesh, the gate's and the state's
   shape and type, never a flag.
 - ``kda_chunked``: a row of T tokens in chunks (a prefill chunk). Inside
-  a chunk the T x T interactions are solved at once (the WY/UT form: a
+  a chunk the C x C interactions are solved at once (the WY/UT form: a
   unit lower-triangular system in the writes ``u``); the state is
   handed from chunk to chunk by ``lax.scan``.
+
+HOW A CHUNK'S SYSTEM IS SOLVED (``unit_lower_inverse``, PR 50). The
+system ``I + Diag(beta) strict(k k^T under the decays)`` of a chunk's
+B x H heads is INVERTED by forward substitution, a row of the inverse a
+step, every head's system at once with the batch on the lanes (a row's
+step is then well under a microsecond), and the inverse multiplied into
+the right-hand side by one matmul; exact, float32 at full precision.
+Until PR 50 each chunk called XLA's ``triangular_solve``, which walks
+the rows one little operation after another: 0.60 ms for 120 systems of
+64 rows, 28.9 of a 103.3 ms prefill call of Olmo-Hybrid's (75.5 since),
+and 0.23 ms a layer-call even for 16 chunks of [16, 16] (PERF.md
+section 6, PR 50, on a TPU v5e: a [4, 256] row through ``kda_chunked``
+3.47 -> 1.21 ms at 30 heads of 96 x 192; the systems of a layer-call
+alone 2.62 -> 0.35 ms there, 0.54 -> 0.35 at 64 heads of [16, 16],
+0.44 -> 0.28 at 32). Blocks of 8 to 32 rows merged upward by matmuls
+were timed too (1.38 | 1.21 | 1.13 ms against 1.16 for the rows alone)
+and left out: in the served cell the plain rows gave the shorter call.
+The inverse is taken INSIDE the scan, a chunk at a time: a system reads
+no state, so every chunk's could be inverted before the scan, and that
+was built and timed too; it is no faster where the chunk is 64 and
+SLOWER than XLA's solve at 64 heads of 128 x 128 (3.04-3.22 for 2.89:
+what the scan then needs from outside it is a second pass over the
+call's [16, 4, 64, 16, 128] arrays, 33.5 MB each, which no longer fit
+the chip's fast memory). NOT BY A SERIES: ``(I + A)^-1 = (I - A)
+(I + A^2)(I + A^4)..`` is six matmuls and wrong here. With unit keys
+and beta up to 2 a prompt that repeats a token gives ``A`` = 2 x (the
+strictly lower ones): the inverse has entries of +-2, ``A^32`` entries
+of 1e18, and float32 cancels to garbage (2e18 of the largest true entry
+at C = 64; tests/test_linear_attention.py holds the substitution on
+those inputs).
 
 STABLE FOR ANY GATE: the only exponentials taken are of differences
 ``G_t - G_i`` of cumulative log-decays with t >= i, which are <= 0.
@@ -61,8 +91,10 @@ heads of 128 took 5.93 ms at C = 64, 3.22 at 32 and 2.90 at 16
 the decay between two positions of a chunk is a [C, C] matrix
 ``exp(G_t - G_i)`` that no channel enters, so ``q k^T`` and ``k k^T``
 are matrix-unit products masked by it, nothing of [C, C, dk] exists,
-and the chunk is as long as the matmuls like (PERF.md section 6, PR 49
-has the chip's readings by chunk).
+and the chunk is as long as the matmuls like: a [4, 256] row of 30
+heads of 96 x 192 took 1.51 ms at C = 16, 1.28 at 32, 1.21 at 64 and
+1.55 at 128 (PERF.md section 6, PR 50; 3.78 | 3.53 | 3.47 | 3.61 under
+XLA's solve), hence that form's chunk.
 
 ``valid`` marks real positions: an invalid one (padding inside a
 prefill row, a free slot riding a decode call) has beta 0 and g 0,
@@ -324,6 +356,26 @@ def kda_step(q, k, v, g, beta, state, valid=None, fresh=None):
 _CHUNK_PER_CHANNEL, _CHUNK_PER_HEAD = 16, 64
 
 
+def unit_lower_inverse(below):
+    """``(I + below)^-1`` for ``below`` [.., C, C] STRICTLY lower-
+    triangular, exactly, by forward substitution (the module's docstring
+    says why no series): row i of the inverse is ``e_i - below[i, :i] @
+    inverse[:i]``, C dependent steps, every matrix at once with the
+    batch on the lanes."""
+    C = below.shape[-1]
+    a = jnp.moveaxis(below.reshape(-1, C, C), 0, -1)        # [i, j, N]
+
+    def row(i, inv):
+        a_i = jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+        e_i = (jnp.arange(C) == i).astype(F32)
+        # the rows not yet written are zeros, as below[i, j >= i] is
+        new = e_i[:, None] - jnp.sum(a_i[:, None, :] * inv, axis=0)
+        return jax.lax.dynamic_update_index_in_dim(inv, new, i, 0)
+
+    inv = jax.lax.fori_loop(0, C, row, jnp.zeros_like(a))
+    return jnp.moveaxis(inv, -1, 0).reshape(below.shape)
+
+
 def kda_chunked(q, k, v, g, beta, state, valid=None,
                 chunk: Optional[int] = None):
     """A row of T tokens. q, k [B, T, H, dk]; v [B, T, H, dv]; beta
@@ -353,7 +405,6 @@ def kda_chunked(q, k, v, g, beta, state, valid=None,
 
     lower = jnp.tril(jnp.ones((C, C), bool))
     strict = jnp.tril(jnp.ones((C, C), bool), -1)
-    eye = jnp.eye(C, dtype=F32)
 
     def one_chunk(S, xs):
         q, k, v, g, beta = xs           # [B, H, C, d]; beta [B, H, C]
@@ -377,9 +428,9 @@ def kda_chunked(q, k, v, g, beta, state, valid=None,
         # (I + Diag(beta) kk_strict) U = Diag(beta) (V - (K.into) S)
         rhs = beta[..., None] * (v - jnp.einsum(
             "bhtc,bhcv->bhtv", k * into, S, precision=_HI))
-        system = eye + beta[..., None] * jnp.where(strict, kk, 0.0)
-        U = jax.lax.linalg.triangular_solve(
-            system, rhs, left_side=True, lower=True, unit_diagonal=True)
+        U = jnp.einsum("bhti,bhiv->bhtv", unit_lower_inverse(
+            beta[..., None] * jnp.where(strict, kk, 0.0)), rhs,
+            precision=_HI)
         o = (jnp.einsum("bhtc,bhcv->bhtv", q * into, S, precision=_HI)
              + jnp.einsum("bhti,bhiv->bhtv", qk, U, precision=_HI))
         out_of = jnp.exp(G[:, :, -1:, :] - G)           # from i to C
@@ -388,9 +439,8 @@ def kda_chunked(q, k, v, g, beta, state, valid=None,
                           precision=_HI))
         return S, o
 
-    with jax.default_matmul_precision("highest"):   # the solve's too
-        state, o = jax.lax.scan(
-            one_chunk, state, tuple(chunks(a) for a in (q, k, v, g, beta)))
+    state, o = jax.lax.scan(
+        one_chunk, state, tuple(chunks(a) for a in (q, k, v, g, beta)))
     # [n, B, H, C, dv] -> [B, T, H, dv]
     o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)
     return o.reshape((B, n * C) + o.shape[3:])[:, :T], state

@@ -11,7 +11,10 @@ compiles it for the chip; chip_smoke.py runs it there).
 
 Tolerance. All three compute the same recurrence in float32 and differ
 in the order of their sums (the chunked form solves a chunk's writes at
-once through a triangular system); with l2-normed keys the state and the
+once through a unit lower-triangular system, inverted by forward
+substitution: ``unit_lower_inverse``, held here to XLA's own
+``triangular_solve`` and to closed forms on the inputs a series would
+fail); with l2-normed keys the state and the
 outputs are of order 1 and agree to 1e-5 absolute, so rtol 1e-4 /
 atol 2e-5 holds with room. A state handed over in bfloat16 between two
 calls (8 mantissa bits) misses it by fifty times and more, and the
@@ -37,13 +40,16 @@ GATES = {"channel": (D, D), "head": (12, 24)}
 BOTH = pytest.mark.parametrize("gate", sorted(GATES))
 
 
-def _inputs(T, seed=0, hard=True, gate="channel"):
-    """Unit keys, values of order 1, beta up to 2 and log-decays (a
-    channel, or a head) from -0.001 down to -20 a step where ``hard``."""
+def _inputs(T, seed=0, hard=True, gate="channel", equal_keys=False):
+    """Unit keys (a head's ALL EQUAL where ``equal_keys``: a prompt that
+    repeats one token), values of order 1, beta up to 2 and log-decays
+    (a channel, or a head) from -0.001 down to -20 a step where
+    ``hard``."""
     dk, dv = GATES[gate]
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     q = l2norm(jax.random.normal(ks[0], (B, T, H, dk))) * dk ** -0.5
-    k = l2norm(jax.random.normal(ks[1], (B, T, H, dk)))
+    k = l2norm(jax.random.normal(ks[1], (B, 1 if equal_keys else T, H, dk)))
+    k = jnp.broadcast_to(k, (B, T, H, dk))
     v = jax.random.normal(ks[2], (B, T, H, dv))
     shape = (B, T, H, dk) if gate == "channel" else (B, T, H)
     g = -jnp.exp(jax.random.uniform(ks[3], shape, minval=-7.0,
@@ -71,14 +77,20 @@ def _close(got, want):
 
 
 @BOTH
-@pytest.mark.parametrize("T,chunk", [(150, 64), (64, 64), (40, 16),
-                                     (7, 64), (129, 32), (150, None)])
-def test_chunked_matches_the_scan(T, chunk, gate):
+@pytest.mark.parametrize("T,chunk,equal_keys", [
+    (150, 64, False), (64, 64, False), (40, 16, False), (7, 64, False),
+    (129, 32, False), (150, None, False), (150, None, True),
+    (128, 128, True)])
+def test_chunked_matches_the_scan(T, chunk, equal_keys, gate):
     """T not a multiple of the chunk, hard decays (g down to -20 a
     step: exp(+20 x 64) would overflow a factored form), beta to 2, a
-    state that is not zero; the gate's own chunk where none is given."""
-    q, k, v, g, beta, state = _inputs(T, gate=gate)
-    if T >= 40:
+    state that is not zero; the gate's own chunk where none is given. A
+    row of EQUAL keys under decays of a thousandth to a twentieth a
+    step: the chunk's system is then nearly 2 x (the strictly lower
+    ones), whose powers reach 1e18 while its inverse stays at +-2."""
+    q, k, v, g, beta, state = _inputs(T, gate=gate, equal_keys=equal_keys,
+                                      hard=not equal_keys)
+    if T >= 40 and not equal_keys:
         assert float(g.min()) < (-19.0 if gate == "channel" else -15.0)
         assert float(beta.max()) > 1.99
     o, s = jax.jit(kda_chunked, static_argnames="chunk")(
@@ -87,6 +99,147 @@ def test_chunked_matches_the_scan(T, chunk, gate):
     assert np.isfinite(np.asarray(o)).all()
     _close(o, want_o)
     _close(s, want_s)
+
+
+@BOTH
+@pytest.mark.parametrize("equal_keys", [False, True])
+def test_chunked_matches_a_float64_scan(equal_keys, gate):
+    """The chunk form against the recurrence in FLOAT64, at a quarter of
+    the file's tolerance: what float32 costs the chunk form itself (the
+    masks' exponentials, the system's inverse, the sums' order), with no
+    second float32 program's rounding in the way. Over six seeds it
+    reads 0.02-0.14 of the file's tolerance, equal keys or not (the
+    float32 scan 0.003-0.04)."""
+    a = _inputs(150, gate=gate, equal_keys=equal_keys, hard=not equal_keys)
+    got = jax.jit(kda_chunked)(*a)
+    with jax.enable_x64(True):
+        want = _scan(*(jnp.asarray(np.asarray(x), jnp.float64) for x in a))
+        assert want[0].dtype == jnp.float64
+        want = [np.asarray(w) for w in want]
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), w, rtol=RTOL / 4,
+                                   atol=ATOL / 4)
+
+
+def _systems(case, C, gate, seed=11):
+    """A chunk's unit lower-triangular system as ``kda_chunked`` builds
+    it, ``I + Diag(beta) strict(kk)`` with ``kk[t, i] = sum_c k_t[c]
+    k_i[c] exp(G_t[c] - G_i[c])``, [B, H, C, C], and a right-hand side
+    of ``dv`` columns. ``random``: unit keys, beta to 2, the gate's
+    decays. ``equal-2`` / ``equal-1``: a head's keys ALL EQUAL, beta 2 /
+    1, no decay. ``four-1.9``: four keys a head, each on a quarter of
+    the chunk, beta 1.9, no decay."""
+    dk, dv = GATES[gate]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    distinct = {"random": C, "equal-2": 1, "equal-1": 1, "four-1.9": 4}[case]
+    k = l2norm(jax.random.normal(ks[0], (B, H, distinct, dk)))
+    k = k[:, :, jnp.arange(C) * distinct // C]
+    rhs = jax.random.normal(ks[1], (B, H, C, dv))
+    if case == "random":
+        beta = 2.0 * jax.nn.sigmoid(3.0 * jax.random.normal(ks[2],
+                                                             (B, H, C)))
+        shape = (B, H, C, dk) if gate == "channel" else (B, H, C, 1)
+        G = jnp.cumsum(-jnp.exp(jax.random.uniform(
+            ks[3], shape, minval=-7.0, maxval=3.0)), axis=2)
+    else:
+        beta = jnp.full((B, H, C), float(case.split("-")[1]))
+        G = jnp.zeros((B, H, C, 1))
+    diff = jnp.minimum(G[:, :, :, None] - G[:, :, None, :], 0.0)
+    kk = jnp.sum(k[:, :, :, None] * k[:, :, None, :] * jnp.exp(diff), -1)
+    return jnp.tril(beta[..., None] * kk, -1), rhs
+
+
+@BOTH
+@pytest.mark.parametrize("C", [7, 16, 32, 64, 128])
+@pytest.mark.parametrize("case", ["random", "equal-2", "equal-1",
+                                  "four-1.9"])
+def test_the_inverse_is_the_triangular_solve(case, C, gate):
+    """``unit_lower_inverse`` against XLA's ``triangular_solve`` of the
+    same system: a short last chunk's rows (7), the per-channel chunk's
+    (16), the per-head chunk's (64) and those beside it (32, 128),
+    under both gates, on random keys and on the keys a prompt that repeats a token gives,
+    where a series in the powers of the system cancels to garbage in
+    float32 (at C = 64: 2e18 of the largest true entry at beta 2) and
+    substitution does not. Equal keys have closed forms: at beta 1 the
+    inverse is the difference operator (1 on the diagonal, -1 under
+    it); at beta 2 x_t = b_t - 2 s_(t-1) with s_t = b_t - s_(t-1)."""
+    below, rhs = _systems(case, C, gate)
+    inverse = jax.jit(la.unit_lower_inverse)(below)
+    assert not np.asarray(jnp.triu(inverse, 1)).any()
+    assert (np.asarray(jnp.diagonal(inverse, axis1=-2, axis2=-1))
+            == 1.0).all()
+    b = np.asarray(rhs, np.float64)
+    got = np.einsum("bhij,bhjv->bhiv", np.asarray(inverse, np.float64), b)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(jax.lax.linalg.triangular_solve(
+            jnp.eye(C) + below, rhs, left_side=True, lower=True,
+            unit_diagonal=True))
+
+    def close(got, want):
+        """The file's tolerance on a system's solution as a whole: by
+        its largest entry (equal keys at beta 2 are conditioned 7e3 at
+        C = 64 and 3e4 at 128, their solutions reach 70, and XLA's own
+        solve is 3e-5 to 5e-5 off a float64 one there)."""
+        scale = np.maximum(1.0, np.abs(want).max(axis=(2, 3), keepdims=True))
+        _close(got / scale, want / scale)
+    close(got, want)
+    if case == "equal-1":
+        _close(inverse, jnp.broadcast_to(
+            jnp.eye(C) - jnp.eye(C, k=-1), inverse.shape))
+    if case == "equal-2":
+        x, s = [], np.zeros_like(b[:, :, 0])
+        for t in range(C):
+            x.append(b[:, :, t] - 2.0 * s)
+            s = b[:, :, t] - s
+        close(got, np.stack(x, axis=2))
+
+
+def _loops(jaxpr):
+    """Every loop of a program, the nested ones too: (primitive, trips
+    or None, its body)."""
+    found = []
+    for eq in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eq.params):
+            if eq.primitive.name in ("scan", "while"):
+                found.append((eq.primitive.name, eq.params.get("length"),
+                              sub))
+            found += _loops(sub)
+    return found
+
+
+def _primitives(jaxpr):
+    names = {eq.primitive.name for eq in jaxpr.eqns}
+    for eq in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eq.params):
+            names |= _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize("model,H_,dk,dv,a_channel", [
+    ("olmo-hybrid", 30, 96, 192, False),    # 4 chunks of 64
+    ("solar-open2", 64, 128, 128, True),    # 16 chunks of 16
+    ("kimi-linear", 32, 128, 128, True)])
+def test_a_chunk_is_solved_by_the_inverse(model, H_, dk, dv, a_channel):
+    """The engagement check, on the program of a [4, 256] prefill call
+    at the served shapes (traced, never run): no ``triangular_solve``
+    anywhere, and the only row-by-row work a chunk does is ONE loop of
+    the chunk's rows inside the scan over the chunks, all of the chunk's
+    B x H systems a step, the batch on the last axis."""
+    f32 = jnp.float32
+    lead = (4, 256, H_)
+    args = [jax.ShapeDtypeStruct(lead + (d,), f32) for d in (dk, dk, dv)]
+    args += [jax.ShapeDtypeStruct(lead + ((dk,) if a_channel else ()), f32),
+             jax.ShapeDtypeStruct(lead, f32),
+             jax.ShapeDtypeStruct((4, H_, dk, dv), f32)]
+    jaxpr = jax.make_jaxpr(kda_chunked)(*args).jaxpr
+    assert "triangular_solve" not in _primitives(jaxpr)
+    C = la._CHUNK_PER_CHANNEL if a_channel else la._CHUNK_PER_HEAD
+    (name, trips, chunk), = _loops(jaxpr)[:1]
+    assert (name, trips) == ("scan", 256 // C)
+    (name, trips, step), = _loops(chunk)
+    assert (name, trips) == ("scan", C) and not _loops(step)
+    carried, = (v.aval.shape for v in step.outvars if v.aval.ndim == 3)
+    assert carried == (C, C, 4 * H_)
 
 
 @BOTH

@@ -5,9 +5,16 @@ TPU chip, at olmo-hybrid-d16.sample-sat's shapes: 30 heads of 96 x 192.
 ``ops/linear_attention.py`` ``kda_chunked`` at those chunk lengths, and
 through the per-channel form fed the gate broadcast over the 96
 channels at its own chunk of 16 (what the layer would cost without a
-form of its own). ``--slots 96 --free 3``: 8 chained one-token steps in
-one program, as a decode dispatch runs them, over a state pool of that
-many slots stored as declared ([slots, 30, 96, 192], which the chip
+form of its own). Before them the chunk's SYSTEM alone, a ``solve`` line
+a model: the unit lower-triangular systems of one layer-call (Olmo-
+Hybrid's 4 chunks of [4, 30, 64, 64] against 192 columns, Solar-Open2's
+and Kimi-Linear's 16 chunks of [4, 64 | 32, 16, 16] against 128) solved
+a chunk at a time in a scan, as the chunk form meets them, by XLA's
+``triangular_solve`` (the chunk form's until PR 50) and by
+``unit_lower_inverse`` and one matmul; both return the whole solution,
+so nothing is folded away. ``--slots 96 --free 3``: 8 chained one-token
+steps in one program, as a decode dispatch runs them, over a state pool
+of that many slots stored as declared ([slots, 30, 96, 192], which the chip
 pads to 256 lanes: the ``jax.numpy`` form) and stored PACKED two heads
 side by side ([slots, 15, 96, 384], whole lane tiles: the ``jax.numpy``
 form and the kernel ``kda_step_packed_kernel`` at ``--unrolls`` groups a
@@ -71,8 +78,46 @@ def main():
         jax.block_until_ready(out)
         return (time.perf_counter() - t0) / n * 1e3, out
 
-    # ------------------------------------------------ a prefill call
+    # ------------------------------------- a layer-call's systems alone
     B, T = 4, 256
+
+    def solved_by(solve):
+        """A layer-call's chunks one after another in a scan, as the
+        chunk form meets them."""
+        def run(below, rhs):
+            with jax.default_matmul_precision("highest"):
+                return jax.lax.scan(lambda _, xs: (None, solve(*xs)), None,
+                                    (below, rhs))[1]
+        return jax.jit(run)
+
+    def xla_solve(below, rhs):
+        return jax.lax.linalg.triangular_solve(
+            jnp.eye(below.shape[-1], dtype=jnp.float32) + below, rhs,
+            left_side=True, lower=True, unit_diagonal=True)
+
+    def by_inverse(below, rhs):
+        return jnp.einsum("...ij,...jk->...ik", la.unit_lower_inverse(below),
+                          rhs)
+
+    for model, heads, C, cols in (("olmo-hybrid", H, 64, DV),
+                                  ("solar-open2", 64, 16, 128),
+                                  ("kimi-linear", 32, 16, 128)):
+        ks = jax.random.split(jax.random.PRNGKey(5), 3)
+        keys = unit(jax.random.normal(ks[0], (T // C, B, heads, C, 96)))
+        below = jnp.tril(
+            2.0 * jax.nn.sigmoid(jax.random.normal(
+                ks[1], keys.shape[:-1]))[..., None]
+            * jnp.einsum("...tc,...ic->...ti", keys, keys), -1)
+        rhs = jax.random.normal(ks[2], keys.shape[:-1] + (cols,))
+        ms_xla, want = timed(solved_by(xla_solve), below, rhs)
+        ms_inv, got = timed(solved_by(by_inverse), below, rhs)
+        print(json.dumps({
+            "solve": list(below.shape), "columns": cols, "model": model,
+            "ms_triangular_solve": round(ms_xla, 4),
+            "ms_inverse_and_matmul": round(ms_inv, 4),
+            "err": float(jnp.max(jnp.abs(got - want)))}), flush=True)
+
+    # ------------------------------------------------ a prefill call
     q, k, v, g, beta = inputs((B, T), 1)
     state = jax.random.normal(jax.random.PRNGKey(2), (B, H, DK, DV))
     want = None
