@@ -416,9 +416,10 @@ def kv_layer_store(cache: PagedKVLayer):
 
 def init_kv_pool(cfg, n_pages: int, page_size: int,
                  kv_dtype: str = "fp", n_slots: int = 0,
-                 ring_len: int = 0):
+                 ring_len: int = 0, mark=None):
     """One entry per layer, by ``layer_kinds(cfg)``. Page 0 of a paged
-    layer is reserved (null).
+    layer is reserved (null). ``mark``, if given, is told after each
+    layer what was made: "pool" (pages) or "state" (a slot's own).
 
     fp:   (pages_k, pages_v) in cfg.dtype, each
           [n_pages, page_size, n_kv_heads, head_dim].
@@ -448,7 +449,14 @@ def init_kv_pool(cfg, n_pages: int, page_size: int,
                           cfg.dtype))
         return tuple(jnp.zeros((n_pages,) + shape, dtype) for shape, dtype
                      in page_layout(cfg, kind, page_size, kv_dtype))
-    return [entry(kind) for kind in layer_kinds(cfg)]
+
+    pool = []
+    for kind in layer_kinds(cfg):
+        pool.append(entry(kind))
+        if mark is not None:
+            mark("state" if kind in (KIND_RECURRENT, KIND_SLIDING)
+                 else "pool")
+    return pool
 
 
 def kv_pool_page_bytes(cfg, page_size: int,

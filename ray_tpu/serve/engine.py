@@ -133,7 +133,8 @@ from ray_tpu.serve.step_programs import (_jit_copy_page, _jit_decode,
                                          _jit_prefill, _jit_seed,
                                          _jit_verify, _jit_write_page,
                                          _moe_vector_of, ambient_mesh)
-from ray_tpu.util.compile_cache import metadata_keyed
+from ray_tpu.util.compile_cache import (build_log, metadata_keyed,
+                                        summarize_builds)
 
 _DONE = object()
 
@@ -644,6 +645,8 @@ class LLMEngine:
                  role: str = ROLE_UNIFIED,
                  capture_logprobs: bool = False):
         from ray_tpu.util.compile_cache import enable_compile_cache
+        # where this constructor's time goes: the ``engine_init`` event
+        _clk = obs.PhaseClock()
         enable_compile_cache()
         self.model = model
         self.cfg = model.config
@@ -662,6 +665,7 @@ class LLMEngine:
         if sharding is not None:
             params = sharding.shard_params(params)
         self.params = params
+        _clk.mark("params")
         # Weight-generation fence (live rollout, serve/weight_rollout):
         # strictly monotonic — every ``swap_weights`` must advance it,
         # including rollbacks (which install the OLD payload under a
@@ -728,9 +732,10 @@ class LLMEngine:
                                                          self.ring_len)
         self.pages = init_kv_pool(self.cfg, n_pages, page_size,
                                   self.kv_dtype, n_slots=max_slots,
-                                  ring_len=self.ring_len)
+                                  ring_len=self.ring_len, mark=_clk.mark)
         if sharding is not None:
             self.pages = sharding.place_kv_pool(self.pages)
+        _clk.mark("pool")
         # capacity gauge: the whole-pool byte budget this engine holds
         # (per process — chaos/fleet runs sum across scrapes). Set
         # once; pools are static-shape for the engine's lifetime.
@@ -747,12 +752,18 @@ class LLMEngine:
         # The engine's jitted programs by their names in a device
         # trace (jit_<function>), with the executables each held when
         # this engine took it: step() counts what a round adds
-        # (stats["programs_built"], the ``compile`` event).
+        # (stats["programs_built"], the ``compile`` event) and says
+        # what each build was from the process's build log
+        # (util/compile_cache.py), read from where it last looked.
         self._programs: Dict[str, Any] = {}
         self._program_sizes: Dict[str, int] = {}
+        self._builds = build_log()
+        self._build_cursor = self._builds.total
+        _clk.mark("metrics")
         self._copy_page_fn = (
             self._track_program(_jit_copy_page(self._mesh))
             if prefix_cache else None)
+        _clk.mark("programs")
         # Fleet prefix-cache digest advertisement cap: load reports
         # ship at most this many path hashes, truncated prefix-closed
         # longest/hottest-first (PrefixCache.digest) so fleet routing
@@ -816,6 +827,7 @@ class LLMEngine:
         # host readbacks trail for emission only.
         self._dev_cur = self._h2d(jnp.zeros((max_slots,), jnp.int32))
         self._dev_pos = self._h2d(jnp.zeros((max_slots,), jnp.int32))
+        _clk.mark("decode_state")
         # Without an eos the schedule is fully deterministic: slots
         # retire by arithmetic at dispatch time and host syncs never
         # gate scheduling. With an eos, completions depend on sampled
@@ -869,6 +881,7 @@ class LLMEngine:
         self._prefill_fn = self._track_program(_jit_prefill(
             self.model, self.temperature, self._max_prefill_batch,
             self.capture_logprobs, self._mesh))
+        _clk.mark("programs")
         # Typed lifecycle event log (serve/obs.py): lock-free bounded
         # ring recording every request phase and scheduler action.
         # ``sched_trace`` stays as a compat view rendering the four
@@ -877,6 +890,7 @@ class LLMEngine:
         self.sched_trace = obs.SchedTraceView(self.events)
         # the collector's long passes become ``gc`` events here
         obs.watch_gc(self)
+        _clk.mark("events")
         # Flight recorder sink: when set, EngineFault containment and
         # whole-engine failure dump a postmortem bundle here.
         self.flight_dir = flight_dir
@@ -897,8 +911,12 @@ class LLMEngine:
             self.model, self.temperature, self.KMAX, self.S,
             self.capture_logprobs, self._mesh))
         self._seed_fn = self._track_program(_jit_seed())
+        _clk.mark("programs")
         # what this round dispatched, for its ``round`` event
         self._round_info = _new_round_info()
+        # a replica's start is this event and the ``compile`` events
+        # up to its first round that builds nothing
+        self.events.append("engine_init", data=_clk.parts())
 
     def _track_program(self, fn):
         """Register a jitted step program under its trace name. The
@@ -907,21 +925,37 @@ class LLMEngine:
         name = "jit_" + fn.__name__
         self._programs[name] = fn
         self._program_sizes[name] = fn._cache_size()
+        self._builds.watch(name)
         return fn
 
     def _count_programs_locked(self, wall_s: float) -> None:
         """After a round: which of the engine's programs gained an
         executable (built, or loaded from the persistent cache) — the
-        in-program answer to "which round recompiled"."""
+        in-program answer to "which round recompiled", and from the
+        build log's records of that program by this thread since the
+        engine last looked, what the build was: seconds tracing,
+        lowering, in the backend (a compile, or a load from the cache
+        of which ``cache_read_s`` read the file) and whether the cache
+        had it. A build the cache did not have is a cold one."""
+        new = None
         for name, fn in self._programs.items():
             n = fn._cache_size()
             grew = n - self._program_sizes[name]
             if grew > 0:
+                if new is None:
+                    me = threading.get_ident()
+                    new = [r for r in self._builds.since(
+                        self._build_cursor) if r["thread"] == me]
+                    self._build_cursor = self._builds.total
+                mine = [r for r in new if r["program"] == name]
                 self._program_sizes[name] = n
                 self.stats["programs_built"] += grew
+                self.stats["cold_builds"] += sum(
+                    r["cache_hit"] is not True for r in mine)
                 self.events.append("compile", data={
                     "program": name, "round": self._round,
-                    "built": grew, "wall_s": round(wall_s, 6)})
+                    "built": grew, "wall_s": round(wall_s, 6),
+                    **summarize_builds(mine)})
 
     # ------------------------------------------------- device trace
 
@@ -1415,6 +1449,11 @@ class LLMEngine:
                 "max_queued_batch": self.max_queued_batch,
                 "shed_retry_after_s": self.shed_retry_after_s,
                 "shed_total": self.stats.get("shed", 0),
+                # executables this engine's step programs have gained,
+                # and those of them the compile cache did not have: a
+                # replica whose counts still rise is still compiling
+                "programs_built": self.stats.get("programs_built", 0),
+                "cold_builds": self.stats.get("cold_builds", 0),
                 "ttft_ewma_s": self._ttft_ewma,
                 "itl_ewma_s": self._itl_ewma,
                 "role": self.role,
@@ -1470,6 +1509,11 @@ class LLMEngine:
                 "max_queued_batch": self.max_queued_batch,
                 "shed_retry_after_s": self.shed_retry_after_s,
                 "shed_total": self.stats.get("shed", 0),
+                # executables this engine's step programs have gained,
+                # and those of them the compile cache did not have: a
+                # replica whose counts still rise is still compiling
+                "programs_built": self.stats.get("programs_built", 0),
+                "cold_builds": self.stats.get("cold_builds", 0),
                 "ttft_ewma_s": self._ttft_ewma,
                 "itl_ewma_s": self._itl_ewma,
                 "role": self.role,
@@ -2785,8 +2829,13 @@ class LLMEngine:
                 self.kv_dtype)] + [None, None])[:3]
         # a head's query is as wide as what it is scored against: a KV
         # head's key, or (absorbed) a stored latent entry, whose value
-        # is its latent
-        q = jax.ShapeDtypeStruct((self.S, 1, cfg.n_heads, k.shape[-1]),
+        # is its latent; and the layer hands the kernel a whole group
+        # of query heads for every head ROW the page stores, the rows
+        # that pad it among them (models/olmo_hybrid.py: 30 heads
+        # stored, and so asked, as 32)
+        heads = (cfg.n_heads if latent else
+                 k.shape[-2] * (cfg.n_heads // cfg.n_kv_heads))
+        q = jax.ShapeDtypeStruct((self.S, 1, heads, k.shape[-1]),
                                  cfg.dtype)
         table = jax.ShapeDtypeStruct((self.S, self.max_pages), jnp.int32)
         with ambient_mesh(self._mesh):

@@ -34,6 +34,9 @@ watchdog, and the autoscaler, plus everything built on top of it:
   (queue_wait, plan, dispatch, readback, round wall, TTFT, inter-token)
   in ``util/metrics`` so the dashboard's ``/metrics`` endpoint exposes
   phase latency distributions.
+- ``PhaseClock`` — an engine's construction by part, the payload of
+  its ``engine_init`` event (the builds that follow are ``compile``
+  events, from ``util/compile_cache.py``'s build log).
 - ``watch_gc`` — the process's one ``gc.callbacks`` hook: a collector
   pass of generation 2, or a longer one, becomes a ``gc`` event in
   every watching engine's log and a ``host.gc`` span on a device
@@ -656,6 +659,44 @@ def phase_metrics() -> Dict[str, Any]:
                 boundaries=_PHASE_BOUNDS),
         }
     return _METRICS
+
+
+# ------------------------------------------------- an engine's start
+
+class PhaseClock:
+    """Where a constructor's wall time went, by name: ``mark(name)``
+    gives ``name`` the time since the mark before it (or the clock's
+    start), summing where a name is marked again. ``parts()`` is the
+    ``engine_init`` event's payload: ``wall_s``, one ``<name>_s`` for
+    each of ``ALWAYS`` and for whatever else took ``NAMED_S`` or more,
+    and the rest as ``other_s``: the parts sum to ``wall_s``."""
+
+    ALWAYS = ("pool", "state", "programs")
+    NAMED_S = 0.050
+
+    def __init__(self):
+        self._t0 = self._t = time.monotonic()
+        self._spent: Dict[str, float] = {}
+
+    def mark(self, name: str) -> None:
+        now = time.monotonic()
+        self._spent[name] = self._spent.get(name, 0.0) + now - self._t
+        self._t = now
+
+    def parts(self) -> Dict[str, float]:
+        self.mark("other")
+        spent = dict(self._spent)
+        out = {"wall_s": round(self._t - self._t0, 6)}
+        other = spent.pop("other")
+        for name in self.ALWAYS:
+            out[name + "_s"] = round(spent.pop(name, 0.0), 6)
+        for name, s in spent.items():
+            if s >= self.NAMED_S:
+                out[name + "_s"] = round(s, 6)
+            else:
+                other += s
+        out["other_s"] = round(other, 6)
+        return out
 
 
 # ---------------------------------------------------- collector passes

@@ -94,6 +94,12 @@ del test_seeded_weights_are_the_parents_bit_for_bit    # noqa: F821
 # four dispatch readers pins them SIXTEEN before the file's end, and
 # benchmarks/tests/test_olmo_hybrid_family.py::
 # test_the_cell_and_sample_sat_as_it_stands pins PR 49's.
+# PR 51 appended four readers of the program's build log (no
+# configuration, no cell), three to all ten cells and one to the nine
+# serving ones: every older case (PR 49's pin among them) runs against
+# the file less those four, the case of the four dispatch readers pins
+# them TWENTY before the file's end, and tests/test_build_log.py pins
+# PR 51's.
 _DISPATCH = ("dispatch_prefill_call_ms", "dispatch_decode_step_ms",
              "dispatch_prefill_share", "dispatch_prefill_call_ms.open")
 _PR39 = ("state_peak_share", "linear_state_roofline.by_kind",
@@ -107,6 +113,8 @@ _PR46_CELL, _PR46_CONFIG = "ouro-2.6b.chat-sat", "ouro-2.6b"
 _PR49 = ("hybrid_step_roofline", "prefill_linear_attn_share",
          "state_kv_bytes_ratio", "kda_step_packed_roofline")
 _PR49_CELL, _PR49_CONFIG = "olmo-hybrid-d16.sample-sat", "olmo-hybrid-7b-d16"
+_PR51 = ("setup_build_s", "setup_program_trace_s", "setup_cold_builds",
+         "engine_init_s")
 _SAT = ["mistral7b-d16.chat-sat", "olmoe-d8.chat-sat",
         "solar-open2-d4.doc-sat", "axk1-d5.longdoc-sat",
         "kimi-linear-d8.gen-sat", _PR42_CELL, _PR46_CELL, _PR49_CELL]
@@ -127,9 +135,17 @@ def _less_a_pr(bench, config, cell, readers):
     return bench
 
 
+def _less_pr51(bench):
+    """BENCHMARK.json as PR 50 left it: without its last four readers,
+    which must be these."""
+    assert tuple(m["name"] for m in bench["per_layer"][-4:]) == _PR51
+    bench["per_layer"] = bench["per_layer"][:-4]
+    return bench
+
+
 def _less_pr49(bench):
     """BENCHMARK.json as PR 48 left it."""
-    return _less_a_pr(bench, _PR49_CONFIG, _PR49_CELL, _PR49)
+    return _less_a_pr(_less_pr51(bench), _PR49_CONFIG, _PR49_CELL, _PR49)
 
 
 def _less_pr46(bench):
@@ -193,12 +209,27 @@ test_the_cell_and_chat_sat_as_it_stands = _as_pr48_left_it(
     test_the_cell_and_chat_sat_as_it_stands)            # noqa: F821
 
 
+def _as_pr50_left_it(case):
+    def test(monkeypatch):
+        from benchmarks import common
+        bench = _less_pr51(common.load_benchmark())
+        monkeypatch.setattr(common, "load_benchmark", lambda: bench)
+        case()
+    test.__name__ = case.__name__
+    test.__doc__ = case.__doc__
+    return test
+
+
+test_the_cell_and_sample_sat_as_it_stands = _as_pr50_left_it(
+    test_the_cell_and_sample_sat_as_it_stands)          # noqa: F821
+
+
 @pytest.mark.parametrize("name", _DISPATCH)
 def test_dispatch_readers_are_appended_to_the_benchmark(name):
     from benchmarks import common
     bench = common.load_benchmark()
-    assert tuple(m["name"] for m in bench["per_layer"][-20:]) == \
-        _DISPATCH + _PR39 + _PR42 + _PR46 + _PR49
+    assert tuple(m["name"] for m in bench["per_layer"][-24:]) == \
+        _DISPATCH + _PR39 + _PR42 + _PR46 + _PR49 + _PR51
     m = common.find_named(bench["per_layer"], name, "metric")
     want = {"name": name, "unit": "ms", "better": "lower",
             "source": "device_trace", "layer": "model step",

@@ -361,6 +361,47 @@ def test_the_engine_matches_the_reference(tiny):
     assert eng.alloc.occupancy() == 0 and eng.alloc.leak_report() == []
 
 
+@pytest.mark.parametrize("one_tpu", [False, True])
+def test_kernel_pages_are_counted_exactly_where_decode_holds_the_kernel(
+        monkeypatch, one_tpu):
+    """The full layers pad their 6 heads to the 16 rows a page stores
+    and hand the kernel's rule those; the engine's counter asks the
+    same rows (with ``cfg.n_heads`` it read 0 beside a program that
+    held the kernel). At the tiny model with heads of 128 in bfloat16,
+    the shapes the rule serves: ``decode_kernel_pages`` moves exactly
+    where the decode program, lowered for a TPU, holds the call."""
+    from ray_tpu.ops import paged_decode_attention as pd
+    from ray_tpu.serve import step_programs
+    cfg = olmo_hybrid_tiny(dtype=jnp.bfloat16, dim=768, n_layers=4)
+    assert (cfg.head_dim, cfg.n_heads, cfg.kv_page_heads) == (128, 6, 16)
+    model, params = _seeded(cfg)
+    eng = _engine((cfg, model, params))
+    prompt = _ids((20,), seed=3).tolist()
+    eng.submit(prompt, max_new_tokens=6)
+    _drive(eng)                     # the CPU's program: the loop
+    assert eng.stats["decode_steps"] and not eng.stats[
+        "decode_kernel_pages"]
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: one_tpu)
+    fresh = step_programs._jit_decode.__wrapped__(
+        model, 0.0, eng.KMAX, eng.S, False, None)
+    i32 = jnp.int32
+    text = fresh.trace(
+        params, eng.pages, jnp.zeros((eng.S, eng.max_pages), i32),
+        jnp.zeros((eng.S,), i32), jnp.zeros((eng.S,), i32),
+        jax.random.PRNGKey(0), i32(1)).lower(
+            lowering_platforms=("tpu",)).as_text()
+    holds = "tpu_custom_call" in text and "paged_decode" in text
+    assert holds is one_tpu
+    assert eng._decode_kernel_serves() is holds
+    # the engine's own program is built (no retrace): only the counter
+    # reads the rule again
+    eng.submit(prompt, max_new_tokens=6)
+    _drive(eng)
+    assert (eng.stats["decode_kernel_pages"] > 0) is holds
+    assert any(e[5]["decode_kernel_pages"] for e in eng.events.snapshot()
+               if e[2] == "round") is holds
+
+
 def test_a_reused_slot_starts_from_zeros(tiny):
     """One slot, two requests in turn: the second finds the first's
     state and convolution tail in its slot and must not see them."""
