@@ -312,6 +312,10 @@ class SlidingRing(NamedTuple):
           attention's two contractions read it (declared otherwise the
           chip's compiler copies every ring into this layout on every
           step: ops/paged_attention.py)
+
+    Written and read where it lies by the layer's one call
+    (ops/ring_window_attention.py): on one TPU a Pallas kernel takes
+    both arrays aliased to its results; elsewhere the jax.numpy pair.
     """
     k: jnp.ndarray
     v: jnp.ndarray
@@ -324,10 +328,6 @@ class SlidingRingView(NamedTuple):
     v: jnp.ndarray
     slots: Optional[jnp.ndarray]
     valid: jnp.ndarray
-
-    def take(self, ring):
-        """The rows' rings of ``ring`` (``k`` or ``v``)."""
-        return _take(ring, self.slots)
 
 
 class PagedKVLayer(NamedTuple):

@@ -15,9 +15,12 @@ model, K/V pages in the full layers, handed out and walked exactly as
 Mistral's and OLMoE's are (``paged_append``,
 ``_paged_window_attention``), and a ``SlidingRing`` a slot in the
 sliding layers: the last ``sliding_ring_len`` positions' keys and
-values, whatever the context (ops/paged_attention.py ``ring_append``,
-``ring_attention``). ``layer_kinds`` is ``KIND_SLIDING`` and
-``KIND_KV``, by the published ``layer_types``.
+values, whatever the context, appended to and attended in ONE call
+(ops/ring_window_attention.py ``ring_window_attention``: on one TPU a
+Pallas kernel that reads and writes the rows' rings where they lie,
+elsewhere ops/paged_attention.py ``ring_append`` + ``ring_attention``).
+``layer_kinds`` is ``KIND_SLIDING`` and ``KIND_KV``, by the published
+``layer_types``.
 
 benchmarks/reference/mellum2.py has the equations, and says which of
 them ``config.json`` leaves open (assumed).
@@ -29,10 +32,10 @@ caches and does not serve it.
 
 The named scopes are metadata only (PERF.md section 3): ``attn_sliding``
 and ``attn_full`` around a layer's append and attention by its type,
-with the parts inside named (``ring_append``, ``ring_scores``,
-``ring_pv``; ``kv_append``, ``kv_gather``, ``attn_scores``,
-``attn_pv``), so that a device trace splits both step programs by
-layer type.
+with the parts inside named (the kernel ``ring_window``, or off the
+chip ``ring_append``, ``ring_scores``, ``ring_pv``; ``kv_append``,
+``kv_gather``, ``attn_scores``, ``attn_pv``), so that a device trace
+splits both step programs by layer type.
 """
 from __future__ import annotations
 
@@ -51,8 +54,8 @@ from ray_tpu.models.kv_cache import (KIND_KV, KIND_SLIDING, PagedKVLayer,
 from ray_tpu.models.llama import block_forward, transformer_forward
 from ray_tpu.models.mixtral import MoEFeedForward
 from ray_tpu.ops.paged_attention import (_paged_window_attention,
-                                         paged_append, ring_append,
-                                         ring_attention)
+                                         paged_append)
+from ray_tpu.ops.ring_window_attention import ring_window_attention
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 _PERIOD = (SLIDING, SLIDING, SLIDING, FULL)
@@ -195,11 +198,9 @@ class MellumAttention(nn.Module):
                     f"and the cache-less forward pass serve this model")
             rc = kv_cache
             with jax.named_scope("attn_sliding"):
-                with jax.named_scope("ring_append"):
-                    rk, rv = ring_append(rc.k, rc.v, rc.slots, cache_len,
-                                         k, v, rc.valid)
-                y = ring_attention(q, rc.take(rk), rc.take(rv), cache_len,
-                                   rc.valid, cfg.sliding_window)
+                y, rk, rv = ring_window_attention(
+                    q, k, v, rc.k, rc.v, rc.slots, cache_len, rc.valid,
+                    cfg.sliding_window)
             new_cache = rc._replace(k=rk, v=rv)
         else:
             if not (isinstance(kv_cache, PagedKVLayer)

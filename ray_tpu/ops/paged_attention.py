@@ -69,14 +69,14 @@ ignored host-side.
 A layer of SLIDING-WINDOW attention (models/mellum.py) keeps no pages
 but a ring a decode slot (models/kv_cache.py ``SlidingRing``:
 [n_slots, n_kv_heads, L, head_dim] for k and for v, position p at
-index p mod L), and has its own two operations, ``ring_append`` and
-``ring_attention``: a slot's ring is contiguous, so nothing is gathered
-by page and no loop walks it. The ring is HEAD-major inside a slot
-because that is how the two contractions read it (a KV head is a batch
-dimension of both): declared position-major, as a page is, the chip's
-compiler re-laid every layer's ring out head-major on every decode
-step, a whole-ring copy for k and for v (read off the compiled
-program, PR 42; the pool's lesson of PRs 29 and 34 again).
+index p mod L). ``ring_append`` and ``ring_attention`` here are the
+``jax.numpy`` FORM of its one call, ops/ring_window_attention.py
+``ring_window_attention``: what runs off the chip and what the tests
+hold that module's Pallas kernel to, which on one TPU writes and reads
+a ring where it lies (PR 52). The ring is HEAD-major inside a slot: a
+KV head is a batch dimension of both contractions and its ring one
+[L, head_dim] slab; position-major, the chip's compiler re-laid every
+ring out on every decode step (PR 42; PRs 29 and 34's lesson again).
 """
 from __future__ import annotations
 

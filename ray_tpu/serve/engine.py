@@ -1449,11 +1449,11 @@ class LLMEngine:
                 "max_queued_batch": self.max_queued_batch,
                 "shed_retry_after_s": self.shed_retry_after_s,
                 "shed_total": self.stats.get("shed", 0),
-                # executables this engine's step programs have gained,
-                # and those of them the compile cache did not have: a
-                # replica whose counts still rise is still compiling
+                # executables the step programs gained and those the
+                # cache lacked; ring positions the sliding kernel read
                 "programs_built": self.stats.get("programs_built", 0),
                 "cold_builds": self.stats.get("cold_builds", 0),
+                "sliding_kernel_keys": self.stats["sliding_kernel_keys"],
                 "ttft_ewma_s": self._ttft_ewma,
                 "itl_ewma_s": self._itl_ewma,
                 "role": self.role,
@@ -1509,11 +1509,11 @@ class LLMEngine:
                 "max_queued_batch": self.max_queued_batch,
                 "shed_retry_after_s": self.shed_retry_after_s,
                 "shed_total": self.stats.get("shed", 0),
-                # executables this engine's step programs have gained,
-                # and those of them the compile cache did not have: a
-                # replica whose counts still rise is still compiling
+                # executables the step programs gained and those the
+                # cache lacked; ring positions the sliding kernel read
                 "programs_built": self.stats.get("programs_built", 0),
                 "cold_builds": self.stats.get("cold_builds", 0),
+                "sliding_kernel_keys": self.stats["sliding_kernel_keys"],
                 "ttft_ewma_s": self._ttft_ewma,
                 "itl_ewma_s": self._itl_ewma,
                 "role": self.role,
@@ -2868,9 +2868,9 @@ class LLMEngine:
         self.stats["decode_context_tokens"] += total
         if self.sliding_window:
             keys = sum(min(e, self.sliding_window) for e in ends)
-            self._round_info["decode_sliding_keys"] = (
-                self._round_info.get("decode_sliding_keys", 0) + keys)
-            self.stats["decode_sliding_keys"] += keys
+            self._note_sliding(
+                decode_sliding_keys=keys,
+                sliding_kernel_keys=self._ring_kernel_keys(len(ends)))
 
     def _note_state_slots(self, n: int) -> None:
         """``n`` slots' recurrent state was advanced by a dispatch (a
@@ -3449,3 +3449,41 @@ class LLMEngine:
         self._hb = time.monotonic()   # dispatch completed: a long
                                       # prompt prefilling chunk by
                                       # chunk is moving, not wedged
+
+    # The sliding layers' counters stand BELOW the dispatch sites: an
+    # operation's location in a step program carries its callers'
+    # lines, so a line added at or above a site re-keys every cell's
+    # compile cache (PERF.md section 7, after PR 45).
+
+    def _note_sliding(self, **counts: int) -> None:
+        """Add a decode dispatch's ``counts`` to the ``round`` event
+        and the stats of a model with sliding-window layers (no other
+        model's carry the keys)."""
+        for key, n in counts.items():
+            self._round_info[key] = self._round_info.get(key, 0) + n
+            self.stats[key] += n
+
+    def _ring_kernel_keys(self, riders: int) -> int:
+        """``sliding_kernel_keys`` of a decode dispatch of ``riders``:
+        the ring positions ONE sliding layer's kernel
+        (ops/ring_window_attention.py) fetches for them, beside
+        ``decode_sliding_keys`` (the riders' windows, what must be
+        read); 0 where the decode program holds the ``jax.numpy`` form.
+        ``ring_window.applies``, the very question the layer asks, of a
+        decode step's queries and new keys and one layer's rings as the
+        pool stores them, under the mesh the program is traced under."""
+        # imported here for the same reason: the module's head stands
+        # above every site
+        from ray_tpu.models.kv_cache import SlidingRing
+        from ray_tpu.ops import ring_window_attention as ring_window
+        cfg = self.cfg
+        ring = next(jax.ShapeDtypeStruct(e.k.shape, e.k.dtype)
+                    for e in self.pages if isinstance(e, SlidingRing))
+        q, k = (jax.ShapeDtypeStruct((self.S, 1, heads, cfg.head_dim),
+                                     cfg.dtype)
+                for heads in (cfg.n_heads, cfg.n_kv_heads))
+        with ambient_mesh(self._mesh):
+            serves = ring_window.applies(q, k, k, ring, ring,
+                                         self.sliding_window)
+        return ring_window.kernel_keys(riders, self.ring_len) if serves \
+            else 0

@@ -73,6 +73,33 @@ def tiny():
     return cfg, model, params
 
 
+@pytest.fixture(params=["form", "kernel"])
+def ring_form(request, monkeypatch):
+    """The sliding layers through ``ring_append`` + ``ring_attention``
+    (what the CPU runs) and through the Pallas kernel of
+    ops/ring_window_attention.py in interpret mode, steered there as
+    the chip's rule would (float32 here, exactly): the step programs
+    are built anew so that none traced under the other form is
+    reused."""
+    from ray_tpu.ops import ring_window_attention as rw
+    from ray_tpu.serve import step_programs
+    programs = (step_programs._jit_prefill, step_programs._jit_decode)
+    if request.param == "kernel":
+        calls = []
+        monkeypatch.setattr(rw, "applies", lambda *a: True)
+        kernel = rw.ring_window_kernel
+        monkeypatch.setattr(
+            rw, "ring_window_kernel", lambda *a, **kw: calls.append(
+                a[0].shape) or kernel(*a, interpret=True, **kw))
+        for program in programs:
+            program.cache_clear()
+    yield request.param
+    if request.param == "kernel":
+        assert calls, "the kernel was never traced"
+        for program in programs:
+            program.cache_clear()
+
+
 def _ids(shape, seed=0):
     return np.random.default_rng(seed).integers(1, 255, size=shape)
 
@@ -323,7 +350,7 @@ def _call(model, params, table, slots):
     return call
 
 
-def test_paged_logits_match_the_reference(tiny):
+def test_paged_logits_match_the_reference(tiny, ring_form):
     """Chunked prefill of 600 tokens in chunks of 16 (eighteen turns of
     the 32-position ring, 150 pages of 4, across the 512-token edge of
     the page loop's first block and far past YaRN's 32 original
@@ -392,7 +419,7 @@ def test_rows_of_different_lengths_in_one_call(tiny):
                                    rtol=RTOL, atol=ATOL)
 
 
-def test_a_stale_ring_is_never_visible(tiny):
+def test_a_stale_ring_is_never_visible(tiny, ring_form):
     """A slot whose rings a longer request filled (here: with values a
     thousand times a key's) serves a shorter one, whose logits are the
     reference's: an index this request has not written is masked by the
@@ -440,7 +467,7 @@ def test_mixed_rows_through_both_kinds_of_entry(tiny):
     assert eng.alloc.occupancy() == 0 and eng.alloc.leak_report() == []
 
 
-def test_a_reused_slot_serves_a_shorter_request(tiny):
+def test_a_reused_slot_serves_a_shorter_request(tiny, ring_form):
     """One slot, two requests in turn: the second, shorter than the
     first, finds the first's keys all round its ring and must see none
     of them."""
@@ -459,14 +486,17 @@ def test_a_reused_slot_serves_a_shorter_request(tiny):
     assert h.result() == h2.result()
 
 
-def test_preemption_recomputes_both_kinds_of_entry(tiny):
+def test_preemption_recomputes_both_kinds_of_entry(tiny, ring_form):
     """A pool too small for two growing requests: the younger is
     evicted, its pages freed, and requeued with prompt + generated,
     prefilled again from position 0 (its rings written again from
     index 0) and gives the tokens it would have given alone."""
     cfg, _model, params = tiny
+    # chunks of 8 make a ring of 24, which no write-back block of the
+    # kernel's divides (the rule keeps the form there): the kernel's
+    # case has chunks of 16 and the ring of 32
     small = dict(max_slots=2, page_size=4, n_pages=14, chunk=2,
-                 prefill_chunk=8)
+                 prefill_chunk=8 if ring_form == "form" else 16)
     eng = _engine(tiny, **small)
     prompts = [_ids((12,), seed=40).tolist(), _ids((11,), 41).tolist()]
     handles = [eng.submit(p, max_new_tokens=22) for p in prompts]
@@ -568,6 +598,49 @@ def test_decode_sliding_keys_by_hand(tiny):
     dense.submit([3, 4, 5, 6], max_new_tokens=6)
     _drive(dense)
     assert all("decode_sliding_keys" not in r for r in _rounds(dense))
+
+
+def test_sliding_kernel_keys_by_hand(tiny, monkeypatch):
+    """The ``round`` event's ``sliding_kernel_keys`` beside
+    ``decode_sliding_keys``: 0 where the decode program holds the
+    ``jax.numpy`` form (the CPU); where the kernel's rule says yes,
+    every rider's WHOLE ring (the kernel's own block arithmetic:
+    ``kernel_keys``), asked of the shapes the layer hands the kernel;
+    in ``stats`` and ``load_report()`` too."""
+    from ray_tpu.ops import ring_window_attention as rw
+    cfg = tiny[0]
+    eng = _engine(tiny, chunk=4)
+    eng.submit(_ids((5,), seed=70).tolist(), max_new_tokens=13)
+    _drive(eng)
+    dec = [r for r in _rounds(eng) if r["decode_steps"]]
+    assert dec and all(r["sliding_kernel_keys"] == 0 for r in dec)
+    assert eng.load_report()["sliding_kernel_keys"] == 0
+    asked = []
+    monkeypatch.setattr(rw, "applies",
+                        lambda *a: asked.append(a) or True)
+    eng.submit(_ids((5,), seed=70).tolist(), max_new_tokens=13)
+    eng.submit(_ids((40,), seed=71).tolist(), max_new_tokens=13)
+    _drive(eng)
+    after = [r for r in _rounds(eng) if r["decode_steps"]][len(dec):]
+    L = sliding_ring_len(cfg, PAGE, CHUNK)
+    assert L == 32 and rw.kernel_keys(2, L) == 64
+    assert after and max(r["decode_riders"] for r in after) == 2
+    for r in after:
+        assert r["sliding_kernel_keys"] == r["decode_riders"] * L
+        assert r["sliding_kernel_keys"] >= r["decode_sliding_keys"]
+    total = sum(r["sliding_kernel_keys"] for r in after)
+    assert eng.stats["sliding_kernel_keys"] == total
+    assert eng.load_report()["sliding_kernel_keys"] == total
+    # the layer's own question: a decode step's queries and new keys,
+    # one layer's rings as the pool stores them
+    q, k, v, ring_k, ring_v, window = asked[0]
+    assert (q.shape, k.shape, v.shape) == (
+        (4, 1, cfg.n_heads, cfg.head_dim),
+        (4, 1, cfg.n_kv_heads, cfg.head_dim),
+        (4, 1, cfg.n_kv_heads, cfg.head_dim))
+    assert ring_k.shape == ring_v.shape == (4, cfg.n_kv_heads, L,
+                                            cfg.head_dim)
+    assert window == cfg.sliding_window and q.dtype == ring_k.dtype
 
 
 def test_the_scopes_by_layer_type_reach_both_programs(tiny):
