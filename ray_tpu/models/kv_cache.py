@@ -667,3 +667,17 @@ def kv_page_heads(cfg) -> int:
     decode kernel's rule of whole sublane tiles holds too. (Defined at
     the file's end for ``kv_entries_per_layer``'s reason.)"""
     return int(getattr(cfg, "kv_page_heads", None) or cfg.n_kv_heads)
+
+
+def kv_query_heads(cfg, kind: str) -> int:
+    """Query heads a layer of ``kind`` hands its attention:
+    ``cfg.n_heads``, or the config's own ``query_heads_by_kind`` where
+    its layer types differ in their query over one K/V pool (models/
+    laguna.py: 48 heads in a full layer, 64 in a sliding one, over 8
+    K/V heads in both). What the engine builds a decode step's query
+    from when it asks a kind's kernel whether it serves this model, so
+    that ``decode_kernel_pages`` and ``sliding_kernel_keys`` answer for
+    the shape the layer really hands it. (Defined at the file's end for
+    ``kv_entries_per_layer``'s reason.)"""
+    by_kind = getattr(cfg, "query_heads_by_kind", None) or {}
+    return int(by_kind.get(kind, cfg.n_heads))

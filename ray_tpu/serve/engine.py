@@ -106,7 +106,7 @@ from ray_tpu.models.kv_cache import (KIND_KV, KIND_LATENT, BlockAllocator,
                                      PagedKVLayer, check_kv_dtype,
                                      export_page_bytes,
                                      has_latent_pages, init_kv_pool,
-                                     kv_pool_page_bytes,
+                                     kv_pool_page_bytes, kv_query_heads,
                                      latent_page_width, layer_kinds,
                                      page_cols_from_bytes, page_layout,
                                      refuse_unsupported,
@@ -2833,8 +2833,8 @@ class LLMEngine:
         # of query heads for every head ROW the page stores, the rows
         # that pad it among them (models/olmo_hybrid.py: 30 heads
         # stored, and so asked, as 32)
-        heads = (cfg.n_heads if latent else
-                 k.shape[-2] * (cfg.n_heads // cfg.n_kv_heads))
+        heads = (cfg.n_heads if latent else k.shape[-2] * (
+            kv_query_heads(cfg, KIND_KV) // cfg.n_kv_heads))
         q = jax.ShapeDtypeStruct((self.S, 1, heads, k.shape[-1]),
                                  cfg.dtype)
         table = jax.ShapeDtypeStruct((self.S, self.max_pages), jnp.int32)
@@ -3474,14 +3474,15 @@ class LLMEngine:
         pool stores them, under the mesh the program is traced under."""
         # imported here for the same reason: the module's head stands
         # above every site
-        from ray_tpu.models.kv_cache import SlidingRing
+        from ray_tpu.models.kv_cache import KIND_SLIDING, SlidingRing
         from ray_tpu.ops import ring_window_attention as ring_window
         cfg = self.cfg
         ring = next(jax.ShapeDtypeStruct(e.k.shape, e.k.dtype)
                     for e in self.pages if isinstance(e, SlidingRing))
         q, k = (jax.ShapeDtypeStruct((self.S, 1, heads, cfg.head_dim),
                                      cfg.dtype)
-                for heads in (cfg.n_heads, cfg.n_kv_heads))
+                for heads in (kv_query_heads(cfg, KIND_SLIDING),
+                              cfg.n_kv_heads))
         with ambient_mesh(self._mesh):
             serves = ring_window.applies(q, k, k, ring, ring,
                                          self.sliding_window)

@@ -27,8 +27,13 @@ test_olmo_hybrid_family.py (the Olmo-Hybrid family: the configuration
 against its published copy, the program against the reference and the
 margin rule against the reference's controls, byte counts by kind of
 layer, the four new readers and the older ones on a hand-made joined
-trace, the cell on sample-sat as it stands, the rehearsal cell),
-collected here so that the suite the driver runs guards them.
+trace, the cell on sample-sat as it stands, the rehearsal cell) and
+test_laguna_family.py (the Laguna family: the configuration against its
+published copy, the program against the reference and the margin rule
+against the reference's controls, byte counts by layer type, the ring
+copies by opcode, the three new readers and the older ones on a
+hand-made joined trace, the cell on gen-sat as it stands, the rehearsal
+cell), collected here so that the suite the driver runs guards them.
 `python -m pytest benchmarks/tests` still runs them where they live."""
 import pytest
 
@@ -40,7 +45,8 @@ _FILES = ("benchmarks.tests.test_families",
           "benchmarks.tests.test_kimi_linear_family",
           "benchmarks.tests.test_mellum2_family",
           "benchmarks.tests.test_ouro_family",
-          "benchmarks.tests.test_olmo_hybrid_family")
+          "benchmarks.tests.test_olmo_hybrid_family",
+          "benchmarks.tests.test_laguna_family")
 pytest.register_assert_rewrite(*_FILES)
 
 from benchmarks.tests.test_families import *          # noqa: E402,F401,F403
@@ -52,6 +58,7 @@ from benchmarks.tests.test_kimi_linear_family import *  # noqa: E402,F401,F403
 from benchmarks.tests.test_mellum2_family import *    # noqa: E402,F401,F403
 from benchmarks.tests.test_ouro_family import *       # noqa: E402,F401,F403
 from benchmarks.tests.test_olmo_hybrid_family import *  # noqa: E402,F401,F403
+from benchmarks.tests.test_laguna_family import *     # noqa: E402,F401,F403
 
 # Recorded without tier-1's low-optimisation XLA flags (tests/conftest.py),
 # under which the CPU draws a normal's last bits differently: the digests
@@ -100,6 +107,13 @@ del test_seeded_weights_are_the_parents_bit_for_bit    # noqa: F821
 # the file less those four, the case of the four dispatch readers pins
 # them TWENTY before the file's end, and tests/test_build_log.py pins
 # PR 51's.
+# PR 53 appended a configuration, a cell and three readers, and the cell
+# to the lists of twenty-three older metrics: every older case (PR 51's
+# pin among them, tests/test_build_log.py's) runs against the file less
+# those too, the case of the four dispatch readers pins them
+# TWENTY-THREE before the file's end, and benchmarks/tests/
+# test_laguna_family.py::test_the_cell_and_gen_sat_as_it_stands pins
+# PR 53's.
 _DISPATCH = ("dispatch_prefill_call_ms", "dispatch_decode_step_ms",
              "dispatch_prefill_share", "dispatch_prefill_call_ms.open")
 _PR39 = ("state_peak_share", "linear_state_roofline.by_kind",
@@ -115,9 +129,13 @@ _PR49 = ("hybrid_step_roofline", "prefill_linear_attn_share",
 _PR49_CELL, _PR49_CONFIG = "olmo-hybrid-d16.sample-sat", "olmo-hybrid-7b-d16"
 _PR51 = ("setup_build_s", "setup_program_trace_s", "setup_cold_builds",
          "engine_init_s")
+_PR53 = ("swa_moe_step_roofline", "decode_attn_gate_ms",
+         "moe_rows_per_expert_mean")
+_PR53_CELL, _PR53_CONFIG = "laguna-xs2-d5.gen-sat", "laguna-xs.2-d5"
 _SAT = ["mistral7b-d16.chat-sat", "olmoe-d8.chat-sat",
         "solar-open2-d4.doc-sat", "axk1-d5.longdoc-sat",
-        "kimi-linear-d8.gen-sat", _PR42_CELL, _PR46_CELL, _PR49_CELL]
+        "kimi-linear-d8.gen-sat", _PR42_CELL, _PR46_CELL, _PR49_CELL,
+        _PR53_CELL]
 
 
 def _less_a_pr(bench, config, cell, readers):
@@ -135,9 +153,15 @@ def _less_a_pr(bench, config, cell, readers):
     return bench
 
 
+def _less_pr53(bench):
+    """BENCHMARK.json as PR 52 left it."""
+    return _less_a_pr(bench, _PR53_CONFIG, _PR53_CELL, _PR53)
+
+
 def _less_pr51(bench):
-    """BENCHMARK.json as PR 50 left it: without its last four readers,
-    which must be these."""
+    """BENCHMARK.json as PR 50 left it: without PR 53's entries and
+    then its last four readers, which must be these."""
+    bench = _less_pr53(bench)
     assert tuple(m["name"] for m in bench["per_layer"][-4:]) == _PR51
     bench["per_layer"] = bench["per_layer"][:-4]
     return bench
@@ -228,8 +252,8 @@ test_the_cell_and_sample_sat_as_it_stands = _as_pr50_left_it(
 def test_dispatch_readers_are_appended_to_the_benchmark(name):
     from benchmarks import common
     bench = common.load_benchmark()
-    assert tuple(m["name"] for m in bench["per_layer"][-24:]) == \
-        _DISPATCH + _PR39 + _PR42 + _PR46 + _PR49 + _PR51
+    assert tuple(m["name"] for m in bench["per_layer"][-27:]) == \
+        _DISPATCH + _PR39 + _PR42 + _PR46 + _PR49 + _PR51 + _PR53
     m = common.find_named(bench["per_layer"], name, "metric")
     want = {"name": name, "unit": "ms", "better": "lower",
             "source": "device_trace", "layer": "model step",

@@ -943,6 +943,121 @@ def test_ring_window_kernel_compiles(one_chip, B, T):
 
 
 # ---------------------------------------------------------------
+# A model whose QUERY differs by layer type over one K/V pool at its
+# cell's widths (Laguna-XS.2: 48 query heads in a full layer, 64 in a
+# sliding one, over 8 KV heads of 128 in both; a window of 512 in a ring
+# of 832 positions a slot, 128 slots, 4,609 pages of 64, a page table 64
+# wide; the dense layer and the first sliding layer with 2 of 256
+# experts held keep the compile short): both programs build, the ring
+# kernel at L = 832 and H = 64 (a group of 8) and the paged decode
+# kernel at H = 48 (a group of 6) as the step programs build them, and
+# rings and pool stay where they lie.
+
+LAGUNA_SLOTS, LAGUNA_PAGES = 128, 4609
+
+
+def _query_widths_step(name, one_chip):
+    from ray_tpu.models.kv_cache import init_kv_pool, sliding_ring_len
+    from ray_tpu.models.laguna import Laguna, laguna_xs2
+    from ray_tpu.serve import step_programs
+    cfg = laguna_xs2(n_layers=2, max_seq_len=4096, experts_held=(0, 2),
+                     param_dtype=jnp.bfloat16)
+    model = Laguna(cfg)
+    ring_len = sliding_ring_len(cfg, PAGE, 256)
+    assert ring_len == 832
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                           sharding=one_chip), tree)
+    params = placed({"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]})
+    pages = placed(jax.eval_shape(
+        lambda: init_kv_pool(cfg, LAGUNA_PAGES, PAGE, n_slots=LAGUNA_SLOTS,
+                             ring_len=ring_len)))
+    i32 = jnp.int32
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    table = ((LAGUNA_SLOTS, cfg.max_seq_len // PAGE), i32)
+    if name == "decode":
+        fn = step_programs._jit_decode(model, 0.0, 128, LAGUNA_SLOTS,
+                                       False, None)
+        rest = [table, ((LAGUNA_SLOTS,), i32), ((LAGUNA_SLOTS,), i32),
+                (key.shape, key.dtype), ((), i32)]
+    else:
+        fn = step_programs._jit_prefill(model, 0.0, 4, False, None)
+        rest = [((4, 256), i32), ((4,), i32), ((4,), i32),
+                ((4, table[0][1]), i32), (key.shape, key.dtype),
+                ((4,), i32)]
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in rest]
+    return fn.lower(params, pages, *rest).compile()
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_two_query_widths_over_one_pool_build_both_kernels(
+        one_chip, monkeypatch, name):
+    from ray_tpu.ops import grouped_matmul as gm
+    from ray_tpu.ops import paged_decode_attention as pd
+    from ray_tpu.ops import ring_window_attention as rw
+    from ray_tpu.serve import step_programs
+    monkeypatch.setattr(gm, "_use_kernel", lambda: True)
+    monkeypatch.setattr(rw, "_on_one_tpu", lambda: True)
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
+    for cached in ("_jit_decode", "_jit_prefill"):
+        monkeypatch.setattr(step_programs, cached,
+                            getattr(step_programs, cached).__wrapped__)
+    assert rw.write_rows(832, 1) == rw.write_rows(832, 256) == 64
+    assert pd.pages_per_visit(48, PAGE, 8, 64) == 4
+    compiled = _query_widths_step(name, one_chip)
+    text = compiled.as_text()
+    ring = r"bf16\[128,8,832,128\]"
+    pool = r"bf16\[4609,64,8,128\]"
+    # the sliding layer: ONE ring call under its scope, 64 query heads
+    # a token (a chunk's rows 4 x 256), the rings its operands and its
+    # results
+    calls = re.findall(
+        r"%ring_window[.\d]* = \([^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*", text)
+    assert len(calls) == 1, len(calls)
+    assert "attn_sliding/jit(ring_window_kernel)/ring_window" in calls[0]
+    assert len(re.findall(ring, calls[0].split("custom-call(")[0])) == 2
+    # (a chunk's result comes back as [rows, tokens, 64 x 128])
+    heads = "bf16[128,1,64,128]" if name == "decode" else "bf16[4,256,8192]"
+    assert heads in calls[0], calls[0][:300]
+    # the full layer: the decode kernel at 48 heads in jit_decode, the
+    # block loop in jit_prefill
+    paged = re.findall(
+        r"%paged_decode[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*", text)
+    assert len(paged) == (1 if name == "decode" else 0), len(paged)
+    if paged:
+        assert "attn_full" in paged[0]
+        assert "bf16[128,1,48,128]" in paged[0], paged[0][:300]
+        assert "kv_gather" not in text
+    else:
+        assert "attn_full/kv_gather" in text
+    # the gate inside either type's scope, the dense layer, the shared
+    # expert
+    for scope in ("attn_sliding/attn_gate", "attn_full/attn_gate",
+                  "layers_0/feed_forward", "moe_shared"):
+        assert scope in text, scope
+    # nothing passes over a whole ring or the whole pool
+    for what, shape in (("ring", ring), ("pool", pool)):
+        copies = re.findall(
+            r"= " + shape + r"(?:\{[^}]*\})? (?:copy|transpose)\(", text)
+        assert not copies, f"{len(copies)} whole-{what} copies in {name}"
+    assert not _pool_copies(text, (128, 8, 832, 128)) + _pool_copies(
+        text, (4609, 64, 8, 128))
+    for op in (r"copy-start\(", r"copy-done\(", r"slice-start\("):
+        assert not re.findall(ring + r"[^\n]* " + op, text), op
+    entry = re.findall(ring + r"(\{[^}]*\}) parameter", text)
+    assert len(entry) >= 2 and all(e.startswith("{3,2,1,0") for e in entry)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (300 << 20 if name == "decode" else 900 << 20), temp
+
+
+# ---------------------------------------------------------------
 # A model whose layers run several times at its cell's sizes, WHOLE
 # (Ouro-2.6B: 48 layers of 16 heads over 16 KV heads of 128 run 4 times,
 # the whole vocabulary, 16 slots, 97 pages of 64 tokens whose pages

@@ -18,8 +18,8 @@ from ray_tpu.models.kv_cache import (KIND_KV, KIND_LATENT, KIND_RECURRENT,
 SERVE = pathlib.Path(__file__).resolve().parent.parent / "ray_tpu" / "serve"
 # the family modules serve/ may import: sampling and the tiny default
 # (llama), the mixture's counters every mixture family shares (mixtral)
-_FAMILY_MODULES = {"axk1", "kimi_linear", "mellum", "olmo_hybrid", "ouro",
-                   "solar_open2"}
+_FAMILY_MODULES = {"axk1", "kimi_linear", "laguna", "mellum", "olmo_hybrid",
+                   "ouro", "solar_open2"}
 
 
 def _trees():
@@ -72,6 +72,7 @@ def test_the_host_loop_holds_no_device_program():
 def _families():
     from ray_tpu.models.axk1 import AXK1, axk1_tiny
     from ray_tpu.models.kimi_linear import KimiLinear, kimi_linear_tiny
+    from ray_tpu.models.laguna import Laguna, laguna_tiny
     from ray_tpu.models.llama import Llama, llama_tiny
     from ray_tpu.models.mellum import Mellum, mellum_tiny
     from ray_tpu.models.mixtral import Mixtral, mixtral_tiny
@@ -82,13 +83,14 @@ def _families():
             "mixtral": (mixtral_tiny, Mixtral, "moe/w2"),
             "axk1": (axk1_tiny, AXK1, None),
             "kimi_linear": (kimi_linear_tiny, KimiLinear, None),
+            "laguna": (laguna_tiny, Laguna, None),
             "mellum": (mellum_tiny, Mellum, None),
             "olmo_hybrid": (olmo_hybrid_tiny, OlmoHybrid, None),
             "ouro": (ouro_tiny, Ouro, None),
             "solar_open2": (solar_open2_tiny, SolarOpen2, None)}
 
 
-FAMILIES = ("llama", "mixtral", "axk1", "kimi_linear", "mellum",
+FAMILIES = ("llama", "mixtral", "axk1", "kimi_linear", "laguna", "mellum",
             "olmo_hybrid", "ouro", "solar_open2")
 
 
