@@ -333,12 +333,14 @@ class AXK1(nn.Module):
     config: AXK1Config
 
     @nn.compact
-    def __call__(self, input_ids, kv_caches=None, cache_len=None):
+    def __call__(self, input_ids, kv_caches=None, cache_len=None,
+                 logits_at=None):
         k = self.config.first_k_dense
         return transformer_forward(
             self, self.config,
             lambda i: AXK1DenseBlock if i < k else AXK1MoEBlock,
-            input_ids, kv_caches, cache_len, rope=False)
+            input_ids, kv_caches, cache_len, rope=False,
+            logits_at=logits_at)
 
 
 def mla_param_count(cfg) -> int:

@@ -188,11 +188,13 @@ class KimiLinear(nn.Module):
     config: KimiLinearConfig
 
     @nn.compact
-    def __call__(self, input_ids, kv_caches=None, cache_len=None):
+    def __call__(self, input_ids, kv_caches=None, cache_len=None,
+                 logits_at=None):
         return transformer_forward(
             self, self.config,
             lambda i: functools.partial(KimiLinearBlock, index=i),
-            input_ids, kv_caches, cache_len, rope=False)
+            input_ids, kv_caches, cache_len, rope=False,
+            logits_at=logits_at)
 
 
 def kda_param_count(cfg) -> int:

@@ -348,12 +348,14 @@ class Laguna(nn.Module):
     config: LagunaConfig
 
     @nn.compact
-    def __call__(self, input_ids, kv_caches=None, cache_len=None):
+    def __call__(self, input_ids, kv_caches=None, cache_len=None,
+                 logits_at=None):
         # each layer rotates by its own type's rule: no shared table
         return transformer_forward(
             self, self.config,
             lambda i: functools.partial(LagunaBlock, index=i),
-            input_ids, kv_caches, cache_len, rope=False)
+            input_ids, kv_caches, cache_len, rope=False,
+            logits_at=logits_at)
 
 
 def laguna_param_count(cfg: LagunaConfig,

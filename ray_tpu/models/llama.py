@@ -279,7 +279,8 @@ class LlamaBlock(nn.Module):
 
 
 def transformer_forward(mod: nn.Module, cfg, block_of, input_ids,
-                        kv_caches=None, cache_len=None, rope=True):
+                        kv_caches=None, cache_len=None, rope=True,
+                        logits_at=None):
     """Shared decoder-transformer body (embedding, RoPE table,
     position/cache plumbing, layer loop, final norm, logits through
     the embedding or, with ``tie_word_embeddings`` false, through a
@@ -289,8 +290,13 @@ def transformer_forward(mod: nn.Module, cfg, block_of, input_ids,
     rely on cannot drift per family. ``block_of`` gives layer i's
     block class: one class for every layer, or a class by the layer's
     kind (models/solar_open2.py). ``rope`` false: no position
-    encoding, so no table (the blocks get None). Called from a compact
-    __call__: submodules bind into the caller's scope."""
+    encoding, so no table (the blocks get None). ``logits_at`` ([B]
+    int32): the one position of each row whose logits the caller wants;
+    the final norm and the head then see ``[B, dim]`` and the logits are
+    ``[B, V]`` (the chunked-prefill program samples one position a row;
+    the norm and the head are each a position's own, so the gather may
+    come first). None: every position, ``[B, T, V]``. Called from a
+    compact __call__: submodules bind into the caller's scope."""
     B, T = input_ids.shape
     tok = mod.param("tok_embeddings",
                     nn.initializers.normal(0.02),
@@ -315,6 +321,8 @@ def transformer_forward(mod: nn.Module, cfg, block_of, input_ids,
         x, nc = block(cfg, name=f"layers_{i}")(
             x, freqs, positions, cache_i, cache_len)
         new_caches.append(nc)
+    if logits_at is not None:
+        x = x[jnp.arange(B), logits_at]                # [B, dim]
     x = RMSNorm(cfg.norm_eps, name="norm")(x)
     head = tok
     if not cfg.tie_word_embeddings:
@@ -323,7 +331,7 @@ def transformer_forward(mod: nn.Module, cfg, block_of, input_ids,
     with jax.named_scope("head"):
         logits = jax.lax.dot_general(
             x.astype(cfg.dtype), head.astype(cfg.dtype),
-            (((2,), (1,)), ((), ())),
+            (((x.ndim - 1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
     if kv_caches is None:
         return logits, None
@@ -348,12 +356,15 @@ class Llama(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, input_ids, kv_caches=None, cache_len=None):
+    def __call__(self, input_ids, kv_caches=None, cache_len=None,
+                 logits_at=None):
         """Returns (logits, new_kv_caches). kv_caches: list per layer of
-        (k, v) arrays [B, max_seq, n_kv_heads, head_dim]."""
+        (k, v) arrays [B, max_seq, n_kv_heads, head_dim]. ``logits_at``
+        [B]: one position's logits a row (transformer_forward)."""
         return transformer_forward(self, self.config,
                                    lambda i: LlamaBlock,
-                                   input_ids, kv_caches, cache_len)
+                                   input_ids, kv_caches, cache_len,
+                                   logits_at=logits_at)
 
 
 def init_kv_caches(cfg: LlamaConfig, batch: int, max_len: int):

@@ -352,10 +352,12 @@ class Mixtral(nn.Module):
     config: MixtralConfig
 
     @nn.compact
-    def __call__(self, input_ids, kv_caches=None, cache_len=None):
+    def __call__(self, input_ids, kv_caches=None, cache_len=None,
+                 logits_at=None):
         return transformer_forward(self, self.config,
                                    lambda i: MixtralBlock,
-                                   input_ids, kv_caches, cache_len)
+                                   input_ids, kv_caches, cache_len,
+                                   logits_at=logits_at)
 
 
 def mixtral_sharding_rules(fsdp: bool = True) -> ShardingRules:

@@ -364,11 +364,13 @@ class OlmoHybrid(nn.Module):
     config: OlmoHybridConfig
 
     @nn.compact
-    def __call__(self, input_ids, kv_caches=None, cache_len=None):
+    def __call__(self, input_ids, kv_caches=None, cache_len=None,
+                 logits_at=None):
         return transformer_forward(
             self, self.config,
             lambda i: functools.partial(OlmoHybridBlock, index=i),
-            input_ids, kv_caches, cache_len, rope=False)
+            input_ids, kv_caches, cache_len, rope=False,
+            logits_at=logits_at)
 
 
 def linear_param_count(cfg: OlmoHybridConfig) -> int:

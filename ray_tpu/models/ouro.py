@@ -218,9 +218,10 @@ class Ouro(nn.Module):
     config: OuroConfig
 
     @nn.compact
-    def __call__(self, input_ids, kv_caches=None, cache_len=None):
+    def __call__(self, input_ids, kv_caches=None, cache_len=None,
+                 logits_at=None):
         cfg = self.config
-        T = input_ids.shape[1]
+        B, T = input_ids.shape
         passes = cfg.total_ut_steps
         tok = self.param("tok_embeddings", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.dim), cfg.param_dtype)
@@ -259,6 +260,11 @@ class Ouro(nn.Module):
         (_, entries), states = loop(cfg, name="stack")(
             (x, entries), jnp.arange(passes, dtype=jnp.int32), freqs,
             positions, page_table, pos)
+        if logits_at is not None:
+            # one position's logits a row (models/llama.py
+            # transformer_forward): the gate, the choice among passes
+            # and the head are each a position's own
+            states = states[:, jnp.arange(B), logits_at, None]
         with jax.named_scope("exit_gate"):
             # one number a position and pass, in float32; every pass
             # was taken: the rule only chooses among the normed states
@@ -268,12 +274,14 @@ class Ouro(nn.Module):
                                cfg.early_exit_threshold)        # [B, T]
             x = jnp.take_along_axis(
                 states, chosen[None, :, :, None], axis=0)[0]
+        if logits_at is not None:
+            x = x[:, 0]                                         # [B, dim]
         head = self.param("lm_head", nn.initializers.normal(0.02),
                           (cfg.vocab_size, cfg.dim), cfg.param_dtype)
         with jax.named_scope("head"):
             logits = jax.lax.dot_general(
                 x.astype(cfg.dtype), head.astype(cfg.dtype),
-                (((2,), (1,)), ((), ())),
+                (((x.ndim - 1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
         if kv_caches is None:
             return logits, None

@@ -275,12 +275,14 @@ class SolarOpen2(nn.Module):
     config: SolarOpen2Config
 
     @nn.compact
-    def __call__(self, input_ids, kv_caches=None, cache_len=None):
+    def __call__(self, input_ids, kv_caches=None, cache_len=None,
+                 logits_at=None):
         kinds = self.config.layer_kinds
         return transformer_forward(self, self.config,
                                    lambda i: _BLOCKS[kinds[i]],
                                    input_ids, kv_caches, cache_len,
-                                   rope=self.config.rope)
+                                   rope=self.config.rope,
+                                   logits_at=logits_at)
 
 
 def solar_open2_param_count(cfg: SolarOpen2Config,

@@ -480,6 +480,11 @@ def _new_round_info() -> Dict[str, int]:
     rounds). ``prefill_width`` is the ``T`` of the round's ``[rows, T]``
     prefill call (a power of two up to ``prefill_chunk``; 0 = no
     call): the shape a call's device time is grouped by.
+    ``prefill_head_rows`` is the positions that call applied the
+    model's output head to: its ``B``, one a row (the program asks the
+    model for the logits of each row's ``last_idx`` alone,
+    serve/step_programs.py ``_jit_prefill``), dummy rows included, where
+    ``B x T`` went through the head before; 0 = no call.
     ``decode_context_tokens`` is the sum over the decode dispatch's
     riders of their OWN context lengths after it (what each rider's
     last step attended), where ``decode_window_tokens`` is the longest
@@ -500,7 +505,8 @@ def _new_round_info() -> Dict[str, int]:
             "decode_kernel_pages": 0,
             "prefill_tokens": 0, "prefill_budget": 0,
             "prefill_rows": 0, "prefill_window_tokens": 0,
-            "prefill_kernel_blocks": 0, "prefill_width": 0}
+            "prefill_kernel_blocks": 0, "prefill_width": 0,
+            "prefill_head_rows": 0}
 
 
 class LLMEngine:
@@ -3432,6 +3438,8 @@ class LLMEngine:
         self.stats["prefill_rows"] += len(rows)
         self._round_info["prefill_rows"] += len(rows)
         self._round_info["prefill_width"] = T
+        self.stats["prefill_head_rows"] += B
+        self._round_info["prefill_head_rows"] += B
         self._note_state_slots(len(rows))
         _granted = sum(take for _ix, _s, take in rows)
         self.stats["prefill_tokens"] += _granted

@@ -57,22 +57,25 @@ def _moe_apply(model, mesh):
     mixture-of-experts model the third result is (the int32 vector,) of
     what the router chose over the program's live tokens
     (models/mixtral.py moe_stats_vector; ``live()`` gives the [B, T]
-    mask); for a dense model it is ()."""
+    mask); for a dense model it is (). ``logits_at`` [B]: the one
+    position of each row the program wants logits for, ``[B, V]``
+    (models/llama.py transformer_forward); None: every position's."""
     E, held, _ = _moe_vector_of(model)
     if not E:
-        def apply(params, ids, kv, start, live):
+        def apply(params, ids, kv, start, live, logits_at=None):
             with ambient_mesh(mesh):
                 logits, new_kv = model.apply(params, ids, kv_caches=kv,
-                                             cache_len=start)
+                                             cache_len=start,
+                                             logits_at=logits_at)
             return logits, new_kv, ()
         return apply
     from ray_tpu.models.mixtral import MOE_STATS, moe_stats_vector
 
-    def apply(params, ids, kv, start, live):
+    def apply(params, ids, kv, start, live, logits_at=None):
         with ambient_mesh(mesh):
             (logits, new_kv), sown = model.apply(
                 params, ids, kv_caches=kv, cache_len=start,
-                mutable=[MOE_STATS])
+                logits_at=logits_at, mutable=[MOE_STATS])
         with jax.named_scope("moe_stats"):
             vec = moe_stats_vector(sown[MOE_STATS], live(),
                                    model.config.num_experts, held)
@@ -129,7 +132,9 @@ def _jit_prefill(model, temp, B, capture, mesh):
     attend causally over each row's own page window. The row's last
     real position samples a candidate first token — junk for rows
     mid-prompt, consumed only for rows that just finished their
-    prompt."""
+    prompt. The model is asked for that one position's logits a row
+    (``logits_at``): the head sees [B, dim], and the program holds no
+    [B, T, V] value."""
     constrain = _constrain_for(mesh)
     apply = _moe_apply(model, mesh)
     from ray_tpu.models.llama import _pick_token
@@ -145,11 +150,10 @@ def _jit_prefill(model, temp, B, capture, mesh):
         def live():
             return (page_table[:, :1] != 0) & (
                 jnp.arange(ids.shape[1])[None] <= last_idx[:, None])
-        logits, new_kv, moe = apply(
+        last, new_kv, moe = apply(
             params, ids, _views(pages, page_table, live, slots), start,
-            live)
+            live, last_idx)                           # [B, V]
         new_pages = constrain([kv_layer_store(c) for c in new_kv])
-        last = logits[jnp.arange(B), last_idx]        # [B, V]
         with jax.named_scope("sample"):
             firsts = _pick_token(last, sub, temp)
         if capture:

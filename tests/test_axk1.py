@@ -361,11 +361,13 @@ class _Mixed(nn.Module):
     config: _MixedConfig
 
     @nn.compact
-    def __call__(self, input_ids, kv_caches=None, cache_len=None):
+    def __call__(self, input_ids, kv_caches=None, cache_len=None,
+                 logits_at=None):
         return transformer_forward(
             self, self.config,
             lambda i: AXK1DenseBlock if i == 0 else _KVBlock,
-            input_ids, kv_caches, cache_len, rope=False)
+            input_ids, kv_caches, cache_len, rope=False,
+            logits_at=logits_at)
 
 
 def test_a_latent_layer_beside_a_kv_layer_in_one_pool():

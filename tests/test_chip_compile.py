@@ -301,8 +301,8 @@ def _f32_blocks(text, kv_heads):
     """Float32 tensors of the compiled program as large as one gathered
     block of the window loop (32 rows x 512 tokens x KH x 128) that
     carry its KV-head and head dimensions, in whatever order (the
-    prefill call's logits, f32[4,256,32768], are as large and are no
-    block)."""
+    verify call's logits, f32[32,5,32768], are large too and are no
+    block; the prefill call's are [4, 32768] since PR 54)."""
     block = SLOTS * 512 * kv_heads * 128
     found = []
     for m in re.finditer(r"= f32\[([0-9,]+)\]", text):
@@ -334,6 +334,76 @@ def test_block_loop_contracts_on_the_matrix_unit(step_program, name,
             r" convolution\([^\n]*" + scope + "/" + spec, text)
         # one a layer
         assert len(convs) >= 2, (scope, len(convs))
+
+
+# the chunked-prefill program samples ONE position a row, so it asks
+# the model for that position's logits alone (``logits_at``): the head
+# contracts [B, dim], and no [B, T, V] value exists in the program
+# (1,024 positions went through the head of a [4, 256] call, 4 were
+# read: PERF.md section 6, PR 54). The verify program reads every
+# position's argmax and keeps them all.
+_TINIES = {"llama": "llama_tiny", "mixtral": "mixtral_tiny",
+           "axk1": "axk1_tiny", "kimi_linear": "kimi_linear_tiny",
+           "laguna": "laguna_tiny", "mellum": "mellum_tiny",
+           "olmo_hybrid": "olmo_hybrid_tiny", "ouro": "ouro_tiny",
+           "solar_open2": "solar_open2_tiny"}
+
+
+def _head_results(text):
+    """Result shapes of the ``dot_general``s under the ``head`` scope of
+    a lowered program (``as_text(debug_info=True)``: an operation names
+    its location by reference)."""
+    head = set(re.findall(
+        r'^(#loc\d+) = loc\("[^"]*/head/dot_general"', text, re.M))
+    found = []
+    for m in re.finditer(r"stablehlo\.dot_general[^\n]*-> tensor<([0-9x]+)"
+                         r"xf32>[^\n]*loc\((#loc\d+)\)", text):
+        if m.group(2) in head:
+            found.append(tuple(int(d) for d in m.group(1).split("x")))
+    return found
+
+
+@pytest.mark.parametrize("family", sorted(_TINIES))
+def test_prefill_applies_the_head_to_one_position_a_row(family):
+    import importlib
+    from ray_tpu.models.kv_cache import (init_kv_pool, sliding_ring_len,
+                                         state_bytes_per_slot)
+    from ray_tpu.serve import step_programs
+    tiny = getattr(importlib.import_module(f"ray_tpu.models.{family}"),
+                   _TINIES[family])
+    B, T, V, S = 4, 16, 424, 6          # no other extent of a toy is 424
+    cfg = tiny(dtype=jnp.float32, vocab_size=V)
+    model = cfg.model_class(cfg)
+    params = {"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]}
+    ring = sliding_ring_len(cfg, 8, T)
+    pages = jax.eval_shape(
+        lambda: init_kv_pool(cfg, 17, 8, n_slots=S, ring_len=ring))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    arr, i32 = jax.ShapeDtypeStruct, jnp.int32
+    slots = (arr((B,), i32),) if state_bytes_per_slot(cfg, ring) else ()
+    prefill = step_programs._jit_prefill(model, 0.0, B, False, None).lower(
+        params, pages, arr((B, T), i32), arr((B,), i32), arr((B,), i32),
+        arr((B, 8), i32), key, *slots).as_text(debug_info=True)
+    assert _head_results(prefill) == [(B, V)]
+    assert f"tensor<{B}x{T}x{V}xf32>" not in prefill
+    assert f"tensor<{B}x{V}xf32>" in prefill
+    verify = step_programs._jit_verify(model, None).lower(
+        params, pages, arr((S, 5), i32), arr((S,), i32),
+        arr((S, 8), i32)).as_text(debug_info=True)
+    assert _head_results(verify) == [(S, 5, V)]
+
+
+def test_the_cells_prefill_program_holds_no_logits_of_the_call(
+        step_program):
+    """At a serving cell's shape, compiled for the chip: the [4, 256]
+    call's float32 logits (134 MB at Mistral's vocabulary, 403 MB at
+    Mellum 2's) are gone from the program, the four rows' are there."""
+    text = step_program("prefill", 8)[0].as_text()
+    assert "f32[4,256,32768]" not in text
+    assert "f32[4,32768]" in text
+    assert "f32[32,5,32768]" in step_program("verify", 8)[0].as_text()
 
 
 # a decode step over a bfloat16 K/V pool on one TPU attends through the

@@ -335,6 +335,7 @@ def edge(tmp_path_factory):
         eng.shutdown()
     evs = eng.events.snapshot()
     facts["events"] = evs
+    facts["stats"] = dict(eng.stats)
     facts["marks"] = [e[5] for e in evs
                       if e[2] in ("trace_start", "trace_stop")]
     facts["gc"] = [e for e in evs if e[2] == "gc"]
@@ -350,7 +351,8 @@ def edge(tmp_path_factory):
 
 @pytest.mark.parametrize("case", [
     "nothing_in_flight", "counts_add_up", "start_says_its_cost",
-    "prefill_width_is_the_calls_T", "cpu_within_wall",
+    "prefill_width_is_the_calls_T", "prefill_head_rows_is_the_calls_B",
+    "cpu_within_wall",
     "gc_pass_is_an_event", "gc_pass_is_a_span",
     "gc_hook_installed_once", "the_log_joins"])
 def test_trace_starts_on_a_rounds_edge(edge, case):
@@ -400,6 +402,15 @@ def test_trace_starts_on_a_rounds_edge(edge, case):
                 seen.add(want)
                 since = []
         assert {0, 8, 16} <= seen
+    elif case == "prefill_head_rows_is_the_calls_B":
+        # the head is applied to ONE position a row of the [4, T] call
+        # (step_programs._jit_prefill asks the model for ``last_idx``
+        # alone), dummy rows included; a round without a call says 0
+        heads = {(bool(d["prefill_rows"]), d["prefill_head_rows"])
+                 for d, _t in rounds}
+        assert heads == {(True, 4), (False, 0)}
+        assert edge["stats"]["prefill_head_rows"] == \
+            4 * edge["stats"]["prefills"]
     elif case == "cpu_within_wall":
         for d, _t in rounds:
             assert 0 <= d["cpu_s"] <= d["wall_s"] + 2e-3

@@ -247,3 +247,149 @@ def test_cache_entries_that_outnumber_the_layers_are_the_caches_to_count():
             kv_cache.page_cols_from_bytes(once, Pg, dtype, blobs)
         assert np.asarray(cols[0][0]).dtype == np.asarray(
             pool[0][0]).dtype
+
+
+# ------------------------ one position's logits a row (``logits_at``)
+
+_PAGE, _T = 8, 16
+
+
+@pytest.fixture(scope="module")
+def toy():
+    """get(family): (config, model, params) of the family's float32
+    toy, initialised once a module in one jitted call."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    @functools.lru_cache(maxsize=None)
+    def get(family):
+        tiny, cls, _rule = _families()[family]
+        cfg = tiny(dtype=jnp.float32)
+        model = cls(cfg)
+        params = jax.jit(model.init)(jax.random.PRNGKey(3),
+                                     jnp.zeros((1, 8), jnp.int32))
+        return cfg, model, {"params": params["params"]}
+    return get
+
+
+def _ids(cfg, shape, seed):
+    import jax
+    return jax.random.randint(jax.random.PRNGKey(seed), shape, 1,
+                              cfg.vocab_size)
+
+
+def _pool(cfg, rows):
+    """A pool of the family's own entries for ``rows`` slots (pages,
+    rings, recurrent states: whatever its layers keep) and a page
+    table giving each row four pages of its own."""
+    import jax.numpy as jnp
+    import numpy as np
+    pool = kv_cache.init_kv_pool(
+        cfg, 1 + 4 * rows, _PAGE, n_slots=rows,
+        ring_len=kv_cache.sliding_ring_len(cfg, _PAGE, _T))
+    table = jnp.asarray(1 + np.arange(4 * rows).reshape(rows, 4),
+                        jnp.int32)
+    return pool, table
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["no_cache", "paged"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_logits_at_is_the_rows_of_the_full_logits(toy, family, paged):
+    """``logits_at`` [B] gives ``[B, V]``: row i of it is position
+    ``logits_at[i]`` of the ``[B, T, V]`` the same call gives without
+    (the final norm, Ouro's gate and choice among passes, and the head
+    are each a position's own), for ragged indices (a row's first
+    position, its last, one inside), with and without a cache; the
+    default keeps every position."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    cfg, model, params = toy(family)
+    B = 3
+    ids = _ids(cfg, (B, _T), 11)
+    at = jnp.asarray([0, _T - 1, 7], jnp.int32)
+    if paged:
+        pool, table = _pool(cfg, B)
+
+        @jax.jit
+        def call(ids, at):
+            def valid():
+                return jnp.ones(ids.shape, bool)
+            views = [kv_cache.kv_layer_view(layer, table, jnp.arange(B),
+                                            valid) for layer in pool]
+            start = jnp.zeros((B,), jnp.int32)
+            return (model.apply(params, ids, kv_caches=views,
+                                cache_len=start)[0],
+                    model.apply(params, ids, kv_caches=views,
+                                cache_len=start, logits_at=at)[0])
+    else:
+        @jax.jit
+        def call(ids, at):
+            return (model.apply(params, ids)[0],
+                    model.apply(params, ids, logits_at=at)[0])
+    full, rows = call(ids, at)
+    assert full.shape == (B, _T, cfg.vocab_size)
+    assert rows.shape == (B, cfg.vocab_size)
+    assert full.dtype == rows.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(rows), np.asarray(full)[np.arange(B), np.asarray(at)],
+        rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("capture", [False, True],
+                         ids=["tokens", "logprobs"])
+@pytest.mark.parametrize("family", ["llama", "mixtral", "kimi_linear",
+                                    "ouro"])
+def test_the_prefill_program_samples_what_the_full_logits_would(
+        toy, family, capture):
+    """``jit_prefill`` asks the model for one position a row; its first
+    tokens (temperature 0) and, under ``capture``, their log-probabilities
+    equal the rule the program had until PR 54 (the full ``[B, T, V]``
+    logits, row ``last_idx`` of each, argmax, ``log_softmax``) for a call
+    that holds a row ending its prompt, a row mid-prompt at an offset, a
+    short row and a dummy row: a dense model, a mixture, one with
+    recurrent state, the looped one."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from ray_tpu.serve import step_programs
+    cfg, model, params = toy(family)
+    B = 4
+    ids = _ids(cfg, (B, _T), 12).at[2, 5:].set(0).at[3].set(0)
+    start = jnp.asarray([0, 8, 0, 0], jnp.int32)
+    last_idx = jnp.asarray([_T - 1, _T - 1, 4, 0], jnp.int32)
+    slots = jnp.asarray([0, 1, 2, B], jnp.int32)      # the dummy: none
+    pool, table = _pool(cfg, B)
+    table = table.at[3].set(0)                        # the dummy: null
+    key = jax.random.PRNGKey(5)
+    recurrent = bool(kv_cache.state_bytes_per_slot(
+        cfg, kv_cache.sliding_ring_len(cfg, _PAGE, _T)))
+    rest = (slots,) if recurrent else ()
+
+    @jax.jit
+    def until_pr54(pool):
+        def live():
+            return (table[:, :1] != 0) & (
+                jnp.arange(_T)[None] <= last_idx[:, None])
+        logits, _kv, _moe = step_programs._moe_apply(model, None)(
+            params, ids, step_programs._views(pool, table, live, *rest),
+            start, live)
+        assert logits.shape == (B, _T, cfg.vocab_size)
+        last = logits[jnp.arange(B), last_idx]
+        firsts = jnp.argmax(last, axis=-1).astype(jnp.int32)
+        lp = jnp.take_along_axis(jax.nn.log_softmax(last),
+                                 firsts[:, None], axis=-1)[:, 0]
+        return firsts, lp
+
+    want_firsts, want_lp = until_pr54(pool)
+    fn = step_programs._jit_prefill(model, 0.0, B, capture, None)
+    out = fn(params, _pool(cfg, B)[0], ids, start, last_idx, table, key,
+             *rest)[0]
+    firsts, lp = out if capture else (out, None)
+    np.testing.assert_array_equal(np.asarray(firsts),
+                                  np.asarray(want_firsts))
+    if capture:
+        np.testing.assert_allclose(np.asarray(lp), np.asarray(want_lp),
+                                   rtol=1e-5, atol=1e-6)
