@@ -48,6 +48,9 @@ from ray_tpu.models.llama import (LlamaMLP, RMSNorm, block_forward,
 from ray_tpu.models.mixtral import MoEFeedForward
 from ray_tpu.ops.paged_attention import (_paged_window_attention,
                                          paged_append)
+from ray_tpu.ops.sparse_latent_attention import (SELECTION_STATS,
+                                                 sparse_attention,
+                                                 topk_mask)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -202,8 +205,18 @@ class MLAttention(nn.Module):
     ``config`` is an ``AXK1Config`` or any config with its attention
     fields (models/kimi_linear.py's: ``q_lora_rank`` None gives the
     query one direct matrix ``wq``, ``mla_rope`` false leaves the
-    decoupled columns of query and key unrotated)."""
+    decoupled columns of query and key unrotated).
+
+    ``indexer`` (models/deepseek_v32.py ``LightningIndexer``; None:
+    every query attends every entry at or before it) is a module that
+    scores the entries against a query's low-rank latent ``c_q`` and
+    the layer's input, keeping its own keys in the cache's
+    ``pages_index``; a query then attends its ``config.index_topk``
+    best entries alone (ops/sparse_latent_attention.py). Up to that
+    many positions the choice is every entry and the layer is the one
+    without an indexer."""
     config: Any
+    indexer: Optional[nn.Module] = None
 
     @nn.compact
     def __call__(self, x, freqs, positions, kv_cache=None,
@@ -240,6 +253,14 @@ class MLAttention(nn.Module):
             kv = dense(R + dr, name="wkv_a")(x)
             c = RMSNorm(cfg.norm_eps, name="kv_norm")(kv[..., :R])
             k_rope = rope(kv[..., None, R:])
+        scores = None
+        if self.indexer is not None:
+            if cfg.q_lora_rank is None:
+                raise ValueError("an indexer reads the query's low-rank "
+                                 "latent, and q_lora_rank is None")
+            # [B, T, S] float32, -inf where a query cannot see
+            scores, kv_cache = self.indexer(c_q, x, rope, positions,
+                                            kv_cache, cache_len)
         # W_kvb [R, H, dn + dv]: head i's key up-projection W_UK^i
         # (its first dn columns) and value up-projection W_UV^i
         w_kvb = self.param("wkv_b", nn.initializers.lecun_normal(),
@@ -260,6 +281,9 @@ class MLAttention(nn.Module):
                                   preferred_element_type=jnp.float32)
                      ) * cfg.softmax_scale
                 causal = jnp.tril(jnp.ones((T, T), bool))
+                if scores is not None:
+                    with jax.named_scope("dsa_topk"):
+                        causal = topk_mask(scores, cfg.index_topk)[:, None]
                 p = jax.nn.softmax(jnp.where(causal, s, -1e30), axis=-1)
             with jax.named_scope("attn_pv"):
                 y = jnp.einsum("bhts,bshv->bthv", p.astype(v.dtype), v)
@@ -282,9 +306,20 @@ class MLAttention(nn.Module):
                 q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, w_uk)
                 q_full = jnp.pad(
                     jnp.concatenate([q_lat, q_rope], axis=-1), pad)
-            o_lat = _paged_window_attention(
-                q_full, pages, None, None, None, pc.page_table,
-                cache_len, softmax_scale=cfg.softmax_scale, value_dim=R)
+            if scores is None:
+                o_lat = _paged_window_attention(
+                    q_full, pages, None, None, None, pc.page_table,
+                    cache_len, softmax_scale=cfg.softmax_scale,
+                    value_dim=R)
+            else:
+                o_lat, chosen, read = sparse_attention(
+                    q_full, pages, pc.page_table, cache_len, scores,
+                    cfg.index_topk, softmax_scale=cfg.softmax_scale,
+                    value_dim=R)
+                self.sow(SELECTION_STATS, "counts",
+                         jnp.stack([positions + 1, chosen, read]),
+                         reduce_fn=lambda _prev, new: new,
+                         init_fn=lambda: None)
             with jax.named_scope("mla_absorb"):
                 y = jnp.einsum("bthr,rhv->bthv", o_lat, w_uv)
         else:
@@ -345,14 +380,21 @@ class AXK1(nn.Module):
 
 def mla_param_count(cfg) -> int:
     """One layer's latent attention: five matrices and two norms, or
-    with a direct query (``q_lora_rank`` None) four and one."""
+    with a direct query (``q_lora_rank`` None) four and one; with an
+    indexer (``index_topk``) its three matrices and its key norm's
+    scale and bias too."""
     H = cfg.n_heads
     if cfg.q_lora_rank is None:
         query = cfg.dim * H * cfg.qk_head_dim
     else:
         query = (cfg.dim * cfg.q_lora_rank + cfg.q_lora_rank
                  + cfg.q_lora_rank * H * cfg.qk_head_dim)
-    return (query
+    indexer = 0
+    if getattr(cfg, "index_topk", None):
+        Hi, Di = cfg.index_n_heads, cfg.index_head_dim
+        indexer = (cfg.q_lora_rank * Hi * Di + cfg.dim * Di + 2 * Di
+                   + cfg.dim * Hi)
+    return (query + indexer
             + cfg.dim * cfg.latent_dim + cfg.kv_lora_rank
             + cfg.kv_lora_rank * H * (cfg.qk_nope_head_dim + cfg.v_head_dim)
             + H * cfg.v_head_dim * cfg.dim)
@@ -366,6 +408,8 @@ def axk1_param_count(cfg: AXK1Config,
     D, F = cfg.dim, cfg.hidden_dim
     dense = 3 * D * cfg.dense_hidden_dim
     moe = (E + cfg.n_shared_experts) * 3 * D * F + D * cfg.num_experts
+    if cfg.router == "sigmoid_bias":
+        moe += cfg.num_experts
     n_dense = min(cfg.first_k_dense, cfg.n_layers)
     return (2 * cfg.vocab_size * D + D
             + cfg.n_layers * (mla_param_count(cfg) + 2 * D)

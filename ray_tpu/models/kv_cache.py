@@ -41,7 +41,7 @@ structure that lets requests join/leave the decode batch per token):
   ops/paged_attention.py; nothing outside it interprets the int8
   payload.
 
-FOUR KINDS OF PER-REQUEST STATE. The pool is a list of per-layer
+FIVE KINDS OF PER-REQUEST STATE. The pool is a list of per-layer
 entries BY KIND (``layer_kinds``): a layer of softmax attention has
 K and V pages, as above; a layer of LATENT attention (models/axk1.py's
 MLA) has pages too, handed out by the same allocator through the same
@@ -51,7 +51,13 @@ its shared rope key side by side, which every head reads (no V pool:
 the values are a column prefix of the same entry; no head axis; the
 entry stored in a whole number of the chip's 128-lane tiles, or the
 chip's compiler lays the pool out with ``n_pages`` minor-most and
-every step program copies it: PERF.md section 6, PR 34); a layer of linear
+every step program copies it: PERF.md section 6, PR 34); a latent layer
+that CHOOSES the entries a query attends (models/deepseek_v32.py) keeps,
+beside that pool, a second one of the same pages for its indexer's keys,
+``[n_pages, page_size, index_head_dim]``: one page id names a page of
+both, so whatever deals in page ids (the allocator, the prefix cache, a
+speculative rewind) carries the index keys with their latent entries
+and knows nothing of them; a layer of linear
 attention (models/solar_open2.py's KDA) has none, but a fixed-size
 ``RecurrentState`` a decode SLOT: the delta rule's matrix a head in
 float32 and the last inputs of its short convolution, whatever the
@@ -102,6 +108,7 @@ KIND_KV = "kv"                  # a layer with K/V pages
 KIND_RECURRENT = "recurrent"    # a layer with a fixed-size state a slot
 KIND_LATENT = "latent"          # a layer with one pool of latent pages
 KIND_SLIDING = "sliding"        # a layer with a ring of its window a slot
+KIND_INDEXED = "indexed"        # latent pages AND pages of index keys
 
 
 def layer_kinds(cfg) -> Tuple[str, ...]:
@@ -156,6 +163,20 @@ KIND_REFUSALS = {
         "sharding": "the pool shards over the KV-head axis, and the "
                     "one latent entry every head reads cannot be split "
                     "over it; no partition rules exist for the layer",
+    }),
+    KIND_INDEXED: ("latent pages and pages of index keys instead of K/V "
+                   "pages", {
+        "kv_dtype": "the int8 code keeps one absmax scale a (page, KV "
+                    "head), and neither a latent entry nor an index key "
+                    "has heads; an index key's rounding also moves which "
+                    "entries a query attends, not only how",
+        "kv_migration": "a KV pull's frames carry K and V a head, and "
+                        "no frame exists for a latent page or for the "
+                        "page of index keys that shares its id",
+        "sharding": "the pool shards over the KV-head axis, and neither "
+                    "the one latent entry nor the one index key every "
+                    "head reads can be split over it; no partition "
+                    "rules exist for the layer or its selector",
     }),
     KIND_SLIDING: ("a ring of their window's keys and values a slot "
                    "instead of K/V pages", {
@@ -239,16 +260,22 @@ def page_layout(cfg, kind: str, page_size: int, kv_dtype: str = "fp"):
     kv int8: k, v, sk, sv   [Pg, KH, D] int8 and [KH] fp32 absmax
              (each behind ``kv_entries_per_layer``'s pass axis, if any)
     latent:  one tensor     [Pg, latent_page_width(cfg)] cfg.dtype
+    indexed: that, and      [Pg, cfg.index_head_dim] cfg.dtype: a
+             token's index key (models/deepseek_v32.py), in a pool of
+             its own under the SAME page ids
     recurrent, sliding: none (they belong to a slot, not to a page)
     """
     if kind in (KIND_RECURRENT, KIND_SLIDING):
         return ()
     quantized = check_kv_dtype(kv_dtype) == "int8"
-    if kind == KIND_LATENT:
+    if kind in (KIND_LATENT, KIND_INDEXED):
         if quantized:
             raise _refusal(cfg, kind, "kv_dtype", kv_dtype)
-        return (((page_size, latent_page_width(cfg)),
-                 jnp.dtype(cfg.dtype)),)
+        latent = ((page_size, latent_page_width(cfg)), jnp.dtype(cfg.dtype))
+        if kind == KIND_LATENT:
+            return (latent,)
+        return (latent, ((page_size, cfg.index_head_dim),
+                         jnp.dtype(cfg.dtype)))
     passes = kv_entries_per_layer(cfg)
     shape = passes + (page_size, kv_page_heads(cfg), cfg.head_dim)
     if quantized:
@@ -339,17 +366,24 @@ class PagedKVLayer(NamedTuple):
                      LATENT layer has ``pages_v`` None and ``pages_k``
                      [n_pages, page_size, latent_page_width]: its
                      values are a column prefix of the same entries.
+                     An INDEXED layer has ``pages_index`` beside them.
     page_table:      [n_slots, max_pages] int32 — logical page p of
                      slot s lives in physical page ``page_table[s, p]``
     scales_k/scales_v: [n_pages, n_kv_heads] fp32 per-page absmax
                      scales when the pool is int8, else None. Optional
                      LAST so fp pytrees keep their PR 1–14 structure.
+    pages_index:     [n_pages, page_size, index_head_dim], an indexed
+                     layer's index keys: page p of it holds the index
+                     keys of the tokens whose latent entries page p of
+                     ``pages_k`` holds. None everywhere else (LAST, for
+                     the scales' reason).
     """
     pages_k: jnp.ndarray
     pages_v: jnp.ndarray
     page_table: jnp.ndarray
     scales_k: Optional[jnp.ndarray] = None
     scales_v: Optional[jnp.ndarray] = None
+    pages_index: Optional[jnp.ndarray] = None
 
     @property
     def page_size(self) -> int:
@@ -363,7 +397,9 @@ class PagedKVLayer(NamedTuple):
 def kv_layer_view(layer, page_table: jnp.ndarray, slots=None,
                   valid=None):
     """Wrap one engine layer entry — ``(pk, pv)`` fp,
-    ``(pk, pv, sk, sv)`` int8, ``(pages,)`` latent, a
+    ``(pk, pv, sk, sv)`` int8, ``(pages,)`` latent, ``(pages,
+    index_pages)`` indexed (told from K and V by a latent page having
+    no head axis), a
     ``RecurrentState`` or a ``SlidingRing`` — as what its
     layer consumes: a PagedKVLayer over ``page_table``, or a
     RecurrentStateView or SlidingRingView of the rows' ``slots`` and
@@ -378,6 +414,9 @@ def kv_layer_view(layer, page_table: jnp.ndarray, slots=None,
         return SlidingRingView(layer.k, layer.v, slots, valid())
     if len(layer) == 1:
         return PagedKVLayer(layer[0], None, page_table)
+    if len(layer) == 2 and layer[0].ndim == 3:
+        return PagedKVLayer(layer[0], None, page_table,
+                            pages_index=layer[1])
     if len(layer) == 2:
         pk, pv = layer
         return PagedKVLayer(pk, pv, page_table)
@@ -406,6 +445,8 @@ def kv_layer_store(cache: PagedKVLayer):
         return RecurrentState(cache.state, cache.conv)
     if isinstance(cache, SlidingRingView):
         return SlidingRing(cache.k, cache.v)
+    if cache.pages_index is not None:
+        return (cache.pages_k, cache.pages_index)
     if cache.pages_v is None:
         return (cache.pages_k,)
     if cache.scales_k is None:
@@ -430,6 +471,8 @@ def init_kv_pool(cfg, n_pages: int, page_size: int,
           without any host-side scale bookkeeping).
     latent: (pages,) in cfg.dtype,
           [n_pages, page_size, latent_page_width(cfg)].
+    indexed: (pages, index_pages): those and
+          [n_pages, page_size, cfg.index_head_dim].
     recurrent: RecurrentState of ``n_slots`` rows, zeros.
     sliding: SlidingRing of ``n_slots`` rings of ``ring_len``
           (``sliding_ring_len``) positions, zeros: the same bytes
@@ -463,7 +506,8 @@ def kv_pool_page_bytes(cfg, page_size: int,
                        kv_dtype: str = "fp") -> int:
     """Bytes ONE physical page costs across the layers that HAVE pages
     (a K/V layer's k+v payload plus, for int8, its two fp32 scales; a
-    latent layer's one entry a token). The allocator multiplies this
+    latent layer's one entry a token, and an indexed layer's index
+    key beside it). The allocator multiplies this
     by occupancy for the bytes view in load/leak reports — the number
     the capacity A/B halves."""
     return sum(int(np.prod(shape)) * dtype.itemsize

@@ -161,9 +161,9 @@ class MoEFeedForward(nn.Module):
       ``norm_topk_prob``), ``"sigmoid_bias"`` (the DeepSeek-V3 /
       GLM-4.5 rule: s = sigmoid(logits); the k experts with the
       largest s + b, b a stored bias a expert that takes part in the
-      CHOICE only; gates s_chosen, divided by their sum where
-      ``norm_topk_prob``) or ``"sigmoid"`` (the same with no stored
-      bias: the k largest s), times ``routed_scaling_factor``;
+      CHOICE only, inside ``group_limited``'s groups where ``n_group``;
+      gates s_chosen, divided by their sum where ``norm_topk_prob``) or
+      ``"sigmoid"`` (no stored bias), times ``routed_scaling_factor``;
     - ``n_shared_experts``: a SwiGLU of that many experts' width which
       every token passes, added to the routed result;
     - ``experts_held`` (lo, n): THE CHIP'S SHARE under expert
@@ -201,7 +201,7 @@ class MoEFeedForward(nn.Module):
                 bias = self.param("router_bias", nn.initializers.zeros,
                                   (E,), jnp.float32)
                 probs = jax.nn.sigmoid(logits)
-                _, topk_idx = jax.lax.top_k(probs + bias, K)
+                _, topk_idx = jax.lax.top_k(group_limited(cfg, probs + bias), K)
                 gates = jnp.take_along_axis(probs, topk_idx, axis=1)
             else:
                 probs = jax.nn.sigmoid(logits)
@@ -421,3 +421,25 @@ def mixtral_param_count(cfg: MixtralConfig) -> int:
 def active_params_per_token(cfg: MixtralConfig) -> int:
     """Sparse models are priced by ACTIVE params: K experts of E."""
     return _param_count(cfg, cfg.num_experts_per_tok)
+
+
+def group_limited(cfg, choice):
+    """``choice`` [N, E] (a biased router's s + b) with every expert
+    outside the token's best groups at ``-inf``: the config's
+    ``n_group`` equal groups of consecutive experts are each scored by
+    the sum of their two largest values, and the ``topk_group`` best
+    groups stay (the DeepSeek-V3 ``noaux_tc`` rule; a tie to the lower
+    group). A config without ``n_group``, or with one group, limits
+    nothing: ``choice`` as it is. (Defined at the file's end and called
+    inside the line that ranked ``choice`` before: the step programs'
+    compile-cache key carries the line of every operation above.)"""
+    n_group = getattr(cfg, "n_group", None) or 1
+    if n_group <= 1:
+        return choice
+    N, E = choice.shape
+    in_group = choice.reshape(N, n_group, E // n_group)
+    best_two, _ = jax.lax.top_k(in_group, 2)
+    _, stay = jax.lax.top_k(jnp.sum(best_two, axis=-1), cfg.topk_group)
+    allowed = jnp.zeros((N, n_group), bool).at[
+        jnp.arange(N)[:, None], stay].set(True)
+    return jnp.where(allowed[:, :, None], in_group, -jnp.inf).reshape(N, E)

@@ -92,25 +92,29 @@ def tile_tokens(T: int, H: int) -> Optional[int]:
 
 
 def serves(T: int, H: int, D: int, value_dim: int, page_size: int,
-           dtype) -> bool:
+           dtype, tokens: Optional[int] = None) -> bool:
     """Whether the kernel serves a chunk of ``T`` tokens of ``H`` heads
     over latent pages of ``page_size`` entries ``D`` wide, queries and
     pool both of ``dtype``: whole query tiles (``tile_tokens``), widths
     of whole 128-lane tiles, pages of whole bfloat16 sublane tiles, and
     a TPU outside any multi-device mesh. Everything else keeps the XLA
     loop. The engine asks the same question for its
-    ``prefill_kernel_blocks``."""
-    return (tile_tokens(T, H) is not None and dtype == jnp.bfloat16
+    ``prefill_kernel_blocks``. ``tokens``: the caller's own tile where
+    not ``tile_tokens``'s (ops/sparse_latent_attention.py: a decode
+    step under a choice is a tile of one token a row)."""
+    return ((tokens or tile_tokens(T, H)) is not None
+            and dtype == jnp.bfloat16
             and D % _LANES == 0 and value_dim % _LANES == 0
             and page_size % 16 == 0 and _on_one_tpu())
 
 
-def applies(q, pages, value_dim: int) -> bool:
+def applies(q, pages, value_dim: int,
+            tokens: Optional[int] = None) -> bool:
     """``serves`` for ``q`` [B, T, H, D] over ``pages`` [n_pages, Pg,
     D]."""
     T, H, D = q.shape[1:]
     return q.dtype == pages.dtype and serves(
-        T, H, D, value_dim, pages.shape[1], q.dtype)
+        T, H, D, value_dim, pages.shape[1], q.dtype, tokens)
 
 
 def kernel_blocks(starts, T: int, block: int, max_blocks: int) -> int:
@@ -140,9 +144,13 @@ def _dot(a, b, dims):
 
 
 def _window_kernel(table_ref, tile_ref, block_ref, count_ref, pos_ref,
-                   q_ref, *rest, scale: float, dv: int, n_q: int):
+                   q_ref, *rest, scale: float, dv: int, n_q: int,
+                   chosen: bool = False):
     del table_ref                               # the index maps' alone
     *k_refs, o_ref, m_scr, l_scr, acc_scr = rest
+    if chosen:
+        # the tile's tokens' chosen keys of this block, {0, 1}
+        *k_refs, member_ref = k_refs
     v = pl.program_id(0)
     tile, j = tile_ref[v], block_ref[v]  # this visit's tile, key block
     tokens, H, D = q_ref.shape[1:]
@@ -163,7 +171,16 @@ def _window_kernel(table_ref, tile_ref, block_ref, count_ref, pos_ref,
         statistics and accumulator."""
         keys = jnp.concatenate([r[0] for r in k_refs], axis=0)
         s = _dot(q_ref[0].reshape(rows, D), keys, _NT) * scale
-        if masked:
+        if chosen:
+            # a token's choice holds for all its heads. A block with
+            # none of a query's keys folds junk into its statistics at
+            # the floor, which the first block that holds one wipes
+            # (``alpha`` underflows to 0), and every live query has one
+            mine = jnp.broadcast_to(
+                member_ref[0].astype(jnp.float32)[:, None, :],
+                (tokens, H, lb)).reshape(rows, lb)
+            s = jnp.where(mine > 0.0, s, _NEG_INF)
+        elif masked:
             q_pos = first + jax.lax.broadcasted_iota(
                 jnp.int32, (tokens, H, lb), 0).reshape(rows, lb)
             k_pos = j * lb + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -178,11 +195,14 @@ def _window_kernel(table_ref, tile_ref, block_ref, count_ref, pos_ref,
         m_scr[...] = jnp.broadcast_to(m, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l, l_scr.shape)
 
-    # no mask where every key of the block is at or under the tile's
-    # first query
-    below = (j + 1) * lb - 1 <= first
-    pl.when((j < count) & below)(functools.partial(visit, False))
-    pl.when((j < count) & ~below)(functools.partial(visit, True))
+    if chosen:
+        pl.when(j < count)(functools.partial(visit, False))
+    else:
+        # no mask where every key of the block is at or under the
+        # tile's first query
+        below = (j + 1) * lb - 1 <= first
+        pl.when((j < count) & below)(functools.partial(visit, False))
+        pl.when((j < count) & ~below)(functools.partial(visit, True))
 
     @pl.when(j == jnp.maximum(count, 1) - 1)
     def _():
@@ -197,14 +217,18 @@ def latent_window_attention(q, pages, page_table, pos, *,
                             softmax_scale: float, value_dim: int,
                             block_pages: int,
                             tokens: Optional[int] = None,
-                            interpret: bool = False):
+                            interpret: bool = False, member=None):
     """Causal attention of ``q`` [B, T, H, D] (row b's queries at
     absolute positions ``pos[b] + t``) over its page-table row's
     entries in the latent pool ``pages`` [n_pages, Pg, D], in blocks of
     ``block_pages`` pages; a key's value is its first ``value_dim``
     columns. Returns [B, T, H, value_dim] in ``q``'s type. A row whose
     page-table row is null (its first page is page 0) reads out zeros.
-    ``tokens``: a query tile's, where not ``tile_tokens``'s."""
+    ``tokens``: a query tile's, where not ``tile_tokens``'s.
+    ``member`` [B, T, S] bool (S the table's positions in whole blocks):
+    the keys each query attends, where that is not every key at or
+    before it (ops/sparse_latent_attention.py: a learned choice, causal
+    by construction); the walk is the same, the mask is the caller's."""
     B, T, H, D = q.shape
     Pg = pages.shape[1]
     max_pages = page_table.shape[1]
@@ -247,13 +271,21 @@ def latent_window_attention(q, pages, page_table, pos, *,
             lambda v, table, tile_of, *_: (tile_of[v] // n_q,
                                            tile_of[v] % n_q, 0, 0))
     rows = tokens * H
+    sparse = ()
+    if member is not None:
+        sparse = (member.astype(q.dtype),)
+        chosen_keys = pl.BlockSpec(
+            (1, tokens, lb),
+            lambda v, table, tile_of, block_of, *_: (
+                tile_of[v] // n_q, tile_of[v] % n_q, block_of[v]))
     return pl.pallas_call(
         functools.partial(_window_kernel, scale=softmax_scale,
-                          dv=value_dim, n_q=n_q),
+                          dv=value_dim, n_q=n_q, chosen=bool(sparse)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(after[-1],),
-            in_specs=[tile(D)] + [page(c) for c in range(block_pages)],
+            in_specs=[tile(D)] + [page(c) for c in range(block_pages)] + (
+                [chosen_keys] if sparse else []),
             out_specs=tile(value_dim),
             scratch_shapes=[
                 pltpu.VMEM((rows, _LANES), jnp.float32),       # m
@@ -266,4 +298,19 @@ def latent_window_attention(q, pages, page_table, pos, *,
             vmem_limit_bytes=_vmem_bytes(rows, D, value_dim, lb)),
         interpret=interpret, name="latent_window",
     )(table, tile_of, block_of, count, pos.astype(i32), q,
-      *([pages] * block_pages))
+      *([pages] * block_pages), *sparse)
+
+
+def entries_read(page_table, pos, T: int, H: int, block_pages: int,
+                 page_size: int, tokens: Optional[int] = None):
+    """[B, T] int32: the entries the kernel fetches for each query of a
+    [B, T] chunk of ``H`` heads: its tile's blocks of ``block_pages``
+    pages, to the one holding the tile's last query, inside the table;
+    none for a row whose page-table row is null."""
+    tokens = tokens or tile_tokens(T, H)
+    block = block_pages * page_size
+    max_blocks = -(-page_table.shape[1] // block_pages)
+    ends = pos.astype(jnp.int32)[:, None] + (
+        jnp.arange(T, dtype=jnp.int32) // tokens + 1)[None] * tokens - 1
+    blocks = jnp.minimum(ends // block + 1, max_blocks)
+    return jnp.where(page_table[:, :1] != 0, blocks, 0) * block

@@ -132,7 +132,8 @@ from ray_tpu.serve.scheduler import (LANE_BATCH, LANE_ONLINE,
 from ray_tpu.serve.step_programs import (_jit_copy_page, _jit_decode,
                                          _jit_prefill, _jit_seed,
                                          _jit_verify, _jit_write_page,
-                                         _moe_vector_of, ambient_mesh)
+                                         _moe_vector_of, ambient_mesh,
+                                         selection_len)
 from ray_tpu.util.compile_cache import (build_log, metadata_keyed,
                                         summarize_builds)
 
@@ -463,11 +464,27 @@ _MOE_SUMS = ("pairs", "experts_touched", "load_max", "layer_steps")
 _MOE_ROUTED = "pairs_routed"
 
 
-def _new_moe_info() -> Dict[str, int]:
+# What the same vector ends with for a model whose latent layers choose
+# the entries they attend (serve/step_programs.py ``selection_len``),
+# summed over those layers and the dispatch's live tokens: index keys
+# the indexer scored, entries chosen (the sum of |S_t|) and entries the
+# attention fetched (a gather reads what was chosen, a masked walk its
+# whole window). The decode dispatches' part alone stands under the
+# same names after ``decode_``.
+_SELECTION = ("index_keys_scored", "sparse_entries_chosen",
+              "sparse_entries_read")
+
+
+def _new_moe_info(selection: bool = False) -> Dict[str, int]:
     """The router's counters of a mixture-of-experts model, as the
-    ``round`` event reports them (docs/serving.md)."""
-    return {prefix + key: 0 for prefix in ("moe_", "moe_decode_")
+    ``round`` event reports them (docs/serving.md), and the selection's
+    behind them where the model has one."""
+    info = {prefix + key: 0 for prefix in ("moe_", "moe_decode_")
             for key in _MOE_SUMS + (_MOE_ROUTED,)}
+    if selection:
+        info.update({prefix + key: 0 for prefix in ("", "decode_")
+                     for key in _SELECTION})
+    return info
 
 
 def _new_round_info() -> Dict[str, int]:
@@ -825,7 +842,8 @@ class LLMEngine:
         self._moe_experts = _moe_vector_of(model)[0]
         self._moe_pending: "collections.deque" = collections.deque()
         self._moe_expert_pairs = np.zeros((self._moe_experts,), np.int64)
-        self._moe_unreported = _new_moe_info()
+        self._selection = bool(selection_len(self.cfg))
+        self._moe_unreported = _new_moe_info(self._selection)
         # Device-authoritative decode state: the next-token input and
         # write position per slot LIVE ON DEVICE and chain dispatch to
         # dispatch — no host readback sits on the decode critical
@@ -3240,6 +3258,11 @@ class LLMEngine:
         E, acc = self._moe_experts, self._moe_unreported
         for vec, (_v, decode) in zip(
                 jax.device_get([v for v, _d in ready]), ready):
+            if self._selection:
+                vec, chose = vec[:-len(_SELECTION)], vec[-len(_SELECTION):]
+                for prefix in ("", "decode_") if decode else ("",):
+                    for key, value in zip(_SELECTION, chose):
+                        acc[prefix + key] += int(value)
             self._moe_expert_pairs += vec[:E]
             sums = dict(zip(
                 _MOE_SUMS + (_MOE_ROUTED,),
@@ -3257,7 +3280,8 @@ class LLMEngine:
         Nothing for a dense model."""
         if not self._moe_experts:
             return {}
-        out, self._moe_unreported = self._moe_unreported, _new_moe_info()
+        out, self._moe_unreported = (self._moe_unreported,
+                                     _new_moe_info(self._selection))
         for k, v in out.items():
             self.stats[k] += v
         return out

@@ -36,7 +36,18 @@ def _moe_vector_of(model):
         return 0, None, 0
     from ray_tpu.models.mixtral import experts_held, moe_stats_len
     return (experts_held(cfg)[1], cfg.experts_held,
-            moe_stats_len(cfg.num_experts, cfg.experts_held))
+            moe_stats_len(cfg.num_experts, cfg.experts_held)
+            + selection_len(cfg))
+
+
+def selection_len(cfg) -> int:
+    """Entries the routing vector of a model whose latent layers CHOOSE
+    their entries (a config with ``index_topk``) ends with: what they
+    scored, chose and read (ops/sparse_latent_attention.py
+    ``selection_stats_vector``);
+    0 for every other model. The counters ride the mixture's vector: a
+    model that chooses without a mixture has none yet."""
+    return 3 if getattr(cfg, "index_topk", None) else 0
 
 
 def ambient_mesh(mesh):
@@ -57,7 +68,8 @@ def _moe_apply(model, mesh):
     mixture-of-experts model the third result is (the int32 vector,) of
     what the router chose over the program's live tokens
     (models/mixtral.py moe_stats_vector; ``live()`` gives the [B, T]
-    mask); for a dense model it is (). ``logits_at`` [B]: the one
+    mask), with ``selection_len``'s entries behind it; for a dense
+    model it is (). ``logits_at`` [B]: the one
     position of each row the program wants logits for, ``[B, V]``
     (models/llama.py transformer_forward); None: every position's."""
     E, held, _ = _moe_vector_of(model)
@@ -70,15 +82,23 @@ def _moe_apply(model, mesh):
             return logits, new_kv, ()
         return apply
     from ray_tpu.models.mixtral import MOE_STATS, moe_stats_vector
+    sown_by = [MOE_STATS]
+    if selection_len(model.config):
+        from ray_tpu.ops.sparse_latent_attention import (
+            SELECTION_STATS, selection_stats_vector)
+        sown_by.append(SELECTION_STATS)
 
     def apply(params, ids, kv, start, live, logits_at=None):
         with ambient_mesh(mesh):
             (logits, new_kv), sown = model.apply(
                 params, ids, kv_caches=kv, cache_len=start,
-                logits_at=logits_at, mutable=[MOE_STATS])
+                logits_at=logits_at, mutable=sown_by)
         with jax.named_scope("moe_stats"):
             vec = moe_stats_vector(sown[MOE_STATS], live(),
                                    model.config.num_experts, held)
+            if len(sown_by) > 1:
+                vec = jnp.concatenate([vec, selection_stats_vector(
+                    sown[SELECTION_STATS], live())])
         return logits, new_kv, (vec,)
     return apply
 

@@ -33,7 +33,13 @@ published copy, the program against the reference and the margin rule
 against the reference's controls, byte counts by layer type, the ring
 copies by opcode, the three new readers and the older ones on a
 hand-made joined trace, the cell on gen-sat as it stands, the rehearsal
-cell), collected here so that the suite the driver runs guards them.
+cell) and test_deepseek_v32_family.py (the DeepSeek-V3.2 family: the
+configuration against its published copy, the program against the
+reference through both pools, the near-tie rule with the groups'
+boundary, byte counts, the seven new readers on a hand-made joined
+trace, the cell on longdoc-sat as it stands, the rehearsal cell at
+--trace 0 and 2), collected here so that the suite the driver runs
+guards them.
 `python -m pytest benchmarks/tests` still runs them where they live."""
 import pytest
 
@@ -46,7 +52,8 @@ _FILES = ("benchmarks.tests.test_families",
           "benchmarks.tests.test_mellum2_family",
           "benchmarks.tests.test_ouro_family",
           "benchmarks.tests.test_olmo_hybrid_family",
-          "benchmarks.tests.test_laguna_family")
+          "benchmarks.tests.test_laguna_family",
+          "benchmarks.tests.test_deepseek_v32_family")
 pytest.register_assert_rewrite(*_FILES)
 
 from benchmarks.tests.test_families import *          # noqa: E402,F401,F403
@@ -59,6 +66,7 @@ from benchmarks.tests.test_mellum2_family import *    # noqa: E402,F401,F403
 from benchmarks.tests.test_ouro_family import *       # noqa: E402,F401,F403
 from benchmarks.tests.test_olmo_hybrid_family import *  # noqa: E402,F401,F403
 from benchmarks.tests.test_laguna_family import *     # noqa: E402,F401,F403
+from benchmarks.tests.test_deepseek_v32_family import *  # noqa: E402,F401,F403
 
 # Recorded without tier-1's low-optimisation XLA flags (tests/conftest.py),
 # under which the CPU draws a normal's last bits differently: the digests
@@ -114,6 +122,12 @@ del test_seeded_weights_are_the_parents_bit_for_bit    # noqa: F821
 # TWENTY-THREE before the file's end, and benchmarks/tests/
 # test_laguna_family.py::test_the_cell_and_gen_sat_as_it_stands pins
 # PR 53's.
+# PR 56 appended a configuration, a cell and seven readers, and the cell
+# to the lists of eighteen older metrics: every older case (PR 53's pin
+# among them) runs against the file less those too, the case of the
+# four dispatch readers pins them THIRTY before the file's end, and
+# benchmarks/tests/test_deepseek_v32_family.py::
+# test_the_dsv32_cell_and_longdoc_sat_as_it_stands pins PR 56's.
 _DISPATCH = ("dispatch_prefill_call_ms", "dispatch_decode_step_ms",
              "dispatch_prefill_share", "dispatch_prefill_call_ms.open")
 _PR39 = ("state_peak_share", "linear_state_roofline.by_kind",
@@ -132,10 +146,14 @@ _PR51 = ("setup_build_s", "setup_program_trace_s", "setup_cold_builds",
 _PR53 = ("swa_moe_step_roofline", "decode_attn_gate_ms",
          "moe_rows_per_expert_mean")
 _PR53_CELL, _PR53_CONFIG = "laguna-xs2-d5.gen-sat", "laguna-xs.2-d5"
+_PR56 = ("decode_index_ms", "decode_sparse_attn_ms", "index_roofline",
+         "sparse_attn_roofline", "prefill_sparse_attn_share",
+         "sparse_read_ratio", "sparse_step_roofline")
+_PR56_CELL, _PR56_CONFIG = "dsv32-d5.longdoc-sat", "deepseek-v3.2-d5-ep32"
 _SAT = ["mistral7b-d16.chat-sat", "olmoe-d8.chat-sat",
         "solar-open2-d4.doc-sat", "axk1-d5.longdoc-sat",
         "kimi-linear-d8.gen-sat", _PR42_CELL, _PR46_CELL, _PR49_CELL,
-        _PR53_CELL]
+        _PR53_CELL, _PR56_CELL]
 
 
 def _less_a_pr(bench, config, cell, readers):
@@ -153,9 +171,14 @@ def _less_a_pr(bench, config, cell, readers):
     return bench
 
 
+def _less_pr56(bench):
+    """BENCHMARK.json as PR 55 left it."""
+    return _less_a_pr(bench, _PR56_CONFIG, _PR56_CELL, _PR56)
+
+
 def _less_pr53(bench):
     """BENCHMARK.json as PR 52 left it."""
-    return _less_a_pr(bench, _PR53_CONFIG, _PR53_CELL, _PR53)
+    return _less_a_pr(_less_pr56(bench), _PR53_CONFIG, _PR53_CELL, _PR53)
 
 
 def _less_pr51(bench):
@@ -248,12 +271,27 @@ test_the_cell_and_sample_sat_as_it_stands = _as_pr50_left_it(
     test_the_cell_and_sample_sat_as_it_stands)          # noqa: F821
 
 
+def _as_pr55_left_it(case):
+    def test(monkeypatch):
+        from benchmarks import common
+        bench = _less_pr56(common.load_benchmark())
+        monkeypatch.setattr(common, "load_benchmark", lambda: bench)
+        case()
+    test.__name__ = case.__name__
+    test.__doc__ = case.__doc__
+    return test
+
+
+test_the_cell_and_gen_sat_as_it_stands = _as_pr55_left_it(
+    test_the_cell_and_gen_sat_as_it_stands)             # noqa: F821
+
+
 @pytest.mark.parametrize("name", _DISPATCH)
 def test_dispatch_readers_are_appended_to_the_benchmark(name):
     from benchmarks import common
     bench = common.load_benchmark()
-    assert tuple(m["name"] for m in bench["per_layer"][-27:]) == \
-        _DISPATCH + _PR39 + _PR42 + _PR46 + _PR49 + _PR51 + _PR53
+    assert tuple(m["name"] for m in bench["per_layer"][-34:]) == \
+        _DISPATCH + _PR39 + _PR42 + _PR46 + _PR49 + _PR51 + _PR53 + _PR56
     m = common.find_named(bench["per_layer"], name, "metric")
     want = {"name": name, "unit": "ms", "better": "lower",
             "source": "device_trace", "layer": "model step",

@@ -530,8 +530,9 @@ def test_the_setup_readers_report_nothing_on_a_program_without_the_log(
 def test_the_setup_readers_are_appended_to_the_benchmark(name):
     from benchmarks import common
     bench = common.load_benchmark()
-    # (PR 53 appended three readers of its own cell behind them)
-    assert tuple(m["name"] for m in bench["per_layer"][-7:-3]) == READERS
+    # (PR 53 appended three readers of its own cell behind them, PR 56
+    # seven)
+    assert tuple(m["name"] for m in bench["per_layer"][-14:-10]) == READERS
     m = common.find_named(bench["per_layer"], name, "metric")
     cells = [w["name"] for w in bench["workloads"]]
     want = {"name": name, "unit": "s", "better": "lower",

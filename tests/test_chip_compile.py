@@ -663,6 +663,93 @@ def test_latent_step_programs_keep_the_pool_page_major(one_chip,
 
 
 # ---------------------------------------------------------------
+# A model whose latent layers CHOOSE their entries, at its cell's widths
+# (DeepSeek-V3.2: 128 heads over a 640-wide latent entry, 64 index heads
+# over a 128-wide index key in pages of their own, 4,353 pages of 64, a
+# page table 256 wide; the dense layer and one mixture layer with 8 of
+# 256 experts held keep the compile short): both pools stay where they
+# lie in both programs, and both attend through the latent kernel with
+# the choice as its mask (a decode step's query a tile of one token).
+
+def _indexed_step(name, one_chip):
+    from ray_tpu.models.deepseek_v32 import DeepSeekV32, deepseek_v32
+    from ray_tpu.models.kv_cache import init_kv_pool
+    from ray_tpu.serve import step_programs
+    cfg = deepseek_v32(n_layers=2, first_k_dense=1, vocab_size=16160,
+                       max_seq_len=16384, experts_held=(0, 8),
+                       param_dtype=jnp.bfloat16)
+    model = DeepSeekV32(cfg)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                           sharding=one_chip), tree)
+    params = placed({"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]})
+    pages = placed(jax.eval_shape(lambda: init_kv_pool(cfg, 4353, PAGE)))
+    i32 = jnp.int32
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    table = ((SLOTS, cfg.max_seq_len // PAGE), i32)
+    if name == "decode":
+        fn = step_programs._jit_decode.__wrapped__(
+            model, 0.0, 128, SLOTS, False, None)
+        rest = [table, ((SLOTS,), i32), ((SLOTS,), i32),
+                (key.shape, key.dtype), ((), i32)]
+    else:
+        fn = step_programs._jit_prefill.__wrapped__(
+            model, 0.0, 4, False, None)
+        rest = [((4, 256), i32), ((4,), i32), ((4,), i32),
+                ((4, table[0][1]), i32), (key.shape, key.dtype)]
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in rest]
+    return fn.lower(params, pages, *rest).compile()
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_chosen_entries_programs_keep_both_pools_in_place(one_chip,
+                                                          monkeypatch,
+                                                          name):
+    from ray_tpu.ops import grouped_matmul as gm
+    from ray_tpu.ops import latent_window_attention as lw
+    for mod, attr in ((gm, "_use_kernel"), (lw, "_on_one_tpu")):
+        monkeypatch.setattr(mod, attr, lambda: True)
+    compiled = _indexed_step(name, one_chip)
+    text = compiled.as_text()
+    # two pools a layer, each as it is declared: page-major, whole tiles
+    for pool in ("4353,64,640", "4353,64,128"):
+        entry = re.search(r"bf16\[%s\](\{[^}]*\}) parameter" % pool, text)
+        assert entry and entry.group(1).startswith("{2,1,0"), (pool, entry)
+        copies = re.findall(
+            r"= bf16\[%s\](?:\{[^}]*\})? copy\(" % pool, text)
+        assert not copies, f"{len(copies)} copies of a {pool} pool in {name}"
+    assert text.count("may-alias") >= 4, name     # both pools, two layers
+    kernels = re.findall(
+        r"custom-call\([^\n]*/dsa_attn/latent_window[^\n]*", text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    # the masked walk is the latent kernel, a call a layer, with the
+    # choice ([4, 256, 16384] a chunk, [32, 1, 16384] a step) as one
+    # more operand; nothing is sorted or gathered
+    assert len(kernels) == 2, len(kernels)
+    choice = "bf16[4,256,16384]" if name == "prefill" else "bf16[32,1,16384]"
+    for call in kernels:
+        assert call.count("bf16[4353,64,640]{2,1,0}") == 8, call[:300]
+        assert choice in call, call[:300]
+    assert not re.search(r" sort\([^\n]*/dsa_", text)
+    assert "bf16[32,2048,640]" not in text
+    if name == "prefill":
+        # nothing of a block's float32 scores [4, 128, 256, 512] is left
+        block = 4 * 128 * 256 * 512
+        scores = sorted({m.group(0) for m in re.finditer(
+            r"= f32\[([0-9,]+)\]", text)
+            if math.prod(int(d) for d in m.group(1).split(",")) == block})
+        assert not scores, scores
+        assert temp < 600 << 20, temp
+    else:
+        assert temp < 400 << 20, temp
+
+
+# ---------------------------------------------------------------
 # A model of recurrent and latent layers ONLY at its cell's widths and
 # ITS slots (Kimi-Linear: 32 delta-rule heads of 128 over 128 SLOTS, a
 # 576-wide latent entry, 4,609 pages of 64, a page table 64 wide; one

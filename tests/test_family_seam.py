@@ -12,7 +12,8 @@ import types
 import pytest
 
 from ray_tpu.models import kv_cache
-from ray_tpu.models.kv_cache import (KIND_KV, KIND_LATENT, KIND_RECURRENT,
+from ray_tpu.models.kv_cache import (KIND_INDEXED, KIND_KV, KIND_LATENT,
+                                     KIND_RECURRENT,
                                      KIND_SLIDING, refuse_unsupported)
 
 SERVE = pathlib.Path(__file__).resolve().parent.parent / "ray_tpu" / "serve"
@@ -152,14 +153,17 @@ def test_the_readers_hold_no_list_of_families():
 
 def _config_of(kind):
     from ray_tpu.models.axk1 import axk1_tiny
+    from ray_tpu.models.deepseek_v32 import deepseek_v32_tiny
     from ray_tpu.models.mellum import mellum_tiny
     from ray_tpu.models.solar_open2 import solar_open2_tiny
     return {KIND_RECURRENT: solar_open2_tiny, KIND_LATENT: axk1_tiny,
+            KIND_INDEXED: deepseek_v32_tiny,
             KIND_SLIDING: mellum_tiny}[kind]()
 
 
 @pytest.mark.parametrize("kind,option", [
-    (kind, option) for kind in (KIND_RECURRENT, KIND_LATENT, KIND_SLIDING)
+    (kind, option) for kind in (KIND_RECURRENT, KIND_LATENT, KIND_INDEXED,
+                                KIND_SLIDING)
     for option in kv_cache.KIND_REFUSALS[kind][1]])
 def test_the_tables_words_reach_the_refusal(kind, option):
     keeps, why = kv_cache.KIND_REFUSALS[kind]
@@ -172,13 +176,14 @@ def test_the_tables_words_reach_the_refusal(kind, option):
     refuse_unsupported(cfg, **{option: False})
 
 
-def test_the_table_is_twelve_refusals_over_four_kinds():
+def test_the_table_is_fifteen_refusals_over_five_kinds():
     kinds = {getattr(kv_cache, name) for name in dir(kv_cache)
              if name.startswith("KIND_") and name != "KIND_REFUSALS"}
     assert kinds == set(kv_cache.KIND_REFUSALS)
     assert {kind: len(why) for kind, (_keeps, why)
             in kv_cache.KIND_REFUSALS.items()} == {
-        KIND_KV: 0, KIND_RECURRENT: 4, KIND_LATENT: 3, KIND_SLIDING: 5}
+        KIND_KV: 0, KIND_RECURRENT: 4, KIND_LATENT: 3, KIND_INDEXED: 3,
+        KIND_SLIDING: 5}
 
 
 def test_pages_of_keys_and_values_are_refused_nothing():
