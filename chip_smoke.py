@@ -11,7 +11,8 @@ through the entry points a user calls:
             one int8-KV request checked the same way
   kernels   the Pallas flash-attention kernel (fwd+bwd), the one-token
             delta-rule kernel, the latent-page prefill attention kernel
-            and the mixture's grouped matmul under its tile plan,
+            and the mixture's grouped matmul (the repo's own Pallas
+            body, the matrix fetched by group) under its tile plan,
             compiled, each against its XLA reference
   training  GPT-2-124M at batch 24 x 1024 through shard_state /
             put_batch / make_train_step; flash kernel present in the
@@ -363,7 +364,8 @@ def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
                  kda_shapes=((32, 64, 128),),
                  window_shapes=((256, 64, 640, 512,
                                  (512, 8192, None, 2304), None),),
-                 gmm_shapes=((64, 2304, 1024, 256, 200),),
+                 gmm_shapes=((64, 2304, 1024, 256, 200),
+                             (64, 2304, 896, 8192, 8192)),
                  decode_shapes=((16, 16, (300, 352, None, 64, 1)),
                                 (32, 4, (8704, None, 70)),
                                 (64, None, (8704, None, 8200, 70)),
@@ -383,10 +385,13 @@ def kernel_phase(*, flash_shapes=((24, 1024, 12, 64),
     no request owns; KV heads None: over latent pages as
     ``window_shapes``' first, A.X-K1's 64 heads and Kimi-Linear's 32 at
     their cells' contexts) against the block loop too; the mixture's grouped
-    matmul
-    (``gmm_shapes``: experts, K, N, sorted pairs, pairs that have an
-    expert) at a contraction the tile plan takes whole where constants
-    left a remainder, against ``jax.lax.ragged_dot`` in float32."""
+    matmul (``ops/grouped_matmul.py`` ``grouped_matmul_kernel``: the
+    repo's own body, which starts the next group's matrix on its way at
+    a group's first visit; ``gmm_shapes``: experts, K, N, sorted pairs,
+    pairs that have an expert) at a decode call's few rows a group and
+    at a prefill call's ~128, where a group lies over two row tiles and
+    the second visit multiplies out of the matrix already there, against
+    ``jax.lax.ragged_dot`` in float32."""
     import jax
     import jax.numpy as jnp
     import numpy as np

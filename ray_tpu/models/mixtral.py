@@ -25,7 +25,7 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.mesh.sharding import ShardingRules
 from ray_tpu.models.kv_cache import PagedKVLayer
-from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops.grouped_matmul import grouped_matmul, visits
 from ray_tpu.models.llama import (LlamaAttention, LlamaConfig,
                                   attention_param_count, block_forward,
                                   embedding_param_count,
@@ -287,21 +287,25 @@ class MoEFeedForward(nn.Module):
 
 def moe_stats_vector(stats, live, num_experts: int, held=None):
     """What the router chose in one forward pass, over live tokens
-    only, as one int32 vector [E + 3]: each expert's pairs summed over
+    only, as one int32 vector [E + 4]: each expert's pairs summed over
     the layers, then the distinct experts touched summed over the
-    layers, the fullest expert's pairs summed over the layers, and the
-    number of layers (what to divide the two sums by). ``stats`` is the
-    ``MOE_STATS`` collection of an apply, ``live`` [B, T] bool.
+    layers, the fullest expert's pairs summed over the layers, the
+    number of layers (what to divide the sums by), and the (row tile,
+    expert) visits the grouped matmul's grid makes over those pairs
+    summed over the layers (ops/grouped_matmul.py ``visits``: less the
+    experts touched, the visits that multiply out of a matrix already
+    fetched). ``stats`` is the ``MOE_STATS`` collection of an apply,
+    ``live`` [B, T] bool.
 
     A mixture that holds a share ``held`` = (lo, n) of its router's
-    experts (``experts_held``) counts THOSE, and its vector [n + 4]
+    experts (``experts_held``) counts THOSE, and its vector [n + 5]
     ends with one number more: the pairs the router made of the live
     tokens, held or not. A mixture that holds every expert routes
-    exactly the pairs it counts, and its vector stays as it was."""
+    exactly the pairs it counts, and its vector has no such entry."""
     lo, n = held or (0, num_experts)
     experts = jnp.arange(n) if held is None else lo + jnp.arange(n)
     counts = jnp.zeros((n,), jnp.int32)
-    touched = fullest = layers = routed = jnp.int32(0)
+    touched = fullest = layers = tiles = routed = jnp.int32(0)
     for topk in jax.tree_util.tree_leaves(stats):             # [B, T, K]
         hit = (topk[..., None] == experts) & \
             live[:, :, None, None]
@@ -310,19 +314,20 @@ def moe_stats_vector(stats, live, num_experts: int, held=None):
         touched = touched + jnp.sum(c > 0, dtype=jnp.int32)
         fullest = fullest + jnp.max(c)
         layers = layers + 1
+        tiles = tiles + visits(c, topk.size)
         if held is not None:
             routed = routed + topk.shape[-1] * jnp.sum(
                 live, dtype=jnp.int32)
-    tail = [touched, fullest, layers] + (
+    tail = [touched, fullest, layers, tiles] + (
         [] if held is None else [routed])
     return jnp.concatenate([counts, jnp.stack(tail)])
 
 
 def moe_stats_len(num_experts: int, held=None) -> int:
     """Entries of ``moe_stats_vector``'s result: the counted experts,
-    the three sums over the layers, and ``pairs_routed`` where the
+    the four sums over the layers, and ``pairs_routed`` where the
     mixture holds a share."""
-    return num_experts + 3 if held is None else held[1] + 4
+    return num_experts + 4 if held is None else held[1] + 5
 
 
 class MixtralBlock(nn.Module):

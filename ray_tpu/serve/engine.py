@@ -460,7 +460,11 @@ class _Slot:
 # counts the HELD ones, and its vector ends with ``pairs_routed``, all
 # the pairs its router made; one that holds them all routes what it
 # counts, and the host reports ``pairs`` under both names.
-_MOE_SUMS = ("pairs", "experts_touched", "load_max", "layer_steps")
+# ``tile_visits`` less ``experts_touched`` is the grouped matmul's
+# visits that found their expert's matrix already fetched
+# (ops/grouped_matmul.py ``visits``).
+_MOE_SUMS = ("pairs", "experts_touched", "load_max", "layer_steps",
+             "tile_visits")
 _MOE_ROUTED = "pairs_routed"
 
 

@@ -84,15 +84,19 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, monkeypatch,
 
 
 # (experts held, D, F, the sorted pairs of a decode call: 8 a token of
-# 32 slots, Kimi-Linear's 128) of the five mixtures the serving cells
-# run; a 4 x 256 prefill call sorts 8,192; the three matmuls of the
-# mixture, each under its own tile plan, none past the memory a kernel
-# may use
+# 32 slots, Kimi-Linear's and Laguna's 128) of the seven mixtures the
+# serving cells run; a 4 x 256 prefill call sorts 8,192; the three
+# matmuls of the mixture, each under its own tile plan (one block a
+# visit; Solar's two column tiles; A.X-K1's and DeepSeek-V3.2's seven
+# contraction blocks up and seven column tiles down), none past the
+# memory a kernel may use
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
 @pytest.mark.parametrize("E,D,F,decode_pairs", [
     (64, 2048, 1024, 256), (40, 4096, 1280, 256), (12, 7168, 2048, 256),
-    (64, 2304, 1024, 1024), (64, 2304, 896, 256)],
-    ids=["olmoe", "solar-open2", "axk1", "kimi-linear", "mellum2"])
+    (64, 2304, 1024, 1024), (64, 2304, 896, 256), (256, 2048, 512, 1024),
+    (8, 7168, 2048, 256)],
+    ids=["olmoe", "solar-open2", "axk1", "kimi-linear", "mellum2",
+         "laguna-xs2", "dsv32"])
 def test_grouped_matmul_compiles(one_chip, monkeypatch, E, D, F,
                                  decode_pairs, kind):
     from ray_tpu.ops import grouped_matmul as gm
@@ -110,6 +114,24 @@ def test_grouped_matmul_compiles(one_chip, monkeypatch, E, D, F,
         ((E, D, F), jnp.bfloat16), ((E, D, F), jnp.bfloat16),
         ((E, F, D), jnp.bfloat16), ((E,), jnp.int32))
     assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+# shapes no cell has, which the same rule has to carry: float32
+# operands, a matrix narrower than whole lanes (the shipped body's, as
+# the fallback plan with a remainder is), a contraction not in whole
+# sublanes, one group, rows short of a tile
+@pytest.mark.parametrize("M,K,N,G,dtype", [
+    (300, 2304, 256, 4, jnp.float32), (256, 2048, 1024, 8, jnp.float32),
+    (256, 200, 72, 3, jnp.bfloat16), (256, 256, 200, 3, jnp.bfloat16),
+    (256, 72, 256, 3, jnp.bfloat16), (256, 5000, 3000, 2, jnp.bfloat16),
+    (16, 128, 128, 1, jnp.bfloat16)])
+def test_grouped_matmul_compiles_off_the_cells_shapes(one_chip, M, K, N, G,
+                                                      dtype):
+    from ray_tpu.ops import grouped_matmul as gm
+    compiled = _compile(gm.grouped_matmul_kernel, one_chip,
+                        ((M, K), dtype), ((G, K, N), dtype),
+                        ((G,), jnp.int32))
+    assert compiled.as_text().count("tpu_custom_call") == 1
 
 
 # (slots, heads): the recurrent state of kimi-linear-d8.gen-sat and of

@@ -152,6 +152,72 @@ def test_prefill_rows_mean_reads_the_round_event(rounds, case):
         assert read(train) is None
 
 
+# ------------------------------------ a mixture's grouped-matmul visits
+
+@pytest.mark.parametrize("case", ["prefill", "decode", "stats"])
+def test_round_event_counts_the_grouped_matmuls_visits(moe_rounds, case):
+    """``moe_tile_visits``: the (row tile of 128 sorted pairs, expert)
+    visits the experts' grouped matmul makes over a dispatch's live
+    pairs, summed over the layers; ``moe_decode_tile_visits`` the
+    decode dispatches' part. Less ``moe_experts_touched`` it is the
+    visits that found their expert's matrix fetched."""
+    rounds, stats, by_hand, touched = moe_rounds
+    if case == "prefill":
+        # one call holds the whole prompt: 64 tokens x 3 = 192 sorted
+        # pairs lie over two row tiles, so in every layer at least one
+        # expert is visited twice
+        got = sum(r["moe_tile_visits"] - r["moe_decode_tile_visits"]
+                  for r in rounds)
+        assert got == by_hand > touched
+    elif case == "decode":
+        # one rider: a step's k pairs are k experts of one row each
+        steps = [r for r in rounds if r["moe_decode_layer_steps"]]
+        assert steps and all(
+            r["moe_decode_tile_visits"] == r["moe_decode_experts_touched"]
+            == r["moe_decode_pairs"] for r in steps)
+    else:
+        for key in ("moe_tile_visits", "moe_decode_tile_visits"):
+            assert stats[key] == sum(r[key] for r in rounds) > 0
+
+
+@pytest.fixture(scope="module")
+def moe_rounds():
+    """One 64-token prompt through a toy mixture's engine in ONE
+    prefill call, then a few decode steps: (the round events, the
+    stats, the prompt's visits and touched experts counted by hand
+    from a cache-less pass's routing)."""
+    import numpy as np
+    from ray_tpu.models.mixtral import MOE_STATS, Mixtral, olmoe_tiny
+    cfg = olmoe_tiny(dtype=jnp.float32, vocab_size=241)
+    model = Mixtral(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
+    prompt = np.random.default_rng(7).integers(1, 240, size=64).tolist()
+    eng = LLMEngine(model, params, max_slots=4, page_size=8, n_pages=65,
+                    chunk=4, prefill_chunk=64, temperature=0.0,
+                    seed=0).start()
+    try:
+        eng.submit(prompt, max_new_tokens=9).result()
+        eng.submit([5, 6, 7], max_new_tokens=2).result()  # reads the rest
+    finally:
+        eng.shutdown()
+    rounds = [e[5] for e in eng.events.snapshot() if e[2] == "round"]
+    _, sown = jax.jit(lambda p, i: model.apply(p, i, mutable=[MOE_STATS])
+                      )(params, jnp.asarray([prompt], jnp.int32))
+    by_hand = touched = 0
+    for topk in jax.tree_util.tree_leaves(sown[MOE_STATS]):   # [1, T, K]
+        group_of_row = np.sort(np.asarray(topk).ravel())
+        by_hand += len({(r // 128, g) for r, g in enumerate(group_of_row)})
+        touched += len(set(group_of_row.tolist()))
+    # the second request's own prefill call: 3 tokens x 3 pairs
+    second = [np.asarray(t).ravel() for t in jax.tree_util.tree_leaves(
+        jax.jit(lambda p, i: model.apply(p, i, mutable=[MOE_STATS]))(
+            params, jnp.asarray([[5, 6, 7]], jnp.int32))[1][MOE_STATS])]
+    by_hand += sum(len(set(t.tolist())) for t in second)
+    touched += sum(len(set(t.tolist())) for t in second)
+    return rounds, dict(eng.stats), by_hand, touched
+
+
 # -------------------------------------------------- the compile counter
 
 @pytest.mark.parametrize("case", ["warm_up_counts", "one_more",

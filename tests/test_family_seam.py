@@ -398,3 +398,57 @@ def test_the_prefill_program_samples_what_the_full_logits_would(
     if capture:
         np.testing.assert_allclose(np.asarray(lp), np.asarray(want_lp),
                                    rtol=1e-5, atol=1e-6)
+
+
+# -------------- a dense family's programs hold nothing of the mixture's
+
+@pytest.mark.parametrize("family", ["llama", "olmo_hybrid", "ouro"])
+def test_a_dense_familys_programs_never_reach_the_grouped_matmul(
+        family, monkeypatch):
+    """The decode (plain and capturing), verify, prefill and cache-less
+    programs of the dense toys are traced with ``ops/grouped_matmul.py``
+    (the op, its kernel, ``visits``) made to raise: a change to that
+    module cannot move one byte of them (their sha256 against the
+    parent's tree is a scratch script's, CHANGES.md PR 57), and they
+    return no routing vector."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import mixtral
+    from ray_tpu.ops import grouped_matmul as gm
+    from ray_tpu.serve import step_programs
+
+    def reached(*_a, **_k):
+        raise AssertionError("a dense program reached the grouped matmul")
+    for module, name in ((gm, "grouped_matmul"), (gm, "visits"),
+                         (gm, "grouped_matmul_kernel"),
+                         (mixtral, "grouped_matmul"), (mixtral, "visits")):
+        monkeypatch.setattr(module, name, reached)
+    tiny, cls, _rule = _families()[family]
+    cfg = tiny(dtype=jnp.float32, vocab_size=229)   # no other test's
+    model = cls(cfg)
+    arr, i32, S = jax.ShapeDtypeStruct, jnp.int32, 6
+    params = {"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), i32))["params"]}
+    pages = jax.eval_shape(lambda: kv_cache.init_kv_pool(
+        cfg, 17, _PAGE, n_slots=S,
+        ring_len=kv_cache.sliding_ring_len(cfg, _PAGE, _T)))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    recurrent = bool(kv_cache.state_bytes_per_slot(
+        cfg, kv_cache.sliding_ring_len(cfg, _PAGE, _T)))
+    texts = [step_programs._jit_decode(model, 0.0, 8, S, cap, None).lower(
+        params, pages, arr((S, 8), i32), arr((S,), i32), arr((S,), i32),
+        key, arr((), i32)).as_text(debug_info=True) for cap in (False, True)]
+    if not recurrent:       # a recurrent state has no rewind: no verify
+        texts.append(step_programs._jit_verify(model, None).lower(
+            params, pages, arr((S, 5), i32), arr((S,), i32),
+            arr((S, 8), i32)).as_text(debug_info=True))
+    texts.append(step_programs._jit_prefill(model, 0.0, 4, False, None).lower(
+        params, pages, arr((4, _T), i32), arr((4,), i32), arr((4,), i32),
+        arr((4, 8), i32), key,
+        *((arr((4,), i32),) if recurrent else ())).as_text(debug_info=True))
+    texts.append(jax.jit(model.apply).lower(
+        params, arr((2, _T), i32)).as_text(debug_info=True))
+    assert len(texts) == 5 - recurrent
+    # no scope of the mixture's (a scope reads "/moe_experts" in a
+    # location), no grouped matmul in its XLA form
+    assert not any("/moe_" in t or "ragged_dot" in t for t in texts)

@@ -262,8 +262,8 @@ def test_the_shares_add_up_to_the_whole_layer(tiny, family):
 
 
 def test_a_mixture_that_holds_every_expert_keeps_its_vector(tiny):
-    """Mixtral's and OLMoE's step programs return the [E + 3] vector
-    they always did (no share declared: what the router made is what
+    """Mixtral's and OLMoE's step programs return the [E + 4] vector
+    of a mixture with no share declared (what the router made is what
     is counted); only a declared share appends the routed pairs, and
     a share of the whole width counts what no share counts."""
     from ray_tpu.models.mixtral import MOE_STATS, moe_stats_vector
@@ -276,7 +276,7 @@ def test_a_mixture_that_holds_every_expert_keeps_its_vector(tiny):
     E = cfg.num_experts
     whole = np.asarray(moe_stats_vector(sown[MOE_STATS], live, E))
     share = np.asarray(moe_stats_vector(sown[MOE_STATS], live, E, (0, E)))
-    assert whole.shape == (E + 3,) and share.shape == (E + 4,)
+    assert whole.shape == (E + 4,) and share.shape == (E + 5,)
     assert whole.tolist() == share[:-1].tolist()
     assert whole[:E].sum() == share[-1] == 24 * cfg.num_experts_per_tok
 
@@ -301,7 +301,10 @@ def test_a_share_routes_over_the_whole_width(tiny):
     want = [(chosen == e).sum() for e in range(4, 8)]
     assert vec[:4].tolist() == want
     assert vec[4] == sum(c > 0 for c in want) and vec[5] == max(want)
-    assert vec[6] == 1 and vec[7] == 24 * 4
+    assert vec[6] == 1 and vec[8] == 24 * 4
+    # the grouped matmul's visits over those pairs: 96 sorted rows lie
+    # in ONE row tile, so each touched expert is visited once
+    assert vec[7] == vec[4]
 
 
 # ------------------------------------------- the pool, by kind of layer

@@ -1,6 +1,7 @@
 """The mixture's grouped matmul on the local TPU chip
-(``ops/grouped_matmul.py``: Pallas ``megablox.gmm`` under
-``tile_plan``), one call at a time as a step program makes it. A line
+(``ops/grouped_matmul.py``: its own Pallas kernel under ``tile_plan``,
+the matrix fetched by group), one call at a time as a step program
+makes it. A line
 is one (experts held E, K, N, sorted pairs m, pairs that have a held
 expert, seed): the pairs are dealt to the experts evenly at random,
 the rest lie past the last group. One JSON line a reading: the plan,
@@ -10,9 +11,11 @@ twice in a row), the touched experts' bytes a second and their share
 of the chip's 819 GB/s, and for a compute-bound call the share of its
 bfloat16 peak that the held pairs' operations come to.
 
-``--cells`` times the ten (K, N) the five mixtures present (w1/w3 and
-w2 each), at their decode call's m and pairs and at their prefill
-call's, under the rule's plan and the parent's constants.
+``--cells`` times the (K, N) the seven mixtures present (w1/w3 and w2
+each), at their decode call's m and pairs and at their prefill call's,
+under the rule's plan: the op as it stands (``body`` "group_keyed") and
+the kernel JAX ships under the same plan (``megablox.gmm``, ``body``
+"gmm": what the op ran until PR 57).
 ``--tiling tm,tk,tn[;tm,tk,tn...]`` times those plans instead;
 ``--sweep`` every plan whose tiles divide the matrix inside the
 kernel's memory at tm 128, then tm 64 and 256 at the fastest.
@@ -32,7 +35,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 HBM = 819e9            # benchmarks/peaks.json, TPU v5e
 PEAK = 197e12          # bfloat16
-PARENT = (128, 2048, 1024)      # the constants before tile_plan
 
 # (config, experts held, D, F, decode (m, held pairs), prefill (m, held
 # pairs)): the serving cells' calls (PERF.md section 4)
@@ -42,6 +44,8 @@ CELLS = (
     ("axk1", 12, 7168, 2048, (256, 15), (8192, 512)),
     ("kimi-linear", 64, 2304, 1024, (1024, 250), (8192, 2048)),
     ("mellum2", 64, 2304, 896, (256, 196), (8192, 8192)),
+    ("laguna-xs2", 256, 2048, 512, (1024, 1000), (8192, 8192)),
+    ("dsv32", 8, 7168, 2048, (256, 6), (8192, 256)),
 )
 
 
@@ -108,9 +112,11 @@ def main():
         byts = touched * k * n * 2
         flops = 2 * pairs * k * n
 
-        def read(plan, fn):
+        def read(plan, fn, body="group_keyed"):
             rec = {"line": name, "E": e, "K": k, "N": n, "m": m,
-                   "pairs": pairs, "touched": touched, "plan": list(plan)}
+                   "pairs": pairs, "touched": touched,
+                   "visits": int(gm.visits(sizes, m)), "plan": list(plan),
+                   "body": body}
             try:
                 got = np.asarray(fn(rows, stacks[0], sizes)[:pairs],
                                  np.float32)
@@ -139,15 +145,20 @@ def main():
                     return gm.grouped_matmul_kernel(rows, w, sizes)
             return jax.jit(fn)
 
+        def shipped(plan):
+            from jax.experimental.pallas.ops.tpu.megablox import gmm
+            return jax.jit(lambda rows, w, sizes: gmm(
+                rows, w, sizes, preferred_element_type=rows.dtype,
+                tiling=plan))
+
         rule = gm.tile_plan(m, k, n, 2)
-        parent = (PARENT[0], min(k, PARENT[1]), min(n, PARENT[2]))
         if args.sweep:
             grid = [(128, tk, tn) for tk in gm.dividing_tiles(k)
                     for tn in gm.dividing_tiles(n)
                     if (2 << 20) <= tk * tn * 2
                     and gm.vmem_bytes(128, tk, tn, 2) <= 16 << 20]
             took = {p: read(p, with_plan(p))
-                    for p in dict.fromkeys(grid + [rule, parent])}
+                    for p in dict.fromkeys(grid + [rule])}
             best = min((p for p in took if took[p]), key=took.get)
             for tm in (64, 256):
                 p = (tm,) + best[1:]
@@ -157,8 +168,7 @@ def main():
                 read(p, with_plan(p))
         else:
             read(rule, jax.jit(gm.grouped_matmul))
-            if parent != rule:
-                read(parent, with_plan(parent))
+            read(rule, shipped(rule), body="gmm")
 
 
 if __name__ == "__main__":
