@@ -49,6 +49,7 @@ from ray_tpu.models.mixtral import MoEFeedForward
 from ray_tpu.ops.paged_attention import (_paged_window_attention,
                                          paged_append)
 from ray_tpu.ops.sparse_latent_attention import (SELECTION_STATS,
+                                                 SelectionStats,
                                                  sparse_attention,
                                                  topk_mask)
 
@@ -107,6 +108,14 @@ class AXK1Config:
         """What a deployment builds (models/llama.py ``LlamaConfig``);
         it declares no partition rules: none exist yet."""
         return AXK1
+
+    @property
+    def stats_sections(self) -> tuple:
+        """What the layers count on the device beside the mixture
+        (models/mixtral.py ``stats_sections``): with an indexer
+        (``index_topk``) ``MLAttention`` sows ``SELECTION_STATS``."""
+        return (SelectionStats(),) if getattr(self, "index_topk", None) \
+            else ()
 
     @property
     def latent_dim(self) -> int:

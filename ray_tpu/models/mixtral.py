@@ -16,7 +16,7 @@ models/llama.py, so `generate` / `generate_stream` work unchanged."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -323,11 +323,60 @@ def moe_stats_vector(stats, live, num_experts: int, held=None):
     return jnp.concatenate([counts, jnp.stack(tail)])
 
 
-def moe_stats_len(num_experts: int, held=None) -> int:
-    """Entries of ``moe_stats_vector``'s result: the counted experts,
-    the four sums over the layers, and ``pairs_routed`` where the
-    mixture holds a share."""
-    return num_experts + 4 if held is None else held[1] + 5
+@dataclasses.dataclass(frozen=True)
+class MoEStats:
+    """``moe_stats_vector``'s result as a SECTION of the vector a step
+    program returns (serve/step_programs.py concatenates its model's
+    sections, serve/round_accounts.py splits them by ``len``), and the
+    host's reading of it: under ``prefix``, and for the decode
+    dispatches' part ``prefix + "decode_"``, the ``round`` event reports
+    ``names``. ``pairs`` is the sum of the ``head``, the leading entries
+    one a counted expert, whose running totals feed ``load_report``;
+    the rest is the scalar tail in its order. A mixture that holds
+    every expert has no last entry and reports ``pairs`` under both
+    names."""
+    num_experts: int
+    held: Optional[Tuple[int, int]] = None
+    collection = MOE_STATS
+    prefix = "moe_"
+    names = ("pairs", "experts_touched", "load_max", "layer_steps",
+             "tile_visits", "pairs_routed")
+
+    @property
+    def head(self) -> int:
+        return self.num_experts if self.held is None else self.held[1]
+
+    def __len__(self) -> int:
+        return self.head + len(self.names) - 2 + (self.held is not None)
+
+    def reduce(self, sown, live):
+        return moe_stats_vector(sown, live, self.num_experts, self.held)
+
+    def read(self, vec) -> Dict[str, int]:
+        """A host copy of the vector as ``{name: count}``."""
+        sums = dict(zip(self.names, (int(vec[:self.head].sum()),
+                                     *(int(x) for x in vec[self.head:]))))
+        sums.setdefault(self.names[-1], sums["pairs"])
+        return sums
+
+    def load_report(self, pairs) -> Dict[str, Any]:
+        """The routing so far, from the head's running totals: each
+        expert's share of the (token, expert) pairs of live rows, and
+        their number."""
+        total = int(pairs.sum())
+        return {"moe_pairs_total": total,
+                "moe_expert_share": (pairs / max(1, total)).tolist()}
+
+
+def stats_sections(cfg) -> tuple:
+    """The sections of the counter vector a model's step programs
+    return, in its order: the mixture's where the config has experts,
+    then those the config names itself (``stats_sections``, declared
+    beside the code that sows their collection: models/axk1.py). ()
+    for a model that counts nothing on the device."""
+    mixture = ((MoEStats(cfg.num_experts, cfg.experts_held),)
+               if getattr(cfg, "num_experts", 0) else ())
+    return mixture + tuple(getattr(cfg, "stats_sections", ()))
 
 
 class MixtralBlock(nn.Module):

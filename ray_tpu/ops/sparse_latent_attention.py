@@ -46,6 +46,8 @@ gather.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 
@@ -71,11 +73,34 @@ def selection_stats_vector(stats, live):
     layers, as one int32 vector [3]: index keys scored, entries chosen
     (the sum of ``|S_t|``), entries the attention fetched. ``stats`` is
     the ``SELECTION_STATS`` collection of an apply, ``live`` [B, T]."""
-    total = jnp.zeros((3,), jnp.int32)
+    total = jnp.zeros((len(SelectionStats.names),), jnp.int32)
     for counts in jax.tree_util.tree_leaves(stats):            # [3, B, T]
         total = total + jnp.sum(jnp.where(live[None], counts, 0),
                                 axis=(1, 2), dtype=jnp.int32)
     return total
+
+
+@dataclasses.dataclass(frozen=True)
+class SelectionStats:
+    """``selection_stats_vector``'s result as a SECTION of the vector a
+    step program returns (models/mixtral.py ``MoEStats`` is the other,
+    of the same members): the ``round`` event reports ``names``, and
+    the decode dispatches' part alone under them after ``decode_``. A
+    gather reads what was chosen, a masked walk its whole window."""
+    collection = SELECTION_STATS
+    prefix = ""
+    head = 0                        # no entry is one of many alike
+    names = ("index_keys_scored", "sparse_entries_chosen",
+             "sparse_entries_read")
+
+    def __len__(self) -> int:
+        return len(self.names)
+
+    def reduce(self, sown, live):
+        return selection_stats_vector(sown, live)
+
+    def read(self, vec) -> dict:
+        return dict(zip(self.names, (int(x) for x in vec)))
 
 
 def _blocks(page_table, pos, T: int, Pg: int):

@@ -102,20 +102,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from ray_tpu.models.kv_cache import (KIND_KV, KIND_LATENT, BlockAllocator,
-                                     PagedKVLayer, check_kv_dtype,
-                                     export_page_bytes,
-                                     has_latent_pages, init_kv_pool,
-                                     kv_pool_page_bytes, kv_query_heads,
-                                     latent_page_width, layer_kinds,
-                                     page_cols_from_bytes, page_layout,
+from ray_tpu.models.kv_cache import (BlockAllocator, check_kv_dtype,
+                                     export_page_bytes, init_kv_pool,
+                                     kv_pool_page_bytes,
+                                     page_cols_from_bytes,
                                      refuse_unsupported,
                                      sliding_bytes_per_slot,
                                      sliding_ring_len,
                                      state_bytes_per_slot)
-from ray_tpu.ops import latent_window_attention as latent_window
-from ray_tpu.ops import paged_decode_attention as paged_decode
-from ray_tpu.ops.paged_attention import paged_window_block_pages
 from ray_tpu.serve import kv_migration, obs, spec_decode
 # Typed lifecycle errors live in a jax-free module (serve/errors.py)
 # so the HTTP proxy and clients can import them without the device
@@ -125,15 +119,14 @@ from ray_tpu.serve.errors import (DeadlineExceeded, EngineDraining,
                                   RequestCancelled, RequestError)
 from ray_tpu.serve.faults import EngineFault
 from ray_tpu.serve.prefix_cache import PrefixCache
+from ray_tpu.serve.round_accounts import RoundAccounts
 from ray_tpu.serve.scheduler import (LANE_BATCH, LANE_ONLINE,
                                      REPLICA_ROLES, ROLE_UNIFIED,
                                      StepPlan, SlotView, plan_step,
                                      role_plan_caps)
 from ray_tpu.serve.step_programs import (_jit_copy_page, _jit_decode,
                                          _jit_prefill, _jit_seed,
-                                         _jit_verify, _jit_write_page,
-                                         _moe_vector_of, ambient_mesh,
-                                         selection_len)
+                                         _jit_verify, _jit_write_page)
 from ray_tpu.util.compile_cache import (build_log, metadata_keyed,
                                         summarize_builds)
 
@@ -453,83 +446,6 @@ class _Slot:
         return len(self.prompt) - self.prefilled
 
 
-# What the ``round`` event reports of a step program's routing vector
-# (models/mixtral.py moe_stats_vector: the counted experts' pairs, then
-# one entry for each name here but the first): ``pairs`` is the sum of
-# the counts. A mixture that holds a share of its router's experts
-# counts the HELD ones, and its vector ends with ``pairs_routed``, all
-# the pairs its router made; one that holds them all routes what it
-# counts, and the host reports ``pairs`` under both names.
-# ``tile_visits`` less ``experts_touched`` is the grouped matmul's
-# visits that found their expert's matrix already fetched
-# (ops/grouped_matmul.py ``visits``).
-_MOE_SUMS = ("pairs", "experts_touched", "load_max", "layer_steps",
-             "tile_visits")
-_MOE_ROUTED = "pairs_routed"
-
-
-# What the same vector ends with for a model whose latent layers choose
-# the entries they attend (serve/step_programs.py ``selection_len``),
-# summed over those layers and the dispatch's live tokens: index keys
-# the indexer scored, entries chosen (the sum of |S_t|) and entries the
-# attention fetched (a gather reads what was chosen, a masked walk its
-# whole window). The decode dispatches' part alone stands under the
-# same names after ``decode_``.
-_SELECTION = ("index_keys_scored", "sparse_entries_chosen",
-              "sparse_entries_read")
-
-
-def _new_moe_info(selection: bool = False) -> Dict[str, int]:
-    """The router's counters of a mixture-of-experts model, as the
-    ``round`` event reports them (docs/serving.md), and the selection's
-    behind them where the model has one."""
-    info = {prefix + key: 0 for prefix in ("moe_", "moe_decode_")
-            for key in _MOE_SUMS + (_MOE_ROUTED,)}
-    if selection:
-        info.update({prefix + key: 0 for prefix in ("", "decode_")
-                     for key in _SELECTION})
-    return info
-
-
-def _new_round_info() -> Dict[str, int]:
-    """What a round dispatched, as its ``round`` event reports it.
-    ``backlog`` is the planner's (serve/scheduler.py
-    ``StepPlan.backlog``): mid-prefill slots the prefill call had no
-    row for, counted only where that queue outlasts the riders; beside
-    a non-zero ``decode_steps`` it says the round's decode was cut to
-    ``BACKLOG_DECODE_STEPS`` (``stats["backlog_rounds"]`` counts those
-    rounds). ``prefill_width`` is the ``T`` of the round's ``[rows, T]``
-    prefill call (a power of two up to ``prefill_chunk``; 0 = no
-    call): the shape a call's device time is grouped by.
-    ``prefill_head_rows`` is the positions that call applied the
-    model's output head to: its ``B``, one a row (the program asks the
-    model for the logits of each row's ``last_idx`` alone,
-    serve/step_programs.py ``_jit_prefill``), dummy rows included, where
-    ``B x T`` went through the head before; 0 = no call.
-    ``decode_context_tokens`` is the sum over the decode dispatch's
-    riders of their OWN context lengths after it (what each rider's
-    last step attended), where ``decode_window_tokens`` is the longest
-    rider's, rounded up to a block: the tokens a paged attention MUST
-    read, beside those its block loop does. ``prefill_kernel_blocks``
-    is 0 where the prefill program holds no kernel for its latent
-    layers' attention (ops/latent_window_attention.py), else the key
-    blocks ONE such layer's kernel visits over the call's live rows,
-    each row to the block of its own last query.
-    ``decode_kernel_pages`` is 0 where the decode program holds no
-    kernel for its K/V or latent layers' attention
-    (ops/paged_decode_attention.py), else the pages ONE such layer's
-    kernel visits at the dispatch's last step, each rider to its own
-    last page: beside ``decode_riders`` x ``decode_window_tokens`` it
-    says how far the visited pages sit from the block loop's."""
-    return {"decode_riders": 0, "decode_steps": 0, "backlog": 0,
-            "decode_window_tokens": 0, "decode_context_tokens": 0,
-            "decode_kernel_pages": 0,
-            "prefill_tokens": 0, "prefill_budget": 0,
-            "prefill_rows": 0, "prefill_window_tokens": 0,
-            "prefill_kernel_blocks": 0, "prefill_width": 0,
-            "prefill_head_rows": 0}
-
-
 class LLMEngine:
     """Continuous-batching decode engine for one model replica.
 
@@ -720,13 +636,11 @@ class LLMEngine:
         # Page-table width: the most a slot can address, so cap it at
         # what the model can legally address rather than the whole
         # pool. The attention programs gather and attend only up to
-        # the batch's longest live context, in blocks of
-        # ``_window_block`` tokens (ops/paged_attention.py
-        # _paged_window_attention); the width bounds that window.
+        # the batch's longest live context, a block of tokens at a time
+        # (ops/paged_attention.py _paged_window_attention); the width
+        # bounds that window.
         self.max_pages = min(n_pages - 1,
                              -(-self.cfg.max_seq_len // page_size))
-        self._window_block = page_size * paged_window_block_pages(
-            page_size, self.max_pages)
         # KV storage dtype: "fp" (cfg.dtype pages, PR 1-14 behavior)
         # or "int8" (quantized pages + per-page scales, half the page
         # bytes -> double the pages at a fixed byte budget).
@@ -751,8 +665,6 @@ class LLMEngine:
         # (a sliding layer's ring among them: the window and one
         # prefill chunk in whole pages, whatever the context)
         self.ring_len = sliding_ring_len(self.cfg, page_size, self.PC)
-        self.sliding_window = (self.cfg.sliding_window if self.ring_len
-                               else 0)
         self.sliding_bytes_per_slot = sliding_bytes_per_slot(
             self.cfg, self.ring_len)
         self.state_bytes_per_slot = state_bytes_per_slot(self.cfg,
@@ -838,16 +750,10 @@ class LLMEngine:
         self._fetchq: "collections.deque" = collections.deque()
         # in-flight prefills: [(firsts_dev, [(ix, slot, row), ...])]
         self._pending_prefill: List = []
-        # A mixture-of-experts model's step programs also return what
-        # the router chose (models/mixtral.py moe_stats_vector), over
-        # live rows only: [(vector_dev, is_decode)], read back behind
-        # the tokens of the same dispatch, never waited for. 0 experts
-        # = a dense model: nothing is returned, queued or reported.
-        self._moe_experts = _moe_vector_of(model)[0]
+        # the counters' vectors of a model that counts on the device
+        # (serve/round_accounts.py): [(vector_dev, is_decode)], read back
+        # behind the tokens of the same dispatch, never waited for
         self._moe_pending: "collections.deque" = collections.deque()
-        self._moe_expert_pairs = np.zeros((self._moe_experts,), np.int64)
-        self._selection = bool(selection_len(self.cfg))
-        self._moe_unreported = _new_moe_info(self._selection)
         # Device-authoritative decode state: the next-token input and
         # write position per slot LIVE ON DEVICE and chain dispatch to
         # dispatch — no host readback sits on the decode critical
@@ -880,6 +786,12 @@ class LLMEngine:
         self._force_killed = False
         self._thread: Optional[threading.Thread] = None
         self.stats: Dict[str, int] = collections.Counter()
+        # what a round dispatched, counted and must have read, for its
+        # ``round`` event and the stats
+        self.accounts = RoundAccounts(
+            self.cfg, self.stats, self.pages, slots=self.S,
+            page_size=page_size, max_pages=self.max_pages,
+            kv_dtype=self.kv_dtype, mesh=self._mesh)
         # Request-lifecycle knobs: bounded admission + bounded retry
         if max_queued is not None and max_queued < 0:
             raise ValueError("max_queued must be >= 0 or None")
@@ -940,8 +852,6 @@ class LLMEngine:
             self.capture_logprobs, self._mesh))
         self._seed_fn = self._track_program(_jit_seed())
         _clk.mark("programs")
-        # what this round dispatched, for its ``round`` event
-        self._round_info = _new_round_info()
         # a replica's start is this event and the ``compile`` events
         # up to its first round that builds nothing
         self.events.append("engine_init", data=_clk.parts())
@@ -1400,17 +1310,6 @@ class LLMEngine:
             "prefix_pages_evicted": evicted})
         self._hb = time.monotonic()
 
-    def _moe_load_report(self) -> Dict[str, Any]:
-        """A mixture-of-experts model's routing so far: each expert's
-        share of the (token, expert) pairs of live rows, and their
-        number. Nothing for a dense model."""
-        if not self._moe_experts:
-            return {}
-        pairs = self._moe_expert_pairs
-        total = int(pairs.sum())
-        return {"moe_pairs_total": total,
-                "moe_expert_share": (pairs / max(1, total)).tolist()}
-
     def load_report(self) -> Dict[str, Any]:
         """Compact load snapshot for pool routing: free capacity,
         queue pressure, outstanding token work, and the prefix-cache
@@ -1506,7 +1405,7 @@ class LLMEngine:
                     self.prefix_digest_max)
                     if self.prefix_cache is not None
                     else frozenset()),
-                **self._moe_load_report(),
+                **self.accounts.load_report(),
             }
         if self._lock.acquire(timeout=0.02):
             try:
@@ -1848,7 +1747,7 @@ class LLMEngine:
             # share the trace's clock). Closed, with no trace running,
             # one costs under a microsecond.
             _rnd = self._round
-            self._round_info = _ri = _new_round_info()
+            _ri = self.accounts.begin_round()
             self._fire("step")     # global-fault site: escapes to
                                    # _fail_all, like real device loss
             if self._stopped:
@@ -1992,7 +1891,7 @@ class LLMEngine:
                 "readback_s": round(_now - _tde, 6),
                 "cpu_s": round(_cpu, 6),
                 "readback_cpu_s": round(_cpu_rb, 6),
-                **_ri, **self._take_moe_info_locked()})
+                **_ri, **self.accounts.take()})
             _pm["round_wall"].observe(_now - _t0)
             _pm["host_gap"].observe(_gap)
             self._count_programs_locked(_now - _t0)
@@ -2125,10 +2024,6 @@ class LLMEngine:
                               prefill_chunk=self.PC,
                               prefill_batch=self._max_prefill_batch,
                               max_run_ahead=self.KMAX)
-        # what the round's prefill call could carry: its rows times
-        # a row's chunk
-        self._round_info["prefill_budget"] = (
-            caps["prefill_batch"] * caps["prefill_chunk"])
         plan = plan_step(views, total_slots=self.S,
                          prefill_chunk=caps["prefill_chunk"],
                          decode_chunk=self.K,
@@ -2136,12 +2031,8 @@ class LLMEngine:
                          prefill_batch=caps["prefill_batch"],
                          eos_bounded=self.eos_id is not None,
                          spec_enabled=bool(self.spec_len))
-        # prompts queue behind full rows and outlast the riders: the
-        # planner cut this round's decode (the spec lane's one verify
-        # a round is not a cut)
-        self._round_info["backlog"] = plan.backlog
-        if plan.backlog and plan.decode_steps:
-            self.stats["backlog_rounds"] += 1
+        self.accounts.note_plan(
+            plan, caps["prefill_batch"] * caps["prefill_chunk"])
         return plan
 
     def _propose_spec_locked(self):
@@ -2823,93 +2714,6 @@ class LLMEngine:
                                           else LANE_ONLINE)})
         self._wait.appendleft(slot.req)   # front: re-admit first
 
-    def _prefill_kernel_serves(self, T: int) -> bool:
-        """Whether the ``[rows, T]`` prefill program's latent layers
-        attend through the kernel: the question
-        ``_paged_window_attention`` asks of the same shapes, under the
-        mesh the program is traced under."""
-        cfg = self.cfg
-        with ambient_mesh(self._mesh):
-            return has_latent_pages(cfg) and latent_window.serves(
-                T, cfg.n_heads, latent_page_width(cfg), cfg.kv_lora_rank,
-                self.Pg, cfg.dtype)
-
-    def _decode_kernel_serves(self) -> bool:
-        """Whether the decode program's paged layers (the latent ones
-        where the model has them, else the K/V ones) attend through the
-        kernel: ``paged_decode.applies``, the very question
-        ``_paged_window_attention`` asks, of a decode step's queries
-        and one layer's pages as ``page_layout`` stores them (what the
-        pool is built from: its type and int8 scales are read there,
-        not decided again here), under the mesh the program is traced
-        under."""
-        cfg = self.cfg
-        latent = has_latent_pages(cfg)
-        if not latent and KIND_KV not in layer_kinds(cfg):
-            return False
-        # a page without its pass axis, if any: [Pg, KH, D] of K and of
-        # V (and an int8 pool's scales), or a latent pool's one [Pg, W]
-        k, v, sk = ([
-            jax.ShapeDtypeStruct((1,) + shape[-(2 if latent else 3):],
-                                 dtype)
-            for shape, dtype in page_layout(
-                cfg, KIND_LATENT if latent else KIND_KV, self.Pg,
-                self.kv_dtype)] + [None, None])[:3]
-        # a head's query is as wide as what it is scored against: a KV
-        # head's key, or (absorbed) a stored latent entry, whose value
-        # is its latent; and the layer hands the kernel a whole group
-        # of query heads for every head ROW the page stores, the rows
-        # that pad it among them (models/olmo_hybrid.py: 30 heads
-        # stored, and so asked, as 32)
-        heads = (cfg.n_heads if latent else k.shape[-2] * (
-            kv_query_heads(cfg, KIND_KV) // cfg.n_kv_heads))
-        q = jax.ShapeDtypeStruct((self.S, 1, heads, k.shape[-1]),
-                                 cfg.dtype)
-        table = jax.ShapeDtypeStruct((self.S, self.max_pages), jnp.int32)
-        with ambient_mesh(self._mesh):
-            return paged_decode.applies(
-                q, k, v, sk, table, cfg.kv_lora_rank if latent else None)
-
-    def _note_window(self, key: str, end: int) -> None:
-        """Record under ``key`` the positions a dispatch's paged
-        attention gathers and attends when its longest live row's last
-        query sits at ``end - 1``: ``end`` rounded up to whole blocks,
-        inside the table's width. The round event keeps the round's
-        widest, ``stats`` the sum over dispatches. The host knows every
-        row's position, so this costs no readback."""
-        blk = self._window_block
-        window = min(-(-end // blk) * blk, self.max_pages * self.Pg)
-        self._round_info[key] = max(self._round_info[key], window)
-        self.stats[key] += window
-
-    def _note_decode_contexts(self, ends) -> None:
-        """Record the sum of the riders' own context lengths when each
-        rider's last query of a decode (or verify) dispatch sits at its
-        ``end - 1``: the ``round`` event's and the stats'
-        ``decode_context_tokens``, from the host's positions. For a
-        model with sliding-window layers also ``decode_sliding_keys``:
-        the same sum with each rider's context cut at the window, the
-        keys ONE sliding layer's last step has to score."""
-        ends = [int(e) for e in ends]
-        total = sum(ends)
-        self._round_info["decode_context_tokens"] += total
-        self.stats["decode_context_tokens"] += total
-        if self.sliding_window:
-            keys = sum(min(e, self.sliding_window) for e in ends)
-            self._note_sliding(
-                decode_sliding_keys=keys,
-                sliding_kernel_keys=self._ring_kernel_keys(len(ends)))
-
-    def _note_state_slots(self, n: int) -> None:
-        """``n`` slots' recurrent state was advanced by a dispatch (a
-        prefill call's rows, a decode call's riders): the ``round``
-        event's and the stats' ``state_slots``. Nothing for a model
-        that keeps none."""
-        if self.state_bytes_per_slot:
-            self._round_info["state_slots"] = (
-                self._round_info.get("state_slots", 0) + n)
-            self.stats["state_slots"] += n
-
     def _dispatch_chunk_locked(self, steps: int):
         """Launch one decode dispatch of ``steps`` steps
         asynchronously. The full carry — pages, per-slot write
@@ -2946,20 +2750,9 @@ class LLMEngine:
             slot.pos += steps
             slot.decoded += steps
         self._fetchq.append((toks, riders, steps))
-        self._note_state_slots(len(riders))
-        self._round_info["decode_riders"] = len(riders)
-        self._round_info["decode_steps"] = steps
-        # slot.pos already counts this dispatch: the window its LAST
-        # step attends (the program widens it step by step)
-        self._note_window("decode_window_tokens",
-                          max(slot.pos for _i, slot, _t in riders))
-        self._note_decode_contexts(slot.pos for _i, slot, _t in riders)
-        if self._decode_kernel_serves():
-            pages = paged_decode.kernel_pages(
-                (slot.pos for _i, slot, _t in riders), self.Pg,
-                self.max_pages)
-            self._round_info["decode_kernel_pages"] += pages
-            self.stats["decode_kernel_pages"] += pages
+        # slot.pos already counts this dispatch
+        self.accounts.note_decode(
+            [slot.pos for _i, slot, _t in riders], steps)
         self.events.append("decode", data=steps)
         self.stats["chunks"] += 1
         self.stats["decode_steps"] += steps
@@ -3073,11 +2866,8 @@ class LLMEngine:
         self._moe_pending.extend((v, True) for v in moe)
         out = np.asarray(out_dev)    # host sync: acceptance gates
         self._hb = time.monotonic()  # verify completed: progress
-        self._round_info["decode_riders"] = len(rows)
-        self._round_info["decode_steps"] = 1   # one verify forward
-        self._note_window("decode_window_tokens",
-                          max(slot.pos for _i, slot, _d in rows) + T)
-        self._note_decode_contexts(slot.pos + T for _i, slot, _d in rows)
+        self.accounts.note_decode(
+            [slot.pos + T for _i, slot, _d in rows], 1, verify=True)
         m = spec_decode.metrics()
         self.stats["spec_rounds"] += 1
         # surviving slots' device decode state is reseeded with the
@@ -3249,46 +3039,15 @@ class LLMEngine:
                                        else lp_buf[:take, i].tolist()))
 
     def _collect_moe_locked(self) -> None:
-        """Read the routing counters of every dispatch that has
-        finished (each vector leaves its program with that dispatch's
-        tokens, so after a token readback the ones before it are
-        there: this never waits) and add them to the running totals
-        and to what the next ``round`` event reports."""
+        """Hand the accounts the counters of every dispatch that has
+        finished (a vector leaves its program with that dispatch's
+        tokens: after a token readback this never waits)."""
         ready = []
         while self._moe_pending and _dev_ready(self._moe_pending[0][0]):
             ready.append(self._moe_pending.popleft())
-        if not ready:
-            return
-        E, acc = self._moe_experts, self._moe_unreported
-        for vec, (_v, decode) in zip(
-                jax.device_get([v for v, _d in ready]), ready):
-            if self._selection:
-                vec, chose = vec[:-len(_SELECTION)], vec[-len(_SELECTION):]
-                for prefix in ("", "decode_") if decode else ("",):
-                    for key, value in zip(_SELECTION, chose):
-                        acc[prefix + key] += int(value)
-            self._moe_expert_pairs += vec[:E]
-            sums = dict(zip(
-                _MOE_SUMS + (_MOE_ROUTED,),
-                (int(vec[:E].sum()), *(int(x) for x in vec[E:]))))
-            # a mixture that holds every expert routes what it counts
-            sums.setdefault(_MOE_ROUTED, sums["pairs"])
-            for prefix in ("moe_", "moe_decode_") if decode else ("moe_",):
-                for key, value in sums.items():
-                    acc[prefix + key] += value
-
-    def _take_moe_info_locked(self) -> Dict[str, int]:
-        """The counters gathered since the last ``round`` event, for
-        this one: those of the dispatches whose results were read back
-        meanwhile (under the overlapped loop, the round before's).
-        Nothing for a dense model."""
-        if not self._moe_experts:
-            return {}
-        out, self._moe_unreported = (self._moe_unreported,
-                                     _new_moe_info(self._selection))
-        for k, v in out.items():
-            self.stats[k] += v
-        return out
+        if ready:
+            self.accounts.fold(jax.device_get([v for v, _d in ready]),
+                               [d for _v, d in ready])
 
     def _fail_rider_locked(self, ix: int, slot: _Slot,
                            err: BaseException) -> None:
@@ -3463,64 +3222,9 @@ class LLMEngine:
             rid=tuple(slot.req.rid for _ix, slot, _t in rows),
             data=tuple((ix, take) for ix, _s, take in rows))
         self.stats["prefills"] += 1
-        self.stats["prefill_rows"] += len(rows)
-        self._round_info["prefill_rows"] += len(rows)
-        self._round_info["prefill_width"] = T
-        self.stats["prefill_head_rows"] += B
-        self._round_info["prefill_head_rows"] += B
-        self._note_state_slots(len(rows))
-        _granted = sum(take for _ix, _s, take in rows)
-        self.stats["prefill_tokens"] += _granted
-        self._round_info["prefill_tokens"] += _granted
-        # every row's queries run to start + T, padding and all
-        self._note_window("prefill_window_tokens",
-                          int(start[:len(rows)].max()) + T)
-        if self._prefill_kernel_serves(T):
-            blocks = latent_window.kernel_blocks(
-                start[:len(rows)], T, self._window_block,
-                -(-self.max_pages * self.Pg // self._window_block))
-            self._round_info["prefill_kernel_blocks"] += blocks
-            self.stats["prefill_kernel_blocks"] += blocks
+        self.accounts.note_prefill(
+            start[:len(rows)], sum(take for _ix, _s, take in rows), B, T)
         self.stats["prefilled_seqs"] += len(placements)
         self._hb = time.monotonic()   # dispatch completed: a long
                                       # prompt prefilling chunk by
                                       # chunk is moving, not wedged
-
-    # The sliding layers' counters stand BELOW the dispatch sites: an
-    # operation's location in a step program carries its callers'
-    # lines, so a line added at or above a site re-keys every cell's
-    # compile cache (PERF.md section 7, after PR 45).
-
-    def _note_sliding(self, **counts: int) -> None:
-        """Add a decode dispatch's ``counts`` to the ``round`` event
-        and the stats of a model with sliding-window layers (no other
-        model's carry the keys)."""
-        for key, n in counts.items():
-            self._round_info[key] = self._round_info.get(key, 0) + n
-            self.stats[key] += n
-
-    def _ring_kernel_keys(self, riders: int) -> int:
-        """``sliding_kernel_keys`` of a decode dispatch of ``riders``:
-        the ring positions ONE sliding layer's kernel
-        (ops/ring_window_attention.py) fetches for them, beside
-        ``decode_sliding_keys`` (the riders' windows, what must be
-        read); 0 where the decode program holds the ``jax.numpy`` form.
-        ``ring_window.applies``, the very question the layer asks, of a
-        decode step's queries and new keys and one layer's rings as the
-        pool stores them, under the mesh the program is traced under."""
-        # imported here for the same reason: the module's head stands
-        # above every site
-        from ray_tpu.models.kv_cache import KIND_SLIDING, SlidingRing
-        from ray_tpu.ops import ring_window_attention as ring_window
-        cfg = self.cfg
-        ring = next(jax.ShapeDtypeStruct(e.k.shape, e.k.dtype)
-                    for e in self.pages if isinstance(e, SlidingRing))
-        q, k = (jax.ShapeDtypeStruct((self.S, 1, heads, cfg.head_dim),
-                                     cfg.dtype)
-                for heads in (kv_query_heads(cfg, KIND_SLIDING),
-                              cfg.n_kv_heads))
-        with ambient_mesh(self._mesh):
-            serves = ring_window.applies(q, k, k, ring, ring,
-                                         self.sliding_window)
-        return ring_window.kernel_keys(riders, self.ring_len) if serves \
-            else 0

@@ -24,30 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.kv_cache import kv_layer_store, kv_layer_view
-
-
-def _moe_vector_of(model):
-    """(experts counted, held, length) of the routing vector the
-    model's step programs return: ``held`` is the config's
-    ``experts_held`` share (lo, n) or None where the mixture holds
-    every expert; (0, None, 0) for a dense model."""
-    cfg = model.config
-    if not getattr(cfg, "num_experts", 0):
-        return 0, None, 0
-    from ray_tpu.models.mixtral import experts_held, moe_stats_len
-    return (experts_held(cfg)[1], cfg.experts_held,
-            moe_stats_len(cfg.num_experts, cfg.experts_held)
-            + selection_len(cfg))
-
-
-def selection_len(cfg) -> int:
-    """Entries the routing vector of a model whose latent layers CHOOSE
-    their entries (a config with ``index_topk``) ends with: what they
-    scored, chose and read (ops/sparse_latent_attention.py
-    ``selection_stats_vector``);
-    0 for every other model. The counters ride the mixture's vector: a
-    model that chooses without a mixture has none yet."""
-    return 3 if getattr(cfg, "index_topk", None) else 0
+from ray_tpu.models.mixtral import stats_sections
 
 
 def ambient_mesh(mesh):
@@ -65,40 +42,28 @@ def ambient_mesh(mesh):
 def _moe_apply(model, mesh):
     """``model.apply`` for a step program, traced under the replica's
     mesh (``ambient_mesh``), a dense model's as a mixture's. For a
-    mixture-of-experts model the third result is (the int32 vector,) of
-    what the router chose over the program's live tokens
-    (models/mixtral.py moe_stats_vector; ``live()`` gives the [B, T]
-    mask), with ``selection_len``'s entries behind it; for a dense
-    model it is (). ``logits_at`` [B]: the one
+    model that counts on the device (models/mixtral.py
+    ``stats_sections``: a mixture's routing, a selection's entries)
+    the third result is (the int32 vector,) of its sections' counts
+    over the program's live tokens, each reduced from the collection
+    its layers sow into (``live()`` gives the [B, T] mask) and
+    concatenated in the sections' order; for any other model it is ().
+    ``logits_at`` [B]: the one
     position of each row the program wants logits for, ``[B, V]``
     (models/llama.py transformer_forward); None: every position's."""
-    E, held, _ = _moe_vector_of(model)
-    if not E:
-        def apply(params, ids, kv, start, live, logits_at=None):
-            with ambient_mesh(mesh):
-                logits, new_kv = model.apply(params, ids, kv_caches=kv,
-                                             cache_len=start,
-                                             logits_at=logits_at)
-            return logits, new_kv, ()
-        return apply
-    from ray_tpu.models.mixtral import MOE_STATS, moe_stats_vector
-    sown_by = [MOE_STATS]
-    if selection_len(model.config):
-        from ray_tpu.ops.sparse_latent_attention import (
-            SELECTION_STATS, selection_stats_vector)
-        sown_by.append(SELECTION_STATS)
+    sections = stats_sections(model.config)
+    sown_by = [s.collection for s in sections]
 
     def apply(params, ids, kv, start, live, logits_at=None):
         with ambient_mesh(mesh):
             (logits, new_kv), sown = model.apply(
                 params, ids, kv_caches=kv, cache_len=start,
                 logits_at=logits_at, mutable=sown_by)
+        if not sections:
+            return logits, new_kv, ()
         with jax.named_scope("moe_stats"):
-            vec = moe_stats_vector(sown[MOE_STATS], live(),
-                                   model.config.num_experts, held)
-            if len(sown_by) > 1:
-                vec = jnp.concatenate([vec, selection_stats_vector(
-                    sown[SELECTION_STATS], live())])
+            parts = [s.reduce(sown[s.collection], live()) for s in sections]
+            vec = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
         return logits, new_kv, (vec,)
     return apply
 
@@ -223,7 +188,7 @@ def _jit_verify(model, mesh):
 def _jit_decode(model, temp, KMAX, S, capture, mesh):
     constrain = _constrain_for(mesh)
     apply = _moe_apply(model, mesh)
-    moe_len = _moe_vector_of(model)[2]
+    moe_len = sum(len(s) for s in stats_sections(model.config))
     from ray_tpu.models.llama import _pick_token
 
     def decode(params, pages, page_table, pos, cur, rng, steps):

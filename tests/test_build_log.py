@@ -528,11 +528,13 @@ def test_the_setup_readers_report_nothing_on_a_program_without_the_log(
 
 @pytest.mark.parametrize("name", READERS)
 def test_the_setup_readers_are_appended_to_the_benchmark(name):
+    from benchmark_as_of import as_of, row
     from benchmarks import common
     bench = common.load_benchmark()
-    # (PR 53 appended three readers of its own cell behind them, PR 56
-    # seven)
-    assert tuple(m["name"] for m in bench["per_layer"][-14:-10]) == READERS
+    # where the table of what each PR appended says (later PRs' readers
+    # stand behind them)
+    assert row(51).readers == READERS
+    assert tuple(m["name"] for m in as_of(51)["per_layer"][-4:]) == READERS
     m = common.find_named(bench["per_layer"], name, "metric")
     cells = [w["name"] for w in bench["workloads"]]
     want = {"name": name, "unit": "s", "better": "lower",

@@ -452,3 +452,172 @@ def test_a_dense_familys_programs_never_reach_the_grouped_matmul(
     # no scope of the mixture's (a scope reads "/moe_experts" in a
     # location), no grouped matmul in its XLA form
     assert not any("/moe_" in t or "ragged_dot" in t for t in texts)
+
+
+# ------------- the round's accounts: one module, a counter's names in one
+
+_PACKAGE = SERVE.parent
+_KERNELS = ("latent_window_attention", "paged_decode_attention",
+            "ring_window_attention", "sparse_latent_attention")
+
+
+def _code_words(tree):
+    """(identifiers, strings) of a module outside its docstrings."""
+    docs = {id(node.body[0].value) for node in ast.walk(tree)
+            if isinstance(node, (ast.Module, ast.ClassDef, ast.FunctionDef))
+            and node.body and isinstance(node.body[0], ast.Expr)
+            and isinstance(node.body[0].value, ast.Constant)}
+    names, strings = set(), set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Name):
+            names.add(node.id)
+        elif isinstance(node, ast.Attribute):
+            names.add(node.attr)
+        elif isinstance(node, (ast.FunctionDef, ast.ClassDef)):
+            names.add(node.name)
+        elif isinstance(node, ast.alias):
+            names |= {node.name, node.asname or node.name}
+        elif isinstance(node, ast.ImportFrom):
+            names.add(node.module or "")
+        elif isinstance(node, ast.Constant) and isinstance(
+                node.value, str) and id(node) not in docs:
+            strings.add(node.value)
+    return names, strings
+
+
+@pytest.mark.parametrize("file,words", [
+    # the host loop imports no kernel's module and names no counter
+    ("engine.py", _KERNELS + ("_MOE_", "_SELECTION", "experts_touched")),
+    # the programs concatenate the sections they are given
+    ("step_programs.py", ("sparse_latent_attention", "index_topk",
+                          "selection_len", "_moe_vector_of", "MOE_STATS",
+                          "moe_stats_vector")),
+])
+def test_the_loop_and_the_programs_know_no_counter(file, words):
+    names, strings = _code_words(ast.parse((SERVE / file).read_text()))
+    hits = [(w, n) for w in words for n in names | strings if w in n]
+    assert not hits, hits
+
+
+def test_a_counters_name_is_written_in_one_module():
+    """Each name of a section (and so its place in the vector) stands
+    where the vector is laid out and nowhere else in the package."""
+    from ray_tpu.models.mixtral import MoEStats
+    from ray_tpu.ops.sparse_latent_attention import SelectionStats
+    want = {name: "mixtral.py" for name in MoEStats.names[1:]}
+    want.update({name: "sparse_latent_attention.py"
+                 for name in SelectionStats.names})
+    found = {name: set() for name in want}
+    for path in sorted(_PACKAGE.rglob("*.py")):
+        _names, strings = _code_words(ast.parse(path.read_text()))
+        for name in want:
+            if any(name in s for s in strings):
+                found[name].add(path.name)
+    assert found == {name: {where} for name, where in want.items()}
+
+
+def test_the_sections_are_asked_of_the_config():
+    """A mixture's by ``num_experts``, a selection's beside the code
+    that sows it, and a model that chooses WITHOUT a mixture is counted
+    by construction: its one section is the whole vector."""
+    from ray_tpu.models.deepseek_v32 import deepseek_v32_tiny
+    from ray_tpu.models.llama import llama_tiny
+    from ray_tpu.models.mixtral import MoEStats, olmoe_tiny, stats_sections
+    from ray_tpu.ops.sparse_latent_attention import SelectionStats
+    assert stats_sections(llama_tiny()) == ()
+    cfg = olmoe_tiny()
+    assert stats_sections(cfg) == (MoEStats(cfg.num_experts, None),)
+    held = olmoe_tiny(experts_held=(2, 4))
+    (share,) = stats_sections(held)
+    assert (share.head, len(share)) == (4, 4 + 4 + 1)
+    assert len(stats_sections(cfg)[0]) == cfg.num_experts + 4
+    cfg = deepseek_v32_tiny()
+    assert stats_sections(cfg) == (MoEStats(cfg.num_experts, None),
+                                   SelectionStats())
+    lone = types.SimpleNamespace(stats_sections=(SelectionStats(),))
+    assert stats_sections(lone) == (SelectionStats(),)
+    assert SelectionStats().read([5, 3, 4]) == {
+        "index_keys_scored": 5, "sparse_entries_chosen": 3,
+        "sparse_entries_read": 4}
+    import numpy as np
+    vec = np.asarray([1, 0, 2, 0, 2, 2, 1, 3], np.int32)
+    assert MoEStats(4).read(vec) == {
+        "pairs": 3, "experts_touched": 2, "load_max": 2, "layer_steps": 1,
+        "tile_visits": 3, "pairs_routed": 3}
+    assert MoEStats(8, (2, 4)).read(np.append(vec, 9)) == {
+        "pairs": 3, "experts_touched": 2, "load_max": 2, "layer_steps": 1,
+        "tile_visits": 3, "pairs_routed": 9}
+
+
+# Every key the benchmark's readers read by name, copied from the
+# parent's run (PR 57's tree, the same requests): a refactor of the
+# accounts cannot drop one unseen.
+_ROUND = {
+    "round", "overlap", "host_gap_s", "wall_s", "admit_s", "plan_s",
+    "dispatch_s", "readback_s", "cpu_s", "readback_cpu_s", "backlog",
+    "decode_riders", "decode_steps", "decode_window_tokens",
+    "decode_context_tokens", "decode_kernel_pages", "prefill_tokens",
+    "prefill_budget", "prefill_rows", "prefill_window_tokens",
+    "prefill_kernel_blocks", "prefill_width", "prefill_head_rows"}
+_ROUND_MOE = {
+    "moe_pairs", "moe_experts_touched", "moe_load_max", "moe_layer_steps",
+    "moe_tile_visits", "moe_pairs_routed", "moe_decode_pairs",
+    "moe_decode_experts_touched", "moe_decode_load_max",
+    "moe_decode_layer_steps", "moe_decode_tile_visits",
+    "moe_decode_pairs_routed"}
+_ROUND_SELECTION = {
+    "index_keys_scored", "sparse_entries_chosen", "sparse_entries_read",
+    "decode_index_keys_scored", "decode_sparse_entries_chosen",
+    "decode_sparse_entries_read"}
+_ROUND_SLIDING = {"decode_sliding_keys", "sliding_kernel_keys",
+                  "state_slots"}
+_LOAD = {
+    "cold_builds", "draining", "fetchq_depth", "free_pages", "free_slots",
+    "has_work", "heartbeat_age_s", "itl_ewma_s", "kv_bytes_in_use",
+    "kv_bytes_per_token", "kv_bytes_total", "kv_dtype", "kv_page_bytes",
+    "max_queued", "max_queued_batch", "outstanding_tokens", "overlap",
+    "pending_prefills", "prefix_digest", "programs_built", "queue_depth",
+    "queue_depth_batch", "queue_depth_online", "role",
+    "shed_retry_after_s", "shed_total", "sliding_bytes_per_slot",
+    "sliding_kernel_keys", "state_bytes_in_use", "state_bytes_total",
+    "stopped", "total_slots", "tp", "ttft_ewma_s", "weight_generation",
+    "weights_id"}
+_LOAD_MOE = {"moe_expert_share", "moe_pairs_total"}
+
+
+@pytest.mark.parametrize("module,tiny,round_keys,load_keys", [
+    ("llama", "llama_tiny", _ROUND, _LOAD),
+    ("mixtral", "olmoe_tiny", _ROUND | _ROUND_MOE, _LOAD | _LOAD_MOE),
+    ("mellum", "mellum_tiny", _ROUND | _ROUND_MOE | _ROUND_SLIDING,
+     _LOAD | _LOAD_MOE),
+    ("deepseek_v32", "deepseek_v32_tiny",
+     _ROUND | _ROUND_MOE | _ROUND_SELECTION, _LOAD | _LOAD_MOE),
+], ids=["llama", "olmoe", "mellum", "deepseek_v32"])
+def test_the_round_event_and_the_load_report_keep_their_keys(
+        module, tiny, round_keys, load_keys):
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.serve.engine import LLMEngine
+    cfg = getattr(importlib.import_module(f"ray_tpu.models.{module}"),
+                  tiny)(dtype=jnp.float32)
+    model = cfg.model_class(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
+    eng = LLMEngine(model, params, max_slots=2, page_size=8, n_pages=32,
+                    chunk=4, prefill_chunk=16, temperature=0.0, seed=0)
+    try:
+        req = eng.submit(list(range(1, 22)), max_new_tokens=6)
+        for _ in range(500):
+            if not eng.step():
+                break
+        assert len(req.result()) == 6
+        rounds = [e[5] for e in eng.events.snapshot() if e[2] == "round"]
+        assert set().union(*rounds) == round_keys
+        assert set(eng.load_report()) == load_keys
+        # the stats sum what the rounds report
+        for key in round_keys - _ROUND | {"decode_context_tokens"}:
+            assert eng.stats[key] == sum(r.get(key, 0) for r in rounds)
+    finally:
+        eng.shutdown()

@@ -542,7 +542,7 @@ def test_a_slots_sliding_bytes_are_the_same_at_any_context(tiny):
     seen = {}
     for n in (20, 180):
         eng = _engine(tiny)
-        assert eng.ring_len == L and eng.sliding_window == 12
+        assert eng.ring_len == L and eng.accounts.sliding_window == 12
         eng.submit(_ids((n,), seed=60).tolist(), max_new_tokens=12)
         reports = []
         while eng.step():

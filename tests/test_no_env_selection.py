@@ -28,6 +28,7 @@ def _env_reads(path):
 
 @pytest.mark.parametrize("where", ["models", "ops", "serve/engine.py",
                                    "serve/step_programs.py",
+                                   "serve/round_accounts.py",
                                    "serve/llm.py"])
 def test_serving_path_reads_no_environment(where):
     target = ROOT / where

@@ -392,7 +392,7 @@ def test_kernel_pages_are_counted_exactly_where_decode_holds_the_kernel(
             lowering_platforms=("tpu",)).as_text()
     holds = "tpu_custom_call" in text and "paged_decode" in text
     assert holds is one_tpu
-    assert eng._decode_kernel_serves() is holds
+    assert eng.accounts.decode_kernel_serves() is holds
     # the engine's own program is built (no retrace): only the counter
     # reads the rule again
     eng.submit(prompt, max_new_tokens=6)

@@ -615,11 +615,11 @@ def test_a_sharded_engine_counts_no_kernel_pages(monkeypatch,
             "decode_kernel_pages"]
         assert not any(e[5]["decode_kernel_pages"]
                        for e in eng.events.snapshot() if e[2] == "round")
-        assert not eng._decode_kernel_serves()
-        monkeypatch.setattr(eng, "_mesh", None)
-        assert eng._decode_kernel_serves()
-        monkeypatch.setattr(eng, "kv_dtype", "int8")
-        assert not eng._decode_kernel_serves()
+        assert not eng.accounts.decode_kernel_serves()
+        monkeypatch.setattr(eng.accounts, "mesh", None)
+        assert eng.accounts.decode_kernel_serves()
+        monkeypatch.setattr(eng.accounts, "kv_dtype", "int8")
+        assert not eng.accounts.decode_kernel_serves()
     finally:
         eng.shutdown()
 
@@ -682,9 +682,9 @@ def test_the_round_event_carries_decode_kernel_pages_of_latent_pages(
     shaped = engine(axk1_tiny(
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, n_layers=1,
         n_heads=16, kv_lora_rank=128))
-    assert not shaped._decode_kernel_serves()             # the CPU
+    assert not shaped.accounts.decode_kernel_serves()    # the CPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert shaped._decode_kernel_serves()
-    monkeypatch.setattr(shaped, "_mesh", Mesh(
+    assert shaped.accounts.decode_kernel_serves()
+    monkeypatch.setattr(shaped.accounts, "mesh", Mesh(
         np.asarray(cpu_mesh_devices[:2]), ("tensor",)))
-    assert not shaped._decode_kernel_serves()
+    assert not shaped.accounts.decode_kernel_serves()
