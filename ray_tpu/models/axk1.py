@@ -321,12 +321,13 @@ class MLAttention(nn.Module):
                     cache_len, softmax_scale=cfg.softmax_scale,
                     value_dim=R)
             else:
-                o_lat, chosen, read = sparse_attention(
+                o_lat, chosen, read, by_kernel = sparse_attention(
                     q_full, pages, pc.page_table, cache_len, scores,
                     cfg.index_topk, softmax_scale=cfg.softmax_scale,
                     value_dim=R)
                 self.sow(SELECTION_STATS, "counts",
-                         jnp.stack([positions + 1, chosen, read]),
+                         jnp.stack([positions + 1, chosen, read,
+                                    by_kernel]),
                          reduce_fn=lambda _prev, new: new,
                          init_fn=lambda: None)
             with jax.named_scope("mla_absorb"):

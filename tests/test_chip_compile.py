@@ -771,6 +771,53 @@ def test_chosen_entries_programs_keep_both_pools_in_place(one_chip,
         assert temp < 400 << 20, temp
 
 
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_the_choice_is_one_kernel_a_layer(one_chip, monkeypatch, name):
+    """Both step programs of the cell, with the selector's rule steered
+    as the chip answers it: ONE ``topk_select`` call a layer under
+    ``dsa_topk`` (a step's [32, 16384] scores, a call's [1024, 16384],
+    the mask in bfloat16 as the walk's kernel takes it), and nothing
+    under that scope loops: no ``while`` whose body counts, no
+    conditional over four widths."""
+    from ray_tpu.ops import grouped_matmul as gm
+    from ray_tpu.ops import latent_window_attention as lw
+    from ray_tpu.ops import sparse_latent_attention as sp
+    for mod, attr in ((gm, "_use_kernel"), (lw, "_on_one_tpu"),
+                      (sp, "_on_one_tpu")):
+        monkeypatch.setattr(mod, attr, lambda: True)
+    text = _indexed_step(name, one_chip).as_text()
+    chosen = re.findall(
+        r"custom-call\([^\n]*/dsa_topk/topk_select[^\n]*", text)
+    assert len(chosen) == 2, len(chosen)               # two layers
+    rows = 1024 if name == "prefill" else 32
+    for call in chosen:
+        assert f"f32[{rows},16384]" in call, call[:300]
+    assert re.search(r"bf16\[%d,16384\][^\n]*custom-call\([^\n]*"
+                     r"/dsa_topk/topk_select" % rows, text)
+    looped = re.findall(
+        r"(?:while|conditional)\([^\n]*/dsa_topk/[^\n]*", text)
+    assert not looped, looped[:2]
+    # the walk's kernel takes the mask as it comes
+    walks = re.findall(
+        r"custom-call\([^\n]*/dsa_attn/latent_window[^\n]*", text)
+    assert len(walks) == 2, len(walks)
+
+
+@pytest.mark.parametrize("rows,width,k", [
+    (32, 12288, 2048), (1024, 8192, 2048), (32, 16384, 2048),
+    (1024, 16384, 2048), (48, 1152, 24)],
+    ids=["step_walked", "call_walked", "step", "call", "off_the_cells"])
+def test_topk_select_compiles(one_chip, rows, width, k):
+    """The selection kernel alone for the described chip: the cell's
+    two shapes at the widths its walks end in and at the table's, and
+    one off them (tiles of 16 rows, chunks of 128 columns)."""
+    from ray_tpu.ops import sparse_latent_attention as sp
+    compiled = _compile(
+        lambda scores, ends: sp.topk_select(scores, k, ends), one_chip,
+        ((rows, width), jnp.float32), ((rows,), jnp.int32))
+    assert "topk_select" in compiled.as_text()
+
+
 # ---------------------------------------------------------------
 # A model of recurrent and latent layers ONLY at its cell's widths and
 # ITS slots (Kimi-Linear: 32 delta-rule heads of 128 over 128 SLOTS, a

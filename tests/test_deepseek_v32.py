@@ -368,28 +368,156 @@ def test_topk_mask_is_the_sort_ties_and_all():
     assert (by_sort == want).all()
 
 
+def _walk_scores(B, T, S, start, seed=1):
+    """Scores [B, T, S] of a call whose longest row starts at ``start``
+    (another at 3, the last without a request) as ``index_scores``
+    leaves them: ``-inf`` at every position a query cannot see."""
+    rng = np.random.default_rng(seed)
+    table = 1 + np.arange(B * (S // 8)).reshape(B, S // 8)
+    table[-1] = 0
+    pos = np.full((B,), 3)
+    pos[0] = start
+    seen = (np.arange(S)[None, None] <= (
+        pos[:, None, None] + np.arange(T)[None, :, None])
+        ) & (table[:, :1, None] != 0)
+    scores = np.where(seen, rng.standard_normal((B, T, S)), -np.inf)
+    return (jnp.asarray(scores, jnp.float32), jnp.asarray(table, jnp.int32),
+            jnp.asarray(pos, jnp.int32))
+
+
 def test_the_choice_is_made_over_the_walks_width():
     """A chunk's choice costs the WALK's width: a table of four blocks
     (4 x 64 pages of 8 = 2,048 positions), rows whose walk ends in the
     first, the second and the last quarter, choose what ``topk_mask``
     over the whole table chooses, and nothing past the walk."""
-    rng = np.random.default_rng(1)
-    B, T, S = 2, 16, 2048
-    table = jnp.asarray(1 + np.arange(B * 256).reshape(B, 256), jnp.int32)
+    B, T, S = 3, 16, 2048
     for start in (40, 600, 1990):
-        pos = jnp.asarray([start, 3], jnp.int32)
-        seen = np.arange(S)[None, None] <= (
-            np.asarray(pos)[:, None, None] + np.arange(T)[None, :, None])
-        # nothing past the block the longest row's last query lies in
-        scores = jnp.asarray(np.where(
-            seen, rng.standard_normal((B, T, S)), -np.inf), jnp.float32)
-        member, chosen = jax.jit(
+        scores, table, pos = _walk_scores(B, T, S, start)
+        member, chosen, by_kernel = jax.jit(
             sparse._chosen_of_the_walk, static_argnums=(3, 4))(
             scores, table, pos, 8, 24)
         want = np.asarray(sparse.topk_mask(scores, 24))
         assert (np.asarray(member) == want).all()
         assert (np.asarray(chosen) == want.sum(-1)).all()
-        assert member.shape == (B, T, S)
+        assert member.shape == (B, T, S) and not by_kernel
+
+
+def _rows(case):
+    """(scores [rows, S], k, ends or None) of a named case."""
+    rng = np.random.default_rng(7)
+    scores = rng.integers(-3, 4, size=(32, 640)).astype(np.float32)
+    k, ends = 24, None
+    if case == "many_equal":
+        scores[0] = rng.standard_normal(640)
+    elif case == "fewer_than_k":
+        scores[::2, 10:] = -np.inf               # 10 visible: all chosen
+        scores[1::2, 40:] = -np.inf
+    elif case == "sees_nothing":
+        scores[3:9] = -np.inf
+        scores[20:] = -np.inf
+    elif case == "inf_tails":
+        ends = rng.integers(0, 641, size=32)
+        ends[:2] = 0, 640
+        scores[np.arange(640)[None] >= ends[:, None]] = -np.inf
+        scores[5, :7] = -np.inf                  # and one inside the sight
+    elif case == "negatives_and_zeros":
+        scores = -np.abs(rng.standard_normal((32, 640))).astype(np.float32)
+        scores[:, ::3] = 0.0
+        scores[:, 1::7] = -0.0
+        scores[4] = 0.0
+    elif case == "k_1":
+        k = 1
+    elif case == "k_over_width":
+        k = 4096
+    elif case == "sixteen_rows_a_tile":
+        scores = scores[:16, :384]
+    elif case == "the_top_bit_alone":
+        # every key on one side of zero, so the first digit decides nothing
+        scores = np.abs(rng.standard_normal((32, 640))).astype(np.float32)
+        scores[1::2] *= -1
+    return scores, k, ends
+
+
+@pytest.mark.parametrize("case,digit_bits", [
+    ("many_equal", 1), ("fewer_than_k", 1), ("sees_nothing", 1),
+    ("inf_tails", 1), ("negatives_and_zeros", 1), ("k_1", 1),
+    ("k_over_width", 1), ("sixteen_rows_a_tile", 1),
+    ("the_top_bit_alone", 1), ("many_equal", 2), ("inf_tails", 4)])
+def test_the_kernels_choice_is_topk_masks_bit_for_bit(case, digit_bits):
+    """``topk_select`` in interpret mode against ``topk_mask``, the
+    definition it is held to, on rows of many equal scores (small
+    integers: the lowest positions among the k-th's equals), rows that
+    see fewer than ``k`` or nothing, tails of ``-inf`` the kernel is
+    told of, negative scores and zeros of both signs, ``k`` of 1 and
+    over the width, and at the digit widths the timer tried."""
+    from unittest import mock
+    scores, k, ends = _rows(case)
+    want = np.asarray(sparse.topk_mask(jnp.asarray(scores), k))
+    with mock.patch.object(sparse, "_SELECT_DIGIT_BITS", digit_bits):
+        member, chosen = sparse.topk_select(
+            jnp.asarray(scores), k,
+            None if ends is None else jnp.asarray(ends, jnp.int32),
+            dtype=jnp.float32, interpret=True)
+    assert member.dtype == jnp.float32
+    assert (np.asarray(member) == want.astype(np.float32)).all()
+    assert (np.asarray(chosen) == want.sum(-1)).all()
+    assert want.sum() > 0
+
+
+@pytest.fixture
+def select_in_interpret(monkeypatch):
+    """``topk_select`` in interpret mode wherever its rule, steered as
+    the chip would answer it, sends a choice; the shapes it was given."""
+    calls = []
+    kernel = sparse.topk_select
+    monkeypatch.setattr(sparse, "_on_one_tpu", lambda: True)
+    monkeypatch.setattr(
+        sparse, "topk_select", lambda *a, **kw: calls.append(
+            a[0].shape) or kernel(*a, interpret=True, **kw))
+    return calls
+
+
+@pytest.mark.parametrize("start", [40, 600, 1100, 1990])
+@pytest.mark.parametrize("B,T", [(16, 1), (2, 16)],
+                         ids=["decode_step", "chunk"])
+def test_the_kernel_chooses_what_the_walk_saw(select_in_interpret, B, T,
+                                              start):
+    """``_chosen_of_the_walk`` through the kernel, a decode step
+    ([B, 1, S]) and a chunk ([B, T, S]) whose walk ends in each quarter
+    of a table of four blocks: ``topk_mask``'s choice over the whole
+    table in the type asked for, and nothing past the walk."""
+    S = 2048
+    scores, table, pos = _walk_scores(B, T, S, start, seed=start)
+    member, chosen, by_kernel = sparse._chosen_of_the_walk(
+        scores, table, pos, 8, 24, jnp.bfloat16)
+    want = np.asarray(sparse.topk_mask(scores, 24))
+    assert by_kernel and select_in_interpret == [(B * T, S)]
+    assert member.dtype == jnp.bfloat16 and member.shape == (B, T, S)
+    assert (np.asarray(member, np.float32) == want).all()
+    assert (np.asarray(chosen) == want.sum(-1)).all()
+    assert not np.asarray(member)[..., start + T:].any()
+
+
+def test_the_kernels_rule():
+    """float32 scores in whole lane tiles, rows in whole tiles that fit
+    beside their keys and their mask (of numbers), one TPU: off the
+    chip nothing is the kernel's."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    assert not sparse.select_serves(32, 12288, f32, bf16)
+    from unittest import mock
+    with mock.patch.object(sparse, "_on_one_tpu", lambda: True):
+        assert sparse.select_serves(32, 12288, f32, bf16)
+        assert sparse.select_serves(1024, 16384, f32, bf16)
+        assert not sparse.select_serves(4, 1024, f32, bf16)
+        assert not sparse.select_serves(32, 1000, f32, bf16)
+        assert not sparse.select_serves(32, 1024, bf16, bf16)
+        assert not sparse.select_serves(32, 1024, f32, jnp.bool_)
+        assert not sparse.select_serves(32, 1 << 20, f32, bf16)
+    assert sparse._select_rows(1024, 16384, bf16) == 64
+    assert sparse._select_rows(1024, 16384, f32) == 64
+    assert sparse._select_rows(1024, 32768, f32) == 32
+    assert sparse._select_rows(32, 16384, bf16) == 32
+    assert sparse._select_rows(48, 1152, bf16) == 16
 
 
 # -------------------------------------- both pools, against the reference
@@ -468,7 +596,7 @@ def test_the_counters_against_hand_counts():
     chunk of 40 at offset 0 scores t + 1 keys, chooses min(t + 1, 24)
     and READS THE WHOLE WALK (one block of the loop: the table's 96
     positions); a decode step at position 70 scores 71, chooses 24 and
-    reads the walk's 96 too."""
+    reads the walk's 96 too; on the CPU the kernel chose for none."""
     cfg = deepseek_v32_tiny(dtype=jnp.float32)
     layer, params, x = _attention(cfg)
     _, _, counts = _paged(layer, params, x, cfg, (40, 30, 1))
@@ -476,10 +604,10 @@ def test_the_counters_against_hand_counts():
     t = np.arange(40)
     assert (first[0] == t + 1).all() and (first[1] == np.minimum(
         t + 1, 24)).all() and (first[2] == 96).all()
-    assert step[:, :, 0].tolist() == [[71, 71], [24, 24], [96, 96]]
+    assert step[:, :, 0].tolist() == [[71, 71], [24, 24], [96, 96], [0, 0]]
     live = jnp.asarray([[True], [False]])
     assert selection_stats_vector({"a": step, "b": step}, live).tolist() \
-        == [142, 48, 192]
+        == [142, 48, 192, 0]
 
 
 @pytest.mark.parametrize("T,tokens,read_first,read_last", [
@@ -698,21 +826,34 @@ def test_speculative_decoding_rolls_both_pools_back(tiny):
     assert eng.alloc.occupancy() == 0
 
 
-def test_the_round_event_carries_the_selections_counters(tiny):
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_the_round_event_carries_the_selections_counters(tiny, form,
+                                                         request):
     """One request of 70 + 10 through the engine: the ``round`` events
     and ``stats`` sum, over the three layers and the live tokens only,
     the keys scored (t + 1 a query), the entries chosen (min(t + 1,
-    24)) and the entries read; the decode dispatches' part stands
-    apart and reads its walk too."""
-    eng = _engine(tiny)
+    24)), the entries read and the queries whose choice the kernel
+    made (none on the CPU; every live one where the rule is steered
+    and the kernel interpreted: 16 slots, so that a decode step's rows
+    fill a tile); the decode dispatches' part stands apart and reads
+    its walk too."""
+    from ray_tpu.serve import step_programs
+    programs = (step_programs._jit_prefill, step_programs._jit_decode)
+    if form == "kernel":
+        calls = request.getfixturevalue("select_in_interpret")
+        for program in programs:
+            program.cache_clear()
+            request.addfinalizer(program.cache_clear)
+    eng = _engine(tiny, max_slots=16)
     h = eng.submit(_ids((70,), seed=1).tolist(), max_new_tokens=10)
     _drive(eng)
     assert len(h.result()) == 10
     rounds = [e[5] for e in eng.events.snapshot() if e[2] == "round"]
     total = {k: sum(r.get(k, 0) for r in rounds) for k in (
         "index_keys_scored", "sparse_entries_chosen",
-        "sparse_entries_read", "decode_index_keys_scored",
-        "decode_sparse_entries_chosen", "decode_sparse_entries_read")}
+        "sparse_entries_read", "selection_kernel_rows",
+        "decode_index_keys_scored", "decode_sparse_entries_chosen",
+        "decode_sparse_entries_read", "decode_selection_kernel_rows")}
     assert all(eng.stats[k] == v for k, v in total.items())
     # (the vector of a dispatch is read back behind its tokens, never
     # waited for: the last one's may still be on the device)
@@ -732,6 +873,15 @@ def test_the_round_event_carries_the_selections_counters(tiny):
     assert total["decode_sparse_entries_read"] == 3 * steps * walk
     assert 80 <= walk and walk % 8 == 0
     assert total["sparse_entries_read"] == 3 * (70 + steps) * walk
+    if form == "kernel":
+        # a prefill call's [1, 32] or [4, 32] queries, a step's [16, 1]
+        assert (16, 1024) in calls and set(calls) <= {
+            (16, 1024), (32, 1024), (128, 1024)}
+        assert total["decode_selection_kernel_rows"] == 3 * steps
+        assert total["selection_kernel_rows"] == 3 * (70 + steps)
+    else:
+        assert total["decode_selection_kernel_rows"] == 0
+        assert total["selection_kernel_rows"] == 0
     assert "index_keys_scored" not in _plain_round_keys()
 
 

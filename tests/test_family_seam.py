@@ -536,9 +536,9 @@ def test_the_sections_are_asked_of_the_config():
                                    SelectionStats())
     lone = types.SimpleNamespace(stats_sections=(SelectionStats(),))
     assert stats_sections(lone) == (SelectionStats(),)
-    assert SelectionStats().read([5, 3, 4]) == {
+    assert SelectionStats().read([5, 3, 4, 1]) == {
         "index_keys_scored": 5, "sparse_entries_chosen": 3,
-        "sparse_entries_read": 4}
+        "sparse_entries_read": 4, "selection_kernel_rows": 1}
     import numpy as np
     vec = np.asarray([1, 0, 2, 0, 2, 2, 1, 3], np.int32)
     assert MoEStats(4).read(vec) == {
@@ -568,7 +568,9 @@ _ROUND_MOE = {
 _ROUND_SELECTION = {
     "index_keys_scored", "sparse_entries_chosen", "sparse_entries_read",
     "decode_index_keys_scored", "decode_sparse_entries_chosen",
-    "decode_sparse_entries_read"}
+    "decode_sparse_entries_read",
+    # PR 59: the queries whose choice the kernel made
+    "selection_kernel_rows", "decode_selection_kernel_rows"}
 _ROUND_SLIDING = {"decode_sliding_keys", "sliding_kernel_keys",
                   "state_slots"}
 _LOAD = {
