@@ -41,7 +41,7 @@ structure that lets requests join/leave the decode batch per token):
   ops/paged_attention.py; nothing outside it interprets the int8
   payload.
 
-FIVE KINDS OF PER-REQUEST STATE. The pool is a list of per-layer
+SEVEN KINDS OF LAYER. The pool is a list of per-layer
 entries BY KIND (``layer_kinds``): a layer of softmax attention has
 K and V pages, as above; a layer of LATENT attention (models/axk1.py's
 MLA) has pages too, handed out by the same allocator through the same
@@ -75,7 +75,16 @@ context eight times the window costs it what the window does. Nothing
 clears a ring either: what a ring index holds is known from the row's
 last written position alone (ops/paged_attention.py
 ``ring_attention``), and an index this request has not written is
-never visible. A model
+never visible. Two kinds KEEP NOTHING (models/
+phi4flash.py): a BORROWED layer attends the pages of the nearest K/V
+layer before it (its OWNER: the same page table, the same page ids, the
+owner's pages as they stand after the owner's append in the same call)
+and a STATELESS layer reads no request state at all. Their entry of the
+pool is the empty tuple: ``page_layout`` is ``()``, ``init_kv_pool``
+makes nothing, a page costs and ships nothing for them, and since the
+allocator, the page table, the prefix cache and a speculative rewind
+deal in page ids they never learn of either: a page freed, handed out
+again or refused is the owner's, and its readers follow. A model
 whose layers are all of one kind declares nothing and gets the pool
 it always had.
 """
@@ -109,6 +118,8 @@ KIND_RECURRENT = "recurrent"    # a layer with a fixed-size state a slot
 KIND_LATENT = "latent"          # a layer with one pool of latent pages
 KIND_SLIDING = "sliding"        # a layer with a ring of its window a slot
 KIND_INDEXED = "indexed"        # latent pages AND pages of index keys
+KIND_BORROWED = "borrowed"      # no entry: reads a K/V layer's pages
+KIND_STATELESS = "stateless"    # no entry, and reads none
 
 
 def layer_kinds(cfg) -> Tuple[str, ...]:
@@ -195,6 +206,21 @@ KIND_REFUSALS = {
         "sharding": "no partition rules exist for the rings or the "
                     "layer that keeps them",
     }),
+    KIND_BORROWED: ("no pages of their own and read an earlier layer's "
+                    "K/V pages", {
+        "kv_dtype": "a layer that reads another layer's pages attends "
+                    "them in the model's type, and nothing dequantises "
+                    "an int8 page for a reader that is not its owner",
+        "kv_migration": "a KV pull's frames are told apart by counting "
+                        "the layers that have pages, and the readers of "
+                        "a pulled page have not been held to it",
+        "sharding": "no partition rules exist for a layer that reads "
+                    "pages sharded for another layer's heads",
+    }),
+    KIND_STATELESS: ("no request state at all", {
+        "sharding": "no partition rules exist for the layer or for the "
+                    "activation it takes from an earlier one",
+    }),
 }
 
 
@@ -236,6 +262,8 @@ def sliding_ring_len(cfg, page_size: int, prefill_chunk: int) -> int:
 
 # The minor axis of a TPU array is stored in tiles of 128 lanes.
 _LANES = 128
+# the kinds of layer that have no page
+_NO_PAGES = (KIND_RECURRENT, KIND_SLIDING, KIND_BORROWED, KIND_STATELESS)
 
 
 def latent_page_width(cfg) -> int:
@@ -264,8 +292,9 @@ def page_layout(cfg, kind: str, page_size: int, kv_dtype: str = "fp"):
              token's index key (models/deepseek_v32.py), in a pool of
              its own under the SAME page ids
     recurrent, sliding: none (they belong to a slot, not to a page)
+    borrowed, stateless: none (they keep nothing)
     """
-    if kind in (KIND_RECURRENT, KIND_SLIDING):
+    if kind in _NO_PAGES:
         return ()
     quantized = check_kv_dtype(kv_dtype) == "int8"
     if kind in (KIND_LATENT, KIND_INDEXED):
@@ -400,16 +429,22 @@ def kv_layer_view(layer, page_table: jnp.ndarray, slots=None,
     ``(pk, pv, sk, sv)`` int8, ``(pages,)`` latent, ``(pages,
     index_pages)`` indexed (told from K and V by a latent page having
     no head axis), a
-    ``RecurrentState`` or a ``SlidingRing`` — as what its
+    ``RecurrentState`` or a ``SlidingRing``, or the empty tuple of a
+    layer that keeps nothing — as what its
     layer consumes: a PagedKVLayer over ``page_table``, or a
     RecurrentStateView or SlidingRingView of the rows' ``slots`` and
     real positions (``valid``: a function giving the [B, T] mask,
-    which only a layer that keeps its entry by slot calls).
+    which only a layer that keeps its entry by slot calls), or the
+    empty tuple as it is (a borrowed layer is handed its OWNER's
+    PagedKVLayer by the model's own layer loop, after the owner's
+    append: models/llama.py ``transformer_forward``'s ``published``).
     Keeps the jitted engine builders kind- and dtype-agnostic: they
     thread opaque entries and only this view/store pair knows what
     they are."""
     if isinstance(layer, RecurrentState):
         return RecurrentStateView(layer.state, layer.conv, slots, valid())
+    if not layer:
+        return ()
     if isinstance(layer, SlidingRing):
         return SlidingRingView(layer.k, layer.v, slots, valid())
     if len(layer) == 1:
@@ -443,6 +478,8 @@ def kv_layer_store(cache: PagedKVLayer):
     between jitted steps."""
     if isinstance(cache, RecurrentStateView):
         return RecurrentState(cache.state, cache.conv)
+    if not cache:
+        return ()
     if isinstance(cache, SlidingRingView):
         return SlidingRing(cache.k, cache.v)
     if cache.pages_index is not None:
@@ -477,6 +514,7 @@ def init_kv_pool(cfg, n_pages: int, page_size: int,
     sliding: SlidingRing of ``n_slots`` rings of ``ring_len``
           (``sliding_ring_len``) positions, zeros: the same bytes
           whatever ``n_pages``.
+    borrowed, stateless: () (nothing is made).
     """
     def entry(kind):
         if kind == KIND_SLIDING:
@@ -542,9 +580,10 @@ def state_bytes_per_slot(cfg, ring_len: int = 0) -> int:
 
 
 def export_page_bytes(layers, page: int) -> List[List[bytes]]:
-    """Raw bytes of ONE physical page across every layer (all of them
-    paged: the engine exports nothing for a model with recurrent
-    state) — the unit a cross-replica KV pull ships. Each entry is the layer's tensor
+    """Raw bytes of ONE physical page across every layer that has
+    pages (the engine exports nothing for a model with recurrent
+    state; a layer that keeps nothing, the empty tuple, ships nothing:
+    a borrowed layer's keys travel as its owner's) — the unit a cross-replica KV pull ships. Each entry is the layer's tensor
     tuple serialized in storage order: ``[k, v]`` for fp pools,
     ``[k, v, sk, sv]`` for int8 (the per-page scales TRAVEL WITH the
     payload — a page without its scale is garbage). ``t[page]`` is
@@ -552,7 +591,7 @@ def export_page_bytes(layers, page: int) -> List[List[bytes]]:
     and scale blobs ``[KH]``; blocks until any in-flight device
     computation producing ``layers`` has settled."""
     return [[np.asarray(t[page]).tobytes() for t in layer]
-            for layer in layers]
+            for layer in layers if len(layer)]
 
 
 def page_cols_from_bytes(cfg, page_size: int, kv_dtype: str,

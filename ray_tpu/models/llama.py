@@ -280,7 +280,7 @@ class LlamaBlock(nn.Module):
 
 def transformer_forward(mod: nn.Module, cfg, block_of, input_ids,
                         kv_caches=None, cache_len=None, rope=True,
-                        logits_at=None):
+                        logits_at=None, norm=None, publishes=False):
     """Shared decoder-transformer body (embedding, RoPE table,
     position/cache plumbing, layer loop, final norm, logits through
     the embedding or, with ``tie_word_embeddings`` false, through a
@@ -295,7 +295,14 @@ def transformer_forward(mod: nn.Module, cfg, block_of, input_ids,
     the final norm and the head then see ``[B, dim]`` and the logits are
     ``[B, V]`` (the chunked-prefill program samples one position a row;
     the norm and the head are each a position's own, so the gather may
-    come first). None: every position, ``[B, T, V]``. Called from a
+    come first). None: every position, ``[B, T, V]``. ``norm``: the
+    class of the final norm where it is not ``RMSNorm`` (built as
+    ``norm(cfg.norm_eps, name="norm")``). ``publishes``: the blocks
+    take and return, after their five arguments and two results, a
+    dict of what earlier blocks of THIS call published for later ones
+    (models/phi4flash.py: an activation, a layer's pages after its
+    append), empty before layer 0 and dropped after the last: nothing
+    of it is cached. Called from a
     compact __call__: submodules bind into the caller's scope."""
     B, T = input_ids.shape
     tok = mod.param("tok_embeddings",
@@ -313,17 +320,22 @@ def transformer_forward(mod: nn.Module, cfg, block_of, input_ids,
     else:
         positions = cache_len + jnp.arange(T)
     new_caches = []
+    published = {}
     for i in range(cfg.n_layers):
         block = block_of(i)
         if cfg.remat:
             block = nn.remat(block, static_argnums=())
         cache_i = None if kv_caches is None else kv_caches[i]
-        x, nc = block(cfg, name=f"layers_{i}")(
-            x, freqs, positions, cache_i, cache_len)
+        if publishes:
+            x, nc, published = block(cfg, name=f"layers_{i}")(
+                x, freqs, positions, cache_i, cache_len, published)
+        else:
+            x, nc = block(cfg, name=f"layers_{i}")(
+                x, freqs, positions, cache_i, cache_len)
         new_caches.append(nc)
     if logits_at is not None:
         x = x[jnp.arange(B), logits_at]                # [B, dim]
-    x = RMSNorm(cfg.norm_eps, name="norm")(x)
+    x = (norm or RMSNorm)(cfg.norm_eps, name="norm")(x)
     head = tok
     if not cfg.tie_word_embeddings:
         head = mod.param("lm_head", nn.initializers.normal(0.02),
@@ -339,16 +351,18 @@ def transformer_forward(mod: nn.Module, cfg, block_of, input_ids,
 
 
 def block_forward(cfg, attention, ffn_module, x, freqs, positions,
-                  kv_cache=None, cache_len=None):
+                  kv_cache=None, cache_len=None, norm=None):
     """Shared pre-norm block body: attention residual + FFN residual.
     ``attention`` (a module named "attention": LlamaAttention, or a
     layer that keeps a recurrent state instead of K/V) and the FFN
-    module are what varies across families and kinds of layer."""
+    module are what varies across families and kinds of layer, and
+    ``norm`` the two norms' class where it is not ``RMSNorm``."""
+    norm = norm or RMSNorm
     h, new_cache = attention(
-        RMSNorm(cfg.norm_eps, name="attention_norm")(x),
+        norm(cfg.norm_eps, name="attention_norm")(x),
         freqs, positions, kv_cache, cache_len)
     x = x + h
-    x = x + ffn_module(RMSNorm(cfg.norm_eps, name="ffn_norm")(x))
+    x = x + ffn_module(norm(cfg.norm_eps, name="ffn_norm")(x))
     return x, new_cache
 
 

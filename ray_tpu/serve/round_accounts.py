@@ -21,8 +21,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models.kv_cache import (KIND_KV, KIND_LATENT, KIND_SLIDING,
-                                     SlidingRing, has_latent_pages,
+from ray_tpu.models.kv_cache import (KIND_BORROWED, KIND_KV, KIND_LATENT,
+                                     KIND_SLIDING, SlidingRing,
+                                     has_latent_pages,
                                      kv_query_heads, latent_page_width,
                                      layer_kinds, page_layout,
                                      state_bytes_per_slot)
@@ -94,6 +95,12 @@ class RoundAccounts:
         self.ring_len = self.ring.shape[2] if self.ring else 0
         self.sliding_window = cfg.sliding_window if self.ring else 0
         self.state_by_slot = bool(state_bytes_per_slot(cfg, self.ring_len))
+        # the layers that READ K/V pages where some keep none of their
+        # own (models/kv_cache.py KIND_BORROWED): the owners and their
+        # readers; 0 for a model whose every reader is its own owner
+        kinds = layer_kinds(cfg)
+        self.page_readers = (kinds.count(KIND_KV) + kinds.count(
+            KIND_BORROWED) if KIND_BORROWED in kinds else 0)
         # what the step programs count on the device, over live rows
         # only (() = nothing is returned, queued or reported), and each
         # section's running totals of its head, for ``load_report``
@@ -209,7 +216,10 @@ class RoundAccounts:
         spec-verify forward over rows that end there. Of a model with
         sliding-window layers also ``decode_sliding_keys``: the riders'
         contexts each cut at the window, the keys ONE sliding layer's
-        last step has to score."""
+        last step has to score. Of a model whose pages have readers
+        beside their owner also ``decode_shared_kv_reads``: the riders'
+        context entries times the layers that READ pages, the entries
+        the last step's attention has to fetch over all of them."""
         ends = [int(e) for e in ends]
         if not verify:
             self.note_state_slots(len(ends))
@@ -217,6 +227,8 @@ class RoundAccounts:
         self.info["decode_steps"] = steps
         self.note_window("decode_window_tokens", max(ends))
         self.add(decode_context_tokens=sum(ends))
+        if self.page_readers:
+            self.add(decode_shared_kv_reads=sum(ends) * self.page_readers)
         if self.sliding_window:
             self.add(
                 decode_sliding_keys=sum(min(e, self.sliding_window)

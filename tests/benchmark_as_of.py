@@ -45,6 +45,10 @@ APPENDED = (
         "decode_index_ms", "decode_sparse_attn_ms", "index_roofline",
         "sparse_attn_roofline", "prefill_sparse_attn_share",
         "sparse_read_ratio", "sparse_step_roofline")),
+    Appended(60, "phi-4-mini-flash-reasoning", "phi4-mini-flash.reason-sat", (
+        "decode_ssm_ms", "prefill_ssm_share", "ssm_scan_roofline",
+        "ssm_prefill_scan_roofline", "decode_shared_attn_ms",
+        "shared_attn_roofline", "shared_kv_read_ratio")),
 )
 
 

@@ -1516,3 +1516,115 @@ def test_a_dense_hybrid_keeps_its_state_and_pages_as_the_chip_tiles_them(
     else:
         assert not calls and not steps
         assert mem.temp_size_in_bytes < 4 * one_state, mem.temp_size_in_bytes
+
+
+# ---- a model whose pages have readers beside their owner, and layers
+# that keep nothing (models/phi4flash.py): at the published widths and
+# phi4-mini-flash.reason-sat's pool, eight layers that keep every kind
+# (state-space 0, 2, 4; sliding 1, 3; full 5; memory unit 6; cross 7).
+# Ten K/V pairs of 128 are stored as 16 head rows a page: declared as
+# 10 (or 12) the compiler keeps the pool positions-minor and copies it
+# around every program (2.2-2.4 GB of temporaries, PERF.md section 6,
+# PR 60).
+
+PHI_SLOTS, PHI_PAGES = 64, 3073
+
+
+def _shared_pages_step(name, one_chip):
+    from ray_tpu.models.kv_cache import init_kv_pool, sliding_ring_len
+    from ray_tpu.models.phi4flash import Phi4Flash, phi4_mini_flash
+    from ray_tpu.serve import step_programs
+    S = PHI_SLOTS
+    cfg = phi4_mini_flash(n_layers=8, max_seq_len=3072,
+                          param_dtype=jnp.bfloat16)
+    model = Phi4Flash(cfg)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                           sharding=one_chip), tree)
+    params = placed({"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]})
+    pages = placed(jax.eval_shape(lambda: init_kv_pool(
+        cfg, PHI_PAGES, PAGE, n_slots=S,
+        ring_len=sliding_ring_len(cfg, PAGE, 256))))
+    i32 = jnp.int32
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    table = ((S, cfg.max_seq_len // PAGE), i32)
+    if name == "decode":
+        fn = step_programs._jit_decode(model, 0.0, 128, S, False, None)
+        rest = [table, ((S,), i32), ((S,), i32),
+                (key.shape, key.dtype), ((), i32)]
+    else:
+        fn = step_programs._jit_prefill(model, 0.0, 4, False, None)
+        rest = [((4, 256), i32), ((4,), i32), ((4,), i32),
+                ((4, table[0][1]), i32), (key.shape, key.dtype),
+                ((4,), i32)]
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in rest]
+    return cfg, fn.lower(params, pages, *rest).compile()
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_shared_pages_rings_and_states_stay_in_place(one_chip, monkeypatch,
+                                                     name):
+    from ray_tpu.models.kv_cache import (kv_pool_page_bytes,
+                                         sliding_ring_len,
+                                         state_bytes_per_slot)
+    from ray_tpu.ops import paged_decode_attention as pd
+    from ray_tpu.ops import ring_window_attention as rw
+    from ray_tpu.serve import step_programs
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
+    monkeypatch.setattr(rw, "_on_one_tpu", lambda: True)
+    for builder in ("_jit_decode", "_jit_prefill"):
+        monkeypatch.setattr(step_programs, builder,
+                            getattr(step_programs, builder).__wrapped__)
+    cfg, compiled = _shared_pages_step(name, one_chip)
+    text = compiled.as_text()
+    ring = sliding_ring_len(cfg, PAGE, 256)
+    assert (cfg.n_kv_heads, cfg.kv_page_heads, ring) == (10, 16, 832)
+    # every kind as declared, none copied whole: the one layer's pages
+    # (16 head rows), the rings (10 heads, major), the float32 states
+    shapes = {"pool": ("bf16", (PHI_PAGES, PAGE, 16, 128)),
+              "ring": ("bf16", (PHI_SLOTS, 10, ring, 128)),
+              "state": ("f32", (PHI_SLOTS, 16, 5120))}
+    for what, (dtype, shape) in shapes.items():
+        pat = r"%s\[%s\]" % (dtype, ",".join(str(d) for d in shape))
+        entry = re.search(pat + r"(\{[^}]*\}) parameter", text)
+        order = ",".join(str(d) for d in reversed(range(len(shape))))
+        assert entry and entry.group(1).startswith("{" + order), (what,
+                                                                  entry)
+        copies = re.findall(
+            r"= " + pat + r"(?:\{[^}]*\})? (?:copy|transpose)\(", text)
+        assert not copies, f"{len(copies)} whole-{what} copies in {name}"
+    assert not _pool_copies(text, shapes["pool"][1])
+    # what the chip keeps is what load_report() counts: ONE layer's
+    # pages, and a slot's rings and states; a borrowed layer and a
+    # stateless one add nothing
+    mem = compiled.memory_analysis()
+    kept = (PHI_PAGES * kv_pool_page_bytes(cfg, PAGE)
+            + PHI_SLOTS * state_bytes_per_slot(cfg, ring))
+    assert kept == (PHI_PAGES * PAGE * 2 * 16 * 128 * 2
+                    + PHI_SLOTS * (2 * 2 * 10 * ring * 128 * 2
+                                   + 3 * (16 * 5120 * 4 + 3 * 5120 * 2)))
+    assert mem.alias_size_in_bytes == pytest.approx(kept, rel=1e-3)
+    paged = [c for c in re.findall(
+        r"custom-call\([^\n]*attn_shared/attn_scores/[^\n]*paged_decode"
+        r"[^\n]*", text) if 'custom_call_target="tpu_custom_call"' in c]
+    rings = [c for c in re.findall(
+        r"custom-call\([^\n]*attn_sliding/[^\n]*ring_window[^\n]*", text)
+        if 'custom_call_target="tpu_custom_call"' in c]
+    assert len(rings) == 2, len(rings)          # both sliding layers
+    one_state = PHI_SLOTS * 16 * 5120 * 4       # a layer's, 21 MB
+    if name == "decode":
+        # the owner and its ONE reader here, each one kernel over the
+        # same pages; no block loop
+        assert len(paged) == 2, len(paged)
+        assert "kv_gather" not in text
+        assert mem.temp_size_in_bytes < 4 * one_state, \
+            mem.temp_size_in_bytes
+    else:
+        assert not paged
+        assert mem.temp_size_in_bytes < 16 * one_state, \
+            mem.temp_size_in_bytes

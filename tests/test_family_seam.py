@@ -12,15 +12,16 @@ import types
 import pytest
 
 from ray_tpu.models import kv_cache
-from ray_tpu.models.kv_cache import (KIND_INDEXED, KIND_KV, KIND_LATENT,
-                                     KIND_RECURRENT,
-                                     KIND_SLIDING, refuse_unsupported)
+from ray_tpu.models.kv_cache import (KIND_BORROWED, KIND_INDEXED, KIND_KV,
+                                     KIND_LATENT, KIND_RECURRENT,
+                                     KIND_SLIDING, KIND_STATELESS,
+                                     refuse_unsupported)
 
 SERVE = pathlib.Path(__file__).resolve().parent.parent / "ray_tpu" / "serve"
 # the family modules serve/ may import: sampling and the tiny default
 # (llama), the mixture's counters every mixture family shares (mixtral)
 _FAMILY_MODULES = {"axk1", "kimi_linear", "laguna", "mellum", "olmo_hybrid",
-                   "ouro", "solar_open2"}
+                   "ouro", "phi4flash", "solar_open2"}
 
 
 def _trees():
@@ -79,6 +80,7 @@ def _families():
     from ray_tpu.models.mixtral import Mixtral, mixtral_tiny
     from ray_tpu.models.olmo_hybrid import OlmoHybrid, olmo_hybrid_tiny
     from ray_tpu.models.ouro import Ouro, ouro_tiny
+    from ray_tpu.models.phi4flash import Phi4Flash, phi4flash_tiny
     from ray_tpu.models.solar_open2 import SolarOpen2, solar_open2_tiny
     return {"llama": (llama_tiny, Llama, "feed_forward"),
             "mixtral": (mixtral_tiny, Mixtral, "moe/w2"),
@@ -88,11 +90,12 @@ def _families():
             "mellum": (mellum_tiny, Mellum, None),
             "olmo_hybrid": (olmo_hybrid_tiny, OlmoHybrid, None),
             "ouro": (ouro_tiny, Ouro, None),
+            "phi4flash": (phi4flash_tiny, Phi4Flash, None),
             "solar_open2": (solar_open2_tiny, SolarOpen2, None)}
 
 
 FAMILIES = ("llama", "mixtral", "axk1", "kimi_linear", "laguna", "mellum",
-            "olmo_hybrid", "ouro", "solar_open2")
+            "olmo_hybrid", "ouro", "phi4flash", "solar_open2")
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -156,14 +159,22 @@ def _config_of(kind):
     from ray_tpu.models.deepseek_v32 import deepseek_v32_tiny
     from ray_tpu.models.mellum import mellum_tiny
     from ray_tpu.models.solar_open2 import solar_open2_tiny
+
+    def pages_and(kind):
+        # no model is made of these alone: pages, and a layer of the kind
+        return lambda: types.SimpleNamespace(
+            layer_kinds=(KIND_KV, kind), n_layers=2)
     return {KIND_RECURRENT: solar_open2_tiny, KIND_LATENT: axk1_tiny,
             KIND_INDEXED: deepseek_v32_tiny,
-            KIND_SLIDING: mellum_tiny}[kind]()
+            KIND_SLIDING: mellum_tiny,
+            KIND_BORROWED: pages_and(KIND_BORROWED),
+            KIND_STATELESS: pages_and(KIND_STATELESS)}[kind]()
 
 
 @pytest.mark.parametrize("kind,option", [
     (kind, option) for kind in (KIND_RECURRENT, KIND_LATENT, KIND_INDEXED,
-                                KIND_SLIDING)
+                                KIND_SLIDING, KIND_BORROWED,
+                                KIND_STATELESS)
     for option in kv_cache.KIND_REFUSALS[kind][1]])
 def test_the_tables_words_reach_the_refusal(kind, option):
     keeps, why = kv_cache.KIND_REFUSALS[kind]
@@ -176,14 +187,14 @@ def test_the_tables_words_reach_the_refusal(kind, option):
     refuse_unsupported(cfg, **{option: False})
 
 
-def test_the_table_is_fifteen_refusals_over_five_kinds():
+def test_the_table_is_nineteen_refusals_over_seven_kinds():
     kinds = {getattr(kv_cache, name) for name in dir(kv_cache)
              if name.startswith("KIND_") and name != "KIND_REFUSALS"}
     assert kinds == set(kv_cache.KIND_REFUSALS)
     assert {kind: len(why) for kind, (_keeps, why)
             in kv_cache.KIND_REFUSALS.items()} == {
         KIND_KV: 0, KIND_RECURRENT: 4, KIND_LATENT: 3, KIND_INDEXED: 3,
-        KIND_SLIDING: 5}
+        KIND_SLIDING: 5, KIND_BORROWED: 3, KIND_STATELESS: 1}
 
 
 def test_pages_of_keys_and_values_are_refused_nothing():
@@ -402,7 +413,8 @@ def test_the_prefill_program_samples_what_the_full_logits_would(
 
 # -------------- a dense family's programs hold nothing of the mixture's
 
-@pytest.mark.parametrize("family", ["llama", "olmo_hybrid", "ouro"])
+@pytest.mark.parametrize("family", ["llama", "olmo_hybrid", "ouro",
+                                    "phi4flash"])
 def test_a_dense_familys_programs_never_reach_the_grouped_matmul(
         family, monkeypatch):
     """The decode (plain and capturing), verify, prefill and cache-less
@@ -573,6 +585,9 @@ _ROUND_SELECTION = {
     "selection_kernel_rows", "decode_selection_kernel_rows"}
 _ROUND_SLIDING = {"decode_sliding_keys", "sliding_kernel_keys",
                   "state_slots"}
+# PR 60: the riders' context entries x the layers that READ pages, of a
+# model whose pages have readers beside their owner
+_ROUND_SHARED = {"decode_shared_kv_reads"}
 _LOAD = {
     "cold_builds", "draining", "fetchq_depth", "free_pages", "free_slots",
     "has_work", "heartbeat_age_s", "itl_ewma_s", "kv_bytes_in_use",
@@ -594,7 +609,9 @@ _LOAD_MOE = {"moe_expert_share", "moe_pairs_total"}
      _LOAD | _LOAD_MOE),
     ("deepseek_v32", "deepseek_v32_tiny",
      _ROUND | _ROUND_MOE | _ROUND_SELECTION, _LOAD | _LOAD_MOE),
-], ids=["llama", "olmoe", "mellum", "deepseek_v32"])
+    ("phi4flash", "phi4flash_tiny",
+     _ROUND | _ROUND_SLIDING | _ROUND_SHARED, _LOAD),
+], ids=["llama", "olmoe", "mellum", "deepseek_v32", "phi4flash"])
 def test_the_round_event_and_the_load_report_keep_their_keys(
         module, tiny, round_keys, load_keys):
     import importlib
