@@ -84,7 +84,13 @@ pool is the empty tuple: ``page_layout`` is ``()``, ``init_kv_pool``
 makes nothing, a page costs and ships nothing for them, and since the
 allocator, the page table, the prefix cache and a speculative rewind
 deal in page ids they never learn of either: a page freed, handed out
-again or refused is the owner's, and its readers follow. A model
+again or refused is the owner's, and its readers follow. THE CONTRACT
+of both kinds: given what it reads (its input at a position, the pages
+it borrows, what an earlier layer of the same call published) a layer
+that keeps no entry is POSITION-WISE, and a call that asks the model
+for ``logits_at`` runs the model's trailing layers of these kinds at
+the sampled position of each row alone (``sampled_only_from``, at the
+file's end, says why that is sound). A model
 whose layers are all of one kind declares nothing and gets the pool
 it always had.
 """
@@ -764,3 +770,34 @@ def kv_query_heads(cfg, kind: str) -> int:
     ``kv_entries_per_layer``'s reason.)"""
     by_kind = getattr(cfg, "query_heads_by_kind", None) or {}
     return int(by_kind.get(kind, cfg.n_heads))
+
+
+def sampled_only_from(cfg) -> int:
+    """The index of the first layer of the model's TRAILING run of
+    layers that keep no entry (``KIND_BORROWED``, ``KIND_STATELESS``):
+    from there on a call that samples one position a row (models/
+    llama.py ``transformer_forward``'s ``logits_at``) computes that
+    position alone. ``n_layers`` where the last layer keeps an entry:
+    every family but Phi-4-mini-flash (18 of 32: the cross-decoder, the
+    paper's linear-time prefill).
+
+    Why that is sound for ANY such layer, whatever the model. A layer
+    that keeps no entry must give the same result in a decode step as
+    in a prefill call. In a decode step (``T = 1``) it can see another
+    position only through pages it borrows, and its own position only
+    through its input or through what an earlier layer of the same call
+    published there: so, given what it reads, it is position-wise, and
+    its result at a position is the same whether or not the call
+    computes it at the call's other positions. And since it writes
+    nothing, no later call can tell whether it ran at a position at
+    all. A layer that keeps an entry is another matter (its entry at
+    EVERY position is read later), which is why only the trailing run
+    counts: after its first layer nothing of the call's other positions
+    is ever read again but through an owner's pages, which the owner
+    has appended in full by then. (Defined at the file's end for
+    ``kv_entries_per_layer``'s reason.)"""
+    kinds = layer_kinds(cfg)
+    first = len(kinds)
+    while first and kinds[first - 1] in (KIND_BORROWED, KIND_STATELESS):
+        first -= 1
+    return first

@@ -22,7 +22,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.mesh.sharding import ShardingRules
-from ray_tpu.models.kv_cache import PagedKVLayer
+from ray_tpu.models.kv_cache import PagedKVLayer, sampled_only_from
 from ray_tpu.ops.paged_attention import (_paged_window_attention,
                                          paged_append)
 
@@ -295,14 +295,32 @@ def transformer_forward(mod: nn.Module, cfg, block_of, input_ids,
     the final norm and the head then see ``[B, dim]`` and the logits are
     ``[B, V]`` (the chunked-prefill program samples one position a row;
     the norm and the head are each a position's own, so the gather may
-    come first). None: every position, ``[B, T, V]``. ``norm``: the
+    come first). Over a cache the gather comes earlier still where the
+    model's LAST layers keep no entry (models/kv_cache.py
+    ``sampled_only_from``, read from the layers' kinds: ``n_layers``, so
+    right here before the norm, for every family whose last layer keeps
+    one): before the first of them the call is narrowed to the sampled
+    position a row, ``x`` ``[B, 1, dim]`` at ``positions`` and
+    ``cache_len`` of that position (``cache_len + logits_at``: the one
+    query's absolute position, as a decode step's), and those layers run
+    what a decode step runs; every layer before them has seen, and kept,
+    every position. (Without a cache nothing is narrowed: a layer that
+    borrows then reads the whole sequence's keys under a mask of its OWN
+    positions, not pages.) None: every position, ``[B, T, V]``. ``norm``: the
     class of the final norm where it is not ``RMSNorm`` (built as
-    ``norm(cfg.norm_eps, name="norm")``). ``publishes``: the blocks
-    take and return, after their five arguments and two results, a
-    dict of what earlier blocks of THIS call published for later ones
-    (models/phi4flash.py: an activation, a layer's pages after its
-    append), empty before layer 0 and dropped after the last: nothing
-    of it is cached. Called from a
+    ``norm(cfg.norm_eps, name="norm")``). ``publishes``: falsy, or a
+    dict; then the blocks take and return, after their five arguments
+    and two results, a dict of what earlier blocks of THIS call
+    published for later ones (models/phi4flash.py: an activation, a
+    layer's pages after its append), empty before layer 0 and dropped
+    after the last: nothing of it is cached. ``publishes`` itself names
+    every key the blocks may publish and says of each whether its value
+    is laid out BY POSITION (``[B, T, ...]``: true) or stands for the
+    whole call (false): the publishing family's to say, since a shape
+    cannot (a cache-less call's keys of the whole sequence are
+    ``[B, T, ...]`` and every later position reads all of them). Where
+    the call is narrowed a value laid out by position is narrowed with
+    ``x``, every other is handed on as it is. Called from a
     compact __call__: submodules bind into the caller's scope."""
     B, T = input_ids.shape
     tok = mod.param("tok_embeddings",
@@ -321,7 +339,22 @@ def transformer_forward(mod: nn.Module, cfg, block_of, input_ids,
         positions = cache_len + jnp.arange(T)
     new_caches = []
     published = {}
+    narrow_at = (sampled_only_from(cfg) if logits_at is not None
+                 and kv_caches is not None else cfg.n_layers)
+
+    def sampled(v):
+        """``v`` [B, T, ...] at each row's sampled position: [B, ...]."""
+        return v[jnp.arange(B), logits_at]
     for i in range(cfg.n_layers):
+        if i == narrow_at:
+            # from here on no layer keeps an entry: the rest of the
+            # stack serves the sampled positions alone, one query a row
+            with jax.named_scope("sampled_only"):
+                x = sampled(x)[:, None]
+                cache_len = cache_len + logits_at
+                positions = cache_len[:, None]
+                published = {key: sampled(v)[:, None] if publishes[key]
+                             else v for key, v in published.items()}
         block = block_of(i)
         if cfg.remat:
             block = nn.remat(block, static_argnums=())
@@ -334,7 +367,8 @@ def transformer_forward(mod: nn.Module, cfg, block_of, input_ids,
                 x, freqs, positions, cache_i, cache_len)
         new_caches.append(nc)
     if logits_at is not None:
-        x = x[jnp.arange(B), logits_at]                # [B, dim]
+        # [B, dim]
+        x = x[:, 0] if narrow_at < cfg.n_layers else sampled(x)
     x = (norm or RMSNorm)(cfg.norm_eps, name="norm")(x)
     head = tok
     if not cfg.tie_word_embeddings:

@@ -54,6 +54,20 @@ The model runs through ``transformer_forward`` as the other families do
 path), with ``publishes``: what a block hands later blocks of the same
 call rides a dict, never a cache. The static-cache ``generate`` of
 models/llama.py does not serve it.
+
+THE CROSS-DECODER'S LINEAR-TIME PREFILL IS TAKEN. Layers ``N/2 + 2``
+onward (memory units and cross layers alternating: 14 of the published
+32) keep nothing, so nothing they compute at a prompt position is ever
+read but the logits of the position a row samples from. A paged prefill
+call (``logits_at``) therefore runs them at that ONE position a row:
+``transformer_forward`` narrows the call before the first of them
+(models/kv_cache.py ``sampled_only_from``, read from ``layer_kinds``,
+not from this model's name), ``m`` with it (``PUBLISHES``), and a cross
+layer is then handed one query a row over the full layer's pages, all
+of the call's positions appended: a decode step's shape, and on one
+TPU a decode step's kernel. The self-decoder (layers 0 to ``N/2 + 1``)
+sees every position, as it must: its states, rings and pages are read
+by later calls.
 """
 from __future__ import annotations
 
@@ -79,8 +93,14 @@ from ray_tpu.ops.selective_scan import ssm_chunked, ssm_step
 SSM, SLIDING, FULL, CROSS, GMU = "ssm", "sliding", "full", "cross", "gmu"
 _KINDS = {SSM: KIND_RECURRENT, SLIDING: KIND_SLIDING, FULL: KIND_KV,
           CROSS: KIND_BORROWED, GMU: KIND_STATELESS}
-# what ``transformer_forward``'s dict carries between blocks of a call
+# what ``transformer_forward``'s dict carries between blocks of a call,
+# and of each key whether its value is laid out BY POSITION: ``m`` is
+# ``[B, T, d_inner]``, a memory unit reads its own position's, and a
+# call narrowed to its sampled positions narrows it too; the full
+# layer's pages (or, without a cache, its keys of the whole sequence)
+# stand for the whole call and are handed on as they are
 MEMORY, SHARED = "memory", "shared"
+PUBLISHES = {MEMORY: True, SHARED: False}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -537,7 +557,7 @@ class Phi4Flash(nn.Module):
             self, self.config,
             lambda i: functools.partial(Phi4FlashBlock, index=i),
             input_ids, kv_caches, cache_len, rope=False,
-            logits_at=logits_at, norm=LayerNorm, publishes=True)
+            logits_at=logits_at, norm=LayerNorm, publishes=PUBLISHES)
 
 
 def ssm_param_count(cfg: Phi4FlashConfig) -> int:

@@ -26,6 +26,7 @@ from ray_tpu.models.kv_cache import (KIND_BORROWED, KIND_KV, KIND_LATENT,
                                      has_latent_pages,
                                      kv_query_heads, latent_page_width,
                                      layer_kinds, page_layout,
+                                     sampled_only_from,
                                      state_bytes_per_slot)
 from ray_tpu.models.mixtral import stats_sections
 from ray_tpu.ops import latent_window_attention as latent_window
@@ -50,6 +51,12 @@ def _new_round_info() -> Dict[str, int]:
     model for the logits of each row's ``last_idx`` alone,
     serve/step_programs.py ``_jit_prefill``), dummy rows included, where
     ``B x T`` went through the head before; 0 = no call.
+    ``prefill_sampled_only_layers`` is the layers of that call that ran
+    on those ``prefill_head_rows`` positions alone: the model's
+    trailing layers that keep no entry (models/kv_cache.py
+    ``sampled_only_from``; models/llama.py ``transformer_forward``
+    narrows the call before the first of them), 0 for a model whose
+    last layer keeps one, and without a call.
     ``decode_context_tokens`` is the sum over the decode dispatch's
     riders of their OWN context lengths after it (what each rider's
     last step attended), where ``decode_window_tokens`` is the longest
@@ -71,7 +78,7 @@ def _new_round_info() -> Dict[str, int]:
             "prefill_tokens": 0, "prefill_budget": 0,
             "prefill_rows": 0, "prefill_window_tokens": 0,
             "prefill_kernel_blocks": 0, "prefill_width": 0,
-            "prefill_head_rows": 0}
+            "prefill_head_rows": 0, "prefill_sampled_only_layers": 0}
 
 
 class RoundAccounts:
@@ -99,6 +106,9 @@ class RoundAccounts:
         # own (models/kv_cache.py KIND_BORROWED): the owners and their
         # readers; 0 for a model whose every reader is its own owner
         kinds = layer_kinds(cfg)
+        # the trailing layers that keep no entry: a prefill call runs
+        # them at the one position a row it samples from
+        self.sampled_only_layers = len(kinds) - sampled_only_from(cfg)
         self.page_readers = (kinds.count(KIND_KV) + kinds.count(
             KIND_BORROWED) if KIND_BORROWED in kinds else 0)
         # what the step programs count on the device, over live rows
@@ -199,6 +209,7 @@ class RoundAccounts:
         """A ``[B, T]`` prefill call was dispatched whose live rows
         begin at ``starts`` and hold ``granted`` prompt tokens."""
         self.add(prefill_rows=len(starts), prefill_head_rows=B,
+                 prefill_sampled_only_layers=self.sampled_only_layers,
                  prefill_tokens=granted)
         self.info["prefill_width"] = T
         self.note_state_slots(len(starts))

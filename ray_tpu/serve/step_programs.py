@@ -50,7 +50,9 @@ def _moe_apply(model, mesh):
     concatenated in the sections' order; for any other model it is ().
     ``logits_at`` [B]: the one
     position of each row the program wants logits for, ``[B, V]``
-    (models/llama.py transformer_forward); None: every position's."""
+    (models/llama.py transformer_forward, which then also runs the
+    model's trailing layers that keep no entry at that position alone);
+    None: every position's."""
     sections = stats_sections(model.config)
     sown_by = [s.collection for s in sections]
 
@@ -119,7 +121,14 @@ def _jit_prefill(model, temp, B, capture, mesh):
     mid-prompt, consumed only for rows that just finished their
     prompt. The model is asked for that one position's logits a row
     (``logits_at``): the head sees [B, dim], and the program holds no
-    [B, T, V] value."""
+    [B, T, V] value. What else the model leaves out for it is the
+    model's to say: the layers after its last layer that keeps an entry
+    (models/kv_cache.py ``sampled_only_from``: none for most models,
+    Phi-4-mini-flash's cross-decoder) write nothing a later call could
+    read, so they run at that one position a row, a decode step's
+    shape, and every entry of the pool is what the every-position call
+    would leave. The decode and verify programs ask for every position
+    they hold and are not narrowed."""
     constrain = _constrain_for(mesh)
     apply = _moe_apply(model, mesh)
     from ray_tpu.models.llama import _pick_token

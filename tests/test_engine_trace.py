@@ -418,6 +418,7 @@ def edge(tmp_path_factory):
 @pytest.mark.parametrize("case", [
     "nothing_in_flight", "counts_add_up", "start_says_its_cost",
     "prefill_width_is_the_calls_T", "prefill_head_rows_is_the_calls_B",
+    "no_layer_of_a_model_of_pages_runs_on_the_sampled_positions_alone",
     "cpu_within_wall",
     "gc_pass_is_an_event", "gc_pass_is_a_span",
     "gc_hook_installed_once", "the_log_joins"])
@@ -477,6 +478,16 @@ def test_trace_starts_on_a_rounds_edge(edge, case):
         assert heads == {(True, 4), (False, 0)}
         assert edge["stats"]["prefill_head_rows"] == \
             4 * edge["stats"]["prefills"]
+    elif case == ("no_layer_of_a_model_of_pages_runs_on_the_sampled_"
+                  "positions_alone"):
+        # every layer of this model keeps an entry (K/V pages): its
+        # prefill call gathers ``last_idx`` after the LAST layer, and
+        # the counter beside ``prefill_head_rows`` says 0 in every
+        # round (tests/test_phi4flash.py has the model where it does
+        # not)
+        assert {d["prefill_sampled_only_layers"] for d, _t in rounds} == {0}
+        assert edge["stats"]["prefill_sampled_only_layers"] == 0
+        assert edge["stats"]["prefills"] > 0
     elif case == "cpu_within_wall":
         for d, _t in rounds:
             assert 0 <= d["cpu_s"] <= d["wall_s"] + 2e-3

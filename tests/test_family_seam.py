@@ -354,6 +354,46 @@ def test_logits_at_is_the_rows_of_the_full_logits(toy, family, paged):
         rtol=1e-5, atol=1e-6)
 
 
+def _kinds(*kinds):
+    """A config of nothing but its layers' kinds."""
+    import types
+    return lambda: types.SimpleNamespace(layer_kinds=kinds,
+                                         n_layers=len(kinds))
+
+
+@pytest.mark.parametrize("family", FAMILIES + (
+    "deepseek_v32", "nothing_kept_anywhere", "an_entry_after_the_run"))
+def test_where_a_call_narrows_is_read_from_the_kinds(family):
+    """``sampled_only_from``: the first layer of the TRAILING run of
+    layers that keep no entry, so ``n_layers`` (the gather stands before
+    the final norm, as it did) for every family whose last layer keeps
+    one: all but Phi-4-mini-flash, whose cross-decoder is 14 of the
+    published 32 layers and 2 of the toy's 8. A layer without an entry
+    BEFORE one that keeps its own does not count (its positions'
+    results are read by that layer, which keeps every position's)."""
+    from ray_tpu.models.deepseek_v32 import deepseek_v32_tiny
+    from ray_tpu.models.kv_cache import (KIND_BORROWED, KIND_KV,
+                                         KIND_STATELESS, layer_kinds,
+                                         sampled_only_from)
+    from ray_tpu.models.phi4flash import phi4_mini_flash
+    tiny = {"deepseek_v32": deepseek_v32_tiny,
+            "nothing_kept_anywhere": _kinds(KIND_STATELESS, KIND_BORROWED),
+            "an_entry_after_the_run": _kinds(
+                KIND_KV, KIND_BORROWED, KIND_STATELESS, KIND_KV)}.get(
+                    family) or _families()[family][0]
+    cfg = tiny()
+    n = len(layer_kinds(cfg))
+    assert n == cfg.n_layers
+    want = {"phi4flash": 6, "nothing_kept_anywhere": 0}.get(family, n)
+    assert sampled_only_from(cfg) == want
+    if family == "phi4flash":
+        assert sampled_only_from(phi4_mini_flash()) == 18
+        assert sampled_only_from(phi4_mini_flash(n_layers=8)) == 6
+        kinds = layer_kinds(phi4_mini_flash())
+        assert set(kinds[18:]) == {KIND_BORROWED, KIND_STATELESS}
+        assert kinds[17] == KIND_KV
+
+
 @pytest.mark.parametrize("capture", [False, True],
                          ids=["tokens", "logprobs"])
 @pytest.mark.parametrize("family", ["llama", "mixtral", "kimi_linear",
@@ -570,7 +610,9 @@ _ROUND = {
     "decode_riders", "decode_steps", "decode_window_tokens",
     "decode_context_tokens", "decode_kernel_pages", "prefill_tokens",
     "prefill_budget", "prefill_rows", "prefill_window_tokens",
-    "prefill_kernel_blocks", "prefill_width", "prefill_head_rows"}
+    "prefill_kernel_blocks", "prefill_width", "prefill_head_rows",
+    # PR 61: the layers of the call that ran on those positions alone
+    "prefill_sampled_only_layers"}
 _ROUND_MOE = {
     "moe_pairs", "moe_experts_touched", "moe_load_max", "moe_layer_steps",
     "moe_tile_visits", "moe_pairs_routed", "moe_decode_pairs",

@@ -20,6 +20,7 @@ teacher-forced, and the captured log-probability of every generated
 token (the whole row of logits behind it) to the reference's.
 """
 import math
+import re
 import types
 
 import jax
@@ -422,6 +423,96 @@ def test_paged_logits_match_the_reference(tiny):
             assert not np.asarray(entry.k[jnp.asarray([1, 3])]).any()
             assert np.abs(np.asarray(entry.k[2])).max() > 0
     assert all(not b.any() for b in before)
+
+
+def test_a_prefill_call_narrows_before_the_layers_that_keep_nothing(tiny):
+    """The chunked-prefill program's call of the model (``logits_at`` =
+    each row's ``last_idx``) over three rows at their SECOND chunk: one
+    ends its prompt mid-chunk (``last_idx`` 6), one stands mid-prompt
+    (``last_idx`` = T - 1), one is a dummy (a null page-table row, no
+    slot, a stale start). The memory unit and the cross layer (6 and 7
+    of the toy's 8: ``sampled_only_from``) see ONE position a row, every
+    layer before them all sixteen; the logits are the every-position
+    call's at ``last_idx``, and every entry of the pool (the pages, the
+    rings, the states, the tails) is bit for bit what the every-position
+    call leaves."""
+    from ray_tpu.serve import step_programs
+    cfg, model, params = tiny
+    assert kv_cache.sampled_only_from(cfg) == 6
+    B, T = 3, CHUNK
+    table = np.zeros((B, 8), np.int32)
+    table[0, :4] = 1 + np.arange(4)
+    table[1, :4] = 10 + np.arange(4)
+    table = jnp.asarray(table)
+    slots = jnp.asarray([2, 0, 4], jnp.int32)
+    apply = step_programs._moe_apply(model, None)
+
+    def call(pool, ids, start, last_idx, sampled):
+        def live():
+            return (table[:, :1] != 0) & (
+                jnp.arange(T)[None] <= last_idx[:, None])
+        logits, new, _moe = apply(
+            params, ids, step_programs._views(pool, table, live, slots),
+            start, live, last_idx if sampled else None)
+        return logits, [kv_layer_store(c) for c in new]
+    every = jax.jit(lambda *a: call(*a, False))
+    narrowed = jax.jit(lambda *a: call(*a, True))
+    first = jnp.asarray(_ids((B, T), seed=71), jnp.int32).at[2].set(0)
+    zeros = jnp.zeros((B,), jnp.int32)
+    _logits, pool = every(_pool(cfg), first, zeros,
+                          jnp.asarray([T - 1, T - 1, 0], jnp.int32))
+    ids = jnp.asarray(_ids((B, T), seed=72), jnp.int32)
+    ids = ids.at[0, 7:].set(0).at[2].set(0)
+    start = jnp.asarray([T, T, 977], jnp.int32)
+    last_idx = jnp.asarray([6, T - 1, 0], jnp.int32)
+    full, want_pool = every(pool, ids, start, last_idx)
+    rows, got_pool = narrowed(pool, ids, start, last_idx)
+    assert full.shape == (B, T, cfg.vocab_size)
+    assert rows.shape == (B, cfg.vocab_size)
+    np.testing.assert_allclose(
+        np.asarray(rows)[:2],
+        np.asarray(full)[np.arange(2), np.asarray(last_idx)[:2]],
+        rtol=1e-5, atol=1e-6)
+    assert np.isfinite(np.asarray(rows)).all()       # the dummy row too
+    same = jax.tree_util.tree_map(
+        lambda a, b: bool((np.asarray(a) == np.asarray(b)).all()),
+        got_pool, want_pool)
+    assert jax.tree_util.tree_structure(got_pool) == \
+        jax.tree_util.tree_structure(want_pool)
+    assert all(jax.tree_util.tree_leaves(same)), same
+    # and the second chunk moved the rows' entries of every kind
+    moved = jax.tree_util.tree_map(
+        lambda a, b: bool((np.asarray(a) != np.asarray(b)).any()),
+        got_pool, pool)
+    assert all(jax.tree_util.tree_leaves(moved)), moved
+    # what ran where: the feed-forwards' two up-projections (no other
+    # extent of the toy is its hidden 96) at sixteen positions a row in
+    # all eight layers, or in the first six and at ONE in the last two
+    def ups(text, t):
+        return len(re.findall(r"stablehlo\.dot_general[^\n]*-> tensor<"
+                              f"{B}x{t}x{cfg.hidden_dim}xf32>", text))
+    every, narrowed = (f.lower(pool, ids, start, last_idx).as_text()
+                       for f in (every, narrowed))
+    assert (ups(every, T), ups(every, 1)) == (16, 0)
+    assert (ups(narrowed, T), ups(narrowed, 1)) == (12, 4)
+
+
+def test_the_round_says_how_many_layers_ran_on_the_sampled_positions(tiny):
+    """``prefill_sampled_only_layers`` of the ``round`` event: the toy's
+    two trailing layers that keep nothing (14 of the published 32) in
+    every round with a prefill call, 0 without one; the stats sum it."""
+    eng = _engine(tiny)
+    handles = [eng.submit(_ids((n,), seed=40 + n).tolist(),
+                          max_new_tokens=6) for n in (37, 9)]
+    _drive(eng)
+    assert all(len(h.result()) == 6 for h in handles)
+    assert eng.accounts.sampled_only_layers == 2
+    rounds = [e[5] for e in eng.events.snapshot() if e[2] == "round"]
+    said = {(bool(r["prefill_rows"]), r["prefill_head_rows"],
+             r["prefill_sampled_only_layers"]) for r in rounds}
+    assert said == {(True, 4, 2), (False, 0, 0)}
+    assert eng.stats["prefill_sampled_only_layers"] == \
+        2 * eng.stats["prefills"] > 0
 
 
 def test_the_differential_kernel_path_is_the_four_product_form(tiny):
