@@ -22,7 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.kv_cache import (KIND_BORROWED, KIND_KV, KIND_LATENT,
-                                     KIND_SLIDING, SlidingRing,
+                                     KIND_SLIDING, RecurrentState,
+                                     SlidingRing,
                                      has_latent_pages,
                                      kv_query_heads, latent_page_width,
                                      layer_kinds, page_layout,
@@ -32,6 +33,7 @@ from ray_tpu.models.mixtral import stats_sections
 from ray_tpu.ops import latent_window_attention as latent_window
 from ray_tpu.ops import paged_decode_attention as paged_decode
 from ray_tpu.ops import ring_window_attention as ring_window
+from ray_tpu.ops import selective_scan
 from ray_tpu.ops.paged_attention import paged_window_block_pages
 from ray_tpu.serve.step_programs import ambient_mesh
 
@@ -66,6 +68,11 @@ def _new_round_info() -> Dict[str, int]:
     layers' attention (ops/latent_window_attention.py), else the key
     blocks ONE such layer's kernel visits over the call's live rows,
     each row to the block of its own last query.
+    ``prefill_scan_kernel_positions`` is 0 where the prefill program
+    holds no kernel for its state-space layers' scan
+    (ops/selective_scan.py), else the positions ONE such layer's kernel
+    walks over the call's rows: ``B x T``, dummy rows included, as the
+    kernel does.
     ``decode_kernel_pages`` is 0 where the decode program holds no
     kernel for its K/V or latent layers' attention
     (ops/paged_decode_attention.py), else the pages ONE such layer's
@@ -77,7 +84,8 @@ def _new_round_info() -> Dict[str, int]:
             "decode_kernel_pages": 0,
             "prefill_tokens": 0, "prefill_budget": 0,
             "prefill_rows": 0, "prefill_window_tokens": 0,
-            "prefill_kernel_blocks": 0, "prefill_width": 0,
+            "prefill_kernel_blocks": 0,
+            "prefill_scan_kernel_positions": 0, "prefill_width": 0,
             "prefill_head_rows": 0, "prefill_sampled_only_layers": 0}
 
 
@@ -100,6 +108,10 @@ class RoundAccounts:
                           for e in pool if isinstance(e, SlidingRing)),
                          None)
         self.ring_len = self.ring.shape[2] if self.ring else 0
+        self.state = next((jax.ShapeDtypeStruct(e.state.shape,
+                                                e.state.dtype)
+                           for e in pool if isinstance(e, RecurrentState)),
+                          None)
         self.sliding_window = cfg.sliding_window if self.ring else 0
         self.state_by_slot = bool(state_bytes_per_slot(cfg, self.ring_len))
         # the layers that READ K/V pages where some keep none of their
@@ -219,6 +231,8 @@ class RoundAccounts:
             self.add(prefill_kernel_blocks=latent_window.kernel_blocks(
                 starts, T, self.window_block,
                 -(-self.max_pages * self.Pg // self.window_block)))
+        self.add(prefill_scan_kernel_positions=B * T
+                 if self.prefill_scan_serves(T) else 0)
 
     def note_decode(self, ends, steps: int, verify: bool = False) -> None:
         """A decode dispatch of ``steps`` steps was launched whose
@@ -261,6 +275,17 @@ class RoundAccounts:
             return has_latent_pages(cfg) and latent_window.serves(
                 T, cfg.n_heads, latent_page_width(cfg), cfg.kv_lora_rank,
                 self.Pg, cfg.dtype)
+
+    def prefill_scan_serves(self, T: int) -> bool:
+        """Whether the ``[rows, T]`` prefill program's state-space
+        layers scan through the kernel: the question ``ssm_chunked``
+        asks, of a chunk of ``T`` positions and one layer's states as
+        the pool keeps them (a delta-rule layer's state has another
+        shape, which the rule refuses), under the mesh the program is
+        traced under."""
+        with ambient_mesh(self.mesh):
+            return self.state is not None and selective_scan.serves(
+                T, self.state)
 
     def decode_kernel_serves(self) -> bool:
         """Whether the decode program's paged layers (the latent ones

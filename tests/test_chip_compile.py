@@ -1566,21 +1566,34 @@ def _shared_pages_step(name, one_chip):
     return cfg, fn.lower(params, pages, *rest).compile()
 
 
+_PHI_PROGRAMS = {}
+
+
+def _shared_pages_program(name, one_chip, monkeypatch):
+    """``_shared_pages_step`` with every rule of its kernels steered as
+    the chip answers it (the pages', the rings', the scan's); compiled
+    once a module."""
+    from ray_tpu.ops import paged_decode_attention as pd
+    from ray_tpu.ops import ring_window_attention as rw
+    from ray_tpu.ops import selective_scan as ss
+    from ray_tpu.serve import step_programs
+    if name not in _PHI_PROGRAMS:
+        for mod in (pd, rw, ss):
+            monkeypatch.setattr(mod, "_on_one_tpu", lambda: True)
+        for builder in ("_jit_decode", "_jit_prefill"):
+            monkeypatch.setattr(step_programs, builder,
+                                getattr(step_programs, builder).__wrapped__)
+        _PHI_PROGRAMS[name] = _shared_pages_step(name, one_chip)
+    return _PHI_PROGRAMS[name]
+
+
 @pytest.mark.parametrize("name", ["decode", "prefill"])
 def test_shared_pages_rings_and_states_stay_in_place(one_chip, monkeypatch,
                                                      name):
     from ray_tpu.models.kv_cache import (kv_pool_page_bytes,
                                          sliding_ring_len,
                                          state_bytes_per_slot)
-    from ray_tpu.ops import paged_decode_attention as pd
-    from ray_tpu.ops import ring_window_attention as rw
-    from ray_tpu.serve import step_programs
-    monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
-    monkeypatch.setattr(rw, "_on_one_tpu", lambda: True)
-    for builder in ("_jit_decode", "_jit_prefill"):
-        monkeypatch.setattr(step_programs, builder,
-                            getattr(step_programs, builder).__wrapped__)
-    cfg, compiled = _shared_pages_step(name, one_chip)
+    cfg, compiled = _shared_pages_program(name, one_chip, monkeypatch)
     text = compiled.as_text()
     ring = sliding_ring_len(cfg, PAGE, 256)
     assert (cfg.n_kv_heads, cfg.kv_page_heads, ring) == (10, 16, 832)
@@ -1640,3 +1653,50 @@ def test_shared_pages_rings_and_states_stay_in_place(one_chip, monkeypatch,
         assert "layers_7/attention/attn_shared/kv_gather" not in text
         assert mem.temp_size_in_bytes < 10 * one_state, \
             mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_the_scan_is_one_kernel_a_state_space_layer(one_chip, monkeypatch,
+                                                    name):
+    """Phi-4-mini-flash's programs with the scan's rule steered as the
+    chip answers it: the ``[4, 256]`` prefill call holds ONE
+    ``selective_scan`` call a state-space layer (three of the eight
+    layers here, nine of the cell's 32) under ``ssm_scan``, over the
+    chunk as the model has it (``u`` in bfloat16, ``delta`` and ``y``
+    in float32, nothing time-major), and nothing under that scope
+    loops; a decode step is ``ssm_step``'s and holds none."""
+    cfg, compiled = _shared_pages_program(name, one_chip, monkeypatch)
+    text = compiled.as_text()
+    scans = re.findall(
+        r"custom-call\([^\n]*/ssm_scan/[^\n]*selective_scan[^\n]*", text)
+    looped = re.findall(r" while\([^\n]*/ssm_scan/[^\n]*", text)
+    assert not looped, looped[:2]
+    if name == "decode":
+        assert not scans
+        assert not re.findall(r"custom-call\([^\n]*/ssm_scan/[^\n]*", text)
+        return
+    assert cfg.mixers.count("ssm") == 3
+    assert len(scans) == 3, len(scans)
+    for call in scans:
+        assert 'custom_call_target="tpu_custom_call"' in call
+        for operand in ("bf16[4,256,5120]", "f32[4,256,5120]",
+                        "f32[4,16,40,128]"):
+            assert operand in call, (operand, call[:400])
+    assert "f32[256,4,5120]" not in text and "f32[256,4,16,5120]" not in text
+
+
+@pytest.mark.parametrize("T", [64, 128, 256])
+def test_selective_scan_kernel_compiles(one_chip, T):
+    """The chunk's kernel alone for the described chip at the widths
+    ``.reason-sat``'s engine builds: four rows of ``T`` positions over
+    5,120 channels of 16 states, operands in the model's types."""
+    from ray_tpu.ops import selective_scan as ss
+    B, C, N = 4, 5120, 16
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert ss.serves.__wrapped__ if hasattr(ss.serves, "__wrapped__") \
+        else True
+    compiled = _compile(
+        ss.selective_scan, one_chip, ((B, T, C), bf16), ((B, T, C), f32),
+        ((N, C), f32), ((B, T, N), bf16), ((B, T, N), bf16), ((C,), f32),
+        ((B, N, C), f32), ((B, T), jnp.bool_))
+    assert "selective_scan" in compiled.as_text()

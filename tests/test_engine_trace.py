@@ -419,7 +419,7 @@ def edge(tmp_path_factory):
     "nothing_in_flight", "counts_add_up", "start_says_its_cost",
     "prefill_width_is_the_calls_T", "prefill_head_rows_is_the_calls_B",
     "no_layer_of_a_model_of_pages_runs_on_the_sampled_positions_alone",
-    "cpu_within_wall",
+    "no_scan_kernel_walks_a_model_of_pages", "cpu_within_wall",
     "gc_pass_is_an_event", "gc_pass_is_a_span",
     "gc_hook_installed_once", "the_log_joins"])
 def test_trace_starts_on_a_rounds_edge(edge, case):
@@ -487,6 +487,14 @@ def test_trace_starts_on_a_rounds_edge(edge, case):
         # not)
         assert {d["prefill_sampled_only_layers"] for d, _t in rounds} == {0}
         assert edge["stats"]["prefill_sampled_only_layers"] == 0
+        assert edge["stats"]["prefills"] > 0
+    elif case == "no_scan_kernel_walks_a_model_of_pages":
+        # no layer of this model keeps a state-space state: the counter
+        # of the scan's kernel (ops/selective_scan.py) says 0 in every
+        # round (tests/test_phi4flash.py has the model where it does
+        # not)
+        assert {d["prefill_scan_kernel_positions"] for d, _t in rounds} == {0}
+        assert edge["stats"]["prefill_scan_kernel_positions"] == 0
         assert edge["stats"]["prefills"] > 0
     elif case == "cpu_within_wall":
         for d, _t in rounds:

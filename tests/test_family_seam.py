@@ -506,11 +506,61 @@ def test_a_dense_familys_programs_never_reach_the_grouped_matmul(
     assert not any("/moe_" in t or "ragged_dot" in t for t in texts)
 
 
+# --------------- the chunk's scan kernel is one family's, and no other's
+
+@pytest.mark.parametrize("family", [f for f in FAMILIES
+                                    if f != "phi4flash"])
+def test_no_other_familys_programs_reach_the_selective_scan(family,
+                                                            monkeypatch):
+    """The prefill and decode programs of every other toy, traced with
+    ``ops/selective_scan.py`` made to raise (its rule, its kernel and
+    both ``jax.numpy`` forms): none of them has a state-space layer, so
+    a change to that module cannot move one byte of them (their lowered
+    text against the parent's tree is a scratch script's, CHANGES.md
+    PR 62), and none holds a ``selective_scan`` call or the scope."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops import selective_scan as ss
+    from ray_tpu.serve import step_programs
+
+    # (every family's module is imported before the names are patched:
+    # the one that binds them keeps the real ones)
+    tiny, cls, _rule = _families()[family]
+
+    def reached(*_a, **_k):
+        raise AssertionError("another family reached the selective scan")
+    for name in ("serves", "selective_scan", "ssm_chunked", "ssm_step"):
+        monkeypatch.setattr(ss, name, reached)
+    cfg = tiny(dtype=jnp.float32, vocab_size=227)   # no other test's
+    model = cls(cfg)
+    arr, i32, S = jax.ShapeDtypeStruct, jnp.int32, 6
+    params = {"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), i32))["params"]}
+    ring = kv_cache.sliding_ring_len(cfg, _PAGE, _T)
+    pages = jax.eval_shape(lambda: kv_cache.init_kv_pool(
+        cfg, 17, _PAGE, n_slots=S, ring_len=ring))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    recurrent = bool(kv_cache.state_bytes_per_slot(cfg, ring))
+    texts = [
+        step_programs._jit_decode(model, 0.0, 8, S, False, None).lower(
+            params, pages, arr((S, 8), i32), arr((S,), i32),
+            arr((S,), i32), key, arr((), i32)).as_text(debug_info=True),
+        step_programs._jit_prefill(model, 0.0, 4, False, None).lower(
+            params, pages, arr((4, _T), i32), arr((4,), i32),
+            arr((4,), i32), arr((4, 8), i32), key,
+            *((arr((4,), i32),) if recurrent else ())).as_text(
+                debug_info=True)]
+    # (a scope, a kernel's name and the module's file all follow a "/")
+    assert not any("/ssm_scan" in t or "/selective_scan" in t
+                   for t in texts)
+
+
 # ------------- the round's accounts: one module, a counter's names in one
 
 _PACKAGE = SERVE.parent
 _KERNELS = ("latent_window_attention", "paged_decode_attention",
-            "ring_window_attention", "sparse_latent_attention")
+            "ring_window_attention", "sparse_latent_attention",
+            "selective_scan")
 
 
 def _code_words(tree):
@@ -612,7 +662,9 @@ _ROUND = {
     "prefill_budget", "prefill_rows", "prefill_window_tokens",
     "prefill_kernel_blocks", "prefill_width", "prefill_head_rows",
     # PR 61: the layers of the call that ran on those positions alone
-    "prefill_sampled_only_layers"}
+    "prefill_sampled_only_layers",
+    # PR 62: the positions one state-space layer's scan kernel walked
+    "prefill_scan_kernel_positions"}
 _ROUND_MOE = {
     "moe_pairs", "moe_experts_touched", "moe_load_max", "moe_layer_steps",
     "moe_tile_visits", "moe_pairs_routed", "moe_decode_pairs",

@@ -19,6 +19,7 @@ engine's tokens are held to the reference's full forward pass
 teacher-forced, and the captured log-probability of every generated
 token (the whole row of logits behind it) to the reference's.
 """
+import functools
 import math
 import re
 import types
@@ -288,6 +289,157 @@ def test_step_chunked_and_the_reference_loop_agree():
     assert np.abs(np.asarray(stepped[0] - seeded[0])).max() > 0
 
 
+# ------------------------------------------- the chunk's kernel (interpret)
+
+@pytest.fixture
+def scan_kernel(monkeypatch):
+    """The module's rule steered as the chip answers it, and its kernel
+    run in interpret mode: ``ssm_chunked`` then serves a chunk as it
+    does on one TPU. Yields the shapes the kernel was handed."""
+    from ray_tpu.ops import selective_scan as ss
+    seen, kernel = [], jax.jit(functools.partial(ss.selective_scan,
+                                                 interpret=True))
+
+    def interpreted(u, *rest):
+        seen.append(tuple(u.shape))
+        return kernel(u, *rest)
+    monkeypatch.setattr(ss, "_on_one_tpu", lambda: True)
+    monkeypatch.setattr(ss, "selective_scan", interpreted)
+    return seen
+
+
+def _unsteered(*args):
+    """``ssm_chunked``'s ``lax.scan``, whatever the rule is steered to."""
+    from unittest import mock
+
+    from ray_tpu.ops import selective_scan as ss
+    with mock.patch.object(ss, "_on_one_tpu", lambda: False):
+        return ssm_chunked(*args)
+
+
+@pytest.mark.parametrize("B,T,C,N,dtype", [
+    (2, 8, 1024, 16, jnp.float32), (3, 24, 2048, 16, jnp.float32),
+    (1, 16, 1024, 8, jnp.float32), (2, 16, 1024, 16, jnp.bfloat16)],
+    ids=["one_group", "two_blocks", "eight_states", "bfloat16_operands"])
+def test_the_scan_kernel_agrees_with_the_scan_and_the_reference(
+        scan_kernel, B, T, C, N, dtype):
+    """From zeros over real positions alone: the kernel = the
+    ``lax.scan`` = the plain reference's loop, float32 (``u``, ``B``,
+    ``C`` in the model's type are widened by both forms alike)."""
+    from benchmarks.reference import phi4flash as ref
+    u, delta, A, Bm, Cm, D = _scan_inputs(B, T, C, N, seed=T)
+    u, Bm, Cm = (x.astype(dtype) for x in (u, Bm, Cm))
+    zero, ok = jnp.zeros((B, N, C), jnp.float32), jnp.ones((B, T), bool)
+    y, end = ssm_chunked(u, delta, A, Bm, Cm, D, zero, ok)
+    assert scan_kernel == [(B, T, C)]
+    want_y, want_end = _unsteered(u, delta, A, Bm, Cm, D, zero, ok)
+    assert y.dtype == end.dtype == jnp.float32
+    np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(end, want_end, rtol=1e-5, atol=1e-6)
+    plain = ref.selective_scan(*(x.astype(jnp.float32) for x in (
+        u, delta, A, Bm, Cm, D)))
+    np.testing.assert_allclose(y, plain, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("cut", [8, 16, 24])
+def test_the_scan_kernel_carries_the_state_between_chunks(scan_kernel, cut):
+    """A chunk in one piece = in two pieces carrying the state, from a
+    seeded non-zero state."""
+    B, T, C, N = 2, 32, 1024, 16
+    u, delta, A, Bm, Cm, D = _scan_inputs(B, T, C, N, seed=3)
+    seeded = jnp.asarray(np.random.default_rng(5).standard_normal(
+        (B, N, C)), jnp.float32)
+    ok = jnp.ones((B, T), bool)
+    whole, end = ssm_chunked(u, delta, A, Bm, Cm, D, seeded, ok)
+    a, mid = ssm_chunked(u[:, :cut], delta[:, :cut], A, Bm[:, :cut],
+                         Cm[:, :cut], D, seeded, ok[:, :cut])
+    b, last = ssm_chunked(u[:, cut:], delta[:, cut:], A, Bm[:, cut:],
+                          Cm[:, cut:], D, mid, ok[:, cut:])
+    assert scan_kernel == [(B, T, C), (B, cut, C), (B, T - cut, C)]
+    np.testing.assert_allclose(jnp.concatenate([a, b], 1), whole,
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(last, end, rtol=1e-6, atol=1e-6)
+    want, want_end = _unsteered(u, delta, A, Bm, Cm, D, seeded, ok)
+    np.testing.assert_allclose(whole, want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(end, want_end, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("n_real", [0, 13, 24])
+def test_the_scan_kernel_leaves_the_state_where_the_scan_does(scan_kernel,
+                                                              n_real):
+    """``valid`` cut at 0, 13 and T positions of a seeded state: the
+    state is the ``lax.scan``'s (untouched at 0, bit for bit), and so is
+    every position's read-out, the unreal ones' too."""
+    B, T, C, N = 2, 24, 1024, 16
+    u, delta, A, Bm, Cm, D = _scan_inputs(B, T, C, N, seed=11)
+    seeded = jnp.asarray(np.random.default_rng(6).standard_normal(
+        (B, N, C)), jnp.float32)
+    valid = jnp.arange(T)[None] < jnp.asarray([n_real, T])[:, None]
+    y, end = ssm_chunked(u, delta, A, Bm, Cm, D, seeded, valid)
+    assert scan_kernel == [(B, T, C)]
+    want_y, want_end = _unsteered(u, delta, A, Bm, Cm, D, seeded, valid)
+    np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(end, want_end, rtol=1e-5, atol=1e-6)
+    if n_real == 0:
+        np.testing.assert_array_equal(end[0], seeded[0])
+    else:
+        assert np.abs(np.asarray(end[0] - seeded[0])).max() > 0
+
+
+@pytest.mark.parametrize("T", [64, 128, 256])
+def test_every_prefill_width_of_the_cell_goes_through_the_kernel(
+        scan_kernel, T):
+    """The widths the engine builds for ``.reason-sat`` (powers of two
+    from a page of 64 to the chunk of 256), one block of the cell's
+    5,120 channels, a row cut short beside a whole one."""
+    from ray_tpu.ops import selective_scan as ss
+    assert ss.serves(T, jax.ShapeDtypeStruct((4, 16, 5120), jnp.float32))
+    B, C, N = 2, 1024, 16
+    u, delta, A, Bm, Cm, D = _scan_inputs(B, T, C, N, seed=T)
+    u, Bm, Cm = (x.astype(jnp.bfloat16) for x in (u, Bm, Cm))
+    zero = jnp.zeros((B, N, C), jnp.float32)
+    valid = jnp.arange(T)[None] < jnp.asarray([T, T - 29])[:, None]
+    y, end = ssm_chunked(u, delta, A, Bm, Cm, D, zero, valid)
+    assert scan_kernel == [(B, T, C)]
+    want_y, want_end = _unsteered(u, delta, A, Bm, Cm, D, zero, valid)
+    np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(end, want_end, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("T,state,why", [
+    (21, (3, 8, 1024), "positions that are no whole sublane tiles"),
+    (16, (3, 8, 96), "channels that are no whole blocks"),
+    (1024, (1, 8, 1024), "a whole sequence, not a chunk"),
+    (16, (3, 2, 8, 1024), "a delta-rule state"),
+], ids=["T_21", "C_96", "T_1024", "delta_rule"])
+def test_a_shape_the_rule_refuses_keeps_the_scan(scan_kernel, T, state,
+                                                 why):
+    """``serves`` reads shapes, types, backend and mesh alone; what it
+    refuses is the ``lax.scan``'s, with the same answer."""
+    from ray_tpu.ops import selective_scan as ss
+    assert not ss.serves(T, jax.ShapeDtypeStruct(state, jnp.float32)), why
+    assert ss.serves(16, jax.ShapeDtypeStruct((3, 8, 1024), jnp.float32))
+    assert not ss.serves(
+        16, jax.ShapeDtypeStruct((3, 8, 1024), jnp.bfloat16))
+    assert not ss.serves(1, jax.ShapeDtypeStruct((3, 8, 1024), jnp.float32))
+    if len(state) == 3:
+        B, N, C = state
+        u, delta, A, Bm, Cm, D = _scan_inputs(B, T, C, N, seed=2)
+        args = (u, delta, A, Bm, Cm, D, jnp.zeros(state, jnp.float32),
+                jnp.ones((B, T), bool))
+        got, want = ssm_chunked(*args), _unsteered(*args)
+        assert not scan_kernel
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+
+
+def test_off_one_tpu_the_rule_refuses_every_shape():
+    """On the CPU (and under a multi-device mesh) no shape is served."""
+    from ray_tpu.ops import selective_scan as ss
+    assert not ss.serves(256, jax.ShapeDtypeStruct((4, 16, 5120),
+                                                   jnp.float32))
+
+
 # ------------------------------------ the paged path against the reference
 
 def _call(model, params, table, slots):
@@ -513,6 +665,46 @@ def test_the_round_says_how_many_layers_ran_on_the_sampled_positions(tiny):
     assert said == {(True, 4, 2), (False, 0, 0)}
     assert eng.stats["prefill_sampled_only_layers"] == \
         2 * eng.stats["prefills"] > 0
+
+
+def test_the_round_says_how_many_positions_the_scan_kernel_walked(
+        tiny, monkeypatch):
+    """``prefill_scan_kernel_positions`` of the ``round`` event: 0 where
+    the prefill program holds no kernel for the scan (the CPU), and with
+    the module's rule steered as the chip answers it (the toy's 128
+    channels one block) the call's ``B x T``, dummy rows included, in
+    every round with a prefill call; the stats sum it. The accounts ask
+    ``serves`` of a chunk of the call's width and one layer's states as
+    the pool keeps them."""
+    from ray_tpu.ops import selective_scan as ss
+    eng = _engine(tiny)
+
+    def drive():
+        seen = len(eng.events.snapshot())
+        handles = [eng.submit(_ids((n,), seed=40 + n).tolist(),
+                              max_new_tokens=3) for n in (37, 9)]
+        _drive(eng)
+        assert all(len(h.result()) == 3 for h in handles)
+        return [e[5] for e in eng.events.snapshot()[seen:]
+                if e[2] == "round"]
+    off = drive()
+    assert {r["prefill_scan_kernel_positions"] for r in off} == {0}
+    assert eng.stats["prefill_scan_kernel_positions"] == 0
+    asked, serves = [], ss.serves
+    monkeypatch.setattr(ss, "_on_one_tpu", lambda: True)
+    monkeypatch.setattr(ss, "_BLOCK", 128)
+    monkeypatch.setattr(
+        ss, "serves", lambda T, state: asked.append(
+            (T, state.shape, state.dtype)) or serves(T, state))
+    on = drive()           # the same widths: no program is built again
+    assert {(r["prefill_width"], r["prefill_scan_kernel_positions"])
+            for r in on} == {(16, 4 * 16), (8, 4 * 8), (0, 0)}
+    assert eng.stats["prefill_scan_kernel_positions"] == sum(
+        4 * r["prefill_width"] for r in on) > 0
+    cfg = tiny[0]
+    assert set(asked) == {
+        (T, (4, cfg.ssm_state, cfg.d_inner), jnp.dtype(jnp.float32))
+        for T in (8, 16)}
 
 
 def test_the_differential_kernel_path_is_the_four_product_form(tiny):
