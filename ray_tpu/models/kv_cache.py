@@ -126,6 +126,9 @@ KIND_SLIDING = "sliding"        # a layer with a ring of its window a slot
 KIND_INDEXED = "indexed"        # latent pages AND pages of index keys
 KIND_BORROWED = "borrowed"      # no entry: reads a K/V layer's pages
 KIND_STATELESS = "stateless"    # no entry, and reads none
+# not a kind of layer, but a row of KIND_REFUSALS beside them: a model
+# whose decode step is a forward of a whole block (``block_decode``)
+DECODES_BY_BLOCKS = "decodes_by_blocks"
 
 
 def layer_kinds(cfg) -> Tuple[str, ...]:
@@ -134,6 +137,55 @@ def layer_kinds(cfg) -> Tuple[str, ...]:
     otherwise."""
     return tuple(getattr(cfg, "layer_kinds", None)
                  or (KIND_KV,) * cfg.n_layers)
+
+
+class BlockDecode(NamedTuple):
+    """What a model that DECODES BY BLOCKS tells serving (a config's
+    ``block_decode`` property; None, or absent, for every model that
+    yields one token a sequence a step). Such a model was trained to
+    fill in masked blocks: a decode step is a forward of a whole block
+    of ``block_length`` positions under a BLOCK-CAUSAL mask (query i
+    sees key j iff j // L <= i // L: causal across blocks,
+    bidirectional inside one; L = 1 is the causal mask), the logits AT
+    a masked position are for that position's own token, and
+    ``denoising_steps`` forwards reveal a block's tokens by
+    ``remasking`` before one more forward of the finished block writes
+    its K/V (the commit). serve/step_programs.py ``_jit_decode_blocks``
+    is the program, docs/serving.md "A model that decodes by blocks"
+    the contract."""
+    block_length: int
+    mask_token_id: int
+    denoising_steps: int
+    remasking: str
+    confidence_threshold: float
+
+    def transfer_counts(self) -> Tuple[int, ...]:
+        """Positions step s of a block reveals at the least (the
+        source's ``get_num_transfer_tokens``): L // T each, the first
+        L % T steps one more."""
+        L, T = self.block_length, self.denoising_steps
+        return tuple(L // T + (s < L % T) for s in range(T))
+
+    def forwards(self, masked: int) -> int:
+        """The MOST forwards a block that opens with ``masked`` masked
+        positions costs, its commit among them; exactly that many under
+        the two schedules that reveal a fixed count a step."""
+        left, steps = masked, 0
+        for n in self.transfer_counts():
+            if left <= 0:
+                break
+            left, steps = left - n, steps + 1
+        return steps + 1
+
+
+REMASKING = ("sequential", "low_confidence_static",
+             "low_confidence_dynamic")
+
+
+def block_decode(cfg) -> Optional[BlockDecode]:
+    """``cfg``'s ``BlockDecode`` where the model decodes by blocks, else
+    None: serving asks the config, never its type."""
+    return getattr(cfg, "block_decode", None)
 
 
 def has_latent_pages(cfg) -> bool:
@@ -227,25 +279,59 @@ KIND_REFUSALS = {
         "sharding": "no partition rules exist for the layer or for the "
                     "activation it takes from an earlier one",
     }),
+    # not a kind of layer: HOW THE MODEL DECODES (``block_decode``). Its
+    # layers keep K/V pages as any other's; what a step is differs
+    DECODES_BY_BLOCKS: (
+        "it decodes by blocks (a step is a forward of a whole block of "
+        "positions, and a block's tokens exist only at its commit)", {
+            "spec_len": "drafts are verified under a causal mask, one "
+                        "token a position, and a block's positions see "
+                        "each other",
+            "capture_logprobs": "a position's token is chosen at one of "
+                                "several forwards of its block, and no "
+                                "buffer carries the probability it was "
+                                "chosen with to its commit",
+            "kv_migration": "a KV pull ships pages only, and the block "
+                            "a decode replica would resume (its tokens, "
+                            "its mask flags, its step) is not in its "
+                            "frames",
+            "prefix_cache": "a page of whole blocks is shareable in "
+                            "principle, but a request's last prompt "
+                            "block is rewritten beside its masks until "
+                            "the first commit, and no test holds a "
+                            "shared page to that yet",
+            "kv_dtype": "a block's positions are written once a forward "
+                        "until its commit, and an int8 page's scale "
+                        "only grows with what was written: the "
+                        "committed entries would be coded at a scale "
+                        "the masked drafts set",
+            "sharding": "no partition rules exist for the model, and the "
+                        "block state a slot rides the decode program "
+                        "unsharded",
+        }),
 }
 
 
 def _refusal(cfg, kind: str, option: str, value) -> ValueError:
     keeps, why = KIND_REFUSALS[kind]
+    what = (keeps if kind == DECODES_BY_BLOCKS
+            else f"it has layers that keep {keeps}")
     return ValueError(
         f"{option}={value!r} is not supported for "
-        f"{type(cfg).__name__}: it has layers that keep "
-        f"{keeps}; {why[option]}")
+        f"{type(cfg).__name__}: {what}; {why[option]}")
 
 
 def refuse_unsupported(cfg, **asked) -> None:
-    """ValueError for the first kind of ``cfg``'s layers (in
-    ``KIND_REFUSALS``' order) that cannot handle an option of ``asked``
-    that is set, naming the option, what the layers keep and why; a
-    model of K/V pages only passes whatever is asked."""
-    kinds = layer_kinds(cfg)
+    """ValueError for the first row of ``KIND_REFUSALS`` (in its order:
+    the kinds of ``cfg``'s layers, then how the model decodes) that
+    cannot handle an option of ``asked`` that is set, naming the option,
+    what the layers keep (or how the model decodes) and why; a model of
+    K/V pages only that yields a token a step passes whatever is
+    asked."""
+    rows = layer_kinds(cfg) + (
+        (DECODES_BY_BLOCKS,) if block_decode(cfg) is not None else ())
     for kind, (_, why) in KIND_REFUSALS.items():
-        if kind not in kinds:
+        if kind not in rows:
             continue
         for option in why:
             if asked.get(option):

@@ -49,8 +49,12 @@ def _flash_on_mesh(q, k, v, causal: bool) -> jax.Array:
 
 def xla_attention(q, k, v, causal: bool = True,
                   bias: Optional[jax.Array] = None,
-                  precision: str = "default") -> jax.Array:
-    """Reference attention, [B, T, H, D] layout.
+                  precision: str = "default",
+                  block_len: int = 1) -> jax.Array:
+    """Reference attention, [B, T, H, D] layout. ``block_len`` > 1
+    (with ``causal``): the BLOCK-causal mask of a model that decodes by
+    blocks (ops/paged_attention.py ``_paged_window_attention``): the
+    query at i sees the keys below ``(i // block_len + 1) * block_len``.
 
     precision="default": scores materialize in the input dtype (bf16 on
     TPU) and only the softmax runs in fp32 — halves the dominant HBM
@@ -70,7 +74,11 @@ def xla_attention(q, k, v, causal: bool = True,
     if bias is not None:
         scores = scores + bias.astype(scores.dtype)
     if causal:
-        mask = jnp.tril(jnp.ones((Tq, Tk), dtype=bool), k=Tk - Tq)
+        if block_len > 1:
+            i = jnp.arange(Tk - Tq, Tk)[:, None] // block_len
+            mask = jnp.arange(Tk)[None] // block_len <= i
+        else:
+            mask = jnp.tril(jnp.ones((Tq, Tk), dtype=bool), k=Tk - Tq)
         scores = jnp.where(mask[None, None], scores,
                            jnp.asarray(-1e30, scores.dtype))
     probs = jax.nn.softmax(scores.astype(jnp.float32),
@@ -80,7 +88,18 @@ def xla_attention(q, k, v, causal: bool = True,
 
 def multi_head_attention(q, k, v, causal: bool = True,
                          impl: str = "auto",
-                         bias: Optional[jax.Array] = None) -> jax.Array:
+                         bias: Optional[jax.Array] = None,
+                         block_len: int = 1) -> jax.Array:
+    """``block_len`` > 1: the block-causal mask (``xla_attention``),
+    which the flash kernel does not have: ``"auto"`` is then XLA's."""
+    if block_len > 1:
+        if impl not in ("auto", "xla") or not causal:
+            raise ValueError(
+                f"a block-causal mask (block_len={block_len}) is served "
+                f"by impl='xla' under causal=True only, got impl="
+                f"{impl!r}, causal={causal}")
+        return xla_attention(q, k, v, causal=True, bias=bias,
+                             block_len=block_len)
     if impl == "auto":
         # Measured on v5e (PR 37, tools/flash_bench.py --shape, causal
         # fwd+bwd, H=12 D=64, ms flash | xla): at T=1024 B=24 3.30 |

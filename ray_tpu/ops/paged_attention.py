@@ -334,12 +334,22 @@ def paged_window_block_pages(page_size: int, max_pages: int) -> int:
 
 
 def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
-                            softmax_scale=None, value_dim=None):
+                            softmax_scale=None, value_dim=None,
+                            block_len: int = 1):
     """Causal grouped-query attention of ``q`` [B, T, H, D] (row b's
     queries at absolute positions ``pos[b] + t``) over its page-table
     row's K/V in the page-major pool ``pk``/``pv`` [n_pages, Pg, KH, D]
     (``sk``/``sv``: an int8 pool's per-page scales [n_pages, KH], else
     None). Each page is gathered whole, by its id, as it lies.
+
+    ``block_len`` (static): the mask is BLOCK-CAUSAL, the query at
+    position i sees the keys below ``(i // block_len + 1) * block_len``:
+    every key of its own block of ``block_len`` positions, later ones
+    among them, and of every block before (a model that decodes by
+    blocks, models/kv_cache.py ``BlockDecode``: the caller has appended
+    the whole block). 1, every other model's: the causal mask, and the
+    traced program is what it was before the argument existed. Only the
+    loop serves ``block_len > 1``: both kernels mask causally.
 
     ``pv`` None (a pool of latent pages ``pk`` [n_pages, Pg, D], one
     KV head with no axis of its own): a key's value is the first
@@ -401,7 +411,9 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
     block_pages = paged_window_block_pages(Pg, max_pages)
     Lb = block_pages * Pg
     max_blocks = -(-max_pages // block_pages)
-    if pv is None and sk is None and latent_window.applies(q, pk, Dv):
+    causal = block_len == 1
+    if causal and pv is None and sk is None \
+            and latent_window.applies(q, pk, Dv):
         # under one of the loop's scopes: a device trace's split of a
         # step by scope keeps counting it as attention
         with jax.named_scope("attn_scores"):
@@ -410,7 +422,8 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
                 softmax_scale=(D ** -0.5 if softmax_scale is None
                                else softmax_scale),
                 block_pages=block_pages)
-    if paged_decode.applies(q, pk, pv, sk, page_table, value_dim):
+    if causal and paged_decode.applies(q, pk, pv, sk, page_table,
+                                       value_dim):
         with jax.named_scope("attn_scores"):
             return paged_decode.paged_decode_attention(
                 q, pk, pv, page_table, pos,
@@ -437,6 +450,9 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
     # causal over absolute positions: query t of row b sits at
     # pos[b] + t and sees keys 0..pos[b]+t
     q_pos = pos[:, None] + jnp.arange(T)[None]              # [B, T]
+    if not causal:
+        # block-causal: the LAST key a query sees is its block's last
+        q_pos = (q_pos // block_len + 1) * block_len - 1
     with jax.named_scope("kv_gather"):
         # a whole number of blocks: columns past the table are null
         # pages, which the mask never lets a live query see
@@ -444,7 +460,9 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
             page_table,
             ((0, 0), (0, max_blocks * block_pages - max_pages)))
         live = page_table[:, 0] != 0
-        last = jnp.max(jnp.where(live, pos + (T - 1), 0))
+        # the last position any live row's last query sees
+        last_seen = pos + (T - 1) if causal else q_pos[:, -1]
+        last = jnp.max(jnp.where(live, last_seen, 0))
         n_blocks = jnp.minimum(last // Lb + 1, max_blocks)
 
     def block(j, carry):

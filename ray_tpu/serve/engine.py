@@ -86,6 +86,23 @@ never cleared by the host), so a context eight times the window costs
 those layers what the window does. Entries that age can be neither
 shared by prefix nor shipped, and what rewinds, quantises or shards
 them is not written: the table's sliding row.
+
+A model that DECODES BY BLOCKS (models/sdar.py; ``cfg.block_decode``,
+models/kv_cache.py ``BlockDecode``: the engine asks the config, never
+its type) keeps K/V pages as any other, but a decode STEP of it is a
+forward of every slot's whole block and yields no token or a block's
+(serve/step_programs.py ``_jit_decode_blocks``, built in
+``_jit_decode``'s place). The host's books are then in FORWARDS and
+BLOCKS: a prefill call covers the prompt's whole blocks and emits
+nothing (the remainder opens the first generated block beside masks:
+``_open_blocks_locked``, ``_seed_blocks_locked``); ``_owed`` is a BOUND
+in forwards, ``blocks left x (denoising steps + 1)``; ``slot.pos`` is a
+bound on the block's start, used to grow pages before a dispatch and
+put right at readback; a committed block's tokens reach the client
+together (TTFT is the first commit); a slot retires when the readback
+shows its last block or the bound is consumed, whichever is first, and
+meanwhile the device idles it. docs/serving.md, "A model that decodes
+by blocks".
 """
 from __future__ import annotations
 
@@ -102,7 +119,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from ray_tpu.models.kv_cache import (BlockAllocator, check_kv_dtype,
+from ray_tpu.models.kv_cache import (BlockAllocator, block_decode,
+                                     check_kv_dtype,
                                      export_page_bytes, init_kv_pool,
                                      kv_pool_page_bytes,
                                      page_cols_from_bytes,
@@ -125,8 +143,10 @@ from ray_tpu.serve.scheduler import (LANE_BATCH, LANE_ONLINE,
                                      StepPlan, SlotView, plan_step,
                                      role_plan_caps)
 from ray_tpu.serve.step_programs import (_jit_copy_page, _jit_decode,
+                                         _jit_decode_blocks,
                                          _jit_prefill, _jit_seed,
-                                         _jit_verify, _jit_write_page)
+                                         _jit_seed_blocks, _jit_verify,
+                                         _jit_write_page, block_state)
 from ray_tpu.util.compile_cache import (build_log, metadata_keyed,
                                         summarize_builds)
 
@@ -301,6 +321,13 @@ class _Request:
                                  # cuts and preemption recompute keep
                                  # the two lists aligned by
                                  # construction.
+    reveal_steps: Optional[List[int]] = None
+                                 # a model that decodes by blocks, with
+                                 # ``LLMEngine.record_reveals`` set (a
+                                 # test's seam): for each generated
+                                 # token the forward of its block (0 =
+                                 # the block's first) that revealed it,
+                                 # index-aligned with ``generated``
 
     @property
     def remaining(self) -> int:
@@ -440,6 +467,22 @@ class _Slot:
                                  # the queue front for normal
                                  # admission (local hit or plain
                                  # prefill fallback).
+    # A model that decodes by blocks (``_open_blocks_locked`` sets
+    # these at admission; None/0/empty for every other model):
+    forwards: Optional[int] = None
+                                 # the BOUND on the forwards this
+                                 # admission's blocks cost, against
+                                 # which ``decoded`` counts forwards
+    tail: List[int] = dataclasses.field(default_factory=list)
+                                 # the prompt's remainder past its last
+                                 # whole block: it opens the first
+                                 # generated block (``prompt`` holds the
+                                 # whole blocks, which are prefilled)
+    end: int = 0                 # the last block's end: no forward of
+                                 # this request writes at or past it
+    reveals: List[int] = dataclasses.field(default_factory=list)
+                                 # ``record_reveals``: the bit masks of
+                                 # the open block's forwards so far
 
     @property
     def prefill_remaining(self) -> int:
@@ -602,7 +645,12 @@ class LLMEngine:
         refuse_unsupported(
             self.cfg, prefix_cache=prefix_cache, spec_len=spec_len,
             kv_dtype=kv_dtype == "int8" and kv_dtype,
-            sharding=sharding is not None)
+            sharding=sharding is not None,
+            capture_logprobs=capture_logprobs)
+        # how the model decodes: None (a token a sequence a step), or
+        # the config's BlockDecode (a step is a forward of a block)
+        self._block = block_decode(self.cfg)
+        self.record_reveals = False  # test seam: _Request.reveal_steps
         self._sharding = sharding
         self._mesh = sharding.mesh if sharding is not None else None
         if sharding is not None:
@@ -625,6 +673,15 @@ class LLMEngine:
         self.Pg = page_size
         self.K = chunk
         self.PC = max(1, int(prefill_chunk or 256))
+        if self._block is not None and (
+                page_size % self._block.block_length
+                or self.PC % self._block.block_length):
+            # a prefill chunk starts on a block's edge, and a block
+            # lies within one page
+            raise ValueError(
+                f"page_size={page_size} and prefill_chunk={self.PC} must "
+                f"be whole multiples of the model's block_length "
+                f"{self._block.block_length}")
         self.temperature = temperature
         self.eos_id = eos_id
         # Run-ahead ceiling: one dispatch may decode up to this many
@@ -761,6 +818,12 @@ class LLMEngine:
         # host readbacks trail for emission only.
         self._dev_cur = self._h2d(jnp.zeros((max_slots,), jnp.int32))
         self._dev_pos = self._h2d(jnp.zeros((max_slots,), jnp.int32))
+        # a block program's state a slot, in their place
+        # (serve/step_programs.py ``block_state``)
+        self._dev_blocks = (
+            None if self._block is None else jax.tree_util.tree_map(
+                self._h2d, block_state(max_slots,
+                                       self._block.block_length)))
         _clk.mark("decode_state")
         # Without an eos the schedule is fully deterministic: slots
         # retire by arithmetic at dispatch time and host syncs never
@@ -847,10 +910,16 @@ class LLMEngine:
         # the TTFT EWMA above
         self._itl_ewma: Optional[float] = None
         self._itl_ewma_alpha = 0.2
-        self._decode_fn = self._track_program(_jit_decode(
-            self.model, self.temperature, self.KMAX, self.S,
-            self.capture_logprobs, self._mesh))
-        self._seed_fn = self._track_program(_jit_seed())
+        if self._block is None:
+            self._decode_fn = self._track_program(_jit_decode(
+                self.model, self.temperature, self.KMAX, self.S,
+                self.capture_logprobs, self._mesh))
+            self._seed_fn = self._track_program(_jit_seed())
+        else:
+            self._decode_fn = self._track_program(_jit_decode_blocks(
+                self.model, self.temperature, self.KMAX, self.S,
+                self.eos_id, self._mesh))
+            self._seed_fn = self._track_program(_jit_seed_blocks())
         _clk.mark("programs")
         # a replica's start is this event and the ``compile`` events
         # up to its first round that builds nothing
@@ -932,7 +1001,8 @@ class LLMEngine:
             self._drain_fetches_locked()
             # the seed scatter and the pool trail the last readback
             jax.block_until_ready(
-                (self.pages, self._dev_cur, self._dev_pos))
+                (self.pages, self._dev_cur, self._dev_pos,
+                 self._dev_blocks))
             t0 = profiling.start_device_trace(log_dir)
             self.events.append("trace_start", t=t0, data={
                 **self._dispatch_counts_locked(), "log_dir": log_dir,
@@ -1012,6 +1082,10 @@ class LLMEngine:
                 f"unknown priority {priority!r}; expected "
                 f"'{LANE_ONLINE}' or '{LANE_BATCH}'")
         total = len(prompt_ids) + max_new_tokens
+        if self._block is not None:
+            # the last block is written whole
+            L = self._block.block_length
+            total = -(-total // L) * L
         need = -(-total // self.Pg)
         if need > self.alloc.n_pages - 1:
             raise RequestError(
@@ -1026,6 +1100,8 @@ class LLMEngine:
                        pull=pull, batch=(priority == LANE_BATCH))
         if self.capture_logprobs:
             req.logprobs = []
+        if self.record_reveals and self._block is not None:
+            req.reveal_steps = []
         if deadline_s is not None:
             req.deadline = req.t_submit + deadline_s
         self.events.append("submit", rid=req.rid, t=req.t_submit,
@@ -2066,18 +2142,28 @@ class LLMEngine:
                 s.spec_pending = [int(t) for t in s.spec.propose(room)]
 
     def _owed(self, slot: _Slot) -> int:
-        """Decode steps this slot still needs, by dispatch-time
+        """Decode STEPS this slot still needs, by dispatch-time
         arithmetic: the prefill emits token 1 of max_new_tokens, every
-        ridden step emits one more. Runs AHEAD of emission (which
+        ridden step emits one more token. Runs AHEAD of emission (which
         trails with the readbacks) — with an eos the true need may be
-        less; emission then closes the request early."""
+        less; emission then closes the request early. Of a model that
+        decodes by blocks a step is a forward and this is a BOUND: the
+        forwards its blocks cost at the most (``_Slot.forwards``) less
+        those dispatched; exact under the schedules that reveal a fixed
+        count a step, and under ``low_confidence_dynamic`` what a slot
+        that finishes its blocks early leaves unused."""
+        if slot.forwards is not None:
+            return slot.forwards - slot.decoded
         return slot.req.max_new_tokens - 1 - slot.decoded
 
     def _retire_planned_locked(self):
-        """No-eos mode: free slots whose budget the dispatch just
-        consumed — their tokens are still in flight (emission trails)
-        but the SCHEDULE is deterministic, so the pages and the slot
-        go back to the pool without waiting for a readback."""
+        """No-eos mode: free slots whose budget of STEPS the dispatch
+        just consumed — their tokens are still in flight (emission
+        trails) but the SCHEDULE is deterministic, so the pages and the
+        slot go back to the pool without waiting for a readback. (A
+        block-decoding slot's budget is its bound in forwards: a slot
+        that finished earlier was freed at the readback that showed its
+        last block, ``_emit_to``.)"""
         for i, slot in enumerate(self.slots):
             if (slot is not None and slot.cur is not None
                     and self._owed(slot) <= 0):
@@ -2341,6 +2427,8 @@ class LLMEngine:
                          decoded=len(req.generated),
                          shared=len(shared_pages))
             self.slots[free[0]] = slot
+            if self._block is not None:
+                self._open_blocks_locked(free[0], slot)
             self.stats["admitted"] += 1
             _now = time.monotonic()
             self.events.append("admit", rid=req.rid, sid=free[0],
@@ -2361,6 +2449,71 @@ class LLMEngine:
                     self.stats["cache_hit_admissions"] += 1
                     self.events.append("cache_hit", rid=req.rid,
                                        sid=free[0], data=start)
+
+    # ------------------------------------ a model that decodes by blocks
+
+    def _open_blocks_locked(self, ix: int, slot: _Slot) -> None:
+        """Admission of a request to a model that decodes by blocks:
+        split the (recompute) prompt at its last whole block, set the
+        books in blocks and forwards, and seed at once a slot whose
+        prompt has no whole block to prefill. The prefill calls run the
+        whole blocks; the remainder opens the first generated block
+        beside masks. A re-admission after a preemption or a fault
+        re-prefills ``prompt + emitted tokens`` the same way: past the
+        first commit that is a whole number of blocks, and its K/V are
+        the commits' (same mask, same tokens)."""
+        bd = self._block
+        L = bd.block_length
+        whole = len(slot.prompt) // L * L
+        slot.prompt, slot.tail = slot.prompt[:whole], slot.prompt[whole:]
+        lead = len(slot.tail)
+        blocks = -(-(lead + slot.req.remaining) // L)
+        slot.end = whole + blocks * L
+        slot.forwards = (bd.forwards(L - lead)
+                         + (blocks - 1) * bd.forwards(L))
+        slot.decoded = 0             # forwards of THIS admission
+        if slot.prefill_remaining == 0:
+            self._seed_blocks_locked([(ix, slot)])
+
+    def _seed_blocks_locked(self, rows) -> None:
+        """Seed the device's block state (``_jit_seed_blocks``) for
+        ``rows`` [(slot index, slot)] whose whole prompt blocks are in
+        their pages (at most a prefill call's rows): on-stream, no host
+        sync; the slots ride the very next decode dispatch."""
+        B, L = self._max_prefill_batch, self._block.block_length
+        ixs = np.full((B,), self.S, np.int32)   # S = dropped row
+        pos, left, lead = (np.zeros((B,), np.int32) for _ in range(3))
+        blk = np.zeros((B, L), np.int32)
+        for r, (ix, slot) in enumerate(rows):
+            ixs[r], pos[r] = ix, len(slot.prompt)
+            left[r], lead[r] = slot.req.remaining, len(slot.tail)
+            blk[r, :lead[r]] = slot.tail
+            slot.pos = len(slot.prompt)
+            slot.cur = -1      # device-seeded: ridable
+        self._dev_blocks = self._seed_fn(
+            self._dev_blocks, *(self._h2d(a) for a in (
+                ixs, pos, left, blk, lead)))
+
+    def _most_committed(self, steps: int) -> int:
+        """Positions the blocks ``steps`` forwards can commit at the
+        most cover: a block costs at least two forwards (one that
+        reveals, one that commits), and a dispatch may open on a
+        commit."""
+        return -(-steps // 2) * self._block.block_length
+
+    def _write_end(self, slot: _Slot, steps: int) -> int:
+        """The position below which a decode dispatch of ``steps`` steps
+        writes this slot's pages, from the host's ``slot.pos``: a token
+        a step; or, of a model that decodes by blocks, whose ``pos`` is
+        the host's bound on the block's start, the end of the furthest
+        block ``steps`` forwards can reach (a block costs at least two
+        forwards, so they commit at most ``ceil(steps / 2)`` blocks and
+        the last writes at most ``steps // 2`` blocks on), and never
+        past the request's last block."""
+        if self._block is None:
+            return slot.pos + steps
+        return min(slot.pos + (steps // 2 + 1) * self._block.block_length,
+                   slot.end)
 
     # -------------------------------------------- KV migration (pull)
 
@@ -2611,7 +2764,7 @@ class LLMEngine:
                 continue        # not riding this dispatch (seed not
                                 # yet scattered): writes nothing
             eff = min(steps, max(1, self._owed(slot)))
-            need = -(-(slot.pos + eff) // self.Pg)
+            need = -(-self._write_end(slot, eff) // self.Pg)
             while len(slot.pages) < need:
                 if self.slots[i] is not slot:
                     # a preemption's drain closed THIS slot (eos /
@@ -2739,20 +2892,33 @@ class LLMEngine:
             # prefill growth — an empty dispatch would decode junk —
             # or the engine was force-killed mid-loop (zombie fence)
             return
-        (toks, self.pages, self._rng, self._dev_pos,
-         self._dev_cur, *moe) = self._decode_fn(
-            self.params, self.pages, self._h2d(pt),
-            self._dev_pos, self._dev_cur, self._rng,
-            self._h2d(jnp.int32(steps)))
+        if self._block is None:
+            (toks, self.pages, self._rng, self._dev_pos,
+             self._dev_cur, *moe) = self._decode_fn(
+                self.params, self.pages, self._h2d(pt),
+                self._dev_pos, self._dev_cur, self._rng,
+                self._h2d(jnp.int32(steps)))
+            moved = steps
+        else:
+            # steps are forwards; toks: (tokens, counts, reveal masks,
+            # the dispatch's counters), read back together
+            (toks, self.pages, self._rng, self._dev_blocks,
+             *moe) = self._decode_fn(
+                self.params, self.pages, self._h2d(pt),
+                self._dev_blocks, self._rng,
+                self._h2d(jnp.int32(steps)))
+            # the bound on the block's start moves by the most blocks
+            # the dispatch can commit; the readback puts it right
+            moved = self._most_committed(steps)
         self._moe_pending.extend((v, True) for v in moe)
         # host mirrors advance NOW; emission trails
         for _i, slot, _t in riders:
-            slot.pos += steps
+            slot.pos += moved
             slot.decoded += steps
         self._fetchq.append((toks, riders, steps))
         # slot.pos already counts this dispatch
         self.accounts.note_decode(
-            [slot.pos for _i, slot, _t in riders], steps)
+            [self._write_end(slot, 0) for _i, slot, _t in riders], steps)
         self.events.append("decode", data=steps)
         self.stats["chunks"] += 1
         self.stats["decode_steps"] += steps
@@ -3022,6 +3188,9 @@ class LLMEngine:
                                   lps=(None if f_lps is None
                                        else [float(f_lps[row])]))
             for (_buf, riders, _steps), toks in zip(batch, vals):
+                if self._block is not None:
+                    self._emit_blocks_locked(riders, _steps, *toks)
+                    continue
                 lp_buf = None
                 if isinstance(toks, tuple):     # logprob capture
                     toks, lp_buf = toks
@@ -3037,6 +3206,50 @@ class LLMEngine:
                     self._emit_to(slot.req, toks[:take, i].tolist(), i,
                                   lps=(None if lp_buf is None
                                        else lp_buf[:take, i].tolist()))
+
+    def _emit_blocks_locked(self, riders, steps: int, buf, cnt, rev,
+                            tally) -> None:
+        """One decode dispatch of a model that decodes by blocks, read
+        back: of each rider's forwards those with a count were commits,
+        and their blocks' tokens go to the client together (``buf``
+        [KMAX, S, L] from column 0, ``cnt`` [KMAX, S]); the host's bound
+        on the block's start gives back the blocks the dispatch could
+        have committed and did not. ``rev`` [KMAX, S] (the positions
+        each forward revealed) is read only under ``record_reveals``."""
+        self.accounts.fold_blocks(tally)
+        L = self._block.block_length
+        for i, slot, take in riders:
+            if slot.preempted:
+                continue    # recomputed from scratch
+            try:
+                self._fire("readback", sid=i, rid=slot.req.rid)
+            except EngineFault as e:
+                self._fail_rider_locked(i, slot, e.original)
+                continue
+            commits = np.flatnonzero(cnt[:take, i])
+            slot.pos -= self._most_committed(steps) - len(commits) * L
+            req = slot.req
+            if req.reveal_steps is None:
+                for f in commits:
+                    self._emit_to(req, buf[f, i, :cnt[f, i]].tolist(), i)
+                continue
+            for f in range(take):
+                if rev[f, i]:
+                    slot.reveals.append(int(rev[f, i]))
+                if not cnt[f, i]:
+                    continue
+                # the block's generated positions follow its prompt
+                # remainder (the admission's first block alone has
+                # one): each one's forward is the one whose mask holds
+                # its bit
+                lead, slot.tail = len(slot.tail), []
+                req.reveal_steps.extend(
+                    next(n for n, m in enumerate(slot.reveals)
+                         if m >> p & 1)
+                    for p in range(lead, lead + int(cnt[f, i])))
+                slot.reveals = []
+                self._emit_to(req, buf[f, i, :cnt[f, i]].tolist(), i)
+                del req.reveal_steps[len(req.generated):]
 
     def _collect_moe_locked(self) -> None:
         """Hand the accounts the counters of every dispatch that has
@@ -3201,16 +3414,26 @@ class LLMEngine:
         # prompt WITHOUT a host sync: scatter firsts/positions into
         # dev_cur/dev_pos rows on-stream, after which the slots ride
         # the very next decode dispatch.
-        ixs = np.full((B,), self.S, np.int32)   # S = dropped row
-        rws = np.zeros((B,), np.int32)
-        posv = np.zeros((B,), np.int32)
-        for r, (ix, slot, row) in enumerate(placements):
-            ixs[r], rws[r], posv[r] = ix, row, slot.pos
-        self._dev_cur, self._dev_pos = self._seed_fn(
-            self._dev_cur, self._dev_pos, firsts,
-            self._h2d(ixs), self._h2d(rws), self._h2d(posv))
-        for ix, slot, _row in placements:
-            slot.cur = -1      # device-seeded: ridable
+        if self._block is not None:
+            # no first token from prefill: the call's sample is not
+            # read, the finished rows' block state is seeded, and
+            # nothing of the call is emitted (its readback still syncs
+            # drains and preemption barriers on it)
+            self._seed_blocks_locked(
+                [(ix, slot) for ix, slot, _row in placements])
+            n_done, placements = len(placements), []
+        else:
+            ixs = np.full((B,), self.S, np.int32)   # S = dropped row
+            rws = np.zeros((B,), np.int32)
+            posv = np.zeros((B,), np.int32)
+            for r, (ix, slot, row) in enumerate(placements):
+                ixs[r], rws[r], posv[r] = ix, row, slot.pos
+            self._dev_cur, self._dev_pos = self._seed_fn(
+                self._dev_cur, self._dev_pos, firsts,
+                self._h2d(ixs), self._h2d(rws), self._h2d(posv))
+            for ix, slot, _row in placements:
+                slot.cur = -1      # device-seeded: ridable
+            n_done = len(placements)
         # firsts also stays on device for EMISSION: its readback
         # rides the next trailing sync, so prefill never stalls the
         # decode stream on a host RTT. Queued even with no finished
@@ -3224,7 +3447,7 @@ class LLMEngine:
         self.stats["prefills"] += 1
         self.accounts.note_prefill(
             start[:len(rows)], sum(take for _ix, _s, take in rows), B, T)
-        self.stats["prefilled_seqs"] += len(placements)
+        self.stats["prefilled_seqs"] += n_done
         self._hb = time.monotonic()   # dispatch completed: a long
                                       # prompt prefilling chunk by
                                       # chunk is moving, not wedged

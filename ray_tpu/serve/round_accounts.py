@@ -23,7 +23,7 @@ import numpy as np
 
 from ray_tpu.models.kv_cache import (KIND_BORROWED, KIND_KV, KIND_LATENT,
                                      KIND_SLIDING, RecurrentState,
-                                     SlidingRing,
+                                     SlidingRing, block_decode,
                                      has_latent_pages,
                                      kv_query_heads, latent_page_width,
                                      layer_kinds, page_layout,
@@ -35,7 +35,7 @@ from ray_tpu.ops import paged_decode_attention as paged_decode
 from ray_tpu.ops import ring_window_attention as ring_window
 from ray_tpu.ops import selective_scan
 from ray_tpu.ops.paged_attention import paged_window_block_pages
-from ray_tpu.serve.step_programs import ambient_mesh
+from ray_tpu.serve.step_programs import BLOCK_COUNTERS, ambient_mesh
 
 
 def _new_round_info() -> Dict[str, int]:
@@ -59,6 +59,11 @@ def _new_round_info() -> Dict[str, int]:
     ``sampled_only_from``; models/llama.py ``transformer_forward``
     narrows the call before the first of them), 0 for a model whose
     last layer keeps one, and without a call.
+    ``decode_steps`` counts FORWARDS: of a model that decodes by blocks
+    (models/kv_cache.py ``BlockDecode``) a step yields no token or a
+    whole block's, and the ``denoise_*`` counters ``take`` adds say
+    which (``fold_blocks``); its riders' positions below are the host's
+    BOUND on each block's end, not a reading.
     ``decode_context_tokens`` is the sum over the decode dispatch's
     riders of their OWN context lengths after it (what each rider's
     last step attended), where ``decode_window_tokens`` is the longest
@@ -127,6 +132,10 @@ class RoundAccounts:
         # only (() = nothing is returned, queued or reported), and each
         # section's running totals of its head, for ``load_report``
         self.sections = stats_sections(cfg)
+        # a model that decodes by blocks: its decode program's own
+        # counters (serve/step_programs.py BLOCK_COUNTERS), and the
+        # queries a row its decode step asks a kernel's rule about
+        self.block = block_decode(cfg)
         self.heads = [np.zeros((s.head,), np.int64) for s in self.sections]
         self.unreported = self._new_counts()
         self.begin_round()
@@ -149,9 +158,12 @@ class RoundAccounts:
         """The sections' counters as the ``round`` event reports them
         (docs/serving.md): each under its section's prefix, and the
         decode dispatches' part under ``decode_`` behind it."""
-        return {prefix + key: 0 for s in self.sections
-                for prefix in (s.prefix, s.prefix + "decode_")
-                for key in s.names}
+        counts = {prefix + key: 0 for s in self.sections
+                  for prefix in (s.prefix, s.prefix + "decode_")
+                  for key in s.names}
+        if self.block is not None:
+            counts.update(("denoise_" + key, 0) for key in BLOCK_COUNTERS)
+        return counts
 
     def fold(self, vectors, decode) -> None:
         """Add the host copies ``vectors`` of finished dispatches'
@@ -168,6 +180,15 @@ class RoundAccounts:
                     if is_decode:
                         self.unreported[s.prefix + "decode_" + key] += value
 
+    def fold_blocks(self, tally) -> None:
+        """Add the host copy of a block program's ``BLOCK_COUNTERS``
+        (one finished decode dispatch's, read with its tokens) to what
+        the next ``round`` event reports as ``denoise_*``: forwards,
+        commits, positions revealed, tokens emitted and forwards idled
+        over the dispatches read back since the last event."""
+        for key, n in zip(BLOCK_COUNTERS, tally):
+            self.unreported["denoise_" + key] += int(n)
+
     def take(self) -> Dict[str, int]:
         """The counters gathered since the last ``round`` event, for
         this one: those of the dispatches whose results were read back
@@ -180,9 +201,17 @@ class RoundAccounts:
 
     def load_report(self) -> Dict[str, Any]:
         """What the sections that keep a head report of its running
-        totals (a mixture's routing so far). Nothing for a dense model."""
-        return {k: v for s, head in zip(self.sections, self.heads)
-                if s.head for k, v in s.load_report(head).items()}
+        totals (a mixture's routing so far), and a block program's
+        counters. Nothing for a dense model that yields a token a step."""
+        out = {k: v for s, head in zip(self.sections, self.heads)
+               if s.head for k, v in s.load_report(head).items()}
+        if self.block is not None:
+            # a model that decodes by blocks: its block, and the block
+            # program's counters so far (a step is a forward)
+            out["block_length"] = self.block.block_length
+            out.update(("denoise_" + key, self.stats.get(
+                "denoise_" + key, 0)) for key in BLOCK_COUNTERS)
+        return out
 
     # ------------------------- what the host knows a dispatch read
 
@@ -316,7 +345,9 @@ class RoundAccounts:
         # stored, and so asked, as 32)
         heads = (cfg.n_heads if latent else k.shape[-2] * (
             kv_query_heads(cfg, KIND_KV) // cfg.n_kv_heads))
-        q = jax.ShapeDtypeStruct((self.S, 1, heads, k.shape[-1]),
+        # (a block program's step asks with a whole block a row)
+        T = 1 if self.block is None else self.block.block_length
+        q = jax.ShapeDtypeStruct((self.S, T, heads, k.shape[-1]),
                                  cfg.dtype)
         table = jax.ShapeDtypeStruct((self.S, self.max_pages), jnp.int32)
         with ambient_mesh(self.mesh):

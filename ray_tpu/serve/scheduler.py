@@ -41,7 +41,10 @@ Policy, in order:
   prefill grant this round) the cadence stays at ``decode_chunk`` so
   new arrivals join promptly and prefill chunks interleave; with a
   full, fully-seeded batch the plan runs ahead to the next completion
-  event (min owed over riders) exactly as before. With an eos the
+  event (min owed over riders) exactly as before (a step is whatever
+  the engine's decode program makes of it: a token a rider, or a
+  forward of a rider's block for a model that decodes by blocks, whose
+  ``owed`` is a bound in forwards). With an eos the
   run-ahead is bounded — tokens past an unpredicted eos are wasted.
   Under the engine's OVERLAPPED loop the views may trail the device
   frontier (``SlotView.stale``: dispatched-but-undrained steps); any
@@ -226,7 +229,18 @@ class SlotView:
     sid: int                 # slot index
     admit_seq: int           # admission order (FIFO fairness)
     prompt_remaining: int    # prompt tokens not yet prefilled
-    owed: int                # decode steps still owed (seeded slots)
+    owed: int                # decode steps still owed (seeded slots).
+                             # Steps, not tokens: of a model that
+                             # decodes by blocks (models/kv_cache.py
+                             # BlockDecode) a step is a forward, and
+                             # this is the engine's BOUND, blocks left
+                             # x (denoising steps + 1) less the
+                             # forwards dispatched; a slot that
+                             # finishes its blocks earlier is retired
+                             # by the engine at the readback that
+                             # shows it, and the run-ahead and backlog
+                             # rules below read the bound as they read
+                             # any other slot's steps
     seeded: bool             # riding decode dispatches already
     spec_drafts: int = 0     # draft tokens proposed this round
                              # (prompt-lookup, serve/spec_decode.py)

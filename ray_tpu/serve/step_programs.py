@@ -23,7 +23,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.kv_cache import kv_layer_store, kv_layer_view
+from ray_tpu.models.kv_cache import (block_decode, kv_layer_store,
+                                     kv_layer_view)
 from ray_tpu.models.mixtral import stats_sections
 
 
@@ -46,7 +47,10 @@ def _moe_apply(model, mesh):
     ``stats_sections``: a mixture's routing, a selection's entries)
     the third result is (the int32 vector,) of its sections' counts
     over the program's live tokens, each reduced from the collection
-    its layers sow into (``live()`` gives the [B, T] mask) and
+    its layers sow into (``live()`` gives the [B, T] mask: a prefill
+    row's real positions, a riding slot's one position of a decode
+    step or, in the block program, the whole block of a slot that is
+    still owed tokens) and
     concatenated in the sections' order; for any other model it is ().
     ``logits_at`` [B]: the one
     position of each row the program wants logits for, ``[B, V]``
@@ -75,7 +79,9 @@ def _views(pages, page_table, live, slots=None):
     (models/kv_cache.py kv_layer_view): a paged layer over the call's
     page table, a recurrent layer over the rows' ``slots`` (None: row
     i is slot i) and the [B, T] positions ``live()`` gives (nothing
-    calls it for a model with pages only). kv_layer_view/store
+    calls it for a model with pages only; the block program nulls the
+    table's rows of slots that are owed nothing instead, so that they
+    write no page). kv_layer_view/store
     keep the builders kind- and dtype-agnostic: fp layers are
     (pk, pv), int8 layers (pk, pv, sk, sv) — the scales ride the same
     donated tuple through the step."""
@@ -257,6 +263,188 @@ def _jit_decode(model, temp, KMAX, S, capture, mesh):
         return (out, pages, key, pos, cur) + tuple(moe)  # buf: [KMAX, S]
 
     return jax.jit(decode, donate_argnums=(1, 3, 4))
+
+
+# The int32 counters a block program sums over a dispatch, in the
+# order of its vector (serve/round_accounts.py reports each under
+# ``denoise_`` in the ``round`` event): forwards ridden by a slot that
+# was still owed tokens; those of them that were commits; positions
+# revealed; tokens emitted (a committed block's, less its prompt
+# remainder, cut at the request's budget and at an eos); forwards a
+# slot rode AFTER its last commit (a dispatch planned on the bound
+# outlasts a slot that finished early: it idles, writes nothing and is
+# counted here).
+BLOCK_COUNTERS = ("rider_forwards", "commits", "revealed", "emitted",
+                  "idle_forwards")
+
+
+def block_state(S: int, L: int):
+    """The device-authoritative state a slot of a block program, zeros:
+    (``pos`` [S] the block's start, ``left`` [S] tokens the request is
+    still owed, ``blk`` [S, L] the block's tokens, ``masked`` [S, L] its
+    flags, ``lead`` [S] the leading positions of the block that are the
+    prompt's remainder and not generated, ``step`` [S] the denoising
+    steps the block has had). Donated and chained dispatch to dispatch
+    as ``pos``/``cur`` are."""
+    z = functools.partial(jnp.zeros, (S,), jnp.int32)
+    return (z(), z(), jnp.zeros((S, L), jnp.int32),
+            jnp.zeros((S, L), jnp.bool_), z(), z())
+
+
+@functools.lru_cache(maxsize=64)
+def _jit_decode_blocks(model, temp, KMAX, S, eos_id, mesh):
+    """The decode program of a model that DECODES BY BLOCKS
+    (models/kv_cache.py ``BlockDecode``), built where ``_jit_decode``
+    is and chosen by what the config says; an engine builds one of the
+    two, and in a device trace this one is ``jit_decode`` too.
+
+    A step of the runtime-bound ``fori_loop`` is a FORWARD of every
+    slot's block ``[S, L]`` at the block's start ``pos`` over the
+    slot's pages under the block mask (a masked position's input is
+    the mask token's). Per slot, by its own flags: no flag set, and
+    this forward WAS the commit (it wrote the finished block's K/V):
+    the block's generated tokens go to the step's row of the output
+    ``[KMAX, S, L]`` with their count (the block's leading prompt
+    remainder is not counted, the count is cut at ``left`` and at an
+    ``eos_id``, which ends the request), ``pos += L``, ``left -=
+    count`` and the next block opens all masked. Else the forward's
+    logits AT the masked positions choose tokens (argmax, or
+    ``_pick_token`` at a temperature) with their confidence (the chosen
+    token's probability, float32) and the config's ``remasking``
+    reveals some: ``sequential`` the first n_s masked,
+    ``low_confidence_static`` the n_s most confident,
+    ``low_confidence_dynamic`` every one above the threshold if those
+    are at least n_s, else the n_s most confident (n_s:
+    ``BlockDecode.transfer_counts`` at the block's step; ties go to
+    the lower position). Revealed tokens stay. A slot with ``left ==
+    0`` or a null page-table row is not live: its row of the table is
+    nulled for the forward, so it writes no page, moves nothing, is
+    given no expert and its routing is not counted.
+
+    Returns ((tokens [KMAX, S, L], counts [KMAX, S], the positions
+    each forward revealed as a bit mask [KMAX, S], ``BLOCK_COUNTERS``
+    summed over the dispatch), pages, key, state) and the model's own
+    counter vector, as ``_jit_decode`` does."""
+    constrain = _constrain_for(mesh)
+    apply = _moe_apply(model, mesh)
+    moe_len = sum(len(s) for s in stats_sections(model.config))
+    bd = block_decode(model.config)
+    L = bd.block_length
+    if L > 30:
+        raise ValueError(f"a block of {L} positions does not fit the "
+                         f"reveal mask's 30 bits")
+    from ray_tpu.models.llama import _pick_token
+
+    def reveal_of(masked, conf, step):
+        """[S, L] bool: the masked positions this forward reveals."""
+        n_s = jnp.asarray(bd.transfer_counts(), jnp.int32)[
+            jnp.minimum(step, bd.denoising_steps - 1)][:, None]
+        at = jnp.arange(L)
+        if bd.remasking == "sequential":
+            rank = jnp.cumsum(masked, axis=1) - 1
+            return masked & (rank < n_s)
+        c = jnp.where(masked, conf, -jnp.inf)
+        # a position's rank among the masked: those more confident, and
+        # of the equally confident those before it
+        ahead = (c[:, None, :] > c[:, :, None]) | (
+            (c[:, None, :] == c[:, :, None])
+            & (at[None, None, :] < at[None, :, None]))
+        rank = jnp.sum(ahead & masked[:, None, :], axis=-1)
+        top = masked & (rank < n_s)
+        if bd.remasking == "low_confidence_static":
+            return top
+        high = masked & (conf > bd.confidence_threshold)
+        enough = jnp.sum(high, axis=1, keepdims=True) >= n_s
+        return jnp.where(enough, high, top)
+
+    def decode(params, pages, page_table, state, rng, steps):
+        buf0 = jnp.zeros((KMAX, S, L), jnp.int32)
+        cnt0 = jnp.zeros((KMAX, S), jnp.int32)
+        tally0 = jnp.zeros((len(BLOCK_COUNTERS),), jnp.int32)
+        moe0 = (jnp.zeros((moe_len,), jnp.int32),) if moe_len else ()
+        riding = page_table[:, 0] != 0
+        at = jnp.arange(L)[None]
+
+        def body(i, carry):
+            pages, (pos, left, blk, masked, lead, step), key, \
+                buf, cnt, rev, tally, *moe = carry
+            key, sub = jax.random.split(key)
+            live_s = riding & (left > 0)
+            # a slot that is owed nothing rides as a free one does
+            table = jnp.where(live_s[:, None], page_table, 0)
+
+            def live():
+                return jnp.broadcast_to(live_s[:, None], (S, L))
+            ids = jnp.where(masked, bd.mask_token_id, blk)
+            logits, new_kv, vec = apply(
+                params, ids, _views(pages, table, live), pos, live)
+            moe = tuple(m + v for m, v in zip(moe, vec))
+            with jax.named_scope("sample"):
+                # (float32 from the head) what _pick_token samples from
+                lg = logits / temp if temp > 0.0 else logits
+                chosen = _pick_token(logits, sub, temp)         # [S, L]
+                conf = jnp.exp(jnp.take_along_axis(
+                    lg, chosen[..., None], axis=-1)[..., 0]
+                    - jax.nn.logsumexp(lg, axis=-1))
+                commit = live_s & ~jnp.any(masked, axis=1)
+                reveal = reveal_of(masked, conf, step) & \
+                    live_s[:, None]
+                blk = jnp.where(reveal, chosen, blk)
+                masked = masked & ~reveal
+                # the committed block's generated tokens, from column 0
+                row = jnp.take_along_axis(
+                    blk, (at + lead[:, None]) % L, axis=1)
+                count = jnp.minimum(L - lead, left)
+                done = jnp.zeros((S,), jnp.bool_)
+                if eos_id is not None:
+                    hit = (row == eos_id) & (at < count[:, None])
+                    done = jnp.any(hit, axis=1)
+                    count = jnp.where(done, jnp.argmax(hit, axis=1) + 1,
+                                      count)
+                count = jnp.where(commit, count, 0)
+                buf = buf.at[i].set(jnp.where(commit[:, None], row, 0))
+                cnt = cnt.at[i].set(count)
+                rev = rev.at[i].set(jnp.sum(
+                    reveal.astype(jnp.int32) << at, axis=1))
+                tally = tally + jnp.stack([
+                    jnp.sum(live_s), jnp.sum(commit), jnp.sum(reveal),
+                    jnp.sum(count), jnp.sum(riding & ~live_s)]
+                ).astype(jnp.int32)
+                pos = jnp.where(commit, pos + L, pos)
+                left = jnp.where(commit & done, 0, left - count)
+                masked = masked | commit[:, None]
+                lead = jnp.where(commit, 0, lead)
+                step = jnp.where(commit, 0, step + live_s)
+            new_pages = constrain(
+                [kv_layer_store(c) for c in new_kv])
+            return (new_pages, (pos, left, blk, masked, lead, step), key,
+                    buf, cnt, rev, tally) + moe
+        pages, state, key, buf, cnt, rev, tally, *moe = \
+            jax.lax.fori_loop(
+                0, steps, body,
+                (pages, state, rng, buf0, cnt0, cnt0, tally0) + moe0)
+        return ((buf, cnt, rev, tally), pages, key, state) + tuple(moe)
+
+    return jax.jit(decode, donate_argnums=(1, 3))
+
+
+@functools.lru_cache(maxsize=64)
+def _jit_seed_blocks():
+    """``_jit_seed`` for a block program: scatter admitted rows' state
+    into the device decode state (``block_state``): the block's start,
+    the tokens owed, and the first block, which opens with the prompt's
+    remainder (``lead`` tokens, flags clear) beside masks. Rows padded
+    with ix == S drop. Nothing of the prefill call is read: a model that
+    decodes by blocks has no first token from prefill."""
+    def seed_blocks(state, ixs, pos, left, blk, lead):
+        p, l, b, m, ld, st = state
+        masked = jnp.arange(b.shape[1])[None] >= lead[:, None]
+
+        def put(old, new):
+            return old.at[ixs].set(new, mode="drop")
+        return (put(p, pos), put(l, left), put(b, blk), put(m, masked),
+                put(ld, lead), put(st, jnp.zeros_like(lead)))
+    return jax.jit(seed_blocks, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=64)

@@ -49,6 +49,9 @@ APPENDED = (
         "decode_ssm_ms", "prefill_ssm_share", "ssm_scan_roofline",
         "ssm_prefill_scan_roofline", "decode_shared_attn_ms",
         "shared_attn_roofline", "shared_kv_read_ratio")),
+    Appended(63, "sdar-30b-a3b-chat-d6", "sdar-30b-d6.gen-sat", (
+        "denoise_tokens_per_forward", "denoise_commit_share",
+        "denoise_idle_share", "denoise_attn_ms", "denoise_step_roofline")),
 )
 
 

@@ -1700,3 +1700,86 @@ def test_selective_scan_kernel_compiles(one_chip, T):
         ((N, C), f32), ((B, T, N), bf16), ((B, T, N), bf16), ((C,), f32),
         ((B, N, C), f32), ((B, T), jnp.bool_))
     assert "selective_scan" in compiled.as_text()
+
+
+# -------------------------- a model that decodes by blocks (PR 63): SDAR
+
+SDAR_PAGES, SDAR_SLOTS = 4609, 128
+
+
+def _blocks_step(name, one_chip):
+    """The block program and the prefill program of
+    ``sdar-30b-d6.gen-sat`` (six layers of the published widths, all 128
+    experts, 128 slots over 4,609 pages, a table as wide as the
+    published context) for the described chip."""
+    from ray_tpu.models.kv_cache import init_kv_pool
+    from ray_tpu.models.sdar import Sdar, sdar_30b_a3b
+    from ray_tpu.serve import step_programs
+    S = SDAR_SLOTS
+    cfg = sdar_30b_a3b(n_layers=6, param_dtype=jnp.bfloat16)
+    model = Sdar(cfg)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                           sharding=one_chip), tree)
+    params = placed({"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]})
+    pages = placed(jax.eval_shape(
+        lambda: init_kv_pool(cfg, SDAR_PAGES, PAGE)))
+    i32 = jnp.int32
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    width = cfg.max_seq_len // PAGE
+    if name == "decode":
+        fn = step_programs._jit_decode_blocks(model, 0.0, 128, S, None,
+                                              None)
+        state = placed(jax.eval_shape(
+            lambda: step_programs.block_state(S, cfg.block_length)))
+        rest = [jax.ShapeDtypeStruct((S, width), i32, sharding=one_chip),
+                state, jax.ShapeDtypeStruct(key.shape, key.dtype,
+                                            sharding=one_chip),
+                jax.ShapeDtypeStruct((), i32, sharding=one_chip)]
+    else:
+        fn = step_programs._jit_prefill(model, 0.0, 4, False, None)
+        rest = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in (((4, 256), i32), ((4,), i32), ((4,), i32),
+                             ((4, width), i32), (key.shape, key.dtype))]
+    return cfg, fn.lower(params, pages, *rest).compile()
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_block_step_programs_copy_no_pool_and_fit_the_chip(one_chip, name):
+    cfg, compiled = _blocks_step(name, one_chip)
+    text = compiled.as_text()
+    stored = (SDAR_PAGES, PAGE, 4, 128)
+    assert not _pool_copies(text, stored)
+    # the block loop serves both: T = 4 queries a rider under the block
+    # mask, T = 256 a row; no kernel of the paged layers' (the rule is
+    # read off the chip here, and refuses T > 1 on it)
+    assert "paged_decode" not in text
+    for scope, spec in (("attn_scores", "btkrd,bskd->bkrts"),
+                        ("attn_pv", "bkrts,bskd->bkrtd")):
+        convs = re.findall(
+            r" convolution\([^\n]*layers_\d+/attention/[^\n\"]*"
+            + scope + "/" + spec, text)
+        assert len(convs) == cfg.n_layers, (scope, len(convs))
+    mem = compiled.memory_analysis()
+    pool = cfg.n_layers * 2 * math.prod(stored) * 2          # 3.62 GB
+    weights = 2 * (cfg.n_layers * (
+        128 * 3 * 2048 * 768 + 2 * 2048 * 4096 + 2 * 2048 * 512)
+        + 2 * 151936 * 2048)                                 # 8.72 GB
+    assert mem.alias_size_in_bytes >= pool
+    assert mem.argument_size_in_bytes >= pool + weights
+    # the largest temporaries: a forward's float32 logits [512, V]
+    # (311 MB) and the gathered blocks; nothing of the pool's size
+    temp = mem.temp_size_in_bytes
+    assert temp < (2048 << 20), temp
+    held = (mem.argument_size_in_bytes + temp + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes)
+    assert held < 15.75 * 2 ** 30, held
+    if name == "decode":
+        # the head over a block's rows, never over a chunk's
+        assert "f32[128,4,151936]" in text
+    else:
+        assert "f32[4,256,151936]" not in text

@@ -21,7 +21,7 @@ SERVE = pathlib.Path(__file__).resolve().parent.parent / "ray_tpu" / "serve"
 # the family modules serve/ may import: sampling and the tiny default
 # (llama), the mixture's counters every mixture family shares (mixtral)
 _FAMILY_MODULES = {"axk1", "kimi_linear", "laguna", "mellum", "olmo_hybrid",
-                   "ouro", "phi4flash", "solar_open2"}
+                   "ouro", "phi4flash", "sdar", "solar_open2"}
 
 
 def _trees():
@@ -81,6 +81,7 @@ def _families():
     from ray_tpu.models.olmo_hybrid import OlmoHybrid, olmo_hybrid_tiny
     from ray_tpu.models.ouro import Ouro, ouro_tiny
     from ray_tpu.models.phi4flash import Phi4Flash, phi4flash_tiny
+    from ray_tpu.models.sdar import Sdar, sdar_tiny
     from ray_tpu.models.solar_open2 import SolarOpen2, solar_open2_tiny
     return {"llama": (llama_tiny, Llama, "feed_forward"),
             "mixtral": (mixtral_tiny, Mixtral, "moe/w2"),
@@ -91,11 +92,12 @@ def _families():
             "olmo_hybrid": (olmo_hybrid_tiny, OlmoHybrid, None),
             "ouro": (ouro_tiny, Ouro, None),
             "phi4flash": (phi4flash_tiny, Phi4Flash, None),
+            "sdar": (sdar_tiny, Sdar, None),
             "solar_open2": (solar_open2_tiny, SolarOpen2, None)}
 
 
 FAMILIES = ("llama", "mixtral", "axk1", "kimi_linear", "laguna", "mellum",
-            "olmo_hybrid", "ouro", "phi4flash", "solar_open2")
+            "olmo_hybrid", "ouro", "phi4flash", "sdar", "solar_open2")
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -188,13 +190,17 @@ def test_the_tables_words_reach_the_refusal(kind, option):
 
 
 def test_the_table_is_nineteen_refusals_over_seven_kinds():
+    """And, since PR 63, one row more that is no kind of layer: what a
+    model that decodes by blocks cannot do yet (six refusals)."""
     kinds = {getattr(kv_cache, name) for name in dir(kv_cache)
              if name.startswith("KIND_") and name != "KIND_REFUSALS"}
-    assert kinds == set(kv_cache.KIND_REFUSALS)
+    assert kinds | {kv_cache.DECODES_BY_BLOCKS} == set(
+        kv_cache.KIND_REFUSALS)
     assert {kind: len(why) for kind, (_keeps, why)
             in kv_cache.KIND_REFUSALS.items()} == {
         KIND_KV: 0, KIND_RECURRENT: 4, KIND_LATENT: 3, KIND_INDEXED: 3,
-        KIND_SLIDING: 5, KIND_BORROWED: 3, KIND_STATELESS: 1}
+        KIND_SLIDING: 5, KIND_BORROWED: 3, KIND_STATELESS: 1,
+        kv_cache.DECODES_BY_BLOCKS: 6}
 
 
 def test_pages_of_keys_and_values_are_refused_nothing():
