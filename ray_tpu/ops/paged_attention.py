@@ -42,7 +42,10 @@ the layout:
   HBM, held the two contractions at a third of the matrix unit; one
   Pallas call keeps them in VMEM and walks each row to its own last
   block (PERF.md section 6, PR 43). The DECODE KERNEL
-  (ops/paged_decode_attention.py): a DECODE STEP (one query a row) over
+  (ops/paged_decode_attention.py): a DECODE STEP (one query a row, or
+  the few of a block of a model that decodes by blocks under its
+  block-causal mask: as many as fit a visit's scores against one
+  page) over
   a bfloat16 K/V pool on one TPU, where the bytes fetched are all the
   step costs and the loop gathers a whole 512-token block for every
   row up to the longest rider's context (67 MB a layer where 40 are
@@ -348,8 +351,9 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
     among them, and of every block before (a model that decodes by
     blocks, models/kv_cache.py ``BlockDecode``: the caller has appended
     the whole block). 1, every other model's: the causal mask, and the
-    traced program is what it was before the argument existed. Only the
-    loop serves ``block_len > 1``: both kernels mask causally.
+    traced program is what it was before the argument existed. The loop
+    and the decode kernel serve ``block_len > 1``; the latent kernel
+    masks causally.
 
     ``pv`` None (a pool of latent pages ``pk`` [n_pages, Pg, D], one
     KV head with no axis of its own): a key's value is the first
@@ -362,8 +366,10 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
     TPU outside any multi-device mesh, is ONE Pallas kernel
     (``latent_window.applies``; ops/latent_window_attention.py: the
     scores and the accumulator in VMEM, each row walked to its own last
-    block). A decode step (``T == 1``) over a bfloat16 K/V pool without
-    int8 scales or over a bfloat16 latent pool, there too, is ANOTHER
+    block). A decode step over a bfloat16 K/V pool without int8 scales
+    (one query a row, or under the block mask as many as fit a visit's
+    scores: a block of ``block_len`` positions) or over a bfloat16
+    latent pool (one query a row), there too, is ANOTHER
     (``paged_decode.applies``; ops/paged_decode_attention.py: each
     rider's own pages read once, where they lie, a row without a rider
     not at all, and zeros read out for it). Both are the same
@@ -422,14 +428,14 @@ def _paged_window_attention(q, pk, pv, sk, sv, page_table, pos,
                 softmax_scale=(D ** -0.5 if softmax_scale is None
                                else softmax_scale),
                 block_pages=block_pages)
-    if causal and paged_decode.applies(q, pk, pv, sk, page_table,
-                                       value_dim):
+    if paged_decode.applies(q, pk, pv, sk, page_table, value_dim,
+                            block_len):
         with jax.named_scope("attn_scores"):
             return paged_decode.paged_decode_attention(
                 q, pk, pv, page_table, pos,
                 softmax_scale=float(D ** -0.5 if softmax_scale is None
                                     else softmax_scale),
-                value_dim=value_dim)
+                value_dim=value_dim, block_len=block_len)
     # Grouped-query attention WITHOUT materializing repeated K/V: q
     # reshapes to [B, T, KH, rep, D] and contracts against the grouped
     # cache directly (a repeat would move rep x the KV bytes a step).

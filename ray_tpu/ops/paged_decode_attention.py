@@ -44,6 +44,21 @@ pages read once, where they lie:
   ``KH`` times the needed FLOPs, which are nothing here: 22 MFLOP a
   layer against 40 MB fetched at Ouro's shape.
 
+A row of ``T`` query positions (a block of a model that decodes by
+blocks, models/sdar.py: 4 positions under the BLOCK-causal mask) is the
+same kernel with a taller query tile: ``q`` [B, T, H, D] is the
+``[T x H, D]`` matrix the same bytes are, query row r is position
+``r // H`` under head ``r % H``, and the last key it sees is its
+block's last (its own position's under the causal mask, which the
+kernel computes too and ``applies`` leaves to the loop: a speculative
+verify, that no cell runs and one reading had slower), a vector down
+the rows where one query a row has a scalar. The
+row is walked to the last page ANY of its queries sees, its pages are
+still fetched once, and the loop it replaces gathered a 512-token block
+of K and of V a rider a layer for four queries as for one (9.6 of
+SDAR's 14.0 ms of attention a forward: PERF.md section 6, PR 64). At
+``T == 1`` under the causal mask the traced kernel is what it was.
+
 A pool of LATENT pages ``[n_pages, page_size, W]`` (``pv`` None;
 models/axk1.py, models/kimi_linear.py: one entry ``[c | k_r]`` a token
 that every query head reads, absorbed) is the ``KH = 1`` case of the
@@ -110,7 +125,8 @@ def pages_per_visit(H: int, page_size: int, kv_heads: int,
                     max_pages: int) -> int:
     """Logical pages of a row one visit fetches (of K and of V each) and
     folds in one step of the online softmax: the power of two whose
-    scores, ``H`` query heads x ``page_size x kv_heads`` rows a page in
+    scores, ``H`` query rows (a row's heads, times its query positions
+    where it has several) x ``page_size x kv_heads`` rows a page in
     float32, fill ``_SCORE_BYTES``, inside the table. More pages a visit
     pay a visit's fixed cost and the fold's latency less often (a fold
     is a chain: scores, max, exp, sum, read-out; 0.5 us a page folded
@@ -119,7 +135,8 @@ def pages_per_visit(H: int, page_size: int, kv_heads: int,
     fetch, so a short context loses nothing to a wide visit. 16 heads
     on 16 KV heads (Ouro, OLMoE) and 32 on 8 (Mistral) go eight pages a
     visit, 32 on 4 (Mellum 2) sixteen, 64 on 8 (Solar-Open2) four, 64
-    or 32 on a latent pool's one (A.X-K1, Kimi-Linear) sixteen."""
+    or 32 on a latent pool's one (A.X-K1, Kimi-Linear) sixteen, a block
+    of 4 positions x 32 heads on 4 (SDAR) four."""
     want = max(1, _SCORE_BYTES // (H * page_size * kv_heads * 4))
     return min(1 << (want.bit_length() - 1), _MAX_PAGES_A_VISIT,
                max_pages)
@@ -159,19 +176,27 @@ def schedule_bytes(rows: int, max_pages: int, pages: int) -> int:
     return 4 * (visits * (pages + 2) + 2 * rows)
 
 
-def applies(q, pk, pv, sk, page_table, value_dim=None) -> bool:
+def applies(q, pk, pv, sk, page_table, value_dim=None,
+            block_len: int = 1) -> bool:
     """Whether the kernel serves ``q`` [B, T, H, D] over the pool
     ``pk``/``pv`` [n_pages, Pg, KH, D], or over the latent pages ``pk``
     [n_pages, Pg, D] whose values are their first ``value_dim`` columns
     (``pv`` None), with the int8 scales ``sk`` (the loop's) under
-    ``page_table`` [B, max_pages]: one query a row (a decode step; a
-    prefill chunk and a speculative verify keep the loop), queries and
-    pool bfloat16, whole query groups, a head and a value of whole
-    128-lane tiles, query heads and a page's rows in whole sublane
-    tiles, a schedule that fits the scalar memory, and a TPU outside
-    any multi-device mesh. Only shapes and types are read:
-    ``_paged_window_attention`` asks it of its arguments, and the
-    engine of the same shapes for its ``decode_kernel_pages``."""
+    ``page_table`` [B, max_pages] and the mask of ``block_len``: a
+    row's ``T x H`` query rows against ONE page's ``Pg x KH`` rows fit
+    a visit's scores (a decode step; a block of a model that decodes by
+    blocks over a K/V pool; a prefill chunk keeps the loop), one query
+    a row under the causal mask and over a latent pool (a speculative
+    verify's few keep the loop: the kernel serves them, but the one
+    reading of it, tools/paged_decode_bench.py ``verify``, was 0.145 ms
+    a layer-step against the loop's 0.131 at the plan, and no cell runs
+    one: PERF.md section 7), queries and pool bfloat16, whole query
+    groups, a head and a value of whole 128-lane tiles, a row's query
+    rows and a page's rows in whole sublane tiles, a schedule that fits
+    the scalar memory, and a TPU outside any multi-device mesh. Only
+    shapes, types and the mask are read: ``_paged_window_attention``
+    asks it of its arguments, and the engine of the same shapes for its
+    ``decode_kernel_pages``."""
     latent = pv is None
     if sk is not None or pk.ndim != (3 if latent else 4):
         return False
@@ -180,12 +205,15 @@ def applies(q, pk, pv, sk, page_table, value_dim=None) -> bool:
         return False
     KH = 1 if latent else pk.shape[2]
     B, max_pages = page_table.shape
-    return (T == 1 and q.dtype == pk.dtype == jnp.bfloat16
+    rows = T * H
+    return ((T == 1 or (block_len > 1 and not latent))
+            and q.dtype == pk.dtype == jnp.bfloat16
             and (latent or pv.dtype == jnp.bfloat16)
-            and H % KH == 0 and D % _LANES == 0 and H % 16 == 0
+            and H % KH == 0 and D % _LANES == 0 and rows % 16 == 0
             and (Pg * KH) % 16 == 0
+            and rows * Pg * KH * 4 <= _SCORE_BYTES
             and schedule_bytes(B, max_pages,
-                               pages_per_visit(H, Pg, KH, max_pages))
+                               pages_per_visit(rows, Pg, KH, max_pages))
             <= _SCHEDULE_BYTES
             and _on_one_tpu())
 
@@ -199,19 +227,33 @@ def kernel_pages(ends, page_size: int, max_pages: int) -> int:
     return sum(min(-(-int(e) // page_size), max_pages) for e in ends)
 
 
-def visit_schedule(page_table, pos, page_size: int, pages: int):
+def _last_seen(pos, block_len: int):
+    """The last key position the query at ``pos`` sees: its own under
+    the causal mask (``block_len`` 1), its block's last under the
+    block-causal one."""
+    if block_len == 1:
+        return pos
+    return (_split(pos, block_len)[0] + 1) * block_len - 1
+
+
+def visit_schedule(page_table, pos, page_size: int, pages: int,
+                   queries: int = 1, block_len: int = 1):
     """The call's visits, from ``page_table`` [B, max_pages] and ``pos``
     [B] on the device: (the page id each of a visit's ``pages`` slots
     fetches [visits x pages], a visit's row, its group of pages within
     the row, the pages a row is walked to [B], the number of visits).
-    The arrays are as long as the most visits the table allows; only
-    the first ``n_visits`` are run."""
+    A row is walked to the last page ANY of its ``queries`` sees: its
+    last query's, at ``pos + queries - 1``, under the mask of
+    ``block_len``. The arrays are as long as the most visits the table
+    allows; only the first ``n_visits`` are run."""
     B, max_pages = page_table.shape
     max_groups = -(-max_pages // pages)
     i32 = jnp.int32
     pos = pos.astype(i32)
     live = page_table[:, 0] != 0
-    count = jnp.where(live, jnp.minimum(pos // page_size + 1, max_pages),
+    last_query = pos + (queries - 1) if queries > 1 else pos
+    last = _last_seen(last_query, block_len)
+    count = jnp.where(live, jnp.minimum(last // page_size + 1, max_pages),
                       0)
     # visit v is group ``group_of[v]`` of row ``row_of[v]``: a row has as
     # many visits as groups of pages, and one where it has none
@@ -249,7 +291,7 @@ def _dot(a, b, dims):
 
 def _decode_kernel(ids_ref, row_ref, group_ref, count_ref, pos_ref,
                    q_ref, *rest, scale: float, pages: int, kv_heads: int,
-                   span: int):
+                   span: int, queries: int, block_len: int):
     del ids_ref                                 # the index maps' alone
     # a latent pool has no V pages: an entry's value is its own first
     # columns, as many as the accumulator is wide
@@ -258,8 +300,10 @@ def _decode_kernel(ids_ref, row_ref, group_ref, count_ref, pos_ref,
     v = pl.program_id(0)
     row, group = row_ref[v], group_ref[v]  # this visit's row, page group
     count = count_ref[row]                 # pages this row visits
-    last = pos_ref[row]                    # its query's position
-    H, dv = q_ref.shape[2], acc_scr.shape[1]
+    last = pos_ref[row]                    # its (first) query's position
+    # a row's query rows: query position r // H under head r % H
+    R, dv = q_ref.shape[2], acc_scr.shape[1]
+    H = R // queries
     page_rows = k_refs[0].shape[1]         # (token, KV head) rows a page
     page_size = page_rows // kv_heads
 
@@ -269,15 +313,22 @@ def _decode_kernel(ids_ref, row_ref, group_ref, count_ref, pos_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0]                                          # [H, D]
+    q = q_ref[0, 0]                                          # [R, D]
     # column c of a contraction's scores (``span`` pages' rows, one
     # after another) is token c // KH under KV head c % KH; query head
     # h reads KV head h // rep, and where there is one KV head every
     # head reads every row
-    col = jax.lax.broadcasted_iota(jnp.int32, (H, span * page_rows), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (R, span * page_rows), 1)
     token, kv_head = _split(col, kv_heads)
-    if kv_heads > 1:
+    if kv_heads > 1 or queries > 1:
         head = jax.lax.broadcasted_iota(jnp.int32, col.shape, 0)
+    if queries > 1:
+        # one query position a row no longer: the last key a query row
+        # sees is its own position's, a vector down the rows
+        ahead, head = _split(head, H)
+        last = last + ahead
+    last = _last_seen(last, block_len)
+    if kv_heads > 1:
         own = kv_head == _split(head, H // kv_heads)[0]
 
     @pl.when(count > 0)
@@ -299,7 +350,7 @@ def _decode_kernel(ids_ref, row_ref, group_ref, count_ref, pos_ref,
         keys, scores = [], []
         for c in range(pages // span):
             k = block(k_refs, c)
-            s = _dot(q, k, _NT) * scale                      # [H, rows]
+            s = _dot(q, k, _NT) * scale                      # [R, rows]
             first = (group * pages + c * span) * page_size
             seen = first + token <= last
             scores.append(jnp.where(own & seen if kv_heads > 1 else seen,
@@ -323,8 +374,8 @@ def _decode_kernel(ids_ref, row_ref, group_ref, count_ref, pos_ref,
 
     @pl.when(group == jnp.maximum(pl.cdiv(count, pages), 1) - 1)
     def _():
-        # key 0 is visible to a live row's query; a row that is not
-        # live scored nothing and reads out zeros
+        # key 0 is visible to a live row's every query; a row that is
+        # not live scored nothing and reads out zeros
         l = l_scr[:, :1]
         y = acc_scr[...] / jnp.where(l > 0.0, l, 1.0)
         o_ref[0, 0] = y.astype(o_ref.dtype)
@@ -332,7 +383,8 @@ def _decode_kernel(ids_ref, row_ref, group_ref, count_ref, pos_ref,
 
 def _attend(q, pk, pv, page_table, pos, *, softmax_scale: float,
             pages: Optional[int] = None, value_dim: Optional[int] = None,
-            span: Optional[int] = None, interpret: bool = False):
+            span: Optional[int] = None, block_len: int = 1,
+            interpret: bool = False):
     """``paged_decode_attention`` at ``pages`` logical pages a visit,
     ``span`` of them a contraction (None: ``pages_per_visit``'s and
     ``pages_per_dot``'s plan): what tools/paged_decode_bench.py and the
@@ -346,13 +398,16 @@ def _attend(q, pk, pv, page_table, pos, *, softmax_scale: float,
             q.shape, pk.shape, value_dim)
     else:
         KH, Dv = pk.shape[2], D
-        assert (T == 1 and pv.shape == pk.shape and H % KH == 0
+        assert (pv.shape == pk.shape and H % KH == 0
                 and value_dim is None), (q.shape, pk.shape, pv.shape)
-    pages = pages or pages_per_visit(H, Pg, KH, page_table.shape[1])
+    # a row's T x H query rows are ONE tile of the kernel's: the
+    # [T * H, D] matrix the same bytes are
+    R = T * H
+    pages = pages or pages_per_visit(R, Pg, KH, page_table.shape[1])
     span = span or pages_per_dot(Pg * KH, pages)
     assert pages % span == 0, (pages, span)
     ids, row_of, group_of, count, n_visits = visit_schedule(
-        page_table, pos, Pg, pages)
+        page_table, pos, Pg, pages, T, block_len)
 
     def page(c):
         return pl.BlockSpec(
@@ -360,7 +415,7 @@ def _attend(q, pk, pv, page_table, pos, *, softmax_scale: float,
 
     def row(width):
         return pl.BlockSpec(
-            (1, 1, H, width),
+            (1, 1, R, width),
             lambda v, ids, row_of, *_: (row_of[v], 0, 0, 0))
     page_bytes = Pg * KH * D * pk.dtype.itemsize
     # a page-major page IS the [Pg x KH, D] matrix of its (token, KV
@@ -369,7 +424,8 @@ def _attend(q, pk, pv, page_table, pos, *, softmax_scale: float,
     pools = (pk,) if pv is None else (pk, pv)
     return pl.pallas_call(
         functools.partial(_decode_kernel, scale=softmax_scale,
-                          pages=pages, kv_heads=KH, span=span),
+                          pages=pages, kv_heads=KH, span=span,
+                          queries=T, block_len=block_len),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(n_visits,),
@@ -377,10 +433,10 @@ def _attend(q, pk, pv, page_table, pos, *, softmax_scale: float,
                       + [page(c) for c in range(pages)] * len(pools)),
             out_specs=row(Dv),
             scratch_shapes=[
-                pltpu.VMEM((H, _LANES), jnp.float32),          # m
-                pltpu.VMEM((H, _LANES), jnp.float32),          # l
-                pltpu.VMEM((H, Dv), jnp.float32)]),            # acc
-        out_shape=jax.ShapeDtypeStruct((B, 1, H, Dv), q.dtype),
+                pltpu.VMEM((R, _LANES), jnp.float32),          # m
+                pltpu.VMEM((R, _LANES), jnp.float32),          # l
+                pltpu.VMEM((R, Dv), jnp.float32)]),            # acc
+        out_shape=jax.ShapeDtypeStruct((B, 1, R, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             # a row's groups in order: its scratch carries them
             dimension_semantics=("arbitrary",),
@@ -389,27 +445,34 @@ def _attend(q, pk, pv, page_table, pos, *, softmax_scale: float,
             # their exponentials, those in the pool's type), and room
             # for what the compiler spills
             vmem_limit_bytes=(4 * pages * page_bytes
-                              + 12 * pages * H * Pg * KH + (8 << 20))),
+                              + 12 * pages * R * Pg * KH + (8 << 20))),
         interpret=interpret, name="paged_decode",
-    )(ids, row_of, group_of, count, pos.astype(jnp.int32), q,
-      *(x for pool in pools for x in [pool.reshape(flat)] * pages))
+    )(ids, row_of, group_of, count, pos.astype(jnp.int32),
+      q.reshape(B, 1, R, D),
+      *(x for pool in pools for x in [pool.reshape(flat)] * pages)
+      ).reshape(B, T, H, Dv)
 
 
 @functools.partial(jax.jit, static_argnames=("softmax_scale", "value_dim",
-                                             "interpret"))
+                                             "block_len", "interpret"))
 def paged_decode_attention(q, pk, pv, page_table, pos, *,
                            softmax_scale: float,
                            value_dim: Optional[int] = None,
+                           block_len: int = 1,
                            interpret: bool = False):
-    """Causal grouped-query attention of ``q`` [B, 1, H, D] (row b's
-    query at absolute position ``pos[b]``) over its page-table row's
-    K/V in the page-major pool ``pk``/``pv`` [n_pages, Pg, KH, D].
-    Returns [B, 1, H, D] in ``q``'s type. A row whose page-table row is
+    """Grouped-query attention of ``q`` [B, T, H, D] (row b's queries
+    at absolute positions ``pos[b] + t``) over its page-table row's
+    K/V in the page-major pool ``pk``/``pv`` [n_pages, Pg, KH, D],
+    causal, or block-causal where ``block_len`` > 1 (the query at i
+    sees the keys below ``(i // block_len + 1) * block_len``).
+    Returns [B, T, H, D] in ``q``'s type. A row whose page-table row is
     null (its first page is page 0) reads out zeros. ``pv`` None: ``pk``
     is a pool of latent pages [n_pages, Pg, D], an entry's value its
-    first ``value_dim`` columns, and [B, 1, H, value_dim] comes back.
+    first ``value_dim`` columns, ``T`` is 1 and [B, 1, H, value_dim]
+    comes back.
 
     One jitted function: the layers of a step program that call it
     with equal shapes share one trace and one lowering."""
     return _attend(q, pk, pv, page_table, pos, softmax_scale=softmax_scale,
-                   value_dim=value_dim, interpret=interpret)
+                   value_dim=value_dim, block_len=block_len,
+                   interpret=interpret)

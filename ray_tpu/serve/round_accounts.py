@@ -266,8 +266,10 @@ class RoundAccounts:
     def note_decode(self, ends, steps: int, verify: bool = False) -> None:
         """A decode dispatch of ``steps`` steps was launched whose
         riders' LAST queries sit at ``ends`` less one (the program
-        widens the window step by step); ``verify``: it was one
-        spec-verify forward over rows that end there. Of a model with
+        widens the window step by step; of a model that decodes by
+        blocks, the end of the block the dispatch closes on: what its
+        last query sees); ``verify``: it was one spec-verify forward
+        over rows that end there. Of a model with
         sliding-window layers also ``decode_sliding_keys``: the riders'
         contexts each cut at the window, the keys ONE sliding layer's
         last step has to score. Of a model whose pages have readers
@@ -345,14 +347,16 @@ class RoundAccounts:
         # stored, and so asked, as 32)
         heads = (cfg.n_heads if latent else k.shape[-2] * (
             kv_query_heads(cfg, KIND_KV) // cfg.n_kv_heads))
-        # (a block program's step asks with a whole block a row)
+        # (a block program's step asks with a whole block a row, under
+        # the block's mask)
         T = 1 if self.block is None else self.block.block_length
         q = jax.ShapeDtypeStruct((self.S, T, heads, k.shape[-1]),
                                  cfg.dtype)
         table = jax.ShapeDtypeStruct((self.S, self.max_pages), jnp.int32)
         with ambient_mesh(self.mesh):
             return paged_decode.applies(
-                q, k, v, sk, table, cfg.kv_lora_rank if latent else None)
+                q, k, v, sk, table, cfg.kv_lora_rank if latent else None,
+                T)
 
     def ring_kernel_keys(self, riders: int) -> int:
         """``sliding_kernel_keys`` of a decode dispatch of ``riders``:

@@ -432,8 +432,9 @@ def test_the_cells_prefill_program_holds_no_logits_of_the_call(
 # Pallas kernel of ops/paged_decode_attention.py (the tests above run
 # the rule on the CPU, where it keeps the loop): one call a layer with
 # the pool going in as it is stored, the visits' schedule computed once
-# a step and shared by the layers, and the chunked prefill and the
-# verify left to the loop
+# a step and shared by the layers, and the chunked prefill (256 x H
+# query rows against a page are no visit's scores) and the verify
+# (several queries a row under the causal mask) left to the loop
 @pytest.mark.parametrize("kv_heads", [8, 16], ids=["mistral", "olmoe"])
 def test_decode_attends_in_one_kernel_a_layer(one_chip, monkeypatch,
                                               kv_heads):
@@ -486,16 +487,23 @@ def test_decode_attends_in_one_kernel_a_layer(one_chip, monkeypatch,
 # is their first 512 columns (A.X-K1: a table of 16,384 tokens, its
 # riders at 8,192-8,704; Kimi-Linear at its 128 slots)
 # and the widest table the rule hands the kernel (its schedule goes in
-# by scalar prefetch: half of the chip's 1 MiB of scalar memory)
-@pytest.mark.parametrize("B,H,KH,max_pages", [
-    (16, 16, 16, 64), (32, 32, 8, 64), (32, 32, 4, 256), (32, 64, 8, 64),
-    (32, 32, 4, 3584), (32, 64, None, 256), (128, 32, None, 64)],
+# by scalar prefetch: half of the chip's 1 MiB of scalar memory); T
+# queries a row under a block mask of L (1: causal): SDAR's block of
+# four at its 128 slots and a table as wide as its published context
+# (394 KB of schedule), and a verify of four drafts at Mistral's shape
+# (the rule leaves it to the loop; the kernel still builds for it)
+@pytest.mark.parametrize("B,H,KH,max_pages,T,L", [
+    (16, 16, 16, 64, 1, 1), (32, 32, 8, 64, 1, 1), (32, 32, 4, 256, 1, 1),
+    (32, 64, 8, 64, 1, 1), (32, 32, 4, 3584, 1, 1),
+    (32, 64, None, 256, 1, 1), (128, 32, None, 64, 1, 1),
+    (128, 32, 4, 512, 4, 4), (32, 32, 8, 64, 5, 1)],
     ids=["ouro", "mistral", "mellum2", "solar_open2", "widest_table",
-         "axk1_latent", "kimi_linear_latent"])
-def test_paged_decode_kernel_compiles(one_chip, B, H, KH, max_pages):
+         "axk1_latent", "kimi_linear_latent", "sdar_block",
+         "mistral_verify"])
+def test_paged_decode_kernel_compiles(one_chip, B, H, KH, max_pages, T, L):
     from ray_tpu.ops import paged_decode_attention as pd
     assert pd.schedule_bytes(
-        B, max_pages, pd.pages_per_visit(H, PAGE, KH or 1, max_pages)
+        B, max_pages, pd.pages_per_visit(T * H, PAGE, KH or 1, max_pages)
     ) <= pd._SCHEDULE_BYTES
     table = [((B, max_pages), jnp.int32), ((B,), jnp.int32)]
     if KH is None:
@@ -508,8 +516,10 @@ def test_paged_decode_kernel_compiles(one_chip, B, H, KH, max_pages):
     else:
         pool = ((1025, PAGE, KH, 128), jnp.bfloat16)
         compiled = _compile(
-            lambda *a: pd.paged_decode_attention(*a, softmax_scale=0.088),
-            one_chip, ((B, 1, H, 128), jnp.bfloat16), pool, pool, *table)
+            lambda *a: pd.paged_decode_attention(*a, softmax_scale=0.088,
+                                                 block_len=L),
+            one_chip, ((B, T, H, 128), jnp.bfloat16), pool, pool, *table)
+        assert "bf16[%d,1,%d,128]" % (B, T * H) in compiled.as_text()
         assert not _pool_copies(compiled.as_text(),
                                 (1025, PAGE * KH, 128))
     assert not _pool_copies(compiled.as_text(), pool[0])
@@ -1749,21 +1759,57 @@ def _blocks_step(name, one_chip):
 
 
 @pytest.mark.parametrize("name", ["decode", "prefill"])
-def test_block_step_programs_copy_no_pool_and_fit_the_chip(one_chip, name):
+def test_block_step_programs_copy_no_pool_and_fit_the_chip(one_chip, name,
+                                                           monkeypatch):
+    from ray_tpu.ops import paged_decode_attention as pd
+    from ray_tpu.serve import step_programs
+    # the rule as one TPU reads it; what is traced so is no other test's
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
+    for fn in ("_jit_decode_blocks", "_jit_prefill"):
+        monkeypatch.setattr(step_programs, fn,
+                            getattr(step_programs, fn).__wrapped__)
     cfg, compiled = _blocks_step(name, one_chip)
     text = compiled.as_text()
     stored = (SDAR_PAGES, PAGE, 4, 128)
     assert not _pool_copies(text, stored)
-    # the block loop serves both: T = 4 queries a rider under the block
-    # mask, T = 256 a row; no kernel of the paged layers' (the rule is
-    # read off the chip here, and refuses T > 1 on it)
-    assert "paged_decode" not in text
-    for scope, spec in (("attn_scores", "btkrd,bskd->bkrts"),
-                        ("attn_pv", "bkrts,bskd->bkrtd")):
-        convs = re.findall(
-            r" convolution\([^\n]*layers_\d+/attention/[^\n\"]*"
-            + scope + "/" + spec, text)
-        assert len(convs) == cfg.n_layers, (scope, len(convs))
+    calls = re.findall(
+        r"custom-call\([^\n]*layers_\d+/attention/attn_scores/[^\n]*"
+        r"paged_decode[^\n]*", text)
+    convs = {scope: re.findall(
+        r" convolution\([^\n]*layers_\d+/attention/[^\n\"]*"
+        + scope + "/" + spec, text)
+        for scope, spec in (("attn_scores", "btkrd,bskd->bkrts"),
+                            ("attn_pv", "bkrts,bskd->bkrtd"))}
+    # a gathered block of K or of V: rows x 8 pages as they are stored
+    gathers = re.findall(r"bf16\[\d+,64,4,128\]\S* (?:gather|fusion)\(",
+                         text.replace("bf16[%d,64,4,128]" % SDAR_PAGES, ""))
+    if name == "decode":
+        # a block's T = 4 queries a rider under the block mask are the
+        # decode kernel's: one call a layer under the scope the
+        # benchmark's reader sums, each rider's own pages fetched where
+        # they lie (4 pages a visit of K and of V), and nothing of the
+        # loop: no gathered block, neither of its contractions
+        assert len(calls) == cfg.n_layers, len(calls)
+        flat = "bf16[%d,%d,128]" % (SDAR_PAGES, PAGE * 4)
+        assert pd.pages_per_visit(4 * 32, PAGE, 4,
+                                  cfg.max_seq_len // PAGE) == 4
+        assert all(call.count(flat) == 2 * 4 for call in calls), \
+            calls[0][:400]
+        assert not any(convs.values()) and not gathers, (convs, gathers)
+        assert "kv_gather" not in text and "attn_pv" not in text
+        # the visits' schedule is the first layer's alone
+        assert re.search(
+            r"layers_0/attention/attn_scores/jit\(paged_decode_attention\)"
+            r"/(cumsum|reduce_window)", text)
+        assert not re.search(
+            r"layers_1/attention/attn_scores/jit\(paged_decode_attention\)"
+            r"/(cumsum|reduce_window|cummax)", text)
+    else:
+        # T = 256 a row: 8,192 query rows against a page are sixteen
+        # visits' scores, so the block loop serves the call
+        assert not calls
+        for scope, found in convs.items():
+            assert len(found) == cfg.n_layers, (scope, len(found))
     mem = compiled.memory_analysis()
     pool = cfg.n_layers * 2 * math.prod(stored) * 2          # 3.62 GB
     weights = 2 * (cfg.n_layers * (

@@ -444,7 +444,7 @@ def test_the_engine_asks_each_kernel_with_its_layer_types_heads(
     eng.submit(_ids((40,), seed=71).tolist(), max_new_tokens=9)
     _drive(eng)                 # no retrace: only the counters ask
     L = sliding_ring_len(cfg, PAGE, CHUNK)
-    q, pk, pv, sk, table, value_dim = paged[0]
+    q, pk, pv, sk, table, value_dim, _block_len = paged[0]
     assert q.shape == (4, 1, 4, cfg.head_dim)
     assert pk.shape == pv.shape == (1, PAGE, cfg.n_kv_heads, cfg.head_dim)
     assert sk is None and value_dim is None

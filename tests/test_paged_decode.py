@@ -9,6 +9,10 @@ rtol 1e-4 as the other window tests; bfloat16 outputs (of order 1, one
 unit in the last place 2**-7) to one such unit and a half.
 """
 import functools
+import hashlib
+import importlib.util
+import json
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -23,13 +27,23 @@ TOL = {jnp.float32: dict(rtol=1e-4, atol=1e-5),
        jnp.bfloat16: dict(rtol=1e-2, atol=1.2e-2)}
 # (query heads, KV heads): one, four and eight rows a KV head
 GROUPS = {"rows_1": (16, 16), "rows_4": (32, 8), "rows_8": (32, 4)}
+# (query positions a row, the mask's block length: 1 the causal mask):
+# a decode step; a speculative verify's few queries; a block of a model
+# that decodes by blocks (models/sdar.py), each query seeing its whole
+# block
+QUERIES = {"one_query": (1, 1),
+           "T2_causal": (2, 1), "T2_block": (2, 2),
+           "T4_causal": (4, 1), "T4_block": (4, 4),
+           "T8_causal": (8, 1), "T8_block": (8, 8)}
 
 
-def _rows(contexts, max_pages, rng, stale):
+def _rows(contexts, max_pages, rng, stale, T=1, block_len=1):
     """(page table, positions) of rows whose contexts hold ``contexts``
-    tokens, the query's among them (None: a row no request owns, its
-    page-table row null and its position ``stale``), their pages
-    scattered over a pool of ``1 + len(contexts) * max_pages``."""
+    tokens, the row's ``T`` queries the last among them (None: a row no
+    request owns, its page-table row null and its position ``stale``),
+    their pages scattered over a pool of ``1 + len(contexts) *
+    max_pages``; a row holds the pages its last query sees, to its
+    block's end under the block mask."""
     B = len(contexts)
     ids = 1 + rng.permutation(B * max_pages).reshape(B, max_pages)
     pt = np.zeros((B, max_pages), np.int32)
@@ -38,40 +52,42 @@ def _rows(contexts, max_pages, rng, stale):
         if n is None:
             pos[b] = stale
             continue
-        pos[b] = n - 1
-        held = -(-n // PAGE)
+        pos[b] = n - T
+        end = -(-n // block_len) * block_len
+        held = min(-(-end // PAGE), max_pages)
         pt[b, :held] = ids[b, :held]
     return jnp.asarray(pt), jnp.asarray(pos)
 
 
 def _inputs(contexts, dtype, max_pages, H=16, KH=16, seed=0,
-            stale=100_000):
-    """Queries of ``H`` heads for ``_rows`` over a K/V pool of ``KH``
-    heads."""
+            stale=100_000, T=1, block_len=1):
+    """``T`` queries a row of ``H`` heads for ``_rows`` over a K/V pool
+    of ``KH`` heads."""
     rng = np.random.default_rng(seed)
     B = len(contexts)
-    pt, pos = _rows(contexts, max_pages, rng, stale)
+    pt, pos = _rows(contexts, max_pages, rng, stale, T, block_len)
     pk, pv = (jnp.asarray(
         0.5 * rng.standard_normal((1 + B * max_pages, PAGE, KH, D)), dtype)
         for _ in range(2))
-    q = jnp.asarray(rng.standard_normal((B, 1, H, D)), dtype)
+    q = jnp.asarray(rng.standard_normal((B, T, H, D)), dtype)
     return q, pk, pv, pt, pos
 
 
-@jax.jit
-def _loop(q, pk, pv, pt, pos):
+@functools.partial(jax.jit, static_argnames=("block_len",))
+def _loop(q, pk, pv, pt, pos, block_len=1):
     return paged_mod._paged_window_attention(q, pk, pv, None, None, pt,
-                                             pos)
+                                             pos, block_len=block_len)
 
 
-def _kernel(q, pk, pv, pt, pos, pages=0):
+def _kernel(q, pk, pv, pt, pos, pages=0, block_len=1):
     """The kernel by its plan, or at ``pages`` a visit."""
     if not pages:
         return pd.paged_decode_attention(
-            q, pk, pv, pt, pos, softmax_scale=D ** -0.5, interpret=True)
+            q, pk, pv, pt, pos, softmax_scale=D ** -0.5,
+            block_len=block_len, interpret=True)
     return jax.jit(functools.partial(
         pd._attend, softmax_scale=D ** -0.5, pages=pages,
-        interpret=True))(q, pk, pv, pt, pos)
+        block_len=block_len, interpret=True))(q, pk, pv, pt, pos)
 
 
 def _agree(got, want, live, dtype):
@@ -94,46 +110,79 @@ CONTEXTS = {
 }
 
 
+def _several(T):
+    """Contexts of rows of ``T`` queries: ragged, a row of its queries
+    alone, queries on both sides of a page's edge (the first sits two
+    short of it: under the block mask the later ones see a page the
+    first does not) and of a 512-token block's, a page's last
+    positions, a null row."""
+    return [301, T, 62 + T, 510 + T, 64, None, 1288]
+
+
+# one query a row over every set of contexts, several over their own
+LOOP_EQUALS = [(name, "one_query") for name in CONTEXTS] + [
+    ("several_queries", queries) for queries in QUERIES
+    if queries != "one_query"]
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("group", list(GROUPS))
-@pytest.mark.parametrize("name", list(CONTEXTS))
-def test_kernel_equals_the_block_loop(name, group, dtype):
+@pytest.mark.parametrize("name,queries", LOOP_EQUALS)
+def test_kernel_equals_the_block_loop(name, queries, group, dtype):
     H, KH = GROUPS[group]
-    args = _inputs(CONTEXTS[name], dtype, max_pages=64, H=H, KH=KH)
-    _agree(_kernel(*args), _loop(*args), slice(None), dtype)
+    T, block_len = QUERIES[queries]
+    contexts = CONTEXTS[name] if T == 1 else _several(T)
+    live = [b for b, n in enumerate(contexts) if n]
+    args = _inputs(contexts, dtype, max_pages=64, H=H, KH=KH, T=T,
+                   block_len=block_len)
+    got = _kernel(*args, block_len=block_len)
+    assert got.shape == (len(contexts), T, H, D)
+    _agree(got, _loop(*args, block_len=block_len), live, dtype)
 
 
+@pytest.mark.parametrize("queries", ["one_query", "T4_causal", "T4_block"])
 @pytest.mark.parametrize("group", list(GROUPS))
-def test_the_table_s_last_page(group):
+def test_the_table_s_last_page(group, queries):
     """A context that fills the table, beside a short one: the last
-    group of the widest row is the table's last columns."""
+    group of the widest row is the table's last columns, and a block's
+    four queries are the table's last positions."""
     H, KH = GROUPS[group]
-    args = _inputs([64 * PAGE, 70], jnp.float32, max_pages=64, H=H,
-                   KH=KH)
-    _agree(_kernel(*args), _loop(*args), slice(None), jnp.float32)
+    T, block_len = QUERIES[queries]
+    args = _inputs([64 * PAGE, 72], jnp.float32, max_pages=64, H=H,
+                   KH=KH, T=T, block_len=block_len)
+    _agree(_kernel(*args, block_len=block_len),
+           _loop(*args, block_len=block_len), slice(None), jnp.float32)
 
 
+@pytest.mark.parametrize("queries", ["one_query", "T4_block"])
 @pytest.mark.parametrize("pages", [1, 2, 4, 8, 16])
-def test_any_number_of_pages_a_visit(pages):
+def test_any_number_of_pages_a_visit(pages, queries):
     """The plan's count is a price, not a meaning: one page a visit or
     more than the table holds a row's groups read the same."""
-    args = _inputs([1, 64, 65, 700, 129], jnp.float32, max_pages=12)
-    _agree(_kernel(*args, pages=pages), _loop(*args), slice(None),
-           jnp.float32)
+    T, block_len = QUERIES[queries]
+    args = _inputs([T, 64, 64 + T, 700, 128 + T], jnp.float32,
+                   max_pages=12, T=T, block_len=block_len)
+    _agree(_kernel(*args, pages=pages, block_len=block_len),
+           _loop(*args, block_len=block_len), slice(None), jnp.float32)
 
 
-def test_a_null_row_with_a_stale_position_changes_nothing():
+@pytest.mark.parametrize("queries", ["one_query", "T4_causal", "T4_block"])
+def test_a_null_row_with_a_stale_position_changes_nothing(queries):
     """A row no request owns is not walked, whatever its position says:
     it reads out zeros, and the live rows read what they read beside a
     calm one."""
+    T, block_len = QUERIES[queries]
     contexts = [1024, None, 300, None]
-    args = _inputs(contexts, jnp.float32, max_pages=64)
-    got = np.asarray(_kernel(*args))
-    _agree(got, _loop(*args), [0, 2], jnp.float32)
+    args = _inputs(contexts, jnp.float32, max_pages=64, T=T,
+                   block_len=block_len)
+    got = np.asarray(_kernel(*args, block_len=block_len))
+    _agree(got, _loop(*args, block_len=block_len), [0, 2], jnp.float32)
     assert not got[[1, 3]].any()
-    calm = _inputs(contexts, jnp.float32, max_pages=64, stale=0)
-    np.testing.assert_array_equal(got, np.asarray(_kernel(*calm)))
+    calm = _inputs(contexts, jnp.float32, max_pages=64, stale=0, T=T,
+                   block_len=block_len)
+    np.testing.assert_array_equal(
+        got, np.asarray(_kernel(*calm, block_len=block_len)))
 
 
 @pytest.mark.parametrize("pages,want", [(1, 16 + 1 + 5 + 1),
@@ -166,21 +215,47 @@ def test_the_grid_is_each_row_s_own_pages(pages, want):
     assert (ids[1:][past[1:]] == ids[:-1][past[1:]]).all()
 
 
-def test_pos_advanced_inside_a_loop_across_a_page_s_edge():
+@pytest.mark.parametrize("T,block_len,want", [
+    (1, 1, [1, 1, 2, 0]), (4, 1, [1, 2, 2, 0]), (4, 4, [1, 2, 2, 0]),
+    (4, 12, [2, 2, 2, 0])],
+    ids=["one_query", "T4_causal", "T4_block", "T4_in_blocks_of_twelve"])
+def test_a_row_is_walked_to_the_last_page_any_of_its_queries_sees(
+        T, block_len, want):
+    """Rows whose first query sits at 59 | 62 | 64 (and a null one): the
+    last of four queries sits at 62 | 65 | 67, past the first's page
+    from 62 on. Blocks that divide a page end where the last query's
+    page does; under blocks of twelve the query at 62 sees to 71, a
+    page further than it sits."""
+    pt = jnp.asarray([[3, 4, 0], [5, 6, 0], [7, 8, 0], [0, 0, 0]],
+                     jnp.int32)
+    pos = jnp.asarray([59, 62, 64, 9999], jnp.int32)
+    ids, row_of, _g, count, n = (np.asarray(a) for a in jax.jit(
+        functools.partial(pd.visit_schedule, page_size=PAGE, pages=1,
+                          queries=T, block_len=block_len))(pt, pos))
+    assert count.tolist() == want
+    assert int(n) == sum(max(c, 1) for c in want)
+    for b in range(3):
+        assert ids[:n][row_of[:n] == b].tolist() == np.asarray(
+            pt)[b, :want[b]].tolist()
+
+
+@pytest.mark.parametrize("queries", ["one_query", "T4_causal", "T4_block"])
+def test_pos_advanced_inside_a_loop_across_a_page_s_edge(queries):
     """The decode dispatch's case: the schedule is part of the program,
     recomputed from ``pos`` every step, so a context that crosses a
     page's edge (and a group's) in the middle of a dispatch is still
-    attended whole."""
-    q, pk, pv, pt, pos = _inputs([126, 60, None], jnp.float32,
-                                 max_pages=8)
+    attended whole; a block program moves ``pos`` a block a commit."""
+    T, block_len = QUERIES[queries]
+    q, pk, pv, pt, pos = _inputs([125 + T, 59 + T, None], jnp.float32,
+                                 max_pages=8, T=T, block_len=block_len)
     # the rows' tables hold the pages the steps walk into
     pt = pt.at[0, 2].set(5).at[1, 1].set(6)
 
     def steps(attend):
         def body(i, carry):
             pos, out = carry
-            y = attend(q, pk, pv, pt, pos)
-            return pos + 1, out.at[i].set(y)
+            y = attend(q, pk, pv, pt, pos, block_len=block_len)
+            return pos + T, out.at[i].set(y)
         out = jnp.zeros((6,) + q.shape, q.dtype)
         return jax.lax.fori_loop(0, 6, body, (pos, out))[1]
 
@@ -199,6 +274,8 @@ def test_pages_per_visit_follows_the_visit_s_scores():
     assert plan(64, kv_heads=8) == 4        # Solar-Open2
     assert plan(16, kv_heads=1) == 16       # no more operands than that
     assert plan(128, kv_heads=16) == 1
+    # a block's query rows count as a step's heads do: SDAR's 4 x 32
+    assert plan(4 * 32, kv_heads=4) == 4
     assert pd.pages_per_visit(16, 64, 16, max_pages=4) == 4
 
 
@@ -330,8 +407,10 @@ def _spied(monkeypatch):
     """``calls``: the kernel's calls from here on (it returns zeros)."""
     calls = []
 
-    def spy(q, pk, pv, page_table, pos, *, softmax_scale, value_dim):
-        calls.append((q.shape, pk.shape))
+    def spy(q, pk, pv, page_table, pos, *, softmax_scale, value_dim,
+            block_len):
+        calls.append((q.shape, pk.shape) + ((block_len,)
+                                            if block_len > 1 else ()))
         return jnp.zeros(q.shape[:3] + (value_dim or q.shape[3],),
                          q.dtype)
     monkeypatch.setattr(pd, "paged_decode_attention", spy)
@@ -340,33 +419,46 @@ def _spied(monkeypatch):
 
 def _call(T=1, H=32, KH=8, D=128, int8=False, latent=False,
           dtype=jnp.bfloat16, pool_dtype=None, page=PAGE, max_pages=64,
-          value_dim=DV):
-    """Trace one ``_paged_window_attention`` call of 32 rows of T
+          value_dim=DV, rows=32, block_len=1):
+    """Trace one ``_paged_window_attention`` call of ``rows`` rows of T
     queries."""
     pool_dtype = pool_dtype or dtype
-    q = jax.ShapeDtypeStruct((32, T, H, D), dtype)
-    pt = jax.ShapeDtypeStruct((32, max_pages), jnp.int32)
-    pos = jax.ShapeDtypeStruct((32,), jnp.int32)
+    q = jax.ShapeDtypeStruct((rows, T, H, D), dtype)
+    pt = jax.ShapeDtypeStruct((rows, max_pages), jnp.int32)
+    pos = jax.ShapeDtypeStruct((rows,), jnp.int32)
     if latent:
         pk = jax.ShapeDtypeStruct((513, page, D), pool_dtype)
         return jax.eval_shape(
             lambda q, pk, pt, pos: paged_mod._paged_window_attention(
                 q, pk, None, None, None, pt, pos, softmax_scale=0.1,
-                value_dim=value_dim), q, pk, pt, pos)
+                value_dim=value_dim, block_len=block_len), q, pk, pt, pos)
     pk = jax.ShapeDtypeStruct((513, page, KH, D),
                               jnp.int8 if int8 else pool_dtype)
     sk = jax.ShapeDtypeStruct((513, KH), jnp.float32) if int8 else None
     return jax.eval_shape(
         lambda q, pk, sk, pt, pos: paged_mod._paged_window_attention(
-            q, pk, pk, sk, sk, pt, pos), q, pk, sk, pt, pos)
+            q, pk, pk, sk, sk, pt, pos, block_len=block_len),
+        q, pk, sk, pt, pos)
 
 
 LOOP_CASES = {
+    # 256 x 32 query rows against one page's 512 rows: 16 MiB of
+    # scores, thirty-two times a visit's
     "a_prefill_chunk": dict(T=256),
+    "a_prefill_chunk_of_blocks": dict(T=256, KH=4, block_len=4),
+    # a speculative verify's few queries under the causal mask, though
+    # five of them fit a visit's scores
     "a_verify_of_five_tokens": dict(T=5),
+    "a_verify_over_sixteen_kv_heads": dict(T=5, H=16, KH=16),
+    "a_verify_past_a_visit_s_scores": dict(T=16),
+    "one_query_past_a_visit_s_scores": dict(H=128, KH=32),
+    "a_verify_whose_rows_fill_no_whole_sublane_tile": dict(T=3, H=8, KH=8),
     "int8_scales": dict(int8=True),
+    "a_block_over_int8_scales": dict(int8=True, T=4, KH=4, block_len=4),
     # what the loop keeps of a latent pool
     "a_latent_verify_of_five_tokens": dict(latent=True, H=64, D=W, T=5),
+    "a_latent_block_of_four": dict(latent=True, H=32, D=W, T=4,
+                                   block_len=4),
     "a_float32_latent_pool": dict(latent=True, H=64, D=W,
                                   dtype=jnp.float32),
     "a_latent_value_of_no_whole_lane_tile": dict(latent=True, H=64, D=W,
@@ -381,6 +473,11 @@ LOOP_CASES = {
     # 32 x 4,096 pages: a schedule of 590 KB of the chip's 1 MiB of
     # scalar memory
     "a_table_wider_than_the_scalar_memory": dict(max_pages=4096),
+    # SDAR's block at TWO pages a visit would be 525,312 B of schedule:
+    # a page of twice the rows halves the plan, and the cell's table
+    # (128 x 512) no longer fits
+    "a_block_whose_schedule_is_wider_than_the_scalar_memory": dict(
+        T=4, KH=4, rows=128, max_pages=512, page=128, block_len=4),
 }
 
 
@@ -393,7 +490,8 @@ def test_the_loop_keeps_what_the_kernel_is_not_for(name, monkeypatch):
     assert not calls
     width = (case.get("value_dim", DV) if case.get("latent")
              else case.get("D", 128))
-    assert out.shape == (32, case.get("T", 1), case.get("H", 32), width)
+    assert out.shape == (case.get("rows", 32), case.get("T", 1),
+                         case.get("H", 32), width)
 
 
 @pytest.mark.parametrize("H,KH", [(16, 16), (32, 8), (32, 4), (64, 8)])
@@ -403,6 +501,29 @@ def test_a_decode_step_over_kv_pages_on_one_tpu_takes_the_kernel(
     monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
     assert _call(H=H, KH=KH).shape == (32, 1, H, 128)
     assert calls == [((32, 1, H, 128), (513, PAGE, KH, 128))]
+
+
+# what fits a visit's scores beside one query a row: ``sdar-30b-d6
+# .gen-sat``'s block (128 rows of 4 positions, 32 heads on 4, a table of
+# 512 columns, the block mask passed on) and a smaller model's block of
+# eight
+@pytest.mark.parametrize("case", [
+    dict(T=4, H=32, KH=4, rows=128, max_pages=512, block_len=4),
+    dict(T=8, H=16, KH=4, block_len=8)],
+    ids=["sdar_s_block", "a_block_of_eight"])
+def test_a_few_queries_a_row_over_kv_pages_on_one_tpu_take_the_kernel(
+        case, monkeypatch):
+    calls = _spied(monkeypatch)
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
+    rows, T, H, KH = (case.get("rows", 32), case["T"], case["H"],
+                      case["KH"])
+    assert _call(**case).shape == (rows, T, H, 128)
+    assert calls == [((rows, T, H, 128), (513, PAGE, KH, 128),
+                      case["block_len"])]
+    # off the chip the same call is the loop's
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: False)
+    assert _call(**case).shape == (rows, T, H, 128)
+    assert len(calls) == 1
 
 
 @pytest.mark.parametrize("rows,H,max_pages", [(32, 64, 256),
@@ -427,25 +548,36 @@ def test_a_decode_step_over_latent_pages_on_one_tpu_takes_the_kernel(
     assert calls == [((rows, 1, H, W), (513, PAGE, W))]
 
 
-def test_the_widest_table_the_kernel_serves(monkeypatch):
+@pytest.mark.parametrize("rows,T,max_pages,pages,groups", [
+    (32, 1, 3584, 16, 224), (128, 4, 512, 4, 128)],
+    ids=["one_query_32_heads", "sdar_s_block_of_128_query_rows"])
+def test_the_widest_table_the_kernel_serves(rows, T, max_pages, pages,
+                                            groups, monkeypatch):
     """The schedule goes in by scalar prefetch, so the rule bounds it:
     32 rows x 3,584 pages (229,376 tokens a row) is the kernel's,
     which tests/test_chip_compile.py builds for the chip (32 x 4,096
-    is among the loop's cases above)."""
+    is among the loop's cases above); and SDAR's 128 rows x 512 pages
+    at the FOUR pages a visit that 4 x 32 query rows against a page's
+    256 plan (394,240 B; at two it would not fit, and the cell would
+    keep the loop without a word)."""
     calls = _spied(monkeypatch)
     monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
-    _call(H=32, KH=4, max_pages=3584)
+    _call(T=T, H=32, KH=4, rows=rows, max_pages=max_pages, block_len=T)
     assert len(calls) == 1
-    assert pd.schedule_bytes(32, 3584, 16) == 4 * (
-        32 * 224 * (16 + 1 + 1) + 2 * 32) <= pd._SCHEDULE_BYTES
+    assert pd.pages_per_visit(T * 32, PAGE, 4, max_pages) == pages
+    assert pd.schedule_bytes(rows, max_pages, pages) == 4 * (
+        rows * groups * (pages + 1 + 1) + 2 * rows) <= pd._SCHEDULE_BYTES
+    assert pd.schedule_bytes(rows, max_pages, pages // 2) > \
+        pd._SCHEDULE_BYTES
     # what visit_schedule hands over is what the rule counted
     out = jax.eval_shape(
-        functools.partial(pd.visit_schedule, page_size=PAGE, pages=16),
-        jax.ShapeDtypeStruct((32, 3584), jnp.int32),
-        jax.ShapeDtypeStruct((32,), jnp.int32))
+        functools.partial(pd.visit_schedule, page_size=PAGE, pages=pages,
+                          queries=T, block_len=T),
+        jax.ShapeDtypeStruct((rows, max_pages), jnp.int32),
+        jax.ShapeDtypeStruct((rows,), jnp.int32))
     ids, row_of, group_of, count, _n = out
     assert 4 * (ids.size + row_of.size + group_of.size + count.size
-                + 32) == pd.schedule_bytes(32, 3584, 16)
+                + rows) == pd.schedule_bytes(rows, max_pages, pages)
 
 
 @pytest.mark.parametrize("pool", [dict(), dict(latent=True, H=64, D=W)],
@@ -580,13 +712,55 @@ def test_the_round_event_carries_decode_kernel_pages(monkeypatch):
         assert eng.stats["decode_kernel_pages"] == sum(
             k for k, _c in after)
         # the program's own question, of the pool's own layout
-        q, k, v, sk, table, value_dim = asked[0]
-        assert value_dim is None
+        q, k, v, sk, table, value_dim, block_len = asked[0]
+        assert value_dim is None and block_len == 1
         assert (q.shape, q.dtype) == (
             (4, 1, cfg.n_heads, cfg.head_dim), jnp.float32)
         assert k.shape == v.shape == (1, 16, cfg.n_kv_heads, cfg.head_dim)
         assert k.dtype == v.dtype == jnp.float32 and sk is None
         assert table.shape == (4, eng.max_pages)
+    finally:
+        eng.shutdown()
+
+
+def test_a_verify_counts_no_kernel_pages(monkeypatch):
+    """A speculative verify is one forward of ``spec_len + 1`` queries a
+    row under the causal mask, which the rule leaves to the loop: the
+    counter does not ask for it and counts no pages for it, whatever
+    the decode program's layers answer."""
+    from ray_tpu.models.llama import Llama, llama_tiny
+    from ray_tpu.serve.engine import LLMEngine
+    cfg = llama_tiny(dtype=jnp.float32)
+    model = Llama(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                 jnp.zeros((1, 8), jnp.int32))
+    eng = LLMEngine(model, params, max_slots=4, page_size=16, n_pages=33,
+                    chunk=4, spec_len=4, spec_ngram=2).start()
+    prompt = [3, 4, 5, 6, 7] * 5
+    try:
+        def rounds():
+            return [e[5] for e in eng.events.snapshot()
+                    if e[2] == "round" and e[5]["decode_steps"]]
+
+        eng.submit(prompt, max_new_tokens=24).result()
+        assert eng.wait_idle(10)
+        before, verifies = rounds(), eng.stats["spec_rounds"]
+        assert verifies and not any(
+            r["decode_kernel_pages"] for r in before)
+        asked = []
+        monkeypatch.setattr(
+            pd, "applies", lambda q, *a: asked.append(q.shape[1]) or True)
+        eng.submit(prompt, max_new_tokens=24).result()
+        assert eng.wait_idle(10)
+        after = rounds()[len(before):]
+        served = [r for r in after if r["decode_kernel_pages"]]
+        assert all(r["decode_kernel_pages"]
+                   == -(-r["decode_context_tokens"] // 16) for r in served)
+        # the decode dispatches asked, with one query a row; the
+        # verifies (one step each) did not
+        assert set(asked) == {1} and len(asked) == len(served)
+        assert len(after) - len(served) \
+            == eng.stats["spec_rounds"] - verifies > 0
     finally:
         eng.shutdown()
 
@@ -666,7 +840,7 @@ def test_the_round_event_carries_decode_kernel_pages_of_latent_pages(
         assert after and all(k == -(-c // 16) for k, c in after)
         assert eng.stats["decode_kernel_pages"] == sum(
             k for k, _c in after)
-        q, pages, v, sk, table, value_dim = asked[0]
+        q, pages, v, sk, table, value_dim, _block_len = asked[0]
         width = latent_page_width(cfg)
         assert (q.shape, q.dtype) == ((4, 1, cfg.n_heads, width),
                                       jnp.float32)
@@ -688,3 +862,51 @@ def test_the_round_event_carries_decode_kernel_pages_of_latent_pages(
     monkeypatch.setattr(shaped.accounts, "mesh", Mesh(
         np.asarray(cpu_mesh_devices[:2]), ("tensor",)))
     assert not shaped.accounts.decode_kernel_serves()
+
+
+# ----------------------------------- the cells' programs, by their text
+
+_ROOT = pathlib.Path(__file__).resolve().parent.parent
+_PINS = json.loads(
+    (_ROOT / "tests" / "data" / "paged_decode_lowered.json").read_text())
+
+
+def _lowered_tool():
+    spec = importlib.util.spec_from_file_location(
+        "paged_decode_lowered", _ROOT / "tools" / "paged_decode_lowered.py")
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    return tool
+
+
+@pytest.mark.parametrize("config", sorted(_PINS["sha256"]))
+def test_a_cells_one_query_a_row_lowers_to_the_pinned_text(config):
+    """One query a row is the decode step of every serving cell but
+    SDAR's, and a wider query tile (PR 64) is none of their business:
+    at each cell's own shape (the engine's question of the rule, over
+    the deployment's pool) the call lowered for a TPU, the Mosaic
+    kernel's body without its debug info, is the text pinned at PR 63's
+    tree. A change that MEANS to move these programs re-pins
+    (``python tools/paged_decode_lowered.py --write``) and measures the
+    cells; one that does not finds out here, not on the chip."""
+    if jax.__version__ != _PINS["jax"]:
+        pytest.skip("pinned under jax %s" % _PINS["jax"])
+    tool = _lowered_tool()
+    asked = tool.question(config)
+    assert asked[0].shape[1] == 1 and asked[-1] == 1
+    text, kernels = tool.lowered(*asked)
+    assert kernels == 1
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _PINS["sha256"][config]
+
+
+def test_every_one_query_cell_is_pinned():
+    """The pins name every serving configuration whose decode step asks
+    the rule with one query a row (SDAR asks with a block of four;
+    DeepSeek-V3.2's layers attend under a choice and ask nothing)."""
+    tool = _lowered_tool()
+    rest = set(tool.configs()) - set(_PINS["sha256"])
+    assert rest == {"sdar-30b-a3b-chat-d6", "deepseek-v3.2-d5-ep32"}
+    assert tool.question("deepseek-v3.2-d5-ep32") is None
+    q, *_pools, block_len = tool.question("sdar-30b-a3b-chat-d6")
+    assert (q.shape[1], block_len) == (4, 4)

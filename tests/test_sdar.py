@@ -501,29 +501,83 @@ def test_the_block_mask_is_the_dense_one(block_len):
                 assert np.allclose(got[b, t, h], want, atol=1e-5)
 
 
-def test_the_kernels_keep_their_rules_and_refuse_a_block_mask(monkeypatch):
-    """Both Pallas forms mask causally: steered onto a TPU, a decode
-    step at ``block_len`` 1 goes to the decode kernel and the same call
-    at ``block_len`` 4 stays in the loop; the engine asks the rule with
-    a whole block a row and is told no."""
+def test_the_decode_kernel_takes_a_block_under_the_block_mask(monkeypatch):
+    """Steered onto a TPU, a decode step at ``block_len`` 1 goes to the
+    decode kernel, and so does a block of four queries a row at
+    ``block_len`` 4, with the mask passed on: what the kernel (here in
+    interpret mode) reads out is the loop's. The engine asks the rule
+    with a whole block a row and is told yes; off the chip, no."""
     from ray_tpu.ops import paged_decode_attention as paged_decode
-    monkeypatch.setattr(paged_decode, "_on_one_tpu", lambda: True)
+    kernel = paged_decode.paged_decode_attention
     called = []
     monkeypatch.setattr(
         paged_decode, "paged_decode_attention",
-        lambda q, *a, **kw: called.append(q.shape) or jnp.zeros_like(q))
-    pk = jnp.zeros((8, 16, 1, 128), jnp.bfloat16)
-    table = jnp.ones((2, 4), jnp.int32)
-    pos = jnp.zeros((2,), jnp.int32)
-    q1 = jnp.zeros((2, 1, 16, 128), jnp.bfloat16)
-    _paged_window_attention(q1, pk, pk, None, None, table, pos)
-    assert called == [q1.shape]
-    _paged_window_attention(q1, pk, pk, None, None, table, pos,
-                            block_len=4)
-    assert called == [q1.shape]
-    cfg = sdar_tiny(dim=256, n_heads=16, n_kv_heads=1, head_dim=128)
+        lambda q, *a, **kw: called.append((q.shape, kw["block_len"]))
+        or kernel(q, *a, interpret=True, **kw))
+    rng = np.random.default_rng(0)
+    pk, pv = (jnp.asarray(rng.standard_normal((8, 16, 1, 128)),
+                          jnp.bfloat16) for _ in range(2))
+    table = jnp.asarray([[3, 5, 1, 0], [0, 0, 0, 0], [2, 4, 6, 7]],
+                        jnp.int32)
+    pos = jnp.asarray([28, 4000, 44], jnp.int32)
+    q1, q4 = (jnp.asarray(rng.standard_normal((3, T, 16, 128)),
+                          jnp.bfloat16) for T in (1, 4))
+    loop = [np.asarray(_paged_window_attention(
+        q, pk, pv, None, None, table, pos, block_len=L), np.float32)
+        for q, L in ((q1, 1), (q4, 4))]
+    assert not called                                    # the CPU
+    monkeypatch.setattr(paged_decode, "_on_one_tpu", lambda: True)
+    for (q, L), want in zip(((q1, 1), (q4, 4)), loop):
+        got = np.asarray(_paged_window_attention(
+            q, pk, pv, None, None, table, pos, block_len=L), np.float32)
+        assert np.allclose(got[[0, 2]], want[[0, 2]], atol=2e-2)
+        assert not got[1].any()
+    assert called == [(q1.shape, 1), (q4.shape, 4)]
+    cfg = sdar_tiny(dim=256, n_heads=16, n_kv_heads=1, head_dim=128,
+                    dtype=jnp.bfloat16)
     from ray_tpu.serve.round_accounts import RoundAccounts
     pool = kv_cache.init_kv_pool(cfg, 4, 16)
     acc = RoundAccounts(cfg, {}, pool, slots=2, page_size=16, max_pages=4,
                         kv_dtype="fp", mesh=None)
-    assert acc.block == cfg.block_decode and not acc.decode_kernel_serves()
+    assert acc.block == cfg.block_decode and acc.decode_kernel_serves()
+    monkeypatch.setattr(paged_decode, "_on_one_tpu", lambda: False)
+    assert not acc.decode_kernel_serves()
+
+
+def test_the_round_event_counts_a_block_s_pages_to_its_last_query(
+        seeded, monkeypatch):
+    """``decode_kernel_pages`` of a model that decodes by blocks: 0
+    where the block program holds the loop (the CPU); where the rule
+    says the kernel serves, each rider's pages to the end of the block
+    the dispatch closes on (what the block's LAST query sees: the
+    bound ``decode_context_tokens`` sums), and the rule was asked with
+    a whole block a row."""
+    from ray_tpu.ops import paged_decode_attention as paged_decode
+    params, _rw = seeded
+    cfg, model = _tiny(remasking="low_confidence_static")
+    eng = _engine(model, params)
+
+    def rounds():
+        return [(e[5]["decode_kernel_pages"],
+                 e[5]["decode_context_tokens"])
+                for e in eng.events.snapshot()
+                if e[2] == "round" and e[5]["decode_steps"]]
+
+    eng.submit(_ids((6,), seed=1).tolist(), max_new_tokens=22)
+    _drive(eng)
+    before = rounds()
+    assert before and not any(k for k, _c in before)
+    asked = []
+    monkeypatch.setattr(paged_decode, "applies",
+                        lambda *a: asked.append(a) or True)
+    eng.submit(_ids((6,), seed=2).tolist(), max_new_tokens=22)
+    _drive(eng)
+    after = rounds()[len(before):]
+    # one rider, pages of 8: a block's end is a whole number of blocks
+    assert after and all(c % L == 0 and k == -(-c // PAGE)
+                         for k, c in after)
+    assert len({c for _k, c in after}) > 1
+    q, k, v, sk, table, value_dim, block_len = asked[0]
+    assert q.shape == (3, L, cfg.n_heads, cfg.head_dim) and block_len == L
+    assert k.shape == v.shape == (1, PAGE, cfg.n_kv_heads, cfg.head_dim)
+    assert sk is None and value_dim is None

@@ -14,6 +14,14 @@ shapes and contexts:
            their first 512 columns, contexts 8,192-8,704, a quarter null
   kimi     128 rows, 32 heads over latent pages, contexts 1,024-2,048,
            three rows null
+  sdar     128 rows of a BLOCK of 4 queries under the block mask, 32 on
+           4, a table of 512 columns, contexts 1,024-2,048 (the block
+           among them), three rows null
+  verify   32 rows of 5 queries under the causal mask (a speculative
+           verify of four drafts), 32 on 8, contexts 256-352: a made-up
+           shape no cell runs, which ``applies`` leaves to the loop
+           (0.145 ms the kernel at its plan, 0.131 the loop; at 2-8
+           pages a visit 0.118: PERF.md section 7)
 
 One JSON line a reading: ms a layer-step (the mean of 50 calls inside
 ONE device loop, each fed the one before it, so no dispatch of the
@@ -42,7 +50,8 @@ PAGE, D, CALLS = 64, 128, 50
 LATENT_D, LATENT_DV, LATENT_SCALE = 640, 512, 0.1309
 POOL_BYTES = 1 << 30              # of K, and of V
 # name: (rows, heads, KV heads (None: latent pages), table columns,
-# (lo, hi) contexts, rows without a rider)
+# (lo, hi) contexts, rows without a rider[, queries a row, the mask's
+# block length (1: causal)]); a row's queries are its context's last
 SHAPES = {
     "ouro": (16, 16, 16, 64, (256, 352), 0),
     "olmoe": (32, 16, 16, 64, (256, 352), 0),
@@ -52,6 +61,8 @@ SHAPES = {
     "solar": (32, 64, 8, 64, (1024, 1280), 0),
     "axk1": (32, 64, None, 256, (8192, 8704), 8),
     "kimi": (128, 32, None, 64, (1024, 2048), 3),
+    "sdar": (128, 32, 4, 512, (1024, 2048), 3, 4, 4),
+    "verify": (32, 32, 8, 64, (256, 352), 0, 5, 1),
 }
 
 
@@ -100,22 +111,28 @@ def main():
         return ({} if pv is not None else
                 dict(softmax_scale=LATENT_SCALE, value_dim=LATENT_DV))
 
-    def loop(q, pk, pv, table, pos):
-        with mock.patch.object(pd, "_on_one_tpu", lambda: False):
-            return pa._paged_window_attention(q, pk, pv, None, None, table,
-                                              pos, **how(pv))
-
-    def kernel(pages, span=None):
-        def run(q, pk, pv, table, pos):
-            return pd._attend(q, pk, pv, table, pos, pages=pages, span=span,
-                              **{"softmax_scale": D ** -0.5, **how(pv)})
-        return run
-
     for name in args.shapes.split(","):
-        B, H, KH, max_pages, (lo, hi), idle = SHAPES[name]
+        B, H, KH, max_pages, (lo, hi), idle, T, block = (
+            SHAPES[name] + (1, 1))[:8]
+
+        def loop(q, pk, pv, table, pos):
+            with mock.patch.object(pd, "_on_one_tpu", lambda: False):
+                return pa._paged_window_attention(
+                    q, pk, pv, None, None, table, pos, block_len=block,
+                    **how(pv))
+
+        def kernel(pages, span=None):
+            def run(q, pk, pv, table, pos):
+                return pd._attend(
+                    q, pk, pv, table, pos, pages=pages, span=span,
+                    block_len=block,
+                    **{"softmax_scale": D ** -0.5, **how(pv)})
+            return run
+
         # every row's pages scattered over the pool, as an allocator
-        # leaves them; page 0 is the null page
-        contexts = rng.integers(lo, hi + 1, B)
+        # leaves them; page 0 is the null page. A block ends on a
+        # block's edge
+        contexts = rng.integers(lo, hi + 1, B) // block * block
         contexts[rng.permutation(B)[:idle]] = 0
         held = -(-contexts // PAGE)
         # a pool of a deployment's size and no smaller: one that fits
@@ -130,7 +147,7 @@ def main():
             table[b, :held[b]] = ids[at:at + held[b]]
             at += held[b]
         # a row without a rider keeps a stale position
-        pos = np.where(contexts > 0, contexts - 1, 3000).astype(np.int32)
+        pos = np.where(contexts > 0, contexts - T, 3000).astype(np.int32)
         if KH is None:
             pk, pv = 0.5 * jax.random.normal(
                 jax.random.PRNGKey(0), (n_pages, PAGE, LATENT_D),
@@ -141,7 +158,7 @@ def main():
             pk, pv = (0.5 * jax.random.normal(
                 jax.random.PRNGKey(k), (n_pages, PAGE, KH, D),
                 jnp.bfloat16) for k in range(2))
-            q = jnp.asarray(rng.standard_normal((B, 1, H, D)),
+            q = jnp.asarray(rng.standard_normal((B, T, H, D)),
                             jnp.bfloat16)
         a = (q, pk, pv, jnp.asarray(table), jnp.asarray(pos))
         own = int(contexts.sum()) * entry * (1 if KH is None else 2)
@@ -150,6 +167,7 @@ def main():
         def line(impl, ms, **more):
             print(json.dumps({
                 "shape": name, "rows": B, "riders": int(riders.sum()),
+                "queries_a_row": T, "mask_block": block,
                 "heads": H, "kv_heads": KH or "latent",
                 "context_tokens": int(contexts.sum()), "impl": impl,
                 "ms": round(ms, 4),
@@ -158,7 +176,7 @@ def main():
 
         want = np.asarray(jax.jit(loop)(*a), np.float32)[riders]
         line("loop", timed(looped(loop), *a))
-        plan = pd.pages_per_visit(H, PAGE, KH or 1, max_pages)
+        plan = pd.pages_per_visit(T * H, PAGE, KH or 1, max_pages)
         for pages in [plan] + [p for p in sweep if p != plan]:
             dot = pd.pages_per_dot(PAGE * (KH or 1), pages)
             for span in [dot] + [x for x in spans
@@ -174,7 +192,8 @@ def main():
         def schedule(q, pk, pv, table, pos):
             # never true, and nothing the compiler can know
             nudge = (q[0, 0, 0, 0] > 1e30).astype(jnp.int32)
-            out = pd.visit_schedule(table, pos + nudge, PAGE, plan)
+            out = pd.visit_schedule(table, pos + nudge, PAGE, plan, T,
+                                    block)
             # a bit of every output: a product with zero is folded away
             # and the schedule with it (PR 47's line read 0.0055 ms so)
             bit = sum(o.sum() for o in out) & 1
