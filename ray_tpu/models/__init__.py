@@ -13,6 +13,9 @@ from ray_tpu.models.mixtral import (Mixtral, MixtralConfig,
                                     mixtral_8x7b, mixtral_sharding_rules,
                                     mixtral_tiny, moe_aux_loss,
                                     olmoe_1b_7b, olmoe_tiny)
+from ray_tpu.models.granite_hybrid import (GraniteHybrid,
+                                           GraniteHybridConfig,
+                                           granite_hybrid_tiny)
 from ray_tpu.models.resnet import ResNet, ResNetConfig, resnet50, resnet18
 from ray_tpu.models.vit import (ViT, ViTConfig, classification_loss,
                                 vit_base_16, vit_sharding_rules,
@@ -32,4 +35,5 @@ __all__ = [
     "Mixtral", "MixtralConfig", "mixtral_8x7b", "mixtral_tiny",
     "mixtral_sharding_rules", "moe_aux_loss", "olmoe_1b_7b",
     "olmoe_tiny",
+    "GraniteHybrid", "GraniteHybridConfig", "granite_hybrid_tiny",
 ]

@@ -98,6 +98,7 @@ from __future__ import annotations
 
 from typing import List, NamedTuple, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -448,6 +449,13 @@ def _take(pool, slots):
     if slots is None:
         return pool
     return pool.at[slots].get(mode="fill", fill_value=0)
+
+
+def decay_log_init(key, shape, dtype):
+    """A recurrent layer's ``A_log`` a head: exp(A_log) in [1, 16], as
+    the gated delta-rule and Mamba-2 layers are initialised (uniform,
+    then log)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
 
 class SlidingRing(NamedTuple):

@@ -280,7 +280,8 @@ class LlamaBlock(nn.Module):
 
 def transformer_forward(mod: nn.Module, cfg, block_of, input_ids,
                         kv_caches=None, cache_len=None, rope=True,
-                        logits_at=None, norm=None, publishes=False):
+                        logits_at=None, norm=None, publishes=False,
+                        embed_scale=None):
     """Shared decoder-transformer body (embedding, RoPE table,
     position/cache plumbing, layer loop, final norm, logits through
     the embedding or, with ``tie_word_embeddings`` false, through a
@@ -327,6 +328,8 @@ def transformer_forward(mod: nn.Module, cfg, block_of, input_ids,
                     nn.initializers.normal(0.02),
                     (cfg.vocab_size, cfg.dim), cfg.param_dtype)
     x = tok[input_ids].astype(cfg.dtype)
+    if embed_scale is not None:
+        x = x * jnp.asarray(embed_scale, x.dtype)
     freqs = (rope_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
              if rope else None)
     if cache_len is None:

@@ -41,9 +41,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.kv_cache import (KIND_KV, KIND_RECURRENT, PagedKVLayer,
-                                     RecurrentStateView)
+                                     RecurrentStateView, decay_log_init)
 from ray_tpu.models.llama import LlamaMLP, RMSNorm, transformer_forward
-from ray_tpu.models.solar_open2 import _decay_log_init, _l2norm
+from ray_tpu.models.solar_open2 import _l2norm
 from ray_tpu.ops.attention import multi_head_attention
 from ray_tpu.ops.linear_attention import (kda_chunked, kda_step, pack_heads,
                                           unpack_heads)
@@ -304,7 +304,7 @@ class GatedDeltaNet(nn.Module):
             v = v.reshape(B, T, H, dv)
         with jax.named_scope("kda_gates"):
             q, k = _l2norm(q) * dk ** -0.5, _l2norm(k)
-            decay_log = self.param("A_log", _decay_log_init, (H,), f32)
+            decay_log = self.param("A_log", decay_log_init, (H,), f32)
             dt_bias = self.param("dt_bias", nn.initializers.zeros, (H,), f32)
             # ONE log-decay a head
             g = -jnp.exp(decay_log) * jax.nn.softplus(

@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.kv_cache import (KIND_KV, KIND_RECURRENT,
-                                     RecurrentStateView, live_rows)
+                                     RecurrentStateView, decay_log_init,
+                                     live_rows)
 from ray_tpu.models.llama import (LlamaAttention, RMSNorm, block_forward,
                                   transformer_forward)
 from ray_tpu.models.mixtral import MoEFeedForward
@@ -133,12 +134,6 @@ def _l2norm(x):
                              + L2_EPS)
 
 
-def _decay_log_init(key, shape, dtype):
-    """exp(A_log) in [1, 16], as the gated delta-rule layers are
-    initialised (uniform, then log)."""
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
-
 class KDAAttention(nn.Module):
     """One KDA layer's token mixing on x [B, T, D] (already normed).
     ``kv_cache`` is None (a whole sequence from an empty state) or the
@@ -206,7 +201,7 @@ class KDAAttention(nn.Module):
                        for a in jnp.split(qkv, 3, axis=-1))
         with jax.named_scope("kda_gates"):
             q, k = _l2norm(q) * d ** -0.5, _l2norm(k)
-            decay_log = self.param("A_log", _decay_log_init, (H,), f32)
+            decay_log = self.param("A_log", decay_log_init, (H,), f32)
             dt_bias = self.param("dt_bias", nn.initializers.zeros, (C,), f32)
             f = dense(C, name="f_b")(dense(d, name="f_a")(x))
             g = -jnp.exp(decay_log)[:, None] * jax.nn.softplus(

@@ -52,6 +52,9 @@ APPENDED = (
     Appended(63, "sdar-30b-a3b-chat-d6", "sdar-30b-d6.gen-sat", (
         "denoise_tokens_per_forward", "denoise_commit_share",
         "denoise_idle_share", "denoise_attn_ms", "denoise_step_roofline")),
+    Appended(65, "granite-4.0-h-small-d10-ep2",
+             "granite4-h-small-d10.gen-sat", (
+                 "ssd_prefill_roofline", "ssm_moe_step_roofline")),
 )
 
 

@@ -1829,3 +1829,126 @@ def test_block_step_programs_copy_no_pool_and_fit_the_chip(one_chip, name,
         assert "f32[128,4,151936]" in text
     else:
         assert "f32[4,256,151936]" not in text
+
+
+# ---- a state-space rule with HEADS behind a mixture (models/
+# granite_hybrid.py): at the published widths and
+# granite4-h-small-d10.gen-sat's pool, three layers that keep both kinds
+# (Mamba-2, attention, Mamba-2; 8 of 72 experts held keeps the compile
+# short). A slot's state is [128, 64, 128] float32, the STATES minor:
+# one lane tile, nothing padded, nothing copied.
+
+GRANITE_SLOTS, GRANITE_PAGES = 112, 112 * 32 + 1
+
+
+def _scalar_decay_step(name, one_chip):
+    from ray_tpu.models.granite_hybrid import (ATTENTION, MAMBA,
+                                               GraniteHybrid,
+                                               GraniteHybridConfig)
+    from ray_tpu.models.kv_cache import init_kv_pool
+    from ray_tpu.serve import step_programs
+    S = GRANITE_SLOTS
+    cfg = GraniteHybridConfig(
+        n_layers=3, layer_types=(MAMBA, ATTENTION, MAMBA), vocab_size=50176,
+        max_seq_len=2304, experts_held=(0, 8), param_dtype=jnp.bfloat16)
+    model = GraniteHybrid(cfg)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                           sharding=one_chip), tree)
+    params = placed({"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jnp.zeros((1, 8), jnp.int32))["params"]})
+    pages = placed(jax.eval_shape(lambda: init_kv_pool(
+        cfg, GRANITE_PAGES, PAGE, n_slots=S)))
+    i32 = jnp.int32
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    table = ((S, cfg.max_seq_len // PAGE), i32)
+    if name == "decode":
+        fn = step_programs._jit_decode(model, 0.0, 8, S, False, None)
+        rest = [table, ((S,), i32), ((S,), i32),
+                (key.shape, key.dtype), ((), i32)]
+    else:
+        fn = step_programs._jit_prefill(model, 0.0, 4, False, None)
+        rest = [((4, 256), i32), ((4,), i32), ((4,), i32),
+                ((4, table[0][1]), i32), (key.shape, key.dtype),
+                ((4,), i32)]
+    rest = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in rest]
+    return cfg, fn.lower(params, pages, *rest).compile()
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_a_state_with_heads_stays_in_place_and_its_chunk_is_matmuls(
+        one_chip, monkeypatch, name):
+    """Granite-4.0-H's step programs at the published widths, the
+    mixture's and the pages' rules steered as the chip answers them: the
+    state pool ``[112, 128, 64, 128]`` float32 is kept as declared and
+    updated in place (aliased bytes = pages + states + tails; nothing
+    copied whole), a decode step passes over a layer's state in ONE
+    fusion (the update and the read-out together: its temporaries hold
+    no state's worth), and the ``[4, 256]`` prefill call solves its one
+    chunk by matrix products (``convolution``s under ``ssd_intra`` and
+    ``ssd_carry``) with no ``while`` over positions."""
+    from ray_tpu.models.kv_cache import (kv_pool_page_bytes,
+                                         state_bytes_per_slot)
+    from ray_tpu.ops import grouped_matmul as gm
+    from ray_tpu.ops import paged_decode_attention as pd
+    from ray_tpu.serve import step_programs
+    monkeypatch.setattr(gm, "_use_kernel", lambda: True)
+    monkeypatch.setattr(pd, "_on_one_tpu", lambda: True)
+    for builder in ("_jit_decode", "_jit_prefill"):
+        monkeypatch.setattr(step_programs, builder,
+                            getattr(step_programs, builder).__wrapped__)
+    cfg, compiled = _scalar_decay_step(name, one_chip)
+    text = compiled.as_text()
+    S = GRANITE_SLOTS
+    # (the convolution's tails, 5.7 MB a layer, are small enough for the
+    # compiler to move them to fast memory and back: not held here)
+    shapes = {"pool": ("bf16", (GRANITE_PAGES, PAGE, 8, 128)),
+              "state": ("f32", (S, 128, 64, 128))}
+    for what, (dtype, shape) in shapes.items():
+        pat = r"%s\[%s\]" % (dtype, ",".join(str(d) for d in shape))
+        entry = re.search(pat + r"(\{[^}]*\}) parameter", text)
+        order = ",".join(str(d) for d in reversed(range(len(shape))))
+        assert entry and entry.group(1).startswith("{" + order), (what,
+                                                                  entry)
+        copies = re.findall(
+            r"= " + pat + r"(?:\{[^}]*\})? (?:copy|transpose)\(", text)
+        assert not copies, f"{len(copies)} whole-{what} copies in {name}"
+    mem = compiled.memory_analysis()
+    kept = (GRANITE_PAGES * kv_pool_page_bytes(cfg, PAGE)
+            + S * state_bytes_per_slot(cfg))
+    assert kept == (GRANITE_PAGES * PAGE * 2 * 8 * 128 * 2
+                    + S * 2 * (128 * 64 * 128 * 4 + 3 * 8448 * 2))
+    assert mem.alias_size_in_bytes == pytest.approx(kept, rel=1e-3)
+    assert "tpu_custom_call" in text          # the experts' grouped matmul
+    one_state = S * 128 * 64 * 128 * 4        # a layer's, 470 MB
+    paged = [c for c in re.findall(
+        r"custom-call\([^\n]*attn_scores/[^\n]*paged_decode[^\n]*", text)
+        if 'custom_call_target="tpu_custom_call"' in c]
+    loops = re.findall(r' while\([^\n]*op_name="([^"]*)"', text)
+    if name == "decode":
+        assert len(paged) == 1 and "/layers_1/" in paged[0], paged
+        assert "ssd_intra" not in text
+        # ONE fusion a layer reads the state, steps it and reads it out
+        state = r"f32\[%d,128,64,128\]" % S
+        passes = re.findall(
+            r"^\s*(?:ROOT )?%\S+ = \(?[^\n]*" + state
+            + r"[^\n]*? fusion\([^\n]*ssm_scan", text, re.M)
+        assert len(passes) == 2, (len(passes), passes[:3])
+        assert mem.temp_size_in_bytes < one_state // 2, \
+            mem.temp_size_in_bytes
+    else:
+        assert not paged
+        # the only loops under the recurrence's scope fetch the call's
+        # four rows of the state (a gather); none walks positions
+        scan_loops = [l for l in loops if "ssm_scan" in l or "ssd_" in l]
+        assert all(l.endswith("ssm_scan/gather") for l in scan_loops), \
+            scan_loops
+        for scope, n in (("ssd_intra", 2), ("ssd_carry", 2)):
+            products = re.findall(
+                r"convolution\([^\n]*/" + scope + r"/[^\n]*", text)
+            assert len(products) >= 2 * n, (scope, len(products))
+        assert mem.temp_size_in_bytes < one_state, mem.temp_size_in_bytes

@@ -20,8 +20,9 @@ from ray_tpu.models.kv_cache import (KIND_BORROWED, KIND_INDEXED, KIND_KV,
 SERVE = pathlib.Path(__file__).resolve().parent.parent / "ray_tpu" / "serve"
 # the family modules serve/ may import: sampling and the tiny default
 # (llama), the mixture's counters every mixture family shares (mixtral)
-_FAMILY_MODULES = {"axk1", "kimi_linear", "laguna", "mellum", "olmo_hybrid",
-                   "ouro", "phi4flash", "sdar", "solar_open2"}
+_FAMILY_MODULES = {"axk1", "granite_hybrid", "kimi_linear", "laguna",
+                   "mellum", "olmo_hybrid", "ouro", "phi4flash", "sdar",
+                   "solar_open2"}
 
 
 def _trees():
@@ -73,6 +74,8 @@ def test_the_host_loop_holds_no_device_program():
 
 def _families():
     from ray_tpu.models.axk1 import AXK1, axk1_tiny
+    from ray_tpu.models.granite_hybrid import (GraniteHybrid,
+                                               granite_hybrid_tiny)
     from ray_tpu.models.kimi_linear import KimiLinear, kimi_linear_tiny
     from ray_tpu.models.laguna import Laguna, laguna_tiny
     from ray_tpu.models.llama import Llama, llama_tiny
@@ -86,6 +89,7 @@ def _families():
     return {"llama": (llama_tiny, Llama, "feed_forward"),
             "mixtral": (mixtral_tiny, Mixtral, "moe/w2"),
             "axk1": (axk1_tiny, AXK1, None),
+            "granite_hybrid": (granite_hybrid_tiny, GraniteHybrid, None),
             "kimi_linear": (kimi_linear_tiny, KimiLinear, None),
             "laguna": (laguna_tiny, Laguna, None),
             "mellum": (mellum_tiny, Mellum, None),
@@ -96,8 +100,9 @@ def _families():
             "solar_open2": (solar_open2_tiny, SolarOpen2, None)}
 
 
-FAMILIES = ("llama", "mixtral", "axk1", "kimi_linear", "laguna", "mellum",
-            "olmo_hybrid", "ouro", "phi4flash", "sdar", "solar_open2")
+FAMILIES = ("llama", "mixtral", "axk1", "granite_hybrid", "kimi_linear",
+            "laguna", "mellum", "olmo_hybrid", "ouro", "phi4flash", "sdar",
+            "solar_open2")
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -556,9 +561,12 @@ def test_no_other_familys_programs_reach_the_selective_scan(family,
             arr((4,), i32), arr((4, 8), i32), key,
             *((arr((4,), i32),) if recurrent else ())).as_text(
                 debug_info=True)]
-    # (a scope, a kernel's name and the module's file all follow a "/")
-    assert not any("/ssm_scan" in t or "/selective_scan" in t
-                   for t in texts)
+    # (a scope, a kernel's name and the module's file all follow a "/");
+    # Granite-4.0-H's Mamba-2 layers name the SAME scope over their own
+    # rule (ops/ssd.py: the readers find either recurrence by it)
+    assert not any("/selective_scan" in t for t in texts)
+    assert all(("/ssm_scan" in t) == (family == "granite_hybrid")
+               for t in texts)
 
 
 # ------------- the round's accounts: one module, a counter's names in one
