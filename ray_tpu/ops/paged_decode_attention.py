@@ -32,7 +32,21 @@ pages read once, where they lie:
   the page-major pool: nothing transposed, nothing copied; PR 30's
   kernel wanted a head-major pool, 5.1 ms a step in copies). A slot of
   a row's last group past its last page repeats the page that slot
-  fetched last, which the pipeline does not fetch again;
+  fetched last, which the pipeline does not fetch again. The ids stay
+  laid out AS THE TABLE LIES, ``[row, group, slot]``, and a page's
+  index map reads ``ids[(row_of[v] x groups + group_of[v]) x pages +
+  slot]`` (``visit_schedule``): nothing in the schedule is gathered a
+  visit, because the most visits a table allows are its rows x its
+  groups whatever the riders hold, and a schedule that gathered a
+  table row and ``pages`` ids for each of them cost what the TABLE is
+  wide (1.80 ms a forward at 128 rows x 512 columns, 7.6 % of SDAR's;
+  0.033 so). The map's two more scalar reads and shifts a slot are not
+  free: the pipeline evaluates every map several times a visit and
+  shares no read between them, ~0.05 us a visit (SDAR's kernel 1.02 ->
+  1.08 ms a layer-step at ~780 visits, a fifth of what the schedule
+  saved there; level where a row is one visit: PERF.md section 6, PR
+  66, which also has the reading of a visit's BASE handed over in place
+  of its group, 1.04);
 - both contractions run on the matrix unit over the WHOLE page: every
   query head against every (token, KV head) row of it, the products of
   a head with another KV head's keys masked out of the softmax like
@@ -239,15 +253,36 @@ def _last_seen(pos, block_len: int):
 def visit_schedule(page_table, pos, page_size: int, pages: int,
                    queries: int = 1, block_len: int = 1):
     """The call's visits, from ``page_table`` [B, max_pages] and ``pos``
-    [B] on the device: (the page id each of a visit's ``pages`` slots
-    fetches [visits x pages], a visit's row, its group of pages within
-    the row, the pages a row is walked to [B], the number of visits).
-    A row is walked to the last page ANY of its ``queries`` sees: its
-    last query's, at ``pos + queries - 1``, under the mask of
-    ``block_len``. The arrays are as long as the most visits the table
-    allows; only the first ``n_visits`` are run."""
+    [B] on the device: (the page ids, a visit's row, its group of pages
+    within the row, the pages a row is walked to [B], the number of
+    visits). A row is walked to the last page ANY of its ``queries``
+    sees: its last query's, at ``pos + queries - 1``, under the mask of
+    ``block_len``. ``row_of`` and ``group_of`` are as long as the most
+    visits the table allows, ``B x G`` at ``G = ceil(max_pages /
+    pages)`` groups a row; only the first ``n_visits`` are run.
+
+    ``ids`` [B x G x pages] lies as the TABLE does, by (row, group,
+    slot): the visit of group g of row b fetches into slot c the page
+    ``ids[(b x G + g) x pages + c]``, which is the table's own entry
+    wherever the row holds a page there (``g x pages + c < count[b]``).
+    A slot the row does not hold names THE PAGE THAT SLOT FETCHED LAST,
+    so that consecutive visits see an unchanged block index there and
+    the pipeline fetches nothing: in group g >= 1 the same slot of
+    group g - 1 (g is then the row's last group, and the one before it
+    is full), in group 0 what the nearest earlier row that held the
+    slot fetched into it last, and page 0, the null page, where no
+    earlier row held it. Groups past a row's visits are never read.
+
+    Nothing here is as long as the visits but one compare against the
+    rows' cumulative visits: the form before gathered a table row and
+    ``pages`` single ids a VISIT, so its cost followed the table's
+    size (ms a call on a v5e, before | so: 1.797 | 0.033 at SDAR's 128
+    rows x 512 columns at four pages a visit, 0.216 | 0.018 at 32 x
+    256 at sixteen, 0.075 | 0.013 at 32 x 64 at eight, 2.511 | 0.020
+    at the widest table the rule allows, 32 x 3,584: PERF.md section
+    6, PR 66)."""
     B, max_pages = page_table.shape
-    max_groups = -(-max_pages // pages)
+    G = -(-max_pages // pages)
     i32 = jnp.int32
     pos = pos.astype(i32)
     live = page_table[:, 0] != 0
@@ -256,25 +291,39 @@ def visit_schedule(page_table, pos, page_size: int, pages: int,
     count = jnp.where(live, jnp.minimum(last // page_size + 1, max_pages),
                       0)
     # visit v is group ``group_of[v]`` of row ``row_of[v]``: a row has as
-    # many visits as groups of pages, and one where it has none
+    # many visits as groups of pages, and one where it has none. Its row
+    # is the number of rows whose visits have all ended by v, its group
+    # v less those rows' visits
     visits = jnp.maximum(-(-count // pages), 1)
     after = jnp.cumsum(visits, dtype=i32)
-    visit = jnp.arange(B * max_groups, dtype=i32)
-    row_of = jnp.minimum(
-        jnp.searchsorted(after, visit, side="right",
-                         method="compare_all").astype(i32), B - 1)
-    group_of = visit - (after - visits)[row_of]
-    # the page each of a visit's ``pages`` slots fetches: the row's own,
-    # by id; past its last page, the one the slot fetched last (a block
-    # whose index has not changed is not fetched again)
-    at = group_of[:, None] * pages + jnp.arange(pages, dtype=i32)[None]
-    held = at < count[row_of][:, None]
-    ids = jnp.take_along_axis(
-        page_table.astype(i32)[row_of],
-        jnp.minimum(at, max_pages - 1), axis=1)
-    latest = jax.lax.cummax(jnp.where(held, visit[:, None], 0), axis=0)
-    ids = jnp.take_along_axis(ids, latest, axis=0).reshape(-1)
-    return ids, row_of, group_of, count, after[-1]
+    visit = jnp.arange(B * G, dtype=i32)
+    ended = after[None, :] <= visit[:, None]                  # [B x G, B]
+    row_of = jnp.minimum(jnp.sum(ended, axis=1, dtype=i32), B - 1)
+    group_of = visit - jnp.sum(jnp.where(ended, visits[None], 0), axis=1,
+                               dtype=i32)
+    table = jnp.pad(page_table.astype(i32),
+                    ((0, 0), (0, G * pages - max_pages)))
+    # the last page a row fetches into each slot, and for each row what
+    # the nearest EARLIER row that held the slot left there
+    slot = jnp.arange(pages, dtype=i32)[None]
+    holds = count[:, None] > slot                             # [B, pages]
+    last_id = jnp.take_along_axis(
+        table, jnp.where(
+            holds, slot + pages * ((count[:, None] - 1 - slot) // pages),
+            0), axis=1)
+    holder = jax.lax.cummax(
+        jnp.where(holds, jnp.arange(B, dtype=i32)[:, None], -1), axis=0)
+    holder = jnp.concatenate(
+        [jnp.full((1, pages), -1, i32), holder[:-1]], axis=0)
+    left = jnp.where(
+        holder >= 0,
+        jnp.take_along_axis(last_id, jnp.maximum(holder, 0), axis=0), 0)
+    # a slot past its row's last page: the same slot a group earlier
+    table = table.reshape(B, G, pages)
+    at = jnp.arange(G * pages, dtype=i32).reshape(1, G, pages)
+    ids = jnp.where(at < count[:, None, None], table,
+                    jnp.concatenate([left[:, None], table[:, :-1]], axis=1))
+    return ids.reshape(-1), row_of, group_of, count, after[-1]
 
 
 def _split(col, n: int):
@@ -408,10 +457,14 @@ def _attend(q, pk, pv, page_table, pos, *, softmax_scale: float,
     assert pages % span == 0, (pages, span)
     ids, row_of, group_of, count, n_visits = visit_schedule(
         page_table, pos, Pg, pages, T, block_len)
+    groups = -(-page_table.shape[1] // pages)
 
     def page(c):
+        # slot c of the visit's (row, group), where the ids lie as the
+        # table does
         return pl.BlockSpec(
-            (1, Pg * KH, D), lambda v, ids, *_: (ids[v * pages + c], 0, 0))
+            (1, Pg * KH, D), lambda v, ids, row_of, group_of, *_: (
+                ids[(row_of[v] * groups + group_of[v]) * pages + c], 0, 0))
 
     def row(width):
         return pl.BlockSpec(

@@ -13,6 +13,7 @@ import hashlib
 import importlib.util
 import json
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -185,6 +186,26 @@ def test_a_null_row_with_a_stale_position_changes_nothing(queries):
         got, np.asarray(_kernel(*calm, block_len=block_len)))
 
 
+@functools.lru_cache(maxsize=None)
+def _jitted_schedule(**static):
+    return jax.jit(functools.partial(pd.visit_schedule, **static))
+
+
+def _schedule(pt, pos, pages, page_size=PAGE, T=1, block_len=1):
+    """``visit_schedule``'s results as NumPy, the ids as the [visits,
+    pages] a visit FETCHES: the dense layout [rows, groups, pages] read
+    at each run visit's (row, group), which is what the kernel's index
+    map does."""
+    ids, row_of, group_of, count, n = (
+        np.asarray(a) for a in _jitted_schedule(
+            page_size=page_size, pages=pages, queries=T,
+            block_len=block_len)(jnp.asarray(pt), jnp.asarray(pos)))
+    B, max_pages = pt.shape
+    assert row_of.shape == group_of.shape == (B * -(-max_pages // pages),)
+    rows, groups = row_of[:n], group_of[:n]
+    return ids.reshape(B, -1, pages)[rows, groups], rows, groups, count
+
+
 @pytest.mark.parametrize("pages,want", [(1, 16 + 1 + 5 + 1),
                                         (2, 8 + 1 + 3 + 1),
                                         (8, 2 + 1 + 1 + 1)])
@@ -192,17 +213,13 @@ def test_the_grid_is_each_row_s_own_pages(pages, want):
     """A rider is walked to its own last page and fetches its own pages
     alone; a null row gets one empty visit whatever its stale
     position."""
-    contexts = [1024, None, 300, None]
-    _q, _pk, _pv, pt, pos = _inputs(contexts, jnp.float32, max_pages=64)
-    ids, row_of, group_of, count, n = (np.asarray(a) for a in jax.jit(
-        functools.partial(pd.visit_schedule, page_size=PAGE,
-                          pages=pages))(pt, pos))
-    assert int(n) == want
-    assert count.tolist() == [16, 0, 5, 0]
-    ids = ids.reshape(-1, pages)[:n]
-    rows, groups = row_of[:n], group_of[:n]
-    assert rows.tolist() == sorted(rows.tolist())
+    pt, pos = _rows([1024, None, 300, None], 64, np.random.default_rng(0),
+                    stale=100_000)
     table = np.asarray(pt)
+    ids, rows, groups, count = _schedule(table, pos, pages)
+    assert len(ids) == want
+    assert count.tolist() == [16, 0, 5, 0]
+    assert rows.tolist() == sorted(rows.tolist())
     for b in range(4):
         mine = ids[rows == b]
         assert groups[rows == b].tolist() == list(range(len(mine)))
@@ -226,17 +243,142 @@ def test_a_row_is_walked_to_the_last_page_any_of_its_queries_sees(
     from 62 on. Blocks that divide a page end where the last query's
     page does; under blocks of twelve the query at 62 sees to 71, a
     page further than it sits."""
-    pt = jnp.asarray([[3, 4, 0], [5, 6, 0], [7, 8, 0], [0, 0, 0]],
-                     jnp.int32)
-    pos = jnp.asarray([59, 62, 64, 9999], jnp.int32)
-    ids, row_of, _g, count, n = (np.asarray(a) for a in jax.jit(
-        functools.partial(pd.visit_schedule, page_size=PAGE, pages=1,
-                          queries=T, block_len=block_len))(pt, pos))
+    pt = np.asarray([[3, 4, 0], [5, 6, 0], [7, 8, 0], [0, 0, 0]], np.int32)
+    pos = np.asarray([59, 62, 64, 9999], np.int32)
+    ids, rows, _g, count = _schedule(pt, pos, 1, T=T, block_len=block_len)
     assert count.tolist() == want
-    assert int(n) == sum(max(c, 1) for c in want)
+    assert len(ids) == sum(max(c, 1) for c in want)
     for b in range(3):
-        assert ids[:n][row_of[:n] == b].tolist() == np.asarray(
-            pt)[b, :want[b]].tolist()
+        assert ids[rows == b, 0].tolist() == pt[b, :want[b]].tolist()
+    # the null row's one visit fetches what the row before it left
+    assert ids[rows == 3, 0].tolist() == [8]
+
+
+# a small table whose width is no whole number of groups at 8 or 16
+# pages a visit, pages of 8 tokens
+WALK_ROWS, WALK_COLUMNS, WALK_PAGE, WALK_FILLS = 6, 20, 8, 50
+
+
+def _fills(pages, T, block_len, seed):
+    """``WALK_FILLS`` seeded (table, pos) of ONE shape: ragged rows with
+    null ones among them (a stale position each), and by turns rows of
+    one page, rows that end on a group's edge, a null first row, a full
+    table."""
+    rng = np.random.default_rng(seed)
+    B, mp, P = WALK_ROWS, WALK_COLUMNS, WALK_PAGE
+    for fill in range(WALK_FILLS):
+        held = rng.integers(1, mp + 1, B)
+        held[rng.random(B) < 0.25] = 0
+        if fill % 5 == 1:
+            held[rng.permutation(B)[:2]] = 1
+        elif fill % 5 == 2:
+            held[rng.permutation(B)[:3]] = pages * rng.integers(
+                1, mp // pages + 1, 3)
+        elif fill % 5 == 3:
+            held[0] = 0
+        elif fill % 5 == 4:
+            held[:] = mp
+        ids = 1 + rng.permutation(B * mp).reshape(B, mp).astype(np.int32)
+        table = np.where(np.arange(mp)[None] < held[:, None], ids, 0)
+        # the row's LAST query's block ends in its last held page, on
+        # any token of it the mask allows; a full row may sit past the
+        # table's end, where the walk stops at the table
+        end = held * P - rng.integers(0, P // block_len, B) * block_len
+        end += np.where(held == mp, rng.integers(0, 3, B) * P, 0)
+        pos = np.where(held > 0, end - T, rng.integers(0, 10_000, B))
+        yield table.astype(np.int32), pos.astype(np.int32)
+
+
+def _walk(table, pos, page_size, pages, T, block_len):
+    """The visits by a plain walk of the table: row after row, group
+    after group, each slot remembering the page it fetched last (the
+    null page before any). ([visits, pages] page ids, rows, groups,
+    pages a row is walked to)."""
+    B, max_pages = table.shape
+    slots = [0] * pages
+    ids, rows, groups, counts = [], [], [], []
+    for b in range(B):
+        last = int(pos[b]) + T - 1
+        last = (last // block_len + 1) * block_len - 1
+        count = min(last // page_size + 1, max_pages) if table[b, 0] else 0
+        counts.append(count)
+        for g in range(max(-(-count // pages), 1)):
+            for c in range(pages):
+                if g * pages + c < count:
+                    slots[c] = int(table[b, g * pages + c])
+            ids.append(list(slots))
+            rows.append(b)
+            groups.append(g)
+    return ids, rows, groups, counts
+
+
+@pytest.mark.parametrize("queries", ["one_query", "T4_block"])
+@pytest.mark.parametrize("pages", [1, 2, 4, 8, 16])
+def test_the_schedule_is_a_plain_walk_of_the_table(pages, queries):
+    """Every visit's row, group and the page each of its slots fetches,
+    against a walk that remembers what each slot fetched last: one
+    compiled shape a case, fed fifty tables. The pages FETCHED (a
+    slot's id differing from the visit before; the first visit fetches
+    every slot) are the rows' own, once each, and a null page for each
+    slot nobody held at the first visit."""
+    T, block_len = QUERIES[queries]
+    for table, pos in _fills(pages, T, block_len, seed=pages):
+        ids, rows, groups, count = _schedule(table, pos, pages, WALK_PAGE,
+                                             T, block_len)
+        want, want_rows, want_groups, want_count = _walk(
+            table, pos, WALK_PAGE, pages, T, block_len)
+        assert count.tolist() == want_count
+        assert rows.tolist() == want_rows
+        assert groups.tolist() == want_groups
+        assert ids.tolist() == want
+        fetched = pages + int((ids[1:] != ids[:-1]).sum())
+        nobody_yet = int((np.arange(pages) >= count[0]).sum())
+        assert fetched == sum(want_count) + nobody_yet
+
+
+def test_the_pages_fetched_are_the_rows_own_once_each():
+    """At a cell's own table (Mistral's 32 x 64 at eight pages a visit,
+    contexts of 16-2,560 tokens, a quarter of the rows null): a slot's
+    page changes between consecutive visits exactly ``sum(count)``
+    times, plus at most ``pages`` for the slots nobody held yet at the
+    first visit."""
+    rng = np.random.default_rng(0)
+    contexts = [None if rng.random() < 0.25 else int(n)
+                for n in rng.integers(16, 2561, 32)]
+    pt, pos = _rows(contexts, 64, rng, stale=100_000)
+    ids, _rows_of, _groups, count = _schedule(np.asarray(pt), pos, 8)
+    assert count.tolist() == [0 if n is None else -(-n // PAGE)
+                              for n in contexts]
+    fetched = 8 + int((ids[1:] != ids[:-1]).sum())
+    assert 0 <= fetched - int(count.sum()) <= 8
+    # no page is fetched twice
+    changed = np.concatenate([np.ones((1, 8), bool), ids[1:] != ids[:-1]])
+    held = ids[changed & (ids != 0)]
+    assert len(held) == len(set(held.tolist())) == int(count.sum())
+
+
+@pytest.mark.parametrize("rows,max_pages,pages,T,block_len", [
+    (128, 512, 4, 4, 4), (32, 64, 8, 1, 1), (32, 256, 16, 1, 1),
+    (128, 64, 16, 1, 1), (32, 3584, 16, 1, 1)],
+    ids=["sdar", "mistral", "mellum2", "kimi_linear", "the_widest"])
+def test_the_schedule_gathers_nothing_a_visit(rows, max_pages, pages, T,
+                                              block_len):
+    """The mechanism, pinned on the lowered text (no chip, nothing
+    compiled): at a cell's table no ``gather`` of the schedule has a
+    result as long as the visits (the form before gathered a table row
+    and ``pages`` single ids a visit: 65,536 of them at SDAR's table,
+    1.8 ms a forward); its two gathers are over [rows, pages]."""
+    text = _jitted_schedule(
+        page_size=PAGE, pages=pages, queries=T, block_len=block_len).lower(
+        jax.ShapeDtypeStruct((rows, max_pages), jnp.int32),
+        jax.ShapeDtypeStruct((rows,), jnp.int32)).as_text()
+    visits = rows * -(-max_pages // pages)
+    gathers = re.findall(r'"?stablehlo\.(?:dynamic_)?gather"?\(.*'
+                         r'-> tensor<([0-9x]+)xi32>', text)
+    assert gathers and "scatter" not in text
+    for shape in gathers:
+        dims = [int(d) for d in shape.split("x")]
+        assert visits not in dims and dims == [rows, pages], shape
 
 
 @pytest.mark.parametrize("queries", ["one_query", "T4_causal", "T4_block"])

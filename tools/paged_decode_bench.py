@@ -17,6 +17,16 @@ shapes and contexts:
   sdar     128 rows of a BLOCK of 4 queries under the block mask, 32 on
            4, a table of 512 columns, contexts 1,024-2,048 (the block
            among them), three rows null
+  olmo     96 rows, 32 on 32, a table of 16 columns, contexts 256-512
+  phi4     64 rows, 64 on 16 head rows a page, a table of 48 columns,
+           contexts 2,048-3,072, four rows null
+  laguna   128 rows, 48 on 8, contexts 1,024-2,048, three rows null
+  granite  112 rows, 32 on 8, a table of 36 columns, contexts
+           1,024-2,048, nine rows null
+  widest   32 rows, 32 on 4, Mellum 2's contexts under the widest table
+           the rule hands the kernel (3,584 columns: 229,376 tokens a
+           row): what the schedule costs where the table is mostly
+           empty
   verify   32 rows of 5 queries under the causal mask (a speculative
            verify of four drafts), 32 on 8, contexts 256-352: a made-up
            shape no cell runs, which ``applies`` leaves to the loop
@@ -62,6 +72,11 @@ SHAPES = {
     "axk1": (32, 64, None, 256, (8192, 8704), 8),
     "kimi": (128, 32, None, 64, (1024, 2048), 3),
     "sdar": (128, 32, 4, 512, (1024, 2048), 3, 4, 4),
+    "olmo": (96, 32, 32, 16, (256, 512), 0),
+    "phi4": (64, 64, 16, 48, (2048, 3072), 4),
+    "laguna": (128, 48, 8, 64, (1024, 2048), 3),
+    "granite": (112, 32, 8, 36, (1024, 2048), 9),
+    "widest": (32, 32, 4, 3584, (8192, 8704), 8),
     "verify": (32, 32, 8, 64, (256, 352), 0, 5, 1),
 }
 
